@@ -1,0 +1,358 @@
+#!/usr/bin/env python
+"""chip_smoke.py — the quickest proof that the system starts on the chip.
+
+One process drives the main path once, through the entry points a user
+calls, at the full width of the one full-size model the repo supports:
+
+    InProcessHiPS (2 parties x 1 worker, every role a thread, every byte
+    over loopback sockets) -> kv workers -> DeviceResidentTrainer ->
+    jitted fwd+BSC-select / sparse apply on the device -> party servers
+    -> global server,
+
+on the 59M decoder of examples/transformer_bsc_device.py (dim 512,
+depth 8, heads 8, vocab 32768, T 512, bf16 compute, batch 8, BSC
+threshold 0.01, lr 0.05, momentum 0.9). Weights are random from a fixed
+seed; the data is the seeded synthetic token stream.
+
+It passes only if: jax's default backend is a TPU; tools/chip_sanity is
+ok; every Pallas kernel in the package compiled under Mosaic and matches
+its reference; every loss is finite; both workers end with bit-identical
+parameters that moved from the initial ones (FSA lockstep is this
+repo's correctness signal); telemetry.wan_bytes() grew; and nothing
+compiled after warm-up. With >= 4 devices it repeats the round on the
+mesh-party tier (2 parties x 2-chip meshes) and checks placement and
+the intra-party all-reduce.
+
+The LAST stdout line of a pass is one JSON object,
+``{"ok": true, "device": {"platform": "tpu", "kind": ..., "count": N}}``.
+Without a TPU it exits nonzero and prints no such line: it never sets
+``jax_platforms``, honours no platform override and has no CPU
+fallback. Wall times it prints are information, not results.
+
+``--rehearse`` is a labelled dry run for the CPU sandbox (tiny width,
+interpreted kernels) that checks the script's own control flow before
+chip time is spent; it never prints the result line and exits with
+REHEARSAL_EXIT, never 0.
+"""
+
+from __future__ import annotations
+
+import argparse
+import faulthandler
+import importlib.metadata
+import json
+import os
+import re
+import sys
+import threading
+import time
+import traceback
+
+import numpy as np
+
+FULL = dict(dim=512, depth=8, heads=8, vocab=32768, seq=512, batch=8)
+TINY = dict(dim=64, depth=2, heads=4, vocab=512, seq=32, batch=4)
+THRESHOLD, LR, MOMENTUM = 0.01, 0.05, 0.9
+# Every step is one complete two-tier FSA round. At full width the WAN
+# tier of this configuration moves the dense fp32 aggregate both ways
+# (945 MB a round, counted by telemetry), so a round is seconds of host
+# wall-clock; ten keep a cold run, mesh variant included, inside the
+# deadline.
+STEPS = 10
+FLASH_SEQS = (512, 2048)    # the lengths bench.py's attention rows use
+
+# One overall deadline inside the 1200 s contract: on expiry every
+# thread's stack is dumped and the process exits 1. The kv timeouts sit
+# well inside it so a hung barrier or round raises its OWN TimeoutError
+# first (the defaults, 600 s / 300 s, would outlast the deadline).
+DEADLINE_S = 1080
+KV_TIMEOUTS = {"barrier_timeout_s": 120.0, "op_timeout_s": 120.0}
+WORKERS_S = 900.0
+REHEARSAL_EXIT = 10
+
+
+def say(msg: str) -> None:
+    print(f"[smoke] {msg}", flush=True)
+
+
+def check_device(rehearse: bool) -> dict:
+    import jax
+    import jaxlib
+
+    from geomx_tpu.runtime import device_stamp, require_tpu
+
+    try:
+        libtpu = importlib.metadata.version("libtpu")
+    except importlib.metadata.PackageNotFoundError:
+        libtpu = "not installed"
+    stamp = device_stamp()
+    say(f"platform={stamp['platform']} device_kind={stamp['kind']!r} "
+        f"count={stamp['count']} jax={jax.__version__} "
+        f"jaxlib={jaxlib.__version__} libtpu={libtpu}")
+    if not rehearse:
+        require_tpu()
+    return stamp
+
+
+def check_sanity() -> None:
+    from tools.chip_sanity import run_chip_sanity
+
+    out = run_chip_sanity()
+    for name in ("transfer_bitexact", "bitcast_in_jit", "bsc_oracle"):
+        say(f"chip_sanity {name}: {out[name]}")
+    say(f"chip_sanity matmul_precision: {out['matmul_precision']}")
+    honest = out["blocking_honest"]
+    say(f"chip_sanity blocking_honest: {honest}")
+    if not honest.get("ok"):
+        say("!!! block_until_ready IS NOT HONEST ON THIS BACKEND: only "
+            "value-fetch-fenced timings may be trusted !!!")
+    if not out["ok"]:
+        raise RuntimeError(f"chip_sanity failed: {json.dumps(out)}")
+    say(f"chip_sanity ok ({out['wall_s']} s)")
+
+
+def check_kernels(on_chip: bool) -> None:
+    """Compile and run every Pallas kernel in the package against its
+    reference at the shapes the repo uses; on the chip, require the
+    Mosaic call in the lowering (compiled, not interpreted)."""
+    import jax
+    import jax.numpy as jnp
+
+    from geomx_tpu.models.transformer import dense_attention
+    from geomx_tpu.ops.flash_attention import flash_attention
+
+    def probe(attn):
+        """One program: output (as aux) and dq, dk, dv of its sum."""
+        def f(q, k, v):
+            out = attn(q, k, v).astype(jnp.float32)
+            return out.sum(), out
+
+        return jax.jit(jax.value_and_grad(f, argnums=(0, 1, 2),
+                                          has_aux=True))
+
+    B, H, D = 2, 8, 64
+    for T in (FLASH_SEQS if on_chip else (64,)):
+        q, k, v = (jax.random.normal(jax.random.PRNGKey(i), (B, T, H, D),
+                                     jnp.bfloat16) for i in range(3))
+        flash, dense = probe(flash_attention), probe(dense_attention)
+        # an interpreted kernel lowers to plain HLO: the Mosaic custom
+        # call IS the proof that it compiled
+        if on_chip and "tpu_custom_call" not in flash.lower(
+                q, k, v).as_text():
+            raise RuntimeError(f"flash attention T={T}: no Mosaic call in "
+                               "the lowering — the kernel did not compile")
+        t0 = time.perf_counter()
+        ((_s, out), g), ((_sr, ref), gr) = flash(q, k, v), dense(q, k, v)
+        # bf16 operands: one ulp at the output scale is ~2^-7 relative
+        got = [np.asarray(x, np.float32) for x in (out, *g)]
+        want = [np.asarray(x, np.float32) for x in (ref, *gr)]
+        errs = [float(np.abs(a - b).max() / max(np.abs(b).max(), 1e-6))
+                for a, b in zip(got, want)]
+        say(f"flash attention T={T} bf16 D={D}: rel err fwd/dq/dk/dv = "
+            f"{[round(e, 4) for e in errs]} "
+            f"({time.perf_counter() - t0:.1f} s incl. compile)")
+        if not all(np.isfinite(e) and e < 0.03 for e in errs):
+            raise RuntimeError(f"flash attention T={T} disagrees with the "
+                               f"dense reference: {errs}")
+
+
+def run_round(shape: dict, steps: int, compiles, mesh_party: bool) -> None:
+    """The main path: warm up outside the FSA round, take ``steps``
+    steps through a live 2-party topology, stop it, check the outcome."""
+    import jax.numpy as jnp
+
+    from examples.transformer_bsc_device import (
+        build_transformer_grad_step, synth_batch)
+    from geomx_tpu import telemetry
+    from geomx_tpu.simulate import InProcessHiPS
+    from geomx_tpu.trainer_device import DeviceResidentTrainer
+
+    tag = "mesh-party" if mesh_party else "hips-bsc"
+    layout = (dict(num_parties=2, workers_per_party=2, party_mesh_size=2)
+              if mesh_party else dict(num_parties=2, workers_per_party=1))
+    telemetry.enable(True)
+    t0 = time.perf_counter()
+    topo = InProcessHiPS(**layout, extra_cfg=KV_TIMEOUTS).start()
+    # built once: grad_step is a pure function, each trainer traces it
+    leaves0, grad_step = build_transformer_grad_step(
+        shape["dim"], shape["depth"], shape["heads"], shape["vocab"],
+        shape["seq"])
+    flat0 = np.concatenate([l.ravel() for l in leaves0])
+    say(f"{tag}: topology up, van={'+'.join(topo.van_backends())}, "
+        f"{flat0.size / 1e6:.1f}M params in {len(leaves0)} keys "
+        f"({time.perf_counter() - t0:.1f} s)")
+
+    n = len(topo.workers)
+    compile_lock = threading.Lock()
+    warm = threading.Barrier(n)
+    res = [None] * n
+
+    def master_init(kv):
+        for i, leaf in enumerate(leaves0):
+            kv.init(i, leaf)
+        kv.wait()
+
+    def worker(kv):
+        try:
+            _worker(kv)
+        except BaseException:
+            warm.abort()    # release the peer now, not at its timeout
+            raise
+
+    def _worker(kv):
+        w = topo.workers.index(kv)
+        t_boot = time.perf_counter()
+        tr = DeviceResidentTrainer(
+            list(leaves0), kv, grad_step, threshold=THRESHOLD,
+            learning_rate=LR, momentum=MOMENTUM)
+        t_boot = time.perf_counter() - t_boot
+        rng = np.random.default_rng(1234 + w)
+        batches = [synth_batch(rng, shape["batch"], shape["seq"],
+                               shape["vocab"]) for _ in range(4)]
+        if not mesh_party:
+            batches = [jnp.asarray(b) for b in batches]
+        info = {"boot_s": t_boot, "k": tr.k}
+        with compile_lock:
+            # trace+compile outside the FSA round (tr.step would barrier
+            # on the peer and deadlock against the lock); serialized so
+            # the second worker's compile hits the persistent cache
+            c0, t_c = compiles.seconds, time.perf_counter()
+            tr.warmup(batches[0], None)
+            info["warm_s"] = time.perf_counter() - t_c
+            info["compile_s"] = compiles.seconds - c0
+            if mesh_party:
+                info.update(_inspect_mesh(tr, w, batches[0]))
+        warm.wait(WORKERS_S)
+        info["programs_at_warm"] = compiles.programs
+        info["wan0"] = telemetry.wan_bytes()
+        losses, step_s = [], []
+        for it in range(steps):
+            t_s = time.perf_counter()
+            losses.append(tr.step(batches[it % len(batches)], None))
+            step_s.append(time.perf_counter() - t_s)
+        info["losses"], info["step_s"] = losses, step_s
+        info["flat"] = np.asarray(tr._flat)
+        if mesh_party:
+            shards = [np.asarray(s.data) for s in
+                      tr._flat.addressable_shards]
+            info["replicas_equal"] = all(
+                np.array_equal(shards[0].view(np.uint32),
+                               s.view(np.uint32)) for s in shards[1:])
+        res[w] = info
+
+    topo.run_workers(worker, include_master=master_init, timeout=WORKERS_S)
+    programs_end, wan_end = compiles.programs, telemetry.wan_bytes()
+    wan_codecs = sorted(telemetry.wan_bytes_by_codec())
+    topo.stop()     # re-raises any node's error
+
+    for w, r in enumerate(res):
+        say(f"{tag} worker {w}: bootstrap {r['boot_s']:.1f} s, warm-up "
+            f"{r['warm_s']:.1f} s (compile {r['compile_s']:.1f} s), "
+            f"selects {r['k']} of {flat0.size} per round, loss "
+            f"{r['losses'][0]:.4f} -> {r['losses'][-1]:.4f}, median "
+            f"step {np.median(r['step_s']) * 1e3:.0f} ms [information]")
+        if not np.isfinite(r["losses"]).all():
+            raise RuntimeError(f"{tag} worker {w}: non-finite loss "
+                               f"{r['losses']}")
+        if mesh_party:
+            say(f"{tag} worker {w}: state on devices {r['state_devices']}"
+                f", all-reduce groups {r['allreduce_groups']}")
+            if not r["replicas_equal"]:
+                raise RuntimeError(f"{tag} worker {w}: mesh replicas of "
+                                   "the parameters differ")
+    bits = [r["flat"].view(np.uint32) for r in res]
+    if not all(np.array_equal(bits[0], b) for b in bits[1:]):
+        raise RuntimeError(f"{tag}: workers' parameters are not "
+                           "bit-identical after the run (FSA lockstep)")
+    if np.array_equal(bits[0], flat0.view(np.uint32)):
+        raise RuntimeError(f"{tag}: parameters never moved from init")
+    wan = wan_end - max(r["wan0"] for r in res)
+    if wan <= 0:
+        raise RuntimeError(f"{tag}: telemetry counted no WAN bytes")
+    late = programs_end - max(r["programs_at_warm"] for r in res)
+    if late:
+        raise RuntimeError(f"{tag}: {late} XLA program(s) compiled after "
+                           "warm-up")
+    say(f"{tag}: PASS — {steps} steps, workers bit-identical and moved, "
+        f"{wan / steps / 1e3:.1f} kB WAN per round (wire codecs "
+        f"{wan_codecs}), 0 compiles after warm-up, "
+        f"{compiles.cache_hits} persistent-cache hits so far")
+
+
+def _inspect_mesh(tr, party: int, batch) -> dict:
+    """Mesh-party placement: the party's state lives on its own chips
+    only, and its compiled step reduces across exactly those."""
+    import jax
+
+    devs = jax.devices()
+    want = {d.id for d in devs[2 * party:2 * party + 2]}
+    for name in ("_flat", "_u", "_v", "_mom"):
+        got = {d.id for d in getattr(tr, name).sharding.device_set}
+        if got != want:
+            raise RuntimeError(f"party {party}: {name} on devices {got}, "
+                               f"expected {want}")
+    X, y = tr._place_batch(batch, None)
+    step = tr._fwd_chunks if tr._pipeline else tr._fwd_compress
+    hlo = step.lower(tr._flat, tr._u, tr._v, X, y).compile().as_text()
+    # replica_groups prints as {{0,1}} or, in iota form, [groups,size]<=[n]
+    groups = sorted(set(re.findall(
+        r"all-reduce[^\n]*?replica_groups="
+        r"(\{\{[\d,]*\}[\d,{}]*\}|\[[\d,]+\]<=\[[\d,]+\])", hlo)))
+    sizes = {len(g[2:g.index("}")].split(",")) if g.startswith("{")
+             else int(g[1:g.index("]")].split(",")[-1]) for g in groups}
+    if sizes != {2}:
+        raise RuntimeError(
+            f"party {party}: expected 2-device all-reduces in the compiled "
+            f"step, found replica_groups {groups or 'none'}")
+    return {"state_devices": sorted(want), "allreduce_groups": groups}
+
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--rehearse", action="store_true",
+                    help="CPU dry run at tiny width; never a chip result")
+    ap.add_argument("--steps", type=int, default=STEPS)
+    args = ap.parse_args(argv)
+
+    faulthandler.dump_traceback_later(DEADLINE_S, exit=True)
+    t_start = time.perf_counter()
+    if args.rehearse:
+        say("DRY RUN on whatever backend jax picked: tiny width, "
+            "interpreted kernels — NOT a chip result")
+
+    from geomx_tpu.runtime import CompileCounter, setup_compile_cache
+
+    stamp = check_device(args.rehearse)
+    say(f"compile cache: {setup_compile_cache()}")
+    compiles = CompileCounter()
+    check_sanity()
+    check_kernels(on_chip=not args.rehearse)
+    shape = TINY if args.rehearse else FULL
+    run_round(shape, args.steps, compiles, mesh_party=False)
+    if stamp["count"] >= 4:
+        run_round(shape, args.steps, compiles, mesh_party=True)
+    else:
+        say(f"mesh-party: not run ({stamp['count']} devices)")
+    say(f"total {time.perf_counter() - t_start:.0f} s, "
+        f"{compiles.programs} programs built or loaded "
+        f"({compiles.seconds:.0f} s), {compiles.cache_hits} from the "
+        "persistent cache [information]")
+    faulthandler.cancel_dump_traceback_later()
+    if args.rehearse:
+        say(f"DRY RUN complete — no result; exit {REHEARSAL_EXIT}")
+        return REHEARSAL_EXIT
+    print(json.dumps({"ok": True, "device": stamp}), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    try:
+        code = main()
+    except BaseException:  # noqa: BLE001 — report, then exit nonzero NOW
+        traceback.print_exc()
+        sys.stdout.flush()
+        sys.stderr.flush()
+        # a failed topology leaves daemon and native threads behind;
+        # they must not hold the exit open
+        os._exit(1)
+    sys.exit(code)

@@ -54,6 +54,10 @@ def main():
 
         jax.config.update("jax_platforms", "cpu")
 
+    from geomx_tpu.runtime import setup_compile_cache
+
+    setup_compile_cache()
+
     if args.mixed_sync:
         kv = gx.kv.create("dist_async")
         if kv.is_master_worker:

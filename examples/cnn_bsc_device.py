@@ -45,6 +45,10 @@ def main():
         import jax
 
         jax.config.update("jax_platforms", "cpu")
+
+    from geomx_tpu.runtime import setup_compile_cache
+
+    setup_compile_cache()
     import jax.numpy as jnp
 
     import geomx_tpu as gx

@@ -48,6 +48,10 @@ def main():
         import jax
 
         jax.config.update("jax_platforms", "cpu")
+
+    from geomx_tpu.runtime import setup_compile_cache
+
+    setup_compile_cache()
     import jax.numpy as jnp
 
     from geomx_tpu.esync import ESyncTrainer

@@ -41,6 +41,10 @@ def main():
 
         jax.config.update("jax_platforms", "cpu")
 
+    from geomx_tpu.runtime import setup_compile_cache
+
+    setup_compile_cache()
+
     kv = gx.kv.create("dist_sync")
     if kv.is_master_worker:
         kv.set_optimizer(gx_opt.Adam(learning_rate=args.learning_rate))

@@ -43,6 +43,10 @@ def main():
 
         jax.config.update("jax_platforms", "cpu")
 
+    from geomx_tpu.runtime import setup_compile_cache
+
+    setup_compile_cache()
+
     period_k1 = int(os.getenv("MXNET_KVSTORE_HFA_K1", 2))
 
     kv = gx.kv.create("dist_sync")

@@ -41,6 +41,10 @@ def main():
 
         jax.config.update("jax_platforms", "cpu")
 
+    from geomx_tpu.runtime import setup_compile_cache
+
+    setup_compile_cache()
+
     size_lower_bound = int(os.getenv("MXNET_KVSTORE_SIZE_LOWER_BOUND", 200000))
 
     kv = gx.kv.create("dist_sync")

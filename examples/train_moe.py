@@ -46,6 +46,10 @@ def main():
 
     if args.cpu:
         jax.config.update("jax_platforms", "cpu")
+
+    from geomx_tpu.runtime import setup_compile_cache
+
+    setup_compile_cache()
     import jax.numpy as jnp
     import numpy as np
     import optax

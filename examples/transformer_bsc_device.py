@@ -96,6 +96,10 @@ def main():
         import jax
 
         jax.config.update("jax_platforms", "cpu")
+
+    from geomx_tpu.runtime import setup_compile_cache
+
+    setup_compile_cache()
     import numpy as np
 
     import geomx_tpu as gx

@@ -276,10 +276,10 @@ class Config:
     # slice budget (loopback BDPs would shrink chunking pointlessly)
     ctrl_rtt_floor_ms: float = 1.0      # GEOMX_CTRL_RTT_FLOOR_MS
     verbose: int = 0                    # PS_VERBOSE
-    # round-4 verdict item 2: the reference makes its transport deadlines
-    # env-tunable (van.cc:527-533 PS_RESEND_TIMEOUT / heartbeat envs);
-    # our barrier and per-op deadlines were constants, and a 59M-param
-    # bootstrap over a ~5 MB/s tunnel blows a hard-coded 600 s barrier
+    # env-tunable like the reference's transport deadlines (van.cc:527-533
+    # PS_RESEND_TIMEOUT / heartbeat envs). They bound one barrier or one
+    # op, not a job: the scheduler's exit round waits for the job
+    # (kvstore_server._run_scheduler, simulate.InProcessHiPS._run_sched)
     barrier_timeout_s: float = 600.0    # PS_BARRIER_TIMEOUT
     op_timeout_s: float = 300.0         # PS_OP_TIMEOUT (push/pull/wait)
 
@@ -344,7 +344,6 @@ class Config:
 
     # ---- TPU-specific ----
     van_type: str = "auto"              # GEOMX_VAN in {auto, python, native}
-    platform: str = ""                  # GEOMX_PLATFORM override for jax
 
     @property
     def is_worker(self) -> bool:
@@ -468,5 +467,4 @@ def load() -> Config:
         wire_codec_wan=env_str("GEOMX_WIRE_CODEC_WAN"),
         wire_2bit_threshold=env_float("GEOMX_WIRE_2BIT_THRESHOLD", 0.5),
         van_type=env_str("GEOMX_VAN", "auto"),
-        platform=env_str("GEOMX_PLATFORM"),
     )

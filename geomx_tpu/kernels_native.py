@@ -7,7 +7,7 @@ GIL for these op sizes, so the per-key-locked server still serializes on
 math; ctypes releases the GIL for the call's duration, restoring thread
 scaling (tools/server_bench.py shows the difference).
 
-Same build-on-demand pattern as ps/native.py (g++, atomic rename).
+Same build-on-demand as ps/native.py (native_lib.ensure_built).
 Disable with GEOMX_NATIVE_KERNELS=0; everything falls back to numpy.
 """
 
@@ -22,12 +22,9 @@ from typing import Optional
 
 import numpy as np
 
-log = logging.getLogger("geomx.kernels")
+from geomx_tpu.native_lib import ensure_built
 
-_NATIVE_DIR = os.path.join(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))), "native")
-_LIB_PATH = os.path.join(_NATIVE_DIR, "libgeomx_kernels.so")
-_SRC_PATH = os.path.join(_NATIVE_DIR, "kernels.cc")
+log = logging.getLogger("geomx.kernels")
 
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
@@ -40,18 +37,6 @@ def enabled() -> bool:
     return os.environ.get("GEOMX_NATIVE_KERNELS", "1") not in ("0", "false")
 
 
-def _build() -> None:
-    tmp = f"{_LIB_PATH}.{os.getpid()}.tmp"
-    cmd = ["g++", "-O3", "-std=c++17", "-fPIC", "-Wall", "-shared",
-           "-o", tmp, _SRC_PATH]
-    try:
-        subprocess.run(cmd, check=True, capture_output=True, timeout=120)
-        os.replace(tmp, _LIB_PATH)
-    finally:
-        if os.path.exists(tmp):
-            os.unlink(tmp)
-
-
 def lib() -> Optional[ctypes.CDLL]:
     global _lib, _failed
     if _lib is not None:
@@ -62,11 +47,7 @@ def lib() -> Optional[ctypes.CDLL]:
         if _lib is not None or _failed:
             return _lib
         try:
-            if not os.path.exists(_LIB_PATH) or (
-                    os.path.exists(_SRC_PATH) and os.path.getmtime(_SRC_PATH)
-                    > os.path.getmtime(_LIB_PATH)):
-                _build()
-            L = ctypes.CDLL(_LIB_PATH)
+            L = ctypes.CDLL(ensure_built("kernels", ["-O3"]))
         except (OSError, subprocess.SubprocessError) as e:
             _failed = True
             log.warning("native kernels unavailable (%s); using numpy", e)

@@ -70,12 +70,8 @@ def maybe_init_multihost(cfg) -> bool:
         return False
     import jax
 
-    try:  # jax<0.5 keeps the handle under jax._src only
-        state = jax.distributed.global_state
-    except AttributeError:
-        from jax._src.distributed import global_state as state
-    if getattr(state, "client", None) is not None:
-        return True   # already initialized (idempotent re-entry)
+    if jax.distributed.is_initialized():
+        return True   # idempotent re-entry
     jax.distributed.initialize(
         coordinator_address=cfg.mesh_coordinator,
         num_processes=cfg.mesh_num_processes,

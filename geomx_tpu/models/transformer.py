@@ -25,9 +25,9 @@ def make_attention(impl: str = "auto", *, causal: bool = True,
     ``"flash"`` — the Pallas FlashAttention-2 kernels
     (geomx_tpu.ops.flash_attention): O(block^2) on-chip memory,
     MXU-tiled, the choice for long sequences on TPU. ``"dense"`` — the
-    XLA einsum reference. ``"auto"`` picks flash on TPU backends and
-    dense elsewhere (on CPU the Pallas kernels run interpreted, which
-    is test-grade, not perf-grade).
+    XLA einsum reference. ``"auto"`` picks flash exactly where the
+    kernels compile (``ops.pallas_interpret()`` false, i.e. a TPU
+    backend) and dense where they would only be interpreted.
 
     A Pallas kernel has no SPMD partitioning rule, so on a multi-device
     ``mesh`` the flash path must run under shard_map; attention is
@@ -36,7 +36,9 @@ def make_attention(impl: str = "auto", *, causal: bool = True,
     attention — ``parallel.make_ring_attention`` — not this hook.)
     """
     if impl == "auto":
-        impl = "flash" if jax.default_backend() == "tpu" else "dense"
+        from geomx_tpu.ops import pallas_interpret
+
+        impl = "dense" if pallas_interpret() else "flash"
     if impl == "flash":
         from geomx_tpu.ops.flash_attention import (
             flash_attention, make_sharded_flash_attention)

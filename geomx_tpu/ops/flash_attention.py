@@ -24,8 +24,9 @@ The logsumexp rides through the kernels as ``[B, H, T, 1]`` — TPU block
 shapes must keep their last two dims (8, 128)-aligned or equal to the
 full array dims, which a trailing singleton satisfies for vectors.
 
-On non-TPU backends the kernels run in Pallas interpret mode, which is
-what the CPU test suite exercises against the dense reference.
+Interpret mode is chosen by ``ops.pallas_interpret()`` alone: compiled on
+a TPU backend, interpreted elsewhere — which is what the CPU test suite
+exercises against the dense reference.
 """
 
 from __future__ import annotations
@@ -288,6 +289,8 @@ def flash_attention(q, k, v, *, causal: bool = True, block_q: int = 128,
     import jax
     import jax.numpy as jnp
 
+    from geomx_tpu.ops import pallas_interpret
+
     if q.ndim != 4:
         raise ValueError(f"expected [B, T, H, D] tensors, got {q.shape}")
     Tq, Tk = q.shape[1], k.shape[1]
@@ -298,7 +301,7 @@ def flash_attention(q, k, v, *, causal: bool = True, block_q: int = 128,
         raise ValueError(
             f"causal attention needs Tq <= Tk, got Tq={Tq} > Tk={Tk}")
     bq, bk = min(block_q, _round_up(Tq, 8)), min(block_k, _round_up(Tk, 8))
-    interpret = jax.default_backend() != "tpu"
+    interpret = pallas_interpret()
 
     @jax.custom_vjp
     def _attn(q, k, v):
@@ -357,9 +360,8 @@ def make_sharded_flash_attention(mesh, *, causal: bool = True,
     independent per batch ("dp") and head ("tp"); sequence-sharded
     meshes ("sp" > 1) need ring attention instead and are rejected.
     """
+    import jax
     from jax.sharding import PartitionSpec as P
-
-    from geomx_tpu.compat import shard_map
 
     if "sp" in mesh.axis_names and mesh.shape["sp"] > 1:
         raise ValueError(
@@ -371,6 +373,6 @@ def make_sharded_flash_attention(mesh, *, causal: bool = True,
              "tp" if "tp" in mesh.axis_names else None, None)
     # check_vma=False: pallas_call outputs carry no varying-mesh-axes
     # annotation, and the kernel touches no collectives
-    return shard_map(lambda q, k, v: fn(q, k, v), mesh=mesh,
-                     in_specs=(spec, spec, spec), out_specs=spec,
-                     check_vma=False)
+    return jax.shard_map(lambda q, k, v: fn(q, k, v), mesh=mesh,
+                         in_specs=(spec, spec, spec), out_specs=spec,
+                         check_vma=False)

@@ -26,8 +26,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from geomx_tpu.compat import shard_map
-
 __all__ = ["pipeline_spmd", "make_pipeline_fn"]
 
 
@@ -88,7 +86,7 @@ def make_pipeline_fn(mesh: Mesh, stage_fn: Callable, *,
         param_specs = jax.tree_util.tree_map(
             lambda p: P(*([axis_name] + [None] * (p.ndim - 1))),
             stacked_params)
-        return shard_map(
+        return jax.shard_map(
             body, mesh=mesh,
             in_specs=(param_specs, in_spec),
             out_specs=in_spec, check_vma=False,

@@ -38,7 +38,6 @@ from typing import Optional, Tuple
 
 import numpy as np
 
-from geomx_tpu.compat import shard_map
 from geomx_tpu.parallel.mesh import P, ring_chunk_layout, ring_perm
 
 __all__ = ["RING_SLOTS", "ring_all_reduce", "residual_slots",
@@ -85,13 +84,11 @@ class _HopCodec:
     inside shard_map.
     """
 
-    def __init__(self, codec: str, m: int, block: int, threshold: float,
-                 use_pallas: bool = False):
+    def __init__(self, codec: str, m: int, block: int, threshold: float):
         self.codec = codec
         self.m = int(m)
         self.block = max(1, int(block))
         self.threshold = float(threshold)
-        self.use_pallas = bool(use_pallas)
 
     def quantize(self, partial, res_slot):
         jnp = _jax().numpy
@@ -99,8 +96,7 @@ class _HopCodec:
             from geomx_tpu import ops
 
             packed, new_res = ops.two_bit_quantize(
-                partial, res_slot, self.threshold,
-                use_pallas=self.use_pallas)
+                partial, res_slot, self.threshold)
             return (packed,), self.dequantize((packed,)), new_res
         e = partial + res_slot
         if self.codec == "int8":
@@ -130,8 +126,7 @@ class _HopCodec:
 
 def ring_all_reduce(x, residual, *, size: int, axis_name: str = "dp",
                     codec: str = "int8", block: int = 256,
-                    threshold: float = 0.5, use_pallas: bool = False
-                    ) -> Tuple:
+                    threshold: float = 0.5) -> Tuple:
     """Quantized ring all-reduce of this rank's flat f32 vector ``x``.
 
     Call INSIDE shard_map over ``axis_name`` (``size`` ranks). Every
@@ -150,7 +145,7 @@ def ring_all_reduce(x, residual, *, size: int, axis_name: str = "dp",
     size = int(size)
     n = int(x.size)
     m, padded = ring_chunk_layout(n, size, _codec_multiple(codec, block))
-    hop = _HopCodec(codec, m, block, threshold, use_pallas)
+    hop = _HopCodec(codec, m, block, threshold)
     perm = ring_perm(size)
 
     xp = jnp.zeros(padded, jnp.float32).at[:n].set(
@@ -211,8 +206,7 @@ def zero_residual(size: int, n: int, codec: str, block: int = 256):
 
 def make_quant_all_reduce(mesh, codec: str, n: int, *,
                           axis_name: str = "dp", block: int = 256,
-                          threshold: float = 0.5, mean: bool = False,
-                          use_pallas: bool = False):
+                          threshold: float = 0.5, mean: bool = False):
     """Jitted standalone quantized all-reduce over ``mesh``.
 
     Returns ``fn(x_stacked, residual) -> (reduced, new_residual)``:
@@ -231,20 +225,21 @@ def make_quant_all_reduce(mesh, codec: str, n: int, *,
             y = jax.lax.psum(xs[0], axis_name)
             return (y / size if mean else y), res
 
-        fn = shard_map(body, mesh=mesh,
-                       in_specs=(P(axis_name), P(axis_name)),
-                       out_specs=(P(), P(axis_name)), check_vma=False)
+        fn = jax.shard_map(body, mesh=mesh,
+                           in_specs=(P(axis_name), P(axis_name)),
+                           out_specs=(P(), P(axis_name)),
+                           check_vma=False)
         return jax.jit(fn)
 
     def body(xs, res):
         y, new_res = ring_all_reduce(
             xs[0], res[0], size=size, axis_name=axis_name, codec=codec,
-            block=block, threshold=threshold, use_pallas=use_pallas)
+            block=block, threshold=threshold)
         return (y / size if mean else y), new_res[None]
 
-    fn = shard_map(body, mesh=mesh,
-                   in_specs=(P(axis_name), P(axis_name)),
-                   out_specs=(P(), P(axis_name)), check_vma=False)
+    fn = jax.shard_map(body, mesh=mesh,
+                       in_specs=(P(axis_name), P(axis_name)),
+                       out_specs=(P(), P(axis_name)), check_vma=False)
     return jax.jit(fn)
 
 
@@ -257,8 +252,7 @@ class QuantRingReducer:
 
     def __init__(self, mesh, codec: str, n: int, *,
                  axis_name: str = "dp", block: int = 256,
-                 threshold: float = 0.5, mean: bool = False,
-                 use_pallas: bool = False):
+                 threshold: float = 0.5, mean: bool = False):
         dev = _device()
         if codec not in dev.MESH_CODECS:
             raise ValueError(
@@ -273,7 +267,7 @@ class QuantRingReducer:
         self._axis = axis_name
         self._fn = make_quant_all_reduce(
             mesh, codec, self.n, axis_name=axis_name, block=block,
-            threshold=threshold, mean=mean, use_pallas=use_pallas)
+            threshold=threshold, mean=mean)
         self._res = self._zero()
 
     def _zero(self):

@@ -19,8 +19,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from geomx_tpu.compat import shard_map
-
 NEG_INF = -1e30
 
 
@@ -103,7 +101,7 @@ def make_ring_attention(mesh: Mesh, *, causal: bool = False,
     """
     spec = q_spec or P("dp", "sp", "tp", None)
     fn = functools.partial(ring_attention, causal=causal)
-    return shard_map(
+    return jax.shard_map(
         fn, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
         check_vma=False,
     )

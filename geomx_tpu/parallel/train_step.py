@@ -61,14 +61,12 @@ class DataParallelTrainer:
         # replaces XLA's inserted collective with an explicit one, so it
         # needs each rank's un-reduced contribution, stacked on a
         # leading "dp" axis the ring's shard_map then consumes
-        from geomx_tpu.compat import shard_map
-
         def _local(p, X, y):
             loss, grads = jax.value_and_grad(loss_fn)(p, X, y)
             return (loss[None],
                     jax.tree_util.tree_map(lambda g: g[None], grads))
 
-        self._local_grad_step = jax.jit(shard_map(
+        self._local_grad_step = jax.jit(jax.shard_map(
             _local, mesh=mesh, in_specs=(P(), P("dp"), P("dp")),
             out_specs=(P("dp"), P("dp")), check_vma=False))
 

@@ -11,7 +11,7 @@ Python (van.py). Both backends speak the identical wire format
 Selection: ``GEOMX_NATIVE_VAN=1`` (default when the library is buildable)
 / ``GEOMX_NATIVE_VAN=0`` forces pure Python. The shared library is built
 on demand with g++ the first time it is needed and cached next to the
-source.
+source under a name that carries the source's hash (native_lib.py).
 """
 
 from __future__ import annotations
@@ -23,32 +23,13 @@ import subprocess
 import threading
 from typing import Optional
 
-log = logging.getLogger("geomx.native")
+from geomx_tpu.native_lib import ensure_built
 
-_NATIVE_DIR = os.path.join(os.path.dirname(os.path.dirname(
-    os.path.dirname(os.path.abspath(__file__)))), "native")
-_LIB_PATH = os.path.join(_NATIVE_DIR, "libgeomx_transport.so")
-_SRC_PATH = os.path.join(_NATIVE_DIR, "transport.cc")
+log = logging.getLogger("geomx.native")
 
 _lib_lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
 _lib_error: Optional[str] = None
-
-
-def _build_library() -> None:
-    # build to a process-unique temp path, then atomically rename: several
-    # processes (scheduler/servers/workers on one host) may race through a
-    # fresh checkout's first build, and interleaved writes to one output
-    # path would leave a permanently corrupt .so
-    tmp = f"{_LIB_PATH}.{os.getpid()}.tmp"
-    cmd = ["g++", "-O2", "-std=c++17", "-fPIC", "-Wall", "-pthread",
-           "-shared", "-o", tmp, _SRC_PATH]
-    try:
-        subprocess.run(cmd, check=True, capture_output=True, timeout=120)
-        os.replace(tmp, _LIB_PATH)
-    finally:
-        if os.path.exists(tmp):
-            os.unlink(tmp)
 
 
 def load_library() -> Optional[ctypes.CDLL]:
@@ -62,11 +43,7 @@ def load_library() -> Optional[ctypes.CDLL]:
         if _lib is not None or _lib_error is not None:
             return _lib
         try:
-            if not os.path.exists(_LIB_PATH) or (
-                    os.path.exists(_SRC_PATH)
-                    and os.path.getmtime(_SRC_PATH) > os.path.getmtime(_LIB_PATH)):
-                _build_library()
-            lib = ctypes.CDLL(_LIB_PATH)
+            lib = ctypes.CDLL(ensure_built("transport", ["-O2", "-pthread"]))
         except (OSError, subprocess.SubprocessError) as e:
             _lib_error = str(e)
             log.warning("native transport unavailable (%s); "

@@ -361,6 +361,13 @@ class Van:
                     pass
             self._conns.clear()
 
+    @property
+    def backend(self) -> str:
+        """The socket layer this van bound — ``"native"`` (C++ core) or
+        ``"python"``. The choice moves host protocol cost, so every
+        smoke and bench result names it. Valid once started."""
+        return "native" if self._native is not None else "python"
+
     def _bind(self) -> None:
         port = self.root_port if self.is_scheduler else 0
         if self.use_native:

@@ -6,9 +6,10 @@ docs/source/pseudo-distributed-deployment.rst, scripts/cpu/*.sh). Because
 our Postoffice/Van are instance-scoped (no process-global singletons,
 unlike ps-lite), a whole multi-party HiPS cluster can also run inside ONE
 process on threads — every protocol byte still crosses real loopback
-sockets through the real transport. Used by bench.py (infra roles on CPU
-threads, worker compute on the accelerator) and available to users for
-experimentation without launch scripts.
+sockets through the real transport. This is the on-chip topology: a chip
+belongs to one process, so chip_smoke.py and bench.py run every role here
+(infra roles on host threads, worker compute on the accelerator); the
+multi-process launch scripts are CPU protocol demos.
 """
 
 from __future__ import annotations
@@ -138,7 +139,12 @@ class InProcessHiPS:
         )
         po.start(60.0)
         po.barrier(psbase.ALL_GROUP, timeout=120.0)    # startup round
-        po.barrier(psbase.ALL_GROUP, timeout=600.0)    # exit round
+        # exit round: a passive wait that ends when every member
+        # finalizes, exactly like kvstore_server._run_scheduler. It is
+        # NOT a deadline on the job — a 600 s cap here killed any
+        # topology that lived longer (a 59M bootstrap plus its compiles
+        # did: "barrier on group 7 timed out"). Callers bound the run.
+        po.barrier(psbase.ALL_GROUP, timeout=24 * 3600.0)
         po.van.stop()
 
     def start(self, sync_global: Optional[bool] = None) -> "InProcessHiPS":
@@ -273,6 +279,16 @@ class InProcessHiPS:
         if hung:
             raise TimeoutError(
                 f"{hung} worker(s) still running after {timeout}s")
+
+    def van_backends(self) -> List[str]:
+        """Distinct socket layers (``Van.backend``) the kv nodes bound;
+        one entry unless the native core failed for some of them."""
+        vans = [getattr(kv, "inner", kv).po.van
+                for kv in [*self.workers, self.master]]
+        for srv in self.servers:
+            vans += [po.van for po in (srv.po_local, srv.po_global)
+                     if po is not None]
+        return sorted({v.backend for v in vans})
 
     def stop(self) -> None:
         closers = [w for w in self.workers]

@@ -309,7 +309,6 @@ class DeviceResidentTrainer:
         if self._mesh_quant:
             from jax.sharding import NamedSharding
 
-            from geomx_tpu.compat import shard_map
             from geomx_tpu.parallel import quant_collectives as qc
             from geomx_tpu.parallel.mesh import P as _P
 
@@ -340,7 +339,7 @@ class DeviceResidentTrainer:
                 loss = jax.lax.psum(loss, "dp") / psize
                 return loss, gs / psize, new_res[None]
 
-            mesh_grad = shard_map(
+            mesh_grad = jax.shard_map(
                 _mesh_grad_body, mesh=self._mesh,
                 in_specs=(_P(), _P("dp"), _P("dp"), _P("dp")),
                 out_specs=(_P(), _P(), _P("dp")), check_vma=False)
@@ -441,35 +440,34 @@ class DeviceResidentTrainer:
             self.kv.count_collective(self.total * 4)
 
     def warmup(self, X, y) -> None:
-        """Trace+compile both device steps WITHOUT running a kv round
-        (results discarded, trainer state untouched) — lets callers
-        serialize expensive first compiles without holding up the FSA
-        barrier."""
+        """Trace+compile the device programs :meth:`step` will run
+        WITHOUT running a kv round (results discarded, trainer state
+        untouched) — lets callers serialize expensive first compiles
+        without holding up the FSA barrier. Only the programs of the
+        active path: the pipelined round never runs the monolithic pair
+        (nor the reverse), and at 59M parameters each forward program is
+        about a minute of cold compile on a v5e."""
         import jax
 
         X, y = self._place_batch(X, y)
+        args = (self._flat, self._u, self._v, X, y)
         if self._mesh_quant:
-            packed, _u, _v, _res = self._fwd_compress_q(
-                self._flat, self._u, self._v, X, y, self._mesh_res)
-        else:
-            packed, _u, _v = self._fwd_compress(self._flat, self._u,
-                                                self._v, X, y)
-        up = jax.device_put(np.zeros(2 * self._up_cap, np.int32))
-        flat2, _mom2 = self._apply(self._flat, self._mom, up)
-        fence = [packed, flat2]
+            args += (self._mesh_res,)
         if self._pipeline:
-            if self._mesh_quant:
-                loss_d, packs, _u2, _v2, _res2 = self._fwd_chunks_q(
-                    self._flat, self._u, self._v, X, y, self._mesh_res)
-            else:
-                loss_d, packs, _u2, _v2 = self._fwd_chunks(
-                    self._flat, self._u, self._v, X, y)
-            fence.extend([loss_d, *packs])
+            fwd = (self._fwd_chunks_q if self._mesh_quant
+                   else self._fwd_chunks)
+            loss_d, packs = fwd(*args)[:2]
+            fence = [loss_d, *packs]
             for _lo, _hi, flo, fsize, cap in self._chunk_meta:
-                up0 = jax.device_put(np.zeros(2 * cap, np.int32))
-                f2, _m2 = self._apply_chunk(self._flat, self._mom,
-                                            up0, flo, fsize)
-                fence.append(f2)
+                up = jax.device_put(np.zeros(2 * cap, np.int32))
+                fence.append(self._apply_chunk(self._flat, self._mom,
+                                               up, flo, fsize)[0])
+        else:
+            fwd = (self._fwd_compress_q if self._mesh_quant
+                   else self._fwd_compress)
+            up = jax.device_put(np.zeros(2 * self._up_cap, np.int32))
+            fence = [fwd(*args)[0],
+                     self._apply(self._flat, self._mom, up)[0]]
         jax.block_until_ready(fence)
 
     # -- one round -------------------------------------------------------

@@ -5,7 +5,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from geomx_tpu.compat import shard_map
 from geomx_tpu.parallel.grad_accum import accumulate_gradients
 
 
@@ -58,7 +57,7 @@ def test_accum_with_mesh_pmean():
     from jax.sharding import PartitionSpec as P
 
     inner = accumulate_gradients(grad_fn, 2, axis_name="dp")
-    fn = jax.jit(shard_map(
+    fn = jax.jit(jax.shard_map(
         inner, mesh=mesh,
         in_specs=(P(), P("dp"), P("dp")), out_specs=(P(), P()),
         check_vma=False))

@@ -204,7 +204,6 @@ def test_none_codec_is_psum_bitwise():
     import jax.numpy as jnp
     from jax.sharding import NamedSharding
 
-    from geomx_tpu.compat import shard_map
     from geomx_tpu.parallel.mesh import P as Spec
 
     P_, n = 4, 333
@@ -214,7 +213,7 @@ def test_none_codec_is_psum_bitwise():
     res0 = np.asarray(red._res).copy()
     got = np.asarray(red.reduce(xs))
 
-    ref_fn = jax.jit(shard_map(
+    ref_fn = jax.jit(jax.shard_map(
         lambda v: jax.lax.psum(v[0], "dp"), mesh=mesh,
         in_specs=(Spec("dp"),), out_specs=Spec(), check_vma=False))
     ref = np.asarray(ref_fn(jax.device_put(

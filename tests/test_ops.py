@@ -69,8 +69,7 @@ def test_bsc_pull_compress_captures_all_nonzeros():
 
 
 @pytest.mark.parametrize("n", [64, 1001])
-@pytest.mark.parametrize("use_pallas", [False, True])
-def test_two_bit_matches_host_kernel(n, use_pallas):
+def test_two_bit_matches_host_kernel(n):
     rng = np.random.default_rng(4)
     grad = rng.normal(size=n).astype(np.float32)
     residual = rng.normal(scale=0.3, size=n).astype(np.float32)
@@ -78,8 +77,7 @@ def test_two_bit_matches_host_kernel(n, use_pallas):
 
     res_host = residual.copy()
     packed_host = host.two_bit_quantize(grad, res_host, thr)
-    packed_dev, res_dev = ops.two_bit_quantize(grad, residual, thr,
-                                               use_pallas=use_pallas)
+    packed_dev, res_dev = ops.two_bit_quantize(grad, residual, thr)
     np.testing.assert_array_equal(np.asarray(packed_dev), packed_host)
     np.testing.assert_allclose(np.asarray(res_dev), res_host, rtol=1e-5, atol=1e-6)
 

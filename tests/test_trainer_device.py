@@ -138,8 +138,8 @@ def test_packed_wire_is_int32_and_index_exact():
     an INT32 array (floats bitcast int-wards), never float32 with
     indices bitcast float-wards. Indices < 2^23 bitcast to float32 are
     denormals, and TPU float data movement inside jit flushes denormals
-    to zero — on the r04 capture every index collapsed to 0 and headline
-    accuracy fell to chance (BENCH_r04.json hips_bsc_cnn 0.0967).
+    to zero — on the first real-TPU capture every index collapsed to 0
+    and headline accuracy fell to chance (0.0967).
     CPU can't reproduce the flush, so this asserts the wire CONTRACT:
     dtype int32 end-to-end and bit-exact recovery of small indices."""
     from geomx_tpu.kvstore import create as kv_create

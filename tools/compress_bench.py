@@ -15,7 +15,7 @@ machine-readable document instead.
 
 Usage: python tools/compress_bench.py [--sizes 262144,1048576,8388608]
                                       [--json]
-       GEOMX_BENCH_PLATFORM=cpu to force the device path onto CPU.
+The device half runs on whatever backend jax picked; every output names it.
 """
 
 from __future__ import annotations
@@ -127,14 +127,11 @@ def main():
                          "lines (machine-readable; what bench.py embeds)")
     args = ap.parse_args()
 
-    plat = os.environ.get("GEOMX_BENCH_PLATFORM")
-    if plat:
-        import jax
-
-        jax.config.update("jax_platforms", plat)
-
     import jax
 
+    from geomx_tpu.runtime import setup_compile_cache
+
+    setup_compile_cache()
     sizes = [int(s) for s in args.sizes.split(",")]
     results = run_compress_bench(sizes, args.threshold)
     if args.json:

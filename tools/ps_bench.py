@@ -4,7 +4,7 @@ throughput, NO accelerator in the loop.
 
 Measures full two-tier rounds (2 parties x 1 worker -> party servers ->
 global server -> pull-back) for numpy payloads of several sizes. This
-isolates the framework's own speed from device/tunnel effects — the
+isolates the framework's own speed from device effects — the
 complement of bench.py's framework-in-the-loop numbers.
 
 Prints one JSON line per payload size:
