@@ -28,6 +28,7 @@ per-device arrays to ``push`` and they are summed on host as a fallback.
 
 from __future__ import annotations
 
+import atexit
 import dataclasses
 import logging
 import pickle
@@ -176,8 +177,6 @@ class KVStoreDist(KVStore):
                 self._send_command(Command.SYNC_GLOBAL_MODE,
                                    "1" if sync_global else "0")
         self._closed = False
-        import atexit
-
         atexit.register(self.close)
 
     # -- identity --------------------------------------------------------
@@ -1938,6 +1937,9 @@ class KVStoreDist(KVStore):
         if getattr(self, "_closed", False):
             return
         self._closed = True
+        # the exit hook has done its job: left registered it pins this
+        # store, its van and its flight-recorder ring until exit
+        atexit.unregister(self.close)
         # a crashed (stopped) van can neither flush pending ops nor
         # reach the scheduler: skip the goodbye protocol entirely
         # instead of serially bleeding through the op, command and

@@ -48,6 +48,21 @@ from geomx_tpu.ps.message import (Control, Message, Meta, Node, Role,
 log = logging.getLogger("geomx.van")
 
 
+def _shutdown_and_close(sock: socket.socket) -> None:
+    """Close a socket another thread may be blocked on. On Linux close()
+    alone wakes neither accept() nor recvfrom(), and the port stays
+    bound until that call returns; shutdown() wakes both (it reports
+    ENOTCONN on a listener or an unconnected UDP socket, and works)."""
+    try:
+        sock.shutdown(socket.SHUT_RDWR)
+    except OSError:
+        pass
+    try:
+        sock.close()
+    except OSError:
+        pass
+
+
 @locks.guarded_by("_member_lock", "my_id", "is_recovery",
                   "membership_epoch", "_declared_dead", "_rejoin_epoch")
 @locks.guarded_by("_stats_lock", "send_bytes", "recv_bytes",
@@ -337,10 +352,7 @@ class Van:
         if self._dgt_queues is not None:
             self._dgt_queues.stop()
         for s in self._udp_socks:
-            try:
-                s.close()
-            except OSError:
-                pass
+            _shutdown_and_close(s)
         if self._udp_send_sock is not None:
             try:
                 self._udp_send_sock.close()
@@ -349,10 +361,7 @@ class Van:
         if self._native is not None:
             self._native.stop()
         if self._listener is not None:
-            try:
-                self._listener.close()
-            except OSError:
-                pass
+            _shutdown_and_close(self._listener)
         with self._conn_lock:
             for sock, _ in self._conns.values():
                 try:

@@ -138,9 +138,7 @@ def test_trainer_local_sgd_step(tmp_path):
 def test_dist_optimizer_states_roundtrip(tmp_path):
     """In HiPS the live optimizer states sit on the global server; the
     master worker's save must fetch them over the command channel."""
-    import sys, os
-    sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
-    from test_hips import Topology, _parallel
+    from tests.harness import Topology, _parallel
 
     topo = Topology().start(sync_global=True)
     fname = str(tmp_path / "dist.states")

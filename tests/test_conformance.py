@@ -32,6 +32,8 @@ import pytest
 
 from geomx_tpu import checkpoint
 from geomx_tpu.ps.conformance import MARKER, StateSanitizer
+from tests.harness import (
+    SingleTier, _kill, _parallel, _round, _wait_declared)
 
 assert MARKER  # the grep target scripts/run_chaos_matrix.sh fails on
 
@@ -278,17 +280,12 @@ def test_membership_churn_with_state_sanitizer_clean(tmp_path, caplog):
     live view. Every van runs the conformance sanitizer; the run must
     end with zero violations, and the flight-recorder dumps must replay
     clean through the offline checker."""
-    from tests.test_hips import _parallel
-    from tests.test_membership import _kill, _wait_declared
-    from tests.test_recovery import SingleTier, _round
     from geomx_tpu.optimizer import SGD
     from tools.modelcheck import replay_paths
 
-    topo = SingleTier(extra={"state_sanitizer": True,
-                             "flightrec_dir": str(tmp_path)}).start()
     w0 = np.full(8, 10.0, np.float32)
-    vans = []
-    try:
+    with SingleTier(extra={"state_sanitizer": True,
+                           "flightrec_dir": str(tmp_path)}) as topo:
         rank0 = next(kv for kv in topo.workers if kv.rank == 0)
         zombie = next(kv for kv in topo.workers if kv.rank == 1)
         rank0.set_optimizer(SGD(learning_rate=1.0))
@@ -315,14 +312,7 @@ def test_membership_churn_with_state_sanitizer_clean(tmp_path, caplog):
         for v in vans:
             v.flightrec.dump("test-conformance")
 
-        topo.workers = [rank0]
         _kill(zombie)
-    finally:
-        _parallel([kv.close for kv in topo.workers])
-        for t in topo.threads:
-            t.join(30)
-        if topo.errors:
-            raise topo.errors[0]
 
     for v in vans:
         assert v.statecheck.violations == [], (

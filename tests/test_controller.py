@@ -26,7 +26,7 @@ from geomx_tpu.ps.tsengine import TSScheduler
 from geomx_tpu.simulate import InProcessHiPS
 from tools import geomx_top
 
-from tests.test_hips import _parallel
+from tests.harness import _parallel
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 SHAPE_PLAN = os.path.join(REPO, "scripts", "shapes",

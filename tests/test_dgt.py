@@ -155,7 +155,7 @@ def test_hips_training_with_dgt(mode):
     are lossless on loopback (UDP rarely drops locally; zero-fill would
     only perturb, not break); mode 3 quantizes unimportant blocks, so we
     assert approximate convergence of the stored weights."""
-    from tests.test_hips import Topology, _parallel
+    from tests.harness import Topology, _parallel
     from geomx_tpu.optimizer import SGD
 
     topo = Topology()

@@ -17,8 +17,7 @@ from geomx_tpu.ps import flightrec
 from geomx_tpu.ps.flightrec import FlightRecorder, default_dir
 from tools import flight_report
 
-from tests.test_hips import _parallel
-from tests.test_recovery import SingleTier
+from tests.harness import SingleTier, _parallel
 
 
 # ---------------------------------------------------------------------------
@@ -216,10 +215,9 @@ def test_faultplan_crash_dumps_in_flight_round(tmp_path):
     plan = json.dumps({"rules": [{
         "type": "crash", "node": victim_id, "at_round": 2,
         "tier": "local"}]})
-    topo = SingleTier(extra={"fault_plan": plan, "ps_seed": 11,
-                             "flightrec_dir": str(tmp_path)}).start()
     w0 = np.zeros(8, np.float32)
-    try:
+    with SingleTier(extra={"fault_plan": plan, "ps_seed": 11,
+                           "flightrec_dir": str(tmp_path)}) as topo:
         workers = sorted(topo.workers, key=lambda kv: kv.rank)
         rank0, victim = workers
         rank0.set_optimizer(SGD(learning_rate=1.0))
@@ -229,11 +227,10 @@ def test_faultplan_crash_dumps_in_flight_round(tmp_path):
         # stamped frames in the victim's ring
         def step(kv):
             kv.push_pull(0, np.ones_like(w0), np.zeros_like(w0))
-            kv.wait(timeout=60.0)
+            kv.wait()
 
         _parallel([lambda kv=kv: step(kv) for kv in workers])
 
-        victim._closed = True            # disarm its atexit close
         victim.notify_round(2)           # at_round rule fires here
         assert victim.po.van.stopped.wait(10), "crash rule did not fire"
 
@@ -250,13 +247,6 @@ def test_faultplan_crash_dumps_in_flight_round(tmp_path):
                  and e.get("round", -1) >= 1]
         assert sends, "no round-stamped sends in the crash dump"
         assert any(e["verb"] in ("push", "pull") for e in sends)
-        topo.workers = [rank0]
-    finally:
-        _parallel([kv.close for kv in topo.workers])
-        for t in topo.threads:
-            t.join(30)
-        if topo.errors:
-            raise topo.errors[0]
 
 
 if __name__ == "__main__":

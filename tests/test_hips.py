@@ -13,42 +13,8 @@ import threading
 import numpy as np
 import pytest
 
-from geomx_tpu.config import Config
-from geomx_tpu.kvstore.dist import KVStoreDist
-from geomx_tpu.kvstore.server import KVStoreDistServer
 from geomx_tpu.optimizer import SGD, Adam
-from geomx_tpu.ps import base as psbase
-from geomx_tpu.ps.message import Role
-from geomx_tpu.ps.postoffice import Postoffice
-from geomx_tpu.simulate import InProcessHiPS, free_port  # noqa: F401
-
-
-class Topology(InProcessHiPS):
-    """The product in-process topology (geomx_tpu.simulate.InProcessHiPS)
-    with test-suite defaults: 2 workers per party, like the reference's
-    12-process demo (scripts/cpu/run_vanilla_hips.sh)."""
-
-    def __init__(self, num_parties=2, workers_per_party=2, **kw):
-        super().__init__(num_parties=num_parties,
-                         workers_per_party=workers_per_party, **kw)
-
-
-def _parallel(fns):
-    errs = []
-
-    def wrap(fn):
-        try:
-            fn()
-        except BaseException as e:  # noqa: BLE001
-            errs.append(e)
-
-    ts = [threading.Thread(target=wrap, args=(fn,), daemon=True) for fn in fns]
-    for t in ts:
-        t.start()
-    for t in ts:
-        t.join(120)
-    if errs:
-        raise errs[0]
+from tests.harness import SingleTier, Topology, _parallel
 
 
 def test_hips_fsa_vanilla():
@@ -218,48 +184,8 @@ def test_hips_multi_server_parties():
 def test_single_tier_classic_ps():
     """No global tier: a classic 1-scheduler/1-server/2-worker PS where the
     local server applies the optimizer (stock-MXNet dist behavior)."""
-    port = free_port()
-    threads = []
-    errors = []
-
-    def run(fn):
-        def w():
-            try:
-                fn()
-            except BaseException as e:  # noqa: BLE001
-                errors.append(e)
-        t = threading.Thread(target=w, daemon=True)
-        t.start()
-        threads.append(t)
-
-    sched_po = Postoffice(my_role=Role.SCHEDULER, is_global=False,
-                          root_uri="127.0.0.1", root_port=port,
-                          num_workers=2, num_servers=1, cfg=Config())
-
-    def sched():
-        sched_po.start(60)
-        sched_po.barrier(psbase.ALL_GROUP, timeout=60)
-        sched_po.barrier(psbase.ALL_GROUP, timeout=120)
-        sched_po.van.stop()
-
-    run(sched)
-    scfg = Config(role="server", ps_root_uri="127.0.0.1", ps_root_port=port,
-                  num_workers=2, num_servers=1)
-    srv = KVStoreDistServer(scfg)
-    run(srv.run)
-    boxes = [[], []]
-    for i in range(2):
-        wcfg = Config(role="worker", ps_root_uri="127.0.0.1",
-                      ps_root_port=port, num_workers=2, num_servers=1)
-        run(lambda b=boxes[i], c=wcfg: b.append(KVStoreDist(cfg=c)))
-    for _ in range(300):
-        if errors:
-            raise errors[0]
-        if all(len(b) == 1 for b in boxes):
-            break
-        threading.Event().wait(0.1)
-    kvs = [b[0] for b in boxes]
-    try:
+    with SingleTier() as topo:
+        kvs = topo.workers
         rank0 = next(kv for kv in kvs if kv.rank == 0)
         rank0.set_optimizer(SGD(learning_rate=0.5))
         w0 = np.ones(10, np.float32)
@@ -273,12 +199,6 @@ def test_single_tier_classic_ps():
             np.testing.assert_allclose(out, np.zeros(10))  # 1 - 0.5*2
 
         _parallel([lambda kv=kv: train(kv) for kv in kvs])
-    finally:
-        _parallel([kv.close for kv in kvs])
-        for t in threads:
-            t.join(30)
-        if errors:
-            raise errors[0]
 
 
 if __name__ == "__main__":

@@ -16,7 +16,7 @@ import time
 import numpy as np
 import pytest
 
-from tests.test_hips import Topology, _parallel
+from tests.harness import Topology, _parallel
 from geomx_tpu.optimizer import SGD
 
 
@@ -222,7 +222,7 @@ def test_resend_give_up_surfaces_error():
     from geomx_tpu.ps.kv_app import KVPairs, KVWorker
     from geomx_tpu.ps.message import Role
     from geomx_tpu.ps.postoffice import Postoffice
-    from tests.test_hips import free_port
+    from geomx_tpu.simulate import free_port
 
     port = free_port()
     cfg = Config(resend=True, resend_timeout_ms=20)
@@ -264,7 +264,7 @@ def test_resend_give_up_surfaces_error():
     assert isinstance(ei.value, RuntimeError), \
         f"expected fast RuntimeError from give-up, got {ei.value!r}"
     assert "undeliverable" in str(ei.value)
-    wpo.van.stop()
+    wpo.finalize(do_barrier=False)       # the customer's thread and the van
     for v in vans:
         v.stop()
 

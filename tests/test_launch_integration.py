@@ -14,69 +14,11 @@ regression shipped through this path while every in-process test stayed
 green.
 """
 
-import os
-import re
-import signal
-import subprocess
 import sys
-import time
 
 import pytest
 
-from geomx_tpu.simulate import free_port as _free_port
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
-
-def _run_launch(script: str, extra_args, n_iters: int, timeout: float,
-                expect_lines: int = 0, env_extra=None,
-                pattern: str = r"Test Acc (\d+\.\d+)",
-                pass_max_iters: bool = True):
-    env = dict(os.environ)
-    if env_extra:
-        env.update(env_extra)
-    env.update({
-        "GPORT": str(_free_port()), "CPORT": str(_free_port()),
-        "APORT": str(_free_port()), "BPORT": str(_free_port()),
-        "JAX_PLATFORMS": "cpu",
-        "PYTHON": sys.executable,
-        # don't inherit the conftest's 8-device virtual mesh into 12
-        # separate processes
-        "XLA_FLAGS": "",
-    })
-    argv = ["bash", os.path.join(REPO, "scripts", script)]
-    if pass_max_iters:
-        argv += ["--max-iters", str(n_iters)]
-    proc = subprocess.Popen(
-        [*argv, *extra_args],
-        cwd=REPO, env=env, stdout=subprocess.PIPE, stderr=subprocess.STDOUT,
-        text=True, start_new_session=True,
-    )
-    try:
-        out, _ = proc.communicate(timeout=timeout)
-    except subprocess.TimeoutExpired:
-        os.killpg(proc.pid, signal.SIGKILL)
-        out, _ = proc.communicate()
-        pytest.fail(f"launch timed out; output:\n{out[-4000:]}")
-
-    assert proc.returncode == 0, f"launch failed:\n{out[-4000:]}"
-    accs = [float(m) for m in re.findall(pattern, out)]
-    expect = expect_lines or n_iters
-    assert len(accs) == expect, \
-        f"expected {expect} iteration lines, got:\n{out[-4000:]}"
-
-    # clean exits: every background process of the group must terminate
-    deadline = time.monotonic() + 60
-    while time.monotonic() < deadline:
-        try:
-            os.killpg(proc.pid, 0)
-        except ProcessLookupError:
-            break  # whole group gone
-        time.sleep(0.5)
-    else:
-        os.killpg(proc.pid, signal.SIGKILL)
-        pytest.fail("background topology processes did not exit cleanly")
-    return accs
+from tests.harness import _run_launch
 
 
 @pytest.mark.slow

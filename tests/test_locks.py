@@ -327,13 +327,19 @@ def test_off_path_overhead_under_five_percent():
     # hands back the raw class itself — not a delegating wrapper
     assert type(lk) is type(raw)
 
+    # both are the same class, so a gap is scheduler noise: on a box
+    # that runs other tests beside this one a single best-of-9 misses
+    # 5% now and then, and a real wrapper would miss it every time
     n, reps = 50_000, 9
-    t_factory = min(timeit.repeat("lk.acquire(); lk.release()",
-                                  globals={"lk": lk},
+    for _ in range(5):
+        t_factory = min(timeit.repeat("lk.acquire(); lk.release()",
+                                      globals={"lk": lk},
+                                      number=n, repeat=reps))
+        t_raw = min(timeit.repeat("lk.acquire(); lk.release()",
+                                  globals={"lk": raw},
                                   number=n, repeat=reps))
-    t_raw = min(timeit.repeat("lk.acquire(); lk.release()",
-                              globals={"lk": raw},
-                              number=n, repeat=reps))
+        if t_factory <= t_raw * 1.05:
+            break
     assert t_factory <= t_raw * 1.05, (
         f"off-path factory lock {t_factory:.4f}s vs raw {t_raw:.4f}s "
         f"(> 5% overhead)")

@@ -11,7 +11,6 @@ declared-dead (but still running) zombies are fenced by epoch.
 """
 
 import json
-import threading
 import time
 
 import numpy as np
@@ -19,34 +18,17 @@ import pytest
 
 from geomx_tpu.optimizer import SGD
 from geomx_tpu.ps import base as psbase
-from tests.test_hips import _parallel
-from tests.test_recovery import SingleTier, _round, _wait_dead
-
-
-def _kill(kv):
-    """Hard worker death: no goodbye, no barrier (disarm atexit close)."""
-    kv._closed = True
-    kv.po.van.stop()
-
-
-def _wait_declared(vans, dead_id, timeout=10.0):
-    deadline = time.monotonic() + timeout
-    while time.monotonic() < deadline:
-        if all(dead_id in v.declared_dead_ids() for v in vans):
-            return
-        time.sleep(0.05)
-    for v in vans:
-        assert dead_id in v.declared_dead_ids(), (
-            f"node {v.my_id} never learned that {dead_id} is dead")
+from tests.harness import (
+    SingleTier, _Background, _kill, _parallel, _poll, _round, _wait_dead,
+    _wait_declared)
 
 
 def test_heartbeat_lapse_declares_dead_and_bumps_epoch():
     """Heartbeat lapse -> dead_nodes() -> declaration: the scheduler
     promotes the lapse to a DEAD_NODE broadcast (epoch bump) and every
     surviving member's van converges on the same dead set + epoch."""
-    topo = SingleTier().start()
     w0 = np.full(6, 2.0, np.float32)
-    try:
+    with SingleTier() as topo:
         rank0 = next(kv for kv in topo.workers if kv.rank == 0)
         victim = next(kv for kv in topo.workers if kv.rank == 1)
         rank0.set_optimizer(SGD(learning_rate=1.0))
@@ -77,22 +59,14 @@ def test_heartbeat_lapse_declares_dead_and_bumps_epoch():
         assert rank0.get_num_dead_node(role="worker") == 1
         assert rank0.get_num_dead_node(role="server") == 0
         assert rank0.membership_epoch() >= 1
-        topo.workers = [rank0]
-    finally:
-        _parallel([kv.close for kv in topo.workers])
-        for t in topo.threads:
-            t.join(30)
-        if topo.errors:
-            raise topo.errors[0]
 
 
 def test_stale_epoch_push_is_dropped():
     """Zombie fencing: a node the scheduler declared dead while it is
     STILL RUNNING (a partition, not a death) keeps pushing — the server
     must drop those pushes unacked instead of aggregating them."""
-    topo = SingleTier().start()
     w0 = np.full(8, 10.0, np.float32)
-    try:
+    with SingleTier() as topo:
         rank0 = next(kv for kv in topo.workers if kv.rank == 0)
         zombie = next(kv for kv in topo.workers if kv.rank == 1)
         rank0.set_optimizer(SGD(learning_rate=1.0))
@@ -117,14 +91,7 @@ def test_stale_epoch_push_is_dropped():
 
         # the poison push must not even have bumped the round version
         assert topo.server._states[(0, 0)].version == 2  # rounds 1+2 only
-        topo.workers = [rank0]
-        _kill(zombie)
-    finally:
-        _parallel([kv.close for kv in topo.workers])
-        for t in topo.threads:
-            t.join(30)
-        if topo.errors:
-            raise topo.errors[0]
+        _kill(zombie)                    # it could not leave by the door
 
 
 @pytest.mark.chaos
@@ -138,10 +105,9 @@ def test_three_workers_lose_one_mid_round_survivors_continue():
     plan = json.dumps({"rules": [{
         "type": "crash", "node": psbase.worker_rank_to_id(2),
         "at_round": 2, "tier": "local"}]})
-    topo = SingleTier(num_workers=3,
-                      extra={"fault_plan": plan, "ps_seed": 11}).start()
     w0 = np.full(10, 30.0, np.float32)
-    try:
+    with SingleTier(num_workers=3,
+                    extra={"fault_plan": plan, "ps_seed": 11}) as topo:
         workers = sorted(topo.workers, key=lambda kv: kv.rank)
         rank0 = workers[0]
         victim = workers[2]
@@ -156,60 +122,42 @@ def test_three_workers_lose_one_mid_round_survivors_continue():
                    for kv in workers])
 
         # round 2: survivors push and block on the missing third push
-        outs = {}
-
         def survivor_round(kv):
             kv.notify_round(2)
             kv.push(0, np.ones_like(w0))
             out = np.zeros_like(w0)
             kv.pull(0, out=out)
-            kv.wait(timeout=60.0)
-            outs[kv.rank] = out
+            kv.wait()
+            return out
 
-        ts = [threading.Thread(target=survivor_round, args=(kv,),
-                               daemon=True) for kv in survivors]
-        for t in ts:
-            t.start()
-        time.sleep(0.4)                  # survivors' pushes land: 2/3
+        rounds = [_Background(lambda kv=kv: survivor_round(kv))
+                  for kv in survivors]
+        state = topo.server._states[(0, 0)]
+        _poll(lambda: len(state.push_reqs) == 2,
+              "the survivors' pushes to land: 2 of 3")
         dead_id = victim.po.my_id
         # the fault plan kills the victim's van at its round-2 entry: no
         # goodbye, no barrier, no push — indistinguishable from death
-        victim._closed = True            # disarm its atexit close
         victim.notify_round(2)
-        deadline = time.monotonic() + 10
-        while time.monotonic() < deadline:
-            if victim.po.van.stopped.is_set():
-                break
-            time.sleep(0.05)
-        assert victim.po.van.stopped.is_set(), \
+        assert victim.po.van.stopped.wait(10), \
             "at_round crash rule did not fire"
 
         # declaration -> the server releases the stalled round with the
         # survivors' gradients (no re-push, no timeout)
-        for t in ts:
-            t.join(60)
-        assert set(outs) == {0, 1}, "survivors did not complete the round"
-        for rank, out in outs.items():
-            np.testing.assert_allclose(out, w0 - 5.0, err_msg=(
-                f"worker {rank}: released round must carry exactly the "
-                f"2 survivor gradients"))
+        for kv, bg in zip(survivors, rounds):
+            np.testing.assert_allclose(bg.result(), w0 - 5.0, err_msg=(
+                f"worker {kv.rank}: released round must carry exactly "
+                f"the 2 survivor gradients"))
         _wait_declared([topo.server.po_local.van], dead_id)
         assert topo.server.po_local.num_live_workers() == 2
 
         # >= 5 subsequent rounds: versions keep advancing
-        v_before = topo.server._states[(0, 0)].version
+        v_before = state.version
         for r in range(1, 6):
             _parallel([lambda kv=kv, r=r:
                        _round(kv, 0, w0, w0 - 5.0 - 2.0 * r)
                        for kv in survivors])
-        assert topo.server._states[(0, 0)].version >= v_before + 5
-        topo.workers = survivors
-    finally:
-        _parallel([kv.close for kv in topo.workers])
-        for t in topo.threads:
-            t.join(30)
-        if topo.errors:
-            raise topo.errors[0]
+        assert state.version >= v_before + 5
 
 
 if __name__ == "__main__":

@@ -10,7 +10,7 @@ exact values on every path."""
 import numpy as np
 import pytest
 
-from tests.test_hips import Topology, _parallel
+from tests.harness import Topology, _parallel
 from geomx_tpu.kvstore import sharding
 from geomx_tpu.optimizer import SGD
 

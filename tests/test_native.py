@@ -16,7 +16,8 @@ from geomx_tpu.ps import base, native
 from geomx_tpu.ps.kv_app import KVPairs, KVServer, KVWorker
 from geomx_tpu.ps.message import Message, Meta, Node, Role
 
-from test_transport import free_port, make_tier, shutdown
+from geomx_tpu.simulate import free_port
+from tests.harness import make_tier, shutdown
 
 pytestmark = pytest.mark.skipif(
     not native.available(), reason="native transport not buildable")

@@ -107,7 +107,7 @@ def test_hips_launch_across_three_addresses():
     address (central 127.0.0.2, parties 127.0.0.3/4): nodes bind
     0.0.0.0, advertise DMLC_NODE_HOST, cross-address WAN + LAN tiers
     train and exit clean."""
-    from tests.test_launch_integration import _run_launch
+    from tests.harness import _run_launch
 
     accs = _run_launch(
         "run_vanilla_hips.sh", [], n_iters=15, timeout=300,

@@ -103,7 +103,7 @@ def test_dgt_block_contrib_ewma():
 
 def test_device_bsc_compressor_end_to_end_topology():
     """The device compressor slots into the live HiPS WAN hop."""
-    from tests.test_hips import Topology, _parallel
+    from tests.harness import Topology, _parallel
 
     topo = Topology().start(sync_global=True)
     try:

@@ -1,20 +1,12 @@
 """P3 priority-propagation tests (reference: P3_EncodeDefaultKey,
 kvstore_dist.h:768-805 + the priority send thread, van.cc:548,851)."""
 
-import threading
-
 import numpy as np
 import pytest
 
-from geomx_tpu.config import Config
 from geomx_tpu.kvstore import sharding
-from geomx_tpu.kvstore.dist import KVStoreDist
-from geomx_tpu.kvstore.server import KVStoreDistServer
 from geomx_tpu.optimizer import SGD
-from geomx_tpu.ps import base as psbase
-from geomx_tpu.ps.message import Role
-from geomx_tpu.ps.postoffice import Postoffice
-from tests.test_hips import _parallel, free_port  # shared scaffolding
+from tests.harness import SingleTier, _parallel
 
 
 def test_assign_p3_covers_and_respects_canonical_ranges():
@@ -51,49 +43,8 @@ def test_assign_p3_small_key_single_slice():
 def test_p3_single_tier_push_pull():
     """Single-tier PS with ENABLE_P3: keys sliced at bigarray granularity,
     per-slice messages through the priority queue; results must be exact."""
-    port = free_port()
-    threads = []
-    errors = []
-
-    def run(fn):
-        def w():
-            try:
-                fn()
-            except BaseException as e:  # noqa: BLE001
-                errors.append(e)
-        t = threading.Thread(target=w, daemon=True)
-        t.start()
-        threads.append(t)
-
-    def mkcfg(role):
-        return Config(role=role, ps_root_uri="127.0.0.1", ps_root_port=port,
-                      num_workers=2, num_servers=1, enable_p3=True,
-                      bigarray_bound=16)
-
-    sched_po = Postoffice(my_role=Role.SCHEDULER, is_global=False,
-                          root_uri="127.0.0.1", root_port=port,
-                          num_workers=2, num_servers=1, cfg=Config())
-
-    def sched():
-        sched_po.start(60)
-        sched_po.barrier(psbase.ALL_GROUP, timeout=60)
-        sched_po.barrier(psbase.ALL_GROUP, timeout=120)
-        sched_po.van.stop()
-
-    run(sched)
-    srv = KVStoreDistServer(mkcfg("server"))
-    run(srv.run)
-    boxes = [[], []]
-    for i in range(2):
-        run(lambda b=boxes[i]: b.append(KVStoreDist(cfg=mkcfg("worker"))))
-    for _ in range(300):
-        if errors:
-            raise errors[0]
-        if all(len(b) == 1 for b in boxes):
-            break
-        threading.Event().wait(0.1)
-    kvs = [b[0] for b in boxes]
-    try:
+    with SingleTier(extra={"enable_p3": True, "bigarray_bound": 16}) as topo:
+        kvs = topo.workers
         rank0 = next(kv for kv in kvs if kv.rank == 0)
         rank0.set_optimizer(SGD(learning_rate=0.5))
         # key 0 is big (sliced into 3 slices of <=16), key 1 small
@@ -114,12 +65,6 @@ def test_p3_single_tier_push_pull():
                 np.testing.assert_allclose(outs[k], w[k] - 1.0)  # 0.5*2 workers
 
         _parallel([lambda kv=kv: train(kv) for kv in kvs])
-    finally:
-        _parallel([kv.close for kv in kvs])
-        for t in threads:
-            t.join(30)
-        if errors:
-            raise errors[0]
 
 
 if __name__ == "__main__":

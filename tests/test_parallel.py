@@ -126,7 +126,7 @@ def test_hierarchical_trainer_geo_dp(devices):
     from geomx_tpu.models import MLP
     from geomx_tpu.optimizer import SGD
     from geomx_tpu.parallel.train_step import HierarchicalTrainer
-    from tests.test_hips import Topology, _parallel
+    from tests.harness import Topology, _parallel
 
     topo = Topology(num_parties=2, workers_per_party=1).start(
         sync_global=True)

@@ -369,7 +369,7 @@ def test_async_frontier_exact_under_faultplan():
         {"type": "reorder", "window": 4},
     ]})
     chaos_cfg = {"fault_plan": plan, "ps_seed": 7, "resend": True,
-                 "resend_timeout_ms": 1000}
+                 "resend_timeout_ms": 20}
 
     clean = _run_bsc("sync")
     completions = []

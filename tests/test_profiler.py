@@ -8,21 +8,14 @@ kvstore_dist_server.h:383-430).
 """
 
 import json
-import threading
 
 import numpy as np
 import pytest
 
 from geomx_tpu import profiler
-from geomx_tpu.config import Config
-from geomx_tpu.kvstore.dist import KVStoreDist
-from geomx_tpu.kvstore.server import KVStoreDistServer
 from geomx_tpu.optimizer import SGD
-from geomx_tpu.ps import base as psbase
-from geomx_tpu.ps.message import Role
-from geomx_tpu.ps.postoffice import Postoffice
 
-from test_hips import _parallel, free_port
+from tests.harness import SingleTier
 
 
 @pytest.fixture(autouse=True)
@@ -173,45 +166,8 @@ def test_worker_drives_server_profiler_end_to_end(tmp_path):
     """A worker remotely configures, runs, and dumps the server's
     profiler; the dump lands rank-prefixed and contains server.push
     scopes from real request handling."""
-    port = free_port()
-    threads, errors = [], []
-
-    def run(fn):
-        def w():
-            try:
-                fn()
-            except BaseException as e:  # noqa: BLE001
-                errors.append(e)
-        t = threading.Thread(target=w, daemon=True)
-        t.start()
-        threads.append(t)
-
-    def sched():
-        po = Postoffice(my_role=Role.SCHEDULER, is_global=False,
-                        root_uri="127.0.0.1", root_port=port,
-                        num_workers=1, num_servers=1, cfg=Config())
-        po.start(60)
-        po.barrier(psbase.ALL_GROUP, timeout=60)
-        po.barrier(psbase.ALL_GROUP, timeout=120)
-        po.van.stop()
-
-    run(sched)
-    scfg = Config(role="server", ps_root_uri="127.0.0.1", ps_root_port=port,
-                  num_workers=1, num_servers=1)
-    srv = KVStoreDistServer(scfg)
-    run(srv.run)
-    box = []
-    wcfg = Config(role="worker", ps_root_uri="127.0.0.1", ps_root_port=port,
-                  num_workers=1, num_servers=1)
-    run(lambda: box.append(KVStoreDist(cfg=wcfg)))
-    for _ in range(300):
-        if errors:
-            raise errors[0]
-        if box:
-            break
-        threading.Event().wait(0.1)
-    kv = box[0]
-    try:
+    with SingleTier(num_workers=1) as topo:
+        (kv,) = topo.workers
         kv.set_optimizer(SGD(learning_rate=1.0))
         kv.set_profiler_params(profiler.CMD_SET_CONFIG,
                                filename=str(tmp_path / "srv.json"))
@@ -233,12 +189,6 @@ def test_worker_drives_server_profiler_end_to_end(tmp_path):
         assert "push:key0" in names
         assert "pull:key0" in names
         assert "update:key0" in names
-    finally:
-        kv.close()
-        for t in threads:
-            t.join(30)
-        if errors:
-            raise errors[0]
 
 
 if __name__ == "__main__":

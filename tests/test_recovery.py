@@ -14,192 +14,137 @@ global-tier recovery is explicitly unimplemented: van.cc:224 TODO).
 """
 
 import json
-import os
-import threading
 import time
 
 import numpy as np
 import pytest
 
-from geomx_tpu.config import Config
-from geomx_tpu.kvstore.dist import KVStoreDist
 from geomx_tpu.kvstore.server import KVStoreDistServer
 from geomx_tpu.optimizer import SGD
 from geomx_tpu.ps import base as psbase
-from geomx_tpu.ps.message import Role
-from geomx_tpu.ps.postoffice import Postoffice
-from geomx_tpu.simulate import free_port
-from tests.test_hips import _parallel
+from tests.harness import (
+    DEADLINES, HB, SingleTier, _Background, _kill, _parallel, _poll, _round,
+    _wait_dead)
 
-_CORES = os.cpu_count() or 1
+# -- worker rejoin (docs/robustness.md, "Elastic membership" steps 1-5) ----
 
-# Per-op deadlines scale with the box: a healthy recovery round here
-# finishes in seconds, so the 300 s default only ever fires when the
-# round is genuinely wedged — and on a starved box that wedge used to
-# burn the full deadline chain (~8 min per test). 60 s/core, capped at
-# the stock default, keeps the give-up budget proportional to how much
-# concurrency the survivor + revived threads can actually get.
-HB = {"heartbeat_interval_s": 0.2, "heartbeat_timeout_s": 1.0,
-      "op_timeout_s": min(300.0, 60.0 * _CORES)}
-
-# The three worker mid-round recovery tests need the survivor round,
-# the revived worker's round, and the server's deferred-ack machinery
-# to interleave; with a single core the threads starve each other, the
-# round never completes, and each test eats its whole timeout budget.
-# They are pathological there, not informative — keep them out of
-# tier-1 (`-m 'not slow'`) on boxes that cannot run them honestly.
-_pathological_on_1core = (
-    pytest.mark.slow if _CORES < 2 else (lambda f: f))
+KEYS = [0, 1]
+W0 = {0: np.full(12, 10.0, np.float32), 1: np.full(5, -3.0, np.float32)}
 
 
-class SingleTier:
-    """scheduler + N servers + 2 workers with fast heartbeats.
-
-    ``extra`` merges into every node's Config (snapshot dirs, fault
-    plans, resend knobs...) so robustness tests configure the whole tier
-    the way a launch script would via environment variables."""
-
-    def __init__(self, extra=None, num_servers=1, num_workers=2):
-        self.port = free_port()
-        self.extra = dict(extra or {})
-        self.num_servers = num_servers
-        self.num_workers = num_workers
-        self.threads = []
-        self.errors = []
-        self.sched_po = None
-        self.server = None
-        self.servers = []
-        self.workers = []
-
-    def _run(self, fn):
-        def w():
-            try:
-                fn()
-            except BaseException as e:  # noqa: BLE001
-                self.errors.append(e)
-
-        t = threading.Thread(target=w, daemon=True)
-        t.start()
-        self.threads.append(t)
-
-    def _cfg(self, **kw):
-        base = dict(ps_root_uri="127.0.0.1", ps_root_port=self.port,
-                    num_workers=self.num_workers,
-                    num_servers=self.num_servers, **HB)
-        base.update(self.extra)
-        base.update(kw)
-        return Config(**base)
-
-    def start(self):
-        sched_cfg = dict(HB)
-        sched_cfg.update(self.extra)
-        self.sched_po = Postoffice(
-            my_role=Role.SCHEDULER, is_global=False,
-            root_uri="127.0.0.1", root_port=self.port,
-            num_workers=self.num_workers, num_servers=self.num_servers,
-            cfg=Config(**sched_cfg))
-
-        def sched():
-            self.sched_po.start(60)
-            self.sched_po.barrier(psbase.ALL_GROUP, timeout=60)
-            self.sched_po.barrier(psbase.ALL_GROUP, timeout=600)
-            self.sched_po.van.stop()
-
-        self._run(sched)
-        self.servers = [KVStoreDistServer(self._cfg(role="server"))
-                        for _ in range(self.num_servers)]
-        self.server = self.servers[0]
-        for s in self.servers:
-            self._run(s.run)
-        boxes = [[] for _ in range(self.num_workers)]
-        for i in range(self.num_workers):
-            self._run(lambda b=boxes[i]: b.append(
-                KVStoreDist(cfg=self._cfg(role="worker"))))
-        for _ in range(300):
-            if self.errors:
-                raise self.errors[0]
-            if all(len(b) == 1 for b in boxes):
-                break
-            time.sleep(0.1)
-        assert all(len(b) == 1 for b in boxes), "workers failed to start"
-        self.workers = [b[0] for b in boxes]
-        return self
+def _ones():
+    return [np.ones_like(W0[k]) for k in KEYS]
 
 
-def _round(kv, key, w0, expect):
-    kv.push(key, np.ones_like(w0))
-    out = np.zeros_like(w0)
-    kv.pull(key, out=out)
+def _round_per_key(kv):
+    for k, g in zip(KEYS, _ones()):
+        kv.push(k, g)
+    outs = [np.zeros_like(W0[k]) for k in KEYS]
+    for k, o in zip(KEYS, outs):
+        kv.pull(k, out=o)
     kv.wait()
-    np.testing.assert_allclose(out, expect)
+    return outs
 
 
-@_pathological_on_1core
-def test_worker_dies_and_recovers_mid_training():
-    topo = SingleTier().start()
-    w0 = np.full(12, 10.0, np.float32)
-    try:
-        rank0 = next(kv for kv in topo.workers if kv.rank == 0)
-        victim = next(kv for kv in topo.workers if kv.rank == 1)
+def _round_batched(kv):
+    kv.push(KEYS, _ones())
+    outs = [np.zeros_like(W0[k]) for k in KEYS]
+    kv.pull(KEYS, out=outs)
+    kv.wait()
+    return outs
+
+
+def _round_push_pull(kv):
+    outs = [np.zeros_like(W0[k]) for k in KEYS]
+    kv.push_pull(KEYS, _ones(), out=outs)
+    kv.wait()
+    return outs
+
+
+WIRES = {"per_key": _round_per_key, "batched": _round_batched,
+         "push_pull": _round_push_pull}
+
+
+def _assert_pushes_applied(outs, n, who):
+    """SGD(lr=1) over unit gradients: the weights read W0 - n once the
+    server has applied n pushes in all."""
+    for k, o in zip(KEYS, outs):
+        np.testing.assert_allclose(
+            o, W0[k] - n, err_msg=f"{who}: key {k} must carry {n} pushes")
+
+
+@pytest.mark.parametrize("phase", ["within_grace", "after_declaration"])
+@pytest.mark.parametrize("wire", list(WIRES))
+def test_worker_rejoin(wire, phase):
+    """A worker dies with its partner's round open, and a replacement
+    takes its slot (reference: ps-lite van.cc:176-193), over each wire.
+
+    ``within_grace``: the lapse is not yet declared (``epoch_grace_s``
+    outlasts the test), so the round stays open, the replacement joins
+    THAT round and both read two more pushes. ``after_declaration``: the
+    declaration shrinks the server's live view, the open round is
+    released with the survivor's push alone, and the replacement joins
+    the next one. Ordered by events the test polls for, never by sleeps.
+    """
+    do_round = WIRES[wire]
+    within_grace = phase == "within_grace"
+    extra = {"epoch_grace_s": 10 * DEADLINES["lifetime_s"]} \
+        if within_grace else None
+    with SingleTier(extra=extra) as topo:
+        rank0, victim = sorted(topo.workers, key=lambda kv: kv.rank)
+        server = topo.server
         rank0.set_optimizer(SGD(learning_rate=1.0))
-        _parallel([lambda kv=kv: kv.init(0, w0) for kv in topo.workers])
-
-        # round 1: everyone alive
-        _parallel([lambda kv=kv: _round(kv, 0, w0, w0 - 2.0)
+        _parallel([lambda kv=kv: [kv.init(k, W0[k]) for k in KEYS]
                    for kv in topo.workers])
+        for outs in _parallel([lambda kv=kv: do_round(kv)
+                               for kv in topo.workers]):
+            _assert_pushes_applied(outs, 2, "round 1")
 
-        # hard-kill the rank-1 worker (no goodbye, no barrier)
+        # round 2 opens with the survivor's push; its partner's never comes
+        survivor = _Background(lambda: do_round(rank0))
+        states = [server._states[(k, 0)] for k in KEYS]
+        _poll(lambda: all(len(st.push_reqs) == 1 for st in states),
+              "the survivor's pushes to reach the server")
         dead_id = victim.po.my_id
-        victim._closed = True          # disarm its atexit close
-        victim.po.van.stop()
+        _kill(victim)
+        _wait_dead(topo, dead_id)        # the slot is now up for handover
 
-        # heartbeat lapse -> scheduler marks it dead
-        deadline = time.monotonic() + 10
-        while time.monotonic() < deadline:
-            if dead_id in topo.sched_po.van.dead_nodes():
-                break
-            time.sleep(0.1)
-        assert dead_id in topo.sched_po.van.dead_nodes()
+        if within_grace:
+            assert dead_id not in topo.sched_po.van.declared_dead_ids()
+            assert server.po_local.num_live_workers() == 2
+            assert all(len(st.push_reqs) == 1 for st in states)
+            assert not survivor.done(), "the round must wait for the pardon"
+        else:
+            _poll(lambda: server.po_local.num_live_workers() == 1,
+                  "the server's live view to shrink")
+            _assert_pushes_applied(survivor.result(), 3, "released round")
 
-        # the survivor pushes round 2 and blocks on the missing peer
-        results = []
-
-        def survivor():
-            _round(rank0, 0, w0, w0 - 4.0)
-            results.append("survivor")
-
-        t = threading.Thread(target=survivor, daemon=True)
-        t.start()
-
-        # revive: a fresh worker re-registers and takes the dead slot
-        revived = KVStoreDist(cfg=topo._cfg(role="worker"))
+        revived = topo.revive_worker()
         assert revived.po.van.is_recovery, "scheduler did not hand over slot"
         assert revived.po.my_id == dead_id
         assert revived.rank == 1
-        revived.init(0, w0)            # key info only; store already live
-        _round(revived, 0, w0, w0 - 4.0)
-        t.join(60)
-        assert results == ["survivor"], "survivor did not complete the round"
+        # the table broadcast reaches members in turn, and the server
+        # fences data from a declared id until it has un-declared it
+        _poll(lambda: server.po_local.num_live_workers() == 2,
+              "the server to admit the replacement")
+        for k in KEYS:
+            revived.init(k, W0[k])       # acked and ignored: store is live
 
-        # round 3 with the recovered pair
-        _parallel([lambda kv=kv: _round(kv, 0, w0, w0 - 6.0)
-                   for kv in (rank0, revived)])
-        topo.workers = [rank0, revived]
-    finally:
-        _parallel([kv.close for kv in topo.workers])
-        for t in topo.threads:
-            t.join(30)
-        if topo.errors:
-            raise topo.errors[0]
+        if within_grace:
+            _assert_pushes_applied(do_round(revived), 4, "joined round")
+            _assert_pushes_applied(survivor.result(), 4, "open round")
+        for outs in _parallel([lambda kv=kv: do_round(kv)
+                               for kv in (rank0, revived)]):
+            _assert_pushes_applied(outs, 6 if within_grace else 5,
+                                   "the pair's round")
 
 
 def test_server_dies_and_recovers_mid_training():
     """Server store is volatile (reference: SURVEY §5.4): after the slot
     handover, workers re-init and re-ship the optimizer, then training
     resumes from the re-initialized weights."""
-    topo = SingleTier().start()
     w0 = np.full(8, 4.0, np.float32)
-    try:
+    with SingleTier() as topo:
         rank0 = next(kv for kv in topo.workers if kv.rank == 0)
         rank0.set_optimizer(SGD(learning_rate=1.0))
         _parallel([lambda kv=kv: kv.init(0, w0) for kv in topo.workers])
@@ -207,23 +152,10 @@ def test_server_dies_and_recovers_mid_training():
                    for kv in topo.workers])
 
         dead_id = topo.server.po_local.my_id
-        topo.server._stop.set()        # stop the run loop...
-        topo.server.po_local.van.stop()  # ...and crash the van (no barrier)
+        topo.server.crash()              # hard kill: no barrier
+        _wait_dead(topo, dead_id)
 
-        deadline = time.monotonic() + 10
-        while time.monotonic() < deadline:
-            if dead_id in topo.sched_po.van.dead_nodes():
-                break
-            time.sleep(0.1)
-        assert dead_id in topo.sched_po.van.dead_nodes()
-
-        revived = KVStoreDistServer(topo._cfg(role="server"))
-        rt = threading.Thread(target=revived.run, daemon=True)
-        rt.start()
-        for _ in range(100):
-            if revived.po_local.van.ready.is_set():
-                break
-            time.sleep(0.1)
+        revived = topo.revive_server()
         assert revived.po_local.van.is_recovery
         assert revived.po_local.my_id == dead_id
 
@@ -232,181 +164,12 @@ def test_server_dies_and_recovers_mid_training():
         _parallel([lambda kv=kv: kv.init(0, w0) for kv in topo.workers])
         _parallel([lambda kv=kv: _round(kv, 0, w0, w0 - 2.0)
                    for kv in topo.workers])
-        topo.server = revived
-    finally:
-        _parallel([kv.close for kv in topo.workers])
-        for t in topo.threads:
-            t.join(30)
-        if topo.errors:
-            raise topo.errors[0]
-
-
-if __name__ == "__main__":
-    import sys
-
-    sys.exit(pytest.main([__file__, "-x", "-q"]))
-
-
-@_pathological_on_1core
-def test_worker_recovery_with_batched_wire():
-    """The batched list wire across a worker death/recovery: the
-    surviving worker's batched round blocks on the missing peer, the
-    revived worker joins the same round through batched messages, and
-    values stay exact (one merged ack per server must survive the
-    re-registration)."""
-    topo = SingleTier().start()
-    KEYS = [0, 1]
-    W0 = {0: np.full(12, 10.0, np.float32),
-          1: np.full(5, -3.0, np.float32)}
-    try:
-        rank0 = next(kv for kv in topo.workers if kv.rank == 0)
-        victim = next(kv for kv in topo.workers if kv.rank == 1)
-        rank0.set_optimizer(SGD(learning_rate=1.0))
-        _parallel([lambda kv=kv: [kv.init(k, W0[k]) for k in KEYS]
-                   for kv in topo.workers])
-
-        def batched_round(kv, r):
-            kv.push(KEYS, [np.ones_like(W0[k]) for k in KEYS])
-            outs = [np.zeros_like(W0[k]) for k in KEYS]
-            kv.pull(KEYS, out=outs)
-            kv.wait()
-            for k, o in zip(KEYS, outs):
-                np.testing.assert_allclose(o, W0[k] - 2.0 * r)
-
-        _parallel([lambda kv=kv: batched_round(kv, 1)
-                   for kv in topo.workers])
-
-        dead_id = victim.po.my_id
-        victim._closed = True
-        victim.po.van.stop()
-        deadline = time.monotonic() + 10
-        while time.monotonic() < deadline:
-            if dead_id in topo.sched_po.van.dead_nodes():
-                break
-            time.sleep(0.1)
-        assert dead_id in topo.sched_po.van.dead_nodes()
-
-        results = []
-
-        def survivor():
-            batched_round(rank0, 2)
-            results.append("survivor")
-
-        t = threading.Thread(target=survivor, daemon=True)
-        t.start()
-
-        revived = KVStoreDist(cfg=topo._cfg(role="worker"))
-        assert revived.po.van.is_recovery
-        for k in KEYS:
-            revived.init(k, W0[k])
-        batched_round(revived, 2)
-        t.join(60)
-        assert results == ["survivor"], "survivor did not complete"
-
-        _parallel([lambda kv=kv: batched_round(kv, 3)
-                   for kv in (rank0, revived)])
-        topo.workers = [rank0, revived]
-    finally:
-        _parallel([kv.close for kv in topo.workers])
-        for t in topo.threads:
-            t.join(30)
-        if topo.errors:
-            raise topo.errors[0]
-
-
-@_pathological_on_1core
-def test_worker_recovery_with_push_pull_wire():
-    """The COMBINED push_pull wire across a worker death/recovery: the
-    survivor's combined round defers its data-carrying ack on the
-    missing peer; the revived worker joins the same round; values stay
-    exact (the merged ack carrying post-round params must survive the
-    re-registration)."""
-    topo = SingleTier().start()
-    KEYS = [0, 1]
-    W0 = {0: np.full(12, 10.0, np.float32),
-          1: np.full(5, -3.0, np.float32)}
-    try:
-        rank0 = next(kv for kv in topo.workers if kv.rank == 0)
-        victim = next(kv for kv in topo.workers if kv.rank == 1)
-        rank0.set_optimizer(SGD(learning_rate=1.0))
-        _parallel([lambda kv=kv: [kv.init(k, W0[k]) for k in KEYS]
-                   for kv in topo.workers])
-
-        def combined_round(kv, r):
-            outs = [np.zeros_like(W0[k]) for k in KEYS]
-            kv.push_pull(KEYS, [np.ones_like(W0[k]) for k in KEYS],
-                         out=outs)
-            kv.wait()
-            for k, o in zip(KEYS, outs):
-                np.testing.assert_allclose(o, W0[k] - 2.0 * r)
-
-        _parallel([lambda kv=kv: combined_round(kv, 1)
-                   for kv in topo.workers])
-
-        dead_id = victim.po.my_id
-        victim._closed = True
-        victim.po.van.stop()
-        deadline = time.monotonic() + 10
-        while time.monotonic() < deadline:
-            if dead_id in topo.sched_po.van.dead_nodes():
-                break
-            time.sleep(0.1)
-        assert dead_id in topo.sched_po.van.dead_nodes()
-
-        results = []
-
-        def survivor():
-            combined_round(rank0, 2)
-            results.append("survivor")
-
-        t = threading.Thread(target=survivor, daemon=True)
-        t.start()
-
-        revived = KVStoreDist(cfg=topo._cfg(role="worker"))
-        assert revived.po.van.is_recovery
-        for k in KEYS:
-            revived.init(k, W0[k])
-        combined_round(revived, 2)
-        t.join(60)
-        assert results == ["survivor"], "survivor did not complete"
-
-        _parallel([lambda kv=kv: combined_round(kv, 3)
-                   for kv in (rank0, revived)])
-        topo.workers = [rank0, revived]
-    finally:
-        _parallel([kv.close for kv in topo.workers])
-        for t in topo.threads:
-            t.join(30)
-        if topo.errors:
-            raise topo.errors[0]
 
 
 # ----------------------------------------------------------------------
 # durable recovery (kvstore/replication.py): a revived server serves
 # PRE-CRASH values — beyond the reference, whose store is volatile
 # ----------------------------------------------------------------------
-
-
-def _wait_dead(topo, dead_id, timeout=10.0):
-    deadline = time.monotonic() + timeout
-    while time.monotonic() < deadline:
-        if dead_id in topo.sched_po.van.dead_nodes():
-            return
-        time.sleep(0.1)
-    assert dead_id in topo.sched_po.van.dead_nodes()
-
-
-def _revive_server(topo, **cfg_kw):
-    revived = KVStoreDistServer(topo._cfg(role="server", **cfg_kw))
-    t = threading.Thread(target=revived.run, daemon=True)
-    t.start()
-    topo.threads.append(t)
-    for _ in range(300):
-        if revived._ready.is_set():
-            break
-        time.sleep(0.1)
-    assert revived._ready.is_set(), "revived server never became ready"
-    return revived
 
 
 def _pull_now(kv, key, like):
@@ -422,10 +185,9 @@ def test_server_recovers_state_from_snapshot(tmp_path):
     periodic snapshot and serves the PRE-CRASH values with NO re-init and
     NO optimizer re-ship (contrast: test_server_dies_and_recovers_mid_
     training above documents the old volatile-store behavior)."""
-    topo = SingleTier(extra={"snapshot_dir": str(tmp_path),
-                             "snapshot_interval_s": 0.1}).start()
     w0 = np.full(8, 4.0, np.float32)
-    try:
+    with SingleTier(extra={"snapshot_dir": str(tmp_path),
+                           "snapshot_interval_s": 0.1}) as topo:
         rank0 = next(kv for kv in topo.workers if kv.rank == 0)
         rank0.set_optimizer(SGD(learning_rate=1.0))
         _parallel([lambda kv=kv: kv.init(0, w0) for kv in topo.workers])
@@ -439,7 +201,7 @@ def test_server_recovers_state_from_snapshot(tmp_path):
         topo.server.crash()              # hard kill: no flush, no barrier
         _wait_dead(topo, dead_id)
 
-        revived = _revive_server(topo)
+        revived = topo.revive_server()
         assert revived.po_local.van.is_recovery
         assert revived.po_local.my_id == dead_id
         assert revived.replication.restored_from == "snapshot"
@@ -450,13 +212,6 @@ def test_server_recovers_state_from_snapshot(tmp_path):
         # training continues (restored updater applies round 3)
         _parallel([lambda kv=kv: _round(kv, 0, w0, w0 - 6.0)
                    for kv in topo.workers])
-        topo.server = revived
-    finally:
-        _parallel([kv.close for kv in topo.workers])
-        for t in topo.threads:
-            t.join(30)
-        if topo.errors:
-            raise topo.errors[0]
 
 
 def test_server_recovers_state_from_peer_replica():
@@ -464,10 +219,9 @@ def test_server_recovers_state_from_peer_replica():
     replicates its dirty state to the next-rank peer every tick, and the
     revived server restores by fetching its replica from that peer
     (Command.REPLICA_FETCH)."""
-    topo = SingleTier(extra={"snapshot_interval_s": 0.1},
-                      num_servers=2).start()
     w0 = np.full(8, 4.0, np.float32)
-    try:
+    with SingleTier(extra={"snapshot_interval_s": 0.1},
+                    num_servers=2) as topo:
         rank0 = next(kv for kv in topo.workers if kv.rank == 0)
         rank0.set_optimizer(SGD(learning_rate=1.0))
         _parallel([lambda kv=kv: kv.init(0, w0) for kv in topo.workers])
@@ -487,7 +241,7 @@ def test_server_recovers_state_from_peer_replica():
         victim.crash()
         _wait_dead(topo, dead_id)
 
-        revived = _revive_server(topo)
+        revived = topo.revive_server()
         assert revived.po_local.van.is_recovery
         assert revived.po_local.my_id == dead_id
         assert revived.replication.restored_from == "replica"
@@ -496,15 +250,6 @@ def test_server_recovers_state_from_peer_replica():
             np.testing.assert_allclose(_pull_now(kv, 0, w0), w0 - 4.0)
         _parallel([lambda kv=kv: _round(kv, 0, w0, w0 - 6.0)
                    for kv in topo.workers])
-        topo.servers = [revived if s is victim else s
-                        for s in topo.servers]
-        topo.server = topo.servers[0]
-    finally:
-        _parallel([kv.close for kv in topo.workers])
-        for t in topo.threads:
-            t.join(30)
-        if topo.errors:
-            raise topo.errors[0]
 
 
 def test_hips_party_server_recovers_state(tmp_path):
@@ -542,14 +287,11 @@ def test_hips_party_server_recovers_state(tmp_path):
         time.sleep(3.0)                  # heartbeat lapse on BOTH tiers
 
         revived = KVStoreDistServer(victim.cfg)
-        rt = threading.Thread(target=revived.run, daemon=True)
-        rt.start()
-        sim.threads.append(rt)
-        for _ in range(300):
-            if revived._ready.is_set():
-                break
-            time.sleep(0.1)
-        assert revived._ready.is_set(), "revived party server not ready"
+        sim._spawn(revived.run)
+        _poll(lambda: sim.errors or revived._ready.is_set(),
+              "the revived party server to become ready",
+              DEADLINES["start_s"])
+        assert not sim.errors, sim.errors
         assert revived.po_local.van.is_recovery
         assert revived.po_global is not None
         assert revived.po_global.van.is_recovery
@@ -609,24 +351,16 @@ def test_faultplan_crash_resume_matches_uninterrupted(tmp_path):
     # -- run A: uninterrupted baseline ---------------------------------
     extra_a = dict(common, snapshot_dir=str(tmp_path / "a"))
     del extra_a["ps_seed"]               # seedless is fine without a plan
-    topo_a = SingleTier(extra=extra_a).start()
-    try:
+    with SingleTier(extra=extra_a) as topo_a:
         train_two_rounds(topo_a)
         # data frames the server received through rounds 1-2: the crash
         # point for run B is the NEXT one (round 3's first arrival)
         n_pre = topo_a.server.po_local.van.num_data_recv
-        final_a = []
-        _parallel([lambda kv=kv: final_a.append(
-            _pull_now(kv, 0, w0)) for kv in topo_a.workers])
+        final_a = _parallel([lambda kv=kv: _pull_now(kv, 0, w0)
+                             for kv in topo_a.workers])
         _parallel([lambda kv=kv: _round(kv, 0, w0, w0 - 6.0)
                    for kv in topo_a.workers])
         expect = w0 - 6.0
-    finally:
-        _parallel([kv.close for kv in topo_a.workers])
-        for t in topo_a.threads:
-            t.join(30)
-        if topo_a.errors:
-            raise topo_a.errors[0]
     np.testing.assert_allclose(final_a[0], w0 - 4.0)
 
     # -- run B: same training, server crashed by the fault plan --------
@@ -635,46 +369,31 @@ def test_faultplan_crash_resume_matches_uninterrupted(tmp_path):
         "on": "recv", "tier": "local"}]})
     extra_b = dict(common, snapshot_dir=str(tmp_path / "b"),
                    fault_plan=plan)
-    topo_b = SingleTier(extra=extra_b).start()
-    try:
+    with SingleTier(extra=extra_b) as topo_b:
         train_two_rounds(topo_b)
         dead_id = topo_b.server.po_local.my_id
         assert dead_id == server_id
 
         # round 3: the first data frame trips the crash rule
-        outs = {}
-
         def round3(kv):
             kv.push(0, np.ones_like(w0))
             out = np.zeros_like(w0)
             kv.pull(0, out=out)
-            kv.wait(timeout=120.0)
-            outs[kv.rank] = out
+            kv.wait()
+            return out
 
-        ts = [threading.Thread(target=round3, args=(kv,), daemon=True)
-              for kv in topo_b.workers]
-        for t in ts:
-            t.start()
-        _wait_dead(topo_b, dead_id, timeout=30.0)
+        rounds = [_Background(lambda kv=kv: round3(kv))
+                  for kv in topo_b.workers]
+        _wait_dead(topo_b, dead_id)
         assert topo_b.server._crashed, "FaultPlan crash did not fire"
 
         # the replacement gets NO fault plan (fresh host) but the same
         # snapshot dir; workers' retransmits then complete round 3
-        revived = _revive_server(topo_b, fault_plan="")
+        revived = topo_b.revive_server(fault_plan="")
         assert revived.po_local.van.is_recovery
         assert revived.replication.restored_from == "snapshot", \
             "run B must resume from the snapshot, not re-init"
-        for t in ts:
-            t.join(120)
-        assert set(outs) == {0, 1}, "round 3 did not complete after revival"
-        for rank, out in outs.items():
-            np.testing.assert_allclose(out, expect, err_msg=(
-                f"worker {rank}: resumed weights diverge from the "
+        for kv, bg in zip(topo_b.workers, rounds):
+            np.testing.assert_allclose(bg.result(), expect, err_msg=(
+                f"worker {kv.rank}: resumed weights diverge from the "
                 f"uninterrupted run"))
-        topo_b.server = revived
-    finally:
-        _parallel([kv.close for kv in topo_b.workers])
-        for t in topo_b.threads:
-            t.join(30)
-        if topo_b.errors:
-            raise topo_b.errors[0]

@@ -17,17 +17,12 @@ import numpy as np
 import pytest
 
 from geomx_tpu.config import Config
-from geomx_tpu.ps import base
 from geomx_tpu.ps.kv_app import KVPairs, KVServer, KVWorker
-from geomx_tpu.ps.message import Role
-from geomx_tpu.ps.postoffice import Postoffice
-
-from test_transport import free_port, shutdown
+from tests.harness import make_tier, shutdown
 
 
 def make_lossy_tier(drop_rate, num_workers=2, num_servers=1,
                     resend_timeout_ms=100, seed=1234):
-    port = free_port()
     kw_cfg = dict(resend=True, resend_timeout_ms=resend_timeout_ms,
                   ps_seed=seed)
     if drop_rate:
@@ -35,24 +30,7 @@ def make_lossy_tier(drop_rate, num_workers=2, num_servers=1,
         # are exempt by default, so rendezvous always completes)
         kw_cfg["fault_plan"] = json.dumps(
             {"rules": [{"type": "drop", "p": drop_rate}]})
-    cfg = Config(**kw_cfg)
-    kw = dict(is_global=False, root_uri="127.0.0.1", root_port=port,
-              num_workers=num_workers, num_servers=num_servers, cfg=cfg)
-    sched = Postoffice(my_role=Role.SCHEDULER, **kw)
-    servers = [Postoffice(my_role=Role.SERVER, **kw)
-               for _ in range(num_servers)]
-    workers = [Postoffice(my_role=Role.WORKER, **kw)
-               for _ in range(num_workers)]
-    threads = []
-    for po in [sched] + servers + workers:
-        t = threading.Thread(target=po.start, daemon=True)
-        t.start()
-        threads.append(t)
-    for t in threads:
-        t.join(30)
-    for po in [sched] + servers + workers:
-        assert po.van.ready.is_set(), "rendezvous failed under loss"
-    return sched, servers, workers
+    return make_tier(num_workers, num_servers, cfg=Config(**kw_cfg))
 
 
 def test_sig_assignment_and_ack_clears_pending():

@@ -10,7 +10,7 @@ import pytest
 
 from geomx_tpu.kvstore.local import KVStoreLocal
 from geomx_tpu.optimizer import SGD
-from tests.test_hips import Topology, _parallel
+from tests.harness import Topology, _parallel
 
 
 def test_local_row_sparse_roundtrip():

@@ -8,23 +8,16 @@ embeds as wan_bytes_per_round).
 """
 
 import json
-import threading
 import time
 
 import numpy as np
 import pytest
 
 from geomx_tpu import profiler, telemetry
-from geomx_tpu.config import Config
-from geomx_tpu.kvstore.dist import KVStoreDist
-from geomx_tpu.kvstore.server import KVStoreDistServer
 from geomx_tpu.optimizer import SGD
-from geomx_tpu.ps import base as psbase
-from geomx_tpu.ps.message import Role
-from geomx_tpu.ps.postoffice import Postoffice
 from geomx_tpu.simulate import InProcessHiPS
 
-from test_hips import _parallel, free_port
+from tests.harness import SingleTier, _parallel
 
 
 @pytest.fixture(autouse=True)
@@ -235,45 +228,8 @@ def test_mesh_store_count_collective_counter_family():
 def _ten_key_round_seconds():
     """Measure one 10-key push+pull round on a single-tier loopback PS
     (same harness as test_profiler's end-to-end test)."""
-    port = free_port()
-    threads, errors = [], []
-
-    def run(fn):
-        def w():
-            try:
-                fn()
-            except BaseException as e:  # noqa: BLE001
-                errors.append(e)
-        t = threading.Thread(target=w, daemon=True)
-        t.start()
-        threads.append(t)
-
-    def sched():
-        po = Postoffice(my_role=Role.SCHEDULER, is_global=False,
-                        root_uri="127.0.0.1", root_port=port,
-                        num_workers=1, num_servers=1, cfg=Config())
-        po.start(60)
-        po.barrier(psbase.ALL_GROUP, timeout=60)
-        po.barrier(psbase.ALL_GROUP, timeout=120)
-        po.van.stop()
-
-    run(sched)
-    scfg = Config(role="server", ps_root_uri="127.0.0.1", ps_root_port=port,
-                  num_workers=1, num_servers=1)
-    srv = KVStoreDistServer(scfg)
-    run(srv.run)
-    box = []
-    wcfg = Config(role="worker", ps_root_uri="127.0.0.1", ps_root_port=port,
-                  num_workers=1, num_servers=1)
-    run(lambda: box.append(KVStoreDist(cfg=wcfg)))
-    for _ in range(300):
-        if errors:
-            raise errors[0]
-        if box:
-            break
-        threading.Event().wait(0.1)
-    kv = box[0]
-    try:
+    with SingleTier(num_workers=1) as topo:
+        (kv,) = topo.workers
         kv.set_optimizer(SGD(learning_rate=1.0))
         for k in range(10):
             kv.init(k, np.ones(8, np.float32))
@@ -285,12 +241,6 @@ def _ten_key_round_seconds():
             kv.pull(k)
         kv.wait()
         return time.perf_counter() - t0
-    finally:
-        kv.close()
-        for t in threads:
-            t.join(30)
-        if errors:
-            raise errors[0]
 
 
 def test_disabled_overhead_under_5pct_of_ten_key_round():

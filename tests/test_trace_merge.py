@@ -13,7 +13,7 @@ from geomx_tpu.optimizer import SGD
 from geomx_tpu.simulate import InProcessHiPS
 from tools import trace_merge
 
-from tests.test_hips import _parallel
+from tests.harness import _parallel
 
 
 @pytest.fixture(autouse=True)

@@ -5,7 +5,6 @@ singletons, unlike the reference's ps::Postoffice), an entire scheduler +
 server + worker topology can run inside one test process on ephemeral ports.
 """
 
-import socket
 import threading
 
 import numpy as np
@@ -14,48 +13,7 @@ import pytest
 from geomx_tpu.ps import base
 from geomx_tpu.ps.kv_app import KVPairs, KVServer, KVWorker
 from geomx_tpu.ps.message import Message, Meta, Node, Role
-from geomx_tpu.ps.postoffice import Postoffice
-
-
-def free_port() -> int:
-    s = socket.socket()
-    s.bind(("127.0.0.1", 0))
-    port = s.getsockname()[1]
-    s.close()
-    return port
-
-
-def make_tier(num_workers=2, num_servers=1, is_global=False):
-    """Boot a full tier in-process; returns (scheduler, servers, workers)."""
-    port = free_port()
-    kw = dict(
-        is_global=is_global,
-        root_uri="127.0.0.1",
-        root_port=port,
-        num_workers=num_workers,
-        num_servers=num_servers,
-    )
-    sched = Postoffice(my_role=Role.SCHEDULER, **kw)
-    servers = [Postoffice(my_role=Role.SERVER, **kw) for _ in range(num_servers)]
-    workers = [Postoffice(my_role=Role.WORKER, **kw) for _ in range(num_workers)]
-    threads = []
-    sched_t = threading.Thread(target=sched.start, daemon=True)
-    sched_t.start()
-    for po in servers + workers:
-        t = threading.Thread(target=po.start, daemon=True)
-        t.start()
-        threads.append(t)
-    sched_t.join(20)
-    for t in threads:
-        t.join(20)
-    for po in [sched] + servers + workers:
-        assert po.van.ready.is_set(), "rendezvous failed"
-    return sched, servers, workers
-
-
-def shutdown(*pos):
-    for po in pos:
-        po.finalize(do_barrier=False)
+from tests.harness import make_tier, shutdown
 
 
 def test_message_roundtrip():

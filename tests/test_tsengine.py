@@ -8,22 +8,17 @@ geomx_tpu/ps/tsengine.py.
 """
 
 import json
-import threading
 import types
 
 import numpy as np
 import pytest
 
-from geomx_tpu.config import Config
-from geomx_tpu.kvstore.dist import KVStoreDist
-from geomx_tpu.kvstore.server import KVStoreDistServer
 from geomx_tpu.optimizer import SGD
 from geomx_tpu.ps import base as psbase
-from geomx_tpu.ps.message import Control, Message, Meta, Role
-from geomx_tpu.ps.postoffice import Postoffice
+from geomx_tpu.ps.message import Control, Message, Meta
 from geomx_tpu.ps.tsengine import DONE_DEST, SERVER_DEST, TSScheduler
 
-from test_hips import Topology, _parallel, free_port
+from tests.harness import SingleTier, Topology, _parallel
 
 
 class FakeVan:
@@ -219,59 +214,12 @@ def test_scheduler_greedy_prefers_measured_throughput():
     assert d["dest"] == w[2]
 
 
-def _single_tier(enable_ts, num_workers=3):
-    """1 scheduler + 1 server + N workers on localhost threads."""
-    port = free_port()
-    threads, errors = [], []
-    extra = dict(enable_intra_ts=enable_ts)
-
-    def run(fn):
-        def wrapped():
-            try:
-                fn()
-            except BaseException as e:  # noqa: BLE001
-                errors.append(e)
-        t = threading.Thread(target=wrapped, daemon=True)
-        t.start()
-        threads.append(t)
-
-    def sched():
-        po = Postoffice(my_role=Role.SCHEDULER, is_global=False,
-                        root_uri="127.0.0.1", root_port=port,
-                        num_workers=num_workers, num_servers=1,
-                        cfg=Config(**extra))
-        po.start(60)
-        po.barrier(psbase.ALL_GROUP, timeout=60)
-        po.barrier(psbase.ALL_GROUP, timeout=300)
-        po.van.stop()
-
-    run(sched)
-    scfg = Config(role="server", ps_root_uri="127.0.0.1", ps_root_port=port,
-                  num_workers=num_workers, num_servers=1, **extra)
-    srv = KVStoreDistServer(scfg)
-    run(srv.run)
-    boxes = [[] for _ in range(num_workers)]
-    for i in range(num_workers):
-        wcfg = Config(role="worker", ps_root_uri="127.0.0.1",
-                      ps_root_port=port, num_workers=num_workers,
-                      num_servers=1, **extra)
-        run(lambda b=boxes[i], c=wcfg: b.append(KVStoreDist(cfg=c)))
-    for _ in range(300):
-        if errors:
-            raise errors[0]
-        if all(len(b) == 1 for b in boxes):
-            break
-        threading.Event().wait(0.1)
-    assert all(len(b) == 1 for b in boxes), "workers failed to start"
-    return [b[0] for b in boxes], threads, errors
-
-
 def test_intra_ts_single_tier_end_to_end():
     """3 workers under ENABLE_INTRA_TS: gradients merge worker-to-worker,
     one merged push hits the server, the model relays back; results match
     the direct-push semantics exactly."""
-    kvs, threads, errors = _single_tier(enable_ts=True)
-    try:
+    with SingleTier(extra={"enable_intra_ts": True}, num_workers=3) as topo:
+        kvs = topo.workers
         rank0 = next(kv for kv in kvs if kv.rank == 0)
         rank0.set_optimizer(SGD(learning_rate=0.5))
         w0 = np.arange(12, dtype=np.float32)
@@ -287,12 +235,6 @@ def test_intra_ts_single_tier_end_to_end():
         _parallel([lambda kv=kv: step(kv, w0 - 1.5) for kv in kvs])
         _parallel([lambda kv=kv: step(kv, w0 - 3.0) for kv in kvs])
         _parallel([lambda kv=kv: step(kv, w0 - 4.5) for kv in kvs])
-    finally:
-        _parallel([kv.close for kv in kvs])
-        for t in threads:
-            t.join(30)
-        if errors:
-            raise errors[0]
 
 
 def test_intra_ts_hips_two_tier():
