@@ -7,7 +7,10 @@ inter-DC hop by the HiPS server. Device (JAX/XLA + Pallas) versions live
 in ``geomx_tpu.ops``; ``make_compressor({"type": "bsc", "device": true})``
 or GEOMX_DEVICE_COMPRESSION=1 routes the server's WAN hop through them —
 for multi-million-element keys the device top-k dominates the host
-partition (4.9-9.2x at 8M elements on a v5e; tools/compress_bench.py). Placement matches the reference: the
+partition (4.9-9.2x at 8M elements on a v5e; tools/compress_bench.py:
+measured against the permutation-based host pass, whose shuffle of
+all n positions was most of its cost; not re-measured since the sample
+is drawn in O(sample)). Placement matches the reference: the
 LAN tier is uncompressed; party servers compress the aggregated gradient
 before the WAN push (BSCompress, :191), the global server decompresses,
 aggregates, and compresses pull responses with the non-zero filter scaled
@@ -59,42 +62,89 @@ def _ops():
 # stateless kernels
 # ---------------------------------------------------------------------------
 
-def bsc_sample_boundary(v: np.ndarray, threshold: float,
-                        rng: np.random.Generator) -> float:
-    """Top-k boundary from a random 0.5% sample (reference: :203-233)."""
-    n = v.size
-    sample_size = int(n * 0.005) if n * 0.005 * threshold >= 10 \
+def _bsc_sample_size(n: int, threshold: float) -> int:
+    """0.5% of n, at least ceil(10/threshold) entries, never more than n
+    (reference: :203-212)."""
+    size = int(n * 0.005) if n * 0.005 * threshold >= 10 \
         else int(np.ceil(10 / threshold))
-    sample_size = min(max(sample_size, 1), n)
-    top_k = max(int(sample_size * threshold), 1)
-    idx = rng.permutation(n)[:sample_size]
-    sample = np.abs(v[idx])
-    top_k = min(top_k, sample.size)
+    return min(max(size, 1), n)
+
+
+def bsc_sample_positions(n: int, threshold: float,
+                         rng: np.random.Generator) -> np.ndarray:
+    """The positions of the boundary sample: uniform over range(n),
+    without replacement, drawn in O(sample) (Floyd's algorithm inside
+    ``Generator.choice``; a permutation of all n positions cost 84% of
+    the pass at 38.6M elements). All positions when the sample is n."""
+    size = _bsc_sample_size(n, threshold)
+    if size >= n:
+        return np.arange(n)
+    return rng.choice(n, size, replace=False, shuffle=False)
+
+
+def bsc_sample_boundary(v: np.ndarray, threshold: float,
+                        rng: np.random.Generator,
+                        positions: Optional[np.ndarray] = None) -> float:
+    """Top-k boundary from a random 0.5% sample (reference: :203-233).
+    ``positions`` are those of :func:`bsc_sample_positions`, for a caller
+    that draws them itself (under its lock on a shared ``rng``)."""
+    if positions is None:
+        positions = bsc_sample_positions(v.size, threshold, rng)
+    sample = np.abs(v[positions])
+    top_k = min(max(int(sample.size * threshold), 1), sample.size)
     return float(np.partition(sample, -top_k)[-top_k])
+
+
+# elements per block of the selection pass: 1 MB of float32, so the
+# |v| and mask temporaries stay in cache and none is O(n)
+_SELECT_BLOCK = 1 << 18
+
+
+def _select_at_least(v: np.ndarray, boundary: float, cap: int) -> np.ndarray:
+    """The first ``cap`` positions, in index order, with |v| >= boundary
+    (``np.nonzero(np.abs(v) >= boundary)[0][:cap]`` block by block,
+    stopping at the cap)."""
+    found, count = [], 0
+    mag = np.empty(min(_SELECT_BLOCK, v.size), dtype=v.dtype)
+    for lo in range(0, v.size, _SELECT_BLOCK):
+        block = v[lo:lo + _SELECT_BLOCK]
+        m = np.abs(block, out=mag[:block.size])
+        hit = np.nonzero(m >= boundary)[0]
+        if hit.size:
+            hit += lo
+            found.append(hit)
+            count += hit.size
+            if count >= cap:
+                break
+    if not found:
+        return np.empty(0, dtype=np.intp)
+    return np.concatenate(found)[:cap]
 
 
 def bsc_compress(grad: np.ndarray, u: np.ndarray, v: np.ndarray,
                  threshold: float,
                  rng: Optional[np.random.Generator] = None,
+                 positions: Optional[np.ndarray] = None,
                  ) -> Tuple[np.ndarray, np.ndarray]:
     """Momentum-corrected top-k sparsification (reference: :191-268).
 
     Mutates ``u``/``v`` in place (momentum correction + residual reset for
-    the transmitted coordinates). Returns (values, indices).
+    the transmitted coordinates); reads ``grad`` only. Returns (values,
+    indices). ``positions``: see :func:`bsc_sample_boundary`.
     """
-    if rng is None:
+    if rng is None and positions is None:
         rng = np.random.default_rng(42)  # reference uses a fixed seed (:212)
     n = grad.size
     zipped = max(int(n * threshold), 1)
     u *= BSC_MOMENTUM
     u += grad
     v += u
-    boundary = bsc_sample_boundary(v, threshold, rng)
-    selected = np.nonzero(np.abs(v) >= boundary)[0][:zipped]
-    values = v[selected].copy()
+    boundary = bsc_sample_boundary(v, threshold, rng, positions)
+    selected = _select_at_least(v, boundary, zipped)
+    values = v[selected]
     v[selected] = 0.0
     u[selected] = 0.0
-    return values.astype(np.float32), selected.astype(np.int32)
+    return values.astype(np.float32, copy=False), selected.astype(np.int32)
 
 
 def bsc_pull_compress(arr: np.ndarray, threshold: float, multiplier: int,
@@ -165,6 +215,9 @@ class Compressor:
 
     def decompress_push(self, tag: str, val: np.ndarray,
                         aux: Optional[np.ndarray], orig_len: int) -> np.ndarray:
+        """-> the dense push. Either ``val`` itself or a new array that
+        nothing else holds: the server keeps one that owns its data as
+        the round's accumulator without copying it."""
         return _generic_decompress(tag, val, aux, orig_len)
 
     def compress_pull(self, tag: str, arr: np.ndarray, factor: int):
@@ -275,9 +328,11 @@ class BSCCompressor(Compressor):
             self._u[state_key] = np.zeros(arr.size, dtype=np.float32)
             self._v[state_key] = np.zeros(arr.size, dtype=np.float32)
         with self._rng_lock:
-            values, indices = bsc_compress(
-                arr.astype(np.float32), self._u[state_key],
-                self._v[state_key], self.threshold, self._rng)
+            positions = bsc_sample_positions(arr.size, self.threshold,
+                                             self._rng)
+        values, indices = bsc_compress(
+            np.asarray(arr, dtype=np.float32), self._u[state_key],
+            self._v[state_key], self.threshold, positions=positions)
         return values, indices, "bsc"
 
     def compress_pull(self, tag, arr, factor):

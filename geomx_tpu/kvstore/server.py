@@ -55,6 +55,7 @@ import logging
 import pickle
 import sys
 import threading
+import time
 from typing import Callable, Dict, List, Optional, Tuple
 
 import numpy as np
@@ -668,7 +669,7 @@ class KVStoreDistServer:
         """One (key, shard-offset) entry of a data request (the loop body
         of :meth:`_handle_data`)."""
         if req.push:
-            val = np.asarray(kvs.vals[i]).ravel()
+            wire = val = np.asarray(kvs.vals[i]).ravel()
             if kvs.compr:
                 with profiler.scope(f"decompress:{kvs.compr}",
                                     cat="kvstore.op") if tagging \
@@ -676,6 +677,9 @@ class KVStoreDistServer:
                     val = self.gc.decompress_push(
                         kvs.compr, val, kvs.aux[i],
                         kvs.len_of(i) or val.size)
+            # an array the decompressor built belongs to this handler;
+            # the message's own buffer (or a view of anything) does not
+            owned = val is not wire and val.flags.owndata
             total = total or val.size
             with self._lock:
                 self._key_total[key] = max(self._key_total.get(key, 0),
@@ -688,7 +692,8 @@ class KVStoreDistServer:
                 with st.lock:
                     acts += self._push_local_store(req, srv, key, off,
                                                    val, total,
-                                                   wire_compr=kvs.compr)
+                                                   wire_compr=kvs.compr,
+                                                   owned=owned)
         elif req.pull:
             length = kvs.len_of(i)
             aux = kvs.aux[i] if i < len(kvs.aux) else None
@@ -707,7 +712,8 @@ class KVStoreDistServer:
     # ------------------------------------------------------------------
 
     def _push_local_store(self, req, srv, key, off, val, total,
-                          wire_compr: str = "") -> List[Action]:
+                          wire_compr: str = "",
+                          owned: bool = False) -> List[Action]:
         st = self._state(key, off)
         if req.head != DATA_INIT:
             # remember the wire codec this round's gradients travel with
@@ -749,7 +755,12 @@ class KVStoreDistServer:
         # released) when the kernels library is available, so concurrent
         # keys aggregate in parallel under their per-state locks
         if not st.push_reqs:
-            st.merged = val.astype(np.float32, copy=True)
+            # later pushes of the round accumulate into st.merged in
+            # place: it must be this state's alone, so ``val`` is taken
+            # as it is only where the handler owns it (``owned``)
+            take = (owned and val.dtype == np.float32
+                    and val.flags.c_contiguous and val.flags.writeable)
+            st.merged = val if take else val.astype(np.float32, copy=True)
         else:
             v32 = np.ascontiguousarray(val, dtype=np.float32)
             if not kernels_native.acc(st.merged, v32):
@@ -813,7 +824,10 @@ class KVStoreDistServer:
         # last weights; the reference's store_ dual-use at :519 is exactly
         # what let a pull observe the gradient) and open a new cycle; worker
         # acks defer until THIS cycle's pull-back lands fresh params
-        st.outbound = payload.astype(st.dtype)
+        # (no copy where the dtype already fits: the next round REBINDS
+        # st.merged at its first push and never writes into this array,
+        # so st.outbound stays the bytes a WAN retry re-slices)
+        st.outbound = payload.astype(st.dtype, copy=False)
         st.staging = True
         st.cycle += 1
         cyc = st.cycle
@@ -1340,9 +1354,15 @@ class KVStoreDistServer:
         the result in ``st.fwd_wire`` — a WAN retry must resend the
         SAME bytes, never re-encode."""
         tag = self._wan_wire_tag(st, int(sub.size))
-        if not tag:
-            return self.gc.compress_push(sub, (key, lo))
+        t0 = time.perf_counter()
         wv, aux, t = self.gc.compress_push(sub, (key, lo))
+        if t == "bsc":
+            # the party server's Bi-Sparse re-selection, host numpy
+            telemetry.counter_inc("server.bsc_select_ms",
+                                  1e3 * (time.perf_counter() - t0),
+                                  tier="global")
+        if not tag:
+            return wv, aux, t
         if t == "bsc":
             # keep the selection (its momentum/residual state already
             # advanced); only the values narrow on the wire

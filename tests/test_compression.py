@@ -16,6 +16,8 @@ from geomx_tpu.compression import (
     bsc_compress,
     bsc_decompress,
     bsc_pull_compress,
+    bsc_sample_boundary,
+    bsc_sample_positions,
     make_compressor,
     two_bit_dequantize,
     two_bit_quantize,
@@ -63,6 +65,48 @@ def test_bsc_momentum_correction_matches_reference_recurrence():
     values, _ = bsc_compress(g2, u, v, threshold=1.0)
     # after reset u==0: u = 0*0.9+1 = 1, v = 0+1 = 1
     np.testing.assert_allclose(values, np.ones(n), rtol=1e-6)
+
+
+@pytest.mark.parametrize("threshold", [0.01, 0.1])
+@pytest.mark.parametrize("n", [1, 768, 2_000, 1_000_000])
+def test_bsc_sample_boundary_draw(n, threshold):
+    """The boundary sample: distinct positions in range, 0.5% of n but at
+    least ceil(10/threshold) and never more than n (reference:
+    gradient_compression.cc:203-212); and at a million elements the
+    sampled boundary brackets the exact top-k one."""
+    rng = np.random.default_rng(7)
+    pos = bsc_sample_positions(n, threshold, rng)
+    documented = min(max(int(n * 0.005), int(np.ceil(10 / threshold))), n)
+    assert pos.size == documented
+    assert np.unique(pos).size == pos.size
+    assert pos.min() >= 0 and pos.max() < n
+    if n == 1_000_000:
+        v = np.random.default_rng(11).normal(size=n).astype(np.float32)
+        boundary = bsc_sample_boundary(v, threshold, rng)
+        mag = np.sort(np.abs(v))[::-1]
+        k = int(n * threshold)
+        assert mag[2 * k - 1] <= boundary <= mag[k // 2 - 1]
+
+
+def test_bsc_compress_push_allocates_no_index_of_every_position():
+    """Steady state (the key's u/v exist): the pass may not build an
+    O(n) int64 array, nor dense float32 copies of the key. The
+    permutation of all positions alone was 8n bytes."""
+    import tracemalloc
+
+    n = 4_000_000
+    grad = np.random.default_rng(3).normal(size=n).astype(np.float32)
+    gc = BSCCompressor(threshold=0.01)
+    gc.compress_push(grad, "k")
+    tracemalloc.start()
+    try:
+        start = tracemalloc.get_traced_memory()[0]
+        values, indices, tag = gc.compress_push(grad, "k")
+        peak = tracemalloc.get_traced_memory()[1]
+    finally:
+        tracemalloc.stop()
+    assert tag == "bsc" and 0 < values.size <= n // 100
+    assert peak - start < 4 * n, (peak - start) / n
 
 
 def test_bsc_pull_compress_keeps_nonzeros():

@@ -7,6 +7,8 @@ aggregated gradient's exact nonzero set. Semantics must equal a dense
 push of the scattered selection.
 """
 
+import threading
+
 import numpy as np
 import pytest
 
@@ -194,3 +196,88 @@ def test_push_pull_bsc_batch_matches_two_op(sharded):
     for w in (0, 1):
         np.testing.assert_allclose(results[w][0], e0)
         np.testing.assert_allclose(results[w][1], e1)
+
+
+@pytest.mark.parametrize("server_compression", [
+    None, {"type": "bsc", "threshold": 1.0}], ids=["dense_wan", "bsc_wan"])
+def test_wan_retry_resends_the_same_bytes(server_compression):
+    """Two workers a party, and party 0's first WAN forward is given up
+    on (as the resender reports it): the per-slice retry of the SAME
+    cycle must carry the bytes of the first attempt, and the aggregate
+    must be the numpy sum. The party server keeps the array its
+    decompressor built as the round's accumulator and stages it for the
+    WAN without copying it; the second worker's push adds into it in
+    place; a retry that re-encoded, or a forward that saw a later write,
+    would show here."""
+    sizes = {0: 40, 1: 24}
+    topo = InProcessHiPS(num_parties=2, workers_per_party=2).start()
+    srv = next(s for s in topo.servers if s.worker_global is not None)
+    wg, van = srv.worker_global, srv.po_global.van
+    real_push, real_send = wg.push, van.send
+    forwards = []          # per WAN push: {(key, lo): (vals, aux) bytes}
+    drop = []
+
+    def push(kvs, rank, **kw):
+        forwards.append({
+            (k, kvs.offset_of(i)): (
+                np.asarray(kvs.vals[i]).tobytes(),
+                None if kvs.aux[i] is None
+                else np.asarray(kvs.aux[i]).tobytes())
+            for i, k in enumerate(kvs.keys)})
+        if len(forwards) == 1:
+            drop.append(True)
+        return real_push(kvs, rank, **kw)
+
+    def send(msg):
+        if drop:
+            drop.clear()
+            threading.Thread(
+                target=van.give_up_handler,
+                args=(msg, RuntimeError, "dropped by the test"),
+                daemon=True).start()
+            return
+        real_send(msg)
+
+    wg.push, van.send = push, send
+    rng = np.random.default_rng(5)
+    sels = [{k: (rng.integers(1, 9, 4).astype(np.float32),
+                 rng.choice(n, 4, replace=False).astype(np.int64))
+             for k, n in sizes.items()} for _ in range(4)]
+    results = {}
+    try:
+        def master_init(kv):
+            if server_compression:
+                kv.set_gradient_compression(dict(server_compression))
+            for k, n in sizes.items():
+                kv.init(k, np.zeros(n, np.float32))
+            kv.wait()
+
+        def worker(kv):
+            widx = topo.workers.index(kv)
+            for k, n in sizes.items():
+                kv.init(k, np.zeros(n, np.float32))
+                kv.pull(k, out=np.zeros(n, np.float32))
+            kv.wait()
+            agg = kv.push_pull_bsc_batch(
+                list(sizes), [sels[widx][k][0] for k in sizes],
+                [sels[widx][k][1] for k in sizes])()
+            results[widx] = {}
+            for k, n in sizes.items():
+                results[widx][k] = np.zeros(n, np.float32)
+                avals, aidx = agg[k]
+                results[widx][k][aidx] = avals
+
+        _run_workers(topo, worker, master_init)
+    finally:
+        topo.stop()
+
+    first, resent = forwards[0], {}
+    for f in forwards[1:]:
+        resent.update(f)
+    assert len(first) == len(sizes) and resent == first
+    for k, n in sizes.items():
+        expect = np.zeros(n, np.float32)
+        for sel in sels:
+            np.add.at(expect, sel[k][1], sel[k][0])
+        for widx in range(4):
+            np.testing.assert_array_equal(results[widx][k], expect)
