@@ -1,23 +1,23 @@
-"""Mixture-of-Experts FFN with expert parallelism over the "ep" axis.
+"""Mixture-of-Experts FFN: a no-drop sparse dispatch, and a top-1 block
+with expert parallelism over the "ep" axis.
 
 Beyond the reference (its op set predates MoE; SURVEY.md §2.3 — the
-rubric's EP axis). TPU-first design: expert weights are STACKED along a
-leading expert dimension and sharded ``P("ep", ...)``; dispatch/combine
-are einsums against the router's one-hot assignment, so GSPMD inserts
-the expert-parallel collectives (all-to-all / reduce-scatter patterns)
-from the shardings alone — no hand-written routing transport.
+rubric's EP axis). Expert weights are STACKED along a leading expert
+dimension and sharded ``P("ep", ...)``.
 
-Documented divergence from capacity-factor MoE systems: every expert
-computes every token and the router mask zeroes non-selected outputs
-("dense dispatch"). That keeps shapes static (XLA-friendly, no token
-dropping) at the cost of E-times FFN FLOPs — the EXPERT-PARALLEL
-sharding story (weights + compute split over "ep") is identical, which
-is what the EP axis is about; capacity-based sparse dispatch is a
-host-level optimization layered later.
+:func:`sparse_dispatch` is the one dispatch: the (row, slot) pairs a
+top-k router chose are sorted by expert, the expert FFN runs as grouped
+matmuls over the group sizes (``jax.lax.ragged_dot``), the rows are
+unsorted and combined with the router's weights. Work follows the rows
+routed, shapes stay static, no capacity factor drops a token. It takes
+``k`` from its inputs and the contiguous range of experts held here as
+an argument: one rank of an expert-parallel layout computes its own
+experts' terms and nothing for the others. ``models/olmoe.py`` is its
+top-8 user, :class:`MoEBlock` its ``k = 1`` case.
 
-Router: top-1 (Switch-style) with optional jitter noise and the
-standard load-balancing auxiliary loss (mean fraction x mean gate per
-expert, scaled by E).
+:class:`MoEBlock` router: top-1 (Switch-style) with optional jitter
+noise and the standard load-balancing auxiliary loss (mean fraction x
+mean gate per expert, scaled by E).
 """
 
 from __future__ import annotations
@@ -29,7 +29,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-__all__ = ["MoEBlock", "moe_param_sharding", "is_expert_param"]
+__all__ = ["MoEBlock", "moe_param_sharding", "is_expert_param",
+           "sparse_dispatch"]
 
 # leaf names of expert-stacked params (leading axis = expert dim)
 EXPERT_PARAM_NAMES = ("w_up", "b_up", "w_dn", "b_dn")
@@ -45,6 +46,65 @@ def expert_spec(ndim: int) -> P:
     """PartitionSpec for an expert-stacked leaf: experts over "ep",
     everything else replicated."""
     return P(*(["ep"] + [None] * (ndim - 1)))
+
+
+@jax.custom_vjp
+def _take_rows(x, fwd_idx, bwd_idx):
+    """``x[fwd_idx]`` for a permutation and its inverse: the cotangent
+    is a gather too (``g[bwd_idx]``), never a scatter-add."""
+    return x[fwd_idx]
+
+
+def _take_rows_fwd(x, fwd_idx, bwd_idx):
+    return x[fwd_idx], bwd_idx
+
+
+def _take_rows_bwd(bwd_idx, g):
+    return g[bwd_idx], None, None
+
+
+_take_rows.defvjp(_take_rows_fwd, _take_rows_bwd)
+
+
+def sparse_dispatch(h, expert_idx, gates, expert_fn, local_experts):
+    """No-drop top-k dispatch to a contiguous range of experts.
+
+    ``h`` [N, D] rows, ``expert_idx`` [N, k] the experts each row chose
+    (ids of the whole router), ``gates`` [N, k] their weights,
+    ``local_experts`` (lo, hi) the experts held here. The (row, slot)
+    pairs are sorted by expert, held experts first and in order, so
+    that ``expert_fn(rows [N*k, D], group_sizes [hi-lo], row_expert
+    [N*k]) -> [N*k, D_out]`` sees each expert's rows contiguous (what
+    ``jax.lax.ragged_dot`` wants); the pairs of experts held elsewhere
+    sort last, past the sum of the group sizes, are zero on the way in
+    and on the way out and cost shape, not arithmetic. Shapes are
+    static, nothing is dropped whatever the routing. Returns the rows'
+    ``sum_slot gate * expert(row)`` over the held experts, [N, D_out],
+    and the group sizes.
+    """
+    lo, hi = local_experts
+    held_n = hi - lo
+    n, k = expert_idx.shape
+    with jax.named_scope("dispatch"):
+        e = expert_idx.reshape(-1) - lo
+        slot = jnp.where((e >= 0) & (e < held_n), e, held_n)
+        order = jnp.argsort(slot, stable=True)
+        inverse = jnp.zeros_like(order).at[order].set(
+            jnp.arange(n * k, dtype=order.dtype))
+        row_expert = slot[order]
+        group_sizes = jnp.bincount(slot, length=held_n + 1)[
+            :held_n].astype(jnp.int32)
+        held = (row_expert < held_n)[:, None]
+        rows = _take_rows(jnp.repeat(h, k, axis=0), order, inverse)
+        # a select, not a product: what a grouped matmul leaves past its
+        # groups need not be a number, in either direction
+        rows = jnp.where(held, rows, jnp.zeros((), rows.dtype))
+    out = expert_fn(rows, group_sizes, row_expert)
+    with jax.named_scope("combine"):
+        out = jnp.where(held, out, jnp.zeros((), out.dtype))
+        out = _take_rows(out, inverse, order).reshape(n, k, -1)
+        y = jnp.einsum("nk,nkd->nd", gates.astype(out.dtype), out)
+    return y, group_sizes
 
 
 class MoEBlock(nn.Module):
@@ -82,7 +142,8 @@ class MoEBlock(nn.Module):
                  E * jnp.sum(frac_tokens * frac_gates))
 
         # expert-stacked MLP params: [E, D, H] / [E, H, D] — shard the
-        # leading axis over "ep" (moe_param_sharding)
+        # leading axis over "ep" (moe_param_sharding); GSPMD partitions
+        # the grouped matmuls from the shardings
         w_up = self.param("w_up", nn.initializers.lecun_normal(),
                           (E, D, H), jnp.float32).astype(dt)
         b_up = self.param("b_up", nn.initializers.zeros,
@@ -92,14 +153,20 @@ class MoEBlock(nn.Module):
         b_dn = self.param("b_dn", nn.initializers.zeros,
                           (E, D), jnp.float32).astype(dt)
 
-        # dense dispatch: every expert runs every token; the einsum over
-        # E contracts against the router mask, and with w_* sharded over
-        # "ep" GSPMD turns this into expert-parallel compute + a psum
-        he = jnp.einsum("btd,edh->ebth", h, w_up) + b_up[:, None, None]
-        he = nn.gelu(he)
-        ye = jnp.einsum("ebth,ehd->ebtd", he, w_dn) + b_dn[:, None, None]
-        mask = (onehot * gate_val[..., None]).astype(dt)  # [B, T, E]
-        y = jnp.einsum("bte,ebtd->btd", mask, ye)
+        # top-1 is the k=1 case of the no-drop sparse dispatch: rows
+        # sorted by expert, grouped matmuls over the group sizes, so
+        # the work follows the rows routed and not E times the tokens
+        def experts(rows, group_sizes, row_expert):
+            he = jax.lax.ragged_dot(rows, w_up, group_sizes) \
+                + b_up[row_expert]
+            return jax.lax.ragged_dot(nn.gelu(he), w_dn, group_sizes) \
+                + b_dn[row_expert]
+
+        B, T, _ = h.shape
+        y, _sizes = sparse_dispatch(
+            h.reshape(B * T, D), expert_idx.reshape(B * T, 1),
+            gate_val.reshape(B * T, 1), experts, (0, E))
+        y = y.reshape(B, T, D)
         return x + y.astype(x.dtype)
 
 

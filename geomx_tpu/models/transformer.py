@@ -53,16 +53,23 @@ def make_attention(impl: str = "auto", *, causal: bool = True,
     raise ValueError(f"unknown attention impl {impl!r}")
 
 
-def dense_attention(q, k, v, *, causal: bool = True):
-    """Plain attention fallback (single-device / no sp axis)."""
+def dense_attention(q, k, v, *, causal: bool = True, scores_dtype=None):
+    """Plain attention fallback (single-device / no sp axis).
+
+    ``scores_dtype`` (e.g. float32 under bfloat16 operands) is the type
+    the QK^T product accumulates into and the softmax runs in: logits of
+    a few units, as q/k norms make them, lose their softmax to an 8-bit
+    significand. ``None``: the operands' own type."""
     d = q.shape[-1]
-    s = jnp.einsum("bqhd,bkhd->bhqk", q, k) / jnp.sqrt(d).astype(q.dtype)
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k,
+                   preferred_element_type=scores_dtype)
+    s = s / jnp.sqrt(d).astype(s.dtype)
     if causal:
         t = q.shape[1]
         mask = jnp.tril(jnp.ones((t, t), bool))
         s = jnp.where(mask[None, None], s, -1e30)
     p = jax.nn.softmax(s, axis=-1)
-    return jnp.einsum("bhqk,bkhd->bqhd", p, v)
+    return jnp.einsum("bhqk,bkhd->bqhd", p.astype(v.dtype), v)
 
 
 class Block(nn.Module):

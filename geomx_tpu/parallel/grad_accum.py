@@ -19,7 +19,8 @@ __all__ = ["accumulate_gradients"]
 
 
 def accumulate_gradients(grad_fn: Callable, num_microbatches: int, *,
-                         axis_name: Optional[str] = None) -> Callable:
+                         axis_name: Optional[str] = None,
+                         has_aux: bool = False) -> Callable:
     """Wrap ``grad_fn(params, *batch) -> (loss, grads)`` into
     ``fn(params, *batch) -> (mean_loss, mean_grads)`` where every batch
     array carries a leading batch dim divisible by ``num_microbatches``
@@ -32,6 +33,11 @@ def accumulate_gradients(grad_fn: Callable, num_microbatches: int, *,
     With ``axis_name`` the MEAN gradient is additionally ``pmean``-ed
     over that mesh axis (call inside shard_map/pjit), so the collective
     runs once per step, not once per microbatch.
+
+    With ``has_aux`` (``jax.value_and_grad(..., has_aux=True)``),
+    ``grad_fn`` returns ``((loss, aux), grads)`` and so does ``fn``:
+    ``aux`` is a pytree of counts, SUMMED over the microbatches (and
+    ``psum``-ed over ``axis_name``).
     """
     if num_microbatches < 1:
         raise ValueError("num_microbatches must be >= 1")
@@ -51,14 +57,16 @@ def accumulate_gradients(grad_fn: Callable, num_microbatches: int, *,
         def body(carry, xs):
             loss_acc, grads_acc = carry
             loss, grads = grad_fn(params, *xs)
+            loss, aux = loss if has_aux else (loss, None)
             grads_acc = jax.tree_util.tree_map(
                 lambda a, g: a + g.astype(a.dtype), grads_acc, grads)
-            return (loss_acc + loss, grads_acc), None
+            return (loss_acc + loss, grads_acc), aux
 
         zeros = jax.tree_util.tree_map(
             lambda p: jnp.zeros(p.shape, jnp.float32), params)
-        (loss_sum, grads_sum), _ = jax.lax.scan(
+        (loss_sum, grads_sum), aux = jax.lax.scan(
             body, (jnp.zeros((), jnp.float32), zeros), split)
+        aux = jax.tree_util.tree_map(lambda a: a.sum(0), aux)
         n = jnp.float32(num_microbatches)
         loss = loss_sum / n
         grads = jax.tree_util.tree_map(
@@ -66,6 +74,8 @@ def accumulate_gradients(grad_fn: Callable, num_microbatches: int, *,
         if axis_name is not None:
             loss = jax.lax.pmean(loss, axis_name)
             grads = jax.lax.pmean(grads, axis_name)
-        return loss, grads
+            if has_aux:
+                aux = jax.lax.psum(aux, axis_name)
+        return ((loss, aux), grads) if has_aux else (loss, grads)
 
     return fn

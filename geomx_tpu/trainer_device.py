@@ -51,7 +51,7 @@ from typing import Any, Callable, Dict, List, Sequence, Tuple
 
 import numpy as np
 
-from geomx_tpu import profiler
+from geomx_tpu import profiler, telemetry
 from geomx_tpu.kvstore.frontier import plan_chunks
 
 __all__ = ["DeviceResidentTrainer"]
@@ -65,7 +65,12 @@ class DeviceResidentTrainer:
         """``params``: list of array leaves (key of leaf i =
         ``begin_key + i``); ``grad_fn(leaf_list, X, y) -> (loss,
         grad_leaves)`` must be jit-compatible (it is traced into the
-        fused device step).
+        fused device step). A ``grad_fn`` may carry ``counted``, a pair
+        ``(telemetry counter names, fn)`` with ``fn(leaf_list, X, y) ->
+        (loss, grad_leaves, counts)``, one float32 count per name: the
+        step then runs ``fn``, the counts come down with the loss, in
+        the transfer the round makes anyway, and are booked once a
+        round (exact below 2^24).
 
         The local optimizer is deliberately SGD on the aggregated
         selection: BSC's residual feedback DELIVERS accumulated
@@ -80,6 +85,9 @@ class DeviceResidentTrainer:
         import jax.numpy as jnp
 
         self.kv = kvstore
+        self._aux_names, grad_fn = getattr(grad_fn, "counted",
+                                           ((), grad_fn))
+        self._head = 1 + len(self._aux_names)
         self.begin_key = begin_key
         self.threshold = threshold
         self.learning_rate = learning_rate
@@ -167,8 +175,20 @@ class DeviceResidentTrainer:
         def _grad_cat(flat, X, y):
             lv = [p.reshape(s) for p, s in
                   zip(jnp.split(flat, bounds), shapes)]
-            loss, grads = grad_fn(lv, X, y)
+            loss, grads, *counts = grad_fn(lv, X, y)
+            if counts:
+                # "loss" is from here on the head of the download: the
+                # loss, then the counts. Without counts nothing changes:
+                # the program stays, instruction for instruction, the one
+                # the compile cache already holds
+                loss = jnp.concatenate(
+                    [jnp.reshape(a, (-1,)).astype(jnp.float32)
+                     for a in (loss, *counts)])
             return loss, jnp.concatenate([gg.reshape(-1) for gg in grads])
+
+        def head_of(loss):
+            return loss if self._aux_names else \
+                loss[None].astype(jnp.float32)
 
         def _bsc(loss, g, u, v):
             # BSC: momentum-corrected accumulation, exact per-key top-k
@@ -205,8 +225,7 @@ class DeviceResidentTrainer:
             # single packed INT32 transfer: [loss, vals(K) bitcast i32,
             # idx(K)] — int lanes are denormal-safe (module docstring)
             packed = jnp.concatenate(
-                [jax.lax.bitcast_convert_type(
-                    loss[None].astype(jnp.float32), jnp.int32),
+                [jax.lax.bitcast_convert_type(head_of(loss), jnp.int32),
                  jax.lax.bitcast_convert_type(vals, jnp.int32),
                  idx])
             return packed, u, v
@@ -336,7 +355,10 @@ class DeviceResidentTrainer:
                 gs, new_res = qc.ring_all_reduce(
                     gl, res[0], size=psize, axis_name="dp",
                     codec=mesh_codec, block=mesh_block, threshold=thr)
-                loss = jax.lax.psum(loss, "dp") / psize
+                loss = jax.lax.psum(loss, "dp")
+                # the loss is the ranks' mean, the counts their sum
+                loss = loss.at[0].divide(psize) if self._aux_names \
+                    else loss / psize
                 return loss, gs / psize, new_res[None]
 
             mesh_grad = jax.shard_map(
@@ -354,8 +376,7 @@ class DeviceResidentTrainer:
                 loss, vals, idx, u, v, res = select_q(flat, u, v,
                                                       X, y, res)
                 packed = jnp.concatenate(
-                    [jax.lax.bitcast_convert_type(
-                        loss[None].astype(jnp.float32), jnp.int32),
+                    [jax.lax.bitcast_convert_type(head_of(loss), jnp.int32),
                      jax.lax.bitcast_convert_type(vals, jnp.int32),
                      idx])
                 return packed, u, v, res
@@ -392,6 +413,14 @@ class DeviceResidentTrainer:
         if not self._mesh_quant:
             return
         self._mesh_res = self._zero_mesh_res()
+
+    def _book(self, head: np.ndarray) -> float:
+        """The loss from the head of a download; grad_fn's counts, if
+        any, go to their telemetry counters."""
+        head = np.atleast_1d(head)
+        for name, value in zip(self._aux_names, head[1:]):
+            telemetry.counter_inc(name, float(value))
+        return float(head[0])
 
     def _run_fwd_compress(self, X, y):
         """Run the monolithic device step, advancing (u, v) and — on the
@@ -489,9 +518,10 @@ class DeviceResidentTrainer:
         packed_d = self._run_fwd_compress(X, y)
         # ONE compact device->host transfer (1 + 2K int32 vs total)
         packed = np.asarray(packed_d)
-        loss = float(packed[:1].view(np.float32)[0])
-        vals = packed[1:1 + self._K].view(np.float32)
-        idx = packed[1 + self._K:].astype(np.int64)
+        h = self._head
+        loss = self._book(packed[:h].view(np.float32))
+        vals = packed[h:h + self._K].view(np.float32)
+        idx = packed[h + self._K:].astype(np.int64)
         if self._sparse_wire:
             ups, upi = self._kv_round_sparse(vals, idx)
         else:
@@ -575,7 +605,7 @@ class DeviceResidentTrainer:
                 keys, vlist, ilist, priority=-ci, slice_bytes=0))
         # loss value-fetch rides behind the dispatches (the wire is
         # already flying when this blocks on the device)
-        loss = float(np.asarray(loss_d))
+        loss = self._book(np.asarray(loss_d))
         for ci, fut in enumerate(futs):
             agg = fut.results()
             up = self._chunk_up(ci, agg)
@@ -603,7 +633,7 @@ class DeviceResidentTrainer:
         t0 = time.perf_counter()
         if self._pipeline:
             loss_d, packs = self._run_fwd_chunks(X, y)
-            loss = float(np.asarray(loss_d))   # fences the fwd program
+            loss = self._book(np.asarray(loss_d))   # fences the program
             t1 = time.perf_counter()
             arrs = [np.asarray(p) for p in packs]
             t2 = time.perf_counter()
@@ -623,13 +653,14 @@ class DeviceResidentTrainer:
                     self._flat, self._mom, up_d, flo, fsize)
         else:
             packed_d = self._run_fwd_compress(X, y)
-            loss = float(np.asarray(packed_d[0:1])
-                         .view(np.float32)[0])  # value fetch = fence
+            h = self._head
+            loss = self._book(np.asarray(packed_d[0:h])
+                              .view(np.float32))  # value fetch = fence
             t1 = time.perf_counter()
             packed = np.asarray(packed_d)
             t2 = time.perf_counter()
-            vals = packed[1:1 + self._K].view(np.float32)
-            idx = packed[1 + self._K:].astype(np.int64)
+            vals = packed[h:h + self._K].view(np.float32)
+            idx = packed[h + self._K:].astype(np.int64)
             ups, upi = self._kv_round_sparse(vals, idx)
             t3 = time.perf_counter()
             n = len(ups)

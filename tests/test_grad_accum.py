@@ -103,3 +103,25 @@ def test_accum_preserves_param_dtype_and_single_array_batch():
     loss, grad = accumulate_gradients(grad_fn, 4)(w, X)
     assert grad.dtype == jnp.bfloat16
     assert np.isfinite(float(loss))
+
+
+@pytest.mark.parametrize("m", [1, 2, 4])
+def test_accum_sums_the_counts_of_a_grad_fn_with_aux(m):
+    rng = np.random.RandomState(2)
+    w = jnp.asarray(rng.normal(size=(5,)), jnp.float32)
+    X = jnp.asarray(rng.normal(size=(8, 5)), jnp.float32)
+
+    def loss_fn(p, X):
+        out = X @ p
+        return jnp.mean(out ** 2), {"positive": jnp.sum(out > 0),
+                                    "rows": jnp.float32(X.shape[0])}
+
+    grad_fn = jax.value_and_grad(loss_fn, has_aux=True)
+    (full_loss, full_aux), full_grad = grad_fn(w, X)
+    (loss, aux), grad = jax.jit(
+        accumulate_gradients(grad_fn, m, has_aux=True))(w, X)
+    np.testing.assert_allclose(float(loss), float(full_loss), rtol=1e-5)
+    np.testing.assert_allclose(np.asarray(grad), np.asarray(full_grad),
+                               rtol=1e-4, atol=1e-6)
+    assert int(aux["positive"]) == int(full_aux["positive"])
+    assert float(aux["rows"]) == 8.0
