@@ -14,7 +14,11 @@ is drawn in O(sample)). Placement matches the reference: the
 LAN tier is uncompressed; party servers compress the aggregated gradient
 before the WAN push (BSCompress, :191), the global server decompresses,
 aggregates, and compresses pull responses with the non-zero filter scaled
-by the number of global workers (BSCPullCompress, :271).
+by the number of global workers (BSCPullCompress, :271). Where every push
+of a round is Bi-Sparse and the servers only aggregate, nothing is
+decompressed at all: the round's aggregate is ``entries.Entries`` (sorted
+positions, float32 values) from the global server's sum to the party
+server's ack, and the response is its exact non-zero set.
 
 Wire-format divergence from the reference (documented, intentional): the
 reference pads compressed buffers to a fixed size with the placeholder
@@ -34,9 +38,12 @@ from typing import Dict, Optional, Tuple
 
 import numpy as np
 
+from geomx_tpu.compression.entries import Entries, SPARSE_TAGS
+
 __all__ = ["make_compressor", "Compressor", "FP16Compressor", "BSCCompressor",
            "TwoBitCompressor", "MPQCompressor", "bsc_compress", "bsc_decompress",
-           "bsc_pull_compress", "two_bit_quantize", "two_bit_dequantize"]
+           "bsc_pull_compress", "two_bit_quantize", "two_bit_dequantize",
+           "Entries", "SPARSE_TAGS"]
 
 BSC_MOMENTUM = 0.9  # reference: gradient_compression.cc:198
 
@@ -217,7 +224,15 @@ class Compressor:
                         aux: Optional[np.ndarray], orig_len: int) -> np.ndarray:
         """-> the dense push. Either ``val`` itself or a new array that
         nothing else holds: the server keeps one that owns its data as
-        the round's accumulator without copying it."""
+        the round's accumulator without copying it.
+
+        This is the path of a push whose receiver needs every element:
+        a party server's worker pushes (its own Bi-Sparse pass runs on
+        the dense aggregate) and the dense wires. A GLOBAL store does
+        not call it for a ``bsc`` / ``bsc16`` push: it takes the payload
+        as :class:`Entries` (``Entries.from_wire``), which keep the
+        wire's own arrays, and sums index lists; the store is dense
+        again only where someone asks it for an array."""
         return _generic_decompress(tag, val, aux, orig_len)
 
     def compress_pull(self, tag: str, arr: np.ndarray, factor: int):

@@ -168,7 +168,7 @@ class ReplicationManager:
         out: Dict[Tuple[int, int], dict] = {}
         for (key, off), st in items:
             with st.lock:
-                if not st.initialized or st.stored is None:
+                if not st.initialized or not st.has_store:
                     continue
                 # _snap_versions is shared with _apply (restore path) and
                 # guarded by self._lock there; taking it here too keeps

@@ -36,7 +36,14 @@ protocol, re-designed for host-side asynchrony without the MXNet engine:
   over the command channel (CommandType kController);
 - WAN compression (FP16 / BSC / MPQ) applies on the inter-DC hop only:
   party servers compress forwarded aggregates and request compressed pulls;
-  the LAN tier stays uncompressed — matching the reference's placement.
+  the LAN tier stays uncompressed — matching the reference's placement;
+- a Bi-Sparse round stays sparse: an FSA aggregator (no updater, no HFA)
+  whose pushes all arrive as ``bsc`` / ``bsc16`` sums their index lists
+  (``compression.Entries``), stores the sum as entries and answers with
+  them; the party server keeps the pull-back as entries and hands it to
+  its workers. A store is made dense once, by ``_KeyState.stored``, for
+  whoever reads an array. Which of the two a round is follows from the
+  pushes' wire tags and the server's mode, nothing else.
 
 Generalization over the reference: a global server stores its CANONICAL
 RANGES of each key (from the deterministic sharding over the full key
@@ -65,7 +72,7 @@ from geomx_tpu import config as cfg_mod
 from geomx_tpu import kernels_native
 from geomx_tpu import profiler
 from geomx_tpu import telemetry
-from geomx_tpu.compression import make_compressor
+from geomx_tpu.compression import Entries, SPARSE_TAGS, make_compressor
 from geomx_tpu.compression.device import WireCodec
 from geomx_tpu.kvstore import sharding
 from geomx_tpu.kvstore.base import Command, DATA_INIT
@@ -80,6 +87,11 @@ from geomx_tpu.ps.postoffice import Postoffice
 log = logging.getLogger("geomx.server")
 
 Action = Callable[[], None]
+
+
+def _as_array(x) -> np.ndarray:
+    """``x`` dense: a push slice or an aggregate that may be ``Entries``."""
+    return x.dense() if isinstance(x, Entries) else x
 
 
 class _SysModulesUnpickler(pickle.Unpickler):
@@ -167,11 +179,21 @@ class _BatchResponder:
 
 
 class _KeyState:
-    """Per-(key, shard-offset) protocol state (UpdateBuf + store_ entry)."""
+    """Per-(key, shard-offset) protocol state (UpdateBuf + store_ entry).
+
+    The store is EITHER an array or, after a round whose aggregate
+    stayed sparse, :class:`Entries` this state owns (``entries``; the
+    arrays inside are shared with responses already sent and are never
+    written). ``stored`` is the one accessor for whoever needs an array:
+    it makes the entries dense on first use and keeps that array until
+    the store is next replaced, so a dense pull, a snapshot, an updater
+    or a test reads and assigns ``st.stored`` as before. ``merged``, the
+    round in progress on a global store, is likewise an array or
+    ``Entries``. Every access runs under ``lock``."""
 
     __slots__ = (
         "lock",
-        "stored", "outbound", "milestone", "merged", "push_reqs",
+        "_dense", "entries", "outbound", "milestone", "merged", "push_reqs",
         "deferred_acks", "pending_pulls", "initialized", "staging", "rounds",
         "offset", "length", "total", "dtype", "elems_received", "init_elems",
         "fwd_parts", "fwd_expected", "fwd_acks_left", "version", "cycle",
@@ -183,13 +205,14 @@ class _KeyState:
         # every access to this state goes through this lock (RLock: the
         # pre-init replay path re-enters _global_slice_push)
         self.lock = locks.make_rlock("_KeyState.lock")
-        self.stored: Optional[np.ndarray] = None
+        self._dense: Optional[np.ndarray] = None
+        self.entries: Optional[Entries] = None
         # the aggregate staged for the global tier lives here, NEVER in
         # `stored` — `stored` always holds parameters, so a pull can never
         # observe a gradient (the round-1/2 freshness race)
         self.outbound: Optional[np.ndarray] = None
         self.milestone: Optional[np.ndarray] = None
-        self.merged: Optional[np.ndarray] = None
+        self.merged = None
         self.push_reqs: List[Tuple[ReqMeta, KVServer]] = []
         self.deferred_acks: List[Tuple[ReqMeta, KVServer]] = []
         # (req, srv, off, length, compr, aux) — compr/aux retained so a
@@ -245,6 +268,24 @@ class _KeyState:
         # invalidates the cache when the store advances
         self.rsp_wire: Dict = {}
 
+    @property
+    def stored(self) -> Optional[np.ndarray]:
+        if self._dense is None and self.entries is not None:
+            self._dense = self.entries.dense()
+        return self._dense
+
+    @stored.setter
+    def stored(self, value: Optional[np.ndarray]) -> None:
+        self._dense, self.entries = value, None
+
+    def store_entries(self, entries: Entries) -> None:
+        self._dense, self.entries = None, entries
+
+    @property
+    def has_store(self) -> bool:
+        """``stored is not None`` without making the store dense."""
+        return self._dense is not None or self.entries is not None
+
 
 @locks.guarded_by("_lock", "_states", "_key_total", "_stops_received",
                   "_stop_forwarded", "_gb_reqs", "_party_nsrv_by_sender")
@@ -262,6 +303,8 @@ class KVStoreDistServer:
             c = self.cfg = dataclasses.replace(
                 c, p3_slice_bytes=slice_bytes_from_shape(c))
         self.is_global_server = c.is_global_server
+        # telemetry label of this server's own counters: the tier it serves
+        self._tier = "global" if self.is_global_server else "local"
         # party servers forward to the global tier; the global server IS it
         self.has_global_tier = c.has_global_tier and not self.is_global_server
 
@@ -535,7 +578,7 @@ class KVStoreDistServer:
                             >= self._expected_global_elems(st)):
                         acts += self._complete_fsa_round(st, key)
                         released += 1
-                elif (st.stored is not None and st.push_reqs
+                elif (st.has_store and st.push_reqs
                         and not st.staging
                         and len(st.push_reqs)
                         >= self._expected_local_pushes()):
@@ -630,6 +673,7 @@ class KVStoreDistServer:
         # when the profiler runs, each key's state-machine step records
         # its own span so a trace shows WHICH key dominated the round
         tagging = profiler.is_running()
+        t0 = time.perf_counter()
         for i, key in enumerate(kvs.keys):
             off = kvs.offset_of(i)
             total = kvs.total_of(i)
@@ -643,6 +687,11 @@ class KVStoreDistServer:
                 self._handle_one_key(req, kvs, srv, global_store,
                                      global_tier, acts, i, key, off,
                                      total, tagging)
+        if req.push:
+            # wire payload -> aggregate -> (where the round completed)
+            # the responses built; the WAN forward's compress_push runs
+            # in the actions below and is server.bsc_select_ms
+            self._count_aggregate_ms(t0)
         if collect:
             try:
                 for fn in acts:
@@ -670,7 +719,12 @@ class KVStoreDistServer:
         of :meth:`_handle_data`)."""
         if req.push:
             wire = val = np.asarray(kvs.vals[i]).ravel()
-            if kvs.compr:
+            if global_store and kvs.compr in SPARSE_TAGS:
+                # a global store sums index lists (_accumulate): the
+                # payload stays what the wire made it
+                val = Entries.from_wire(val, kvs.aux[i],
+                                        kvs.len_of(i) or val.size)
+            elif kvs.compr:
                 with profiler.scope(f"decompress:{kvs.compr}",
                                     cat="kvstore.op") if tagging \
                         else _null_ctx():
@@ -679,7 +733,8 @@ class KVStoreDistServer:
                         kvs.len_of(i) or val.size)
             # an array the decompressor built belongs to this handler;
             # the message's own buffer (or a view of anything) does not
-            owned = val is not wire and val.flags.owndata
+            owned = (isinstance(val, np.ndarray) and val is not wire
+                     and val.flags.owndata)
             total = total or val.size
             with self._lock:
                 self._key_total[key] = max(self._key_total.get(key, 0),
@@ -722,7 +777,7 @@ class KVStoreDistServer:
             # GEOMX_WIRE_CODEC_WAN policy overrides
             st.push_compr = wire_compr \
                 if wire_compr in ("fp16", "2bit", "bsc16") else ""
-        if st.stored is None:
+        if not st.has_store:
             # init-on-first-push (reference: kvstore_dist_server.h:1241);
             # kv.init marks its pushes DATA_INIT — a gradient should never
             # arrive first (workers init+pull before training)
@@ -799,6 +854,7 @@ class KVStoreDistServer:
                          np.asarray(st.merged, dtype=st.dtype).ravel())
             st.initialized = True
             st.version += 1
+            self._count_key_round(sparse=False)
             return (self._push_round_acks(st, key, reqs)
                     + self._flush_pulls(st, key)
                     + self._offer_local(st, key))
@@ -808,6 +864,7 @@ class KVStoreDistServer:
             # (reference: :1327-1333)
             st.stored = st.merged.astype(st.dtype)
             st.version += 1
+            self._count_key_round(sparse=False)
             return (self._push_round_acks(st, key, reqs)
                     + self._flush_pulls(st, key)
                     + self._offer_local(st, key))
@@ -870,7 +927,7 @@ class KVStoreDistServer:
     def _global_slice_push(self, req, srv, key, rng, lo, sub, total,
                            from_global_tier) -> List[Action]:
         st = self._state(key, rng.offset)
-        if st.stored is None:
+        if not st.has_store:
             st.stored = np.zeros(rng.length, dtype=sub.dtype)
             st.length, st.total = rng.length, total
             st.dtype = sub.dtype
@@ -885,7 +942,8 @@ class KVStoreDistServer:
                 return []
             # initialization pushes fill the canonical range (master worker's
             # init; reference: :1241-1262 + initialized_ flag)
-            st.stored[lo - rng.offset:lo - rng.offset + sub.size] = sub
+            st.stored[lo - rng.offset:lo - rng.offset + sub.size] = \
+                _as_array(sub)
             st.init_elems += sub.size
             acts: List[Action] = [lambda: srv.response(req)]
             if st.init_elems >= st.length:
@@ -926,7 +984,7 @@ class KVStoreDistServer:
             # MixedSync: update per arriving push, no barrier (reference:
             # DataHandleAsyncDefault :1532)
             grad = np.zeros(st.length, dtype=np.float32)
-            grad[lo - rng.offset:lo - rng.offset + sub.size] = sub
+            grad[lo - rng.offset:lo - rng.offset + sub.size] = _as_array(sub)
             st.stored = (self._run_updater(st, (key, rng.offset), grad)
                          if self.updater else st.stored)
             st.version += 1
@@ -960,12 +1018,8 @@ class KVStoreDistServer:
         # kvstore_dist_server.h:1305-1319, which deadlocks for multi-server
         # parties).
         if st.merged is None:
-            st.merged = np.zeros(st.length, dtype=np.float32)
             st.elems_received = 0
-        seg = st.merged[lo - rng.offset:lo - rng.offset + sub.size]
-        sub32 = np.ascontiguousarray(sub, dtype=np.float32)
-        if not kernels_native.acc(seg, sub32):
-            seg += sub32
+        self._accumulate(st, lo - rng.offset, sub)
         # TSEngine final hops carry num_merge parties' worth of gradient in
         # one push (reference counting: kvstore_dist_server.h:1301)
         st.elems_received += sub.size * max(req.num_merge, 1)
@@ -997,6 +1051,51 @@ class KVStoreDistServer:
             return []
         return self._complete_fsa_round(st, key)
 
+    def _keeps_sparse(self, st: _KeyState) -> bool:
+        """Whether this server, as configured, stores a round's aggregate
+        as it is: an FSA aggregator of float32 keys. An updater turns the
+        aggregate into dense weights, HFA adds it to a dense milestone,
+        MixedSync has no round to aggregate."""
+        return (self.updater is None and not self.use_hfa
+                and self.sync_global_mode and st.dtype == np.float32)
+
+    def _accumulate(self, st: _KeyState, rel: int, sub) -> None:
+        """Add one push slice, ``sub`` at position ``rel`` of the state's
+        range, to the round's aggregate ``st.merged``.
+
+        While every slice of the round arrived as ``Entries`` (a
+        Bi-Sparse wire) and the server keeps aggregates sparse, the
+        aggregate is the merge of the index lists. The first dense slice
+        (``""``, ``fp16``, ``2bit``, ``rsp``) makes what the round holds
+        dense, and the round goes on as a dense ``+=``."""
+        if (isinstance(sub, Entries) and self._keeps_sparse(st)
+                and not isinstance(st.merged, np.ndarray)):
+            part = sub.placed(rel, st.length)
+            st.merged = part if st.merged is None else st.merged.add(part)
+            return
+        if st.merged is None:
+            st.merged = np.zeros(st.length, dtype=np.float32)
+        elif isinstance(st.merged, Entries):
+            st.merged = st.merged.dense()
+        seg = st.merged[rel:rel + sub.size]
+        sub32 = np.ascontiguousarray(_as_array(sub), dtype=np.float32)
+        if not kernels_native.acc(seg, sub32):
+            seg += sub32
+
+    def _count_key_round(self, sparse: bool) -> None:
+        """One (key, shard) round completed with its aggregate stored as
+        entries (``sparse``) or as an array."""
+        telemetry.counter_inc(
+            "server.sparse_key_rounds" if sparse
+            else "server.dense_key_rounds", tier=self._tier)
+
+    def _count_aggregate_ms(self, t0: float) -> None:
+        """Host time since ``t0`` spent between a push or a pull-back
+        payload coming off the wire and its responses being built."""
+        telemetry.counter_inc("server.aggregate_ms",
+                              1e3 * (time.perf_counter() - t0),
+                              tier=self._tier)
+
     def _expected_global_elems(self, st) -> int:
         """FSA countdown target in ELEMENTS, sized from the live
         membership view at check time: a party whose servers are
@@ -1022,10 +1121,20 @@ class KVStoreDistServer:
         an epoch bump shrinks the countdown below what already arrived."""
         # global round complete: run the optimizer (reference: :1305-1319)
         st.rounds += 1
-        st.stored = (self._run_updater(st, (key, st.offset), st.merged)
-                     if self.updater else
-                     np.asarray(st.merged, dtype=st.dtype).ravel())
-        st.merged = None
+        merged, st.merged = st.merged, None
+        sparse = (isinstance(merged, Entries) and merged.sparse
+                  and self._keeps_sparse(st))
+        if sparse:
+            # the store of an aggregator IS the round's aggregate; sums
+            # of exactly 0 leave here, once, as the dense non-zero
+            # filter dropped them for every puller
+            st.store_entries(merged.nonzero())
+        else:
+            merged = _as_array(merged)
+            st.stored = (self._run_updater(st, (key, st.offset), merged)
+                         if self.updater else
+                         np.asarray(merged, dtype=st.dtype).ravel())
+        self._count_key_round(sparse)
         st.elems_received = 0
         st.version += 1
         reqs, st.push_reqs = st.push_reqs, []
@@ -1121,7 +1230,13 @@ class KVStoreDistServer:
     def _pull_response_action(self, st: _KeyState, req, srv, key,
                               req_off: int, req_len: int,
                               req_compr: str, aux=None) -> Action:
-        """Build the response closure for one pull against state ``st``."""
+        """Build the response closure for one pull against state ``st``.
+
+        A Bi-Sparse response (``bsc`` / ``bsc16``, no updater) of a
+        store that is ``st.entries`` is built from them in O(entries)
+        and shares their arrays, which the state owns and nobody
+        writes. Every other response reads ``st.stored``, which makes
+        the store dense on first use (``_KeyState``)."""
         if req_compr == "rsp":
             # row-sparse gather (reference: PullRowSparse, kvstore.h:59):
             # aux = row ids, req_len = row length; respond with just those
@@ -1149,20 +1264,8 @@ class KVStoreDistServer:
             hi = min(req_off + req_len, st.offset + st.length)
         else:
             lo, hi = st.offset, st.offset + st.length
-        data = st.stored[lo - st.offset:hi - st.offset]
-        if req_compr == "bsc":
-            if self.updater is not None:
-                # BSC pull-compression assumes the store holds a SPARSE
-                # gradient aggregate (no server-side optimizer — reference
-                # cnn_bsc.py uses a local Trainer); with an updater the
-                # store is dense weights and the non-zero filter would
-                # truncate them. Serve dense.
-                if not getattr(self, "_warned_bsc_dense", False):
-                    self._warned_bsc_dense = True
-                    log.warning("BSC pull-compression disabled: an optimizer "
-                                "is set, the store holds dense weights")
-                req_compr = ""
-            else:
+        if req_compr in SPARSE_TAGS:
+            if self.updater is None:
                 # Aggregator mode: the store holds the round's aggregated
                 # gradient, whose support is bounded by (workers x top-k) —
                 # serve its EXACT nonzero set. Divergence from the
@@ -1171,29 +1274,38 @@ class KVStoreDistServer:
                 # truncating beyond it): our wire carries variable-length
                 # (values, indices), so the lossless superset costs the
                 # same protocol and never drops aggregate entries. Works
-                # with or without a compressor configured.
-                nz = np.nonzero(data)[0]
-                out = KVPairs(keys=[key],
-                              vals=[data[nz].astype(np.float32)],
-                              aux=[nz.astype(np.int32)], offsets=[lo],
-                              totals=[st.total], lens=[hi - lo],
-                              compr="bsc")
+                # with or without a compressor configured. After a sparse
+                # round the store IS that set (st.entries, zeros already
+                # gone): the range's part of it goes out as it is, the
+                # same arrays to every puller; a dense store is filtered.
+                # "bsc16" is the same response with float16 values.
+                if st.entries is not None:
+                    part = st.entries[lo - st.offset:hi - st.offset]
+                    idx, vals = part.idx, part.vals
+                else:
+                    data = st.stored[lo - st.offset:hi - st.offset]
+                    idx = np.nonzero(data)[0]
+                    vals = data[idx]
+                out = KVPairs(
+                    keys=[key],
+                    vals=[vals.astype(np.float32 if req_compr == "bsc"
+                                      else np.float16, copy=False)],
+                    aux=[idx.astype(np.int32, copy=False)], offsets=[lo],
+                    totals=[st.total], lens=[hi - lo], compr=req_compr)
                 return lambda: srv.response(req, out)
-        if req_compr == "bsc16":
-            # quantized combined wire: the "bsc" exact-nonzeros response
-            # with float16 values. Same dense-downgrade rule: an updater
-            # means the store holds dense weights, where the non-zero
-            # filter truncates — serve dense fp16 instead (still narrow)
-            if self.updater is not None:
-                req_compr = "fp16"
-            else:
-                nz = np.nonzero(data)[0]
-                out = KVPairs(keys=[key],
-                              vals=[data[nz].astype(np.float16)],
-                              aux=[nz.astype(np.int32)], offsets=[lo],
-                              totals=[st.total], lens=[hi - lo],
-                              compr="bsc16")
-                return lambda: srv.response(req, out)
+            # Bi-Sparse pull-compression assumes the store holds a SPARSE
+            # gradient aggregate (no server-side optimizer — reference
+            # cnn_bsc.py uses a local Trainer); with an updater the store
+            # is dense weights and the non-zero filter would truncate
+            # them. Serve dense: raw for "bsc", fp16 (still narrow) for
+            # the quantized combined wire's "bsc16".
+            if req_compr == "bsc" \
+                    and not getattr(self, "_warned_bsc_dense", False):
+                self._warned_bsc_dense = True
+                log.warning("BSC pull-compression disabled: an optimizer "
+                            "is set, the store holds dense weights")
+            req_compr = "" if req_compr == "bsc" else "fp16"
+        data = st.stored[lo - st.offset:hi - st.offset]
         if req_compr == "2bit":
             # threshold codes carry GRADIENT sign/magnitude with error
             # feedback; against an updater's dense weights they would
@@ -1493,6 +1605,7 @@ class KVStoreDistServer:
         # final decrement every other rank's callback has already
         # applied its part, so completion sees the full set
         resps = self.worker_global.take_response(ts)
+        t0 = time.perf_counter()
         # a key can appear several times in one batch (P3 slicing gives
         # one (key, off) state per slice): route each response entry to
         # every item of that key whose slice range overlaps the data
@@ -1508,12 +1621,7 @@ class KVStoreDistServer:
                 r_off = kvs.offset_of(i)
                 match = next((c for c in cands if c[3] == r_off),
                              cands[0])
-                data = np.asarray(kvs.vals[i]).ravel()
-                if kvs.compr:
-                    data = self.gc.decompress_pull(
-                        kvs.compr, data, kvs.aux[i],
-                        kvs.len_of(i) or match[4] - match[3],
-                        self._pull_compress_factor())
+                data = self._pull_payload(kvs, i, match[4] - match[3])
                 for it in cands:
                     key, off, cycle, lo, hi, total, _v, _a = it
                     lo2 = max(lo, r_off)
@@ -1542,10 +1650,26 @@ class KVStoreDistServer:
                     # our server but a legal wire state; fall back to an
                     # explicit batched pull (resets part accounting)
                     need_pull.append((key, off, cycle))
+        self._count_aggregate_ms(t0)
         for fn in acts:
             fn()
         if need_pull:
             self._global_pull_batch(need_pull)
+
+    def _pull_payload(self, kvs: KVPairs, i: int, length: int):
+        """Entry ``i`` of a global-tier response: ``Entries`` where it
+        came on a Bi-Sparse wire (what the global server sent is kept,
+        not scattered into a dense key to be filtered again for the
+        workers), else the dense array."""
+        data = np.asarray(kvs.vals[i]).ravel()
+        if kvs.compr in SPARSE_TAGS:
+            return Entries.from_wire(data, kvs.aux[i],
+                                     kvs.len_of(i) or length)
+        if kvs.compr:
+            return self.gc.decompress_pull(
+                kvs.compr, data, kvs.aux[i], kvs.len_of(i) or length,
+                self._pull_compress_factor())
+        return data
 
     def _global_pull_batch(self, ready) -> None:
         per_rank: Dict[Tuple[int, str], List[tuple]] = {}
@@ -1582,6 +1706,7 @@ class KVStoreDistServer:
                                   cycle, g_rank, lo, hi, total)
             return
         resps = self.worker_global.take_response(ts)
+        t0 = time.perf_counter()
         # route each response entry to its (key, off) slice; a key can
         # appear several times in one batch (P3 slicing gives one
         # (key, off) state per slice), so match by range overlap
@@ -1597,12 +1722,7 @@ class KVStoreDistServer:
                 r_off = kvs.offset_of(i)
                 match = next((c for c in cands if c[3] == r_off),
                              cands[0])
-                data = np.asarray(kvs.vals[i]).ravel()
-                if kvs.compr:
-                    data = self.gc.decompress_pull(
-                        kvs.compr, data, kvs.aux[i],
-                        kvs.len_of(i) or match[4] - match[3],
-                        self._pull_compress_factor())
+                data = self._pull_payload(kvs, i, match[4] - match[3])
                 for it in cands:
                     key, off, cycle, lo, hi, total = it
                     lo2 = max(lo, r_off)
@@ -1617,6 +1737,7 @@ class KVStoreDistServer:
                         if (len(st.fwd_parts) >= st.fwd_expected
                                 and st.fwd_expected > 0):
                             acts += self._complete_global_round(st, key)
+        self._count_aggregate_ms(t0)
         for fn in acts:
             fn()
 
@@ -1810,6 +1931,7 @@ class KVStoreDistServer:
             return
         # drain the tracker even when the cycle guard discards the data
         resps = self.worker_global.take_response(ts)
+        t0 = time.perf_counter()
         acts: List[Action] = []
         st = self._state(key, off)
         with st.lock:
@@ -1817,29 +1939,38 @@ class KVStoreDistServer:
                 return
             for kvs in resps:
                 for i, _k in enumerate(kvs.keys):
-                    data = np.asarray(kvs.vals[i]).ravel()
-                    if kvs.compr:
-                        data = self.gc.decompress_pull(
-                            kvs.compr, data, kvs.aux[i], kvs.len_of(i) or hi - lo,
-                            self._pull_compress_factor())
+                    data = self._pull_payload(kvs, i, hi - lo)
                     r_off = kvs.offset_of(i)
                     lo2 = max(lo, r_off)
                     hi2 = min(hi, r_off + data.size)
                     st.fwd_parts[lo2] = data[lo2 - r_off:hi2 - r_off]
             if len(st.fwd_parts) >= st.fwd_expected and st.fwd_expected > 0:
                 acts = self._complete_global_round(st, key)
+        self._count_aggregate_ms(t0)
         for fn in acts:
             fn()
 
     def _complete_global_round(self, st: _KeyState, key: int) -> List[Action]:
-        assembled = np.concatenate(
-            [st.fwd_parts[o] for o in sorted(st.fwd_parts)]).astype(np.float32)
+        parts = [st.fwd_parts[o] for o in sorted(st.fwd_parts)]
         st.fwd_parts = {}
         st.fwd_expected = 0
+        sparse = (self._keeps_sparse(st)
+                  and all(isinstance(p, Entries) for p in parts))
+        if sparse:
+            # what the global server sent IS the new store: the slices
+            # joined by offset, no dense key rebuilt to be filtered again
+            assembled = Entries.concat(parts)
+            sparse = assembled.sparse
+        if not sparse:
+            assembled = np.concatenate(
+                [_as_array(p) for p in parts]).astype(np.float32)
         if assembled.size != st.length:
             log.warning("assembled %d elems for key %d shard of %d",
                         assembled.size, key, st.length)
-        if self.use_hfa and st.milestone is not None:
+        self._count_key_round(sparse)
+        if sparse:
+            st.store_entries(assembled)
+        elif self.use_hfa and st.milestone is not None:
             # stored = milestone + pulled delta; milestone follows
             # (reference: :993-998)
             st.stored = (st.milestone + assembled).astype(st.dtype)
