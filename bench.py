@@ -916,7 +916,7 @@ def bench_transformer_bsc(threshold: float = 0.01, rounds: int = 30,
                           B: int = 8, T: int = 512):
     """The 59M-param transformer through LIVE HiPS + BSC device-resident
     (the path chip_smoke.py proves): params stay on the chip, the
-    LAN hop carries the element-sparse selection (push_bsc/pull_bsc).
+    LAN hop carries the element-sparse selection (push_pull_bsc_batch_async).
     Reports steady tokens/s and the loss curve (must decline)."""
     import jax.numpy as jnp
 

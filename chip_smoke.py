@@ -292,8 +292,7 @@ def _inspect_mesh(tr, party: int, batch) -> dict:
             raise RuntimeError(f"party {party}: {name} on devices {got}, "
                                f"expected {want}")
     X, y = tr._place_batch(batch, None)
-    step = tr._fwd_chunks if tr._pipeline else tr._fwd_compress
-    hlo = step.lower(tr._flat, tr._u, tr._v, X, y).compile().as_text()
+    hlo = tr._fwd_chunks.lower(tr._flat, tr._u, tr._v, X, y).compile().as_text()
     # replica_groups prints as {{0,1}} or, in iota form, [groups,size]<=[n]
     groups = sorted(set(re.findall(
         r"all-reduce[^\n]*?replica_groups="

@@ -5,7 +5,9 @@ The round-4 flagship config: the 59M-param decoder-only transformer
 (the bench model) trains through ``DeviceResidentTrainer`` — parameters
 never leave the chip; the host<->device link and the LAN hop carry only
 the per-tensor BSC top-k selection down and the aggregated nonzeros up
-(KVStoreDist.push_bsc / pull_bsc element-sparse wire).
+(the store's one sparse round, push_pull_bsc_batch_async: a combined
+element-sparse message per server on KVStoreDist, the selection itself
+on the --local store).
 
 Reference lineage: examples/cnn_bsc.py's aggregator-PS + worker-side
 optimizer semantics (reference: examples/cnn_bsc.py:37-60), applied to

@@ -293,10 +293,6 @@ class Config:
     # -1 = auto-size to the shaped topology's worst-link BDP
     # (frontier.auto_slice_bytes over GEOMX_SHAPE_PLAN).
     p3_slice_bytes: int = 0             # P3_SLICE_BYTES
-    # trainer-side overlap switch: per-chunk dispatch/apply in
-    # DeviceResidentTrainer and the deferred round barrier in Trainer
-    # (the barrier moves to the point of first use, not away)
-    overlap: bool = True                # GEOMX_OVERLAP
 
     # ---- mesh-party tier (ours; docs/mesh-party.md) ----
     # form a GSPMD party mesh over the local devices and aggregate
@@ -455,7 +451,6 @@ def load() -> Config:
         barrier_timeout_s=env_float("PS_BARRIER_TIMEOUT", 600.0),
         op_timeout_s=env_float("PS_OP_TIMEOUT", 300.0),
         p3_slice_bytes=env_int("P3_SLICE_BYTES", 0),
-        overlap=env_bool("GEOMX_OVERLAP", True),
         party_mesh=env_bool("GEOMX_PARTY_MESH"),
         party_mesh_size=env_int("GEOMX_PARTY_MESH_SIZE", 0),
         mesh_codec=env_str("GEOMX_MESH_CODEC", "none"),

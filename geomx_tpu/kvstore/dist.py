@@ -392,14 +392,7 @@ class KVStoreDist(KVStore):
                 kvs.totals.append(sh.total)
                 kvs.lens.append(sh.length)
                 server_keys.setdefault(sh.server_rank, []).append(k)
-        self._send_batch_pushes(per_server, server_keys, priority,
-                                trace_round=trace_round)
-
-    def _send_batch_pushes(self, per_server: Dict[int, KVPairs],
-                           server_keys: Dict[int, List[int]],
-                           priority: int, trace_round: int = -1) -> None:
-        """Shared tail of the batched push paths: register per-(server,
-        shard) ack bookkeeping and send one message per server."""
+        # per-(server, shard) ack bookkeeping, then one message per server
         with self._lock:
             for ks in server_keys.values():
                 for k in ks:
@@ -1133,138 +1126,26 @@ class KVStoreDist(KVStore):
             raise TimeoutError(f"pull_row_sparse of key {key} timed out")
         return out
 
-    # -- element-sparse push/pull (the TPU-native BSC wire) ---------------
+    # -- the element-sparse round (the TPU-native BSC wire) ---------------
     # The device-resident trainer (geomx_tpu.trainer_device) selects
     # top-k gradient coordinates ON THE CHIP; shipping them to the party
     # server as a dense scatter would put O(total) bytes on the LAN hop
     # and O(total) host allocations per round (round-3 verdict weak #4).
     # Wire format: tag "bsc" — vals = selected values, aux = within-shard
-    # element indices (int32). The server's generic push decompression
-    # (compression._generic_decompress) scatters to dense for
-    # aggregation; a "bsc"-tagged pull returns the aggregated gradient's
-    # exact nonzero set (server._pull_response_action). Semantically
-    # identical to a dense push of the scattered selection — only the
-    # bytes differ.
-
-    def push_bsc(self, key, values, indices, priority: int = 0) -> None:
-        """Push a sparse gradient selection: ``values[j]`` belongs at
-        flat position ``indices[j]`` of this key. Aggregates by sum with
-        other workers' selections (server scatters to dense)."""
-        vals = np.ascontiguousarray(values, dtype=np.float32).ravel()
-        idx = np.asarray(indices, dtype=np.int64).ravel()
-        assert vals.size == idx.size, "values/indices length mismatch"
-        info = self._key_info.get(key)
-        assert info is not None, f"push_bsc of key {key} before init"
-        if idx.size and (idx.min() < 0 or idx.max() >= info.total):
-            raise IndexError(
-                f"push_bsc: indices out of range for key {key} "
-                f"({info.total} elements)")
-        with self._lock:
-            self._push_acks_left[key] = (
-                self._push_acks_left.get(key, 0) + len(info.shards))
-        self._track(len(info.shards), key)
-        for sh in info.shards:
-            # every shard gets a push (possibly empty) — the server's FSA
-            # round counts contributed elements per shard, so skipping an
-            # empty shard would stall the round
-            sel = (idx >= sh.offset) & (idx < sh.offset + sh.length)
-            kvs = KVPairs(
-                keys=[key], vals=[vals[sel]],
-                aux=[(idx[sel] - sh.offset).astype(np.int32)],
-                offsets=[sh.offset], totals=[sh.total],
-                lens=[sh.length], compr="bsc")
-            self.kvw.push(kvs, sh.server_rank, priority=priority,
-                          cb=lambda ts, kk=key: self._on_push_ack(kk, ts))
-
-    def pull_bsc(self, key, priority: int = 0, timeout: float = None):
-        """Pull the aggregated gradient's nonzeros: returns
-        ``(values float32, flat_indices int64)`` for this key. Ordered
-        after this key's push acks like dense pulls. Falls back
-        transparently when a server serves dense (e.g. optimizer-mode
-        stores): nonzeros are extracted host-side."""
-        timeout = self.cfg.op_timeout_s if timeout is None else timeout
-        info = self._key_info.get(key)
-        assert info is not None, f"pull_bsc of key {key} before init"
-        parts: List = []
-        done = threading.Event()
-        remaining = [len(info.shards)]
-        self._track(1, key)
-
-        fails: List[str] = []
-
-        def on_data(ts: int, sh: sharding.Shard):
-            fail = self.kvw.take_failure(ts)
-            if fail is not None:
-                # recorded locally AND globally: join() raises this
-                # call's own failures (and consumes nothing else); the
-                # global list still surfaces them to a later wait() if
-                # the caller never joins
-                with self._lock:
-                    fails.append(f"pull_bsc key {key}: {fail}")
-                    self._transport_errors.append(
-                        f"pull_bsc key {key}: {fail}")
-            for kvs in self.kvw.take_response(ts):
-                for i, _k in enumerate(kvs.keys):
-                    data = np.asarray(kvs.vals[i],
-                                      dtype=np.float32).ravel()
-                    r_off = kvs.offset_of(i)
-                    aux = kvs.aux[i] if i < len(kvs.aux) else None
-                    if kvs.compr in ("bsc", "bsc16") and aux is not None:
-                        gidx = (np.asarray(aux, np.int64).ravel() + r_off)
-                        with self._lock:
-                            parts.append((data, gidx))
-                    else:
-                        # dense response: extract nonzeros here
-                        nz = np.nonzero(data)[0]
-                        with self._lock:
-                            parts.append((data[nz].astype(np.float32),
-                                          nz + r_off))
-            with self._lock:
-                remaining[0] -= 1
-                last = remaining[0] == 0
-            if last:
-                done.set()
-                self._untrack(key)
-
-        def issue():
-            for sh in info.shards:
-                self.kvw.pull([key], sh.server_rank, offsets=[sh.offset],
-                              totals=[sh.total], lens=[sh.length],
-                              priority=priority, compr="bsc",
-                              cb=lambda ts, s=sh: on_data(ts, s))
-
-        self._issue_after_push_acks(key, issue)
-
-        def join():
-            if not done.wait(timeout):
-                raise TimeoutError(f"pull_bsc of key {key} timed out")
-            with self._lock:
-                errs = list(fails)
-                if errs:
-                    # consume from the global list too — this call's
-                    # failure is surfaced here, not re-raised by every
-                    # later wait()
-                    self._transport_errors = [
-                        e for e in self._transport_errors
-                        if e not in fails]
-            if errs:
-                raise _give_up_exc(errs)("transport gave up on "
-                                         + "; ".join(errs))
-            with self._lock:
-                got = list(parts)
-            if not got:
-                return (np.zeros(0, np.float32), np.zeros(0, np.int64))
-            return (np.concatenate([p[0] for p in got]),
-                    np.concatenate([p[1] for p in got]))
-
-        return join
+    # element indices (int32); "bsc16" ships the values as float16. One
+    # combined message per (chunk, server) pushes a worker's selections,
+    # and its countdown-merged ack carries the aggregate's exact nonzero
+    # set. Semantically identical to a dense push_pull of the scattered
+    # selections — only the bytes differ. ONE implementation:
+    # push_pull_bsc_batch_async; push_pull_bsc_batch is its blocking
+    # one-chunk form.
 
     def _prepare_bsc_shards(self, keys, values_list, indices_list,
                             wire_tag: str = "bsc"):
         """Validate per-key sparse selections and partition them into
-        one KVPairs per server (shared by the separate and combined BSC
-        wire sends). ``wire_tag="bsc16"`` ships the selected values as
-        float16 (the quantized combined wire; indices stay int32) — the
+        one KVPairs per server. ``wire_tag="bsc16"`` ships the selected
+        values as float16 (the quantized combined wire; indices stay
+        int32) — the
         trainer's device-side error feedback makes the narrowing
         lossless on the wire (trainer_device.select)."""
         per_server: Dict[int, KVPairs] = {}
@@ -1275,10 +1156,12 @@ class KVStoreDist(KVStore):
             idx = np.asarray(indices, dtype=np.int64).ravel()
             assert vals.size == idx.size, "values/indices mismatch"
             info = self._key_info.get(k)
-            assert info is not None, f"push_bsc of key {k} before init"
+            assert info is not None, \
+                f"push_pull_bsc_batch of key {k} before init"
             if idx.size and (idx.min() < 0 or idx.max() >= info.total):
                 raise IndexError(
-                    f"push_bsc: indices out of range for key {k}")
+                    f"push_pull_bsc_batch: indices out of range for key "
+                    f"{k} ({info.total} elements)")
             prepared.append((k, vals, idx, info))
         for k, vals, idx, info in prepared:
             for sh in info.shards:
@@ -1295,144 +1178,27 @@ class KVStoreDist(KVStore):
                 server_keys.setdefault(sh.server_rank, []).append(k)
         return per_server, server_keys
 
-    def push_bsc_batch(self, keys, values_list, indices_list,
-                       priority: int = 0) -> None:
-        """Batched ``push_bsc``: one message per server carrying every
-        key's sparse selection (same countdown-merged ack as the dense
-        batched wire). Under ENABLE_P3 it fans out per key with
-        descending priority, like the dense list form — one coalesced
-        message would defeat the priority send thread's interleaving."""
-        assert len(set(keys)) == len(keys), "duplicate keys in one round"
-        if self.cfg.enable_p3:
-            for i, (k, v, ix) in enumerate(zip(keys, values_list,
-                                               indices_list)):
-                self.push_bsc(k, v, ix, priority=priority - i)
-            return
-        per_server, server_keys = self._prepare_bsc_shards(
-            keys, values_list, indices_list,
-            wire_tag="bsc16" if self._wire.enabled() else "bsc")
-        self._send_batch_pushes(per_server, server_keys, priority)
-
     def push_pull_bsc_batch(self, keys, values_list, indices_list,
                             priority: int = 0, timeout: float = None):
-        """Combined sparse round (ZPushPull over the element-sparse BSC
-        wire): one message per server per round; the countdown-merged
-        ack carries the aggregate's exact nonzeros. Returns a ``join()
-        -> {key: (values, flat_indices)}`` callable like
-        ``pull_bsc_batch``. Falls back to the two-op sequence under
-        ENABLE_P3 (per-key priority interleaving)."""
+        """Blocking form of :meth:`push_pull_bsc_batch_async` sent as
+        one chunk (one message per server): returns a ``join() -> {key:
+        (values, flat_indices)}`` callable. A give-up surfaces from
+        ``join()`` with the class ``wait()`` would raise, a time-out as
+        ``TimeoutError``."""
         timeout = self.cfg.op_timeout_s if timeout is None else timeout
-        assert len(set(keys)) == len(keys), "duplicate keys in one round"
-        if self.cfg.enable_p3:
-            self.push_bsc_batch(keys, values_list, indices_list,
-                                priority=priority)
-            return self.pull_bsc_batch(keys, priority=priority,
-                                       timeout=timeout)
-        per_server, server_keys = self._prepare_bsc_shards(
-            keys, values_list, indices_list,
-            wire_tag="bsc16" if self._wire.enabled() else "bsc")
-        rid = self._begin_round()
-        parts: Dict[int, List] = {k: [] for k in keys}
-        fails: List[str] = []
-        done = threading.Event()
-        remaining = [len(per_server)]
-        with self._lock:
-            for ks in server_keys.values():
-                for k in ks:
-                    self._push_acks_left[k] = (
-                        self._push_acks_left.get(k, 0) + 1)
-        for ks in server_keys.values():
-            for k in ks:
-                self._track(1, k)
-
-        def on_resp(ts: int, srank: int):
-            fail = self.kvw.take_failure(ts)
-            if fail is not None:
-                with self._lock:
-                    fails.append(
-                        f"push_pull_bsc keys "
-                        f"{sorted(set(server_keys[srank]))}: {fail}")
-                    self._transport_errors.append(fails[-1])
-            for kvs in self.kvw.take_response(ts):
-                for i, k in enumerate(kvs.keys):
-                    data = np.asarray(kvs.vals[i],
-                                      dtype=np.float32).ravel()
-                    r_off = kvs.offset_of(i)
-                    aux = kvs.aux[i] if i < len(kvs.aux) else None
-                    if kvs.compr in ("bsc", "bsc16") and aux is not None:
-                        entry = (data,
-                                 np.asarray(aux, np.int64).ravel()
-                                 + r_off)
-                    else:
-                        nz = np.nonzero(data)[0]
-                        entry = (data[nz].astype(np.float32), nz + r_off)
-                    with self._lock:
-                        parts[k].append(entry)
-            ready = []
-            with self._lock:
-                remaining[0] -= 1
-                last = remaining[0] == 0
-                for k in server_keys[srank]:
-                    self._push_acks_left[k] -= 1
-                    if (self._push_acks_left[k] == 0
-                            and k in self._deferred):
-                        ready.extend(self._deferred.pop(k))
-            if last:
-                done.set()
-            for k in server_keys[srank]:
-                self._untrack(k)
-            for fn in ready:
-                fn()
-
-        for srank, kvs in per_server.items():
-            self.kvw.push(kvs, srank, priority=priority, pull=True,
-                          trace_round=rid,
-                          cb=lambda ts, s=srank: on_resp(ts, s))
-
-        expected_parts = {k: sum(1 for ks in server_keys.values()
-                                 if k in ks) for k in keys}
-
-        def join():
-            if not done.wait(timeout):
-                raise TimeoutError("push_pull_bsc_batch timed out")
-            with self._lock:
-                errs = list(fails)
-                if errs:
-                    self._transport_errors = [
-                        e for e in self._transport_errors
-                        if e not in fails]
-            if errs:
-                raise _give_up_exc(errs)("transport gave up on "
-                                         + "; ".join(errs))
-            out = {}
-            with self._lock:
-                got = {k: list(v) for k, v in parts.items()}
-            short = [k for k in keys
-                     if len(got[k]) < expected_parts[k]]
-            if short:
-                # a server acked without data for these keys: a missing
-                # entry is NOT an empty aggregate — re-pull explicitly
-                agg = self.pull_bsc_batch(short, timeout=timeout)()
-                for k in short:
-                    got[k] = [agg[k]]
-            for k, ps in got.items():
-                if not ps:
-                    out[k] = (np.zeros(0, np.float32),
-                              np.zeros(0, np.int64))
-                else:
-                    out[k] = (np.concatenate([p[0] for p in ps]),
-                              np.concatenate([p[1] for p in ps]))
-            return out
-
-        return join
+        fut = self.push_pull_bsc_batch_async(
+            keys, values_list, indices_list, priority=priority,
+            slice_bytes=0)
+        return lambda: fut.results(timeout)
 
     def push_pull_bsc_batch_async(self, keys, values_list, indices_list,
                                   priority: int = 0,
                                   slice_bytes: Optional[int] = None
                                   ) -> RoundFuture:
-        """Non-blocking chunked combined sparse round (the P3-pipelined
-        form of :meth:`push_pull_bsc_batch`): keys group in layer order
-        into ~``slice_bytes``-byte chunks (~8 wire bytes per selected
+        """Non-blocking chunked combined sparse round (ZPushPull over
+        the element-sparse BSC wire, the only implementation of the
+        sparse round): keys group in layer order into
+        ~``slice_bytes``-byte chunks (~8 wire bytes per selected
         element; default ``cfg.p3_slice_bytes``, <= 0 = one chunk), one
         message per (chunk, server) at descending priority. Keys stay
         WHOLE — the server FSA counts one push per (key, shard) per
@@ -1579,8 +1345,7 @@ class KVStoreDist(KVStore):
                           fut: RoundFuture) -> None:
         """Async fallback pull for BSC keys whose combined ack came back
         short: per-server "bsc" pulls, completing each key on ``fut`` as
-        its last response lands (the non-blocking twin of the
-        pull_bsc_batch re-pull in push_pull_bsc_batch's join)."""
+        its last response lands."""
         per_server: Dict[int, KVPairs] = {}
         server_keys: Dict[int, List[int]] = {}
         for k in keys:
@@ -1610,7 +1375,7 @@ class KVStoreDist(KVStore):
             if fail is not None:
                 with self._lock:
                     for k in sorted(set(server_keys[srank])):
-                        err = f"pull_bsc key {k}: {fail}"
+                        err = f"bsc re-pull key {k}: {fail}"
                         self._transport_errors.append(err)
                         failed_keys.append((k, err))
             for k, err in failed_keys:
@@ -1657,115 +1422,6 @@ class KVStoreDist(KVStore):
                               cb=lambda ts, s=sr: on_data(ts, s))
 
             self._issue_after_push_acks(set(server_keys[srank]), issue)
-
-    def pull_bsc_batch(self, keys, priority: int = 0,
-                       timeout: float = None):
-        """Batched ``pull_bsc``: one request per server; returns a
-        ``join() -> {key: (values, flat_indices)}`` callable. Under
-        ENABLE_P3 it fans out per key (see push_bsc_batch)."""
-        timeout = self.cfg.op_timeout_s if timeout is None else timeout
-        assert len(set(keys)) == len(keys), "duplicate keys in one call"
-        if self.cfg.enable_p3:
-            joins = [(k, self.pull_bsc(k, priority=priority - i,
-                                       timeout=timeout))
-                     for i, k in enumerate(keys)]
-
-            def join_all():
-                return {k: j() for k, j in joins}
-
-            return join_all
-        per_server: Dict[int, KVPairs] = {}
-        server_keys: Dict[int, List[int]] = {}
-        for k in keys:
-            info = self._key_info.get(k)
-            assert info is not None, f"pull_bsc of key {k} before init"
-            for sh in info.shards:
-                kvs = per_server.setdefault(sh.server_rank,
-                                            KVPairs(compr="bsc"))
-                kvs.keys.append(k)
-                kvs.vals.append(np.zeros(0, np.float32))
-                kvs.offsets.append(sh.offset)
-                kvs.totals.append(sh.total)
-                kvs.lens.append(sh.length)
-                server_keys.setdefault(sh.server_rank, []).append(k)
-        parts: Dict[int, List] = {k: [] for k in keys}
-        fails: List[str] = []
-        done = threading.Event()
-        remaining = [len(per_server)]
-        # tracked per (server, shard) entry, untracked the same way on
-        # that server's response — symmetric with _on_batch_push_ack
-        for ks in server_keys.values():
-            for k in ks:
-                self._track(1, k)
-
-        def on_data(ts: int, srank: int):
-            fail = self.kvw.take_failure(ts)
-            if fail is not None:
-                with self._lock:
-                    fails.append(
-                        f"pull_bsc keys {sorted(set(server_keys[srank]))}"
-                        f": {fail}")
-                    self._transport_errors.append(fails[-1])
-            for kvs in self.kvw.take_response(ts):
-                for i, k in enumerate(kvs.keys):
-                    # array work OUTSIDE the store lock (it serializes
-                    # every transport callback on this worker)
-                    data = np.asarray(kvs.vals[i],
-                                      dtype=np.float32).ravel()
-                    r_off = kvs.offset_of(i)
-                    aux = kvs.aux[i] if i < len(kvs.aux) else None
-                    if kvs.compr in ("bsc", "bsc16") and aux is not None:
-                        entry = (data,
-                                 np.asarray(aux, np.int64).ravel()
-                                 + r_off)
-                    else:
-                        nz = np.nonzero(data)[0]
-                        entry = (data[nz].astype(np.float32), nz + r_off)
-                    with self._lock:
-                        parts[k].append(entry)
-            last = False
-            with self._lock:
-                remaining[0] -= 1
-                last = remaining[0] == 0
-            if last:
-                done.set()
-            for k in server_keys[srank]:
-                self._untrack(k)
-
-        for srank, kvs in per_server.items():
-            def issue(sr=srank, kv=kvs):
-                self.kvw.pull(kv.keys, sr, offsets=kv.offsets,
-                              totals=kv.totals, lens=kv.lens,
-                              priority=priority, compr="bsc",
-                              cb=lambda ts, s=sr: on_data(ts, s))
-
-            self._issue_after_push_acks(set(server_keys[srank]), issue)
-
-        def join():
-            if not done.wait(timeout):
-                raise TimeoutError("pull_bsc_batch timed out")
-            with self._lock:
-                errs = list(fails)
-                if errs:
-                    self._transport_errors = [
-                        e for e in self._transport_errors
-                        if e not in fails]
-            if errs:
-                raise _give_up_exc(errs)("transport gave up on "
-                                         + "; ".join(errs))
-            out = {}
-            with self._lock:
-                got = {k: list(v) for k, v in parts.items()}
-            for k, ps in got.items():
-                if not ps:
-                    out[k] = (np.zeros(0, np.float32),
-                              np.zeros(0, np.int64))
-                else:
-                    out[k] = (np.concatenate([p[0] for p in ps]),
-                              np.concatenate([p[1] for p in ps]))
-            return out
-
-        return join
 
     def wait(self, keys=None, timeout: float = None) -> None:
         """Block until outstanding pushes/pulls complete. With ``keys``,

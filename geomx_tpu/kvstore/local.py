@@ -11,11 +11,12 @@ worker's local device reduction.
 
 from __future__ import annotations
 
-from typing import Dict
+from typing import Dict, Optional
 
 import numpy as np
 
 from geomx_tpu.kvstore.base import KVStore, _sum_values
+from geomx_tpu.kvstore.frontier import RoundFuture
 
 
 class KVStoreLocal(KVStore):
@@ -77,6 +78,43 @@ class KVStoreLocal(KVStore):
         ids = np.asarray(row_ids, dtype=np.int64).ravel()
         w = np.asarray(self._store[key])
         return w.reshape(-1, w.shape[-1])[ids].copy()
+
+    # -- the element-sparse round (KVStoreDist.push_pull_bsc_batch_async) --
+
+    def push_pull_bsc_batch_async(self, keys, values_list, indices_list,
+                                  priority: int = 0,
+                                  slice_bytes: Optional[int] = None
+                                  ) -> RoundFuture:
+        """The sparse round, answered on the spot: with one worker and
+        no updater the aggregate of a selection is the selection itself
+        less its entries of value 0, in index order — what the servers'
+        ack carries. Per key ``(values float32, flat_indices int64)``
+        through a :class:`RoundFuture` that is already complete. The
+        stored values are not touched (nothing reads them back in
+        aggregator mode); ``priority`` and ``slice_bytes`` order and cut
+        a wire this store does not have."""
+        if self._updater is not None:
+            raise RuntimeError(
+                "the sparse round returns the aggregated selection: "
+                "with an updater set the store holds weights — "
+                "DeviceResidentTrainer requires aggregator mode")
+        keys = list(keys)
+        fut = RoundFuture(keys)
+        for k, values, indices in zip(keys, values_list, indices_list):
+            vals = np.asarray(values, dtype=np.float32).ravel()
+            idx = np.asarray(indices, dtype=np.int64).ravel()
+            if vals.size != idx.size:
+                raise ValueError(f"key {k}: {vals.size} values for "
+                                 f"{idx.size} indices")
+            total = self._store[k].size
+            if idx.size and (idx.min() < 0 or idx.max() >= total):
+                raise IndexError(
+                    f"push_pull_bsc_batch_async: indices out of range "
+                    f"for key {k} ({total} elements)")
+            keep = np.flatnonzero(vals)
+            keep = keep[np.argsort(idx[keep], kind="stable")]
+            fut.complete_key(k, (vals[keep], idx[keep]))
+        return fut
 
     def set_updater(self, updater) -> None:
         self._updater = updater

@@ -306,25 +306,6 @@ class KVStorePartyMesh(KVStore):
         return self._watch(self.inner.push_pull_async(
             key, value, out, priority=priority, slice_bytes=slice_bytes))
 
-    def push_bsc(self, key, values, indices, priority: int = 0) -> None:
-        self._require_global("push_bsc")
-        self.inner.push_bsc(key, values, indices, priority=priority)
-
-    def pull_bsc(self, key, priority: int = 0, timeout: float = None):
-        self._require_global("pull_bsc")
-        return self.inner.pull_bsc(key, priority=priority, timeout=timeout)
-
-    def push_bsc_batch(self, keys, values_list, indices_list,
-                       priority: int = 0) -> None:
-        self._require_global("push_bsc_batch")
-        self.inner.push_bsc_batch(keys, values_list, indices_list,
-                                  priority=priority)
-
-    def pull_bsc_batch(self, keys, priority: int = 0, timeout: float = None):
-        self._require_global("pull_bsc_batch")
-        return self.inner.pull_bsc_batch(keys, priority=priority,
-                                         timeout=timeout)
-
     def push_pull_bsc_batch(self, keys, values_list, indices_list,
                             priority: int = 0, timeout: float = None):
         self._require_global("push_pull_bsc_batch")

@@ -50,7 +50,8 @@ class Trainer:
         usual flatten order) keys at higher priority, matching the
         examples' ``priority=-idx`` P3 pattern.
 
-        ``overlap`` (default: the store's GEOMX_OVERLAP config) defers
+        ``overlap`` (default: on for a store that speaks a wire, i.e.
+        has a ``cfg``; off for the single-process stores) defers
         ``step``'s round barrier to the point of first use: the next
         ``leaves`` access — usually the next forward, or an HFA K2
         global round riding behind K1 local steps — joins the in-flight
@@ -61,8 +62,7 @@ class Trainer:
         self.begin_key = begin_key
         self.priority_descending = priority_descending
         if overlap is None:
-            overlap = bool(getattr(getattr(kvstore, "cfg", None),
-                                   "overlap", False))
+            overlap = getattr(kvstore, "cfg", None) is not None
         self._overlap = overlap
         self._dirty = False      # a step's round is still in flight
         self._round = 0          # 1-based training-round counter

@@ -28,10 +28,13 @@ every scatter landed on coordinate 0. Integer lanes never flush, so the
 int32 packing is bit-exact on every backend (probe:
 tools/chip_sanity.py transfer_bitexact / bitcast_in_jit).
 
-The LAN hop is element-sparse when the kvstore supports it
-(KVStoreDist.push_bsc / pull_bsc — O(k) bytes and host work per key);
-stores without the sparse wire (e.g. the single-process "local" store)
-fall back to a dense scatter per key.
+The round is one verb of the store, ``push_pull_bsc_batch_async``: the
+trainer hands it each chunk's per-key selection and applies the
+aggregate the returned ``RoundFuture`` completes with. HOW a selection
+is aggregated is the store's business: ``KVStoreDist`` (and the mesh
+party store around it) sends one combined message per (chunk, server) —
+O(k) bytes and host work per key; the single-process "local" store
+answers on the spot with the selection itself.
 
 KVStore semantics follow examples/cnn_bsc.py: the PS tier is an
 AGGREGATOR (no server-side optimizer); every worker applies the same
@@ -47,6 +50,7 @@ grads, the standard treatment).
 
 from __future__ import annotations
 
+from functools import partial
 from typing import Any, Callable, Dict, List, Sequence, Tuple
 
 import numpy as np
@@ -87,7 +91,6 @@ class DeviceResidentTrainer:
         self.kv = kvstore
         self._aux_names, grad_fn = getattr(grad_fn, "counted",
                                            ((), grad_fn))
-        self._head = 1 + len(self._aux_names)
         self.begin_key = begin_key
         self.threshold = threshold
         self.learning_rate = learning_rate
@@ -148,12 +151,6 @@ class DeviceResidentTrainer:
         # tier sums every party's aggregate), not the party-local count
         nw = max(int(getattr(self.kv, "num_all_workers", 0)
                      or getattr(self.kv, "num_workers", 1)), 1)
-        self._num_workers = nw
-        # the aggregate has <= nw*k nonzeros; padding the upload to that
-        # FIXED size keeps one compiled apply (a shape that varied per
-        # round would retrace/recompile jit every step)
-        self._up_cap = m = nw * self.k
-        K = self.k
 
         # quantized combined wire: when a wire codec is active the store
         # ships the selected values as float16 ("bsc16"). Fuse the
@@ -161,14 +158,14 @@ class DeviceResidentTrainer:
         # rounding error goes BACK into the residual v instead of being
         # dropped on the host cast, so the wire's astype(float16) in
         # dist._prepare_bsc_shards is exactly lossless
-        kcfg0 = getattr(self.kv, "cfg", None)
-        wire16 = bool(getattr(kcfg0, "wire_codec", ""))
+        kcfg = getattr(self.kv, "cfg", None)
+        wire16 = bool(getattr(kcfg, "wire_codec", ""))
 
         # quantized mesh collective (GEOMX_MESH_CODEC != "none"): the
         # party aggregate moves off the XLA-inserted fp32 psum and onto
         # the explicit quantized ppermute ring — set up below, after
         # the shared BSC body is defined
-        mesh_codec = (getattr(kcfg0, "mesh_codec", "none") or "none") \
+        mesh_codec = (getattr(kcfg, "mesh_codec", "none") or "none") \
             if self._mesh is not None else "none"
         self._mesh_quant = mesh_codec != "none"
 
@@ -185,10 +182,6 @@ class DeviceResidentTrainer:
                     [jnp.reshape(a, (-1,)).astype(jnp.float32)
                      for a in (loss, *counts)])
             return loss, jnp.concatenate([gg.reshape(-1) for gg in grads])
-
-        def head_of(loss):
-            return loss if self._aux_names else \
-                loss[None].astype(jnp.float32)
 
         def _bsc(loss, g, u, v):
             # BSC: momentum-corrected accumulation, exact per-key top-k
@@ -219,102 +212,70 @@ class DeviceResidentTrainer:
             loss, g = _grad_cat(flat, X, y)
             return _bsc(loss, g / nw, u, v)
 
-        @jax.jit
-        def fwd_compress(flat, u, v, X, y):
-            loss, vals, idx, u, v = select(flat, u, v, X, y)
-            # single packed INT32 transfer: [loss, vals(K) bitcast i32,
-            # idx(K)] — int lanes are denormal-safe (module docstring)
-            packed = jnp.concatenate(
-                [jax.lax.bitcast_convert_type(head_of(loss), jnp.int32),
-                 jax.lax.bitcast_convert_type(vals, jnp.int32),
-                 idx])
-            return packed, u, v
-
-        @jax.jit
-        def apply_sgd(flat, mom, packed):
-            vals = jax.lax.bitcast_convert_type(packed[:m], jnp.float32)
-            idx = packed[m:]
-            # pad slots carry (val 0.0, idx 0): a scatter-add no-op
-            g = jnp.zeros_like(flat).at[idx].add(vals)
-            if mom is None:
-                return flat - learning_rate * g, None
-            mom = momentum * mom + g
-            return flat - learning_rate * mom, mom
-
-        self._fwd_compress = fwd_compress
-        self._apply = apply_sgd
-        self._K = K
-        self._sparse_wire = (hasattr(self.kv, "push_bsc")
-                             and hasattr(self.kv, "pull_bsc"))
-
-        # -- pipelined round (GEOMX_OVERLAP + P3_SLICE_BYTES) ------------
+        # -- the round's chunks (P3_SLICE_BYTES) ------------------------
         # keys group in layer order into ~P3_SLICE_BYTES wire-byte
         # chunks (~8 bytes per selected element); each chunk's D2H
         # fetch, async combined round and jitted dynamic_update_slice
         # apply flow independently — chunk i applies while chunk i+1's
-        # bytes are still on the wire. 0 = one chunk: the pipelined
-        # machinery with round-5 message counts.
-        kcfg = getattr(self.kv, "cfg", None)
-        self._pipeline = (bool(getattr(kcfg, "overlap", False))
-                          and self._sparse_wire
-                          and hasattr(self.kv, "push_pull_bsc_batch_async"))
-        if self._pipeline:
-            from functools import partial
+        # bytes are still on the wire. 0 = one chunk: one message per
+        # server per round.
+        chunks = plan_chunks(list(range(len(sizes))),
+                             [8 * kk for kk in ks],
+                             int(getattr(kcfg, "p3_slice_bytes", 0)))
+        self._chunks = chunks
+        # per chunk: selection range, flat param range, upload cap —
+        # chunk key runs are contiguous, so each covers one flat
+        # slice [flo, flo+fsize) and the slices partition [0, total);
+        # the aggregate has <= nw*k nonzeros, and padding the upload to
+        # that FIXED size keeps one compiled apply per chunk (a shape
+        # that varied per round would retrace/recompile every step)
+        meta = []
+        for ch in chunks:
+            a, b = ch.items[0], ch.items[-1]
+            sel_lo, sel_hi = int(self._kofs[a]), int(self._kofs[b + 1])
+            flo, fhi = int(self._offsets[a]), int(self._offsets[b + 1])
+            meta.append((sel_lo, sel_hi, flo, fhi - flo,
+                         nw * (sel_hi - sel_lo)))
+        self._chunk_meta = meta
+        sel_bounds = [(m[0], m[1]) for m in meta]
 
-            chunks = plan_chunks(list(range(len(sizes))),
-                                 [8 * kk for kk in ks],
-                                 int(getattr(kcfg, "p3_slice_bytes", 0)))
-            self._chunks = chunks
-            # per chunk: selection range, flat param range, upload cap —
-            # chunk key runs are contiguous, so each covers one flat
-            # slice [flo, flo+fsize) and the slices partition [0, total)
-            meta = []
-            for ch in chunks:
-                a, b = ch.items[0], ch.items[-1]
-                sel_lo, sel_hi = int(self._kofs[a]), int(self._kofs[b + 1])
-                flo, fhi = int(self._offsets[a]), int(self._offsets[b + 1])
-                meta.append((sel_lo, sel_hi, flo, fhi - flo,
-                             nw * (sel_hi - sel_lo)))
-            self._chunk_meta = meta
-            sel_bounds = [(m[0], m[1]) for m in meta]
+        @jax.jit
+        def fwd_chunks(flat, u, v, X, y):
+            loss, vals, idx, u, v = select(flat, u, v, X, y)
+            # one packed int32 array PER CHUNK so the host can fetch
+            # and dispatch each chunk independently; loss rides
+            # separately (fetching its value fences the program)
+            packs = tuple(
+                jnp.concatenate(
+                    [jax.lax.bitcast_convert_type(vals[lo:hi],
+                                                  jnp.int32),
+                     idx[lo:hi]])
+                for lo, hi in sel_bounds)
+            return loss.astype(jnp.float32), packs, u, v
 
-            @jax.jit
-            def fwd_chunks(flat, u, v, X, y):
-                loss, vals, idx, u, v = select(flat, u, v, X, y)
-                # one packed int32 array PER CHUNK so the host can fetch
-                # and dispatch each chunk independently; loss rides
-                # separately (fetching its value fences the program)
-                packs = tuple(
-                    jnp.concatenate(
-                        [jax.lax.bitcast_convert_type(vals[lo:hi],
-                                                      jnp.int32),
-                         idx[lo:hi]])
-                    for lo, hi in sel_bounds)
-                return loss.astype(jnp.float32), packs, u, v
-
-            @partial(jax.jit, static_argnums=(3, 4))
-            def apply_chunk(flat, mom, up, flo, fsize):
-                # up layout mirrors apply_sgd but chunk-local: [vals(cap)
-                # bitcast i32, idx(cap) CHUNK-relative]; pad slots are
-                # (0.0, 0) — a scatter-add no-op, and position 0 of the
-                # chunk is a real coordinate so adding 0.0 is exact
-                # (aggregated nonzeros are never ±0.0)
-                cap = up.shape[0] // 2
-                vals = jax.lax.bitcast_convert_type(up[:cap], jnp.float32)
-                cidx = up[cap:]
-                g = jnp.zeros((fsize,), flat.dtype).at[cidx].add(vals)
-                seg = jax.lax.dynamic_slice(flat, (flo,), (fsize,))
-                if mom is None:
-                    return (jax.lax.dynamic_update_slice(
-                        flat, seg - learning_rate * g, (flo,)), None)
-                mseg = jax.lax.dynamic_slice(mom, (flo,), (fsize,))
-                mseg = momentum * mseg + g
+        @partial(jax.jit, static_argnums=(3, 4))
+        def apply_chunk(flat, mom, up, flo, fsize):
+            # up layout (see _chunk_up): [vals(cap) bitcast i32,
+            # idx(cap) CHUNK-relative]; pad slots are (0.0, 0) — a
+            # scatter-add no-op, and position 0 of the chunk is a real
+            # coordinate so adding 0.0 is exact (aggregated nonzeros
+            # are never ±0.0)
+            cap = up.shape[0] // 2
+            vals = jax.lax.bitcast_convert_type(up[:cap], jnp.float32)
+            cidx = up[cap:]
+            g = jnp.zeros((fsize,), flat.dtype).at[cidx].add(vals)
+            seg = jax.lax.dynamic_slice(flat, (flo,), (fsize,))
+            if mom is None:
                 return (jax.lax.dynamic_update_slice(
-                            flat, seg - learning_rate * mseg, (flo,)),
-                        jax.lax.dynamic_update_slice(mom, mseg, (flo,)))
+                    flat, seg - learning_rate * g, (flo,)), None)
+            mseg = jax.lax.dynamic_slice(mom, (flo,), (fsize,))
+            mseg = momentum * mseg + g
+            return (jax.lax.dynamic_update_slice(
+                        flat, seg - learning_rate * mseg, (flo,)),
+                    jax.lax.dynamic_update_slice(mom, mseg, (flo,)))
 
-            self._fwd_chunks = fwd_chunks
-            self._apply_chunk = apply_chunk
+        self._fwd_chunks = fwd_chunks
+        self._apply_chunk = apply_chunk
 
         # -- quantized mesh collective (GEOMX_MESH_CODEC) ----------------
         # The psum XLA inserts for the dp-sharded mean loss moves the
@@ -332,8 +293,8 @@ class DeviceResidentTrainer:
             from geomx_tpu.parallel.mesh import P as _P
 
             psize = int(self._mesh.shape["dp"])
-            mesh_block = int(getattr(kcfg0, "mesh_block", 256) or 256)
-            thr = float(getattr(kcfg0, "wire_2bit_threshold", 0.5))
+            mesh_block = int(getattr(kcfg, "mesh_block", 256) or 256)
+            thr = float(getattr(kcfg, "wire_2bit_threshold", 0.5))
             self._mesh_size = psize
             self._mesh_codec = mesh_codec
             self._mesh_block = mesh_block
@@ -372,32 +333,18 @@ class DeviceResidentTrainer:
                 return loss, vals, idx, u, v, res
 
             @jax.jit
-            def fwd_compress_q(flat, u, v, X, y, res):
+            def fwd_chunks_q(flat, u, v, X, y, res):
                 loss, vals, idx, u, v, res = select_q(flat, u, v,
                                                       X, y, res)
-                packed = jnp.concatenate(
-                    [jax.lax.bitcast_convert_type(head_of(loss), jnp.int32),
-                     jax.lax.bitcast_convert_type(vals, jnp.int32),
-                     idx])
-                return packed, u, v, res
+                packs = tuple(
+                    jnp.concatenate(
+                        [jax.lax.bitcast_convert_type(vals[lo:hi],
+                                                      jnp.int32),
+                         idx[lo:hi]])
+                    for lo, hi in sel_bounds)
+                return loss.astype(jnp.float32), packs, u, v, res
 
-            self._fwd_compress_q = fwd_compress_q
-            if self._pipeline:
-                sel_bounds_q = [(mm[0], mm[1]) for mm in self._chunk_meta]
-
-                @jax.jit
-                def fwd_chunks_q(flat, u, v, X, y, res):
-                    loss, vals, idx, u, v, res = select_q(flat, u, v,
-                                                          X, y, res)
-                    packs = tuple(
-                        jnp.concatenate(
-                            [jax.lax.bitcast_convert_type(vals[lo:hi],
-                                                          jnp.int32),
-                             idx[lo:hi]])
-                        for lo, hi in sel_bounds_q)
-                    return loss.astype(jnp.float32), packs, u, v, res
-
-                self._fwd_chunks_q = fwd_chunks_q
+            self._fwd_chunks_q = fwd_chunks_q
             self._reset_mesh_residual()
             # abort recovery zeroes this trainer's residual along with
             # the store-keyed reducers
@@ -422,20 +369,9 @@ class DeviceResidentTrainer:
             telemetry.counter_inc(name, float(value))
         return float(head[0])
 
-    def _run_fwd_compress(self, X, y):
-        """Run the monolithic device step, advancing (u, v) and — on the
-        quantized mesh path — the ring residual."""
-        if self._mesh_quant:
-            packed, self._u, self._v, self._mesh_res = \
-                self._fwd_compress_q(self._flat, self._u, self._v,
-                                     X, y, self._mesh_res)
-        else:
-            packed, self._u, self._v = self._fwd_compress(
-                self._flat, self._u, self._v, X, y)
-        return packed
-
     def _run_fwd_chunks(self, X, y):
-        """Chunked twin of :meth:`_run_fwd_compress`."""
+        """Run the device step, advancing (u, v) and — on the quantized
+        mesh path — the ring residual."""
         if self._mesh_quant:
             loss_d, packs, self._u, self._v, self._mesh_res = \
                 self._fwd_chunks_q(self._flat, self._u, self._v,
@@ -472,78 +408,28 @@ class DeviceResidentTrainer:
         """Trace+compile the device programs :meth:`step` will run
         WITHOUT running a kv round (results discarded, trainer state
         untouched) — lets callers serialize expensive first compiles
-        without holding up the FSA barrier. Only the programs of the
-        active path: the pipelined round never runs the monolithic pair
-        (nor the reverse), and at 59M parameters each forward program is
-        about a minute of cold compile on a v5e."""
+        without holding up the FSA barrier (at 59M parameters the
+        forward program is about a minute of cold compile on a v5e)."""
         import jax
 
         X, y = self._place_batch(X, y)
         args = (self._flat, self._u, self._v, X, y)
         if self._mesh_quant:
             args += (self._mesh_res,)
-        if self._pipeline:
-            fwd = (self._fwd_chunks_q if self._mesh_quant
-                   else self._fwd_chunks)
-            loss_d, packs = fwd(*args)[:2]
-            fence = [loss_d, *packs]
-            for _lo, _hi, flo, fsize, cap in self._chunk_meta:
-                up = jax.device_put(np.zeros(2 * cap, np.int32))
-                fence.append(self._apply_chunk(self._flat, self._mom,
-                                               up, flo, fsize)[0])
-        else:
-            fwd = (self._fwd_compress_q if self._mesh_quant
-                   else self._fwd_compress)
-            up = jax.device_put(np.zeros(2 * self._up_cap, np.int32))
-            fence = [fwd(*args)[0],
-                     self._apply(self._flat, self._mom, up)[0]]
+        fwd = self._fwd_chunks_q if self._mesh_quant else self._fwd_chunks
+        loss_d, packs = fwd(*args)[:2]
+        fence = [loss_d, *packs]
+        for _lo, _hi, flo, fsize, cap in self._chunk_meta:
+            up = jax.device_put(np.zeros(2 * cap, np.int32))
+            fence.append(self._apply_chunk(self._flat, self._mom,
+                                           up, flo, fsize)[0])
         jax.block_until_ready(fence)
 
     # -- one round -------------------------------------------------------
 
-    def step(self, X, y) -> float:
-        """One FSA round: device grad+compress, HiPS aggregate, device
-        sparse apply. Returns the loss (device-computed, host float).
-
-        With the pipelined path active (GEOMX_OVERLAP and an async
-        sparse wire) the round runs per chunk — dispatch every chunk's
-        fetch+send first, then apply each as its aggregate lands —
-        same post-round state, overlapped wall clock."""
-        import jax
-
-        X, y = self._place_batch(X, y)
-        self._count_mesh_round()
-        if self._pipeline:
-            return self._step_pipelined(X, y)
-        packed_d = self._run_fwd_compress(X, y)
-        # ONE compact device->host transfer (1 + 2K int32 vs total)
-        packed = np.asarray(packed_d)
-        h = self._head
-        loss = self._book(packed[:h].view(np.float32))
-        vals = packed[h:h + self._K].view(np.float32)
-        idx = packed[h + self._K:].astype(np.int64)
-        if self._sparse_wire:
-            ups, upi = self._kv_round_sparse(vals, idx)
-        else:
-            ups, upi = self._kv_round_dense(vals, idx)
-        # ONE compact FIXED-SIZE host->device transfer; apply locally
-        # (cnn_bsc worker-side optimizer semantics).
-        n = len(ups)
-        if n > self._up_cap:
-            raise RuntimeError(
-                f"aggregated selection ({n}) exceeds the upload capacity "
-                f"({self._up_cap}) — is the PS tier running an optimizer? "
-                "DeviceResidentTrainer requires aggregator mode")
-        up = np.zeros(2 * self._up_cap, np.int32)
-        up[:n] = np.asarray(ups, np.float32).view(np.int32)
-        up[self._up_cap:self._up_cap + n] = upi.astype(np.int32)
-        self._flat, self._mom = self._apply(
-            self._flat, self._mom, jax.device_put(up))
-        return loss
-
     def _chunk_wire_parts(self, ci: int, arr: np.ndarray):
         """Split chunk ``ci``'s fetched pack into the per-key wire lists
-        (keys, values, KEY-relative indices) push_pull_bsc_batch expects."""
+        (keys, values, KEY-relative indices) the store's round takes."""
         sel_lo, sel_hi, _flo, _fsize, _cap = self._chunk_meta[ci]
         kc = sel_hi - sel_lo
         vals = arr[:kc].view(np.float32)
@@ -581,15 +467,19 @@ class DeviceResidentTrainer:
         up[cap:cap + n] = cat_i.astype(np.int32)
         return up
 
-    def _step_pipelined(self, X, y) -> float:
-        """Chunked overlapped round: fetch+dispatch every chunk in
-        layer order (priority -chunk), then apply each chunk's
-        aggregate as it arrives. Chunk flat ranges partition [0, total)
-        and the arithmetic per coordinate is identical to the
-        monolithic apply, so the post-round state is bit-identical to
-        the serial path."""
+    def step(self, X, y) -> float:
+        """One FSA round: device grad+compress, HiPS aggregate, device
+        sparse apply. Returns the loss (device-computed, host float).
+
+        The round runs per chunk: fetch+dispatch every chunk in layer
+        order (priority -chunk), then apply each chunk's aggregate as
+        it arrives. Chunk flat ranges partition [0, total) and every
+        coordinate's arithmetic is the same whatever the chunking, so
+        the post-round state does not depend on P3_SLICE_BYTES."""
         import jax
 
+        X, y = self._place_batch(X, y)
+        self._count_mesh_round()
         loss_d, packs = self._run_fwd_chunks(X, y)
         for p in packs:
             if hasattr(p, "copy_to_host_async"):
@@ -627,51 +517,28 @@ class DeviceResidentTrainer:
 
         import jax
 
-        assert self._sparse_wire, "step_timed needs the sparse wire"
         X, y = self._place_batch(X, y)
         self._count_mesh_round()
         t0 = time.perf_counter()
-        if self._pipeline:
-            loss_d, packs = self._run_fwd_chunks(X, y)
-            loss = self._book(np.asarray(loss_d))   # fences the program
-            t1 = time.perf_counter()
-            arrs = [np.asarray(p) for p in packs]
-            t2 = time.perf_counter()
-            futs = [self.kv.push_pull_bsc_batch_async(
-                        *self._chunk_wire_parts(ci, arrs[ci]),
-                        priority=-ci, slice_bytes=0)
-                    for ci in range(len(self._chunks))]
-            aggs = [f.results() for f in futs]
-            t3 = time.perf_counter()
-            ups_d = [jax.device_put(self._chunk_up(ci, aggs[ci]))
-                     for ci in range(len(self._chunks))]
-            jax.block_until_ready(ups_d)
-            t4 = time.perf_counter()
-            for ci, up_d in enumerate(ups_d):
-                _sl, _sh, flo, fsize, _cap = self._chunk_meta[ci]
-                self._flat, self._mom = self._apply_chunk(
-                    self._flat, self._mom, up_d, flo, fsize)
-        else:
-            packed_d = self._run_fwd_compress(X, y)
-            h = self._head
-            loss = self._book(np.asarray(packed_d[0:h])
-                              .view(np.float32))  # value fetch = fence
-            t1 = time.perf_counter()
-            packed = np.asarray(packed_d)
-            t2 = time.perf_counter()
-            vals = packed[h:h + self._K].view(np.float32)
-            idx = packed[h + self._K:].astype(np.int64)
-            ups, upi = self._kv_round_sparse(vals, idx)
-            t3 = time.perf_counter()
-            n = len(ups)
-            up = np.zeros(2 * self._up_cap, np.int32)
-            up[:n] = np.asarray(ups, np.float32).view(np.int32)
-            up[self._up_cap:self._up_cap + n] = upi.astype(np.int32)
-            up_d = jax.device_put(up)
-            jax.block_until_ready(up_d)
-            t4 = time.perf_counter()
-            self._flat, self._mom = self._apply(self._flat, self._mom,
-                                                up_d)
+        loss_d, packs = self._run_fwd_chunks(X, y)
+        loss = self._book(np.asarray(loss_d))   # fences the program
+        t1 = time.perf_counter()
+        arrs = [np.asarray(p) for p in packs]
+        t2 = time.perf_counter()
+        futs = [self.kv.push_pull_bsc_batch_async(
+                    *self._chunk_wire_parts(ci, arrs[ci]),
+                    priority=-ci, slice_bytes=0)
+                for ci in range(len(self._chunks))]
+        aggs = [f.results() for f in futs]
+        t3 = time.perf_counter()
+        ups_d = [jax.device_put(self._chunk_up(ci, aggs[ci]))
+                 for ci in range(len(self._chunks))]
+        jax.block_until_ready(ups_d)
+        t4 = time.perf_counter()
+        for ci, up_d in enumerate(ups_d):
+            _sl, _sh, flo, fsize, _cap = self._chunk_meta[ci]
+            self._flat, self._mom = self._apply_chunk(
+                self._flat, self._mom, up_d, flo, fsize)
         float(np.asarray(self._flat[0:1])[0])   # value fetch = fence
         t5 = time.perf_counter()
         return loss, {
@@ -681,76 +548,6 @@ class DeviceResidentTrainer:
             "h2d_ms": (t4 - t3) * 1e3,
             "apply_ms": (t5 - t4) * 1e3,
         }
-
-    # -- host-side kv round ----------------------------------------------
-
-    def _kv_round_sparse(self, vals: np.ndarray, idx: np.ndarray
-                         ) -> Tuple[np.ndarray, np.ndarray]:
-        """Element-sparse LAN round: O(k_i) bytes and host work per key,
-        batched to one message per server per direction when the store
-        supports it. The fwd layout is per-key contiguous (segment i
-        covers kofs[i]:kofs[i+1]), so partitioning is slicing, not
-        scanning."""
-        n = len(self._sizes)
-        keys = [self.begin_key + i for i in range(n)]
-        segs = [(int(self._kofs[i]), int(self._kofs[i + 1]),
-                 int(self._offsets[i])) for i in range(n)]
-        if hasattr(self.kv, "push_pull_bsc_batch"):
-            # combined sparse round: ONE message per server per round
-            # (the ack carries the aggregate's nonzeros)
-            agg = self.kv.push_pull_bsc_batch(
-                keys, [vals[lo:hi] for lo, hi, _ in segs],
-                [idx[lo:hi] - off for lo, hi, off in segs])()
-            ups = [agg[k][0] for k in keys]
-            upi = [agg[k][1] + off
-                   for k, (_, _, off) in zip(keys, segs)]
-            return np.concatenate(ups), np.concatenate(upi)
-        if hasattr(self.kv, "push_bsc_batch"):
-            self.kv.push_bsc_batch(
-                keys, [vals[lo:hi] for lo, hi, _ in segs],
-                [idx[lo:hi] - off for lo, hi, off in segs])
-            agg = self.kv.pull_bsc_batch(keys)()
-            ups = [agg[k][0] for k in keys]
-            upi = [agg[k][1] + off
-                   for k, (_, _, off) in zip(keys, segs)]
-            return np.concatenate(ups), np.concatenate(upi)
-        handles = []
-        for i, (lo, hi, off) in enumerate(segs):
-            self.kv.push_bsc(keys[i], vals[lo:hi], idx[lo:hi] - off,
-                             priority=-i)
-            handles.append((i, self.kv.pull_bsc(keys[i], priority=-i)))
-        ups, upi = [], []
-        for i, join in handles:
-            avals, aidx = join()
-            ups.append(avals)
-            upi.append(aidx + int(self._offsets[i]))
-        return np.concatenate(ups), np.concatenate(upi)
-
-    def _kv_round_dense(self, vals: np.ndarray, idx: np.ndarray
-                        ) -> Tuple[np.ndarray, np.ndarray]:
-        """Dense fallback for stores without the sparse wire (e.g. the
-        in-process "local" store): scatter each key's selection into a
-        dense buffer, push/pull, gather nonzeros."""
-        ups, upi = [], []
-        for i, (off, sz) in enumerate(zip(self._offsets[:-1],
-                                          self._sizes)):
-            lo, hi = int(self._kofs[i]), int(self._kofs[i + 1])
-            dense = np.zeros(sz, np.float32)
-            dense[idx[lo:hi] - off] = vals[lo:hi]
-            key = self.begin_key + i
-            self.kv.push(key, dense.reshape(self._shapes[i]), priority=-i)
-            out = np.zeros(self._shapes[i], np.float32)
-            self.kv.pull(key, out=out, priority=-i)
-            ups.append(out)
-            upi.append(off)
-        self.kv.wait()
-        cat_v, cat_i = [], []
-        for out, off in zip(ups, upi):
-            flat = out.ravel()
-            nz = np.nonzero(flat)[0]
-            cat_v.append(flat[nz].astype(np.float32))
-            cat_i.append(nz + off)
-        return np.concatenate(cat_v), np.concatenate(cat_i)
 
     # -- escape hatch ----------------------------------------------------
 

@@ -108,14 +108,14 @@ run_case partition \
 # countdowns drain, epochs stay monotone, and every traced lock feeds
 # the witness (order inversions, blocking under a lock, @guarded_by
 # locksets) — any violation of either fails the case below.
-export GEOMX_OVERLAP=1 P3_SLICE_BYTES=131072 GEOMX_WIRE_SANITIZER=1 \
+export P3_SLICE_BYTES=131072 GEOMX_WIRE_SANITIZER=1 \
        GEOMX_LOCK_SANITIZER=1
 run_case overlap \
   '[{"type": "drop", "p": 0.1},
     {"type": "reorder", "window": 4},
     {"type": "dup", "p": 0.05}]' \
   9790 "$@"
-unset GEOMX_OVERLAP P3_SLICE_BYTES GEOMX_WIRE_SANITIZER \
+unset P3_SLICE_BYTES GEOMX_WIRE_SANITIZER \
       GEOMX_LOCK_SANITIZER
 # launch_hips overwrites /tmp/hips_*.log per case, so these are the
 # overlap run's logs
@@ -138,12 +138,12 @@ fi
 # would corrupt the error feedback — so the bar is the same as overlap:
 # training completes AND the wire sanitizer stays silent.
 export GEOMX_WIRE_CODEC=2bit
-export GEOMX_OVERLAP=1 P3_SLICE_BYTES=131072 GEOMX_WIRE_SANITIZER=1
+export P3_SLICE_BYTES=131072 GEOMX_WIRE_SANITIZER=1
 run_case quant-wire \
   '[{"type": "drop", "p": 0.1},
     {"type": "dup", "p": 0.05}]' \
   10090 "$@"
-unset GEOMX_WIRE_CODEC GEOMX_OVERLAP P3_SLICE_BYTES GEOMX_WIRE_SANITIZER
+unset GEOMX_WIRE_CODEC P3_SLICE_BYTES GEOMX_WIRE_SANITIZER
 if grep -l "WIRE-SANITIZER VIOLATION" /tmp/hips_*.log 2>/dev/null; then
   echo "=== chaos[quant-wire] FAILED: wire-sanitizer violations (see logs above) ==="
   collect_artifacts quant-wire-sanitizer "$LAST_FDIR" "$LAST_TDIR"
@@ -224,7 +224,7 @@ rm -f /tmp/hips_mesh_*.log /tmp/hips_server_1019[23].log
   export GEOMX_TELEMETRY=1 GEOMX_TELEMETRY_DIR=$LAST_TDIR
   export PS_SNAPSHOT_DIR=$(mktemp -d) PS_SNAPSHOT_INTERVAL=1
   export GEOMX_MESH_CODEC=int8 GEOMX_WIRE_CODEC=2bit
-  export GEOMX_OVERLAP=1 P3_SLICE_BYTES=131072 GEOMX_WIRE_SANITIZER=1
+  export P3_SLICE_BYTES=131072 GEOMX_WIRE_SANITIZER=1
   # scoped via hips_env.sh so ONLY party A's server runs this plan
   # (see the server-kill case below); at=60 recv frames lands a few
   # training rounds in — past init, while the ring residuals are warm

@@ -168,13 +168,17 @@ def test_p3_list_form_fans_out_per_key():
         topo.stop()
 
 
-def test_p3_sparse_batch_fans_out_per_key():
-    """The sparse batch paths under ENABLE_P3 fan out per key like the
-    dense list form (aggregator mode: no server optimizer, the
-    pull-back is the aggregated selection)."""
+def test_p3_sparse_round_sums_and_chunks_per_key():
+    """The sparse round under ENABLE_P3 (aggregator mode: no server
+    optimizer, the ack is the aggregated selection): two workers'
+    entries sum, and with a P3_SLICE_BYTES that makes each key its own
+    chunk the round sends one message per key per server at descending
+    priority — chunks are this round's P3."""
     topo = InProcessHiPS(num_parties=2, workers_per_party=1,
                          extra_cfg={"enable_p3": True,
-                                    "bigarray_bound": 8}).start()
+                                    "bigarray_bound": 8,
+                                    "p3_slice_bytes": 8}).start()
+    sent = {}
     try:
         def master_init(kv):
             for k, n in ((0, 20), (1, 6)):
@@ -187,16 +191,42 @@ def test_p3_sparse_batch_fans_out_per_key():
                 kv.init(k, np.zeros(n, np.float32))
                 kv.pull(k, out=np.zeros(n, np.float32))
             kv.wait()
-            kv.push_bsc_batch([0, 1],
-                              [np.array([1.0], np.float32)] * 2,
-                              [np.array([3], np.int64)] * 2)
-            agg = kv.pull_bsc_batch([0, 1])()
-            for k in (0, 1):
-                avals, aidx = agg[k]
-                dense = np.zeros(20 if k == 0 else 6, np.float32)
-                dense[aidx] = avals
-                np.testing.assert_allclose(dense[3], 2.0)  # 2 workers
+            sel = ([0, 1], [np.array([1.0], np.float32)] * 2,
+                   [np.array([3], np.int64)] * 2)
+
+            def check(agg):
+                for k in (0, 1):
+                    avals, aidx = agg[k]
+                    dense = np.zeros(20 if k == 0 else 6, np.float32)
+                    dense[aidx] = avals
+                    np.testing.assert_allclose(dense[3], 2.0)  # 2 workers
+
+            check(kv.push_pull_bsc_batch(*sel)())
+            # the chunked form: 8 wire bytes a selected element, one
+            # element a key, so a budget of 8 bytes is a key a chunk
+            log = sent.setdefault(id(kv), [])
+            real_push = kv.kvw.push
+
+            def push(kvs, rank, **kw):
+                log.append((tuple(kvs.keys), rank, kw["priority"]))
+                return real_push(kvs, rank, **kw)
+
+            kv.kvw.push = push
+            try:
+                check(kv.push_pull_bsc_batch_async(*sel, priority=7)
+                      .results(timeout=120))
+            finally:
+                del kv.kvw.push
 
         topo.run_workers(worker, include_master=master_init, timeout=300)
     finally:
         topo.stop()
+    assert len(sent) == 2
+    for log in sent.values():
+        # every message carries ONE key (possibly several of its shards
+        # for one server), and key 1's chunk rides one priority lower
+        assert log and all(len(set(keys)) == 1 for keys, _r, _p in log)
+        assert {(keys[0], prio) for keys, _r, prio in log} \
+            == {(0, 7), (1, 6)}
+        per_key_server = [(keys[0], rank) for keys, rank, _p in log]
+        assert len(per_key_server) == len(set(per_key_server))

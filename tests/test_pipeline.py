@@ -419,7 +419,7 @@ def test_async_frontier_exact_under_faultplan():
 
 
 # ---------------------------------------------------------------------------
-# pipelined device trainer == serial trainer, bit for bit
+# device trainer: the chunking of the round does not change its result
 # ---------------------------------------------------------------------------
 
 def _run_trainer(extra_cfg, rounds=8):
@@ -454,34 +454,32 @@ def _run_trainer(extra_cfg, rounds=8):
             for _ in range(rounds):
                 tr.step(shift, None)
             results[widx] = ([np.asarray(l).copy() for l in tr.leaves],
-                             tr._pipeline,
-                             len(getattr(tr, "_chunks", [])))
+                             len(tr._chunks))
 
         topo.run_workers(worker, include_master=master_init,
                          timeout=300)
     finally:
         topo.stop()
-    (l0, pipe0, nch0), (l1, pipe1, _) = results[0], results[1]
-    assert pipe0 == pipe1
+    (l0, nch0), (l1, nch1) = results[0], results[1]
+    assert nch0 == nch1
     for a, b in zip(l0, l1):
         np.testing.assert_array_equal(a, b)
-    return l0, pipe0, nch0
+    return l0, nch0
 
 
-def test_pipelined_trainer_bit_identical_to_serial():
-    """GEOMX_OVERLAP + P3_SLICE_BYTES route DeviceResidentTrainer
-    through per-chunk fetch/dispatch/apply; the post-training leaves
-    must equal the monolithic round's bit for bit (chunk flat ranges
-    partition the parameter vector; per-coordinate arithmetic is
+def test_trainer_round_is_chunking_invariant():
+    """P3_SLICE_BYTES cuts DeviceResidentTrainer's round into chunks
+    that are fetched, sent and applied independently; the post-training
+    leaves must equal the one-chunk round's bit for bit (chunk flat
+    ranges partition the parameter vector; per-coordinate arithmetic is
     unchanged)."""
-    serial, pipe_s, _ = _run_trainer({"overlap": False})
-    assert not pipe_s
-    piped, pipe_p, nchunks = _run_trainer(
-        {"overlap": True, "p3_slice_bytes": 8})
-    assert pipe_p and nchunks == 2
-    for a, b in zip(serial, piped):
+    one, nchunks = _run_trainer({"p3_slice_bytes": 0})
+    assert nchunks == 1
+    two, nchunks = _run_trainer({"p3_slice_bytes": 8})
+    assert nchunks == 2
+    for a, b in zip(one, two):
         np.testing.assert_array_equal(a, b)
-    assert any(np.abs(a).sum() > 0 for a in piped)
+    assert any(np.abs(a).sum() > 0 for a in two)
 
 
 # ---------------------------------------------------------------------------

@@ -12,6 +12,7 @@ import threading
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from geomx_tpu.simulate import InProcessHiPS
 from geomx_tpu.trainer_device import DeviceResidentTrainer
@@ -153,15 +154,59 @@ def test_packed_wire_is_int32_and_index_exact():
     kv = kv_create("local")
     tr = DeviceResidentTrainer([np.zeros(500, np.float32)], kv, gfn,
                                threshold=0.01, learning_rate=1.0)
-    packed, _u, _v = tr._fwd_compress(tr._flat, tr._u, tr._v,
-                                      jnp.asarray(0.0), None)
+    _loss, (packed,), _u, _v = tr._fwd_chunks(tr._flat, tr._u, tr._v,
+                                              jnp.asarray(0.0), None)
     assert np.asarray(packed).dtype == np.int32
     k = tr.k
-    p = np.asarray(packed)
-    idx = p[1 + k:]
-    vals = p[1:1 + k].view(np.float32)
+    p = np.asarray(packed)       # the one chunk's pack: [vals(k), idx(k)]
+    assert p.shape == (2 * k,)
+    idx = p[k:]
+    vals = p[:k].view(np.float32)
     # exact top-k of the rigged gradient: u=g, v=g -> top-|g| coords
     expect = np.argsort(-np.abs(w), kind="stable")[:k]
     assert set(idx.tolist()) == set(expect.tolist())
     np.testing.assert_array_equal(np.sort(np.abs(vals)),
                                   np.sort(np.abs(w[expect])))
+
+
+def test_local_store_sparse_round_returns_the_selection():
+    """KVStoreLocal answers the trainer's verb on the spot: one worker,
+    no updater, so a key's aggregate is its own selection, less entries
+    of value 0 (the servers' ack carries nonzeros only), in index
+    order; an empty selection comes back empty; an index outside the
+    key is refused."""
+    from geomx_tpu.kvstore import create as kv_create
+
+    kv = kv_create("local")
+    kv.init(0, np.zeros((3, 4), np.float32))
+    kv.init(1, np.zeros(5, np.float32))
+    fut = kv.push_pull_bsc_batch_async(
+        [0, 1],
+        [np.array([3.0, 0.0, -1.0], np.float32), np.zeros(0, np.float32)],
+        [np.array([11, 2, 1]), np.zeros(0, np.int64)],
+        priority=-1, slice_bytes=0)
+    assert fut.done()
+    agg = fut.results(timeout=1)
+    np.testing.assert_array_equal(agg[0][0], np.array([-1.0, 3.0],
+                                                      np.float32))
+    np.testing.assert_array_equal(agg[0][1], [1, 11])
+    assert agg[0][0].dtype == np.float32 and agg[0][1].dtype == np.int64
+    assert agg[1][0].size == 0 and agg[1][1].size == 0
+    with pytest.raises(IndexError):
+        kv.push_pull_bsc_batch_async([1], [np.ones(1, np.float32)],
+                                     [np.array([5])])
+
+
+def test_local_store_sparse_round_refuses_an_updater():
+    """With an updater the store holds weights, not an aggregate: the
+    round is refused with the trainer's own words for it."""
+    from geomx_tpu.kvstore import create as kv_create
+    from geomx_tpu.optimizer import SGD
+
+    kv = kv_create("local")
+    kv.set_optimizer(SGD(learning_rate=0.1))
+    tr = DeviceResidentTrainer(
+        [np.zeros((16,), np.float32)], kv, _grad_fn_16,
+        threshold=0.5, learning_rate=0.1)
+    with pytest.raises(RuntimeError, match="requires aggregator mode"):
+        tr.step(jnp.asarray(0.0), None)

@@ -32,7 +32,7 @@ Probes:
    result. If the value fetch costs >2x the "blocked" wall time, timing
    via block_until_ready under-measures and any steps/s derived from it
    is invalid (an MFU above 1 is the symptom).
-5. ``bsc_oracle`` — runs the DeviceResidentTrainer fwd_compress/apply
+5. ``bsc_oracle`` — runs the DeviceResidentTrainer fwd_chunks/apply_chunk
    cycle for N rounds on the live backend against a pure-numpy oracle of
    the same BSC semantics (reference: gradient_compression.cc:191-268
    momentum-corrected accumulate + per-tensor top-k + residual zeroing)
@@ -161,7 +161,7 @@ def _probe_blocking_honest(jax, jnp):
 def _probe_bsc_oracle(jax, jnp, rounds=25):
     """DeviceResidentTrainer's device cycle vs a numpy oracle.
 
-    Two-leaf toy model through the real fwd_compress/apply_sgd jitted
+    Two-leaf toy model through the real fwd_chunks/apply_chunk jitted
     functions via a local single-worker store — no transport, isolating
     the DEVICE packing + top-k + residual + scatter-apply. The
     "gradient" is deliberately matmul-free and deterministic
