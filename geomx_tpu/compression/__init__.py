@@ -16,9 +16,12 @@ before the WAN push (BSCompress, :191), the global server decompresses,
 aggregates, and compresses pull responses with the non-zero filter scaled
 by the number of global workers (BSCPullCompress, :271). Where every push
 of a round is Bi-Sparse and the servers only aggregate, nothing is
-decompressed at all: the round's aggregate is ``entries.Entries`` (sorted
-positions, float32 values) from the global server's sum to the party
-server's ack, and the response is its exact non-zero set.
+decompressed at all: a worker's selection reaches the party server's
+Bi-Sparse state as ``entries.Pairs`` (positions and values as the wire
+has them; ``bsc_compress`` adds them into ``u`` where they are), and the
+round's aggregate is ``entries.Entries`` (sorted positions, float32
+values) from the global server's sum to the party server's ack, the
+response its exact non-zero set.
 
 Wire-format divergence from the reference (documented, intentional): the
 reference pads compressed buffers to a fixed size with the placeholder
@@ -34,16 +37,16 @@ values) on the quantized combined wire (``compression.device``).
 
 from __future__ import annotations
 
-from typing import Dict, Optional, Tuple
+from typing import Dict, Optional, Tuple, Union
 
 import numpy as np
 
-from geomx_tpu.compression.entries import Entries, SPARSE_TAGS
+from geomx_tpu.compression.entries import Entries, Pairs, SPARSE_TAGS
 
 __all__ = ["make_compressor", "Compressor", "FP16Compressor", "BSCCompressor",
            "TwoBitCompressor", "MPQCompressor", "bsc_compress", "bsc_decompress",
            "bsc_pull_compress", "two_bit_quantize", "two_bit_dequantize",
-           "Entries", "SPARSE_TAGS"]
+           "takes_pairs", "Entries", "Pairs", "SPARSE_TAGS"]
 
 BSC_MOMENTUM = 0.9  # reference: gradient_compression.cc:198
 
@@ -128,8 +131,8 @@ def _select_at_least(v: np.ndarray, boundary: float, cap: int) -> np.ndarray:
     return np.concatenate(found)[:cap]
 
 
-def bsc_compress(grad: np.ndarray, u: np.ndarray, v: np.ndarray,
-                 threshold: float,
+def bsc_compress(grad: Union[np.ndarray, Pairs], u: np.ndarray,
+                 v: np.ndarray, threshold: float,
                  rng: Optional[np.random.Generator] = None,
                  positions: Optional[np.ndarray] = None,
                  ) -> Tuple[np.ndarray, np.ndarray]:
@@ -138,13 +141,24 @@ def bsc_compress(grad: np.ndarray, u: np.ndarray, v: np.ndarray,
     Mutates ``u``/``v`` in place (momentum correction + residual reset for
     the transmitted coordinates); reads ``grad`` only. Returns (values,
     indices). ``positions``: see :func:`bsc_sample_boundary`.
+
+    ``grad`` is an array or, sparse, :class:`Pairs` over as many
+    elements: its values are then added into ``u`` where they are. Every
+    element sees the same float32 operations in the same order either
+    way (``0.9 * u + g``, then ``v + u``), so the selection, ``u`` and
+    ``v`` are equal (an untouched ``-0.0`` of ``u`` keeps the sign that
+    ``+ 0.0`` clears; a position repeated inside one payload adds its
+    values to ``u`` one by one, not their sum).
     """
     if rng is None and positions is None:
         rng = np.random.default_rng(42)  # reference uses a fixed seed (:212)
     n = grad.size
     zipped = max(int(n * threshold), 1)
     u *= BSC_MOMENTUM
-    u += grad
+    if isinstance(grad, Pairs):
+        grad.add_into(u)
+    else:
+        u += grad
     v += u
     boundary = bsc_sample_boundary(v, threshold, rng, positions)
     selected = _select_at_least(v, boundary, zipped)
@@ -217,7 +231,9 @@ class Compressor:
     type_name = "none"
 
     def compress_push(self, arr: np.ndarray, state_key=None):
-        """-> (wire_values, aux_or_None, tag)."""
+        """-> (wire_values, aux_or_None, tag). ``arr`` is an array:
+        only a compressor that :func:`takes_pairs` (the host Bi-Sparse
+        pass) may be handed a sparse gradient instead."""
         return arr, None, ""
 
     def decompress_push(self, tag: str, val: np.ndarray,
@@ -227,12 +243,15 @@ class Compressor:
         the round's accumulator without copying it.
 
         This is the path of a push whose receiver needs every element:
-        a party server's worker pushes (its own Bi-Sparse pass runs on
-        the dense aggregate) and the dense wires. A GLOBAL store does
-        not call it for a ``bsc`` / ``bsc16`` push: it takes the payload
-        as :class:`Entries` (``Entries.from_wire``), which keep the
-        wire's own arrays, and sums index lists; the store is dense
-        again only where someone asks it for an array."""
+        the dense wires, and a ``bsc`` / ``bsc16`` push to a server that
+        applies it or forwards an array (single tier, HFA, TSEngine, a
+        compressor that does not :func:`takes_pairs`). Neither store
+        calls it for a Bi-Sparse push it can keep sparse: a GLOBAL store
+        takes the payload as :class:`Entries` (``Entries.from_wire``)
+        and sums index lists, a party server that re-selects with
+        Bi-Sparse takes it as :class:`Pairs` and hands them to
+        ``compress_push``; both keep the wire's own arrays, and a store
+        is dense again only where someone asks it for an array."""
         return _generic_decompress(tag, val, aux, orig_len)
 
     def compress_pull(self, tag: str, arr: np.ndarray, factor: int):
@@ -279,27 +298,14 @@ def _generic_decompress(tag, val, aux, orig_len):
                 ids, rows = ids[ok], rows[ok]
             np.add.at(out.reshape(n_rows, row_len), ids, rows)
         return out
-    if tag in ("bsc", "bsc16"):
+    if tag in SPARSE_TAGS:
         # scatter-ADD, not assignment: a push payload carrying duplicate
         # indices must aggregate by sum (same contract as the "rsp"
         # branch above); for pull payloads indices are unique (nonzeros
         # of one array) so add and set coincide. "bsc16" is the same
-        # wire with float16 values (quantized combined wire) — the
-        # astype below widens either way and aggregation stays fp32
-        assert aux is not None, "bsc payload missing index aux array"
-        idx = np.asarray(aux, dtype=np.int64).ravel()
-        vals = np.asarray(val, dtype=np.float32).ravel()
-        out = np.zeros(orig_len, dtype=np.float32)
-        ok = (idx >= 0) & (idx < orig_len)
-        if not ok.all():
-            import logging
-
-            logging.getLogger("geomx.compression").warning(
-                "bsc push: dropping %d out-of-range indices "
-                "(payload addresses %d elements)",
-                int((~ok).sum()), orig_len)
-        np.add.at(out, idx[ok], vals[ok])
-        return out
+        # wire with float16 values (quantized combined wire) — they
+        # widen on arrival and aggregation stays fp32
+        return Pairs.from_wire(val, aux, orig_len).dense()
     if tag == "2bit":
         assert aux is not None and aux.size == 1, "2bit payload missing threshold"
         return two_bit_dequantize(val, orig_len, float(aux[0]))
@@ -339,15 +345,20 @@ class BSCCompressor(Compressor):
         self._rng_lock = __import__("threading").Lock()
 
     def compress_push(self, arr, state_key=None):
+        """``arr``: the gradient as an array or as :class:`Pairs` (a
+        party server's aggregate of Bi-Sparse pushes, never made
+        dense); the state, the draw and the selection are the same."""
         if state_key not in self._u:
             self._u[state_key] = np.zeros(arr.size, dtype=np.float32)
             self._v[state_key] = np.zeros(arr.size, dtype=np.float32)
         with self._rng_lock:
             positions = bsc_sample_positions(arr.size, self.threshold,
                                              self._rng)
+        if not isinstance(arr, Pairs):
+            arr = np.asarray(arr, dtype=np.float32)
         values, indices = bsc_compress(
-            np.asarray(arr, dtype=np.float32), self._u[state_key],
-            self._v[state_key], self.threshold, positions=positions)
+            arr, self._u[state_key], self._v[state_key], self.threshold,
+            positions=positions)
         return values, indices, "bsc"
 
     def compress_pull(self, tag, arr, factor):
@@ -418,6 +429,16 @@ class MPQCompressor(Compressor):
 
     def push_tag(self, num_elems: int = 0) -> str:
         return self._route(num_elems).push_tag(num_elems)
+
+
+def takes_pairs(gc, num_elems: int) -> bool:
+    """Whether ``gc.compress_push`` of a key of ``num_elems`` elements
+    takes its gradient as :class:`Pairs`: the host Bi-Sparse pass,
+    configured as such or reached by MPQ's route for this size. Every
+    other compressor (the device one included) reads an array."""
+    if isinstance(gc, MPQCompressor):
+        gc = gc._route(num_elems)
+    return isinstance(gc, BSCCompressor)
 
 
 def make_compressor(params: Optional[dict]) -> Compressor:

@@ -6,12 +6,16 @@ at most. :class:`Entries` carries it as what the ``bsc`` / ``bsc16``
 wire already is — sorted, unique positions and float32 values, plus the
 range's length — so a server sums index lists instead of scattering each
 push into ``np.zeros(n)`` and finding the support again with
-``np.nonzero``. Every pass here is O(entries), none is O(n) except
-:meth:`Entries.dense`, which whoever truly needs an array calls once.
+``np.nonzero``. :class:`Pairs` is the same without the order: a worker's
+selection as ``lax.top_k`` hands it over, by magnitude, which a party
+server adds into its Bi-Sparse state where it is (``Pairs.add_into``)
+and orders only to merge it with a second one. Every pass here is
+O(entries), none is O(n) except ``dense``, which whoever truly needs an
+array calls once.
 
 Arrays are never written after construction: a server hands the same
 ``idx`` / ``vals`` to every puller of a round and keeps the wire's own
-arrays where they already are in order.
+arrays where it can.
 """
 
 from __future__ import annotations
@@ -21,7 +25,7 @@ from typing import Sequence
 
 import numpy as np
 
-__all__ = ["Entries", "SPARSE_TAGS"]
+__all__ = ["Pairs", "Entries", "SPARSE_TAGS"]
 
 # wire tags whose payload is (values, positions): float32 or float16 values
 SPARSE_TAGS = ("bsc", "bsc16")
@@ -42,9 +46,15 @@ def _sum_runs(idx: np.ndarray, vals: np.ndarray):
     return idx[starts], np.add.reduceat(vals, starts)
 
 
-class Entries:
-    """``size`` float32 elements of which ``idx`` (sorted, unique, in
-    ``[0, size)``) hold ``vals``; every other element is 0."""
+def _itype(size: int):
+    """The position type of :class:`Entries` over ``size`` elements."""
+    return np.int32 if size <= np.iinfo(np.int32).max else np.int64
+
+
+class Pairs:
+    """``size`` float32 elements given as (position, value) pairs: every
+    ``idx`` lies in ``[0, size)``, in any order; a position that repeats
+    holds the sum of its values; every other element is 0."""
 
     __slots__ = ("idx", "vals", "size")
     dtype = np.dtype(np.float32)
@@ -53,47 +63,91 @@ class Entries:
         self.idx, self.vals, self.size = idx, vals, int(size)
 
     @classmethod
-    def from_wire(cls, val, aux, size: int) -> "Entries":
-        """A ``bsc`` / ``bsc16`` payload addressing ``size`` elements.
-
-        Positions outside the range are dropped with the warning the
-        dense scatter gives; duplicate positions inside one payload sum
-        (the wire's contract). A payload already in order — what
-        every selection and every server response is — is taken as it
-        is, its float32 values without a copy."""
+    def from_wire(cls, val, aux, size: int) -> "Pairs":
+        """A ``bsc`` / ``bsc16`` payload addressing ``size`` elements,
+        as the wire's own arrays (float16 values widen). Positions
+        outside the range are dropped with a warning. O(entries), no
+        sort."""
         if aux is None:
             raise ValueError("bsc payload missing index aux array")
         idx = np.asarray(aux).ravel()
         if idx.dtype.kind not in "iu":
             idx = idx.astype(np.int64)
         vals = np.asarray(val, dtype=np.float32).ravel()
-        if idx.size and not (
-                idx[0] >= 0 and idx[-1] < size
-                and (idx.size < 2 or bool((idx[1:] > idx[:-1]).all()))):
+        if idx.size and not (idx.min() >= 0 and idx.max() < size):
             ok = (idx >= 0) & (idx < size)
-            if not ok.all():
-                logging.getLogger("geomx.compression").warning(
-                    "bsc push: dropping %d out-of-range indices "
-                    "(payload addresses %d elements)",
-                    int((~ok).sum()), size)
-                idx, vals = idx[ok], vals[ok]
-            order = np.argsort(idx, kind="stable")
-            idx, vals = _sum_runs(idx[order], vals[order])
-        itype = np.int32 if size <= np.iinfo(np.int32).max else np.int64
-        return cls(idx.astype(itype, copy=False), vals, size)
+            logging.getLogger("geomx.compression").warning(
+                "bsc push: dropping %d out-of-range indices "
+                "(payload addresses %d elements)",
+                int((~ok).sum()), size)
+            idx, vals = idx[ok], vals[ok]
+        return cls(idx, vals, size)
 
-    def __getitem__(self, s: slice) -> "Entries":
+    def entries(self) -> "Entries":
+        """These pairs in order, each position once (equal positions
+        summed in the order they came)."""
+        order = np.argsort(self.idx, kind="stable")
+        idx, vals = _sum_runs(self.idx[order], self.vals[order])
+        return Entries(idx.astype(_itype(self.size), copy=False), vals,
+                       self.size)
+
+    def __getitem__(self, s: slice):
         """The range ``[s.start, s.stop)`` with positions relative to its
         start, as slicing the dense array gives."""
         lo, hi, step = s.indices(self.size)
         if step != 1:
-            raise IndexError("Entries take contiguous ranges only")
+            raise IndexError("contiguous ranges only")
         if lo == 0 and hi == self.size:
             return self
+        idx, vals = self._between(lo, hi)
+        return type(self)(idx - lo if lo else idx, vals, max(hi - lo, 0))
+
+    def _between(self, lo: int, hi: int):
+        """(idx, vals) of the pairs at positions ``[lo, hi)``: a mask,
+        O(entries) a cut and no sort."""
+        keep = (self.idx >= lo) & (self.idx < hi)
+        return self.idx[keep], self.vals[keep]
+
+    def add_into(self, out: np.ndarray) -> None:
+        """``out += dense()`` at the positions held, without the array:
+        what a float32 ``out`` gets at a position held once is bit for
+        bit what the dense ``+=`` gives it."""
+        np.add.at(out, self.idx, self.vals)
+
+    def dense(self) -> np.ndarray:
+        """A new float32 array of ``size`` elements. The one O(n) pass."""
+        out = np.zeros(self.size, dtype=np.float32)
+        self.add_into(out)
+        return out
+
+
+class Entries(Pairs):
+    """:class:`Pairs` in order: ``idx`` sorted and unique."""
+
+    __slots__ = ()
+
+    @classmethod
+    def from_wire(cls, val, aux, size: int) -> "Entries":
+        """A ``bsc`` / ``bsc16`` payload addressing ``size`` elements.
+
+        Positions outside the range are dropped with the warning the
+        dense scatter gives; duplicate positions inside one payload sum
+        (the wire's contract). A payload already in order — what
+        every server's selection and response is — is taken as it
+        is, its float32 values without a copy."""
+        pairs = Pairs.from_wire(val, aux, size)
+        idx = pairs.idx
+        if idx.size < 2 or bool((idx[1:] > idx[:-1]).all()):
+            return cls(idx.astype(_itype(size), copy=False), pairs.vals,
+                       size)
+        return pairs.entries()
+
+    def entries(self) -> "Entries":
+        return self
+
+    def _between(self, lo: int, hi: int):
         a, b = np.searchsorted(self.idx, (lo, hi))
-        idx = self.idx[a:b]
-        return Entries(idx - lo if lo else idx, self.vals[a:b],
-                       max(hi - lo, 0))
+        return self.idx[a:b], self.vals[a:b]
 
     def placed(self, offset: int, size: int) -> "Entries":
         """These entries as part of a range of ``size`` elements that
@@ -148,7 +202,7 @@ class Entries:
         return 2 * self.idx.size <= self.size
 
     def dense(self) -> np.ndarray:
-        """A new float32 array of ``size`` elements. The one O(n) pass."""
+        """As :meth:`Pairs.dense`, by assignment: no position repeats."""
         out = np.zeros(self.size, dtype=np.float32)
         out[self.idx] = self.vals
         return out

@@ -37,13 +37,21 @@ protocol, re-designed for host-side asynchrony without the MXNet engine:
 - WAN compression (FP16 / BSC / MPQ) applies on the inter-DC hop only:
   party servers compress forwarded aggregates and request compressed pulls;
   the LAN tier stays uncompressed — matching the reference's placement;
-- a Bi-Sparse round stays sparse: an FSA aggregator (no updater, no HFA)
-  whose pushes all arrive as ``bsc`` / ``bsc16`` sums their index lists
-  (``compression.Entries``), stores the sum as entries and answers with
-  them; the party server keeps the pull-back as entries and hands it to
-  its workers. A store is made dense once, by ``_KeyState.stored``, for
-  whoever reads an array. Which of the two a round is follows from the
-  pushes' wire tags and the server's mode, nothing else.
+- a Bi-Sparse round stays sparse, both ways. Forward: a party server
+  that re-selects with the host Bi-Sparse pass keeps its workers'
+  ``bsc`` / ``bsc16`` pushes as what the wire carries, positions and
+  values (``compression.Pairs``: one push as it came, unsorted; two or
+  more merged as ``Entries``), through ``st.merged`` and ``st.outbound``
+  to ``compress_push``, which adds them into its momentum state where
+  they are; no array of the key's length carries 1% of it. Back: an FSA
+  aggregator (no updater, no HFA) whose pushes all arrive as ``bsc`` /
+  ``bsc16`` sums their index lists (``compression.Entries``), stores the
+  sum as entries and answers with them; the party server keeps the
+  pull-back as entries and hands it to its workers. An aggregate is
+  made dense once, by ``_as_array``, and a store by
+  ``_KeyState.stored``, for whoever reads an array. Which of the two a
+  round is follows from the pushes' wire tags and the server's mode,
+  nothing else.
 
 Generalization over the reference: a global server stores its CANONICAL
 RANGES of each key (from the deterministic sharding over the full key
@@ -72,7 +80,8 @@ from geomx_tpu import config as cfg_mod
 from geomx_tpu import kernels_native
 from geomx_tpu import profiler
 from geomx_tpu import telemetry
-from geomx_tpu.compression import Entries, SPARSE_TAGS, make_compressor
+from geomx_tpu.compression import (Entries, Pairs, SPARSE_TAGS,
+                                   make_compressor, takes_pairs)
 from geomx_tpu.compression.device import WireCodec
 from geomx_tpu.kvstore import sharding
 from geomx_tpu.kvstore.base import Command, DATA_INIT
@@ -90,8 +99,9 @@ Action = Callable[[], None]
 
 
 def _as_array(x) -> np.ndarray:
-    """``x`` dense: a push slice or an aggregate that may be ``Entries``."""
-    return x.dense() if isinstance(x, Entries) else x
+    """``x`` dense: a push slice or an aggregate that may be ``Pairs``
+    (``Entries`` are)."""
+    return x.dense() if isinstance(x, Pairs) else x
 
 
 class _SysModulesUnpickler(pickle.Unpickler):
@@ -188,8 +198,10 @@ class _KeyState:
     it makes the entries dense on first use and keeps that array until
     the store is next replaced, so a dense pull, a snapshot, an updater
     or a test reads and assigns ``st.stored`` as before. ``merged``, the
-    round in progress on a global store, is likewise an array or
-    ``Entries``. Every access runs under ``lock``."""
+    round in progress, is likewise an array or sparse: ``Entries`` on a
+    global store; on a party server that forwards sparse, the one push
+    as ``Pairs`` or the merge of several as ``Entries``, which
+    ``outbound`` then stages. Every access runs under ``lock``."""
 
     __slots__ = (
         "lock",
@@ -210,7 +222,7 @@ class _KeyState:
         # the aggregate staged for the global tier lives here, NEVER in
         # `stored` — `stored` always holds parameters, so a pull can never
         # observe a gradient (the round-1/2 freshness race)
-        self.outbound: Optional[np.ndarray] = None
+        self.outbound = None
         self.milestone: Optional[np.ndarray] = None
         self.merged = None
         self.push_reqs: List[Tuple[ReqMeta, KVServer]] = []
@@ -719,18 +731,22 @@ class KVStoreDistServer:
         of :meth:`_handle_data`)."""
         if req.push:
             wire = val = np.asarray(kvs.vals[i]).ravel()
+            n = kvs.len_of(i) or val.size
             if global_store and kvs.compr in SPARSE_TAGS:
                 # a global store sums index lists (_accumulate): the
                 # payload stays what the wire made it
-                val = Entries.from_wire(val, kvs.aux[i],
-                                        kvs.len_of(i) or val.size)
+                val = Entries.from_wire(val, kvs.aux[i], n)
+            elif kvs.compr in SPARSE_TAGS and self._forwards_sparse(
+                    self._state(key, off), n):
+                # a party server's re-selection adds the pairs into its
+                # state where they are: the wire's own arrays, no sort
+                val = Pairs.from_wire(val, kvs.aux[i], n)
             elif kvs.compr:
                 with profiler.scope(f"decompress:{kvs.compr}",
                                     cat="kvstore.op") if tagging \
                         else _null_ctx():
                     val = self.gc.decompress_push(
-                        kvs.compr, val, kvs.aux[i],
-                        kvs.len_of(i) or val.size)
+                        kvs.compr, val, kvs.aux[i], n)
             # an array the decompressor built belongs to this handler;
             # the message's own buffer (or a view of anything) does not
             owned = (isinstance(val, np.ndarray) and val is not wire
@@ -812,12 +828,20 @@ class KVStoreDistServer:
         if not st.push_reqs:
             # later pushes of the round accumulate into st.merged in
             # place: it must be this state's alone, so ``val`` is taken
-            # as it is only where the handler owns it (``owned``)
-            take = (owned and val.dtype == np.float32
-                    and val.flags.c_contiguous and val.flags.writeable)
+            # as it is only where the handler owns it (``owned``), or
+            # where it is sparse and nobody writes it
+            take = isinstance(val, Pairs) or (
+                owned and val.dtype == np.float32
+                and val.flags.c_contiguous and val.flags.writeable)
             st.merged = val if take else val.astype(np.float32, copy=True)
+        elif isinstance(val, Pairs) and isinstance(st.merged, Pairs):
+            # a second selection: the index lists merge, equal positions
+            # summed in arrival order, as the dense += sums them
+            st.merged = st.merged.entries().add(val.entries())
         else:
-            v32 = np.ascontiguousarray(val, dtype=np.float32)
+            # the first dense push makes the round dense (_accumulate)
+            st.merged = _as_array(st.merged)
+            v32 = np.ascontiguousarray(_as_array(val), dtype=np.float32)
             if not kernels_native.acc(st.merged, v32):
                 st.merged += v32
         st.push_reqs.extend([(req, srv)] * max(req.num_merge, 1))
@@ -884,7 +908,8 @@ class KVStoreDistServer:
         # (no copy where the dtype already fits: the next round REBINDS
         # st.merged at its first push and never writes into this array,
         # so st.outbound stays the bytes a WAN retry re-slices)
-        st.outbound = payload.astype(st.dtype, copy=False)
+        st.outbound = (payload if isinstance(payload, Pairs)
+                       else payload.astype(st.dtype, copy=False))
         st.staging = True
         st.cycle += 1
         cyc = st.cycle
@@ -1050,6 +1075,20 @@ class KVStoreDistServer:
         if st.elems_received < self._expected_global_elems(st):
             return []
         return self._complete_fsa_round(st, key)
+
+    def _forwards_sparse(self, st: _KeyState, n: int) -> bool:
+        """Whether this server, as configured, takes a Bi-Sparse push of
+        this key from its workers as ``Pairs``: a party server whose
+        forward is a Bi-Sparse re-selection on the host, which reads
+        the gradient only where it is non-zero. A single-tier server
+        applies the aggregate, HFA subtracts a dense milestone from it,
+        TSEngine relays arrays, and every other compressor (none, fp16,
+        2bit, MPQ below its bound, the device pass) reads an array."""
+        return (self.has_global_tier and not self.use_hfa
+                and not (self.ts_global is not None
+                         and self.sync_global_mode)
+                and st.has_store and st.dtype == np.float32
+                and takes_pairs(self.gc, n))
 
     def _keeps_sparse(self, st: _KeyState) -> bool:
         """Whether this server, as configured, stores a round's aggregate
@@ -1454,8 +1493,14 @@ class KVStoreDistServer:
             return self._wire.resolve(n)
         return ""
 
-    def _wan_compress(self, st: _KeyState, key: int, lo: int,
-                      sub: np.ndarray):
+    @staticmethod
+    def _outbound_slice(st: _KeyState, lo: int, hi: int):
+        """Elements ``[lo, hi)`` of the key from the staged aggregate:
+        a contiguous array, or the pairs that fall there."""
+        sub = st.outbound[lo - st.offset:hi - st.offset]
+        return sub if isinstance(sub, Pairs) else np.ascontiguousarray(sub)
+
+    def _wan_compress(self, st: _KeyState, key: int, lo: int, sub):
         """Compress one WAN-forward slice -> (wire_val, aux, compr).
 
         The configured compressor still runs first so BSC momentum /
@@ -1464,8 +1509,20 @@ class KVStoreDistServer:
         or, when the compressor was a no-op, packs the slice itself
         (fp16 / 2bit with the ("fwd", key, lo) residual). Callers cache
         the result in ``st.fwd_wire`` — a WAN retry must resend the
-        SAME bytes, never re-encode."""
-        tag = self._wan_wire_tag(st, int(sub.size))
+        SAME bytes, never re-encode.
+
+        ``sub`` is an array or ``Pairs``; the compressor is handed the
+        pairs where it ``takes_pairs`` for a slice of this size, and
+        the array they make otherwise."""
+        n = int(sub.size)
+        tag = self._wan_wire_tag(st, n)
+        if isinstance(sub, Pairs):
+            if not takes_pairs(self.gc, n):
+                sub = sub.dense()
+            elif lo == st.offset:
+                # once a (key, shard) round, however many global slices
+                telemetry.counter_inc("server.sparse_forward_key_rounds",
+                                      tier=self._tier)
         t0 = time.perf_counter()
         wv, aux, t = self.gc.compress_push(sub, (key, lo))
         if t == "bsc":
@@ -1519,8 +1576,8 @@ class KVStoreDistServer:
                 return
             cached = st.fwd_wire.get(lo)
             if cached is None:
-                sub = np.ascontiguousarray(st.outbound[lo - off:hi - off])
-                cached = self._wan_compress(st, key, lo, sub)
+                cached = self._wan_compress(
+                    st, key, lo, self._outbound_slice(st, lo, hi))
                 st.fwd_wire[lo] = cached
         wire_val, aux, compr = cached
         kvs = KVPairs(keys=[key], vals=[wire_val], aux=[aux],
@@ -1567,8 +1624,8 @@ class KVStoreDistServer:
                 st.fwd_wire = {}
                 total = st.total
                 for g_rank, lo, hi in slices:
-                    sub = np.ascontiguousarray(st.outbound[lo - off:hi - off])
-                    cached = self._wan_compress(st, key, lo, sub)
+                    cached = self._wan_compress(
+                        st, key, lo, self._outbound_slice(st, lo, hi))
                     st.fwd_wire[lo] = cached
                     wire_val, aux, compr = cached
                     per_rank.setdefault((g_rank, compr), []).append(
@@ -1751,7 +1808,7 @@ class KVStoreDistServer:
         with st.lock:
             if st.cycle != cycle:
                 return
-            payload = st.outbound
+            payload = _as_array(st.outbound)
             total = st.total
             length = st.length
             ranges = sharding.assign(key, total, self.po_global.num_servers,

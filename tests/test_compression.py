@@ -5,6 +5,8 @@ momentum-corrected top-k with residual reset for BSC, residual-feedback
 2-bit quantization, size-threshold routing for MPQ.
 """
 
+import logging
+
 import numpy as np
 import pytest
 
@@ -12,6 +14,8 @@ from geomx_tpu.compression import (
     BSCCompressor,
     FP16Compressor,
     MPQCompressor,
+    Pairs,
+    _generic_decompress,
     TwoBitCompressor,
     bsc_compress,
     bsc_decompress,
@@ -19,6 +23,7 @@ from geomx_tpu.compression import (
     bsc_sample_boundary,
     bsc_sample_positions,
     make_compressor,
+    takes_pairs,
     two_bit_dequantize,
     two_bit_quantize,
 )
@@ -107,6 +112,129 @@ def test_bsc_compress_push_allocates_no_index_of_every_position():
         tracemalloc.stop()
     assert tag == "bsc" and 0 < values.size <= n // 100
     assert peak - start < 4 * n, (peak - start) / n
+
+
+def _compressor_pair(n, seed):
+    """Two Bi-Sparse compressors with one starting state for key "k":
+    the same ``u`` and ``v`` (copies) and generators at the same point."""
+    rng = np.random.default_rng(seed)
+    u0 = rng.normal(size=n).astype(np.float32)
+    v0 = rng.normal(size=n).astype(np.float32)
+    pair = BSCCompressor(0.01), BSCCompressor(0.01)
+    for gc in pair:
+        gc._u["k"], gc._v["k"] = u0.copy(), v0.copy()
+    return pair
+
+
+def _same_state(a, b):
+    for name in ("_u", "_v"):
+        x, y = getattr(a, name)["k"], getattr(b, name)["k"]
+        assert (x == y).all(), name
+
+
+@pytest.mark.parametrize("wire", ["bsc", "bsc16"])
+@pytest.mark.parametrize("order", ["magnitude", "sorted"])
+@pytest.mark.parametrize("n", [1, 768, 1_000_000])
+def test_bsc_compress_of_pairs_equals_the_dense_pass(n, order, wire):
+    """Five rounds of a worker's selection (1% of the key, in the order
+    ``lax.top_k`` hands it over or in index order), given to the pass as
+    ``Pairs`` and as the array ``decompress_push`` builds: the same
+    entries leave, and ``u`` and ``v`` are equal, every round."""
+    dense, sparse = _compressor_pair(n, seed=n)
+    rng = np.random.default_rng(n + 1)
+    k = max(int(n * 0.01), 1)
+    for _rnd in range(5):
+        idx = rng.choice(n, k, replace=False).astype(np.int32)
+        vals = rng.normal(size=k).astype(np.float32)
+        by = np.argsort(-np.abs(vals)) if order == "magnitude" \
+            else np.argsort(idx)
+        idx = idx[by]
+        vals = vals[by].astype(np.float16 if wire == "bsc16" else np.float32)
+        want = dense.compress_push(_generic_decompress(wire, vals, idx, n),
+                                   "k")
+        got = sparse.compress_push(Pairs.from_wire(vals, idx, n), "k")
+        assert got[2] == want[2] == "bsc"
+        assert got[0].dtype == np.float32 and got[1].dtype == np.int32
+        assert (got[0] == want[0]).all() and (got[1] == want[1]).all()
+        _same_state(dense, sparse)
+
+
+def test_bsc_compress_of_pairs_with_a_repeated_position():
+    """A payload that repeats a position inside itself (no selection of
+    this repo does): ``u`` gets its values one by one where the dense
+    scatter summed them first, one unit of the last place apart at most,
+    on that element alone."""
+    n = 768
+    dense, sparse = _compressor_pair(n, seed=5)
+    idx = np.array([700, 3, 40, 3, 3], np.int32)
+    vals = np.array([0.1, 0.3, -2.0, 1e-4, 0.7], np.float32)
+    pairs = Pairs.from_wire(vals, idx, n)
+    np.testing.assert_array_equal(pairs.dense(),
+                                  _generic_decompress("bsc", vals, idx, n))
+    u = sparse._u["k"].copy()
+    u *= np.float32(0.9)
+    pairs.add_into(u)
+    want = dense._u["k"] * np.float32(0.9) + pairs.dense()
+    others = np.arange(n) != 3
+    assert (u[others] == want[others]).all()
+    assert abs(u[3] - want[3]) <= np.spacing(np.abs(want[3]))
+    # and through the pass: the same positions leave or stay
+    a = dense.compress_push(pairs.dense(), "k")
+    b = sparse.compress_push(pairs, "k")
+    np.testing.assert_array_equal(a[1], b[1])
+    np.testing.assert_allclose(a[0], b[0], rtol=2e-7, atol=0)
+
+
+def test_bsc_compress_of_pairs_drops_out_of_range_positions(caplog):
+    n = 768
+    dense, sparse = _compressor_pair(n, seed=6)
+    idx = np.array([5, n, 40, -1, 767], np.int32)
+    vals = np.array([1.0, 9.0, -2.0, 9.0, 0.5], np.float32)
+    with caplog.at_level(logging.WARNING, logger="geomx.compression"):
+        arr = _generic_decompress("bsc", vals, idx, n)
+        pairs = Pairs.from_wire(vals, idx, n)
+    said = [r.getMessage() for r in caplog.records]
+    assert len(said) == 2 and said[0] == said[1]
+    assert "dropping 2 out-of-range indices" in said[0]
+    np.testing.assert_array_equal(pairs.idx, [5, 40, 767])
+    want = dense.compress_push(arr, "k")
+    got = sparse.compress_push(pairs, "k")
+    assert (got[0] == want[0]).all() and (got[1] == want[1]).all()
+    _same_state(dense, sparse)
+
+
+def test_pairs_cut_and_order():
+    """``Pairs`` keep the wire's arrays and its order; a range is cut by
+    a mask (no sort), ``entries()`` orders them and sums repeats in the
+    order they came."""
+    idx = np.array([9, 2, 5, 2], np.int32)
+    vals = np.array([1.0, 2.0, 3.0, 0.5], np.float32)
+    p = Pairs.from_wire(vals, idx, 10)
+    assert np.shares_memory(p.idx, idx) and np.shares_memory(p.vals, vals)
+    assert p[0:10] is p
+    cut = p[2:9]
+    np.testing.assert_array_equal(cut.idx, [0, 3, 0])
+    np.testing.assert_array_equal(cut.vals, [2.0, 3.0, 0.5])
+    assert cut.size == 7
+    np.testing.assert_array_equal(cut.dense(), p.dense()[2:9])
+    e = p.entries()
+    np.testing.assert_array_equal(e.idx, [2, 5, 9])
+    np.testing.assert_array_equal(e.vals, [2.5, 3.0, 1.0])
+    assert e.entries() is e and e.idx.dtype == np.int32
+
+
+@pytest.mark.parametrize("params,n,want", [
+    ({"type": "bsc", "device": False}, 64, True),
+    ({"type": "mpq", "device": False, "size_lower_bound": 1000}, 999, False),
+    ({"type": "mpq", "device": False, "size_lower_bound": 1000}, 1000, True),
+    ({"type": "bsc", "device": True}, 10_000, False),
+    ({"type": "mpq", "device": True, "size_lower_bound": 1000}, 5000, False),
+    ({"type": "fp16"}, 10_000, False),
+    ({"type": "2bit"}, 10_000, False),
+    (None, 10_000, False),
+])
+def test_takes_pairs_is_the_host_bsc_pass_alone(params, n, want):
+    assert takes_pairs(make_compressor(params), n) is want
 
 
 def test_bsc_pull_compress_keeps_nonzeros():

@@ -7,9 +7,12 @@ on, instead of scattering each push into ``np.zeros(n)`` and finding the
 support again with ``np.nonzero``. The first half holds that path to the
 dense path it replaces, which is still in the tree: the same pushes sent
 dense run today's ``+=`` and non-zero filter, and the two answers must
-agree to the bit. The second half shows the bypass through a live
-two-party topology: a round with a dense wire, an updater, HFA or
-MixedSync never goes sparse and gives the parameters the numpy sum gives.
+agree to the bit. The party server's forward half is held the same way:
+its workers' selections reach its own Bi-Sparse pass as ``Pairs``, and
+what it sends on is what ``_generic_decompress`` and the dense pass give.
+The second half shows the bypass through a live two-party topology: a
+round with a dense wire, an updater, HFA or MixedSync never goes sparse
+and gives the parameters the numpy sum gives.
 """
 
 import logging
@@ -21,14 +24,17 @@ import numpy as np
 import pytest
 
 from geomx_tpu import telemetry
-from geomx_tpu.compression import (BSCCompressor, Entries, _generic_decompress,
-                                   two_bit_dequantize, two_bit_quantize)
+from geomx_tpu.compression import (BSCCompressor, Entries, FP16Compressor,
+                                   MPQCompressor, Pairs, _generic_decompress,
+                                   make_compressor, two_bit_dequantize,
+                                   two_bit_quantize)
 from geomx_tpu.kvstore.base import DATA_INIT
 from geomx_tpu.kvstore.replication import ReplicationManager
 from geomx_tpu.kvstore.server import KVStoreDistServer
 from geomx_tpu.optimizer import SGD
 from geomx_tpu.ps.kv_app import KVPairs, ReqMeta
 from geomx_tpu.simulate import InProcessHiPS
+from tests.harness import SingleTier, _parallel
 
 KEY = 5
 
@@ -45,10 +51,11 @@ class RecordingApp:
         self.responses.append((req, kvs))
 
 
-def _req(sender, ts, compr, head=0, pull=True):
+def _req(sender, ts, compr, head=0, pull=True, num_merge=1):
     return ReqMeta(sender=sender, timestamp=ts, customer_id=0, push=True,
                    pull=pull, simple_app=False, head=head, body="",
-                   priority=0, version=0, iters=0, compr=compr, num_merge=1)
+                   priority=0, version=0, iters=0, compr=compr,
+                   num_merge=num_merge)
 
 
 def _server(parties, is_global, fsa_slice_elems=0):
@@ -69,6 +76,66 @@ def _server(parties, is_global, fsa_slice_elems=0):
     s.po_global = types.SimpleNamespace(
         my_rank=0, num_servers=1, num_live_workers=lambda: parties)
     return s
+
+
+class RecordingGlobalWorker:
+    """The party server's client of the global tier: keeps what is
+    pushed and answers a push's callback with the responses it is given."""
+
+    def __init__(self):
+        self.pushed = []            # (kvs, g_rank, cb)
+        self.responses = {}
+
+    def push(self, kvs, g_rank, cb=None, **kw):
+        assert kw["pull"] and kw["party_nsrv"] == 1
+        self.pushed.append((kvs, g_rank, cb))
+
+    def take_failure(self, ts):
+        return None
+
+    def take_response(self, ts):
+        return self.responses.pop(ts)
+
+
+def _party_server(workers, global_servers=1, n=768):
+    """A party server of ``workers`` workers below ``global_servers``
+    global servers that split every key evenly, key ``KEY`` of ``n``
+    elements initialized; Bi-Sparse 0.01 on its forward."""
+    s = _server(1, False)
+    s.has_global_tier = True
+    s.cfg.bigarray_bound = 1 if global_servers > 1 else 1 << 40
+    s.po_global.num_servers = global_servers
+    s.po_local = types.SimpleNamespace(
+        num_servers=1, num_live_workers=lambda: workers,
+        van=types.SimpleNamespace())
+    s._wire = types.SimpleNamespace(enabled=lambda: False)
+    s._wire_wan = s._transport = None
+    s._wan_trace = (-1, -1)
+    s._fwd_tls = threading.local()
+    s.worker_global = RecordingGlobalWorker()
+    st = s._state(KEY, 0)
+    st.stored = np.zeros(n, np.float32)
+    st.length = st.total = n
+    st.initialized = True
+    return s
+
+
+def _party_push(s, app, sender, ts, wire, vals, idx, n, num_merge=1):
+    """One worker's combined push+pull of ``KEY`` on the local tier:
+    ``wire`` "" sends the selection as the dense array it stands for."""
+    if wire:
+        vdt = np.float16 if wire == "bsc16" else np.float32
+        kvs = KVPairs(keys=[KEY], vals=[vals.astype(vdt, copy=False)],
+                      aux=[idx], offsets=[0], totals=[n], lens=[n],
+                      compr=wire)
+    else:
+        kvs = KVPairs(keys=[KEY], vals=[_generic_decompress(
+            "bsc", vals, idx, n)], offsets=[0], totals=[n], lens=[n])
+    acts = []
+    s._handle_one_key(_req(sender, ts, wire, num_merge=num_merge), kvs, app,
+                      False, False, acts, 0, KEY, 0, n, False)
+    for fn in acts:
+        fn()
 
 
 def _global_round(pushes, n, wire, sparse_wire, fsa_slice_elems=0,
@@ -271,6 +338,242 @@ def test_party_pull_back_in_slices_is_handed_on(wire):
     np.testing.assert_array_equal(st.stored, dense_st.stored)
 
 
+def _unsorted(sel):
+    """A selection in the order ``lax.top_k`` hands it over: by
+    magnitude, not by position."""
+    vals, idx = sel
+    by = np.argsort(-np.abs(vals), kind="stable")
+    return vals[by], idx[by]
+
+
+def _party_rounds(workers, wire, global_servers, n, rounds, sparse=True):
+    """``rounds`` rounds of ``workers`` workers' overlapping selections
+    on a party server; each forward is answered as a global server of
+    this one party would answer it, with the forward itself. Returns
+    per round what went to the global tier and the workers' acks, and
+    how many (key, shard) rounds went forward sparse."""
+    s = _party_server(workers, global_servers, n)
+    if not sparse:
+        s._forwards_sparse = lambda st, n: False    # the parent's path
+    telemetry.reset()
+    telemetry.enable(True)
+    out = []
+    try:
+        for rnd in range(rounds):
+            app = RecordingApp()
+            pushes = [_unsorted(sel) for sel in
+                      _selections(workers, n, seed=100 * rnd + workers)]
+            for w, (vals, idx) in enumerate(pushes):
+                _party_push(s, app, 9 + 2 * w, 10 * rnd + w, wire, vals,
+                            idx, n)
+            sent, s.worker_global.pushed = s.worker_global.pushed, []
+            assert not app.responses, "acked before the pull-back"
+            for ts, (kvs, _g, cb) in enumerate(sent):
+                s.worker_global.responses[ts] = [kvs]
+                cb(ts)
+            out.append((pushes, sent, app.responses))
+        forward = _counters("server.sparse_forward_key_rounds")
+    finally:
+        telemetry.reset()
+    return out, forward
+
+
+@pytest.mark.parametrize("wire", ["bsc", "bsc16"])
+@pytest.mark.parametrize("global_servers", [1, 2])
+@pytest.mark.parametrize("workers", [1, 2, 3])
+def test_party_forward_equals_the_dense_recomputation(workers,
+                                                      global_servers, wire):
+    """Three rounds on a party server against numpy: the pushes
+    scattered by ``_generic_decompress`` and summed in arrival order,
+    each global slice through the dense ``compress_push`` of a
+    compressor at the same point of the same generator. The same bytes
+    go to the global tier, and the pull-back reaches every worker."""
+    n, rounds = 20_000, 3
+    got, forward = _party_rounds(workers, wire, global_servers, n, rounds)
+    parent, none = _party_rounds(workers, wire, global_servers, n, rounds,
+                                 sparse=False)
+    assert forward == rounds and none == 0
+    ref = BSCCompressor(0.01)
+    vdt = np.float16 if wire == "bsc16" else np.float32
+    cuts = [(g * n // global_servers, (g + 1) * n // global_servers)
+            for g in range(global_servers)]
+    for (pushes, sent, acks), (_p, parent_sent, _a) in zip(got, parent):
+        dense = None
+        for vals, idx in pushes:
+            one = _generic_decompress(wire, vals.astype(vdt), idx, n)
+            dense = one if dense is None else dense + one
+        assert len(sent) == len(parent_sent) == global_servers
+        want_idx, want_vals = [], []
+        for (kvs, g_rank, _cb), (lo, hi), (pkvs, pg, _c) in zip(
+                sent, cuts, parent_sent):
+            vals, idx, _tag = ref.compress_push(dense[lo:hi], (KEY, lo))
+            assert kvs.compr == wire and g_rank == pg
+            assert kvs.offsets == [lo] and kvs.lens == [hi - lo]
+            assert kvs.vals[0].dtype == vdt
+            np.testing.assert_array_equal(kvs.aux[0], idx)
+            np.testing.assert_array_equal(_bits(kvs.vals[0]),
+                                          _bits(vals.astype(vdt)))
+            _same_response(kvs, pkvs)
+            want_idx.append(kvs.aux[0] + lo)
+            want_vals.append(kvs.vals[0])
+        # the pull-back (here: the forward itself) is every worker's ack
+        assert len(acks) == workers
+        for _r, ack in acks:
+            assert ack.compr == wire
+            np.testing.assert_array_equal(ack.aux[0],
+                                          np.concatenate(want_idx))
+            np.testing.assert_array_equal(_bits(ack.vals[0]),
+                                          _bits(np.concatenate(want_vals)))
+
+
+@pytest.mark.parametrize("num_merge", [1, 2])
+def test_one_push_round_keeps_the_wire_arrays(num_merge):
+    """One push a round (one worker, or a mesh party's merged selection
+    counting for two): the staged aggregate is the wire's own arrays in
+    the wire's order; nothing is sorted, nothing copied."""
+    n = 20_000
+    s = _party_server(num_merge, n=n)
+    vals, idx = _unsorted(_selections(1, n, seed=4)[0])
+    assert (np.diff(idx) < 0).any()
+    _party_push(s, RecordingApp(), 9, 1, "bsc", vals, idx, n,
+                num_merge=num_merge)
+    st = s._states[(KEY, 0)]
+    assert st.staging and type(st.outbound) is Pairs
+    assert np.shares_memory(st.outbound.idx, idx)
+    assert np.shares_memory(st.outbound.vals, vals)
+    (kvs, _g, _cb), = s.worker_global.pushed
+    assert kvs.compr == "bsc"
+
+
+@pytest.mark.parametrize("first", ["bsc", "dense"])
+def test_a_dense_push_makes_the_party_round_dense(first):
+    """Two workers, one on the Bi-Sparse wire and one uncompressed: the
+    round's aggregate is an array from the dense push on, and the
+    forward is the dense pass's."""
+    n = 20_000
+    a, b = [_unsorted(sel) for sel in _selections(2, n, seed=8)]
+    s = _party_server(2, n=n)
+    telemetry.reset()
+    telemetry.enable(True)
+    try:
+        app = RecordingApp()
+        wires = ("bsc", "") if first == "bsc" else ("", "bsc")
+        _party_push(s, app, 9, 1, wires[0], *a, n)
+        st = s._states[(KEY, 0)]
+        assert isinstance(st.merged, Pairs) == (first == "bsc")
+        _party_push(s, app, 11, 2, wires[1], *b, n)
+        forward = _counters("server.sparse_forward_key_rounds")
+    finally:
+        telemetry.reset()
+    assert forward == 0 and isinstance(st.outbound, np.ndarray)
+    dense = (_generic_decompress("bsc", *a, n)
+             + _generic_decompress("bsc", *b, n))
+    np.testing.assert_array_equal(st.outbound, dense)
+    vals, idx, _t = BSCCompressor(0.01).compress_push(dense, (KEY, 0))
+    (kvs, _g, _cb), = s.worker_global.pushed
+    np.testing.assert_array_equal(kvs.aux[0], idx)
+    np.testing.assert_array_equal(_bits(kvs.vals[0]), _bits(vals))
+
+
+def test_round_released_early_forwards_the_pairs_it_holds():
+    """A membership change completes a round with whatever ``st.merged``
+    holds (``_on_membership`` -> ``_complete_local_round``): one of two
+    workers' pushes, still ``Pairs``."""
+    n = 20_000
+    s = _party_server(2, n=n)
+    vals, idx = _unsorted(_selections(1, n, seed=9)[0])
+    app = RecordingApp()
+    _party_push(s, app, 9, 1, "bsc", vals, idx, n)
+    st = s._states[(KEY, 0)]
+    assert not st.staging and not s.worker_global.pushed
+    with st.lock:
+        acts = s._complete_local_round(st, KEY)
+    for fn in acts:
+        fn()
+    (kvs, _g, _cb), = s.worker_global.pushed
+    want = BSCCompressor(0.01).compress_push(
+        _generic_decompress("bsc", vals, idx, n), (KEY, 0))
+    np.testing.assert_array_equal(kvs.aux[0], want[1])
+    np.testing.assert_array_equal(_bits(kvs.vals[0]), _bits(want[0]))
+
+
+def _make_dense(s, why):
+    st = s._states[(KEY, 0)]
+    if why == "single_tier":
+        s.has_global_tier = False
+    elif why == "hfa":
+        s.use_hfa = True
+    elif why == "tsengine_forward":
+        s.ts_global = object()
+    elif why == "no_compressor":
+        s.gc = make_compressor(None)
+    elif why == "fp16_compressor":
+        s.gc = FP16Compressor()
+    elif why == "mpq_small_key":
+        s.gc = MPQCompressor(0.01, size_lower_bound=st.length + 1)
+    elif why == "float16_key":
+        st.stored = st.stored.astype(np.float16)
+        st.dtype = np.dtype(np.float16)
+    elif why == "before_init":
+        st.stored = None
+    else:
+        raise AssertionError(why)
+
+
+@pytest.mark.parametrize("why", ["single_tier", "hfa", "tsengine_forward",
+                                 "no_compressor", "fp16_compressor",
+                                 "mpq_small_key", "float16_key",
+                                 "before_init"])
+def test_party_server_takes_pairs_only_where_it_reselects(why):
+    """Each condition alone turns the sparse forward off: the push is
+    decompressed on arrival, as at the parent."""
+    n = 768
+    s = _party_server(2, n=n)
+    st = s._states[(KEY, 0)]
+    assert s._forwards_sparse(st, n)
+    _make_dense(s, why)
+    assert not s._forwards_sparse(st, n)
+    if why == "before_init":
+        return
+    vals, idx = _unsorted(_selections(1, n, seed=2)[0])
+    _party_push(s, RecordingApp(), 9, 1, "bsc", vals, idx, n)
+    assert isinstance(st.merged, np.ndarray)
+    np.testing.assert_array_equal(
+        st.merged, _generic_decompress("bsc", vals, idx, n))
+
+
+def test_steady_state_party_round_allocates_under_one_key():
+    """A second round of a 1,000,000-element key on a party server, one
+    worker at 1%: beside the compressor's standing ``u`` and ``v``
+    nothing of the key's size is built (the parent scattered the push
+    into ``np.zeros(n)``: over one key's bytes)."""
+    n = 1_000_000
+    peaks = {}
+    for sparse in (True, False):
+        s = _party_server(1, n=n)
+        if not sparse:
+            s._forwards_sparse = lambda st, n: False
+        pushes = [_unsorted(_selections(1, n, seed=r)[0]) for r in (0, 1)]
+        tracemalloc.start()
+        try:
+            app = RecordingApp()
+            _party_push(s, app, 9, 1, "bsc", *pushes[0], n)
+            (kvs, _g, cb), = s.worker_global.pushed
+            s.worker_global.pushed = []
+            s.worker_global.responses[0] = [kvs]
+            cb(0)
+            assert len(app.responses) == 1
+            tracemalloc.reset_peak()
+            start = tracemalloc.get_traced_memory()[0]
+            _party_push(s, app, 9, 2, "bsc", *pushes[1], n)
+            peaks[sparse] = tracemalloc.get_traced_memory()[1] - start
+        finally:
+            tracemalloc.stop()
+        assert len(s.worker_global.pushed) == 1
+    assert peaks[True] < 4 * n, peaks[True] / n
+    assert peaks[False] > 4 * n, peaks[False] / n
+
+
 def test_steady_state_sparse_round_allocates_under_one_key():
     """A second round of a 4M-element key on the global server, two
     parties at 1%: index lists are merged, no array of the key's size is
@@ -343,9 +646,39 @@ def _qd(x, residual):
         np.asarray(x, np.float32), residual, THR), x.size, THR)
 
 
+def _sel(worker, rnd):
+    """A worker's selection of 4 of ``N``: (values, positions in no
+    order), all in the key's first half."""
+    rng = np.random.RandomState(500 + 7 * worker + rnd)
+    return (rng.uniform(-1, 1, 4).astype(np.float32),
+            rng.choice(N // 2, 4, replace=False).astype(np.int64))
+
+
+def _scattered(sel):
+    out = np.zeros(N, np.float32)
+    out[sel[1]] = sel[0]
+    return out
+
+
 def _expected(mode, w0):
     """Worker parameters after each of the two rounds, replayed in numpy
-    (one worker a party, so a party's aggregate is its worker's push)."""
+    (one worker a party, so a party's aggregate is its worker's push).
+    In the ``bsc_*`` modes a worker pushes ``_sel`` on the Bi-Sparse
+    wire and reads the round's aggregate."""
+    if mode.startswith("bsc_"):
+        x = [[_scattered(_sel(w, r)) for w in range(4)] for r in (0, 1)]
+        if mode == "bsc_hfa":
+            # as "hfa" below, both workers pushing worker 0's selection
+            delta = (x[1][0] - w0) / np.float32(2)
+            return [x[0][0], w0 + (delta + delta)]
+        if mode == "bsc_mpq_small_key":
+            return [_f16(_f16(x[r][0]) + _f16(x[r][1])) for r in (0, 1)]
+        if mode == "bsc_joined_by_a_dense_push":
+            # two workers a party; a party's selection at threshold 0.5
+            # is the key's first half (see _bsc_topology), the whole sum
+            return [(x[r][0] + x[r][1]) + (x[r][2] + x[r][3])
+                    for r in (0, 1)]
+        return [x[r][0] + x[r][1] for r in (0, 1)]
     g = [[_grad(p, r) for p in (0, 1)] for r in (0, 1)]
     if mode == "dense":
         return [g[r][0] + g[r][1] for r in (0, 1)]
@@ -382,17 +715,33 @@ def _counters(prefix):
                if k.startswith(prefix))
 
 
-@pytest.mark.parametrize("mode", ["dense", "fp16", "2bit", "updater",
-                                  "hfa", "mixed_sync"])
+@pytest.mark.parametrize("mode", [
+    "dense", "fp16", "2bit", "updater", "hfa", "mixed_sync", "bsc_hfa",
+    "bsc_single_tier", "bsc_inter_ts", "bsc_mpq_small_key",
+    "bsc_joined_by_a_dense_push"])
 def test_rounds_that_never_go_sparse(mode):
     """Two rounds of one key: no (key, shard) round is stored as entries,
-    every worker reads the numpy sum's parameters to the bit."""
+    none goes forward from a party server as pairs, every worker reads
+    the numpy sum's parameters to the bit. The ``bsc_*`` modes push on
+    the Bi-Sparse wire to a party server that cannot keep it sparse."""
     kw = dict(num_parties=2, workers_per_party=1)
     if mode in ("fp16", "2bit"):
         kw["extra_cfg"] = {"wire_codec": mode, "wire_2bit_threshold": THR}
-    if mode == "hfa":
+    if mode in ("hfa", "bsc_hfa"):
         kw.update(use_hfa=True, hfa_k2=2)
-    topo = InProcessHiPS(**kw).start(sync_global=mode != "mixed_sync")
+    if mode == "bsc_inter_ts":
+        kw["extra_cfg"] = {"enable_inter_ts": True}
+    if mode == "bsc_joined_by_a_dense_push":
+        kw["workers_per_party"] = 2
+    if mode == "bsc_single_tier":
+        topo = SingleTier(num_workers=2).start()
+
+        def run_workers(fn, include_master=None, timeout=None):
+            _parallel([lambda kv=kv: fn(kv) for kv in topo.workers],
+                      timeout)
+    else:
+        topo = InProcessHiPS(**kw).start(sync_global=mode != "mixed_sync")
+        run_workers = topo.run_workers
     w0 = np.linspace(-1, 1, N).astype(np.float32)
     got = {}
     try:
@@ -400,6 +749,12 @@ def test_rounds_that_never_go_sparse(mode):
             topo.master.set_optimizer(SGD(learning_rate=1.0))
 
         def master_init(kv):
+            if mode == "bsc_mpq_small_key":
+                kv.set_gradient_compression(
+                    {"type": "mpq", "size_lower_bound": N + 1})
+            if mode == "bsc_joined_by_a_dense_push":
+                kv.set_gradient_compression(
+                    {"type": "bsc", "threshold": 0.5})
             kv.init(KEY, w0)
             kv.wait()
 
@@ -407,7 +762,7 @@ def test_rounds_that_never_go_sparse(mode):
             kv.init(KEY, w0)
             np.testing.assert_array_equal(kv.pull(KEY), w0)
 
-        topo.run_workers(init, include_master=master_init, timeout=60)
+        run_workers(init, include_master=master_init, timeout=60)
         telemetry.reset()
         telemetry.enable(True)
 
@@ -415,8 +770,17 @@ def test_rounds_that_never_go_sparse(mode):
             p = topo.workers.index(kv)
             outs = []
             for rnd in (0, 1):
-                grad = _grad(0 if mode == "hfa" else p, rnd)
                 out = np.zeros(N, np.float32)
+                if mode.startswith("bsc_") and not (
+                        mode == "bsc_joined_by_a_dense_push" and p % 2):
+                    vals, idx = _sel(0 if mode == "bsc_hfa" else p, rnd)
+                    vals, idx = kv.push_pull_bsc_batch(
+                        [KEY], [vals], [idx], timeout=60)()[KEY]
+                    out[idx] = vals
+                    outs.append(out)
+                    continue
+                grad = (_scattered(_sel(p, rnd)) if mode.startswith("bsc_")
+                        else _grad(0 if mode == "hfa" else p, rnd))
                 if mode in ("fp16", "2bit"):
                     kv.push_pull_async(KEY, grad, out).wait(timeout=60)
                 else:
@@ -426,14 +790,21 @@ def test_rounds_that_never_go_sparse(mode):
                 outs.append(out)
             got[p] = outs
 
-        topo.run_workers(train, timeout=120)
+        run_workers(train, timeout=120)
         sparse = _counters("server.sparse_key_rounds")
         dense = _counters("server.dense_key_rounds")
+        forward = _counters("server.sparse_forward_key_rounds")
         final = topo.master.pull(KEY) if mode == "mixed_sync" else None
     finally:
         telemetry.reset()
         topo.stop()
-    assert sparse == 0 and dense > 0
+    assert forward == 0
+    if mode == "bsc_joined_by_a_dense_push":
+        # the party's aggregate was an array; its re-selection still
+        # leaves on the Bi-Sparse wire, and from there the round is sparse
+        assert sparse > 0
+    else:
+        assert sparse == 0 and dense > 0
     if mode == "mixed_sync":
         # no barrier: a worker reads one or both parties' updates of a
         # round; with every ack back the global store holds all four
@@ -444,7 +815,7 @@ def test_rounds_that_never_go_sparse(mode):
                                    rtol=0, atol=1e-5)
         return
     want = _expected(mode, w0)
-    for p in (0, 1):
+    for p in sorted(got):
         for rnd in (0, 1):
             np.testing.assert_array_equal(
                 _bits(got[p][rnd]), _bits(want[rnd]),
@@ -504,6 +875,7 @@ def _bsc_topology(rounds, after=None):
         topo.run_workers(train, timeout=120)
         counters = {name: _counters("server." + name)
                     for name in ("sparse_key_rounds", "dense_key_rounds",
+                                 "sparse_forward_key_rounds",
                                  "aggregate_ms")}
         extra = after(topo, keys) if after else None
     finally:
@@ -526,6 +898,8 @@ def test_bi_sparse_round_counts_every_key_on_every_server():
             np.testing.assert_array_equal(_bits(a), _bits(b))
     # each key: one round on the global server, one on each party server
     assert counters["sparse_key_rounds"] == len(SIZES) * 3 * rounds
+    # and each party server's forward ran from its worker's pairs
+    assert counters["sparse_forward_key_rounds"] == len(SIZES) * 2 * rounds
     assert counters["dense_key_rounds"] == 0
     assert counters["aggregate_ms"] > 0
 
