@@ -1,0 +1,279 @@
+"""Laguna decoder (poolside/Laguna-XS.2): full and sliding-window
+attention layers with different numbers of query heads over shared
+key/value heads, a sigmoid gate on the attention output, a dense first
+layer, then layers of a shared expert beside top-k routed experts under
+a sigmoid router.
+
+Layer equations (``n*`` RMSNorm with a learned scale; the residual
+stream float32). Layer l is ``full`` or ``sliding`` and has H_l query
+heads in groups of G_l = H_l / KV over the KV key/value heads:
+
+    a = n1(x);  q = a Wq [H_l x hd];  k, v = a Wk, a Wv [KV x hd]
+    g = sigmoid(a Wg) [H_l x hd]
+    full:    rotary positions on the first ``partial_rotary_factor`` of
+             a head's dims, YaRN frequencies (HF
+             ``_compute_yarn_parameters``), cos and sin times the
+             attention factor; key j <= query i
+    sliding: rotary positions on all dims, plain frequencies; key j
+             with 0 <= i - j < window
+    o_h = softmax(q_h k_{h // G_l}^T / sqrt(hd)) v_{h // G_l}
+    h' = x + (o * g) Wo;  m = n2(h')
+    dense layer:  y = h' + Wd(silu(Wg_f m) * (Wu_f m))
+    sparse layer: s = sigmoid(m Wr) over ALL experts; the top-k by s;
+                  w_e = routed_scale * s_e / sum_{chosen} s
+                  y = h' + shared(m) + sum_{chosen e held here} w_e expert_e(m)
+                  (shared and experts SiLU-gated)
+    logits = norm(x) Whead
+
+The model is one rank's share of a tensor- and expert-parallel layout:
+it takes the contiguous ranges of query heads (per layer) and key/value
+heads, of experts (``local_experts``) and the vocabulary rows
+(``vocab``) held here. Heads are independent until ``Wo`` sums them and
+experts until the combine, so the rank computes its heads' part of
+``(o * g) Wo`` and its experts' terms; what other ranks would add is
+left out and nothing stands in for it. Norms, router, shared expert and
+the dense layer's FFN are whole.
+
+Precision: parameters float32, matmul operands in ``compute_dtype``;
+float32 for the residual stream, every norm's statistics, the attention
+scores and their softmax, the logits, and everything that decides
+routing (``n2``, the router product at ``highest``, sigmoid, top-k).
+"""
+
+from __future__ import annotations
+
+import math
+from functools import partial
+from typing import Any, Tuple
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from geomx_tpu.models.moe import sparse_dispatch
+from geomx_tpu.models.olmoe import HIGHEST, RMSNorm
+from geomx_tpu.models.transformer import (grouped_attention, score_entries,
+                                          window_attention)
+
+__all__ = ["Laguna", "LagunaBlock", "next_token_loss",
+           "rotary_frequencies"]
+
+FULL = "full_attention"      # a layer of any other kind slides
+
+
+def rotary_frequencies(rope, head_dim: int):
+    """(inverse frequencies of the rotated pairs, float32; the factor on
+    cos and sin) from one block of HF ``rope_parameters``: ``default``,
+    or ``yarn`` as ``_compute_yarn_parameters`` has it: pairs that turn
+    more than ``beta_fast`` times over the original context keep their
+    frequency, those that turn less than ``beta_slow`` times have it
+    divided by ``factor``, a linear ramp over the pairs between."""
+    dim = int(head_dim * rope["partial_rotary_factor"])
+    base = float(rope["rope_theta"])
+    freq = base ** (-np.arange(0, dim, 2, dtype=np.float64) / dim)
+    if rope["rope_type"] == "default":
+        return freq.astype(np.float32), 1.0
+    if rope["rope_type"] != "yarn":
+        raise ValueError(f"rope_type {rope['rope_type']!r}")
+    factor = float(rope["factor"])
+    original = rope["original_max_position_embeddings"]
+
+    def pair_turning(rotations):
+        return dim * math.log(original / (rotations * 2 * math.pi)) \
+            / (2 * math.log(base))
+
+    low = max(math.floor(pair_turning(rope["beta_fast"])), 0)
+    high = min(math.ceil(pair_turning(rope["beta_slow"])), dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(dim // 2) - low) / (high - low), 0.0, 1.0)
+    scaled = freq / factor * ramp + freq * (1.0 - ramp)
+    return scaled.astype(np.float32), float(rope["attention_factor"])
+
+
+def rotary(x, inv_freq, factor: float):
+    """Rotary positions on the leading ``2 * len(inv_freq)`` dims of
+    every head of ``x`` [B, T, ..., head_dim] (HF's half-split layout,
+    ``x * cos + rotate_half(x) * sin``); the other dims pass. Angles in
+    float32."""
+    t, rot = x.shape[1], 2 * len(inv_freq)
+    ang = jnp.arange(t, dtype=jnp.float32)[:, None] * jnp.asarray(inv_freq)
+    ang = jnp.concatenate([ang, ang], -1).reshape(
+        (1, t) + (1,) * (x.ndim - 3) + (rot,))
+    turned, passed = x[..., :rot].astype(jnp.float32), x[..., rot:]
+    x1, x2 = jnp.split(turned, 2, axis=-1)
+    turned = (turned * jnp.cos(ang)
+              + jnp.concatenate([-x2, x1], -1) * jnp.sin(ang)) * factor
+    return jnp.concatenate([turned.astype(x.dtype), passed], -1)
+
+
+class LagunaBlock(nn.Module):
+    dim: int
+    head_dim: int
+    kind: str                   # "full_attention" | "sliding_attention"
+    query_heads: Tuple[int, int]    # held here, of this layer's H_l
+    key_value_heads: Tuple[int, int]
+    window: int
+    rope: Any                       # this kind's block of rope_parameters
+    sparse: bool
+    dense_width: int
+    num_experts: int
+    experts_per_token: int
+    expert_width: int
+    shared_width: int
+    local_experts: Tuple[int, int]
+    routed_scale: float
+    eps: float = 1e-6
+    compute_dtype: Any = jnp.float32
+
+    def _gated_ffn(self, h, width: int, prefix: str):
+        dense = partial(nn.Dense, use_bias=False, dtype=self.compute_dtype)
+        a = nn.silu(dense(width, name=prefix + "gate")(h)) \
+            * dense(width, name=prefix + "up")(h)
+        return dense(self.dim, name=prefix + "down")(a)
+
+    @nn.compact
+    def __call__(self, x):
+        """``x`` [B, T, D] float32 -> (x', rows routed to the held
+        experts: 0 in the dense layer)."""
+        dt = self.compute_dtype
+        b, t, d = x.shape
+        hd = self.head_dim
+        kv = self.key_value_heads[1] - self.key_value_heads[0]
+        heads = self.query_heads[1] - self.query_heads[0]
+        group = heads // kv
+        if (self.query_heads[0] != self.key_value_heads[0] * group
+                or heads != kv * group):
+            raise ValueError(
+                f"query heads {self.query_heads} are not the groups of "
+                f"key/value heads {self.key_value_heads}")
+        dense = partial(nn.Dense, use_bias=False, dtype=dt)
+        full = self.kind == FULL
+        with jax.named_scope("attention_full" if full
+                             else "attention_window"):
+            h = RMSNorm(self.eps, dt, name="n1")(x)
+            q = dense(heads * hd, name="q")(h).reshape(b, t, kv, group, hd)
+            k, v = (dense(kv * hd, name=n)(h).reshape(b, t, kv, hd)
+                    for n in ("k", "v"))
+            gate = nn.sigmoid(dense(heads * hd, name="gate")(h))
+            inv_freq, factor = rotary_frequencies(self.rope, hd)
+            q, k = rotary(q, inv_freq, factor), rotary(k, inv_freq, factor)
+            attend = partial(grouped_attention, scores_dtype=jnp.float32) \
+                if full else partial(window_attention, window=self.window,
+                                     scores_dtype=jnp.float32)
+            o = jax.checkpoint(attend)(q, k, v)
+            x = x + dense(d, name="o")(o.reshape(b, t, heads * hd) * gate)
+        m = RMSNorm(self.eps, jnp.float32, name="n2")(x)
+        if not self.sparse:
+            with jax.named_scope("dense_ffn"):
+                y = self._gated_ffn(m, self.dense_width, "ffn_")
+            return x + y.astype(jnp.float32), jnp.zeros((), jnp.int32)
+        with jax.named_scope("router"):
+            scores = nn.sigmoid(nn.Dense(
+                self.num_experts, use_bias=False, dtype=jnp.float32,
+                precision=HIGHEST, name="router")(m))
+            chosen_s, chosen = jax.lax.top_k(scores, self.experts_per_token)
+            weights = self.routed_scale * chosen_s / jnp.sum(
+                chosen_s, -1, keepdims=True)
+        with jax.named_scope("shared_expert"):
+            y = self._gated_ffn(m, self.shared_width, "shared_")
+        held = self.local_experts[1] - self.local_experts[0]
+        init = nn.initializers.lecun_normal()
+        w_gate, w_up = (
+            self.param(n, init, (held, d, self.expert_width),
+                       jnp.float32).astype(dt) for n in ("w_gate", "w_up"))
+        w_down = self.param("w_down", init, (held, self.expert_width, d),
+                            jnp.float32).astype(dt)
+
+        @jax.checkpoint
+        def routed_experts(rows, chosen, weights, w_gate, w_up, w_down):
+            def experts(rows, group_sizes, _row_expert):
+                with jax.named_scope("expert_matmuls"):
+                    a = nn.silu(
+                        jax.lax.ragged_dot(rows, w_gate, group_sizes)) \
+                        * jax.lax.ragged_dot(rows, w_up, group_sizes)
+                    return jax.lax.ragged_dot(a, w_down, group_sizes)
+
+            return sparse_dispatch(rows, chosen, weights, experts,
+                                   self.local_experts)
+
+        routed, group_sizes = routed_experts(
+            m.reshape(b * t, d).astype(dt), chosen.reshape(b * t, -1),
+            weights.reshape(b * t, -1), w_gate, w_up, w_down)
+        y = y.astype(jnp.float32) + routed.reshape(b, t, d).astype(
+            jnp.float32)
+        return x + y, jnp.sum(group_sizes)
+
+
+class Laguna(nn.Module):
+    vocab: int
+    dim: int
+    head_dim: int
+    layer_types: Tuple[str, ...]
+    mlp_layer_types: Tuple[str, ...]        # "dense" | "sparse"
+    query_heads: Tuple[Tuple[int, int], ...]    # held here, per layer
+    key_value_heads: Tuple[int, int]
+    window: int
+    rope: Any                   # rope_parameters: a block per layer type
+    dense_width: int
+    num_experts: int
+    experts_per_token: int
+    expert_width: int
+    shared_width: int
+    local_experts: Tuple[int, int]
+    routed_scale: float
+    eps: float = 1e-6
+    compute_dtype: Any = jnp.float32
+
+    def counts(self, batch: int, t: int):
+        """What a pass over ``batch`` sequences of ``t`` positions has
+        by shape: (all routed (token, slot) rows, live score entries,
+        computed score entries), the entries over all layers and held
+        query heads."""
+        live = computed = 0
+        for kind, (lo, hi) in zip(self.layer_types, self.query_heads):
+            a, c = score_entries(t, None if kind == FULL else self.window)
+            live, computed = live + (hi - lo) * a, computed + (hi - lo) * c
+        sparse = sum(m == "sparse" for m in self.mlp_layer_types)
+        return (batch * t * sparse * self.experts_per_token,
+                batch * live, batch * computed)
+
+    @nn.compact
+    def __call__(self, tokens):
+        """``tokens`` [B, T] -> (logits [B, T, vocab] float32, rows
+        routed to the held experts summed over the sparse layers)."""
+        x = nn.Embed(self.vocab, self.dim, name="embed")(tokens)
+        rows_local = 0
+        for i, (kind, mlp) in enumerate(zip(self.layer_types,
+                                            self.mlp_layer_types)):
+            x, rows = LagunaBlock(
+                self.dim, self.head_dim, kind, tuple(self.query_heads[i]),
+                tuple(self.key_value_heads), self.window, self.rope[kind],
+                mlp == "sparse", self.dense_width, self.num_experts,
+                self.experts_per_token, self.expert_width,
+                self.shared_width, tuple(self.local_experts),
+                self.routed_scale, self.eps, self.compute_dtype,
+                name=f"block{i}")(x)
+            rows_local = rows_local + rows
+        with jax.named_scope("head"):
+            x = RMSNorm(self.eps, self.compute_dtype, name="norm")(x)
+            logits = nn.Dense(
+                self.vocab, use_bias=False, dtype=self.compute_dtype,
+                dot_general=partial(jax.lax.dot_general,
+                                    preferred_element_type=jnp.float32),
+                name="head")(x)
+        return logits, rows_local
+
+
+def next_token_loss(model: Laguna, variables, toks):
+    """``toks`` [B, T+1]: the mean next-token cross-entropy. Returns
+    (loss, [rows routed to the held experts, all routed rows, live
+    attention score entries, computed attention score entries]), the
+    counts as float32."""
+    logits, rows_local = model.apply(variables, toks[:, :-1])
+    logp = jax.nn.log_softmax(logits)
+    loss = -jnp.mean(jnp.take_along_axis(logp, toks[:, 1:, None], axis=-1))
+    by_shape = model.counts(toks.shape[0], toks.shape[1] - 1)
+    return loss, jnp.stack([rows_local.astype(jnp.float32),
+                            *(jnp.float32(c) for c in by_shape)])

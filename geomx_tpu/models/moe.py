@@ -12,8 +12,13 @@ unsorted and combined with the router's weights. Work follows the rows
 routed, shapes stay static, no capacity factor drops a token. It takes
 ``k`` from its inputs and the contiguous range of experts held here as
 an argument: one rank of an expert-parallel layout computes its own
-experts' terms and nothing for the others. ``models/olmoe.py`` is its
-top-8 user, :class:`MoEBlock` its ``k = 1`` case.
+experts' terms and nothing for the others. The gates are whatever
+weights the caller made of its router's scores: softmax probabilities
+as they are (``models/olmoe.py``, top-8 of 64), sigmoid scores
+normalised over the chosen and scaled (``models/laguna.py``, top-8 of
+256 beside a shared expert that never comes here), one probability
+(:class:`MoEBlock`, the ``k = 1`` case); the dispatch multiplies and
+sums, it normalises nothing.
 
 :class:`MoEBlock` router: top-1 (Switch-style) with optional jitter
 noise and the standard load-balancing auxiliary loss (mean fraction x
@@ -70,7 +75,8 @@ def sparse_dispatch(h, expert_idx, gates, expert_fn, local_experts):
     """No-drop top-k dispatch to a contiguous range of experts.
 
     ``h`` [N, D] rows, ``expert_idx`` [N, k] the experts each row chose
-    (ids of the whole router), ``gates`` [N, k] their weights,
+    (ids of the whole router), ``gates`` [N, k] their weights, as the
+    caller normalised them,
     ``local_experts`` (lo, hi) the experts held here. The (row, slot)
     pairs are sorted by expert, held experts first and in order, so
     that ``expert_fn(rows [N*k, D], group_sizes [hi-lo], row_expert

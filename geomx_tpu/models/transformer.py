@@ -72,6 +72,76 @@ def dense_attention(q, k, v, *, causal: bool = True, scores_dtype=None):
     return jnp.einsum("bhqk,bkhd->bqhd", p.astype(v.dtype), v)
 
 
+def grouped_attention(q, k, v, *, scores_dtype=None):
+    """Causal attention with grouped queries: ``q`` [B, T, KV, G, D],
+    ``k`` and ``v`` [B, T, KV, D]; the G query heads of a group share
+    their key/value head, which is never repeated. The dense [T, T]
+    product, ``scores_dtype`` as in :func:`dense_attention`. Returns
+    [B, T, KV, G, D]."""
+    t = q.shape[1]
+    s = jnp.einsum("bqkgd,bjkd->bkgqj", q, k,
+                   preferred_element_type=scores_dtype)
+    s = s / jnp.sqrt(q.shape[-1]).astype(s.dtype)
+    s = jnp.where(jnp.tril(jnp.ones((t, t), bool)), s, -1e30)
+    p = jax.nn.softmax(s, axis=-1)
+    return jnp.einsum("bkgqj,bjkd->bqkgd", p.astype(v.dtype), v)
+
+
+def window_blocks(t: int, window: int):
+    """(block, number of blocks) of :func:`window_attention` at ``t``
+    positions: a block is the window, or the sequence where that is
+    shorter."""
+    block = min(window, t)
+    return block, -(-t // block)
+
+
+def window_attention(q, k, v, window: int, *, scores_dtype=None):
+    """Sliding-window attention with grouped queries, exact: query i
+    sees the keys j with ``0 <= i - j < window``. Shapes as
+    :func:`grouped_attention`. Blocked by the window: a block of
+    ``window`` queries is multiplied with its own and the previous key
+    block, which hold every key it may see, so the score product has
+    ``2 * window`` columns a query whatever T; T is padded to whole
+    blocks and the first block's predecessor is padding, both masked."""
+    b, t, kv, g, d = q.shape
+    block, nb = window_blocks(t, window)
+    pad = nb * block - t
+    q = jnp.pad(q, ((0, 0), (0, pad)) + ((0, 0),) * 3)
+    q = q.reshape(b, nb, block, kv, g, d)
+
+    def with_previous(x):
+        x = jnp.pad(x, ((0, 0), (block, pad), (0, 0), (0, 0)))
+        x = x.reshape(b, nb + 1, block, kv, d)
+        return jnp.concatenate([x[:, :-1], x[:, 1:]], axis=2)
+
+    k, v = with_previous(k), with_previous(v)
+    s = jnp.einsum("bnqkgd,bnjkd->bnkgqj", q, k,
+                   preferred_element_type=scores_dtype)
+    s = s / jnp.sqrt(d).astype(s.dtype)
+    # column j of block n is position (n - 1) * block + j
+    behind = (jnp.arange(block)[:, None] + block
+              - jnp.arange(2 * block)[None])
+    live = ((behind >= 0) & (behind < window))[None] & (
+        (jnp.arange(nb)[:, None] > 0)
+        | (jnp.arange(2 * block)[None] >= block))[:, None]
+    s = jnp.where(live[None, :, None, None], s, -1e30)
+    p = jax.nn.softmax(s, axis=-1)
+    o = jnp.einsum("bnkgqj,bnjkd->bnqkgd", p.astype(v.dtype), v)
+    return o.reshape(b, nb * block, kv, g, d)[:, :t]
+
+
+def score_entries(t: int, window: Optional[int] = None):
+    """(live, computed) score entries of one head over one sequence of
+    ``t`` positions: the entries the mask keeps, and the entries the
+    score product of :func:`grouped_attention` (``window`` None) or
+    :func:`window_attention` has by its shape."""
+    if window is None:
+        return t * (t + 1) // 2, t * t
+    block, nb = window_blocks(t, window)
+    return (block * (block + 1) // 2 + (t - block) * window,
+            nb * block * 2 * block)
+
+
 class Block(nn.Module):
     dim: int
     heads: int

@@ -239,7 +239,10 @@ class DeviceResidentTrainer:
         self._chunk_meta = meta
         sel_bounds = [(m[0], m[1]) for m in meta]
 
-        @jax.jit
+        # u and v are donated: the round rebinds both from the outputs,
+        # and at 248M parameters two more flat vectors beside two
+        # trainers' state do not fit a 16 GB chip
+        @partial(jax.jit, donate_argnums=(1, 2))
         def fwd_chunks(flat, u, v, X, y):
             loss, vals, idx, u, v = select(flat, u, v, X, y)
             # one packed int32 array PER CHUNK so the host can fetch
@@ -253,7 +256,7 @@ class DeviceResidentTrainer:
                 for lo, hi in sel_bounds)
             return loss.astype(jnp.float32), packs, u, v
 
-        @partial(jax.jit, static_argnums=(3, 4))
+        @partial(jax.jit, static_argnums=(3, 4), donate_argnums=(0, 1))
         def apply_chunk(flat, mom, up, flo, fsize):
             # up layout (see _chunk_up): [vals(cap) bitcast i32,
             # idx(cap) CHUNK-relative]; pad slots are (0.0, 0) — a
@@ -332,7 +335,7 @@ class DeviceResidentTrainer:
                 loss, vals, idx, u, v = _bsc(loss, g / nw, u, v)
                 return loss, vals, idx, u, v, res
 
-            @jax.jit
+            @partial(jax.jit, donate_argnums=(1, 2))
             def fwd_chunks_q(flat, u, v, X, y, res):
                 loss, vals, idx, u, v, res = select_q(flat, u, v,
                                                       X, y, res)
@@ -406,10 +409,12 @@ class DeviceResidentTrainer:
 
     def warmup(self, X, y) -> None:
         """Trace+compile the device programs :meth:`step` will run
-        WITHOUT running a kv round (results discarded, trainer state
-        untouched) — lets callers serialize expensive first compiles
-        without holding up the FSA barrier (at 59M parameters the
-        forward program is about a minute of cold compile on a v5e)."""
+        WITHOUT running them: they donate the trainer's state, so they
+        are lowered and compiled for these arguments (the executable is
+        the one the first call finds) and the state stays untouched —
+        lets callers serialize expensive first compiles without holding
+        up the FSA barrier (at 59M parameters the forward program is
+        about a minute of cold compile on a v5e)."""
         import jax
 
         X, y = self._place_batch(X, y)
@@ -417,13 +422,11 @@ class DeviceResidentTrainer:
         if self._mesh_quant:
             args += (self._mesh_res,)
         fwd = self._fwd_chunks_q if self._mesh_quant else self._fwd_chunks
-        loss_d, packs = fwd(*args)[:2]
-        fence = [loss_d, *packs]
+        fwd.lower(*args).compile()
         for _lo, _hi, flo, fsize, cap in self._chunk_meta:
             up = jax.device_put(np.zeros(2 * cap, np.int32))
-            fence.append(self._apply_chunk(self._flat, self._mom,
-                                           up, flo, fsize)[0])
-        jax.block_until_ready(fence)
+            self._apply_chunk.lower(self._flat, self._mom, up, flo,
+                                    fsize).compile()
 
     # -- one round -------------------------------------------------------
 
