@@ -6,10 +6,12 @@ at most. :class:`Entries` carries it as what the ``bsc`` / ``bsc16``
 wire already is — sorted, unique positions and float32 values, plus the
 range's length — so a server sums index lists instead of scattering each
 push into ``np.zeros(n)`` and finding the support again with
-``np.nonzero``. :class:`Pairs` is the same without the order: a worker's
-selection as ``lax.top_k`` hands it over, by magnitude, which a party
-server adds into its Bi-Sparse state where it is (``Pairs.add_into``)
-and orders only to merge it with a second one. Every pass here is
+``np.nonzero``. :class:`Pairs` is the same without a promise of order:
+a worker's selection as the wire hands it over (the device step's
+``ops.select`` writes each key's positions ascending; a sort-based
+selection gives them by magnitude), which a party server adds into its
+Bi-Sparse state where it is (``Pairs.add_into``) and orders only to
+merge it with a second one. Every pass here is
 O(entries), none is O(n) except ``dense``, which whoever truly needs an
 array calls once.
 

@@ -16,6 +16,10 @@ on the WAN hop:
 - ``dgt_block_contrib`` — per-block mean |g| EWMA scoring for DGT
   channel assignment (reference: EvalMsgContribution, kv_app.h:978).
 
+The device trainer's own selection is ``ops.select``: the same exact
+top-k found by counting passes and a compaction, no sort (ROADMAP D5
+moves the two ``lax.top_k`` below onto it).
+
 All functions are pure (state in, state out) and jit-compiled per
 (shape, static-arg) signature. The host-side numpy kernels in
 ``geomx_tpu.compression`` remain the fallback for processes without an

@@ -8,9 +8,12 @@ parameters stay resident on the device as one flat fp32 vector and the
 host<->device link carries only:
 
 - down: the PER-KEY BSC-selected (values, indices) of the
-  momentum-corrected gradient (top-k per tensor on device, matching the
-  reference's per-tensor compression — reference semantics:
-  gradient_compression.cc:191 BSCompress runs per key);
+  momentum-corrected gradient (exact top-k per tensor on device,
+  matching the reference's per-tensor compression — reference
+  semantics: gradient_compression.cc:191 BSCompress runs per key — and
+  found without a sort: ``ops.select`` counts its way to each key's
+  k-th magnitude and compacts what is at or over it, keys of one size
+  under one traced body);
 - up: the nonzeros of the aggregated gradient pulled back from the
   HiPS tier (bounded by workers x k), as one fixed-size padded array so
   the jitted apply never retraces.
@@ -57,6 +60,7 @@ import numpy as np
 
 from geomx_tpu import profiler, telemetry
 from geomx_tpu.kvstore.frontier import plan_chunks
+from geomx_tpu.ops.select import topk_flat
 
 __all__ = ["DeviceResidentTrainer"]
 
@@ -188,24 +192,20 @@ class DeviceResidentTrainer:
             # (reference: gradient_compression.cc:191-268, per tensor)
             u = 0.9 * u + g
             v = v + u
-            vals_parts, idx_parts = [], []
-            for off, sz, kk in zip(offsets, sizes, ks):
-                seg = v[off:off + sz]
-                _mags, ii = jax.lax.top_k(jnp.abs(seg), kk)
-                vals_parts.append(seg[ii])
-                idx_parts.append((ii + off).astype(jnp.int32))
-            vals = jnp.concatenate(vals_parts)
-            idx = jnp.concatenate(idx_parts)       # model-flat positions
-            u = u.at[idx].set(0.0)
+            # model-flat positions, ascending and distinct (keys in
+            # flat order, each key's ascending), and v there
+            idx, vals = topk_flat(v, offsets, sizes, ks)
+            ordered = dict(indices_are_sorted=True, unique_indices=True)
+            u = u.at[idx].set(0.0, **ordered)
             if wire16:
                 narrowed = vals.astype(jnp.float16).astype(jnp.float32)
                 # selected coordinates keep the narrowing error as their
                 # residual (instead of resetting to zero) — it rides
                 # into the next round's accumulation
-                v = v.at[idx].set(vals - narrowed)
+                v = v.at[idx].set(vals - narrowed, **ordered)
                 vals = narrowed
             else:
-                v = v.at[idx].set(0.0)
+                v = v.at[idx].set(0.0, **ordered)
             return loss, vals, idx, u, v
 
         def select(flat, u, v, X, y):
@@ -366,7 +366,10 @@ class DeviceResidentTrainer:
 
     def _book(self, head: np.ndarray) -> float:
         """The loss from the head of a download; grad_fn's counts, if
-        any, go to their telemetry counters."""
+        any, go to their telemetry counters, and so does the number of
+        keys this round selected by threshold."""
+        telemetry.counter_inc("step.select_threshold_keys",
+                              len(self._sizes))
         head = np.atleast_1d(head)
         for name, value in zip(self._aux_names, head[1:]):
             telemetry.counter_inc(name, float(value))

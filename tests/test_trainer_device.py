@@ -210,3 +210,82 @@ def test_local_store_sparse_round_refuses_an_updater():
         threshold=0.5, learning_rate=0.1)
     with pytest.raises(RuntimeError, match="requires aggregator mode"):
         tr.step(jnp.asarray(0.0), None)
+
+
+def _by_sorting(x, k):
+    """What ``ops.select.topk_by_magnitude`` stands in for: the
+    positions ``lax.top_k`` gives, put in ascending order, and x
+    there."""
+    pos = jnp.sort(jax.lax.top_k(jnp.abs(x), k)[1]).astype(jnp.int32)
+    return pos, x[pos]
+
+
+def _three_rounds(wire_codec):
+    """Every leaf of both workers after three rounds of a two-party job
+    over keys of several sizes (two of them alike), with ties in the
+    gradient; and what the rounds booked on the selection's counter."""
+    from geomx_tpu import telemetry
+
+    shapes = [(40, 16), (640,), (7,), (40, 16), (3, 50)]
+    rng = np.random.default_rng(11)
+    target = [np.round(rng.standard_normal(s) * 4).astype(np.float32) / 4
+              for s in shapes]
+
+    def grad_fn(leaves, X, y):
+        diffs = [w - jnp.asarray(t) + X for w, t in zip(leaves, target)]
+        return sum(0.5 * jnp.sum(d * d) for d in diffs), diffs
+
+    extra = {"wire_codec": wire_codec} if wire_codec else {}
+    topo = InProcessHiPS(num_parties=2, workers_per_party=1,
+                         extra_cfg=extra).start()
+    results = {}
+    was_on = telemetry.enabled()
+    telemetry.enable(True)
+    before = dict(telemetry.snapshot()["counters"])
+    try:
+        def worker(kv):
+            widx = 0 if kv is topo.workers[0] else 1
+            tr = DeviceResidentTrainer(
+                [np.zeros(s, np.float32) for s in shapes], kv, grad_fn,
+                threshold=0.05, learning_rate=0.1)
+            for _ in range(3):
+                tr.step(jnp.asarray(0.5 if widx == 0 else -0.25), None)
+            results[widx] = [l.copy() for l in tr.leaves]
+
+        def master_init(kv):
+            for i, s in enumerate(shapes):
+                kv.init(i, np.zeros(s, np.float32))
+            kv.wait()
+
+        t = threading.Thread(target=lambda: topo.run_workers(
+            worker, include_master=master_init, timeout=300))
+        t.start()
+        t.join(300)
+        assert not t.is_alive(), "workers hung"
+    finally:
+        topo.stop()
+        after = telemetry.snapshot()["counters"]
+        telemetry.enable(was_on)
+    name = "step.select_threshold_keys"
+    return results, after.get(name, 0) - before.get(name, 0), len(shapes)
+
+
+@pytest.mark.parametrize("wire_codec", ["", "fp16"])
+def test_selection_without_a_sort_trains_bit_for_bit(wire_codec,
+                                                     monkeypatch):
+    """Three rounds with the trainer's selection as it is against the
+    same rounds with ``lax.top_k`` in its place: every leaf of every
+    worker bit-equal (the same set leaves each key, so the servers sum
+    the same pairs), ``bsc`` and ``bsc16``; every key of every round is
+    booked on the selection's counter (no key keeps a sort, so there is
+    no second counter)."""
+    from geomx_tpu.ops import select
+
+    got, booked, keys = _three_rounds(wire_codec)
+    assert booked == keys * 3 * 2       # rounds x workers
+    monkeypatch.setattr(select, "topk_by_magnitude", _by_sorting)
+    want, _booked, _keys = _three_rounds(wire_codec)
+    for widx in (0, 1):
+        for a, b in zip(got[widx], want[widx]):
+            np.testing.assert_array_equal(a, b)
+    assert any(np.any(l != 0) for l in got[0])
