@@ -4,12 +4,16 @@ CNN, examples/cnn.py:56-63).
 
 Decoders by module, not re-exported here: ``transformer`` (GPT-2 style;
 ``dense_attention``, and beside it ``grouped_attention`` and the blocked
-``window_attention`` for grouped queries), and the three users of
-``moe.sparse_dispatch``: ``moe.MoEBlock`` (top-1), ``olmoe`` (softmax
-top-8 of 64) and ``laguna`` (window and full attention layers with
-their own head counts, a gated attention output, a dense first layer,
-a shared expert beside sigmoid top-8 of 256), the last two as one
-rank's share of an expert-parallel layout.
+``window_attention`` for grouped queries, ``rotary`` positions over a
+part of a head and ``gated_attention``, the sigmoid-gated branch two
+families share), and the four users of ``moe.sparse_dispatch``:
+``moe.MoEBlock`` (top-1), ``olmoe`` (softmax top-8 of 64), ``laguna``
+(window and full attention layers with their own head counts, a gated
+attention output, a dense first layer, a shared expert beside sigmoid
+top-8 of 256) and ``qwen3_next`` (three Gated DeltaNet linear-attention
+layers on ``ops.gated_delta`` to one gated full-attention layer, a
+gated shared expert beside normalised softmax top-10 of 512), the last
+three as one rank's share of an expert-parallel layout.
 """
 
 from geomx_tpu.models.cnn import LeNetCNN, create_cnn  # noqa: F401

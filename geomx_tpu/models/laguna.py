@@ -42,70 +42,24 @@ routing (``n2``, the router product at ``highest``, sigmoid, top-k).
 
 from __future__ import annotations
 
-import math
 from functools import partial
 from typing import Any, Tuple
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
-import numpy as np
 
-from geomx_tpu.models.moe import sparse_dispatch
+from geomx_tpu.models.moe import gated_experts, sparse_dispatch
 from geomx_tpu.models.olmoe import HIGHEST, RMSNorm
-from geomx_tpu.models.transformer import (grouped_attention, score_entries,
+from geomx_tpu.models.transformer import (gated_attention,
+                                          grouped_attention,
+                                          rotary_frequencies, score_entries,
                                           window_attention)
 
 __all__ = ["Laguna", "LagunaBlock", "next_token_loss",
            "rotary_frequencies"]
 
 FULL = "full_attention"      # a layer of any other kind slides
-
-
-def rotary_frequencies(rope, head_dim: int):
-    """(inverse frequencies of the rotated pairs, float32; the factor on
-    cos and sin) from one block of HF ``rope_parameters``: ``default``,
-    or ``yarn`` as ``_compute_yarn_parameters`` has it: pairs that turn
-    more than ``beta_fast`` times over the original context keep their
-    frequency, those that turn less than ``beta_slow`` times have it
-    divided by ``factor``, a linear ramp over the pairs between."""
-    dim = int(head_dim * rope["partial_rotary_factor"])
-    base = float(rope["rope_theta"])
-    freq = base ** (-np.arange(0, dim, 2, dtype=np.float64) / dim)
-    if rope["rope_type"] == "default":
-        return freq.astype(np.float32), 1.0
-    if rope["rope_type"] != "yarn":
-        raise ValueError(f"rope_type {rope['rope_type']!r}")
-    factor = float(rope["factor"])
-    original = rope["original_max_position_embeddings"]
-
-    def pair_turning(rotations):
-        return dim * math.log(original / (rotations * 2 * math.pi)) \
-            / (2 * math.log(base))
-
-    low = max(math.floor(pair_turning(rope["beta_fast"])), 0)
-    high = min(math.ceil(pair_turning(rope["beta_slow"])), dim - 1)
-    if low == high:
-        high += 0.001
-    ramp = np.clip((np.arange(dim // 2) - low) / (high - low), 0.0, 1.0)
-    scaled = freq / factor * ramp + freq * (1.0 - ramp)
-    return scaled.astype(np.float32), float(rope["attention_factor"])
-
-
-def rotary(x, inv_freq, factor: float):
-    """Rotary positions on the leading ``2 * len(inv_freq)`` dims of
-    every head of ``x`` [B, T, ..., head_dim] (HF's half-split layout,
-    ``x * cos + rotate_half(x) * sin``); the other dims pass. Angles in
-    float32."""
-    t, rot = x.shape[1], 2 * len(inv_freq)
-    ang = jnp.arange(t, dtype=jnp.float32)[:, None] * jnp.asarray(inv_freq)
-    ang = jnp.concatenate([ang, ang], -1).reshape(
-        (1, t) + (1,) * (x.ndim - 3) + (rot,))
-    turned, passed = x[..., :rot].astype(jnp.float32), x[..., rot:]
-    x1, x2 = jnp.split(turned, 2, axis=-1)
-    turned = (turned * jnp.cos(ang)
-              + jnp.concatenate([-x2, x1], -1) * jnp.sin(ang)) * factor
-    return jnp.concatenate([turned.astype(x.dtype), passed], -1)
 
 
 class LagunaBlock(nn.Module):
@@ -156,14 +110,12 @@ class LagunaBlock(nn.Module):
             q = dense(heads * hd, name="q")(h).reshape(b, t, kv, group, hd)
             k, v = (dense(kv * hd, name=n)(h).reshape(b, t, kv, hd)
                     for n in ("k", "v"))
-            gate = nn.sigmoid(dense(heads * hd, name="gate")(h))
-            inv_freq, factor = rotary_frequencies(self.rope, hd)
-            q, k = rotary(q, inv_freq, factor), rotary(k, inv_freq, factor)
+            gate = dense(heads * hd, name="gate")(h)
             attend = partial(grouped_attention, scores_dtype=jnp.float32) \
                 if full else partial(window_attention, window=self.window,
                                      scores_dtype=jnp.float32)
-            o = jax.checkpoint(attend)(q, k, v)
-            x = x + dense(d, name="o")(o.reshape(b, t, heads * hd) * gate)
+            x = x + dense(d, name="o")(gated_attention(
+                q, k, v, gate, attend, *rotary_frequencies(self.rope, hd)))
         m = RMSNorm(self.eps, jnp.float32, name="n2")(x)
         if not self.sparse:
             with jax.named_scope("dense_ffn"):
@@ -188,14 +140,8 @@ class LagunaBlock(nn.Module):
 
         @jax.checkpoint
         def routed_experts(rows, chosen, weights, w_gate, w_up, w_down):
-            def experts(rows, group_sizes, _row_expert):
-                with jax.named_scope("expert_matmuls"):
-                    a = nn.silu(
-                        jax.lax.ragged_dot(rows, w_gate, group_sizes)) \
-                        * jax.lax.ragged_dot(rows, w_up, group_sizes)
-                    return jax.lax.ragged_dot(a, w_down, group_sizes)
-
-            return sparse_dispatch(rows, chosen, weights, experts,
+            return sparse_dispatch(rows, chosen, weights,
+                                   gated_experts(w_gate, w_up, w_down),
                                    self.local_experts)
 
         routed, group_sizes = routed_experts(

@@ -35,7 +35,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 __all__ = ["MoEBlock", "moe_param_sharding", "is_expert_param",
-           "sparse_dispatch"]
+           "sparse_dispatch", "gated_experts"]
 
 # leaf names of expert-stacked params (leading axis = expert dim)
 EXPERT_PARAM_NAMES = ("w_up", "b_up", "w_dn", "b_dn")
@@ -111,6 +111,20 @@ def sparse_dispatch(h, expert_idx, gates, expert_fn, local_experts):
         out = _take_rows(out, inverse, order).reshape(n, k, -1)
         y = jnp.einsum("nk,nkd->nd", gates.astype(out.dtype), out)
     return y, group_sizes
+
+
+def gated_experts(w_gate, w_up, w_down):
+    """The ``expert_fn`` of :func:`sparse_dispatch` for stacks of
+    SiLU-gated experts, ``w_gate`` and ``w_up`` [E, D, W], ``w_down``
+    [E, W, D]: ``(silu(x Wg_e) * (x Wu_e)) Wd_e`` as three grouped
+    matmuls over the rows' groups."""
+    def experts(rows, group_sizes, _row_expert):
+        with jax.named_scope("expert_matmuls"):
+            a = nn.silu(jax.lax.ragged_dot(rows, w_gate, group_sizes)) \
+                * jax.lax.ragged_dot(rows, w_up, group_sizes)
+            return jax.lax.ragged_dot(a, w_down, group_sizes)
+
+    return experts
 
 
 class MoEBlock(nn.Module):

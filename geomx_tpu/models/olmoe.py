@@ -37,7 +37,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from geomx_tpu.models.moe import sparse_dispatch
+from geomx_tpu.models.moe import gated_experts, sparse_dispatch
 from geomx_tpu.models.transformer import dense_attention
 
 __all__ = ["Olmoe", "OlmoeBlock", "next_token_loss"]
@@ -46,13 +46,20 @@ HIGHEST = jax.lax.Precision.HIGHEST
 
 
 class RMSNorm(nn.Module):
+    """``x / rms(x) * scale`` over the last axis; ``zero_centred``: the
+    parameter is the scale's distance from 1, ``x / rms(x) * (1 + w)``,
+    zero at the start (the form Qwen3-Next publishes)."""
     eps: float
     dtype: Any = jnp.float32
+    zero_centred: bool = False
 
     @nn.compact
     def __call__(self, x):
-        scale = self.param("scale", nn.initializers.ones, (x.shape[-1],),
-                           jnp.float32)
+        scale = self.param(
+            "scale", nn.initializers.zeros if self.zero_centred
+            else nn.initializers.ones, (x.shape[-1],), jnp.float32)
+        if self.zero_centred:
+            scale = 1.0 + scale
         x = x.astype(jnp.float32)
         x = x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + self.eps)
         return (x * scale).astype(self.dtype)
@@ -118,16 +125,10 @@ class OlmoeBlock(nn.Module):
         w_down = self.param("w_down", init, (held, self.expert_width, d),
                             jnp.float32).astype(dt)
 
-        def experts(rows, group_sizes, _row_expert):
-            with jax.named_scope("expert_matmuls"):
-                a = nn.silu(jax.lax.ragged_dot(rows, w_gate, group_sizes)) \
-                    * jax.lax.ragged_dot(rows, w_up, group_sizes)
-                return jax.lax.ragged_dot(a, w_down, group_sizes)
-
         y, group_sizes = sparse_dispatch(
             h.reshape(b * t, d).astype(dt),
-            chosen.reshape(b * t, -1), gates.reshape(b * t, -1), experts,
-            self.local_experts)
+            chosen.reshape(b * t, -1), gates.reshape(b * t, -1),
+            gated_experts(w_gate, w_up, w_down), self.local_experts)
         return (x + y.reshape(b, t, d).astype(jnp.float32), probs, chosen,
                 jnp.sum(group_sizes))
 

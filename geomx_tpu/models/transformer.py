@@ -9,11 +9,13 @@ this is the capability the TPU build adds as first-class.
 
 from __future__ import annotations
 
+import math
 from typing import Any, Callable, Optional
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 
@@ -140,6 +142,69 @@ def score_entries(t: int, window: Optional[int] = None):
     block, nb = window_blocks(t, window)
     return (block * (block + 1) // 2 + (t - block) * window,
             nb * block * 2 * block)
+
+
+def rotary_frequencies(rope, head_dim: int):
+    """(inverse frequencies of the rotated pairs, float32; the factor on
+    cos and sin) from one block of HF ``rope_parameters``: ``default``,
+    or ``yarn`` as ``_compute_yarn_parameters`` has it: pairs that turn
+    more than ``beta_fast`` times over the original context keep their
+    frequency, those that turn less than ``beta_slow`` times have it
+    divided by ``factor``, a linear ramp over the pairs between."""
+    dim = int(head_dim * rope["partial_rotary_factor"])
+    base = float(rope["rope_theta"])
+    freq = base ** (-np.arange(0, dim, 2, dtype=np.float64) / dim)
+    if rope["rope_type"] == "default":
+        return freq.astype(np.float32), 1.0
+    if rope["rope_type"] != "yarn":
+        raise ValueError(f"rope_type {rope['rope_type']!r}")
+    factor = float(rope["factor"])
+    original = rope["original_max_position_embeddings"]
+
+    def pair_turning(rotations):
+        return dim * math.log(original / (rotations * 2 * math.pi)) \
+            / (2 * math.log(base))
+
+    low = max(math.floor(pair_turning(rope["beta_fast"])), 0)
+    high = min(math.ceil(pair_turning(rope["beta_slow"])), dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(dim // 2) - low) / (high - low), 0.0, 1.0)
+    scaled = freq / factor * ramp + freq * (1.0 - ramp)
+    return scaled.astype(np.float32), float(rope["attention_factor"])
+
+
+def rotary(x, inv_freq, factor: float):
+    """Rotary positions on the leading ``2 * len(inv_freq)`` dims of
+    every head of ``x`` [B, T, ..., head_dim] (HF's half-split layout,
+    ``x * cos + rotate_half(x) * sin``); the other dims pass. Angles in
+    float32."""
+    t, rot = x.shape[1], 2 * len(inv_freq)
+    ang = jnp.arange(t, dtype=jnp.float32)[:, None] * jnp.asarray(inv_freq)
+    ang = jnp.concatenate([ang, ang], -1).reshape(
+        (1, t) + (1,) * (x.ndim - 3) + (rot,))
+    turned, passed = x[..., :rot].astype(jnp.float32), x[..., rot:]
+    x1, x2 = jnp.split(turned, 2, axis=-1)
+    turned = (turned * jnp.cos(ang)
+              + jnp.concatenate([-x2, x1], -1) * jnp.sin(ang)) * factor
+    return jnp.concatenate([turned.astype(x.dtype), passed], -1)
+
+
+def gated_attention(q, k, v, gate, attend, inv_freq, factor: float):
+    """An attention branch with rotary positions on the way in and an
+    element-wise sigmoid gate on the way out (the form Qwen3-Next
+    publishes; ``models/laguna.py``, ``models/qwen3_next.py``): ``q``
+    [B, T, KV, G, D], ``k`` and ``v`` [B, T, KV, D], ``gate`` [B, T,
+    KV * G * D] the gate BEFORE its sigmoid, from the layer's own normed
+    input. ``attend`` is the core (:func:`grouped_attention`,
+    :func:`window_attention`); it is computed again on the way back
+    (``jax.checkpoint``), so no [T, T] scores are kept. Returns
+    ``attend(rotary(q), rotary(k), v) * sigmoid(gate)`` [B, T, KV * G *
+    D], what the output projection takes."""
+    gate = nn.sigmoid(gate)
+    q, k = rotary(q, inv_freq, factor), rotary(k, inv_freq, factor)
+    o = jax.checkpoint(attend)(q, k, v)
+    return o.reshape(gate.shape) * gate
 
 
 class Block(nn.Module):

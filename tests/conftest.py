@@ -14,6 +14,11 @@ import sys
 import pytest
 
 os.environ["JAX_PLATFORMS"] = "cpu"
+# LAPACK calls from jitted code (lax.linalg.triangular_solve on the CPU)
+# run on scipy's OpenBLAS, whose pool threads spin on every core between
+# calls: beside six xdist workers that starves the chaos tests' 0.2 s
+# heartbeats. One thread a process is plenty at the tests' sizes.
+os.environ.setdefault("OPENBLAS_NUM_THREADS", "1")
 _flags = os.environ.get("XLA_FLAGS", "")
 if "xla_force_host_platform_device_count" not in _flags:
     os.environ["XLA_FLAGS"] = (

@@ -430,3 +430,27 @@ def test_two_party_round_through_the_device_trainer():
     assert booked("attn.score_entries_live") == sequences * live
     assert booked("attn.score_entries_computed") == sequences * computed
     assert reference.live_score_entries(TINY, SEQ) == live
+
+
+# -- the program itself --------------------------------------------------------
+
+# sha256 of the StableHLO of the benchmark's grad_step at TINY, bfloat16,
+# two sequences, as the tree of PR 31 lowers it: the rotary positions
+# and the sigmoid-gated attention output moved to
+# ``models/transformer.py`` (PR 32, where Qwen3-Next shares them) and
+# the program Laguna compiles is, byte for byte, the one it was. A
+# change that means to alter Laguna's program records the new value.
+FUSED_STEP_STABLEHLO = \
+    "bbaa198759c2c880faef6418807a92ede9dfc0e651b56389c808962166a71be3"
+
+
+def test_grad_step_lowers_to_the_recorded_program():
+    import hashlib
+
+    cfg = dict(TINY, compute_dtype="bfloat16")
+    names, grad_step = bench_model.build(cfg, SEQ)
+    shapes = reference.param_shapes(cfg)
+    text = jax.jit(grad_step).lower(
+        [jax.ShapeDtypeStruct(shapes[n], jnp.float32) for n in names],
+        jax.ShapeDtypeStruct((2, SEQ + 1), jnp.int32), None).as_text()
+    assert hashlib.sha256(text.encode()).hexdigest() == FUSED_STEP_STABLEHLO
