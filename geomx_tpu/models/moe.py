@@ -5,20 +5,30 @@ Beyond the reference (its op set predates MoE; SURVEY.md §2.3 — the
 rubric's EP axis). Expert weights are STACKED along a leading expert
 dimension and sharded ``P("ep", ...)``.
 
-:func:`sparse_dispatch` is the one dispatch: the (row, slot) pairs a
-top-k router chose are sorted by expert, the expert FFN runs as grouped
-matmuls over the group sizes (``jax.lax.ragged_dot``), the rows are
-unsorted and combined with the router's weights. Work follows the rows
-routed, shapes stay static, no capacity factor drops a token. It takes
-``k`` from its inputs and the contiguous range of experts held here as
-an argument: one rank of an expert-parallel layout computes its own
-experts' terms and nothing for the others. The gates are whatever
-weights the caller made of its router's scores: softmax probabilities
-as they are (``models/olmoe.py``, top-8 of 64), sigmoid scores
-normalised over the chosen and scaled (``models/laguna.py``, top-8 of
-256 beside a shared expert that never comes here), one probability
-(:class:`MoEBlock`, the ``k = 1`` case); the dispatch multiplies and
-sums, it normalises nothing.
+:func:`sparse_dispatch` is the one dispatch. The (row, slot) pairs a
+top-k router chose are ordered by expert, held experts first: index
+work on ``N * k`` integers. Then only the rows held here move: the
+first ``C`` pairs of that order are gathered straight from the rows
+(``C`` a cap read from the shapes, :func:`dispatch_cap`: about twice
+what even routing sends to the held experts, all ``N * k`` where every
+expert is held), the expert FFN runs as grouped matmuls over the group
+sizes (``jax.lax.ragged_dot``), and the ``C`` results, times their
+gates, are added into their tokens' rows. One rank of an
+expert-parallel layout that holds 8 of 512 experts gathers 1,536 rows
+of 40,960, not all of them. A pass that holds more than ``C`` pairs
+takes the next ``C`` of the order through the same body, and so on:
+a loop of ``ceil(held pairs / C)`` tiles, one in the usual pass, so
+work follows the rows routed, shapes stay static, no capacity factor
+drops a token. It takes ``k`` from its inputs and the
+contiguous range of experts held here as an argument: a rank computes
+its own experts' terms and nothing for the others. The gates are
+whatever weights the caller made of its router's scores: softmax
+probabilities as they are (``models/olmoe.py``, top-8 of 64), sigmoid
+scores normalised over the chosen and scaled (``models/laguna.py``,
+top-8 of 256 beside a shared expert that never comes here), softmax
+probabilities normalised over the chosen (``models/qwen3_next.py``,
+top-10 of 512), one probability (:class:`MoEBlock`, the ``k = 1``
+case); the dispatch multiplies and sums, it normalises nothing.
 
 :class:`MoEBlock` router: top-1 (Switch-style) with optional jitter
 noise and the standard load-balancing auxiliary loss (mean fraction x
@@ -35,7 +45,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 __all__ = ["MoEBlock", "moe_param_sharding", "is_expert_param",
-           "sparse_dispatch", "gated_experts"]
+           "sparse_dispatch", "dispatch_cap", "gated_experts"]
 
 # leaf names of expert-stacked params (leading axis = expert dim)
 EXPERT_PARAM_NAMES = ("w_up", "b_up", "w_dn", "b_dn")
@@ -53,64 +63,130 @@ def expert_spec(ndim: int) -> P:
     return P(*(["ep"] + [None] * (ndim - 1)))
 
 
-@jax.custom_vjp
-def _take_rows(x, fwd_idx, bwd_idx):
-    """``x[fwd_idx]`` for a permutation and its inverse: the cotangent
-    is a gather too (``g[bwd_idx]``), never a scatter-add."""
-    return x[fwd_idx]
+def _sum_of_tiles(tile, count, ints):
+    """``sum(tile(t, *ints) for t in range(count))`` for a traced
+    ``count``: a loop as long as its input asks, where ``lax.scan``
+    needs a length and ``lax.cond`` a copy of the body a branch. The way
+    back is the same loop over each tile's own vjp, recomputed: nothing
+    is kept a tile, and what the program holds is one body forward and
+    one backward. ``tile`` takes integer arrays and may close over the
+    floating-point ones it reads (they are found and differentiated)."""
+    like = jax.eval_shape(tile, 0, *ints)
+    tile, closed = jax.closure_convert(tile, 0, *ints)
+
+    @jax.custom_vjp
+    def run(count, ints, closed):
+        return jax.lax.fori_loop(
+            0, count, lambda t, y: y + tile(t, *ints, *closed),
+            jnp.zeros(like.shape, like.dtype))
+
+    def back(kept, g):
+        count, ints, closed = kept
+
+        def add(t, sums):
+            terms, = jax.vjp(lambda closed: tile(t, *ints, *closed),
+                             closed)[1](g)
+            return jax.tree_util.tree_map(jnp.add, sums, terms)
+
+        return None, None, jax.lax.fori_loop(
+            0, count, add, jax.tree_util.tree_map(jnp.zeros_like, closed))
+
+    run.defvjp(lambda *kept: (run(*kept), kept), back)
+    return run(count, ints, closed)
 
 
-def _take_rows_fwd(x, fwd_idx, bwd_idx):
-    return x[fwd_idx], bwd_idx
+# the cap is a whole number of these: rows of a grouped matmul's tile
+ROW_TILE = 512
 
 
-def _take_rows_bwd(bwd_idx, g):
-    return g[bwd_idx], None, None
+def dispatch_cap(n, k, held_n, num_experts=None):
+    """Rows :func:`sparse_dispatch` gathers in the usual pass, from
+    shapes alone: about twice what ``held_n`` of ``num_experts`` experts
+    get of ``n * k`` (row, slot) pairs when the router spreads them
+    evenly, rounded up to ``ROW_TILE``, never over ``n * k``; all ``n *
+    k`` where every expert is held or the router's width is not given.
+    A pass that holds more pairs runs further tiles of as many: a
+    caller that wants to know how often counts ``sum(group_sizes) >
+    dispatch_cap(...)``."""
+    pairs = n * k
+    if num_experts is None or held_n >= num_experts:
+        return pairs
+    tiles = -(-2 * pairs * held_n // (num_experts * ROW_TILE))
+    return min(pairs, max(tiles, 1) * ROW_TILE)
 
 
-_take_rows.defvjp(_take_rows_fwd, _take_rows_bwd)
-
-
-def sparse_dispatch(h, expert_idx, gates, expert_fn, local_experts):
+def sparse_dispatch(h, expert_idx, gates, expert_fn, local_experts,
+                    num_experts=None):
     """No-drop top-k dispatch to a contiguous range of experts.
 
     ``h`` [N, D] rows, ``expert_idx`` [N, k] the experts each row chose
     (ids of the whole router), ``gates`` [N, k] their weights, as the
-    caller normalised them,
-    ``local_experts`` (lo, hi) the experts held here. The (row, slot)
-    pairs are sorted by expert, held experts first and in order, so
-    that ``expert_fn(rows [N*k, D], group_sizes [hi-lo], row_expert
-    [N*k]) -> [N*k, D_out]`` sees each expert's rows contiguous (what
-    ``jax.lax.ragged_dot`` wants); the pairs of experts held elsewhere
-    sort last, past the sum of the group sizes, are zero on the way in
-    and on the way out and cost shape, not arithmetic. Shapes are
-    static, nothing is dropped whatever the routing. Returns the rows'
+    caller normalised them, ``local_experts`` (lo, hi) the experts held
+    here, ``num_experts`` the router's width. The (row, slot) pairs are
+    ordered by expert, held experts first and in order (integers only);
+    the first ``C = dispatch_cap(...)`` of that order are gathered from
+    ``h``, so that ``expert_fn(rows [C, D], group_sizes [hi-lo],
+    row_expert [C]) -> [C, D_out]`` sees each expert's rows contiguous
+    (what ``jax.lax.ragged_dot`` wants), and their results, times their
+    gates, are added into their tokens' rows. Rows past the held pairs
+    are zero on the way in and on the way out and cost shape, not
+    arithmetic. The body runs ``ceil(held pairs / C)`` times, tile
+    after tile of the order (:func:`_sum_of_tiles`): once in the usual
+    pass, not at all where no pair is held, and as often as it takes
+    where the router crowds the held experts: shapes are static,
+    nothing is dropped whatever the routing, and no pass builds an
+    array of ``N * k`` rows unless ``C`` is that. Returns the rows'
     ``sum_slot gate * expert(row)`` over the held experts, [N, D_out],
-    and the group sizes.
+    and the group sizes of the whole pass.
     """
     lo, hi = local_experts
     held_n = hi - lo
     n, k = expert_idx.shape
+    pairs = n * k
+    cap = dispatch_cap(n, k, held_n, num_experts)
     with jax.named_scope("dispatch"):
         e = expert_idx.reshape(-1) - lo
         slot = jnp.where((e >= 0) & (e < held_n), e, held_n)
         order = jnp.argsort(slot, stable=True)
-        inverse = jnp.zeros_like(order).at[order].set(
-            jnp.arange(n * k, dtype=order.dtype))
-        row_expert = slot[order]
         group_sizes = jnp.bincount(slot, length=held_n + 1)[
             :held_n].astype(jnp.int32)
-        held = (row_expert < held_n)[:, None]
-        rows = _take_rows(jnp.repeat(h, k, axis=0), order, inverse)
-        # a select, not a product: what a grouped matmul leaves past its
-        # groups need not be a number, in either direction
-        rows = jnp.where(held, rows, jnp.zeros((), rows.dtype))
-    out = expert_fn(rows, group_sizes, row_expert)
-    with jax.named_scope("combine"):
-        out = jnp.where(held, out, jnp.zeros((), out.dtype))
-        out = _take_rows(out, inverse, order).reshape(n, k, -1)
-        y = jnp.einsum("nk,nkd->nd", gates.astype(out.dtype), out)
-    return y, group_sizes
+        ends = jnp.cumsum(group_sizes)
+        flat_gates = gates.reshape(-1)
+    like = jax.eval_shape(
+        expert_fn, jax.ShapeDtypeStruct((cap, h.shape[1]), h.dtype),
+        group_sizes, jax.ShapeDtypeStruct((cap,), slot.dtype))
+
+    def tile(t, order, slot, ends, group_sizes):
+        """The pairs ``order[t * cap:(t + 1) * cap]`` through the
+        experts: their gated results added into their tokens' rows of
+        zeros, [N, D_out] float32."""
+        start = t * cap
+        with jax.named_scope("dispatch"):
+            at = start + jnp.arange(cap)
+            held = (at < ends[-1])[:, None]
+            pair = order[jnp.minimum(at, pairs - 1)]
+            token = pair // k
+            sizes = jnp.clip(ends, start, start + cap) \
+                - jnp.clip(ends - group_sizes, start, start + cap)
+            # a select, not a product: what a grouped matmul leaves past
+            # its groups need not be a number, in either direction
+            rows = jnp.where(held, h[token], jnp.zeros((), h.dtype))
+        out = expert_fn(rows, sizes, slot[pair])
+        with jax.named_scope("combine"):
+            # the gates in the rows' dtype; a token's slots are summed
+            # in float32 and rounded once
+            gate = flat_gates[pair][:, None].astype(out.dtype)
+            terms = jnp.where(held, out, jnp.zeros((), out.dtype)).astype(
+                jnp.float32) * gate.astype(jnp.float32)
+            return jnp.zeros((n, like.shape[1]), jnp.float32).at[
+                token].add(terms)
+
+    ints = (order, slot, ends, group_sizes)
+    if cap == pairs:
+        y = tile(0, *ints)
+    else:
+        y = _sum_of_tiles(tile, -(-ends[-1] // cap), ints)
+    return y.astype(like.dtype), group_sizes
 
 
 def gated_experts(w_gate, w_up, w_down):

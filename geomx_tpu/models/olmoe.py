@@ -128,7 +128,8 @@ class OlmoeBlock(nn.Module):
         y, group_sizes = sparse_dispatch(
             h.reshape(b * t, d).astype(dt),
             chosen.reshape(b * t, -1), gates.reshape(b * t, -1),
-            gated_experts(w_gate, w_up, w_down), self.local_experts)
+            gated_experts(w_gate, w_up, w_down), self.local_experts,
+            self.num_experts)
         return (x + y.reshape(b, t, d).astype(jnp.float32), probs, chosen,
                 jnp.sum(group_sizes))
 

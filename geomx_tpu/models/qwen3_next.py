@@ -251,7 +251,7 @@ class Qwen3NextBlock(nn.Module):
         def routed_experts(rows, chosen, weights, w_gate, w_up, w_down):
             return sparse_dispatch(rows, chosen, weights,
                                    gated_experts(w_gate, w_up, w_down),
-                                   self.local_experts)
+                                   self.local_experts, self.num_experts)
 
         routed, group_sizes = routed_experts(
             m.reshape(b * t, d).astype(dt), chosen.reshape(b * t, -1),

@@ -435,22 +435,43 @@ def test_two_party_round_through_the_device_trainer():
 # -- the program itself --------------------------------------------------------
 
 # sha256 of the StableHLO of the benchmark's grad_step at TINY, bfloat16,
-# two sequences, as the tree of PR 31 lowers it: the rotary positions
-# and the sigmoid-gated attention output moved to
-# ``models/transformer.py`` (PR 32, where Qwen3-Next shares them) and
-# the program Laguna compiles is, byte for byte, the one it was. A
-# change that means to alter Laguna's program records the new value.
+# two sequences, as the tree of PR 33 lowers it: ``sparse_dispatch``
+# gathers the held (token, slot) pairs up to a cap before any row moves
+# (PR 33 meant to alter Laguna's program; PRs 31 and 32 left it byte for
+# byte what it was). A change that means to alter it records the new
+# value.
 FUSED_STEP_STABLEHLO = \
-    "bbaa198759c2c880faef6418807a92ede9dfc0e651b56389c808962166a71be3"
+    "677ab71659c886cde786d57933b4e33c110f8b8d60d2ebb9ada2d4037162fea4"
+# the same of the GPT-2 family's grad_step at gpt2-small's rehearsal
+# widths, 37 tokens, taken on the tree of PR 32: a family without
+# experts compiles what it compiled before the dispatch changed
+GPT2_STEP_STABLEHLO = \
+    "657e9adddff64d1f1838639620ac1eabfac06877c59c6c5cea4243988416a4f2"
 
 
-def test_grad_step_lowers_to_the_recorded_program():
+def _stablehlo_sha256(model, family_reference, cfg):
     import hashlib
 
-    cfg = dict(TINY, compute_dtype="bfloat16")
-    names, grad_step = bench_model.build(cfg, SEQ)
-    shapes = reference.param_shapes(cfg)
+    names, grad_step = model.build(cfg, SEQ)
+    shapes = family_reference.param_shapes(cfg)
     text = jax.jit(grad_step).lower(
         [jax.ShapeDtypeStruct(shapes[n], jnp.float32) for n in names],
         jax.ShapeDtypeStruct((2, SEQ + 1), jnp.int32), None).as_text()
-    assert hashlib.sha256(text.encode()).hexdigest() == FUSED_STEP_STABLEHLO
+    return hashlib.sha256(text.encode()).hexdigest()
+
+
+def test_grad_step_lowers_to_the_recorded_program():
+    assert _stablehlo_sha256(
+        bench_model, reference,
+        dict(TINY, compute_dtype="bfloat16")) == FUSED_STEP_STABLEHLO
+
+
+def test_gpt2_grad_step_lowers_to_the_parents_program():
+    from benchmark import manifest
+    from benchmark.models import transformer
+    from benchmark.references import transformer as transformer_reference
+
+    cfg = manifest.load_cell("gpt2s-hips-bsc")["config"]
+    assert _stablehlo_sha256(
+        transformer, transformer_reference,
+        dict(cfg, **cfg["rehearsal"])) == GPT2_STEP_STABLEHLO

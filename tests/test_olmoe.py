@@ -15,7 +15,7 @@ import pytest
 from benchmark.models import olmoe as bench_model
 from benchmark.references import olmoe as reference
 from geomx_tpu import telemetry
-from geomx_tpu.models.moe import sparse_dispatch
+from geomx_tpu.models.moe import dispatch_cap, sparse_dispatch
 from geomx_tpu.models.olmoe import Olmoe, OlmoeBlock
 from geomx_tpu.simulate import InProcessHiPS
 from geomx_tpu.trainer_device import DeviceResidentTrainer
@@ -109,32 +109,39 @@ def test_float8_operands_fail_the_bfloat16_tolerance():
 E, D, W, N = 8, 16, 12, 24
 
 
-def _ffn_weights(rng):
+def _ffn_weights(rng, experts=E):
     return tuple(jnp.asarray(rng.normal(0, 0.3, s), jnp.float32)
-                 for s in ((E, D, W), (E, D, W), (E, W, D)))
+                 for s in ((experts, D, W), (experts, D, W),
+                           (experts, W, D)))
 
 
-def _sparse(h, chosen, gates, weights, local=(0, E)):
+def _sparse(h, chosen, gates, weights, local=(0, E), num_experts=None):
     lo, hi = local
-    w_gate, w_up, w_down = (w[lo:hi] for w in weights)
+    return sparse_dispatch(h, chosen, gates,
+                           _gated(*(w[lo:hi] for w in weights)), local,
+                           num_experts)
 
+
+def _gated(w_gate, w_up, w_down):
     def experts(rows, group_sizes, _row_expert):
         a = jax.nn.silu(jax.lax.ragged_dot(rows, w_gate, group_sizes)) \
             * jax.lax.ragged_dot(rows, w_up, group_sizes)
         return jax.lax.ragged_dot(a, w_down, group_sizes)
 
-    return sparse_dispatch(h, chosen, gates, experts, local)
+    return experts
 
 
 def _dense(h, chosen, gates, weights, local=(0, E)):
     """Every expert computes every row; the router's mask picks."""
     w_gate, w_up, w_down = weights
+    experts = len(w_gate)
     act = jax.nn.silu(jnp.einsum("nd,edw->enw", h, w_gate)) \
         * jnp.einsum("nd,edw->enw", h, w_up)
     out = jnp.einsum("enw,ewd->end", act, w_down)
     mask = jnp.einsum("nk,nke->en", gates,
-                      jax.nn.one_hot(chosen, E, dtype=h.dtype))
-    held = (jnp.arange(E) >= local[0]) & (jnp.arange(E) < local[1])
+                      jax.nn.one_hot(chosen, experts, dtype=h.dtype))
+    held = (jnp.arange(experts) >= local[0]) \
+        & (jnp.arange(experts) < local[1])
     return jnp.einsum("en,end->nd", mask * held[:, None], out)
 
 
@@ -182,6 +189,162 @@ def test_sparse_dispatch_is_the_dense_formulation(k, case):
     for a, b in zip(jax.tree_util.tree_leaves(got),
                     jax.tree_util.tree_leaves(want)):
         np.testing.assert_allclose(a, b, rtol=2e-4, atol=2e-5)
+
+
+# -- the compact path: a router of 64, experts 2..4 held, 512 rows of
+# top-4: 2,048 pairs, a cap of 512, so up to four tiles --------------------
+
+WIDE, HELD, ROWS, TOP = 64, (2, 5), 512, 4
+
+
+def _held_routing(rng, case):
+    """[ROWS, TOP] choices whose held pairs (experts 2, 3, 4) number
+    what ``case`` says; every other slot goes to an expert held
+    elsewhere."""
+    away = np.delete(np.arange(WIDE), np.arange(*HELD))
+    chosen = np.stack([rng.permutation(away)[:TOP] for _ in range(ROWS)])
+    if case == "fewer_than_the_cap":        # what a router sends: ~96
+        chosen = np.stack([rng.permutation(WIDE)[:TOP]
+                           for _ in range(ROWS)])
+    elif case == "exactly_the_cap":         # 256 rows x experts 2 and 3
+        chosen[:256, 1], chosen[:256, 3] = 2, 3
+    elif case == "two_tiles":               # expert 3 takes all, + 100
+        chosen[:, 0] = 3
+        chosen[:100, 2] = 4
+    elif case == "three_tiles":             # 512 x (2, 3) and 7 more
+        chosen[:, 3], chosen[:, 1] = 2, 3
+        chosen[5:12, 0] = 4
+    else:
+        assert case == "no_held_pair"
+    gates = rng.uniform(0.05, 1.0, (ROWS, TOP))
+    return jnp.asarray(chosen, jnp.int32), jnp.asarray(gates, jnp.float32)
+
+
+HELD_PAIRS = {"fewer_than_the_cap": None, "exactly_the_cap": 512,
+              "two_tiles": 612, "three_tiles": 1031, "no_held_pair": 0}
+
+
+@pytest.mark.parametrize("case,remat,dtype", [
+    *((case, False, "float32") for case in HELD_PAIRS),
+    ("fewer_than_the_cap", True, "float32"),
+    ("three_tiles", True, "float32"),
+    ("fewer_than_the_cap", False, "bfloat16"),
+    ("two_tiles", False, "bfloat16")])
+def test_compact_dispatch_is_the_dense_formulation(case, remat, dtype):
+    """With the router's width given and 3 of 64 experts held the
+    dispatch gathers 512 rows a tile, not 2,048: the result and every
+    gradient are the dense formulation's whatever the held count (no
+    tile, one, two with an expert's rows across the boundary, three),
+    kept or recomputed, and in the compute dtype of the cells."""
+    assert dispatch_cap(ROWS, TOP, HELD[1] - HELD[0], WIDE) == 512
+    rng = np.random.default_rng(len(case))
+    dt = jnp.dtype(dtype)
+    weights = tuple(w.astype(dt) for w in _ffn_weights(rng, WIDE))
+    h = jnp.asarray(rng.normal(size=(ROWS, D)), dt)
+    chosen, gates = _held_routing(rng, case)
+    gates = gates.astype(dt)
+    sparse = lambda h, gates, weights: _sparse(        # noqa: E731
+        h, chosen, gates, weights, HELD, WIDE)
+    if remat:
+        sparse = jax.checkpoint(sparse)
+    y, sizes = jax.jit(sparse)(h, gates, weights)
+    np.testing.assert_array_equal(
+        sizes, np.bincount(np.asarray(chosen).ravel(),
+                           minlength=WIDE)[HELD[0]:HELD[1]])
+    if HELD_PAIRS[case] is not None:
+        assert int(sizes.sum()) == HELD_PAIRS[case]
+    else:
+        assert 0 < int(sizes.sum()) < 512
+
+    def dense(h, gates, weights):
+        return _dense(h.astype(jnp.float32), chosen,
+                      gates.astype(jnp.float32),
+                      tuple(w.astype(jnp.float32) for w in weights), HELD)
+
+    # bfloat16: 2**-8 a rounding, a few of them between h and y
+    rtol, atol = (2e-5, 2e-6) if dtype == "float32" else (4e-2, 4e-2)
+    np.testing.assert_allclose(np.asarray(y, np.float32),
+                               dense(h, gates, weights), rtol, atol)
+
+    def loss(fn, *a):
+        out = fn(*a)
+        out = out[0] if isinstance(out, tuple) else out
+        return jnp.sum(jnp.sin(out.astype(jnp.float32)))
+
+    got = jax.jit(jax.grad(lambda *a: loss(sparse, *a), (0, 1, 2)))(
+        h, gates, weights)
+    want = jax.grad(lambda *a: loss(dense, *a), (0, 1, 2))(
+        h, gates, weights)
+    for a, b in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        if dtype == "float32":
+            np.testing.assert_allclose(a, b, rtol=2e-4, atol=2e-5)
+        else:
+            assert np.linalg.norm(a - b) <= 3e-2 * np.linalg.norm(b)
+    if case == "no_held_pair":
+        assert not np.any(np.asarray(y)) and not any(
+            np.any(np.asarray(g)) for g in jax.tree_util.tree_leaves(got))
+
+
+def test_the_cap_follows_from_the_shapes():
+    # 4,096 rows a pass: Qwen3-Next top-10, 8 of 512 held; Laguna top-8,
+    # 8 of 256; OLMoE top-8, 16 of 64: about twice the even count, in
+    # tiles of 512
+    assert dispatch_cap(4096, 10, 8, 512) == 1536
+    assert dispatch_cap(4096, 8, 8, 256) == 2048
+    assert dispatch_cap(4096, 8, 16, 64) == 16384
+    # the router's width not given, or every expert held: all the pairs
+    assert dispatch_cap(4096, 10, 8) == 40960
+    assert dispatch_cap(4096, 8, 64, 64) == 32768
+    # never over the pairs there are, never under a tile
+    assert dispatch_cap(24, 2, 4, 8) == 48
+    assert dispatch_cap(4096, 8, 1, 4096) == 512
+
+
+def _largest_arrays(fn, *args):
+    """(rows, elements) maxima over every array of two or more
+    dimensions that ``fn``'s jaxpr makes, nested jaxprs included."""
+    seen = []
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            for v in eqn.outvars:
+                shape = getattr(v.aval, "shape", ())
+                if len(shape) >= 2 and shape[-1] > 1:
+                    seen.append((int(np.prod(shape[:-1])),
+                                 int(np.prod(shape))))
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub)
+
+    walk(jax.make_jaxpr(fn)(*args).jaxpr)
+    return max(r for r, _ in seen), max(n for _, n in seen)
+
+
+def test_the_compact_path_builds_no_array_of_all_the_pairs():
+    """Forward, recomputed and backward: no array of ROWS * TOP rows by
+    more than one column, and none larger than the rows by the wider of
+    D and W, whatever the number of tiles (a loop that kept its tiles'
+    terms for the way back would stack them)."""
+    rng = np.random.default_rng(5)
+    weights = _ffn_weights(rng, HELD[1] - HELD[0])
+    h = jnp.asarray(rng.normal(size=(ROWS, D)), jnp.float32)
+    chosen, gates = _held_routing(rng, "fewer_than_the_cap")
+
+    def grads(num_experts, remat):
+        def loss(h, gates, weights):
+            return jnp.sum(jnp.sin(sparse_dispatch(
+                h, chosen, gates, _gated(*weights), HELD, num_experts)[0]))
+        return jax.grad(remat(loss), (0, 1, 2))
+
+    for remat in (jax.checkpoint, lambda f: f):
+        rows, size = _largest_arrays(grads(WIDE, remat), h, gates, weights)
+        assert rows < ROWS * TOP and size <= ROWS * max(D, W), (rows, size)
+    # the walk sees what it should: without the router's width the
+    # dispatch gathers every pair
+    rows, size = _largest_arrays(grads(None, jax.checkpoint),
+                                 h, gates, weights)
+    assert rows == ROWS * TOP and size == ROWS * TOP * D, (rows, size)
 
 
 def test_a_held_range_computes_its_own_experts_only():
