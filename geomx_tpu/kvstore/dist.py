@@ -757,7 +757,9 @@ class KVStoreDist(KVStore):
             for k, err in failed_keys:
                 fut.add_error(k, err)   # future methods outside _lock
             finished = []
-            with profiler.chunk_scope("recv", cid, server=srank):
+            with profiler.scope("pipeline:recv", cat="pipeline",
+                                chunk=cid, server=srank,
+                                **self.po.van.round_args(rid)):
                 for kvs in self.kvw.take_response(ts):
                     for i, k in enumerate(kvs.keys):
                         data = _wire_decode(kvs, i)
@@ -820,8 +822,10 @@ class KVStoreDist(KVStore):
         for mid, cid, srank, kvs, _mks, prio in sorted(
                 msgs, key=lambda m: -sum(
                     np.asarray(v).nbytes for v in m[3].vals)):
-            with profiler.chunk_scope("send", cid, server=srank,
-                                      keys=len(kvs.keys)):
+            with profiler.scope("pipeline:send", cat="pipeline",
+                                chunk=cid, server=srank,
+                                keys=len(kvs.keys),
+                                **self.po.van.round_args(rid)):
                 self.kvw.push(kvs, srank, priority=prio, pull=True,
                               trace_round=rid, trace_chunk=cid,
                               cb=lambda ts, m=mid: on_resp(ts, m))
@@ -1219,6 +1223,7 @@ class KVStoreDist(KVStore):
         fut = RoundFuture(keys, consume=self._consume_errors,
                           max_retries=self.cfg.chunk_retries,
                           on_abort=self._abort_round)
+        fut.trace_round = rid
         parts: Dict[int, List] = {k: [] for k in keys}
         expected_parts: Dict[int, int] = {}
         msgs = []  # (mid, cid, srank, kvs, msg_keys, chunk_priority)
@@ -1251,6 +1256,15 @@ class KVStoreDist(KVStore):
                 self._track(1, k)
 
         def on_resp(ts: int, mid: int):
+            # a response into its keys' results: decode, join the parts,
+            # complete the keys the trainer waits for
+            _m, cid, srank, _kvs, _mks, _prio = msgs[mid]
+            with profiler.scope("pipeline:recv", cat="pipeline",
+                                chunk=cid, server=srank,
+                                **self.po.van.round_args(rid)):
+                take(ts, mid)
+
+        def take(ts: int, mid: int):
             _m, cid, srank, m_kvs, mks, m_prio = msgs[mid]
             fail = self.kvw.take_failure(ts)
             # same bounded retry as push_pull_async's on_resp: re-issue
@@ -1277,23 +1291,22 @@ class KVStoreDist(KVStore):
                         failed_keys.append((k, err))
             for k, err in failed_keys:
                 fut.add_error(k, err)   # future methods outside _lock
-            with profiler.chunk_scope("recv", cid, server=srank):
-                for kvs in self.kvw.take_response(ts):
-                    for i, k in enumerate(kvs.keys):
-                        data = np.asarray(kvs.vals[i],
-                                          dtype=np.float32).ravel()
-                        r_off = kvs.offset_of(i)
-                        aux = kvs.aux[i] if i < len(kvs.aux) else None
-                        if kvs.compr in ("bsc", "bsc16") and aux is not None:
-                            entry = (data,
-                                     np.asarray(aux, np.int64).ravel()
-                                     + r_off)
-                        else:
-                            nz = np.nonzero(data)[0]
-                            entry = (data[nz].astype(np.float32),
-                                     nz + r_off)
-                        with self._lock:
-                            parts[k].append(entry)
+            for kvs in self.kvw.take_response(ts):
+                for i, k in enumerate(kvs.keys):
+                    data = np.asarray(kvs.vals[i],
+                                      dtype=np.float32).ravel()
+                    r_off = kvs.offset_of(i)
+                    aux = kvs.aux[i] if i < len(kvs.aux) else None
+                    if kvs.compr in ("bsc", "bsc16") and aux is not None:
+                        entry = (data,
+                                 np.asarray(aux, np.int64).ravel()
+                                 + r_off)
+                    else:
+                        nz = np.nonzero(data)[0]
+                        entry = (data[nz].astype(np.float32),
+                                 nz + r_off)
+                    with self._lock:
+                        parts[k].append(entry)
             finished = []
             ready = []
             with self._lock:
@@ -1334,8 +1347,10 @@ class KVStoreDist(KVStore):
                 self._repull_bsc_async(short, priority, fut)
 
         for mid, cid, srank, kvs, _mks, prio in msgs:
-            with profiler.chunk_scope("send", cid, server=srank,
-                                      keys=len(kvs.keys)):
+            with profiler.scope("pipeline:send", cat="pipeline",
+                                chunk=cid, server=srank,
+                                keys=len(kvs.keys),
+                                **self.po.van.round_args(rid)):
                 self.kvw.push(kvs, srank, priority=prio, pull=True,
                               trace_round=rid, trace_chunk=cid,
                               cb=lambda ts, m=mid: on_resp(ts, m))

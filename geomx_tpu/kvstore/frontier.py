@@ -250,6 +250,9 @@ class RoundFuture:
         # chunk instead of recording its error
         self.max_retries = max_retries
         self._retries: Dict[int, int] = {}
+        # Meta.trace_round of the round's wire messages, where the
+        # issuing store stamps one: the caller's spans carry it too
+        self.trace_round = -1
 
     @property
     def keys(self) -> List[int]:

@@ -64,6 +64,7 @@ aligned wire-key ranges and supports only matching layouts).
 from __future__ import annotations
 
 import dataclasses
+import functools
 import io
 import json
 import logging
@@ -127,6 +128,33 @@ def _safe_unpickle(data: bytes):
 
 
 from contextlib import nullcontext as _null_ctx
+
+
+# the handler's round span by (global tier, push): profiler.ROUND_SPANS
+_HANDLER_SPANS = (("server.pull", "server.push"),
+                  ("server.pull.global", "server.push.global"))
+
+
+def _round_span(name: str):
+    """Run a party server's method under the round span ``name``
+    (profiler.ROUND_SPANS), with the id of the round it last took a
+    worker's push for."""
+    def wrap(fn):
+        @functools.wraps(fn)
+        def run(self, *args):
+            with profiler.scope(name, cat="kvstore",
+                                **self._round_args(self._wan_trace[0])):
+                return fn(self, *args)
+        return run
+    return wrap
+
+
+def _clocks() -> Tuple[float, float]:
+    """Now, on the wall clock and on this thread's CPU clock (ms each):
+    an interval of numpy-only host work booked on both says how long the
+    thread was off the processor in it, which here is the wait for the
+    GIL."""
+    return 1e3 * time.perf_counter(), 1e3 * time.thread_time()
 
 
 class _BatchResponder:
@@ -630,13 +658,21 @@ class KVStoreDistServer:
             self._handle_command(req, srv, global_tier)
             return
         global_store = self.is_global_server or global_tier
-        if profiler.is_running():
-            tag = ("server.push" if req.push else "server.pull") + (
-                ".global" if global_tier else "")
-            with profiler.scope(tag, cat="kvstore"):
-                self._handle_data(req, kvs, srv, global_store, global_tier)
-            return
-        self._handle_data(req, kvs, srv, global_store, global_tier)
+        with profiler.scope(_HANDLER_SPANS[global_tier][bool(req.push)],
+                            cat="kvstore",
+                            **self._round_args(req.trace_round,
+                                               global_tier)):
+            self._handle_data(req, kvs, srv, global_store, global_tier)
+
+    def _round_args(self, trace_round: int,
+                    global_tier: bool = False) -> Dict[str, object]:
+        """Arguments of a round span of this server: as the van's, with
+        the identity of the tier the span works for — a party server's
+        spans (push, pull, select, forward, pull-back) carry its
+        local-tier id, the global server's its global-tier id."""
+        po = (self.po_global if global_tier and self.po_global is not None
+              else self.po_local)
+        return po.van.round_args(trace_round)
 
     def _handle_data(self, req: ReqMeta, kvs: KVPairs, srv: KVServer,
                      global_store: bool, global_tier: bool) -> None:
@@ -685,7 +721,7 @@ class KVStoreDistServer:
         # when the profiler runs, each key's state-machine step records
         # its own span so a trace shows WHICH key dominated the round
         tagging = profiler.is_running()
-        t0 = time.perf_counter()
+        t0 = _clocks()
         for i, key in enumerate(kvs.keys):
             off = kvs.offset_of(i)
             total = kvs.total_of(i)
@@ -712,6 +748,14 @@ class KVStoreDistServer:
                 self._fwd_tls.entries = None
             if entries:
                 self._flush_forward_batch(entries)
+        elif global_tier and acts:
+            # the parties' answers are merged, packed and sent here,
+            # one message a party (a key's part of one is cut from the
+            # store as its round completes, in the handler's own time)
+            with profiler.scope("server.respond", cat="kvstore",
+                                **self._round_args(req.trace_round, True)):
+                for fn in acts:
+                    fn()
         else:
             for fn in acts:
                 fn()
@@ -1128,11 +1172,15 @@ class KVStoreDistServer:
             "server.sparse_key_rounds" if sparse
             else "server.dense_key_rounds", tier=self._tier)
 
-    def _count_aggregate_ms(self, t0: float) -> None:
-        """Host time since ``t0`` spent between a push or a pull-back
-        payload coming off the wire and its responses being built."""
-        telemetry.counter_inc("server.aggregate_ms",
-                              1e3 * (time.perf_counter() - t0),
+    def _count_aggregate_ms(self, t0: Tuple[float, float]) -> None:
+        """Host time since ``t0`` (:func:`_clocks`) spent between a push
+        or a pull-back payload coming off the wire and its responses
+        being built: on the wall clock, and on this thread's CPU clock
+        beside it."""
+        wall, cpu = _clocks()
+        telemetry.counter_inc("server.aggregate_ms", wall - t0[0],
+                              tier=self._tier)
+        telemetry.counter_inc("server.aggregate_cpu_ms", cpu - t0[1],
                               tier=self._tier)
 
     def _expected_global_elems(self, st) -> int:
@@ -1523,12 +1571,16 @@ class KVStoreDistServer:
                 # once a (key, shard) round, however many global slices
                 telemetry.counter_inc("server.sparse_forward_key_rounds",
                                       tier=self._tier)
-        t0 = time.perf_counter()
-        wv, aux, t = self.gc.compress_push(sub, (key, lo))
+        t0 = _clocks()
+        with profiler.scope("server.select", cat="kvstore",
+                            **self._round_args(self._wan_trace[0])):
+            wv, aux, t = self.gc.compress_push(sub, (key, lo))
         if t == "bsc":
             # the party server's Bi-Sparse re-selection, host numpy
-            telemetry.counter_inc("server.bsc_select_ms",
-                                  1e3 * (time.perf_counter() - t0),
+            wall, cpu = _clocks()
+            telemetry.counter_inc("server.bsc_select_ms", wall - t0[0],
+                                  tier="global")
+            telemetry.counter_inc("server.bsc_select_cpu_ms", cpu - t0[1],
                                   tier="global")
         if not tag:
             return wv, aux, t
@@ -1604,6 +1656,7 @@ class KVStoreDistServer:
     # assumes, kvstore_dist.h:567-618, which likewise amortizes per-key
     # overheads across the send queue.)
 
+    @_round_span("server.forward")
     def _flush_forward_batch(self, entries) -> None:
         if self._transport is not None:
             # refresh the transport plan once per WAN round (idempotent
@@ -1645,6 +1698,7 @@ class KVStoreDistServer:
                 cb=lambda ts, its=items, g=g_rank:
                     self._on_global_push_ack_batch(its, g, ts))
 
+    @_round_span("server.pullback")
     def _on_global_push_ack_batch(self, items, g_rank, ts) -> None:
         fail = self.worker_global.take_failure(ts)
         if fail is not None:
@@ -1662,7 +1716,7 @@ class KVStoreDistServer:
         # final decrement every other rank's callback has already
         # applied its part, so completion sees the full set
         resps = self.worker_global.take_response(ts)
-        t0 = time.perf_counter()
+        t0 = _clocks()
         # a key can appear several times in one batch (P3 slicing gives
         # one (key, off) state per slice): route each response entry to
         # every item of that key whose slice range overlaps the data
@@ -1753,6 +1807,7 @@ class KVStoreDistServer:
                 cb=lambda ts, its=items, g=g_rank:
                     self._on_global_pull_data_batch(its, g, ts))
 
+    @_round_span("server.pullback")
     def _on_global_pull_data_batch(self, items, g_rank, ts) -> None:
         fail = self.worker_global.take_failure(ts)
         if fail is not None:
@@ -1763,7 +1818,7 @@ class KVStoreDistServer:
                                   cycle, g_rank, lo, hi, total)
             return
         resps = self.worker_global.take_response(ts)
-        t0 = time.perf_counter()
+        t0 = _clocks()
         # route each response entry to its (key, off) slice; a key can
         # appear several times in one batch (P3 slicing gives one
         # (key, off) state per slice), so match by range overlap
@@ -1988,7 +2043,7 @@ class KVStoreDistServer:
             return
         # drain the tracker even when the cycle guard discards the data
         resps = self.worker_global.take_response(ts)
-        t0 = time.perf_counter()
+        t0 = _clocks()
         acts: List[Action] = []
         st = self._state(key, off)
         with st.lock:

@@ -1,4 +1,5 @@
-"""Tracing/profiling: chrome-trace host events + device trace bridge.
+"""Tracing/profiling: chrome-trace host events + the round's spans on
+a JAX trace's clock.
 
 Plays the role of the reference profiler (reference: src/profiler/
 profiler.h:256 Profiler singleton, SetState :270, DumpProfile :304 —
@@ -9,10 +10,14 @@ set_state/pause/resume/dump surface), re-designed for the TPU stack:
   rounds) are recorded by this module into chrome trace-event JSON,
   viewable in chrome://tracing or Perfetto — same artifact the reference
   emits;
-- device-side compute profiling is delegated to ``jax.profiler``
-  (XLA's tracer knows the TPU better than any host timer):
-  :func:`start_device_trace` / :func:`stop_device_trace` wrap
-  ``jax.profiler.start_trace`` so one call site controls both layers.
+- device-side compute profiling is ``jax.profiler``'s (XLA's tracer
+  knows the TPU better than any host timer), and a JAX trace is the one
+  switch of the ROUND SPANS (:data:`ROUND_SPANS`): :func:`annotate` and
+  :func:`scope` open a ``jax.profiler.TraceAnnotation`` whenever JAX is
+  loaded in the process and anybody's ``jax.profiler.start_trace``
+  session runs: the round's host spans then land on their threads'
+  lines of ``/host:CPU``, on the clock of the chip's ``XLA Ops`` lines.
+  With no session active a span is one atomic load.
 
 The distributed twist is kept: workers remotely drive SERVER profilers
 over the command channel (reference: KVStoreServerProfilerCommand
@@ -26,17 +31,15 @@ from __future__ import annotations
 
 import json
 import os
+import sys
 import threading
 import time
-from contextlib import contextmanager
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, List, NamedTuple, Optional
 
 _lock = threading.Lock()
 _events: List[Dict[str, Any]] = []
-_counters: Dict[str, float] = {}
 _state_running = False
 _paused = False
-_device_trace_dir: Optional[str] = None
 _config: Dict[str, Any] = {"filename": "profile.json"}
 _t0 = time.monotonic()
 
@@ -47,12 +50,48 @@ CMD_PAUSE = 2
 CMD_DUMP = 3
 
 
+class RoundSpan(NamedTuple):
+    name: str       # the span's name in a trace, a constant
+    layer: str      # whose time it is when the chip waits
+    cls: str        # "work": the thread computes or copies;
+    #                 "wait": it sleeps for somebody else's work
+
+
+# The spans of one training round, from the trainer's ``step()`` down to
+# the link, each opened where the work happens (docs/observability.md
+# has the file and line of each). Names are constants; node, tier,
+# chunk and the round's ``Meta.trace_round`` id ride as arguments. On a
+# thread the innermost open span owns the instant, so a parent's time is
+# its self time. benchmark/gap_readers.py reads them by these names.
+ROUND_SPANS = (
+    RoundSpan("trainer.step", "trainer", "work"),
+    RoundSpan("trainer.fetch", "trainer", "work"),
+    RoundSpan("trainer.pack", "trainer", "work"),
+    RoundSpan("trainer.wait", "trainer", "wait"),
+    RoundSpan("trainer.unpack", "trainer", "work"),
+    RoundSpan("trainer.h2d", "trainer", "work"),
+    RoundSpan("trainer.apply", "trainer", "work"),
+    RoundSpan("pipeline:send", "van", "work"),
+    RoundSpan("pipeline:recv", "van", "work"),
+    RoundSpan("van.send", "van", "work"),
+    RoundSpan("van.recv", "van", "work"),
+    RoundSpan("server.push", "party_server", "work"),
+    RoundSpan("server.pull", "party_server", "work"),
+    RoundSpan("server.forward", "party_server", "work"),
+    RoundSpan("server.pullback", "party_server", "work"),
+    RoundSpan("server.select", "select", "work"),
+    RoundSpan("server.push.global", "global_server", "work"),
+    RoundSpan("server.pull.global", "global_server", "work"),
+    RoundSpan("server.respond", "global_server", "work"),
+    RoundSpan("link.hold", "link", "wait"),
+)
+
+
 def set_config(**kwargs) -> None:
     """Configure the profiler (reference: profiler.py set_config).
 
-    Recognized keys: ``filename`` (chrome-trace output path),
-    ``aggregate_stats`` (keep per-name duration totals). Unknown keys are
-    stored but ignored, for reference-kwarg compatibility.
+    Recognized key: ``filename`` (chrome-trace output path). Unknown
+    keys are stored but ignored, for reference-kwarg compatibility.
     """
     with _lock:
         _config.update(kwargs)
@@ -105,47 +144,98 @@ def record(name: str, cat: str, ts_us: float, dur_us: float,
         ev["args"] = args
     with _lock:
         _events.append(ev)
-        if _config.get("aggregate_stats"):
-            _counters[name] = _counters.get(name, 0.0) + dur_us
 
 
-@contextmanager
+class _NoSpan:
+    """What :func:`annotate` gives where nothing would be written."""
+
+    __slots__ = ()
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+    def set_metadata(self, **args) -> None:
+        pass
+
+
+_NO_SPAN = _NoSpan()
+_annotation = None      # jax.profiler.TraceAnnotation, once JAX is loaded
+
+
+def _find_annotation():
+    global _annotation
+    jax = sys.modules.get("jax")
+    # a module half-way through its import has no ``profiler`` yet
+    _annotation = getattr(getattr(jax, "profiler", None),
+                          "TraceAnnotation", None)
+    return _annotation
+
+
+def annotate(name: str, **args):
+    """A span on the JAX trace's clock and nothing else: a
+    ``jax.profiler.TraceAnnotation`` named ``name`` (a constant of
+    :data:`ROUND_SPANS`; what the span is about rides in ``args`` as
+    numbers or constant strings), for ``with``. More arguments can
+    follow while it is open (``set_metadata``).
+
+    The one switch is the JAX trace itself: with no session active
+    (``TraceAnnotation.is_enabled()``, an atomic load) the span is a
+    shared no-op. JAX is never imported from here: a server process
+    that did not load it gets the no-op too, and no backend is touched.
+    The sites that time themselves for the chrome trace
+    (:func:`record` with explicit times: the van) use this beside it;
+    everybody else uses :func:`scope`, which is both."""
+    ann = _annotation or _find_annotation()
+    if ann is None or not ann.is_enabled():
+        return _NO_SPAN
+    return ann(name, **args)
+
+
+class _ChromeScope:
+    """:func:`scope` while the chrome-trace half is recording."""
+
+    __slots__ = ("_name", "_cat", "_args", "_ann", "_start")
+
+    def __init__(self, name: str, cat: str, args: Dict[str, Any]):
+        self._name, self._cat, self._args = name, cat, args
+        self._ann = annotate(name, **args)
+
+    def __enter__(self):
+        self._start = _now_us()
+        self._ann.__enter__()
+        return self._ann
+
+    def __exit__(self, *exc):
+        self._ann.__exit__(*exc)
+        record(self._name, self._cat, self._start,
+               _now_us() - self._start, self._args or None)
+        return False
+
+
 def scope(name: str, cat: str = "geomx", **args):
     """Time a host-side region (the engine-op tag equivalent of the
-    reference's PROFILER_MESSAGE_FUNCNAME, kvstore_dist_server.h:570).
+    reference's PROFILER_MESSAGE_FUNCNAME, kvstore_dist_server.h:570),
+    for ``with``. Two halves, each with its own switch:
 
-    While an XLA device trace is active (start_device_trace), the region
-    ALSO emits a ``jax.profiler.TraceAnnotation`` — the TPU-idiomatic
-    analogue of the reference's VTune ITT domain/task bridge
-    (src/profiler/vtune.cc): host protocol events appear aligned on the
-    XLA trace timeline next to the device ops they drive, which is what
-    the ITT instrumentation bought the reference inside VTune."""
-    if not is_running():
-        yield
-        return
-    start = _now_us()
-    ann = None
-    if _device_trace_dir is not None:
-        import jax
+    - a chrome trace event, while :func:`is_running`;
+    - a ``jax.profiler.TraceAnnotation`` (:func:`annotate`), while a
+      JAX trace runs — the TPU-idiomatic analogue of the reference's
+      VTune ITT domain/task bridge (src/profiler/vtune.cc): host
+      protocol spans appear on the XLA trace timeline next to the
+      device ops they drive.
 
-        ann = jax.profiler.TraceAnnotation(name)
-        ann.__enter__()
-    try:
-        yield
-    finally:
-        if ann is not None:
-            ann.__exit__(None, None, None)
-        record(name, cat, start, _now_us() - start, args or None)
-
-
-def chunk_scope(stage: str, chunk: int, **args):
-    """Scope tag for one pipeline chunk stage — ``stage`` is one of
-    fetch/send/recv/apply, ``chunk`` the chunk id — so traces show the
-    pipelined round's shape (which chunk was on the wire while which
-    was applying). Same exception-safe ``with`` discipline as the
-    server's per-key tags; near-free when the profiler is stopped."""
-    return scope(f"pipeline:{stage}:c{chunk}", cat="pipeline",
-                 chunk=chunk, **args)
+    ``with scope(...) as span`` gives the annotation, for
+    ``span.set_metadata``."""
+    if _state_running and not _paused:
+        return _ChromeScope(name, cat, args)
+    # annotate(), without a second call on the path of every span
+    ann = _annotation or _find_annotation()
+    if ann is None or not ann.is_enabled():
+        return _NO_SPAN
+    return ann(name, **args)
 
 
 def instant(name: str, cat: str = "geomx", **args: Any) -> None:
@@ -198,47 +288,14 @@ def dump(finished: bool = True, filename: Optional[str] = None) -> str:
     return path
 
 
-def aggregate_stats() -> Dict[str, float]:
-    """Per-name total duration (us), when aggregate_stats was configured."""
-    with _lock:
-        return dict(_counters)
-
-
 def reset() -> None:
     global _state_running, _paused
     with _lock:
         _events.clear()
-        _counters.clear()
         _state_running = False
         _paused = False
         _config.clear()
         _config["filename"] = "profile.json"
-
-
-# ----------------------------------------------------------------------
-# device-side (XLA) tracing bridge
-# ----------------------------------------------------------------------
-
-
-def start_device_trace(logdir: str) -> None:
-    """Start an XLA device trace (TensorBoard-viewable) alongside the
-    host trace. The TPU equivalent of the reference's GPU-side profiler
-    scopes — XLA's profiler sees HLO-level op timings on the chip."""
-    global _device_trace_dir
-    import jax
-
-    jax.profiler.start_trace(logdir)
-    _device_trace_dir = logdir
-
-
-def stop_device_trace() -> None:
-    global _device_trace_dir
-    if _device_trace_dir is None:
-        return
-    import jax
-
-    jax.profiler.stop_trace()
-    _device_trace_dir = None
 
 
 # ----------------------------------------------------------------------

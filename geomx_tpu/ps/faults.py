@@ -45,6 +45,8 @@ import threading
 import time
 from typing import Dict, List, Optional, Tuple
 
+from geomx_tpu import profiler
+
 log = logging.getLogger("geomx.faults")
 
 KINDS = ("drop", "delay", "dup", "reorder", "partition", "crash")
@@ -111,17 +113,24 @@ def deliver_later(van, delay_s: float, msg) -> None:
     normal dispatch (``van._process``). Shared by the fault injector's
     delay/dup rules and the link shaper (``ps/shaping.py``) so both
     layers use one timer/delivery mechanism — a frame held by either
-    re-enters the SAME way and is never gated (or shaped) twice."""
+    re-enters the SAME way and is never gated (or shaped) twice.
+
+    The hold is the round span ``link.hold`` (class ``wait``) on the
+    thread that sleeps for it: on a JAX trace the time a frame spends
+    on the emulated wire has the link's name."""
+    meta = msg.meta
+
     def _deliver():
+        with profiler.annotate("link.hold", sender=meta.sender,
+                               **van.round_args(meta.trace_round)):
+            time.sleep(delay_s)
         try:
             if not van.stopped.is_set():
                 van._process(msg)
         except Exception:  # noqa: BLE001 — held frames must not kill vans
             log.exception("delayed re-injection failed")
 
-    t = threading.Timer(delay_s, _deliver)
-    t.daemon = True
-    t.start()
+    threading.Thread(target=_deliver, name="link-hold", daemon=True).start()
 
 
 class FaultPlan:

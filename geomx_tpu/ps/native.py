@@ -127,8 +127,12 @@ class NativeTransport:
         if n < 0:
             raise OSError(f"native send to {host}:{port} failed")
 
-    def recv(self, timeout_s: float = 1.0) -> Optional[bytes]:
-        """One complete frame, or None on timeout; raises on shutdown."""
+    def wait_frame(self, timeout_s: float = 1.0):
+        """Block for one complete frame: ``(pointer, length)`` into the
+        core's memory, for :meth:`take_frame`, or None on timeout;
+        raises on shutdown. Apart from :meth:`recv` so that the caller
+        can tell the wait from the work on the frame (the van's
+        ``van.recv`` span starts between the two)."""
         out = ctypes.POINTER(ctypes.c_uint8)()
         n = self._lib.gx_recv(self._h, ctypes.byref(out), timeout_s)
         if n == -1:
@@ -138,10 +142,21 @@ class NativeTransport:
             raise MemoryError("native recv allocation failed")
         if n < 0:
             raise ConnectionAbortedError("native transport stopped")
+        return out, n
+
+    def take_frame(self, frame) -> bytes:
+        """Copy a frame of :meth:`wait_frame` out of the core's memory
+        and free it there."""
+        out, n = frame
         try:
             return ctypes.string_at(out, n)
         finally:
             self._lib.gx_free(out)
+
+    def recv(self, timeout_s: float = 1.0) -> Optional[bytes]:
+        """One complete frame, or None on timeout; raises on shutdown."""
+        frame = self.wait_frame(timeout_s)
+        return None if frame is None else self.take_frame(frame)
 
     @property
     def send_bytes(self) -> int:

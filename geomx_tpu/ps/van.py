@@ -23,6 +23,7 @@ Responsibilities:
 from __future__ import annotations
 
 import dataclasses
+import functools
 import heapq
 import itertools
 import logging
@@ -61,6 +62,12 @@ def _shutdown_and_close(sock: socket.socket) -> None:
         sock.close()
     except OSError:
         pass
+
+
+@functools.lru_cache(maxsize=4096)
+def _node_tag(is_global: bool, my_id: int, root_port: int) -> str:
+    # formatted once a node: every round span asks for it
+    return f"{'g' if is_global else 'l'}{my_id}p{root_port}"
 
 
 @locks.guarded_by("_member_lock", "my_id", "is_recovery",
@@ -400,24 +407,27 @@ class Van:
         assert self._native is not None
         while not self.stopped.is_set():
             try:
-                buf = self._native.recv(timeout_s=0.5)
+                frame = self._native.wait_frame(timeout_s=0.5)
             except ConnectionAbortedError:
                 return
             except MemoryError:
                 log.error("native recv allocation failure; retrying")
                 time.sleep(0.1)
                 continue
-            if buf is None:
+            if frame is None:
                 continue
-            with self._stats_lock:
-                self.recv_bytes += len(buf)
-            try:
-                msg = Message.unpack(buf)
-                if not self._inbound_gate(msg):
-                    continue
-                self._process(msg)
-            except Exception:
-                log.exception("error processing inbound frame; loop kept")
+            # the native core has read and reassembled the frame on its
+            # own threads: van.recv starts where python takes it over
+            with profiler.annotate("van.recv") as span:
+                t0 = profiler.now_us()
+                try:
+                    buf = self._native.take_frame(frame)
+                    with self._stats_lock:
+                        self.recv_bytes += len(buf)
+                    self._receive(Message.unpack(buf), t0, span)
+                except Exception:
+                    log.exception(
+                        "error processing inbound frame; loop kept")
 
     def _inbound_gate(self, msg: Message) -> bool:
         """Every inbound frame passes here before dispatch: first the
@@ -688,14 +698,26 @@ class Van:
                           "will retry" if self._resender else "off", e)
 
     def _send_one(self, target: int, msg: Message) -> int:
-        if profiler.is_running() and not msg.is_control:
-            t0 = profiler.now_us()
+        if msg.is_control:
+            return self._send_one_inner(target, msg)
+        t0 = profiler.now_us() if profiler.is_running() else None
+        with profiler.annotate("van.send",
+                               **self.round_args(msg.meta.trace_round)):
             n = self._send_one_inner(target, msg)
+        if t0 is not None:
             profiler.record(
                 "van.send", "transport", t0, profiler.now_us() - t0,
                 self._span_args(target, msg.meta, n))
-            return n
-        return self._send_one_inner(target, msg)
+        return n
+
+    def round_args(self, trace_round: int) -> dict:
+        """What a round span of this node says of itself
+        (``profiler.scope`` / ``profiler.annotate``): the node as the
+        van's own chrome spans name it, so tools/trace_merge.py puts a
+        node's spans on one row, and the round's id."""
+        return {"node": self.node_tag(),
+                "tier": "global" if self.is_global else "local",
+                "round": trace_round}
 
     def _span_args(self, peer: int, meta: Meta, nbytes: int) -> dict:
         """Args for van.send/van.recv spans. Carries everything
@@ -845,28 +867,50 @@ class Van:
     def _reader_loop(self, conn: socket.socket) -> None:
         while not self.stopped.is_set():
             try:
-                got = read_message(conn)
-            except (ValueError, OSError):
+                # block for a frame's first byte with no span open
+                if not conn.recv(1, socket.MSG_PEEK):
+                    break
+            except OSError:
                 break
-            if got is None:
-                break
-            msg, nbytes = got
-            with self._stats_lock:
-                self.recv_bytes += nbytes
-            try:
-                if not self._inbound_gate(msg):
-                    continue
-                self._process(msg)
-            except Exception:
-                # an exception here must not kill the reader thread — that
-                # would silently sever the connection for all future frames
-                log.exception("error processing inbound frame; connection kept")
+            with profiler.annotate("van.recv") as span:
+                t0 = profiler.now_us()
+                try:
+                    got = read_message(conn)
+                except (ValueError, OSError):
+                    break
+                if got is None:
+                    break
+                msg, nbytes = got
+                with self._stats_lock:
+                    self.recv_bytes += nbytes
+                try:
+                    self._receive(msg, t0, span)
+                except Exception:
+                    # an exception here must not kill the reader thread —
+                    # that would silently sever the connection for all
+                    # future frames
+                    log.exception(
+                        "error processing inbound frame; connection kept")
         try:
             conn.close()
         except OSError:
             pass
 
-    def _process(self, msg: Message) -> None:
+    def _receive(self, msg: Message, t0: float, span) -> None:
+        """One decoded frame from a reader thread, inside its
+        ``van.recv`` span (open since ``t0`` on the profiler's clock):
+        gate it, then dispatch it. The span ends with the hand-over to
+        the handler, or where the shaper takes the frame to hold."""
+        if not msg.is_control:
+            span.set_metadata(**self.round_args(msg.meta.trace_round))
+        if self._inbound_gate(msg):
+            self._process(msg, t0)
+
+    def _process(self, msg: Message, t0: Optional[float] = None) -> None:
+        """Dispatch one inbound frame. ``t0``: when its first byte was
+        seen, for the ``van.recv`` chrome event; a frame that re-enters
+        after a hold (shaper, fault plan, DGT reassembly) has none and
+        is timed from here."""
         r = self._resender
         if r is not None:
             if msg.meta.control_cmd == Control.ACK:
@@ -887,9 +931,10 @@ class Van:
                 # guarantee the reference's resender provides.
                 r.mark_seen(msg.meta.msg_sig)
                 r.send_ack(msg)
-        self._process_inner(msg)
+        self._process_inner(msg, t0)
 
-    def _process_inner(self, msg: Message) -> None:
+    def _process_inner(self, msg: Message,
+                       t0: Optional[float] = None) -> None:
         if self.sanitizer is not None:
             # post-dedup (resender dropped duplicate frames already) and
             # post-ACK-handling, so this sees each logical delivery once
@@ -943,9 +988,10 @@ class Van:
                 nbytes = sum(len(d) for d in msg.data)
                 self._note_wire("recv", msg.meta.sender, msg.meta, nbytes)
                 if profiler.is_running():
-                    t = profiler.now_us()
+                    now = profiler.now_us()
+                    t0 = now if t0 is None else t0
                     profiler.record(
-                        "van.recv", "transport", t, 0,
+                        "van.recv", "transport", t0, now - t0,
                         self._span_args(msg.meta.recver, msg.meta, nbytes))
             # geomx-healthd board query (kv.health() -> Command.HEALTH):
             # answered at van level on the scheduler — the scheduler's
@@ -1469,12 +1515,11 @@ class Van:
                 f"/{self.my_id}@{getattr(self, 'my_port', '?')}]")
 
     def node_tag(self) -> str:
-        """Filename-safe node identity for telemetry and flight-recorder
-        dumps: tier + id + overlay root port. The root port disambiguates
-        overlays that reuse the same id space (every party's local tier
-        numbers its workers/servers identically)."""
-        return (f"{'g' if self.is_global else 'l'}{self.my_id}"
-                f"p{self.root_port}")
+        """Filename-safe node identity for telemetry, spans and
+        flight-recorder dumps: tier + id + overlay root port. The root
+        port disambiguates overlays that reuse the same id space (every
+        party's local tier numbers its workers/servers identically)."""
+        return _node_tag(self.is_global, self.my_id, self.root_port)
 
     @staticmethod
     def _verb_of(meta: Meta) -> str:

@@ -261,17 +261,6 @@ def _per_link(prefix: str, table: Dict[str, float]
     return out
 
 
-def link_goodput(snap: Optional[Dict[str, Any]] = None
-                 ) -> Dict[Tuple[int, int], float]:
-    """Observed per-link goodput (MB/s), keyed ``(src, dst)`` — the
-    TSEngine sender's push->ack measurement (``link.goodput_mb_s``
-    gauges). Under GEOMX_SHAPE_PLAN this reflects the emulated link,
-    which is exactly what lets the scheduler route around thin pipes."""
-    if snap is None:
-        snap = snapshot()
-    return _per_link("link.goodput_mb_s", snap.get("gauges", {}))
-
-
 def link_shaped_delay_ms(snap: Optional[Dict[str, Any]] = None
                          ) -> Dict[Tuple[int, int], float]:
     """Last emulated delivery delay (ms) the shaper imposed per link

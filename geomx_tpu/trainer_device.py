@@ -484,32 +484,53 @@ class DeviceResidentTrainer:
         the post-round state does not depend on P3_SLICE_BYTES."""
         import jax
 
-        X, y = self._place_batch(X, y)
-        self._count_mesh_round()
-        loss_d, packs = self._run_fwd_chunks(X, y)
-        for p in packs:
-            if hasattr(p, "copy_to_host_async"):
-                p.copy_to_host_async()
-        futs = []
-        for ci in range(len(self._chunks)):
-            with profiler.chunk_scope("fetch", ci):
-                arr = np.asarray(packs[ci])
-            keys, vlist, ilist = self._chunk_wire_parts(ci, arr)
-            # slice_bytes=0: this call IS one chunk — one message per
-            # server, the store must not re-slice it
-            futs.append(self.kv.push_pull_bsc_batch_async(
-                keys, vlist, ilist, priority=-ci, slice_bytes=0))
-        # loss value-fetch rides behind the dispatches (the wire is
-        # already flying when this blocks on the device)
-        loss = self._book(np.asarray(loss_d))
-        for ci, fut in enumerate(futs):
-            agg = fut.results()
-            up = self._chunk_up(ci, agg)
-            _sel_lo, _sel_hi, flo, fsize, _cap = self._chunk_meta[ci]
-            with profiler.chunk_scope("apply", ci):
-                self._flat, self._mom = self._apply_chunk(
-                    self._flat, self._mom, jax.device_put(up),
-                    flo, fsize)
+        # who this trainer is on a trace: its worker's node in its
+        # overlay (a local store has neither)
+        po = getattr(self.kv, "po", None)
+        me = po.van.round_args(-1) if po is not None else {}
+        with profiler.scope("trainer.step", cat="trainer", **me) as whole:
+            X, y = self._place_batch(X, y)
+            self._count_mesh_round()
+            loss_d, packs = self._run_fwd_chunks(X, y)
+            for p in packs:
+                if hasattr(p, "copy_to_host_async"):
+                    p.copy_to_host_async()
+            futs = []
+            for ci in range(len(self._chunks)):
+                # the round's id is allotted at the dispatch below: the
+                # fetch before it carries its chunk alone. The device's
+                # part of the round ends inside this fetch; what is left
+                # of it after that is the D2H copy
+                with profiler.scope("trainer.fetch", cat="trainer",
+                                    **dict(me, chunk=ci)):
+                    arr = np.asarray(packs[ci])
+                with profiler.scope("trainer.pack", cat="trainer",
+                                    **dict(me, chunk=ci)) as span:
+                    keys, vlist, ilist = self._chunk_wire_parts(ci, arr)
+                    # slice_bytes=0: this call IS one chunk — one message
+                    # per server, the store must not re-slice it
+                    futs.append(self.kv.push_pull_bsc_batch_async(
+                        keys, vlist, ilist, priority=-ci, slice_bytes=0))
+                    span.set_metadata(round=futs[-1].trace_round)
+            if futs:
+                whole.set_metadata(round=futs[0].trace_round)
+            # loss value-fetch rides behind the dispatches (the wire is
+            # already flying when this blocks on the device)
+            loss = self._book(np.asarray(loss_d))
+            for ci, fut in enumerate(futs):
+                args = dict(me, chunk=ci, round=fut.trace_round)
+                with profiler.scope("trainer.wait", cat="trainer", **args):
+                    agg = fut.results()
+                with profiler.scope("trainer.unpack", cat="trainer",
+                                    **args):
+                    up = self._chunk_up(ci, agg)
+                _sel_lo, _sel_hi, flo, fsize, _cap = self._chunk_meta[ci]
+                with profiler.scope("trainer.h2d", cat="trainer", **args):
+                    up_d = jax.device_put(up)
+                with profiler.scope("trainer.apply", cat="trainer",
+                                    **args):
+                    self._flat, self._mom = self._apply_chunk(
+                        self._flat, self._mom, up_d, flo, fsize)
         return loss
 
     def step_timed(self, X, y) -> Tuple[float, Dict[str, float]]:

@@ -128,6 +128,9 @@ class StubVan:
     def _process(self, msg):
         self.delivered.append(msg)
 
+    def round_args(self, trace_round):
+        return {"node": self.my_id, "round": trace_round}
+
     def _crash_from_fault(self, reason):
         self.crashed.append(reason)
         self.stopped.set()
@@ -135,7 +138,7 @@ class StubVan:
 
 def msg(sender=8, control=False, tag=None):
     m = types.SimpleNamespace()
-    m.meta = types.SimpleNamespace(sender=sender)
+    m.meta = types.SimpleNamespace(sender=sender, trace_round=-1)
     m.is_control = control
     m.tag = tag
     return m

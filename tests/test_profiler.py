@@ -26,8 +26,7 @@ def _clean_profiler():
 
 
 def test_scope_records_chrome_trace_events(tmp_path):
-    profiler.set_config(filename=str(tmp_path / "trace.json"),
-                        aggregate_stats=True)
+    profiler.set_config(filename=str(tmp_path / "trace.json"))
     profiler.set_state("run")
     with profiler.scope("work", cat="test"):
         pass
@@ -39,7 +38,6 @@ def test_scope_records_chrome_trace_events(tmp_path):
     assert "work" in names and "queue_depth" in names
     ev = next(e for e in doc["traceEvents"] if e["name"] == "work")
     assert ev["ph"] == "X" and ev["dur"] >= 0 and ev["cat"] == "test"
-    assert profiler.aggregate_stats().get("work", 0) >= 0
 
 
 def test_paused_and_stopped_record_nothing():
@@ -120,10 +118,11 @@ def test_remote_pause_defaults_true_and_roundtrips():
 def test_remote_set_config_without_filename_keeps_default():
     profiler.apply_remote_command(
         json.dumps({"cmd": profiler.CMD_SET_CONFIG,
-                    "params": {"aggregate_stats": True}}), rank=5)
-    # no filename param -> nothing to rank-prefix, default stays
+                    "params": {"continuous_dump": True}}), rank=5)
+    # no filename param -> nothing to rank-prefix, default stays; a
+    # reference kwarg this profiler has no use for is stored, no more
     assert profiler._config["filename"] == "profile.json"
-    assert profiler._config["aggregate_stats"] is True
+    assert profiler._config["continuous_dump"] is True
 
 
 def test_dump_is_atomic_leaves_no_tmp(tmp_path):

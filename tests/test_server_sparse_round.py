@@ -72,9 +72,13 @@ def _server(parties, is_global, fsa_slice_elems=0):
     s.gc = BSCCompressor(0.01)
     s.cfg = types.SimpleNamespace(bigarray_bound=1 << 40, num_parties=0,
                                   enable_central_worker=False)
-    s.po_local = None
+    # the vans name a server's round spans, no more
+    van = types.SimpleNamespace(round_args=lambda r: {"round": r})
+    s.po_local = None if is_global else types.SimpleNamespace(van=van)
     s.po_global = types.SimpleNamespace(
-        my_rank=0, num_servers=1, num_live_workers=lambda: parties)
+        my_rank=0, num_servers=1, num_live_workers=lambda: parties,
+        van=van)
+    s._wan_trace = (-1, -1)
     return s
 
 
@@ -107,10 +111,9 @@ def _party_server(workers, global_servers=1, n=768):
     s.po_global.num_servers = global_servers
     s.po_local = types.SimpleNamespace(
         num_servers=1, num_live_workers=lambda: workers,
-        van=types.SimpleNamespace())
+        van=s.po_local.van)
     s._wire = types.SimpleNamespace(enabled=lambda: False)
     s._wire_wan = s._transport = None
-    s._wan_trace = (-1, -1)
     s._fwd_tls = threading.local()
     s.worker_global = RecordingGlobalWorker()
     st = s._state(KEY, 0)

@@ -130,10 +130,13 @@ class StubVan:
     def _process(self, msg):
         self.delivered.append(msg)
 
+    def round_args(self, trace_round):
+        return {"node": self.my_id, "round": trace_round}
+
 
 def msg(sender=9, nbytes=0, control=False):
     m = types.SimpleNamespace()
-    m.meta = types.SimpleNamespace(sender=sender)
+    m.meta = types.SimpleNamespace(sender=sender, trace_round=-1)
     m.is_control = control
     m.data = [b"\0" * nbytes] if nbytes else []
     return m
