@@ -54,6 +54,8 @@ from geomx_tpu.ps.postoffice import Postoffice
 
 log = logging.getLogger("geomx.dist")
 
+_INT32_MAX = np.iinfo(np.int32).max
+
 
 def _give_up_exc(errs) -> type:
     """Exception class for surfacing transport give-ups — one mapping,
@@ -1157,7 +1159,9 @@ class KVStoreDist(KVStore):
         prepared = []
         for k, values, indices in zip(keys, values_list, indices_list):
             vals = np.ascontiguousarray(values, dtype=np.float32).ravel()
-            idx = np.asarray(indices, dtype=np.int64).ravel()
+            idx = np.asarray(indices).ravel()
+            if idx.dtype.kind not in "iu":
+                idx = idx.astype(np.int64)
             assert vals.size == idx.size, "values/indices mismatch"
             info = self._key_info.get(k)
             assert info is not None, \
@@ -1167,20 +1171,64 @@ class KVStoreDist(KVStore):
                     f"push_pull_bsc_batch: indices out of range for key "
                     f"{k} ({info.total} elements)")
             prepared.append((k, vals, idx, info))
+        copied = 0
         for k, vals, idx, info in prepared:
+            whole = len(info.shards) == 1 and info.shards[0].offset == 0
             for sh in info.shards:
-                sel = (idx >= sh.offset) & (idx < sh.offset + sh.length)
+                if whole:
+                    # the one shard is the key: the given arrays go on the
+                    # message as they are (range-checked above)
+                    s_vals, s_idx = vals, idx
+                else:
+                    sel = (idx >= sh.offset) & (idx < sh.offset + sh.length)
+                    s_vals, s_idx = vals[sel], idx[sel] - sh.offset
+                    copied += s_vals.nbytes + s_idx.nbytes
                 kvs = per_server.setdefault(sh.server_rank,
                                             KVPairs(compr=wire_tag))
                 kvs.keys.append(k)
-                kvs.vals.append(vals[sel].astype(np.float16)
-                                if wire_tag == "bsc16" else vals[sel])
-                kvs.aux.append((idx[sel] - sh.offset).astype(np.int32))
+                kvs.vals.append(s_vals.astype(np.float16)
+                                if wire_tag == "bsc16" else s_vals)
+                kvs.aux.append(s_idx.astype(np.int32, copy=False))
                 kvs.offsets.append(sh.offset)
                 kvs.totals.append(sh.total)
                 kvs.lens.append(sh.length)
                 server_keys.setdefault(sh.server_rank, []).append(k)
+        if copied:
+            telemetry.counter_inc("van.payload_bytes_copied", copied)
         return per_server, server_keys
+
+    def _bsc_entry(self, kvs: KVPairs, i: int, r_off: int):
+        """Part ``i`` of a sparse round's response, whose range starts
+        at ``r_off`` of its key, as ``(values float32, key-relative
+        indices)``. The indices stay the frame's own int32 view where
+        the range starts the key and the key's size fits them; a shard
+        further in, or a larger key, gets int64."""
+        data = np.asarray(kvs.vals[i], dtype=np.float32).ravel()
+        aux = kvs.aux[i] if i < len(kvs.aux) else None
+        if kvs.compr in ("bsc", "bsc16") and aux is not None:
+            idx = np.asarray(aux).ravel()
+            if r_off or self._key_info[kvs.keys[i]].total > _INT32_MAX:
+                telemetry.counter_inc("van.payload_bytes_copied",
+                                      idx.nbytes)
+                idx = idx.astype(np.int64) + r_off
+            return data, idx
+        nz = np.nonzero(data)[0]
+        return data[nz].astype(np.float32), nz + r_off
+
+    @staticmethod
+    def _join_bsc_parts(ps):
+        """A key's result from its parts: the part itself where there is
+        one, the parts joined (a copy, booked) where a key came back in
+        several."""
+        if not ps:
+            return np.zeros(0, np.float32), np.zeros(0, np.int64)
+        if len(ps) == 1:
+            return ps[0]
+        telemetry.counter_inc(
+            "van.payload_bytes_copied",
+            sum(p[0].nbytes + p[1].nbytes for p in ps))
+        return (np.concatenate([p[0] for p in ps]),
+                np.concatenate([p[1] for p in ps]))
 
     def push_pull_bsc_batch(self, keys, values_list, indices_list,
                             priority: int = 0, timeout: float = None):
@@ -1208,7 +1256,11 @@ class KVStoreDist(KVStore):
         WHOLE — the server FSA counts one push per (key, shard) per
         worker per round, so intra-key splitting would double-count.
         Returns a :class:`RoundFuture` whose per-key result is
-        ``(values float32, flat_indices int64)``, completing each key as
+        ``(values float32, flat_indices)``: the arrays of the response's
+        own part, read-only views of its frame with the wire's int32
+        indices, where a key came back in one part from offset 0; int64
+        indices where a shard's offset or the key's size asks for them;
+        a join where there were several parts. It completes each key as
         its last response lands — apply key i while key j is still on
         the wire. Give-ups surface through ``fut.wait()``."""
         assert len(set(keys)) == len(keys), "duplicate keys in one round"
@@ -1293,18 +1345,7 @@ class KVStoreDist(KVStore):
                 fut.add_error(k, err)   # future methods outside _lock
             for kvs in self.kvw.take_response(ts):
                 for i, k in enumerate(kvs.keys):
-                    data = np.asarray(kvs.vals[i],
-                                      dtype=np.float32).ravel()
-                    r_off = kvs.offset_of(i)
-                    aux = kvs.aux[i] if i < len(kvs.aux) else None
-                    if kvs.compr in ("bsc", "bsc16") and aux is not None:
-                        entry = (data,
-                                 np.asarray(aux, np.int64).ravel()
-                                 + r_off)
-                    else:
-                        nz = np.nonzero(data)[0]
-                        entry = (data[nz].astype(np.float32),
-                                 nz + r_off)
+                    entry = self._bsc_entry(kvs, i, kvs.offset_of(i))
                     with self._lock:
                         parts[k].append(entry)
             finished = []
@@ -1336,13 +1377,8 @@ class KVStoreDist(KVStore):
                     # NOT an empty aggregate; async re-pull (this runs
                     # on a transport thread: never block here)
                     short.append(k)
-                elif not ps:
-                    fut.complete_key(k, (np.zeros(0, np.float32),
-                                         np.zeros(0, np.int64)))
                 else:
-                    fut.complete_key(
-                        k, (np.concatenate([p[0] for p in ps]),
-                            np.concatenate([p[1] for p in ps])))
+                    fut.complete_key(k, self._join_bsc_parts(ps))
             if short:
                 self._repull_bsc_async(short, priority, fut)
 
@@ -1397,17 +1433,7 @@ class KVStoreDist(KVStore):
                 fut.add_error(k, err)
             for kvs in self.kvw.take_response(ts):
                 for i, k in enumerate(kvs.keys):
-                    data = np.asarray(kvs.vals[i],
-                                      dtype=np.float32).ravel()
-                    r_off = kvs.offset_of(i)
-                    aux = kvs.aux[i] if i < len(kvs.aux) else None
-                    if kvs.compr in ("bsc", "bsc16") and aux is not None:
-                        entry = (data,
-                                 np.asarray(aux, np.int64).ravel()
-                                 + r_off)
-                    else:
-                        nz = np.nonzero(data)[0]
-                        entry = (data[nz].astype(np.float32), nz + r_off)
+                    entry = self._bsc_entry(kvs, i, kvs.offset_of(i))
                     with self._lock:
                         parts[k].append(entry)
             finished = []
@@ -1421,13 +1447,7 @@ class KVStoreDist(KVStore):
             for k in finished:
                 with self._lock:
                     ps = list(parts[k])
-                if not ps:
-                    fut.complete_key(k, (np.zeros(0, np.float32),
-                                         np.zeros(0, np.int64)))
-                else:
-                    fut.complete_key(
-                        k, (np.concatenate([p[0] for p in ps]),
-                            np.concatenate([p[1] for p in ps])))
+                fut.complete_key(k, self._join_bsc_parts(ps))
 
         for srank, kvs in per_server.items():
             def issue(sr=srank, kv=kvs):

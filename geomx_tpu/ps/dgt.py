@@ -38,6 +38,7 @@ from typing import Callable, Dict, List, Optional, Tuple
 
 import numpy as np
 
+from geomx_tpu import telemetry
 from geomx_tpu.ps.message import Message, Meta
 
 log = logging.getLogger("geomx.dgt")
@@ -316,6 +317,8 @@ class DGTReassembler:
         for seq, arr in group.blocks.items():
             off = seq * stride
             buf[off:off + arr.size] = arr[:max(total_elems - off, 0)]
+        # the blocks' parts joined into one value: a copy of each
+        telemetry.counter_inc("van.payload_bytes_copied", buf.nbytes)
 
         out_meta = dataclasses.replace(
             meta, msg_type=0, seq=-1, seq_begin=-1, seq_end=-1,

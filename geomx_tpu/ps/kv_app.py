@@ -121,29 +121,30 @@ def _pack_kv(meta: Meta, kvs: KVPairs) -> Message:
 
 
 def _unpack_kv(msg: Message) -> KVPairs:
-    arrays = msg.arrays()
-    keys = [int(k) for k in arrays[0]] if len(arrays) else []
+    nparts = len(msg.data)
+    keys = msg.get_ints(0) if nparts else []
     kvs = KVPairs(keys=keys, compr=msg.meta.compr)
     nkeys = len(keys)
     if nkeys:
-        kvs.offsets = [int(x) for x in arrays[1]]
-        kvs.totals = [int(x) for x in arrays[2]]
-        kvs.lens = [int(x) for x in arrays[3]]
+        kvs.offsets = msg.get_ints(1)
+        kvs.totals = msg.get_ints(2)
+        kvs.lens = msg.get_ints(3)
     first_val = 4
     if msg.meta.aux_len and msg.meta.aux_mask:
         # aux arrays interleaved after their value part
         bits = bin(msg.meta.aux_mask)[2:].zfill(msg.meta.aux_len)
         idx = first_val
         for i in range(nkeys):
-            kvs.vals.append(arrays[idx])
+            kvs.vals.append(msg.get_array(idx))
             idx += 1
             if bits[i] == "1":
-                kvs.aux.append(arrays[idx])
+                kvs.aux.append(msg.get_array(idx))
                 idx += 1
             else:
                 kvs.aux.append(None)
     else:
-        kvs.vals = arrays[first_val:first_val + nkeys]
+        kvs.vals = [msg.get_array(i)
+                    for i in range(first_val, min(first_val + nkeys, nparts))]
         kvs.aux = [None] * nkeys
     return kvs
 

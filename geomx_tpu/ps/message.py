@@ -30,6 +30,8 @@ from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
 
+from geomx_tpu import telemetry
+
 MAGIC = 0x47454F4D  # "GEOM"
 
 _PREHDR = struct.Struct("<IiBiI")  # magic, recver, flags, priority, meta_len
@@ -406,14 +408,27 @@ class Message:
     For KV traffic part 0 is the key array (int64) and subsequent parts are
     value buffers / length arrays, mirroring ps-lite's keys/vals/lens triple
     (reference: kv_app.h:39-77).
+
+    A part is a BUFFER that is borrowed, not a ``bytes`` that is made:
+    ``bytes`` / ``bytearray`` (memory the message owns) or a flat
+    byte-format ``memoryview`` over memory somebody else owns: the
+    array a sender gave ``add_array``, or the frame a socket filled
+    (``unpack``). Readers take ``len()`` and slices. A borrowed part is
+    good while the call that writes it is on the stack; whoever keeps
+    the message longer calls ``snapshot()`` at the moment it decides to.
     """
 
     meta: Meta = dataclasses.field(default_factory=Meta)
-    data: List[bytes] = dataclasses.field(default_factory=list)
+    data: List[Any] = dataclasses.field(default_factory=list)
 
     # -- framing ---------------------------------------------------------
 
-    def pack(self) -> bytes:
+    def frame_parts(self) -> List[Any]:
+        """The frame as the buffers whose bytes, in order, ARE the frame:
+        the prefix (pre-header, meta, part count), then a 4-byte length
+        and the part's own memory for every part. A gathered write
+        (``socket.sendmsg``, the native core's ``gx_sendv``) sends them
+        as they lie; ``pack()`` joins them."""
         flags = FLAG_GLOBAL if self.meta.is_global else 0
         if self.meta.nodes:
             # node tables (bootstrap/topology control) stay JSON: rare,
@@ -423,19 +438,28 @@ class Message:
         else:
             meta_b = _encode_meta_bin(self.meta)
             flags |= FLAG_BINMETA
-        out = [
-            _PREHDR.pack(MAGIC, self.meta.recver, flags, self.meta.priority, len(meta_b)),
+        out = [b"".join((
+            _PREHDR.pack(MAGIC, self.meta.recver, flags, self.meta.priority,
+                         len(meta_b)),
             meta_b,
-            _U32.pack(len(self.data)),
-        ]
+            _U32.pack(len(self.data))))]
         for part in self.data:
-            mv = memoryview(part)
-            out.append(_U32.pack(len(mv)))
-            out.append(mv)
-        return b"".join(out)
+            out.append(_U32.pack(len(part)))
+            out.append(part)
+        return out
+
+    def pack(self) -> bytes:
+        """The frame as one ``bytes``: control messages, datagrams, the
+        one-shot registration send, tests. The data path does not join
+        (``frame_parts``)."""
+        return b"".join(self.frame_parts())
 
     @staticmethod
-    def unpack(buf: bytes) -> "Message":
+    def unpack(buf) -> "Message":
+        """Decode a frame; every part is a VIEW of ``buf`` (no copy),
+        which therefore lives as long as any part, or any array over
+        one (``get_array``), does."""
+        buf = memoryview(buf)
         magic, recver, flags, priority, meta_len = _PREHDR.unpack_from(buf, 0)
         if magic != MAGIC:
             raise ValueError(f"bad frame magic {magic:#x}")
@@ -447,29 +471,75 @@ class Message:
         off += meta_len
         (ndata,) = _U32.unpack_from(buf, off)
         off += _U32.size
-        data: List[bytes] = []
+        data: List[Any] = []
         for _ in range(ndata):
             (n,) = _U32.unpack_from(buf, off)
             off += _U32.size
-            data.append(bytes(buf[off:off + n]))
+            if off + n > len(buf):
+                raise ValueError("truncated frame")
+            data.append(buf[off:off + n])
             off += n
         return Message(meta=meta, data=data)
 
     # -- tensor helpers --------------------------------------------------
 
     def add_array(self, arr: np.ndarray) -> None:
-        arr = np.ascontiguousarray(arr)
+        """Append ``arr`` as a part WITHOUT copying it where it is
+        contiguous: the part is a byte view of the caller's memory (see
+        the class note for how long that may be relied on). What is not
+        contiguous is copied once, and booked."""
+        arr = np.asarray(arr)
+        if not arr.flags.c_contiguous:
+            arr = np.ascontiguousarray(arr)
+            telemetry.counter_inc("van.payload_bytes_copied", arr.nbytes)
+        elif arr.ndim == 0:
+            arr = arr.reshape(1)
         self.meta.dtypes.append(arr.dtype.str)
         self.meta.shapes.append(list(arr.shape))
-        self.data.append(arr.tobytes())
+        self.data.append(memoryview(arr.reshape(-1).view(np.uint8)))
 
     def get_array(self, i: int) -> np.ndarray:
+        """Part ``i`` as an array over the part's memory (read-only
+        where that is a received frame's). A part that does not lie
+        aligned for its dtype (a datagram's, one behind an odd-sized
+        part) is copied once into memory that does: numpy's unaligned
+        paths cost every later pass several times that copy."""
         dt = np.dtype(self.meta.dtypes[i])
         shape = tuple(self.meta.shapes[i])
-        return np.frombuffer(self.data[i], dtype=dt).reshape(shape)
+        arr = np.frombuffer(self.data[i], dtype=dt)
+        if not arr.flags.aligned:
+            arr = arr.copy()
+            arr.flags.writeable = False
+            telemetry.counter_inc("van.payload_bytes_copied", arr.nbytes)
+        return arr.reshape(shape)
+
+    def get_ints(self, i: int) -> List[int]:
+        """Part ``i``, little-endian int64s, as python ints (the KV
+        header parts: read where they lie, whatever their alignment)."""
+        part = self.data[i]
+        return list(struct.unpack_from(f"<{len(part) // 8}q", part))
 
     def arrays(self) -> List[np.ndarray]:
         return [self.get_array(i) for i in range(len(self.data))]
+
+    def payload_bytes(self) -> int:
+        return sum(len(d) for d in self.data)
+
+    def borrowed_bytes(self) -> int:
+        """Bytes of the parts that are views of memory the message does
+        not own."""
+        return sum(len(d) for d in self.data if isinstance(d, memoryview))
+
+    def snapshot(self) -> int:
+        """Make every borrowed part the message's own ``bytes``, for a
+        holder that outlives the sender's call (a resend table, a send
+        queue). Returns the bytes copied; a second call copies nothing."""
+        copied = 0
+        for i, d in enumerate(self.data):
+            if isinstance(d, memoryview):
+                self.data[i] = bytes(d)
+                copied += len(d)
+        return copied
 
     @property
     def is_control(self) -> bool:

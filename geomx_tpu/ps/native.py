@@ -8,6 +8,19 @@ per-destination connection cache; routing and message semantics stay in
 Python (van.py). Both backends speak the identical wire format
 (message.py), so native and pure-Python nodes interoperate in one job.
 
+Who owns a buffer. Outbound, :meth:`NativeTransport.sendv` passes the
+core the addresses of the caller's buffers (a ``Message``'s
+``frame_parts()``); the core writes them as they lie and keeps nothing:
+they must stay alive and unchanged until the call returns, which the
+caller's references see to. Inbound, the core reads a frame once into a
+``malloc``ed buffer and :meth:`NativeTransport.wait_frame` is handed
+that buffer itself; :meth:`NativeTransport.take_frame` wraps it in a
+read-only ``memoryview`` without copying. The frame is python's from
+then on: every part ``Message.unpack`` slices from it, and every array
+``np.frombuffer`` makes over a part, holds a reference to the view's
+owner, and when the last of them is dropped a finaliser releases the
+buffer through ``gx_free``, exactly once (``frames_freed()`` counts).
+
 Selection: ``GEOMX_NATIVE_VAN=1`` (default when the library is buildable)
 / ``GEOMX_NATIVE_VAN=0`` forces pure Python. The shared library is built
 on demand with g++ the first time it is needed and cached next to the
@@ -21,7 +34,10 @@ import logging
 import os
 import subprocess
 import threading
-from typing import Optional
+import weakref
+from typing import Optional, Sequence
+
+import numpy as np
 
 from geomx_tpu.native_lib import ensure_built
 
@@ -59,6 +75,11 @@ def load_library() -> Optional[ctypes.CDLL]:
         lib.gx_send.restype = ctypes.c_int64
         lib.gx_send.argtypes = [ctypes.c_void_p, ctypes.c_int,
                                 ctypes.c_char_p, ctypes.c_uint64]
+        lib.gx_sendv.restype = ctypes.c_int64
+        lib.gx_sendv.argtypes = [ctypes.c_void_p, ctypes.c_int,
+                                 ctypes.POINTER(ctypes.c_void_p),
+                                 ctypes.POINTER(ctypes.c_uint64),
+                                 ctypes.c_uint64]
         lib.gx_send_addr.restype = ctypes.c_int64
         lib.gx_send_addr.argtypes = [ctypes.c_void_p, ctypes.c_char_p,
                                      ctypes.c_int, ctypes.c_char_p,
@@ -67,8 +88,12 @@ def load_library() -> Optional[ctypes.CDLL]:
         lib.gx_recv.argtypes = [ctypes.c_void_p,
                                 ctypes.POINTER(ctypes.POINTER(ctypes.c_uint8)),
                                 ctypes.c_double]
+        lib.gx_wait.restype = ctypes.c_int
+        lib.gx_wait.argtypes = [ctypes.c_void_p, ctypes.c_double]
         lib.gx_free.restype = None
-        lib.gx_free.argtypes = [ctypes.POINTER(ctypes.c_uint8)]
+        lib.gx_free.argtypes = [ctypes.c_void_p]
+        lib.gx_frames_freed.restype = ctypes.c_uint64
+        lib.gx_frames_freed.argtypes = []
         lib.gx_send_bytes.restype = ctypes.c_uint64
         lib.gx_send_bytes.argtypes = [ctypes.c_void_p]
         lib.gx_recv_bytes.restype = ctypes.c_uint64
@@ -89,6 +114,12 @@ def enabled() -> bool:
     """Native backend selection: on by default when buildable."""
     flag = os.environ.get("GEOMX_NATIVE_VAN", "1")
     return flag not in ("0", "false", "no") and available()
+
+
+def frames_freed() -> int:
+    """Frames released through ``gx_free`` so far, process-wide."""
+    lib = load_library()
+    return int(lib.gx_frames_freed()) if lib is not None else 0
 
 
 class NativeTransport:
@@ -115,6 +146,23 @@ class NativeTransport:
 
     def send(self, node_id: int, frame: bytes) -> int:
         n = self._lib.gx_send(self._h, node_id, frame, len(frame))
+        return self._sent(node_id, n)
+
+    def sendv(self, node_id: int, buffers: Sequence) -> int:
+        """One frame from ``buffers`` (any buffer objects, read-only
+        ones too), written in order from where they lie: a gathered
+        write, no joined copy. They are borrowed for the call only."""
+        n = len(buffers)
+        # an array over each buffer: its address, and a reference that
+        # keeps the memory in place until the write has returned
+        held = [np.frombuffer(b, np.uint8) for b in buffers]
+        ptrs = (ctypes.c_void_p * n)(*[a.ctypes.data for a in held])
+        lens = (ctypes.c_uint64 * n)(*[a.size for a in held])
+        return self._sent(
+            node_id, self._lib.gx_sendv(self._h, node_id, ptrs, lens, n))
+
+    @staticmethod
+    def _sent(node_id: int, n: int) -> int:
         if n == -2:
             raise OSError(f"no route to node {node_id}")
         if n < 0:
@@ -127,33 +175,42 @@ class NativeTransport:
         if n < 0:
             raise OSError(f"native send to {host}:{port} failed")
 
+    def wait_begin(self, timeout_s: float = 1.0) -> bool:
+        """Block until a frame has BEGUN to arrive (or lies complete)
+        and has not been taken: True; False on timeout; raises on
+        shutdown. The core goes on reading it on its own thread; the
+        van opens ``van.recv`` here, so that the span covers the rest of
+        the read, and then calls :meth:`wait_frame`."""
+        n = self._lib.gx_wait(self._h, timeout_s)
+        if n < 0:
+            raise ConnectionAbortedError("native transport stopped")
+        return bool(n)
+
     def wait_frame(self, timeout_s: float = 1.0):
-        """Block for one complete frame: ``(pointer, length)`` into the
-        core's memory, for :meth:`take_frame`, or None on timeout;
-        raises on shutdown. Apart from :meth:`recv` so that the caller
-        can tell the wait from the work on the frame (the van's
-        ``van.recv`` span starts between the two)."""
+        """Block for one complete frame: ``(address, length)`` of the
+        buffer the core's reader filled, now the caller's (nothing was
+        copied to hand it over), for :meth:`take_frame`; None on
+        timeout; raises on shutdown."""
         out = ctypes.POINTER(ctypes.c_uint8)()
         n = self._lib.gx_recv(self._h, ctypes.byref(out), timeout_s)
         if n == -1:
             return None
-        if n == -3:
-            # transient allocation failure; the frame stays queued
-            raise MemoryError("native recv allocation failed")
         if n < 0:
             raise ConnectionAbortedError("native transport stopped")
-        return out, n
+        return ctypes.addressof(out.contents), n
 
-    def take_frame(self, frame) -> bytes:
-        """Copy a frame of :meth:`wait_frame` out of the core's memory
-        and free it there."""
-        out, n = frame
-        try:
-            return ctypes.string_at(out, n)
-        finally:
-            self._lib.gx_free(out)
+    def take_frame(self, frame) -> memoryview:
+        """A frame of :meth:`wait_frame` as a read-only byte view of
+        that buffer. ``gx_free`` runs when the last reference to the
+        view's owner goes (slices of the view and arrays over them each
+        hold one)."""
+        addr, n = frame
+        owner = (ctypes.c_uint8 * n).from_address(addr)
+        # not at interpreter exit: arrays may still look into the frame
+        weakref.finalize(owner, self._lib.gx_free, addr).atexit = False
+        return memoryview(owner).cast("B").toreadonly()
 
-    def recv(self, timeout_s: float = 1.0) -> Optional[bytes]:
+    def recv(self, timeout_s: float = 1.0) -> Optional[memoryview]:
         """One complete frame, or None on timeout; raises on shutdown."""
         frame = self.wait_frame(timeout_s)
         return None if frame is None else self.take_frame(frame)

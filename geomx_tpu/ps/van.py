@@ -64,6 +64,24 @@ def _shutdown_and_close(sock: socket.socket) -> None:
         pass
 
 
+_IOV_BATCH = 1024  # Linux IOV_MAX: buffers one sendmsg takes
+
+
+def _sendmsg_all(sock: socket.socket, bufs) -> None:
+    """``sendall`` for a list of buffers: a gathered write of all of
+    them, in order, from where they lie (``socket.sendmsg``), resumed
+    inside a buffer after a short write."""
+    bufs = [memoryview(b) for b in bufs if len(b)]
+    i = 0
+    while i < len(bufs):
+        sent = sock.sendmsg(bufs[i:i + _IOV_BATCH])
+        while i < len(bufs) and sent >= len(bufs[i]):
+            sent -= len(bufs[i])
+            i += 1
+        if sent:
+            bufs[i] = bufs[i][sent:]
+
+
 @functools.lru_cache(maxsize=4096)
 def _node_tag(is_global: bool, my_id: int, root_port: int) -> str:
     # formatted once a node: every round span asks for it
@@ -407,24 +425,25 @@ class Van:
         assert self._native is not None
         while not self.stopped.is_set():
             try:
-                frame = self._native.wait_frame(timeout_s=0.5)
+                if not self._native.wait_begin(timeout_s=0.5):
+                    continue
             except ConnectionAbortedError:
                 return
-            except MemoryError:
-                log.error("native recv allocation failure; retrying")
-                time.sleep(0.1)
-                continue
-            if frame is None:
-                continue
-            # the native core has read and reassembled the frame on its
-            # own threads: van.recv starts where python takes it over
+            # a frame's first bytes are in: van.recv covers the rest of
+            # the read (the native core's, on its own thread, into a
+            # buffer that is then ours) and the decode where it lies
             with profiler.annotate("van.recv") as span:
                 t0 = profiler.now_us()
                 try:
+                    frame = self._native.wait_frame(timeout_s=0.5)
+                    if frame is None:
+                        continue  # still arriving: wait on, a new span
                     buf = self._native.take_frame(frame)
                     with self._stats_lock:
                         self.recv_bytes += len(buf)
                     self._receive(Message.unpack(buf), t0, span)
+                except ConnectionAbortedError:
+                    return
                 except Exception:
                     log.exception(
                         "error processing inbound frame; loop kept")
@@ -531,7 +550,9 @@ class Van:
             self._send_one(target, msg)
             return
         port = ports[(channel - 1) % len(ports)]
-        buf = msg.pack()
+        buf = msg.pack()  # a datagram is one buffer: the join is a copy
+        telemetry.counter_inc("van.payload_bytes_copied",
+                              msg.payload_bytes())
         self._udp_send_sock.sendto(buf, (addr[0], port))
         with self._stats_lock:
             self.send_bytes += len(buf)
@@ -546,6 +567,7 @@ class Van:
                 self.recv_bytes += len(data)
             try:
                 msg = Message.unpack(data)
+                self._book_received(msg)
                 if not self._inbound_gate(msg):
                     continue
                 self._process(msg)
@@ -634,6 +656,7 @@ class Van:
                         and m.meta.control_cmd != Control.ACK
                         and self.my_id >= 0):
                     self._resender.assign_sig(m)
+                    self._keep(m)
                     self._resender.add_outgoing(t, m)
                     continue
                 raise OSError(
@@ -648,9 +671,11 @@ class Van:
                 # kv_app.h:1146-1205)
                 for ch, bmsg in self._dgt_sender.split(m):
                     total += len(bmsg.data[-1]) if bmsg.data else 0
+                    self._keep(bmsg)
                     self._dgt_queues.put(ch, t, bmsg)
                 continue
             if self.use_priority_send and not m.is_control:
+                self._keep(m)
                 with self._send_cv:
                     heapq.heappush(
                         self._send_queue, (-m.meta.priority, next(self._send_seq), m)
@@ -676,6 +701,26 @@ class Van:
             return msg
         meta = dataclasses.replace(msg.meta, recver=target)
         return Message(meta=meta, data=msg.data)
+
+    @staticmethod
+    def _keep(msg: Message) -> None:
+        """The van is about to hold ``msg`` past the sender's call (the
+        resend table, the priority queue, a DGT queue): borrowed parts
+        become the message's own bytes NOW, so what reaches the wire
+        later is the arrays' content at ``send()``. A message written
+        before ``send()`` returns is never passed here and goes out
+        from the caller's memory."""
+        copied = msg.snapshot()
+        if copied:
+            telemetry.counter_inc("van.payload_bytes_copied", copied)
+
+    @staticmethod
+    def _book_received(msg: Message) -> None:
+        """A data message fresh off a socket: its parts are views of the
+        buffer the socket filled, and go to the handler as they are."""
+        if not msg.is_control and msg.data:
+            telemetry.counter_inc("van.payload_bytes_borrowed",
+                                  msg.payload_bytes())
 
     def _priority_send_loop(self) -> None:
         while not self.stopped.is_set():
@@ -754,6 +799,7 @@ class Van:
                 and msg.meta.control_cmd != Control.ACK
                 and self.my_id >= 0 and target != self.my_id):
             self._resender.assign_sig(msg)
+            self._keep(msg)
             self._resender.add_outgoing(target, msg)
         if not msg.is_control and target in self._declared_dead:
             # fail-fast: a data frame to a declared-dead peer must not
@@ -768,9 +814,18 @@ class Van:
             if self._resender is not None and msg.meta.msg_sig != 0:
                 return 0
             raise OSError(f"send to node {target}: peer declared dead")
-        buf = msg.pack()
+        # a gathered write: the prefix, then every part's length and the
+        # part's own memory, as they lie; no joined frame exists
+        bufs = msg.frame_parts()
+        nbytes = sum(len(b) for b in bufs)
         if not msg.is_control:
-            self._note_wire("sent", target, msg.meta, len(buf))
+            self._note_wire("sent", target, msg.meta, nbytes)
+            # booked before the write: the far side may have the frame,
+            # and have answered, before this thread runs again
+            borrowed = msg.borrowed_bytes()
+            if borrowed:
+                telemetry.counter_inc("van.payload_bytes_borrowed",
+                                      borrowed)
         if self._native is not None:
             addr = self.node_table.get(target)
             if addr is None:
@@ -778,7 +833,7 @@ class Van:
             # set_route is a no-op when unchanged; on an address change it
             # evicts the cached connection (peer recovered elsewhere)
             self._native.set_route(target, addr[0], addr[1])
-            n = self._native.send(target, buf)
+            n = self._native.sendv(target, bufs)
             with self._stats_lock:
                 self.send_bytes += n
             return n
@@ -789,10 +844,10 @@ class Van:
             sock, lock = conn
             try:
                 with lock:
-                    sock.sendall(buf)
+                    _sendmsg_all(sock, bufs)
                 with self._stats_lock:
-                    self.send_bytes += len(buf)
-                return len(buf)
+                    self.send_bytes += nbytes
+                return nbytes
             except OSError:
                 # evict the (possibly stale) cached connection and re-dial
                 # once — the peer may have restarted at a new address
@@ -903,6 +958,7 @@ class Van:
         the handler, or where the shaper takes the frame to hold."""
         if not msg.is_control:
             span.set_metadata(**self.round_args(msg.meta.trace_round))
+        self._book_received(msg)
         if self._inbound_gate(msg):
             self._process(msg, t0)
 
@@ -985,7 +1041,7 @@ class Van:
                 # approximate payload size: the exact framed length was
                 # accounted in recv_bytes by the reader; spans only need
                 # a comparable magnitude and the trace-context args
-                nbytes = sum(len(d) for d in msg.data)
+                nbytes = msg.payload_bytes()
                 self._note_wire("recv", msg.meta.sender, msg.meta, nbytes)
                 if profiler.is_running():
                     now = profiler.now_us()

@@ -439,7 +439,7 @@ class DeviceResidentTrainer:
         sel_lo, sel_hi, _flo, _fsize, _cap = self._chunk_meta[ci]
         kc = sel_hi - sel_lo
         vals = arr[:kc].view(np.float32)
-        aidx = arr[kc:].astype(np.int64)
+        aidx = arr[kc:]  # int32, as the wire carries them
         keys, vlist, ilist = [], [], []
         for i in self._chunks[ci].items:
             lo = int(self._kofs[i]) - sel_lo

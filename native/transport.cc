@@ -10,7 +10,7 @@
 //   - one reader thread per inbound connection, each parsing frame
 //     boundaries (17-byte preheader | meta | u32 ndata | {u32 len|part}*)
 //     and enqueueing complete frames,
-//   - a bounded inbound frame queue drained by the host (Python) through
+//   - an inbound frame queue drained by the host (Python) through
 //     gx_recv,
 //   - outbound connections dialed lazily per destination id and cached
 //     (reference: zmq_van.h:160-196 Connect caches per-id sockets),
@@ -23,20 +23,42 @@
 //   u32 magic "GEOM" | i32 recver | u8 flags | i32 priority | u32 meta_len
 //   | meta bytes | u32 ndata | { u32 len | bytes } * ndata
 // all little-endian, no padding (preheader is 17 bytes).
+//
+// Who owns a frame's memory:
+//   - outbound, gx_sendv writes the caller's buffers as they lie (the
+//     prefix, then a 4-byte length and the part's own memory for each
+//     part) in a sendmsg loop; they are the caller's before, during and
+//     after the call, and no joined frame exists. gx_send takes one joined
+//     buffer (control messages, the one-shot registration send).
+//   - inbound, a reader thread reads a frame ONCE into one malloc'ed
+//     block, placed so that the first part's data is 16-byte aligned
+//     (never zero-filled; grown with realloc as the parts' lengths arrive
+//     unless a block the host has released is large enough already, which
+//     after a first round it is), and queues it. gx_recv hands that very
+//     memory to the host: no copy in this file. From then on it is the
+//     host's, to be released with gx_free exactly once (ps/native.py does
+//     so when the last view of the frame is dropped); gx_free keeps the
+//     largest few released blocks for the frames to come (BlockPool). A
+//     frame still queued at Stop is freed here.
 
 #include <arpa/inet.h>
 #include <netdb.h>
 #include <netinet/in.h>
 #include <netinet/tcp.h>
 #include <sys/socket.h>
+#include <sys/uio.h>
 #include <unistd.h>
 
 #include <algorithm>
 #include <atomic>
 #include <condition_variable>
+#include <climits>
+#include <cstddef>
 #include <cstdint>
+#include <cstdlib>
 #include <cstring>
 #include <deque>
+#include <functional>
 #include <map>
 #include <memory>
 #include <mutex>
@@ -50,6 +72,8 @@ constexpr uint32_t kMagic = 0x47454F4D;  // "GEOM"
 constexpr size_t kPrehdrSize = 4 + 4 + 1 + 4 + 4;
 constexpr size_t kMaxFrame = size_t(1) << 31;  // 2 GiB sanity bound
 constexpr size_t kMaxParts = 1 << 20;
+
+std::atomic<uint64_t> g_frames_freed{0};
 
 int SetNoDelay(int fd) {
   int one = 1;
@@ -65,6 +89,43 @@ bool SendAll(int fd, const uint8_t* buf, size_t len) {
       return false;
     }
     off += size_t(n);
+  }
+  return true;
+}
+
+// Gathered write of n buffers, in order, whole: sendmsg in batches of
+// at most IOV_MAX entries, resumed inside a buffer after a short write.
+bool SendAllV(int fd, const uint8_t* const* bufs, const uint64_t* lens,
+              size_t n) {
+  constexpr size_t kBatch = IOV_MAX < 1024 ? IOV_MAX : 1024;
+  struct iovec iov[kBatch];
+  size_t i = 0;      // first buffer not yet fully written
+  size_t done = 0;   // bytes of buffer i already written
+  while (i < n) {
+    size_t cnt = 0;
+    for (size_t j = i; j < n && cnt < kBatch; ++j) {
+      size_t skip = j == i ? done : 0;
+      if (lens[j] == skip) continue;
+      iov[cnt].iov_base = const_cast<uint8_t*>(bufs[j]) + skip;
+      iov[cnt].iov_len = size_t(lens[j]) - skip;
+      ++cnt;
+    }
+    if (cnt == 0) break;  // only empty buffers were left
+    struct msghdr mh{};
+    mh.msg_iov = iov;
+    mh.msg_iovlen = cnt;
+    ssize_t w = ::sendmsg(fd, &mh, MSG_NOSIGNAL);
+    if (w <= 0) {
+      if (w < 0 && errno == EINTR) continue;
+      return false;
+    }
+    size_t left = size_t(w);
+    while (i < n && left >= size_t(lens[i]) - done) {
+      left -= size_t(lens[i]) - done;
+      done = 0;
+      ++i;
+    }
+    done += left;
   }
   return true;
 }
@@ -120,8 +181,116 @@ int DialTcp(const char* host, int port, double timeout_s) {
   return fd;
 }
 
-// Read one complete frame from fd into out. Returns false on EOF/error.
-bool ReadFrame(int fd, std::string* out) {
+// One inbound frame: `len` bytes at `buf`, which lies a few bytes (under
+// kAlign) into a block's room; gx_free finds the block again by rounding
+// down (see the header comment for who calls it).
+struct Frame {
+  uint8_t* buf = nullptr;
+  size_t len = 0;
+};
+
+// malloc's own guarantee on this platform: what a frame's first part is
+// aligned to, and what lets RoomOf undo a frame's shift.
+constexpr size_t kAlign = 16;
+static_assert(alignof(std::max_align_t) >= kAlign, "malloc alignment");
+
+// A block: kAlign bytes that hold its capacity, then that many bytes of
+// room, malloc'ed as one.
+struct Block {
+  uint8_t* room = nullptr;
+  size_t cap = 0;
+};
+
+uint8_t* RoomOf(uint8_t* frame) {
+  return reinterpret_cast<uint8_t*>(reinterpret_cast<uintptr_t>(frame) &
+                                    ~uintptr_t(kAlign - 1));
+}
+
+void FreeBlock(uint8_t* room) {
+  if (room) ::free(room - kAlign);
+}
+
+// Blocks whose frames the host has released, kept for the frames to come:
+// a round's frames are about as large as the last round's, and a block
+// that has held one has the room, with its pages already touched, so the
+// next one is read without growing (a copy of what was read so far, below
+// malloc's mmap threshold) and without page faults (above it). The kSlots
+// largest are kept, process-wide; the rest go back to malloc.
+class BlockPool {
+ public:
+  static BlockPool& Get() {
+    static BlockPool* pool = new BlockPool;  // never destroyed: the host's
+    return *pool;                            // finalisers may outlive main
+  }
+
+  // The largest block kept, if it has room for `need`; else none.
+  Block Take(size_t need) {
+    std::lock_guard<std::mutex> lk(mu_);
+    auto it = std::max_element(
+        kept_.begin(), kept_.end(),
+        [](const Block& a, const Block& b) { return a.cap < b.cap; });
+    if (it == kept_.end() || it->cap < need) return Block{};
+    Block got = *it;
+    kept_.erase(it);
+    return got;
+  }
+
+  void Give(uint8_t* room) {
+    Block b{room, 0};
+    std::memcpy(&b.cap, room - kAlign, sizeof(b.cap));
+    {
+      std::lock_guard<std::mutex> lk(mu_);
+      kept_.push_back(b);
+      if (kept_.size() <= kSlots) return;
+      auto it = std::min_element(
+          kept_.begin(), kept_.end(),
+          [](const Block& a, const Block& b) { return a.cap < b.cap; });
+      b = *it;
+      kept_.erase(it);
+    }
+    FreeBlock(b.room);
+  }
+
+ private:
+  static constexpr size_t kSlots = 8;
+  std::mutex mu_;
+  std::vector<Block> kept_;
+};
+
+// Make room for `need` bytes in a block of which `used` are written. A
+// block that has to grow (a frame with parts: the frame's header carries
+// no total) first looks for a released block that is large enough; else
+// it doubles, so that a frame of many parts reallocates a logarithmic
+// number of times. Nothing is zero-filled; RecvExact overwrites it.
+bool Reserve(Block* b, size_t used, size_t need) {
+  if (need <= b->cap) return true;
+  if (b->room) {
+    Block got = BlockPool::Get().Take(need);
+    if (got.room) {
+      std::memcpy(got.room, b->room, used);
+      FreeBlock(b->room);
+      *b = got;
+      return true;
+    }
+  }
+  size_t want = std::max(need, b->cap * 2);
+  auto* p = static_cast<uint8_t*>(
+      ::realloc(b->room ? b->room - kAlign : nullptr, want + kAlign));
+  if (!p) return false;
+  std::memcpy(p, &want, sizeof(want));
+  b->room = p + kAlign;
+  b->cap = want;
+  return true;
+}
+
+// Read one complete frame from fd, calling `begun` once its pre-header is
+// in. Returns false on EOF/error (nothing is left allocated then). The
+// frame is the wire's bytes, unpadded; it
+// starts `shift` bytes into its block's room so that the FIRST part's
+// data is kAlign-aligned. Parts whose sizes are multiples of 4 (float32
+// values, int32 positions, the int64 header arrays) then all start
+// 4-aligned, and numpy reads arrays over them on its aligned paths.
+bool ReadFrame(int fd, Frame* out, const std::function<void()>& begun) {
   uint8_t hdr[kPrehdrSize];
   if (!RecvExact(fd, hdr, kPrehdrSize)) return false;
   uint32_t magic, meta_len;
@@ -129,28 +298,50 @@ bool ReadFrame(int fd, std::string* out) {
   std::memcpy(&meta_len, hdr + 13, 4);
   if (magic != kMagic) return false;
   if (meta_len > kMaxFrame) return false;
-  out->clear();
-  out->reserve(kPrehdrSize + meta_len + 4);
-  out->append(reinterpret_cast<char*>(hdr), kPrehdrSize);
-  size_t off = out->size();
-  out->resize(off + meta_len + 4);
-  if (!RecvExact(fd, reinterpret_cast<uint8_t*>(&(*out)[off]), meta_len + 4))
-    return false;
-  uint32_t ndata;
-  std::memcpy(&ndata, &(*out)[off + meta_len], 4);
-  if (ndata > kMaxParts) return false;
-  for (uint32_t i = 0; i < ndata; ++i) {
-    uint8_t lenb[4];
-    if (!RecvExact(fd, lenb, 4)) return false;
-    uint32_t n;
-    std::memcpy(&n, lenb, 4);
-    if (n > kMaxFrame || out->size() + n + 4 > kMaxFrame) return false;
-    size_t poff = out->size();
-    out->resize(poff + 4 + n);
-    std::memcpy(&(*out)[poff], lenb, 4);
-    if (n && !RecvExact(fd, reinterpret_cast<uint8_t*>(&(*out)[poff + 4]), n))
-      return false;
+  begun();  // a frame is arriving: the host may start its clock
+  size_t len = kPrehdrSize + meta_len + 4;  // the prefix
+  const size_t shift = (kAlign - (len + 4) % kAlign) % kAlign;
+  Block b;
+  len += shift;  // from here on, offsets into the room
+  bool ok = Reserve(&b, 0, len);
+  if (ok) {
+    std::memcpy(b.room + shift, hdr, kPrehdrSize);
+    ok = RecvExact(fd, b.room + shift + kPrehdrSize, meta_len + 4);
   }
+  uint32_t ndata = 0;
+  if (ok) {
+    std::memcpy(&ndata, b.room + len - 4, 4);
+    ok = ndata <= kMaxParts;
+  }
+  for (uint32_t i = 0; ok && i < ndata; ++i) {
+    // the part's length lands where it belongs in the frame
+    ok = Reserve(&b, len, len + 4) && RecvExact(fd, b.room + len, 4);
+    if (!ok) break;
+    uint32_t n;
+    std::memcpy(&n, b.room + len, 4);
+    len += 4;
+    ok = n <= kMaxFrame && len + n <= kMaxFrame &&
+         Reserve(&b, len, len + n) &&
+         (n == 0 || RecvExact(fd, b.room + len, n));
+    len += n;
+  }
+  if (!ok) {
+    FreeBlock(b.room);
+    return false;
+  }
+  if (b.cap / 4 > len) {
+    // a small frame that took a large released block: move it out, so
+    // that whoever keeps a view of it pins its own size and not a large
+    // frame's (doubling alone stays under 2x; this keeps it under 4x)
+    Block exact;
+    if (Reserve(&exact, 0, len)) {
+      std::memcpy(exact.room, b.room, len);
+      BlockPool::Get().Give(b.room);
+      b = exact;
+    }
+  }
+  out->buf = b.room + shift;
+  out->len = len - shift;
   return true;
 }
 
@@ -220,6 +411,12 @@ class Transport {
     }
     for (auto& t : readers)
       if (t.joinable()) t.join();
+    {
+      // frames nobody took: theirs was never handed over
+      std::lock_guard<std::mutex> lk(queue_mu_);
+      for (auto& f : queue_) FreeBlock(RoomOf(f.buf));
+      queue_.clear();
+    }
     std::vector<std::shared_ptr<Route>> routes;
     {
       std::lock_guard<std::mutex> lk(routes_mu_);
@@ -262,8 +459,11 @@ class Transport {
     }
   }
 
-  // Framed send with connection reuse and one redial on failure.
-  int64_t Send(int id, const uint8_t* buf, size_t len) {
+  // Framed send with connection reuse and one redial on failure: the
+  // n buffers, in order, are one frame. A write that fails part-way
+  // drops the connection, and the redial sends the frame whole.
+  int64_t Send(int id, const uint8_t* const* bufs, const uint64_t* lens,
+               size_t n) {
     std::shared_ptr<Route> r;
     {
       std::lock_guard<std::mutex> lk(routes_mu_);
@@ -271,6 +471,8 @@ class Transport {
       if (it == routes_.end()) return -2;  // no route
       r = it->second;
     }
+    uint64_t len = 0;
+    for (size_t i = 0; i < n; ++i) len += lens[i];
     std::lock_guard<std::mutex> lk(r->send_mu);
     for (int attempt = 0; attempt < 2; ++attempt) {
       if (r->fd >= 0) {
@@ -295,7 +497,7 @@ class Transport {
           continue;
         }
       }
-      if (SendAll(r->fd, buf, len)) {
+      if (SendAllV(r->fd, bufs, lens, n)) {
         send_bytes_ += len;
         return int64_t(len);
       }
@@ -330,7 +532,8 @@ class Transport {
   }
 
   // Pop one complete inbound frame. Returns:
-  //   >=0 frame length (frame copied into *out, caller frees with gx_free)
+  //   >=0 frame length; *out is the buffer the reader filled, the
+  //       caller's from here on (gx_free)
   //   -1 timeout, -2 stopped.
   int64_t Recv(uint8_t** out, double timeout_s) {
     std::unique_lock<std::mutex> lk(queue_mu_);
@@ -343,16 +546,24 @@ class Transport {
         return -1;
     }
     if (queue_.empty()) return stopped_.load() ? -2 : -1;
-    // allocate before dequeuing so an allocation failure doesn't lose
-    // the frame — the caller can retry
-    uint8_t* buf = static_cast<uint8_t*>(::malloc(queue_.front().size()));
-    if (!buf) return -3;
-    std::string frame = std::move(queue_.front());
+    Frame frame = queue_.front();
     queue_.pop_front();
+    --arriving_;
     lk.unlock();
-    std::memcpy(buf, frame.data(), frame.size());
-    *out = buf;
-    return int64_t(frame.size());
+    *out = frame.buf;
+    return int64_t(frame.len);
+  }
+
+  // Block until a frame has BEGUN to arrive (its pre-header is in; it may
+  // be complete and queued) and nobody has taken it yet: 1; 0 on timeout,
+  // -2 stopped. The host opens its receive span here, so that the span
+  // covers the rest of the read, which Recv then waits for.
+  int Wait(double timeout_s) {
+    std::unique_lock<std::mutex> lk(queue_mu_);
+    queue_cv_.wait_for(lk, std::chrono::duration<double>(timeout_s),
+                       [this] { return arriving_ > 0 || stopped_.load(); });
+    if (arriving_ > 0) return 1;
+    return stopped_.load() ? -2 : 0;
   }
 
   uint64_t send_bytes() const { return send_bytes_.load(); }
@@ -377,14 +588,26 @@ class Transport {
   }
 
   void ReaderLoop(int fd) {
-    std::string frame;
+    Frame frame;
     while (!stopped_.load()) {
-      if (!ReadFrame(fd, &frame)) break;
-      recv_bytes_ += frame.size();
+      bool counted = false;
+      auto begun = [&] {
+        std::lock_guard<std::mutex> lk(queue_mu_);
+        ++arriving_;
+        counted = true;
+        queue_cv_.notify_all();
+      };
+      if (!ReadFrame(fd, &frame, begun)) {
+        if (counted) {
+          std::lock_guard<std::mutex> lk(queue_mu_);
+          --arriving_;  // the frame that began will never be whole
+        }
+        break;
+      }
+      recv_bytes_ += frame.len;
       std::lock_guard<std::mutex> lk(queue_mu_);
-      queue_.push_back(std::move(frame));
-      frame.clear();
-      queue_cv_.notify_one();
+      queue_.push_back(frame);
+      queue_cv_.notify_all();
     }
     // close + deregister atomically so Stop never shutdown()s a reused
     // fd number
@@ -409,7 +632,8 @@ class Transport {
 
   std::mutex queue_mu_;
   std::condition_variable queue_cv_;
-  std::deque<std::string> queue_;
+  std::deque<Frame> queue_;
+  int arriving_ = 0;  // frames begun or queued, not yet taken (queue_mu_)
 
   std::atomic<uint64_t> send_bytes_{0};
   std::atomic<uint64_t> recv_bytes_{0};
@@ -435,7 +659,13 @@ void gx_set_route(void* h, int id, const char* host, int port) {
 }
 
 int64_t gx_send(void* h, int id, const uint8_t* buf, uint64_t len) {
-  return static_cast<Transport*>(h)->Send(id, buf, size_t(len));
+  return static_cast<Transport*>(h)->Send(id, &buf, &len, 1);
+}
+
+// One frame from n buffers of the caller's, written as they lie.
+int64_t gx_sendv(void* h, int id, const uint8_t* const* bufs,
+                 const uint64_t* lens, uint64_t n) {
+  return static_cast<Transport*>(h)->Send(id, bufs, lens, size_t(n));
 }
 
 int64_t gx_send_addr(void* h, const char* host, int port, const uint8_t* buf,
@@ -447,7 +677,17 @@ int64_t gx_recv(void* h, uint8_t** out, double timeout_s) {
   return static_cast<Transport*>(h)->Recv(out, timeout_s);
 }
 
-void gx_free(uint8_t* buf) { ::free(buf); }
+int gx_wait(void* h, double timeout_s) {
+  return static_cast<Transport*>(h)->Wait(timeout_s);
+}
+
+void gx_free(uint8_t* buf) {
+  BlockPool::Get().Give(RoomOf(buf));
+  ++g_frames_freed;
+}
+
+// Frames released through gx_free, process-wide (tests count them).
+uint64_t gx_frames_freed() { return g_frames_freed.load(); }
 
 uint64_t gx_send_bytes(void* h) {
   return static_cast<Transport*>(h)->send_bytes();

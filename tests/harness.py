@@ -319,6 +319,22 @@ class SingleTier:
         return [t.name for t in threads if t.is_alive()]
 
 
+def count_sent_payload(monkeypatch):
+    """Every data message any van of this process writes from here on
+    adds its parts' bytes to the returned list (one entry a write)."""
+    from geomx_tpu.ps.van import Van
+    sent = []
+    inner = Van._send_one_inner
+
+    def counting(self, target, msg):
+        if not msg.is_control:
+            sent.append(msg.payload_bytes())
+        return inner(self, target, msg)
+
+    monkeypatch.setattr(Van, "_send_one_inner", counting)
+    return sent
+
+
 # -- a bare tier of Postoffices (no kvstore on top) -----------------------
 
 

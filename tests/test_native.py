@@ -54,6 +54,37 @@ def test_frame_roundtrip_and_order():
         b.close()
 
 
+def test_wait_begin_says_when_a_frame_starts_to_arrive():
+    """``wait_begin`` returns once a frame's pre-header is in, before
+    the frame is whole, so the van's receive span covers the read."""
+    import socket
+    import time
+
+    b = native.NativeTransport("127.0.0.1", 0)
+    try:
+        assert b.wait_begin(timeout_s=0.05) is False
+        m = Message(Meta(sender=1, recver=7))
+        m.add_array(np.arange(1000, dtype=np.float32))
+        wire = m.pack()
+        s = socket.create_connection(("127.0.0.1", b.port))
+        s.sendall(wire[:40])                    # the pre-header and a bit
+        assert b.wait_begin(timeout_s=5.0) is True
+        assert b.wait_frame(timeout_s=0.05) is None     # not whole yet
+        s.sendall(wire[40:])
+        assert b.recv(timeout_s=5.0) == wire
+        assert b.wait_begin(timeout_s=0.05) is False    # taken
+        # a frame that begins and never ends stops counting
+        s.sendall(wire[:40])
+        assert b.wait_begin(timeout_s=5.0) is True
+        s.close()
+        deadline = time.monotonic() + 5.0
+        while b.wait_begin(timeout_s=0.01) and time.monotonic() < deadline:
+            time.sleep(0.01)
+        assert b.wait_begin(timeout_s=0.01) is False
+    finally:
+        b.close()
+
+
 def test_recv_timeout_and_stop():
     t = native.NativeTransport("127.0.0.1", 0)
     assert t.recv(timeout_s=0.05) is None
