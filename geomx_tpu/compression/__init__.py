@@ -41,12 +41,13 @@ from typing import Dict, Optional, Tuple, Union
 
 import numpy as np
 
+from geomx_tpu import kernels_native
 from geomx_tpu.compression.entries import Entries, Pairs, SPARSE_TAGS
 
 __all__ = ["make_compressor", "Compressor", "FP16Compressor", "BSCCompressor",
            "TwoBitCompressor", "MPQCompressor", "bsc_compress", "bsc_decompress",
            "bsc_pull_compress", "two_bit_quantize", "two_bit_dequantize",
-           "takes_pairs", "Entries", "Pairs", "SPARSE_TAGS"]
+           "takes_pairs", "draw_ahead", "Entries", "Pairs", "SPARSE_TAGS"]
 
 BSC_MOMENTUM = 0.9  # reference: gradient_compression.cc:198
 
@@ -92,6 +93,12 @@ def bsc_sample_positions(n: int, threshold: float,
     return rng.choice(n, size, replace=False, shuffle=False)
 
 
+def _boundary_of(sample: np.ndarray, threshold: float) -> float:
+    """The smallest of the ``threshold`` largest of ``sample`` (>= 0)."""
+    top_k = min(max(int(sample.size * threshold), 1), sample.size)
+    return float(np.partition(sample, -top_k)[-top_k])
+
+
 def bsc_sample_boundary(v: np.ndarray, threshold: float,
                         rng: np.random.Generator,
                         positions: Optional[np.ndarray] = None) -> float:
@@ -100,9 +107,7 @@ def bsc_sample_boundary(v: np.ndarray, threshold: float,
     that draws them itself (under its lock on a shared ``rng``)."""
     if positions is None:
         positions = bsc_sample_positions(v.size, threshold, rng)
-    sample = np.abs(v[positions])
-    top_k = min(max(int(sample.size * threshold), 1), sample.size)
-    return float(np.partition(sample, -top_k)[-top_k])
+    return _boundary_of(np.abs(v[positions]), threshold)
 
 
 # elements per block of the selection pass: 1 MB of float32, so the
@@ -150,10 +155,17 @@ def bsc_compress(grad: Union[np.ndarray, Pairs], u: np.ndarray,
     ``+ 0.0`` clears; a position repeated inside one payload adds its
     values to ``u`` one by one, not their sum).
     """
-    if rng is None and positions is None:
-        rng = np.random.default_rng(42)  # reference uses a fixed seed (:212)
     n = grad.size
     zipped = max(int(n * threshold), 1)
+    if positions is None:
+        if rng is None:     # the reference uses a fixed seed (:212)
+            rng = np.random.default_rng(42)
+        positions = bsc_sample_positions(n, threshold, rng)
+    if isinstance(grad, Pairs) and u.size == n \
+            and kernels_native.bsc_pass_usable(u, v):
+        swept = _one_sweep(grad, u, v, threshold, positions, zipped)
+        if swept is not None:
+            return swept
     u *= BSC_MOMENTUM
     if isinstance(grad, Pairs):
         grad.add_into(u)
@@ -166,6 +178,33 @@ def bsc_compress(grad: Union[np.ndarray, Pairs], u: np.ndarray,
     v[selected] = 0.0
     u[selected] = 0.0
     return values.astype(np.float32, copy=False), selected.astype(np.int32)
+
+
+def _one_sweep(grad: Pairs, u: np.ndarray, v: np.ndarray, threshold: float,
+               positions: np.ndarray, cap: int,
+               ) -> Optional[Tuple[np.ndarray, np.ndarray]]:
+    """:func:`bsc_compress` of pairs as one sweep over ``u`` and ``v``
+    (``kernels_native``: 16 bytes an element moved where the numpy
+    passes move 24 and then gather and scatter the selection): the
+    boundary is read ahead, from what the sampled positions are about
+    to hold, so a block is decayed, accumulated, compared and cleared
+    while it is in cache. The same float32 operations in the same order
+    an element, the same sample, so the same selection, ``u`` and ``v``.
+    None, nothing touched, for pairs outside the key (numpy's error is
+    the passes' to raise)."""
+    idx = grad.idx.astype(np.int64, copy=False)
+    vals = np.ascontiguousarray(grad.vals, dtype=np.float32)
+    if idx.size > 1 and not (idx[1:] >= idx[:-1]).all():
+        order = np.argsort(idx, kind="stable")  # equal positions keep
+        idx, vals = idx[order], vals[order]     # their order of arrival
+    if idx.size and not (idx[0] >= 0 and idx[-1] < u.size):
+        return None
+    idx = np.ascontiguousarray(idx)
+    pos = np.sort(positions).astype(np.int64, copy=False)
+    boundary = _boundary_of(kernels_native.bsc_sample(
+        u, v, BSC_MOMENTUM, idx, vals, pos), threshold)
+    return kernels_native.bsc_sweep(u, v, BSC_MOMENTUM, idx, vals, boundary,
+                                    cap)
 
 
 def bsc_pull_compress(arr: np.ndarray, threshold: float, multiplier: int,
@@ -344,16 +383,28 @@ class BSCCompressor(Compressor):
         # concurrently; numpy Generators are not thread-safe
         self._rng_lock = __import__("threading").Lock()
 
-    def compress_push(self, arr, state_key=None):
+    def draw(self, num_elems: int, state_key=None) -> np.ndarray:
+        """What ``compress_push`` of a key of ``num_elems`` elements
+        shares with the other keys, done apart from it: the key's state
+        allocated on first sight, and the boundary sample's positions
+        out of the one generator. A caller that compresses the keys of a
+        batch side by side calls this for each of them first, in the
+        batch's order, from one thread (the generator's stream is then
+        the serial pass's), and hands each key's positions on."""
+        if state_key not in self._u:
+            self._u[state_key] = np.zeros(num_elems, dtype=np.float32)
+            self._v[state_key] = np.zeros(num_elems, dtype=np.float32)
+        with self._rng_lock:
+            return bsc_sample_positions(num_elems, self.threshold, self._rng)
+
+    def compress_push(self, arr, state_key=None, positions=None):
         """``arr``: the gradient as an array or as :class:`Pairs` (a
         party server's aggregate of Bi-Sparse pushes, never made
-        dense); the state, the draw and the selection are the same."""
-        if state_key not in self._u:
-            self._u[state_key] = np.zeros(arr.size, dtype=np.float32)
-            self._v[state_key] = np.zeros(arr.size, dtype=np.float32)
-        with self._rng_lock:
-            positions = bsc_sample_positions(arr.size, self.threshold,
-                                             self._rng)
+        dense); the state, the draw and the selection are the same.
+        ``positions``: those of :meth:`draw` for this key, where the
+        caller drew ahead; the rest touches this key's state alone."""
+        if positions is None:
+            positions = self.draw(arr.size, state_key)
         if not isinstance(arr, Pairs):
             arr = np.asarray(arr, dtype=np.float32)
         values, indices = bsc_compress(
@@ -416,8 +467,8 @@ class MPQCompressor(Compressor):
     def _route(self, num_elems: int) -> Compressor:
         return self._bsc if num_elems >= self.size_lower_bound else self._fp16
 
-    def compress_push(self, arr, state_key=None):
-        return self._route(arr.size).compress_push(arr, state_key)
+    def compress_push(self, arr, state_key=None, **drawn):
+        return self._route(arr.size).compress_push(arr, state_key, **drawn)
 
     def compress_pull(self, tag, arr, factor):
         if tag == "bsc":
@@ -431,14 +482,30 @@ class MPQCompressor(Compressor):
         return self._route(num_elems).push_tag(num_elems)
 
 
-def takes_pairs(gc, num_elems: int) -> bool:
-    """Whether ``gc.compress_push`` of a key of ``num_elems`` elements
-    takes its gradient as :class:`Pairs`: the host Bi-Sparse pass,
-    configured as such or reached by MPQ's route for this size. Every
-    other compressor (the device one included) reads an array."""
+def _host_bsc(gc, num_elems: int) -> Optional[BSCCompressor]:
+    """The host Bi-Sparse pass that ``gc.compress_push`` of a key of
+    ``num_elems`` elements runs: ``gc`` configured as such or reached by
+    MPQ's route for this size; None for every other compressor (the
+    device one included)."""
     if isinstance(gc, MPQCompressor):
         gc = gc._route(num_elems)
-    return isinstance(gc, BSCCompressor)
+    return gc if isinstance(gc, BSCCompressor) else None
+
+
+def takes_pairs(gc, num_elems: int) -> bool:
+    """Whether ``gc.compress_push`` of a key of ``num_elems`` elements
+    takes its gradient as :class:`Pairs`: the host Bi-Sparse pass. Every
+    other compressor reads an array."""
+    return _host_bsc(gc, num_elems) is not None
+
+
+def draw_ahead(gc, num_elems: int, state_key) -> Optional[np.ndarray]:
+    """:meth:`BSCCompressor.draw` where ``gc.compress_push`` of this key
+    runs the host Bi-Sparse pass: the positions to hand it as
+    ``positions=``. None where it does not: such a compressor keeps
+    whatever it shares to itself, and its keys go one after the other."""
+    bsc = _host_bsc(gc, num_elems)
+    return None if bsc is None else bsc.draw(num_elems, state_key)
 
 
 def make_compressor(params: Optional[dict]) -> Compressor:

@@ -31,6 +31,8 @@ _lib: Optional[ctypes.CDLL] = None
 _failed = False
 
 _f32p = ctypes.POINTER(ctypes.c_float)
+_i64p = ctypes.POINTER(ctypes.c_int64)
+_i32p = ctypes.POINTER(ctypes.c_int32)
 
 
 def enabled() -> bool:
@@ -47,7 +49,8 @@ def lib() -> Optional[ctypes.CDLL]:
         if _lib is not None or _failed:
             return _lib
         try:
-            L = ctypes.CDLL(ensure_built("kernels", ["-O3"]))
+            L = ctypes.CDLL(ensure_built(
+                "kernels", ["-O3", "-ffp-contract=off"]))
         except (OSError, subprocess.SubprocessError) as e:
             _failed = True
             log.warning("native kernels unavailable (%s); using numpy", e)
@@ -65,6 +68,12 @@ def lib() -> Optional[ctypes.CDLL]:
         L.gxk_adam.restype = None
         L.gxk_adam.argtypes = [_f32p, _f32p, _f32p, _f32p, f32, f32, f32,
                                f32, f32, i64, i64]
+        L.gxk_bsc_sample.restype = None
+        L.gxk_bsc_sample.argtypes = [_f32p, _f32p, f32, _i64p, _f32p, i64,
+                                     _i64p, i64, _f32p]
+        L.gxk_bsc_sweep.restype = i64
+        L.gxk_bsc_sweep.argtypes = [_f32p, _f32p, i64, f32, _i64p, _f32p,
+                                    i64, f32, _i32p, _f32p, i64]
         _lib = L
         return _lib
 
@@ -117,3 +126,47 @@ def adam(w: np.ndarray, g: np.ndarray, m: np.ndarray, v: np.ndarray,
     L.gxk_adam(_ptr(w), _ptr(g), _ptr(m), _ptr(v), lr, b1, b2, eps, wd,
                t, w.size)
     return True
+
+
+def _i64(a: np.ndarray):
+    return a.ctypes.data_as(_i64p)
+
+
+def bsc_pass_usable(u: np.ndarray, v: np.ndarray) -> bool:
+    """Whether :func:`bsc_sample` and :func:`bsc_sweep` take this
+    state: the library, a key large enough, two float32 arrays of one
+    size that own contiguous, writeable memory below 2**31 elements."""
+    return (u.size == v.size < 1 << 31 and _eligible(u, v)
+            and u.flags.writeable and v.flags.writeable
+            and usable(u.size))
+
+
+def bsc_sample(u: np.ndarray, v: np.ndarray, momentum: float,
+               idx: np.ndarray, vals: np.ndarray,
+               pos: np.ndarray) -> np.ndarray:
+    """``|v + (momentum * u + g)|`` at ``pos`` (``gxk_bsc_sample``),
+    ``g`` the pairs ``(idx, vals)``: what :func:`bsc_sweep` is about to
+    leave in ``v`` there. ``u``, ``v`` as :func:`bsc_pass_usable` wants
+    them; ``idx`` and ``pos`` int64, contiguous, ascending, inside the
+    key (``pos`` distinct); ``vals`` float32, contiguous: the caller's
+    to see to."""
+    out = np.empty(pos.size, dtype=np.float32)
+    lib().gxk_bsc_sample(_ptr(u), _ptr(v), momentum, _i64(idx), _ptr(vals),
+                         idx.size, _i64(pos), pos.size, _ptr(out))
+    return out
+
+
+def bsc_sweep(u: np.ndarray, v: np.ndarray, momentum: float,
+              idx: np.ndarray, vals: np.ndarray, boundary: float,
+              cap: int):
+    """The Bi-Sparse pass over one key in one sweep (``gxk_bsc_sweep``):
+    ``u *= momentum``, ``vals`` added into ``u`` at ``idx`` in their
+    order, ``v += u``, then the first ``cap`` positions, ascending,
+    with ``|v| >= boundary`` -> (their values, the positions as int32),
+    ``v`` and ``u`` cleared there. Arguments as :func:`bsc_sample`'s."""
+    out_idx = np.empty(cap, dtype=np.int32)
+    out_val = np.empty(cap, dtype=np.float32)
+    got = lib().gxk_bsc_sweep(
+        _ptr(u), _ptr(v), u.size, momentum, _i64(idx), _ptr(vals), idx.size,
+        boundary, out_idx.ctypes.data_as(_i32p), _ptr(out_val), cap)
+    return out_val[:got], out_idx[:got]

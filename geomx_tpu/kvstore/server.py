@@ -63,6 +63,7 @@ aligned wire-key ranges and supports only matching layouts).
 
 from __future__ import annotations
 
+import collections
 import dataclasses
 import functools
 import io
@@ -72,7 +73,8 @@ import pickle
 import sys
 import threading
 import time
-from typing import Callable, Dict, List, Optional, Tuple
+from concurrent.futures import ThreadPoolExecutor
+from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -81,7 +83,7 @@ from geomx_tpu import config as cfg_mod
 from geomx_tpu import kernels_native
 from geomx_tpu import profiler
 from geomx_tpu import telemetry
-from geomx_tpu.compression import (Entries, Pairs, SPARSE_TAGS,
+from geomx_tpu.compression import (Entries, Pairs, SPARSE_TAGS, draw_ahead,
                                    make_compressor, takes_pairs)
 from geomx_tpu.compression.device import WireCodec
 from geomx_tpu.kvstore import sharding
@@ -155,6 +157,90 @@ def _clocks() -> Tuple[float, float]:
     thread was off the processor in it, which here is the wait for the
     GIL."""
     return 1e3 * time.perf_counter(), 1e3 * time.thread_time()
+
+
+# A party server's WAN-forward re-selection of one round runs its large
+# keys side by side (_flush_forward_batch): an entry of fewer elements
+# than this costs interpreter time, not memory bandwidth, and stays on
+# the calling thread
+_POOL_MIN_ELEMS = 1 << 18
+# threads beside the calling one. On the chip machine's host (13 cores)
+# the fourth thread still shortens a server's round alone and is a wash
+# where both party servers select at once; six would crowd the vans and
+# the global server (tools/select_bench.py; PERF.md section 6, PR 37)
+_POOL_HELPERS = 3
+
+
+class _SelectPool:
+    """The threads a party server selects beside. ``run`` works a list
+    of tasks off from its front, the calling thread among the workers,
+    and returns when the last one is done; the threads outlive it and
+    end with :meth:`close`."""
+
+    def __init__(self, helpers: int, name: str = "select"):
+        self.helpers = helpers
+        self._threads = ThreadPoolExecutor(helpers, thread_name_prefix=name)
+
+    def run(self, tasks: Sequence[Callable[[], None]],
+            first: Optional[Callable[[], None]] = None) -> None:
+        """Run every task once. The calling thread runs ``first``, then
+        takes tasks as the others do. The first exception of a task or
+        of ``first`` is raised here once every thread has left the
+        list; the tasks nobody had taken by then are not run."""
+        queue = collections.deque(tasks)
+        failed: List[BaseException] = []
+
+        def attempt(task: Callable[[], None]) -> None:
+            try:
+                task()
+            except BaseException as e:  # noqa: BLE001 - raised below
+                failed.append(e)
+                queue.clear()
+
+        def drain() -> None:
+            while True:
+                try:
+                    task = queue.popleft()
+                except IndexError:
+                    return
+                attempt(task)
+
+        helping = []
+        try:
+            for _ in range(min(self.helpers, len(tasks))):
+                helping.append(self._threads.submit(drain))
+        except RuntimeError:
+            pass    # closed under a crash: the calling thread does it all
+        if first is not None:
+            attempt(first)
+        drain()
+        for f in helping:
+            f.result()
+        if failed:
+            raise failed[0]
+
+    def run_sized(self, sizes: Sequence[int],
+                  work: Callable[[int], None]) -> None:
+        """``work(i)`` once for every i of ``sizes``: those of
+        ``_POOL_MIN_ELEMS`` elements and more side by side, largest
+        first; the calling thread does the others first, in their
+        order, then takes from the same list. Fewer than two large
+        ones: all of them on the calling thread, in their order."""
+        large = sorted((i for i, n in enumerate(sizes)
+                        if n >= _POOL_MIN_ELEMS),
+                       key=lambda i: -sizes[i])
+        if len(large) < 2:
+            for i in range(len(sizes)):
+                work(i)
+            return
+        small = [i for i, n in enumerate(sizes) if n < _POOL_MIN_ELEMS]
+        self.run([functools.partial(work, i) for i in large],
+                 first=lambda: [work(i) for i in small])
+
+    def close(self) -> None:
+        """Join the threads; ``run`` after this works on the calling
+        thread alone."""
+        self._threads.shutdown(wait=True)
 
 
 class _BatchResponder:
@@ -332,6 +418,11 @@ class _KeyState:
 class KVStoreDistServer:
     """Runs in every DMLC_ROLE=server process (global server included)."""
 
+    # the threads one round's WAN-forward re-selection runs over
+    # (_flush_forward_batch): a party server's, from start() to
+    # shutdown() or crash(); without it the keys go one after the other
+    _select_pool: Optional[_SelectPool] = None
+
     def __init__(self, cfg: Optional[cfg_mod.Config] = None):
         self.cfg = cfg or cfg_mod.load()
         c = self.cfg
@@ -463,6 +554,8 @@ class KVStoreDistServer:
     # ------------------------------------------------------------------
 
     def start(self, timeout: float = 120.0) -> None:
+        if self.has_global_tier:
+            self._select_pool = _SelectPool(_POOL_HELPERS)
         self.po_local.start(timeout)
         # elastic membership: epoch bumps re-check every pending
         # aggregation countdown, and esync's reporter window tracks the
@@ -578,8 +671,15 @@ class KVStoreDistServer:
         try:
             self.po_local.finalize(do_barrier=not self._crashed)
         finally:
-            if self.po_global is not None:
-                self.po_global.finalize(do_barrier=not self._crashed)
+            try:
+                if self.po_global is not None:
+                    self.po_global.finalize(do_barrier=not self._crashed)
+            finally:
+                self._close_select_pool()
+
+    def _close_select_pool(self) -> None:
+        if self._select_pool is not None:
+            self._select_pool.close()
 
     def crash(self) -> None:
         """Hard-kill this server as a fault would: stop both vans NOW, no
@@ -591,6 +691,7 @@ class KVStoreDistServer:
         self.po_local.van.stop()
         if self.po_global is not None:
             self.po_global.van.stop()
+        self._close_select_pool()
 
     def _on_van_crash(self) -> None:
         # called by the van after a FaultPlan "crash" rule fired (the van
@@ -1548,7 +1649,8 @@ class KVStoreDistServer:
         sub = st.outbound[lo - st.offset:hi - st.offset]
         return sub if isinstance(sub, Pairs) else np.ascontiguousarray(sub)
 
-    def _wan_compress(self, st: _KeyState, key: int, lo: int, sub):
+    def _wan_compress(self, st: _KeyState, key: int, lo: int, sub,
+                      positions=None, span=None):
         """Compress one WAN-forward slice -> (wire_val, aux, compr).
 
         The configured compressor still runs first so BSC momentum /
@@ -1561,7 +1663,11 @@ class KVStoreDistServer:
 
         ``sub`` is an array or ``Pairs``; the compressor is handed the
         pairs where it ``takes_pairs`` for a slice of this size, and
-        the array they make otherwise."""
+        the array they make otherwise. ``positions``: what
+        ``draw_ahead`` gave for this slice, where the caller drew the
+        batch's samples before compressing any of it; ``span``: the
+        arguments of the ``server.select`` span, which a thread of the
+        pool gets from the thread that took the push."""
         n = int(sub.size)
         tag = self._wan_wire_tag(st, n)
         if isinstance(sub, Pairs):
@@ -1571,10 +1677,12 @@ class KVStoreDistServer:
                 # once a (key, shard) round, however many global slices
                 telemetry.counter_inc("server.sparse_forward_key_rounds",
                                       tier=self._tier)
+        if span is None:
+            span = self._round_args(self._wan_trace[0])
+        drawn = {} if positions is None else {"positions": positions}
         t0 = _clocks()
-        with profiler.scope("server.select", cat="kvstore",
-                            **self._round_args(self._wan_trace[0])):
-            wv, aux, t = self.gc.compress_push(sub, (key, lo))
+        with profiler.scope("server.select", cat="kvstore", **span):
+            wv, aux, t = self.gc.compress_push(sub, (key, lo), **drawn)
         if t == "bsc":
             # the party server's Bi-Sparse re-selection, host numpy
             wall, cpu = _clocks()
@@ -1656,33 +1764,86 @@ class KVStoreDistServer:
     # assumes, kvstore_dist.h:567-618, which likewise amortizes per-key
     # overheads across the send queue.)
 
-    @_round_span("server.forward")
-    def _flush_forward_batch(self, entries) -> None:
-        if self._transport is not None:
-            # refresh the transport plan once per WAN round (idempotent
-            # per round) so _wan_wire_tag sees the freshest decisions
-            self._transport.plan(self._wan_trace[0])
-        per_rank: Dict[Tuple[int, str], List[tuple]] = {}
+    def _forward_entry(self, key, off, cycle, slices, drawn,
+                       span) -> List[tuple]:
+        """Stage one entry of a forward batch: every global slice of the
+        (key, shard) compressed and cached for a retry, under the key's
+        own lock -> (global rank, tag, item of the message) a slice.
+        Touches nothing another key's entry touches."""
+        st = self._state(key, off)
+        out = []
+        with st.lock:
+            if st.cycle != cycle or st.outbound is None:
+                return out
+            st.fwd_acks_left = len(slices)
+            # the pull-back rides the push ack (pull=True below), so
+            # the response accounting starts at push time
+            st.fwd_expected = len(slices)
+            st.fwd_parts = {}
+            st.fwd_wire = {}
+            total = st.total
+            for (g_rank, lo, hi), positions in zip(slices, drawn):
+                cached = self._wan_compress(
+                    st, key, lo, self._outbound_slice(st, lo, hi),
+                    positions, span)
+                st.fwd_wire[lo] = cached
+                wire_val, aux, compr = cached
+                out.append((g_rank, compr, (key, off, cycle, lo, hi, total,
+                                            wire_val, aux)))
+        return out
+
+    def _stage_forwards(self, entries, span) -> List[List[tuple]]:
+        """:meth:`_forward_entry` of every entry of a batch -> its
+        results in the entries' order. First what the keys share, on
+        this thread and in the batch's order: each slice's boundary
+        sample out of the compressor's one generator (and a new key's
+        state), so the generator's stream is the same whoever
+        compresses what. Then the large entries side by side, largest
+        first, over the server's pool, this thread staging the small
+        ones and then working the same list."""
+        jobs, sizes = [], []
         for key, off, cycle in entries:
             st = self._state(key, off)
             with st.lock:
                 if st.cycle != cycle or st.outbound is None:
                     continue
                 slices = self._global_slices(key, off, st.length, st.total)
-                st.fwd_acks_left = len(slices)
-                # the pull-back rides the push ack (pull=True below), so
-                # the response accounting starts at push time
-                st.fwd_expected = len(slices)
-                st.fwd_parts = {}
-                st.fwd_wire = {}
-                total = st.total
-                for g_rank, lo, hi in slices:
-                    cached = self._wan_compress(
-                        st, key, lo, self._outbound_slice(st, lo, hi))
-                    st.fwd_wire[lo] = cached
-                    wire_val, aux, compr = cached
-                    per_rank.setdefault((g_rank, compr), []).append(
-                        (key, off, cycle, lo, hi, total, wire_val, aux))
+                drawn = [draw_ahead(self.gc, hi - lo, (key, lo))
+                         for _g_rank, lo, hi in slices]
+                jobs.append((key, off, cycle, slices, drawn))
+                # an entry whose compressor draws for itself stays in
+                # line on this thread, as small ones do
+                sizes.append(0 if any(p is None for p in drawn)
+                             else st.length)
+        staged: List[List[tuple]] = [[] for _ in jobs]
+
+        def stage(i: int) -> None:
+            staged[i] = self._forward_entry(*jobs[i], span)
+
+        if self._select_pool is None:
+            for i in range(len(jobs)):
+                stage(i)
+        else:
+            self._select_pool.run_sized(sizes, stage)
+        return staged
+
+    @_round_span("server.forward")
+    def _flush_forward_batch(self, entries) -> None:
+        if self._transport is not None:
+            # refresh the transport plan once per WAN round (idempotent
+            # per round) so _wan_wire_tag sees the freshest decisions
+            self._transport.plan(self._wan_trace[0])
+        span = self._round_args(self._wan_trace[0])
+        # all of it is the selection's: this thread draws, stages and
+        # then waits for the pool under server.select, not under
+        # server.forward (innermost span owns the instant)
+        with profiler.scope("server.select", cat="kvstore", **span):
+            staged = self._stage_forwards(entries, span)
+        # the messages in the entries' own order, whoever staged them
+        per_rank: Dict[Tuple[int, str], List[tuple]] = {}
+        for parts in staged:
+            for g_rank, compr, item in parts:
+                per_rank.setdefault((g_rank, compr), []).append(item)
         for (g_rank, compr), items in per_rank.items():
             kvs = KVPairs(
                 keys=[it[0] for it in items],

@@ -9,10 +9,12 @@
 // ctypes calls release the GIL, so these plain-C loops restore true
 // thread scaling for concurrent per-key handling (tools/server_bench.py).
 //
-// Build: g++ -O3 -std=c++17 -fPIC -shared (geomx_tpu/kernels_native.py,
+// Build: g++ -O3 -ffp-contract=off -std=c++17 -fPIC -shared
+// (geomx_tpu/kernels_native.py,
 // same on-demand pattern as the transport core).
 
 #include <cstdint>
+#include <cstring>
 #include <cmath>
 
 extern "C" {
@@ -65,6 +67,84 @@ void gxk_adam(float* w, const float* g, float* m, float* v, float lr,
         float vh = v[i] / bc2;
         w[i] -= lr * mh / (std::sqrt(vh) + eps);
     }
+}
+
+// The party server's Bi-Sparse pass over one key
+// (compression.bsc_compress runs it as numpy passes: u *= m, the pairs
+// added into u, v += u, a boundary from |v| at sampled positions, the
+// positions with |v| >= boundary, their values, v and u cleared there)
+// in two calls: the sample read ahead, then one sweep in which a block
+// of u and v is decayed, gets its pairs, is accumulated, compared and
+// cleared where it was selected while it is in cache, so u and v are
+// read and written once. Every element sees the float32 operations of
+// the numpy passes in their order: one rounding a step, no fused
+// multiply-add (-ffp-contract=off, and no clone is built with FMA).
+//
+// The pairs: pidx[np] ascending in [0, n), pvals beside it, added into u
+// one by one in their order (a repeated position gets its values one
+// after the other).
+#if defined(__x86_64__) && defined(__GNUC__) && !defined(__clang__)
+#define GXK_CLONES __attribute__((target_clones("avx2", "default")))
+#else
+#define GXK_CLONES
+#endif
+
+// out[ns] = |v + (m * u + g)| at the positions pos[ns] (ascending,
+// distinct, in [0, n)): what the sweep below leaves in v there.
+void gxk_bsc_sample(const float* u, const float* v, float m,
+                    const int64_t* pidx, const float* pvals, int64_t np,
+                    const int64_t* pos, int64_t ns, float* out) {
+    int64_t p = 0;
+    for (int64_t s = 0; s < ns; ++s) {
+        const int64_t at = pos[s];
+        float x = u[at] * m;
+        while (p < np && pidx[p] < at) ++p;
+        for (; p < np && pidx[p] == at; ++p) x += pvals[p];
+        out[s] = std::fabs(v[at] + x);
+    }
+}
+
+// The sweep. The first `cap` positions, ascending, with |v| >= boundary
+// (a NaN never is) go to out_idx[cap], their values to out_val[cap], and
+// v and u are cleared there; returns how many.
+GXK_CLONES
+int64_t gxk_bsc_sweep(float* __restrict u, float* __restrict v, int64_t n,
+                      float m, const int64_t* pidx, const float* pvals,
+                      int64_t np, float boundary, int32_t* out_idx,
+                      float* out_val, int64_t cap) {
+    const int64_t B = 2048;             // 16 KB of u and v a block
+    uint8_t flag[B + 8];
+    int64_t p = 0, cnt = 0;
+    for (int64_t lo = 0; lo < n; lo += B) {
+        const int64_t len = n - lo < B ? n - lo : B;
+        float* __restrict ub = u + lo;
+        float* __restrict vb = v + lo;
+        for (int64_t i = 0; i < len; ++i) ub[i] *= m;
+        for (; p < np && pidx[p] < lo + len; ++p) u[pidx[p]] += pvals[p];
+        if (cnt >= cap) {
+            for (int64_t i = 0; i < len; ++i) vb[i] += ub[i];
+            continue;
+        }
+        for (int64_t i = 0; i < len; ++i) {
+            const float x = vb[i] + ub[i];
+            vb[i] = x;
+            flag[i] = std::fabs(x) >= boundary;
+        }
+        std::memset(flag + len, 0, 8);
+        for (int64_t i = 0; i < len; i += 8) {
+            uint64_t any;
+            std::memcpy(&any, flag + i, 8);
+            if (!any) continue;
+            for (int64_t j = i; j < i + 8 && cnt < cap; ++j) {
+                if (!flag[j]) continue;
+                out_idx[cnt] = (int32_t)(lo + j);
+                out_val[cnt++] = vb[j];
+                vb[j] = 0.0f;
+                ub[j] = 0.0f;
+            }
+        }
+    }
+    return cnt;
 }
 
 }  // extern "C"

@@ -15,14 +15,17 @@ import subprocess
 import sys
 import threading
 import time
+import types
 
 import numpy as np
 import pytest
 
+from geomx_tpu.compression import BSCCompressor
 from geomx_tpu.config import Config
 from geomx_tpu.kvstore.dist import KVStoreDist
 from geomx_tpu.kvstore.server import KVStoreDistServer
 from geomx_tpu.ps import base as psbase
+from geomx_tpu.ps.kv_app import KVPairs, ReqMeta
 from geomx_tpu.ps.message import Role
 from geomx_tpu.ps.postoffice import Postoffice
 from geomx_tpu.simulate import InProcessHiPS, free_port
@@ -412,3 +415,109 @@ def _run_launch(script: str, extra_args, n_iters: int, timeout: float,
         os.killpg(proc.pid, signal.SIGKILL)
         pytest.fail("background topology processes did not exit cleanly")
     return accs
+
+
+# ---------------------------------------------------------------------------
+# servers without sockets (as tests/test_server_protocol.py builds them)
+# ---------------------------------------------------------------------------
+
+KEY = 5
+
+
+class RecordingApp:
+    def __init__(self):
+        self.responses = []
+
+    def response(self, req, kvs=None, body=""):
+        self.responses.append((req, kvs))
+
+
+def push_req(sender, ts, compr, head=0, pull=True, num_merge=1,
+             trace_round=-1):
+    return ReqMeta(sender=sender, timestamp=ts, customer_id=0, push=True,
+                   pull=pull, simple_app=False, head=head, body="",
+                   priority=0, version=0, iters=0, compr=compr,
+                   num_merge=num_merge, trace_round=trace_round)
+
+
+def server_without_sockets(parties, is_global, fsa_slice_elems=0):
+    s = KVStoreDistServer.__new__(KVStoreDistServer)
+    s._lock = threading.RLock()
+    s._states, s._key_total = {}, {}
+    s._party_nsrv, s._party_nsrv_by_sender = 1, {}
+    s._fsa_slice_elems = fsa_slice_elems
+    s.is_global_server = is_global
+    s._tier = "global" if is_global else "local"
+    s.sync_global_mode = True
+    s.updater = s.ts_global = s.ts_local = None
+    s.use_hfa = False
+    s.gc = BSCCompressor(0.01)
+    s.cfg = types.SimpleNamespace(bigarray_bound=1 << 40, num_parties=0,
+                                  enable_central_worker=False)
+    # the vans name a server's round spans, no more
+    van = types.SimpleNamespace(round_args=lambda r: {"round": r},
+                                is_stale=lambda sender, epoch: False)
+    s.po_local = None if is_global else types.SimpleNamespace(van=van)
+    s.po_global = types.SimpleNamespace(
+        my_rank=0, num_servers=1, num_live_workers=lambda: parties,
+        van=van)
+    s._wan_trace = (-1, -1)
+    return s
+
+
+class RecordingGlobalWorker:
+    """The party server's client of the global tier: keeps what is
+    pushed and answers a push's callback with the responses it is given."""
+
+    def __init__(self):
+        self.pushed = []            # (kvs, g_rank, cb)
+        self.responses = {}
+
+    def push(self, kvs, g_rank, cb=None, **kw):
+        assert kw["pull"] and kw["party_nsrv"] == 1
+        self.pushed.append((kvs, g_rank, cb))
+
+    def take_failure(self, ts):
+        return None
+
+    def take_response(self, ts):
+        return self.responses.pop(ts)
+
+
+def party_server_without_sockets(workers, global_servers=1, n=768,
+                                 keys=None):
+    """A party server of ``workers`` workers below ``global_servers``
+    global servers that split every key evenly, ``keys`` (key -> its
+    elements; ``KEY`` of ``n`` by default) initialized; Bi-Sparse 0.01
+    on its forward."""
+    s = server_without_sockets(1, False)
+    s.has_global_tier = True
+    s.cfg.bigarray_bound = 1 if global_servers > 1 else 1 << 40
+    s.po_global.num_servers = global_servers
+    s.po_local = types.SimpleNamespace(
+        num_servers=1, num_live_workers=lambda: workers,
+        van=s.po_local.van)
+    s._wire = types.SimpleNamespace(enabled=lambda: False)
+    s._wire_wan = s._transport = None
+    s._fwd_tls = threading.local()
+    s.worker_global = RecordingGlobalWorker()
+    for key, size in ({KEY: n} if keys is None else keys).items():
+        st = s._state(key, 0)
+        st.stored = np.zeros(size, np.float32)
+        st.length = st.total = size
+        st.initialized = True
+    return s
+
+
+def party_batch_push(s, app, sender, ts, pushes, trace_round=-1):
+    """One worker's combined push+pull of several keys in ONE message on
+    the local tier, through the server's own ``_handle_data``: ``pushes``
+    maps a key to its ``(values, positions)`` on the ``bsc`` wire. The
+    rounds this completes go forward as one batch."""
+    keys = list(pushes)
+    sizes = [s._state(k, 0).total for k in keys]
+    kvs = KVPairs(keys=keys, vals=[pushes[k][0] for k in keys],
+                  aux=[pushes[k][1] for k in keys], offsets=[0] * len(keys),
+                  totals=sizes, lens=sizes, compr="bsc")
+    s._handle_data(push_req(sender, ts, "bsc", trace_round=trace_round),
+                   kvs, app, False, False)

@@ -6,10 +6,12 @@ momentum-corrected top-k with residual reset for BSC, residual-feedback
 """
 
 import logging
+from concurrent.futures import ThreadPoolExecutor
 
 import numpy as np
 import pytest
 
+from geomx_tpu import kernels_native
 from geomx_tpu.compression import (
     BSCCompressor,
     FP16Compressor,
@@ -22,6 +24,7 @@ from geomx_tpu.compression import (
     bsc_pull_compress,
     bsc_sample_boundary,
     bsc_sample_positions,
+    draw_ahead,
     make_compressor,
     takes_pairs,
     two_bit_dequantize,
@@ -235,6 +238,218 @@ def test_pairs_cut_and_order():
 ])
 def test_takes_pairs_is_the_host_bsc_pass_alone(params, n, want):
     assert takes_pairs(make_compressor(params), n) is want
+
+
+@pytest.mark.parametrize("params,n,want", [
+    ({"type": "bsc", "device": False}, 64, True),
+    ({"type": "mpq", "device": False, "size_lower_bound": 1000}, 999, False),
+    ({"type": "mpq", "device": False, "size_lower_bound": 1000}, 1000, True),
+    ({"type": "bsc", "device": True}, 10_000, False),
+    ({"type": "fp16"}, 10_000, False),
+    (None, 10_000, False),
+])
+def test_draw_ahead_is_for_the_host_bsc_pass_alone(params, n, want):
+    """Positions where ``compress_push`` of such a key runs the host
+    Bi-Sparse pass (``takes_pairs``), None for a compressor that keeps
+    its draw, or has none, to itself."""
+    gc = make_compressor(params)
+    pos = draw_ahead(gc, n, ("k", 0))
+    assert (pos is not None) is want is takes_pairs(gc, n)
+    if want:
+        ref = bsc_sample_positions(n, 0.01, np.random.default_rng(42))
+        np.testing.assert_array_equal(pos, ref)
+
+
+DRAWN_SIZES = [1, 40, 768, 2_000, 65_000, 300_000]
+
+
+@pytest.mark.parametrize("order", ["key_order", "reversed", "threads_1",
+                                   "threads_2", "threads_4"])
+@pytest.mark.parametrize("kind", ["bsc", "mpq"])
+def test_keys_drawn_ahead_compress_in_any_order(kind, order):
+    """Five rounds of seeded ``Pairs`` on keys of mixed sizes: every
+    key's sample drawn first, in key order, then the keys compressed
+    in another order or side by side: values, positions, ``u``, ``v``
+    and the generator's state are those of one ``compress_push`` a key
+    in key order. MPQ's small keys go fp16 and draw nothing."""
+    params = {"type": kind, "device": False, "size_lower_bound": 1000}
+    serial, ahead = make_compressor(params), make_compressor(params)
+    for rnd in range(5):
+        rng = np.random.default_rng(50 + rnd)
+        grads = []
+        for n in DRAWN_SIZES:
+            idx = rng.choice(n, max(n // 100, 1), replace=False)
+            grads.append(Pairs(idx.astype(np.int32),
+                               rng.standard_normal(idx.size).astype(
+                                   np.float32), n))
+        want = [serial.compress_push(
+            g if takes_pairs(serial, g.size) else g.dense(), (i, 0))
+            for i, g in enumerate(grads)]
+        drawn = [draw_ahead(ahead, g.size, (i, 0))
+                 for i, g in enumerate(grads)]
+
+        def one(i):
+            g = grads[i]
+            if drawn[i] is None:
+                return ahead.compress_push(g.dense(), (i, 0))
+            return ahead.compress_push(g, (i, 0), positions=drawn[i])
+
+        ids = range(len(grads))
+        if order.startswith("threads"):
+            with ThreadPoolExecutor(int(order[-1])) as pool:
+                got = list(pool.map(one, sorted(ids, key=lambda i:
+                                                -grads[i].size)))[::-1]
+        elif order == "reversed":
+            got = [one(i) for i in reversed(ids)][::-1]
+        else:
+            got = [one(i) for i in ids]
+        for (wv, wi, wt), (gv, gi, gt) in zip(want, got):
+            assert wt == gt and wv.dtype == gv.dtype
+            np.testing.assert_array_equal(wv.view(np.uint8).ravel(),
+                                          gv.view(np.uint8).ravel())
+            if wi is None:
+                assert gi is None
+            else:
+                np.testing.assert_array_equal(wi, gi)
+    a, b = (gc._bsc if kind == "mpq" else gc for gc in (serial, ahead))
+    assert list(a._u) == list(b._u)
+    for k in a._u:
+        assert a._u[k].tobytes() == b._u[k].tobytes()
+        assert a._v[k].tobytes() == b._v[k].tobytes()
+    assert a._rng.bit_generator.state == b._rng.bit_generator.state
+
+
+# ---------------------------------------------------------------------------
+# the Bi-Sparse pass as one native sweep (native/kernels.cc, gxk_bsc_*)
+# against the numpy passes, which stay in the tree as its fallback
+
+
+@pytest.fixture
+def numpy_passes(monkeypatch):
+    """-> a context in which ``bsc_compress`` runs its numpy passes."""
+    import contextlib
+
+    if kernels_native.lib() is None:
+        pytest.skip("no native kernels here (g++)")
+
+    @contextlib.contextmanager
+    def off():
+        with monkeypatch.context() as m:
+            m.setattr(kernels_native, "bsc_pass_usable", lambda u, v: False)
+            yield
+
+    return off
+
+
+def _sweep_case(case, n, rng):
+    k = max(n // 100, 1)
+    idx = rng.choice(n, k, replace=False).astype(np.int32)
+    vals = rng.standard_normal(k).astype(np.float32)
+    if case == "sorted":
+        idx.sort()
+    elif case == "repeated":        # a third of the positions twice
+        idx[:k // 3] = idx[k // 3:2 * (k // 3)]
+    elif case == "int64_edges":
+        idx = np.sort(idx).astype(np.int64)
+        idx[0], idx[-1] = 0, n - 1
+    elif case == "nan_and_inf":
+        vals[::7], vals[1::7] = np.nan, np.inf
+    elif case == "no_pairs":
+        idx, vals = idx[:0], vals[:0]
+    return Pairs(idx, vals, n)
+
+
+@pytest.mark.parametrize("case", ["sorted", "by_magnitude", "repeated",
+                                  "int64_edges", "nan_and_inf", "no_pairs",
+                                  "from_zero", "half_of_it"])
+@pytest.mark.parametrize("n", [kernels_native.MIN_N, 16_392, 100_003,
+                               1_000_000])
+def test_one_sweep_is_the_numpy_passes_bit_for_bit(numpy_passes, case, n):
+    """Four rounds a case: values, positions, ``u``, ``v`` and the
+    generator after the sweep are those of the numpy passes, from a
+    state of noise or of zeros (the first round's boundary of 0, where
+    the cap cuts the selection), at 1% and at 50%, with positions
+    sorted, as ``lax.top_k`` gives them, repeated, at both ends of the
+    key, and with values that are not finite."""
+    rng = np.random.default_rng(n % 1000 + len(case))
+    threshold = 0.5 if case == "half_of_it" else 0.01
+    if case in ("from_zero", "half_of_it"):
+        u, v = np.zeros(n, np.float32), np.zeros(n, np.float32)
+    else:
+        u = (0.01 * rng.standard_normal(n)).astype(np.float32)
+        v = rng.standard_normal(n).astype(np.float32)
+        u[::11] = -0.0              # keeps its sign through the decay
+    ru, rv = u.copy(), v.copy()
+    gen, rgen = np.random.default_rng(42), np.random.default_rng(42)
+    swept = []
+    real = kernels_native.bsc_sweep
+
+    def sweep(*args):
+        swept.append(args[0].size)
+        return real(*args)
+
+    for _ in range(4):
+        grad = _sweep_case(case, n, rng)
+        kernels_native.bsc_sweep = sweep
+        try:
+            vals, idx = bsc_compress(grad, u, v, threshold, rng=gen)
+        finally:
+            kernels_native.bsc_sweep = real
+        with numpy_passes():
+            rvals, ridx = bsc_compress(grad, ru, rv, threshold, rng=rgen)
+        assert vals.dtype == rvals.dtype == np.float32
+        assert idx.dtype == ridx.dtype == np.int32
+        assert vals.tobytes() == rvals.tobytes()
+        assert idx.tobytes() == ridx.tobytes()
+        assert u.tobytes() == ru.tobytes() and v.tobytes() == rv.tobytes()
+        assert gen.bit_generator.state == rgen.bit_generator.state
+    assert swept == [n] * 4         # the sweep ran, every round
+
+
+@pytest.mark.parametrize("why", ["small_key", "strided_state",
+                                 "read_only_state", "float64_state",
+                                 "dense_gradient", "position_past_the_key"])
+def test_the_sweep_is_passed_over_where_it_cannot_run(numpy_passes, why):
+    """A key under ``MIN_N`` elements, a state the library cannot be
+    handed, a dense gradient, pairs outside the key: the numpy passes
+    run, with their result or their error, and the library is not
+    called."""
+    n = 2_000 if why == "small_key" else 20_000
+    rng = np.random.default_rng(3)
+    grad = _sweep_case("by_magnitude", n, rng)
+    u = np.zeros(n, np.float32)
+    v = rng.standard_normal(n).astype(np.float32)
+    if why == "strided_state":
+        u = np.zeros(2 * n, np.float32)[::2]
+    elif why == "float64_state":
+        u = np.zeros(n, np.float64)
+    elif why == "dense_gradient":
+        grad = grad.dense()
+    elif why == "position_past_the_key":
+        grad.idx[5] = n
+    ru, rv = u.copy(), v.copy()
+    if why == "read_only_state":
+        v.setflags(write=False)
+
+    def never(*args):
+        raise AssertionError("the native sweep was called")
+
+    pos = bsc_sample_positions(n, 0.01, rng)
+    real = kernels_native.bsc_sweep
+    kernels_native.bsc_sweep = never
+    try:
+        if why in ("read_only_state", "position_past_the_key"):
+            with pytest.raises((ValueError, IndexError)):
+                bsc_compress(grad, u, v, 0.01, positions=pos)
+            return
+        vals, idx = bsc_compress(grad, u, v, 0.01, positions=pos)
+    finally:
+        kernels_native.bsc_sweep = real
+    with numpy_passes():
+        rvals, ridx = bsc_compress(grad, ru, rv, 0.01, positions=pos)
+    assert vals.tobytes() == rvals.tobytes()
+    assert idx.tobytes() == ridx.tobytes()
+    assert u.tobytes() == ru.tobytes() and v.tobytes() == rv.tobytes()
 
 
 def test_bsc_pull_compress_keeps_nonzeros():

@@ -22,12 +22,14 @@ import pytest
 from geomx_tpu import profiler
 from geomx_tpu.simulate import InProcessHiPS
 
-from tests.harness import SingleTier
+from tests.harness import (RecordingApp, SingleTier, party_batch_push,
+                           party_server_without_sockets)
 
 # span events a round of the benchmark's GPT-2 cells (150 keys, two
 # parties): two select spans a key and 43 others (counted in a traced
-# run of gpt2s-hips-bsc on the chip, PR 34)
-SPANS_A_ROUND_CELL_1 = 343
+# run of gpt2s-hips-bsc on the chip, PR 34), and since PR 37 one more
+# select span a party server, around its batch's draws and fan-out
+SPANS_A_ROUND_CELL_1 = 345
 OFF_BUDGET_S = 0.5e-3
 
 
@@ -42,16 +44,20 @@ class _Recorder:
     """Stands where ``jax.profiler.TraceAnnotation`` does."""
 
     opened = []
+    order = []          # ("open" | "close", span) as they happened
 
     def __init__(self, name, **args):
         self.name, self.args = name, dict(args)
 
     def __enter__(self):
+        self.thread = threading.get_ident()
         _Recorder.opened.append(self)
+        _Recorder.order.append(("open", self))
         return self
 
     def __exit__(self, *exc):
         self.closed = True
+        _Recorder.order.append(("close", self))
         return False
 
     def set_metadata(self, **args):
@@ -64,7 +70,7 @@ class _Recorder:
 
 @pytest.fixture
 def recorder(monkeypatch):
-    _Recorder.opened = []
+    _Recorder.opened, _Recorder.order = [], []
     monkeypatch.setattr(profiler, "_annotation", _Recorder)
     return _Recorder
 
@@ -144,6 +150,71 @@ def test_the_off_path_fits_a_round_of_cell_one():
     assert best < OFF_BUDGET_S, (
         f"{SPANS_A_ROUND_CELL_1} spans with no trace active took "
         f"{best * 1e6:.0f} us")
+
+
+@pytest.mark.parametrize("global_servers", [1, 2])
+def test_a_pooled_round_selects_under_server_select(recorder, monkeypatch,
+                                                    global_servers):
+    """A party server that re-selects its keys over a pool, the profiler
+    running: one ``server.select`` a forwarded (key, slice) with the
+    round's id and the server's node, whichever thread ran it, and one
+    around the fan-out on the thread that took the push, so that thread
+    is never under ``server.forward`` while a key is being selected:
+    its innermost span during the join is ``server.select``."""
+    from geomx_tpu.kvstore import server as server_mod
+
+    monkeypatch.setattr(server_mod, "_POOL_MIN_ELEMS", 10_000)
+    sizes = {3: 5_000, 7: 120_000, 9: 70_000, 12: 300, 13: 70_000}
+    s = party_server_without_sockets(1, global_servers, keys=sizes)
+    s.po_local.van.round_args = lambda r: {"round": r, "node": "l8p1"}
+    s._select_pool = server_mod._SelectPool(2)
+    rng = np.random.default_rng(3)
+    pushes = {}
+    for key, n in sizes.items():
+        idx = rng.choice(n, n // 100, replace=False).astype(np.int32)
+        pushes[key] = (rng.standard_normal(idx.size).astype(np.float32), idx)
+    # this thread's small keys wait until a thread of the pool is at work
+    me, helped, real = threading.get_ident(), threading.Event(), \
+        s.gc.compress_push
+
+    def compress_push(arr, state_key=None, **drawn):
+        if threading.get_ident() != me:
+            helped.set()
+        elif arr.size < 10_000:
+            assert helped.wait(30)
+        return real(arr, state_key, **drawn)
+
+    s.gc.compress_push = compress_push
+    profiler.set_state("run")
+    try:
+        party_batch_push(s, RecordingApp(), 9, 0, pushes, trace_round=41)
+    finally:
+        profiler.set_state("stop")
+        s._close_select_pool()
+    selects = [a for a in recorder.opened if a.name == "server.select"]
+    assert all(a.args == {"round": 41, "node": "l8p1"} and a.closed
+               for a in selects)
+    # the batch's own span, then one a (key, slice)
+    assert len(selects) == 1 + len(sizes) * global_servers
+    (forward,) = [a for a in recorder.opened if a.name == "server.forward"]
+    fanout = selects[0]
+    assert forward.thread == fanout.thread == me
+    at = {id(span): {} for _what, span in recorder.order}
+    for i, (what, span) in enumerate(recorder.order):
+        at[id(span)][what] = i
+
+    def inside(a, b):
+        return (at[id(b)]["open"] < at[id(a)]["open"]
+                and at[id(a)]["close"] < at[id(b)]["close"])
+
+    assert inside(fanout, forward)
+    assert all(inside(a, fanout) for a in selects[1:])
+    # the pool took part; what this thread opened under the forward is
+    # the fan-out and nothing beside it
+    assert {a.thread for a in selects} - {me}
+    mine = [a for a in recorder.opened
+            if a.thread == me and inside(a, forward)]
+    assert all(a is fanout or inside(a, fanout) for a in mine)
 
 
 def test_the_table_is_constant_names_in_known_layers():

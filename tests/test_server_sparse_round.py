@@ -16,6 +16,7 @@ and gives the parameters the numpy sum gives.
 """
 
 import logging
+import sys
 import threading
 import tracemalloc
 import types
@@ -29,98 +30,17 @@ from geomx_tpu.compression import (BSCCompressor, Entries, FP16Compressor,
                                    make_compressor, two_bit_dequantize,
                                    two_bit_quantize)
 from geomx_tpu.kvstore.base import DATA_INIT
+from geomx_tpu.kvstore import server as server_mod
 from geomx_tpu.kvstore.replication import ReplicationManager
-from geomx_tpu.kvstore.server import KVStoreDistServer
+from geomx_tpu.kvstore.server import _SelectPool
 from geomx_tpu.optimizer import SGD
-from geomx_tpu.ps.kv_app import KVPairs, ReqMeta
+from geomx_tpu.ps.kv_app import KVPairs
 from geomx_tpu.simulate import InProcessHiPS
-from tests.harness import SingleTier, _parallel
-
-KEY = 5
-
-
-# ---------------------------------------------------------------------------
-# servers without sockets (as tests/test_server_protocol.py builds them)
-# ---------------------------------------------------------------------------
-
-class RecordingApp:
-    def __init__(self):
-        self.responses = []
-
-    def response(self, req, kvs=None, body=""):
-        self.responses.append((req, kvs))
-
-
-def _req(sender, ts, compr, head=0, pull=True, num_merge=1):
-    return ReqMeta(sender=sender, timestamp=ts, customer_id=0, push=True,
-                   pull=pull, simple_app=False, head=head, body="",
-                   priority=0, version=0, iters=0, compr=compr,
-                   num_merge=num_merge)
-
-
-def _server(parties, is_global, fsa_slice_elems=0):
-    s = KVStoreDistServer.__new__(KVStoreDistServer)
-    s._lock = threading.RLock()
-    s._states, s._key_total = {}, {}
-    s._party_nsrv, s._party_nsrv_by_sender = 1, {}
-    s._fsa_slice_elems = fsa_slice_elems
-    s.is_global_server = is_global
-    s._tier = "global" if is_global else "local"
-    s.sync_global_mode = True
-    s.updater = s.ts_global = s.ts_local = None
-    s.use_hfa = False
-    s.gc = BSCCompressor(0.01)
-    s.cfg = types.SimpleNamespace(bigarray_bound=1 << 40, num_parties=0,
-                                  enable_central_worker=False)
-    # the vans name a server's round spans, no more
-    van = types.SimpleNamespace(round_args=lambda r: {"round": r})
-    s.po_local = None if is_global else types.SimpleNamespace(van=van)
-    s.po_global = types.SimpleNamespace(
-        my_rank=0, num_servers=1, num_live_workers=lambda: parties,
-        van=van)
-    s._wan_trace = (-1, -1)
-    return s
-
-
-class RecordingGlobalWorker:
-    """The party server's client of the global tier: keeps what is
-    pushed and answers a push's callback with the responses it is given."""
-
-    def __init__(self):
-        self.pushed = []            # (kvs, g_rank, cb)
-        self.responses = {}
-
-    def push(self, kvs, g_rank, cb=None, **kw):
-        assert kw["pull"] and kw["party_nsrv"] == 1
-        self.pushed.append((kvs, g_rank, cb))
-
-    def take_failure(self, ts):
-        return None
-
-    def take_response(self, ts):
-        return self.responses.pop(ts)
-
-
-def _party_server(workers, global_servers=1, n=768):
-    """A party server of ``workers`` workers below ``global_servers``
-    global servers that split every key evenly, key ``KEY`` of ``n``
-    elements initialized; Bi-Sparse 0.01 on its forward."""
-    s = _server(1, False)
-    s.has_global_tier = True
-    s.cfg.bigarray_bound = 1 if global_servers > 1 else 1 << 40
-    s.po_global.num_servers = global_servers
-    s.po_local = types.SimpleNamespace(
-        num_servers=1, num_live_workers=lambda: workers,
-        van=s.po_local.van)
-    s._wire = types.SimpleNamespace(enabled=lambda: False)
-    s._wire_wan = s._transport = None
-    s._fwd_tls = threading.local()
-    s.worker_global = RecordingGlobalWorker()
-    st = s._state(KEY, 0)
-    st.stored = np.zeros(n, np.float32)
-    st.length = st.total = n
-    st.initialized = True
-    return s
+from tests.harness import (KEY, RecordingApp, SingleTier, _parallel,
+                           party_batch_push,
+                           party_server_without_sockets as _party_server,
+                           push_req as _req,
+                           server_without_sockets as _server)
 
 
 def _party_push(s, app, sender, ts, wire, vals, idx, n, num_merge=1):
@@ -934,3 +854,256 @@ def test_dense_readers_of_a_sparse_store_get_the_aggregate():
         for st, d in zip(states, want):
             assert st.entries is not None
             np.testing.assert_array_equal(st.stored, d)
+
+
+# ---------------------------------------------------------------------------
+# one round's re-selection over a pool of threads: bit for bit the serial
+# result (a worker's combined push completes the round of all its keys in
+# one _handle_data call; _flush_forward_batch draws every key's boundary
+# sample first, in the batch's order, then compresses the large keys side
+# by side)
+# ---------------------------------------------------------------------------
+
+MIXED = {3: 5_000, 4: 40, 7: 120_000, 9: 70_000, 11: 1, 12: 300,
+         13: 70_000, 14: 33_000, 15: 2_048}
+ABOVE_EVERY_KEY = 1 << 30
+
+
+def _seeded_pushes(sizes, seed):
+    """Every key's selection of one round: 1% of its positions in the
+    order ``lax.top_k`` would give them."""
+    rng = np.random.default_rng(seed)
+    out = {}
+    for key, n in sizes.items():
+        idx = rng.choice(n, max(n // 100, 1), replace=False)
+        out[key] = (rng.standard_normal(idx.size).astype(np.float32),
+                    idx.astype(np.int32))
+    return out
+
+
+def _answer_forwards(s):
+    """Complete the round as a global server of this one party would:
+    every forwarded message answered with itself. Returns the messages."""
+    sent, s.worker_global.pushed = s.worker_global.pushed, []
+    for ts, (kvs, _g, cb) in enumerate(sent):
+        s.worker_global.responses[ts] = [kvs]
+        cb(ts)
+    return sent
+
+
+def _batched_rounds(monkeypatch, helpers, min_elems, global_servers=1,
+                    sizes=MIXED, rounds=5):
+    """``rounds`` rounds of one worker's combined pushes of ``sizes`` on
+    a party server that selects over ``helpers`` threads beside its own
+    (0: no pool, the serial path) -> (server, messages a round)."""
+    monkeypatch.setattr(server_mod, "_POOL_MIN_ELEMS", min_elems)
+    s = _party_server(1, global_servers, keys=sizes)
+    if helpers:
+        s._select_pool = _SelectPool(helpers)
+    sent = []
+    try:
+        for rnd in range(rounds):
+            party_batch_push(s, RecordingApp(), 9, rnd,
+                             _seeded_pushes(sizes, 1000 + rnd),
+                             trace_round=rnd)
+            sent.append(_answer_forwards(s))
+    finally:
+        s._close_select_pool()
+    return s, sent
+
+
+def _same_forwards(got, want):
+    assert len(got) == len(want)
+    for a, b in zip(got, want):
+        assert [g for _k, g, _c in a] == [g for _k, g, _c in b]
+        for (kvs, _g, _cb), (ref, _rg, _rcb) in zip(a, b):
+            _same_response(kvs, ref)
+
+
+def _same_compressor_state(got, want):
+    assert list(got._u) == list(want._u) and list(got._v) == list(want._v)
+    for k in want._u:
+        np.testing.assert_array_equal(_bits(got._u[k]), _bits(want._u[k]))
+        np.testing.assert_array_equal(_bits(got._v[k]), _bits(want._v[k]))
+    assert got._rng.bit_generator.state == want._rng.bit_generator.state
+
+
+@pytest.mark.parametrize("global_servers", [1, 2])
+@pytest.mark.parametrize("min_elems", [0, 10_000, ABOVE_EVERY_KEY])
+@pytest.mark.parametrize("helpers", [1, 2, 4])
+def test_pooled_selection_is_the_serial_one_bit_for_bit(
+        monkeypatch, helpers, min_elems, global_servers):
+    """Five rounds on keys of 1 to 120,000 elements: the messages to the
+    global tier (keys in the entries' order, positions, values), every
+    key's ``u`` and ``v`` and the boundary sample's generator are what
+    the pass without a pool leaves, whatever the pool's size and
+    whichever keys it is handed."""
+    serial, want = _batched_rounds(monkeypatch, 0, min_elems, global_servers)
+    pooled, got = _batched_rounds(monkeypatch, helpers, min_elems,
+                                  global_servers)
+    slices = sum(min(global_servers, n) for n in MIXED.values())
+    assert len(serial.gc._u) == slices
+    assert all(sum(len(kvs.keys) for kvs, _g, _c in rnd) == slices
+               for rnd in want)
+    _same_forwards(got, want)
+    _same_compressor_state(pooled.gc, serial.gc)
+
+
+def test_pooled_selection_under_a_short_switch_interval(monkeypatch):
+    """More threads than cores and the interpreter switching every 10
+    us: still the serial result, and every (key, round) counted once."""
+    sizes = {k: 3_000 + 517 * k for k in range(40)}
+    serial, want = _batched_rounds(monkeypatch, 0, 0, sizes=sizes, rounds=8)
+    telemetry.reset()
+    telemetry.enable(True)
+    was = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        pooled, got = _batched_rounds(monkeypatch, 24, 0, sizes=sizes,
+                                      rounds=8)
+        forward = _counters("server.sparse_forward_key_rounds")
+    finally:
+        sys.setswitchinterval(was)
+        telemetry.reset()
+    assert forward == len(sizes) * 8
+    _same_forwards(got, want)
+    _same_compressor_state(pooled.gc, serial.gc)
+
+
+@pytest.mark.parametrize("bad", [7, 12], ids=["pool_task", "calling_thread"])
+def test_a_selection_that_raises_surfaces_and_frees_its_key(monkeypatch, bad):
+    """A key whose compressor raises, on a thread of the pool (key 7,
+    the largest) or on the calling thread (key 12, a small one): the
+    error comes out of the forward, no key's lock stays held, and the
+    pool takes the next batch."""
+    monkeypatch.setattr(server_mod, "_POOL_MIN_ELEMS", 10_000)
+    s = _party_server(1, keys=MIXED)
+    s._select_pool = _SelectPool(2)
+    real = s.gc.compress_push
+
+    def compress_push(arr, state_key=None, **drawn):
+        if state_key[0] == bad:
+            raise RuntimeError(f"no selection for key {bad}")
+        return real(arr, state_key, **drawn)
+
+    try:
+        s.gc.compress_push = compress_push
+        with pytest.raises(RuntimeError, match=f"for key {bad}"):
+            party_batch_push(s, RecordingApp(), 9, 0,
+                             _seeded_pushes(MIXED, 1))
+        assert s.worker_global.pushed == []
+        states = {key: s._state(key, 0) for key in MIXED}
+
+        def free(st):       # from another thread: the lock is re-entrant
+            got = st.lock.acquire(blocking=False)
+            if got:
+                st.lock.release()
+            return got
+
+        assert all(_parallel([lambda st=st: free(st)
+                              for st in states.values()]))
+        # the round is still staged: forward it again, through the pool
+        s.gc.compress_push = real
+        s._flush_forward_batch([(key, 0, st.cycle)
+                                for key, st in states.items()])
+        (kvs, _g, _cb), = s.worker_global.pushed
+        assert kvs.keys == list(MIXED)
+        assert any(t.name.startswith("select") for t in
+                   threading.enumerate())
+    finally:
+        s._close_select_pool()
+
+
+@pytest.mark.parametrize("how", ["shutdown", "crash"])
+def test_no_pool_thread_outlives_the_server(monkeypatch, how):
+    monkeypatch.setattr(server_mod, "_POOL_MIN_ELEMS", 0)
+    s = _party_server(1, keys=MIXED)
+    s._select_pool = _SelectPool(4)
+    s._crashed, s._stop = False, threading.Event()
+    s.replication = types.SimpleNamespace(stop=lambda flush: None)
+    for po in (s.po_local, s.po_global):
+        po.finalize = lambda do_barrier: None
+        po.van.stop = lambda: None
+    before = set(threading.enumerate())
+    party_batch_push(s, RecordingApp(), 9, 0, _seeded_pushes(MIXED, 1))
+    want = _answer_forwards(s)
+    mine = set(threading.enumerate()) - before
+    assert mine and all(t.name.startswith("select") for t in mine)
+    getattr(s, how)()
+    assert not any(t.is_alive() for t in mine)
+    # a push that still arrives is selected on its own thread
+    party_batch_push(s, RecordingApp(), 9, 1, _seeded_pushes(MIXED, 2))
+    assert len(_answer_forwards(s)) == len(want) == 1
+    assert set(threading.enumerate()) <= before
+
+
+def _forwarded_by_a_topology(monkeypatch, pooled, rounds=3):
+    """Two parties x one worker, ``rounds`` Bi-Sparse rounds on keys of
+    mixed sizes -> what each party server sent to the global tier, as
+    bytes, in its order of sending."""
+    monkeypatch.setattr(server_mod, "_POOL_MIN_ELEMS", 2_000)
+    topo = InProcessHiPS(num_parties=2, workers_per_party=1).start()
+    keys = list(MIXED)
+    sent = {}
+    try:
+        party_servers = [s for s in topo.servers if s.has_global_tier]
+        assert len(party_servers) == 2
+        for p, srv in enumerate(party_servers):
+            assert isinstance(srv._select_pool, _SelectPool)
+            if not pooled:
+                srv._close_select_pool()
+                srv._select_pool = None
+            log = sent[p] = []
+
+            def push(kvs, g_rank, _real=srv.worker_global.push, _log=log,
+                     **kw):
+                _log.append((g_rank, kvs.compr, list(kvs.keys),
+                             list(kvs.offsets), list(kvs.lens),
+                             [np.asarray(v).tobytes() for v in kvs.vals],
+                             [np.asarray(a).tobytes() for a in kvs.aux]))
+                return _real(kvs, g_rank, **kw)
+
+            srv.worker_global.push = push
+
+        def master_init(kv):
+            kv.set_gradient_compression({"type": "bsc", "threshold": 0.05})
+            for k, n in MIXED.items():
+                kv.init(k, np.zeros(n, np.float32))
+            kv.wait()
+
+        def init(kv):
+            for k, n in MIXED.items():
+                kv.init(k, np.zeros(n, np.float32))
+                kv.pull(k, out=np.zeros(n, np.float32))
+            kv.wait()
+
+        topo.run_workers(init, include_master=master_init, timeout=60)
+
+        def train(kv):
+            p = topo.workers.index(kv)
+            for rnd in range(rounds):
+                sel = _seeded_pushes(MIXED, 77 * p + rnd)
+                kv.push_pull_bsc_batch(
+                    keys, [sel[k][0] for k in keys],
+                    [sel[k][1].astype(np.int64) for k in keys],
+                    timeout=60)()
+
+        topo.run_workers(train, timeout=120)
+    finally:
+        topo.stop()
+    assert not any(t.name.startswith("select") and t.is_alive()
+                   for t in threading.enumerate())
+    return sent
+
+
+@pytest.mark.parametrize("second", ["pooled", "serial"])
+def test_two_topologies_forward_the_same_bytes(monkeypatch, second):
+    """A live two-party topology run twice: each party server's messages
+    to the global tier are byte-equal, pool against pool and pool
+    against none, and the pool's threads are gone with the servers."""
+    first = _forwarded_by_a_topology(monkeypatch, pooled=True)
+    again = _forwarded_by_a_topology(monkeypatch, pooled=second == "pooled")
+    assert first == again
+    for p in (0, 1):
+        assert len(first[p]) == 3
+        assert all(msg[2] == list(MIXED) for msg in first[p])
