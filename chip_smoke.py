@@ -111,6 +111,19 @@ def check_sanity() -> None:
     say(f"chip_sanity ok ({out['wall_s']} s)")
 
 
+def _probe(attn):
+    """One program: ``attn``'s output (as aux) and dq, dk, dv of its
+    sum."""
+    import jax
+    import jax.numpy as jnp
+
+    def f(q, k, v):
+        out = attn(q, k, v).astype(jnp.float32)
+        return out.sum(), out
+
+    return jax.jit(jax.value_and_grad(f, argnums=(0, 1, 2), has_aux=True))
+
+
 def check_kernels(on_chip: bool) -> None:
     """Compile and run every Pallas kernel in the package against its
     reference at the shapes the repo uses; on the chip, require the
@@ -121,20 +134,11 @@ def check_kernels(on_chip: bool) -> None:
     from geomx_tpu.models.transformer import dense_attention
     from geomx_tpu.ops.flash_attention import flash_attention
 
-    def probe(attn):
-        """One program: output (as aux) and dq, dk, dv of its sum."""
-        def f(q, k, v):
-            out = attn(q, k, v).astype(jnp.float32)
-            return out.sum(), out
-
-        return jax.jit(jax.value_and_grad(f, argnums=(0, 1, 2),
-                                          has_aux=True))
-
     B, H, D = 2, 8, 64
     for T in (FLASH_SEQS if on_chip else (64,)):
         q, k, v = (jax.random.normal(jax.random.PRNGKey(i), (B, T, H, D),
                                      jnp.bfloat16) for i in range(3))
-        flash, dense = probe(flash_attention), probe(dense_attention)
+        flash, dense = _probe(flash_attention), _probe(dense_attention)
         # an interpreted kernel lowers to plain HLO: the Mosaic custom
         # call IS the proof that it compiled
         if on_chip and "tpu_custom_call" not in flash.lower(
@@ -154,6 +158,92 @@ def check_kernels(on_chip: bool) -> None:
         if not all(np.isfinite(e) and e < 0.03 for e in errs):
             raise RuntimeError(f"flash attention T={T} disagrees with the "
                                f"dense reference: {errs}")
+
+
+def check_attention_paths(on_chip: bool) -> None:
+    """The three families' full causal attention as their blocks call
+    it (``transformer.causal_attention``), at their head shapes and the
+    cells' length: on the chip the kernels must be in the block's own
+    lowering (a Mosaic call under the scope ``attention`` /
+    ``attention_full``) and agree with the dense product; off it the
+    rule must give the dense product."""
+    import jax
+    import jax.numpy as jnp
+
+    from geomx_tpu.models.laguna import LagunaBlock
+    from geomx_tpu.models.olmoe import OlmoeBlock
+    from geomx_tpu.models.qwen3_next import Qwen3NextBlock
+    from geomx_tpu.models.transformer import (causal_attention,
+                                              dense_attention,
+                                              grouped_attention)
+
+    T = 4096 if on_chip else 32
+    bf = jnp.bfloat16
+    rope = dict(rope_type="default", rope_theta=10000.0,
+                partial_rotary_factor=0.5)
+    blocks = {
+        "olmoe": OlmoeBlock(dim=256, heads=2, num_experts=4,
+                            experts_per_token=2, expert_width=64,
+                            local_experts=(0, 4), compute_dtype=bf),
+        "laguna": LagunaBlock(
+            dim=256, head_dim=128, kind="full_attention",
+            query_heads=(0, 6), key_value_heads=(0, 1), window=512,
+            rope=rope, sparse=False, dense_width=64, num_experts=4,
+            experts_per_token=2, expert_width=64, shared_width=64,
+            local_experts=(0, 4), routed_scale=1.0, compute_dtype=bf),
+        "qwen3next": Qwen3NextBlock(
+            dim=256, kind="full_attention", head_dim=256,
+            query_heads=(0, 8), key_value_heads=(0, 1), rope=rope,
+            linear_key_dim=8, linear_value_dim=8, linear_key_heads=(0, 1),
+            linear_value_heads=(0, 1), conv_kernel=4, num_experts=4,
+            experts_per_token=2, expert_width=64, shared_width=64,
+            local_experts=(0, 4), compute_dtype=bf),
+    }
+    x = jax.random.normal(jax.random.PRNGKey(0), (1, T, 256), jnp.float32)
+    for name, block in blocks.items():
+        variables = jax.eval_shape(block.init, jax.random.PRNGKey(1), x)
+        lowered = jax.jit(jax.grad(
+            lambda v, x: block.apply(v, x)[0].sum())).lower(variables, x)
+        calls = lowered.as_text().count("tpu_custom_call")
+        if on_chip != bool(calls):
+            raise RuntimeError(
+                f"{name} block at T={T}: {calls} Mosaic calls in the "
+                f"lowering, the rule should give "
+                f"{'the kernels' if on_chip else 'the dense product'}")
+        if on_chip:
+            # the compiled program names an operation's scopes; XLA's
+            # own grouped matmul is a Mosaic call too, under no scope
+            scope = "attention" if name == "olmoe" else "attention_full"
+            named = [re.search(r'op_name="([^"]*)"', line).group(1)
+                     for line in lowered.compile().as_text().splitlines()
+                     if "tpu_custom_call" in line and "pallas_call" in line]
+            if len(named) != calls or not all(
+                    f"/{scope}/" in n for n in named):
+                raise RuntimeError(f"{name} block: the kernels are not "
+                                   f"under the scope {scope!r}: {named}")
+        say(f"{name} block T={T}: {calls} Mosaic calls in the lowering")
+    if not on_chip:
+        return
+    shapes = {"olmoe": ((1, T, 16, 128), (1, T, 16, 128)),
+              "laguna": ((1, T, 1, 6, 128), (1, T, 1, 128)),
+              "qwen3next": ((1, T, 1, 8, 256), (1, T, 1, 256))}
+    for name, (q_shape, kv_shape) in shapes.items():
+        q = jax.random.normal(jax.random.PRNGKey(2), q_shape, bf)
+        k, v = (jax.random.normal(jax.random.PRNGKey(i), kv_shape, bf)
+                for i in (3, 4))
+        dense = grouped_attention if len(q_shape) == 5 else dense_attention
+        ((_s, out), got), ((_r, ref), want) = _probe(causal_attention)(
+            q, k, v), _probe(lambda q, k, v: dense(
+                q, k, v, scores_dtype=jnp.float32))(q, k, v)
+        got, want = (out, *got), (ref, *want)
+        errs = [float(jnp.linalg.norm((a - b).astype(jnp.float32))
+                      / jnp.linalg.norm(b.astype(jnp.float32)))
+                for a, b in zip(got, want)]
+        say(f"{name} attention T={T}: rel l2 err fwd/dq/dk/dv = "
+            f"{[round(e, 5) for e in errs]}")
+        if not all(np.isfinite(e) and e < 0.01 for e in errs):
+            raise RuntimeError(f"{name} attention on the kernels disagrees "
+                               f"with the dense product: {errs}")
 
 
 def run_round(shape: dict, steps: int, compiles, mesh_party: bool) -> None:
@@ -326,6 +416,7 @@ def main(argv=None) -> int:
     compiles = CompileCounter()
     check_sanity()
     check_kernels(on_chip=not args.rehearse)
+    check_attention_paths(on_chip=not args.rehearse)
     shape = TINY if args.rehearse else FULL
     run_round(shape, args.steps, compiles, mesh_party=False)
     if stamp["count"] >= 4:

@@ -4,9 +4,12 @@ CNN, examples/cnn.py:56-63).
 
 Decoders by module, not re-exported here: ``transformer`` (GPT-2 style;
 ``dense_attention``, and beside it ``grouped_attention`` and the blocked
-``window_attention`` for grouped queries, ``rotary`` positions over a
-part of a head and ``gated_attention``, the sigmoid-gated branch two
-families share), and the four users of ``moe.sparse_dispatch``:
+``window_attention`` for grouped queries, ``causal_attention``, which
+runs full causal attention as the Pallas kernels of
+``ops.flash_attention`` where ``runs_kernel`` says so, ``rotary``
+positions over a part of a head and ``gated_attention``, the
+sigmoid-gated branch two families share), and the four users of
+``moe.sparse_dispatch``:
 ``moe.MoEBlock`` (top-1), ``olmoe`` (softmax top-8 of 64), ``laguna``
 (window and full attention layers with their own head counts, a gated
 attention output, a dense first layer, a shared expert beside sigmoid

@@ -52,9 +52,9 @@ import jax.numpy as jnp
 from geomx_tpu.models.moe import gated_experts, sparse_dispatch
 from geomx_tpu.models.olmoe import HIGHEST, RMSNorm
 from geomx_tpu.models.transformer import (gated_attention,
-                                          grouped_attention,
-                                          rotary_frequencies, score_entries,
-                                          window_attention)
+                                          kernel_score_entries,
+                                          rotary_frequencies, runs_kernel,
+                                          score_entries)
 
 __all__ = ["Laguna", "LagunaBlock", "next_token_loss",
            "rotary_frequencies"]
@@ -111,11 +111,9 @@ class LagunaBlock(nn.Module):
             k, v = (dense(kv * hd, name=n)(h).reshape(b, t, kv, hd)
                     for n in ("k", "v"))
             gate = dense(heads * hd, name="gate")(h)
-            attend = partial(grouped_attention, scores_dtype=jnp.float32) \
-                if full else partial(window_attention, window=self.window,
-                                     scores_dtype=jnp.float32)
             x = x + dense(d, name="o")(gated_attention(
-                q, k, v, gate, attend, *rotary_frequencies(self.rope, hd)))
+                q, k, v, gate, *rotary_frequencies(self.rope, hd),
+                window=None if full else self.window))
         m = RMSNorm(self.eps, jnp.float32, name="n2")(x)
         if not self.sparse:
             with jax.named_scope("dense_ffn"):
@@ -172,14 +170,17 @@ class Laguna(nn.Module):
     eps: float = 1e-6
     compute_dtype: Any = jnp.float32
 
-    def counts(self, batch: int, t: int):
+    def counts(self, batch: int, t: int, kernel: bool = False):
         """What a pass over ``batch`` sequences of ``t`` positions has
         by shape: (all routed (token, slot) rows, live score entries,
         computed score entries), the entries over all layers and held
-        query heads."""
+        query heads; ``kernel``: the full layers run as the kernel
+        (``transformer.runs_kernel``), which computes its live blocks."""
         live = computed = 0
         for kind, (lo, hi) in zip(self.layer_types, self.query_heads):
             a, c = score_entries(t, None if kind == FULL else self.window)
+            if kernel and kind == FULL:
+                c = kernel_score_entries(t, self.head_dim)
             live, computed = live + (hi - lo) * a, computed + (hi - lo) * c
         sparse = sum(m == "sparse" for m in self.mlp_layer_types)
         return (batch * t * sparse * self.experts_per_token,
@@ -220,6 +221,7 @@ def next_token_loss(model: Laguna, variables, toks):
     logits, rows_local = model.apply(variables, toks[:, :-1])
     logp = jax.nn.log_softmax(logits)
     loss = -jnp.mean(jnp.take_along_axis(logp, toks[:, 1:, None], axis=-1))
-    by_shape = model.counts(toks.shape[0], toks.shape[1] - 1)
+    by_shape = model.counts(toks.shape[0], toks.shape[1] - 1,
+                            runs_kernel(toks[:, :-1]))
     return loss, jnp.stack([rows_local.astype(jnp.float32),
                             *(jnp.float32(c) for c in by_shape)])

@@ -38,7 +38,7 @@ import jax
 import jax.numpy as jnp
 
 from geomx_tpu.models.moe import gated_experts, sparse_dispatch
-from geomx_tpu.models.transformer import dense_attention
+from geomx_tpu.models.transformer import causal_attention
 
 __all__ = ["Olmoe", "OlmoeBlock", "next_token_loss"]
 
@@ -104,8 +104,7 @@ class OlmoeBlock(nn.Module):
             q = RMSNorm(self.eps, dt, name="q_norm")(q)
             k = RMSNorm(self.eps, dt, name="k_norm")(k)
             shp = (b, t, self.heads, d // self.heads)
-            attn = self.attn_fn or partial(dense_attention,
-                                           scores_dtype=jnp.float32)
+            attn = self.attn_fn or causal_attention
             o = attn(rope(q.reshape(shp), self.rope_theta),
                      rope(k.reshape(shp), self.rope_theta), v.reshape(shp))
             x = x + nn.Dense(d, use_bias=False, dtype=dt, name="o")(

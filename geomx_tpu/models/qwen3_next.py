@@ -64,8 +64,9 @@ import jax.numpy as jnp
 from geomx_tpu.models.moe import gated_experts, sparse_dispatch
 from geomx_tpu.models.olmoe import HIGHEST, RMSNorm
 from geomx_tpu.models.transformer import (gated_attention,
-                                          grouped_attention,
-                                          rotary_frequencies, score_entries)
+                                          kernel_score_entries,
+                                          rotary_frequencies, runs_kernel,
+                                          score_entries)
 from geomx_tpu.ops.gated_delta import chunks_of, gated_delta_rule
 
 __all__ = ["Qwen3Next", "Qwen3NextBlock", "GatedDeltaNet", "causal_conv",
@@ -206,10 +207,8 @@ class Qwen3NextBlock(nn.Module):
                     for n in ("k_proj", "v_proj"))
             q = ZeroCentredRMSNorm(self.eps, dt, name="q_norm")(q)
             k = ZeroCentredRMSNorm(self.eps, dt, name="k_norm")(k)
-            o = gated_attention(
-                q, k, v, gate.reshape(b, t, -1),
-                partial(grouped_attention, scores_dtype=jnp.float32),
-                *rotary_frequencies(self.rope, hd))
+            o = gated_attention(q, k, v, gate.reshape(b, t, -1),
+                                *rotary_frequencies(self.rope, hd))
             return dense(self.dim, name="o_proj")(o)
 
     @nn.compact
@@ -281,15 +280,19 @@ class Qwen3Next(nn.Module):
     eps: float = 1e-6
     compute_dtype: Any = jnp.float32
 
-    def counts(self, batch: int, t: int):
+    def counts(self, batch: int, t: int, kernel: bool = False):
         """What a pass over ``batch`` sequences of ``t`` positions has
         by shape: (all routed (token, slot) rows; live and computed
-        score entries of the full layers' held query heads; (token,
-        value head) pairs through the linear layers' recurrence; the
-        dependent chunk steps that takes, a sequence a loop)."""
+        score entries of the full layers' held query heads, ``kernel``:
+        as the kernel computes them, its live blocks
+        (``transformer.runs_kernel``); (token, value head) pairs
+        through the linear layers' recurrence; the dependent chunk
+        steps that takes, a sequence a loop)."""
         linear = sum(kind == LINEAR for kind in self.layer_types)
         full = len(self.layer_types) - linear
         live, computed = score_entries(t)
+        if kernel:
+            computed = kernel_score_entries(t, self.head_dim)
         heads = full * (self.query_heads[1] - self.query_heads[0])
         held = self.linear_value_heads[1] - self.linear_value_heads[0]
         return (batch * t * len(self.layer_types) * self.experts_per_token,
@@ -332,6 +335,7 @@ def next_token_loss(model: Qwen3Next, variables, toks):
     logits, rows_local = model.apply(variables, toks[:, :-1])
     logp = jax.nn.log_softmax(logits)
     loss = -jnp.mean(jnp.take_along_axis(logp, toks[:, 1:, None], axis=-1))
-    by_shape = model.counts(toks.shape[0], toks.shape[1] - 1)
+    by_shape = model.counts(toks.shape[0], toks.shape[1] - 1,
+                            runs_kernel(toks[:, :-1]))
     return loss, jnp.stack([rows_local.astype(jnp.float32),
                             *(jnp.float32(c) for c in by_shape)])

@@ -21,7 +21,8 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 def make_attention(impl: str = "auto", *, causal: bool = True,
                    mesh: Optional[Mesh] = None,
-                   block_q: int = 128, block_k: int = 128) -> Callable:
+                   block_q: Optional[int] = None,
+                   block_k: Optional[int] = None) -> Callable:
     """Attention implementation selector for ``Transformer(attn_fn=...)``.
 
     ``"flash"`` — the Pallas FlashAttention-2 kernels
@@ -89,6 +90,54 @@ def grouped_attention(q, k, v, *, scores_dtype=None):
     return jnp.einsum("bkgqj,bjkd->bqkgd", p.astype(v.dtype), v)
 
 
+# The length from which full causal attention runs as the kernel: read
+# on a v5e (tools/attention_bench.py lengths; PERF.md section 6, PR 39)
+KERNEL_MIN_T = 2048
+
+
+def runs_kernel(q, forced: Optional[bool] = None) -> bool:
+    """THE rule for the form of full causal attention over ``q``
+    [B, T, ...]: the Pallas kernels of ``ops/flash_attention.py`` where
+    they compile (``ops.pallas_interpret()`` false: a TPU backend), no
+    mesh is in play, and T is at or over :data:`KERNEL_MIN_T` (under it
+    a head's scores are a few MB and the dense product is as fast); the
+    dense product otherwise. It is one algorithm that wants another
+    form at another length, so the rule reads what the trace can see
+    and nothing names a model. A Pallas call has no partitioning rule,
+    so a mesh means dense: seen as the abstract mesh of the context
+    (``jax.set_mesh``, a ``shard_map`` body) or of ``q``'s own sharding
+    (an operand sharded over explicit axes). Operands that GSPMD shards
+    over ``Auto`` axes under a plain ``jit`` show no mesh while tracing:
+    such a caller passes its own ``attn_fn`` (:func:`make_attention`
+    with its mesh). ``forced`` is for tests: the answer itself."""
+    if forced is not None:
+        return forced
+    from geomx_tpu.ops import pallas_interpret
+
+    return (not pallas_interpret()
+            and jax.sharding.get_abstract_mesh().empty
+            and jax.typeof(q).sharding.mesh.empty
+            and q.shape[1] >= KERNEL_MIN_T)
+
+
+def causal_attention(q, k, v):
+    """Full causal attention with float32 scores, in the form
+    :func:`runs_kernel` gives: ``q, k, v`` [B, T, H, D]
+    (:func:`dense_attention`'s contract) or ``q`` [B, T, KV, G, D] on
+    ``k``, ``v`` [B, T, KV, D] (:func:`grouped_attention`'s). The
+    kernel's arithmetic is the dense product's: operands in their own
+    type, the products accumulated and the softmax run in float32,
+    the probabilities rounded to ``v``'s type before the second
+    product. The default of OLMoE's, Laguna's and Qwen3-Next's full
+    layers."""
+    if runs_kernel(q):
+        from geomx_tpu.ops.flash_attention import flash_attention
+
+        return flash_attention(q, k, v)
+    dense = grouped_attention if q.ndim == 5 else dense_attention
+    return dense(q, k, v, scores_dtype=jnp.float32)
+
+
 def window_blocks(t: int, window: int):
     """(block, number of blocks) of :func:`window_attention` at ``t``
     positions: a block is the window, or the sequence where that is
@@ -144,6 +193,16 @@ def score_entries(t: int, window: Optional[int] = None):
             nb * block * 2 * block)
 
 
+def kernel_score_entries(t: int, head_dim: int) -> int:
+    """Score entries the kernels compute for one head over one causal
+    sequence of ``t`` positions: the live blocks' area, at the blocks
+    they run with (``ops.flash_attention.attention_blocks``)."""
+    from geomx_tpu.ops.flash_attention import attention_blocks, live_blocks
+
+    block_q, block_k = attention_blocks(t, head_dim)
+    return live_blocks(t, block_q, block_k) * block_q * block_k
+
+
 def rotary_frequencies(rope, head_dim: int):
     """(inverse frequencies of the rotated pairs, float32; the factor on
     cos and sin) from one block of HF ``rope_parameters``: ``default``,
@@ -190,21 +249,29 @@ def rotary(x, inv_freq, factor: float):
     return jnp.concatenate([turned.astype(x.dtype), passed], -1)
 
 
-def gated_attention(q, k, v, gate, attend, inv_freq, factor: float):
+def gated_attention(q, k, v, gate, inv_freq, factor: float,
+                    window: Optional[int] = None):
     """An attention branch with rotary positions on the way in and an
     element-wise sigmoid gate on the way out (the form Qwen3-Next
     publishes; ``models/laguna.py``, ``models/qwen3_next.py``): ``q``
     [B, T, KV, G, D], ``k`` and ``v`` [B, T, KV, D], ``gate`` [B, T,
     KV * G * D] the gate BEFORE its sigmoid, from the layer's own normed
-    input. ``attend`` is the core (:func:`grouped_attention`,
-    :func:`window_attention`); it is computed again on the way back
-    (``jax.checkpoint``), so no [T, T] scores are kept. Returns
-    ``attend(rotary(q), rotary(k), v) * sigmoid(gate)`` [B, T, KV * G *
-    D], what the output projection takes."""
+    input. The core is :func:`window_attention` over ``window`` keys or,
+    with none, :func:`causal_attention`. A dense core is computed again
+    on the way back (``jax.checkpoint``), so no [T, T] scores are kept;
+    the kernel keeps q, k, v, o and the log-sum-exp only, so under it a
+    checkpoint would buy nothing and cost a forward kernel a pass.
+    Returns ``core(rotary(q), rotary(k), v) * sigmoid(gate)`` [B, T,
+    KV * G * D], what the output projection takes."""
     gate = nn.sigmoid(gate)
     q, k = rotary(q, inv_freq, factor), rotary(k, inv_freq, factor)
-    o = jax.checkpoint(attend)(q, k, v)
-    return o.reshape(gate.shape) * gate
+    if window is not None:
+        core = jax.checkpoint(lambda q, k, v: window_attention(
+            q, k, v, window, scores_dtype=jnp.float32))
+    else:
+        core = causal_attention if runs_kernel(q) \
+            else jax.checkpoint(causal_attention)
+    return core(q, k, v).reshape(gate.shape) * gate
 
 
 class Block(nn.Module):

@@ -33,19 +33,53 @@ from __future__ import annotations
 
 import functools
 
-__all__ = ["flash_attention", "make_sharded_flash_attention"]
+__all__ = ["flash_attention", "make_sharded_flash_attention",
+           "attention_blocks", "live_blocks"]
+
+
+def _round_up(x: int, m: int) -> int:
+    return -(-x // m) * m
+
+
+def attention_blocks(t: int, d: int):
+    """(block_q, block_k) of the kernels for ``t`` positions and heads
+    of size ``d``, from the two alone. Read on a v5e (PERF.md section 6,
+    PR 39; ``tools/attention_bench.py blocks``): a grid step costs the
+    same whatever its tile, so small tiles are bound by the grid (128 x
+    128 is five times slower than 512 x 512), and past 512 x 1024 the
+    gain is under 5% while the float32 score tile's temporaries grow
+    with the area and the causal diagonal wastes more of it. So 512
+    queries against 1,024 keys at heads up to 128, against 512 over
+    that (a head's K/V tile doubles with ``d``); a shorter sequence is
+    one block, rounded up to the sublane tile."""
+    block_q = min(512, _round_up(t, 8))
+    block_k = min(1024 if d <= 128 else 512, _round_up(t, 8))
+    return block_q, block_k
+
+
+def live_blocks(t: int, block_q: int, block_k: int) -> int:
+    """(q-block, k-block) pairs of a causal [t, t] product that hold an
+    entry the mask keeps: the tiles the kernels compute, every other one
+    is skipped. ``live_blocks * block_q * block_k`` are the score
+    entries a head computes a pass."""
+    nq, nk = -(-t // block_q), -(-t // block_k)
+    return sum(min(nk, -(-(i + 1) * block_q // block_k)) for i in range(nq))
 
 
 @functools.lru_cache(maxsize=None)
 def _kernels(Tq: int, Tk: int, D: int, block_q: int, block_k: int,
-             causal: bool, q_len: int, kv_len: int, interpret: bool):
+             causal: bool, q_len: int, kv_len: int, group: int,
+             interpret: bool):
     """Build (fwd, bwd_dq, bwd_dkv) pallas_calls for one static shape.
 
-    All three work on ``[B, H, T, D]``-transposed arrays. Grids are
-    (batch, head, outer-block, inner-block) with the inner dimension
-    iterated sequentially on-core, accumulating into VMEM scratch.
-    ``q_len`` <= Tq and ``kv_len`` <= Tk are the true (unpadded)
-    lengths; keys past ``kv_len`` are masked out.
+    All three work on ``[B, H, T, D]``-transposed arrays; ``k`` and
+    ``v`` have ``H / group`` heads, query head ``h`` reads key/value
+    head ``h // group``. Grids are (batch, head, outer-block,
+    inner-block) with the inner dimension iterated sequentially
+    on-core, accumulating into VMEM scratch (dK/dV: a key/value head's
+    ``group`` query heads are one more inner dimension, summed in the
+    same scratch). ``q_len`` <= Tq and ``kv_len`` <= Tk are the true
+    (unpadded) lengths; keys past ``kv_len`` are masked out.
     """
     import jax
     import jax.numpy as jnp
@@ -79,6 +113,48 @@ def _kernels(Tq: int, Tk: int, D: int, block_q: int, block_k: int,
             return True
         return kj * block_k < (qi + 1) * block_q + causal_offset
 
+    def _whole(qi, kj):
+        """Is every entry of the block valid (no mask to apply)?"""
+        inside = (kj + 1) * block_k <= kv_len
+        if not causal:
+            return inside
+        return inside & ((kj + 1) * block_k - 1
+                         <= qi * block_q + causal_offset)
+
+    def _on_live_block(qi, kj, body):
+        """Run ``body(mask or None)`` for a live block: the mask is
+        built only where the block crosses the diagonal or the padding."""
+        whole = _whole(qi, kj)
+
+        @pl.when(_live(qi, kj) & jnp.logical_not(whole))
+        def _():
+            body(_mask(qi, kj))
+
+        @pl.when(_live(qi, kj) & whole)
+        def _():
+            body(None)
+
+    # a dead block's tile takes the index of the live one beside it, so
+    # the pipeline sees no new block and issues no DMA for it
+    def _k_seen(qi, kj):
+        if not causal:
+            return kj
+        last = ((qi + 1) * block_q + causal_offset - 1) // block_k
+        return jnp.minimum(kj, jnp.clip(last, 0, nk - 1))
+
+    def _q_seen(qi, kj):
+        if not causal:
+            return qi
+        first = (kj * block_k - causal_offset) // block_q
+        return jnp.maximum(qi, jnp.clip(first, 0, nq - 1))
+
+    def _params(inner: int):
+        if interpret:
+            return {}
+        return dict(compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",) * 3 + ("arbitrary",) * inner,
+            vmem_limit_bytes=64 * 1024 * 1024))
+
     # -- forward ---------------------------------------------------------
     # grid (B, H, nq, nk): k-blocks innermost; acc/m/l scratch persists
     # across the k sweep for one q-block, finalized at the last k step.
@@ -93,8 +169,7 @@ def _kernels(Tq: int, Tk: int, D: int, block_q: int, block_k: int,
             m_ref[:] = jnp.full_like(m_ref, neg_inf)
             l_ref[:] = jnp.zeros_like(l_ref)
 
-        @pl.when(_live(qi, kj))
-        def _():
+        def body(mask):
             # matmul operands stay in the INPUT dtype (bf16 runs the MXU
             # at full rate; an up-front f32 cast would halve it) with
             # f32 accumulation; softmax math is f32
@@ -104,31 +179,35 @@ def _kernels(Tq: int, Tk: int, D: int, block_q: int, block_k: int,
             s = jax.lax.dot_general(
                 q, kb, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32) * scale
-            s = jnp.where(_mask(qi, kj), s, neg_inf)
-            m = m_ref[:, 0]
-            m_new = jnp.maximum(m, jnp.max(s, axis=1))
-            p = jnp.exp(s - m_new[:, None])
+            if mask is not None:
+                s = jnp.where(mask, s, neg_inf)
+            m = m_ref[:]
+            m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+            p = jnp.exp(s - m_new)
             alpha = jnp.exp(m - m_new)
-            l_ref[:, 0] = l_ref[:, 0] * alpha + jnp.sum(p, axis=1)
-            acc_ref[:] = acc_ref[:] * alpha[:, None] + jax.lax.dot_general(
+            l_ref[:] = l_ref[:] * alpha + jnp.sum(p, axis=1, keepdims=True)
+            acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
                 p.astype(vb.dtype), vb, (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
-            m_ref[:, 0] = m_new
+            m_ref[:] = m_new
+
+        _on_live_block(qi, kj, body)
 
         @pl.when(kj == nk - 1)
         def _():
-            l = l_ref[:, 0]
+            l = l_ref[:]
             # rows with no valid key (padding) have l == 0; emit zeros
             safe_l = jnp.where(l > 0.0, l, 1.0)
-            o_ref[0, 0] = (acc_ref[:] / safe_l[:, None]).astype(o_ref.dtype)
-            lse_ref[0, 0, :, 0] = m_ref[:, 0] + jnp.log(safe_l)
+            o_ref[0, 0] = (acc_ref[:] / safe_l).astype(o_ref.dtype)
+            lse_ref[0, 0] = m_ref[:] + jnp.log(safe_l)
 
     def fwd(q, k, v):
         B, H = q.shape[0], q.shape[1]
         qspec = pl.BlockSpec((1, 1, block_q, D),
                              lambda b, h, i, j: (b, h, i, 0))
-        kspec = pl.BlockSpec((1, 1, block_k, D),
-                             lambda b, h, i, j: (b, h, j, 0))
+        kspec = pl.BlockSpec(
+            (1, 1, block_k, D),
+            lambda b, h, i, j: (b, h // group, _k_seen(i, j), 0))
         return pl.pallas_call(
             fwd_kernel,
             grid=(B, H, nq, nk),
@@ -148,8 +227,23 @@ def _kernels(Tq: int, Tk: int, D: int, block_q: int, block_k: int,
                 pltpu.VMEM((block_q, 1), jnp.float32),
                 pltpu.VMEM((block_q, 1), jnp.float32),
             ],
-            interpret=interpret,
+            interpret=interpret, **_params(1),
         )(q, k, v)
+
+    def _p_and_ds(q, kb, vb, do, lse, delta, mask):
+        """A block's probabilities and score cotangents, recomputed
+        from the saved logsumexp: (p float32, ds in the operands'
+        type)."""
+        s = jax.lax.dot_general(
+            q, kb, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale
+        p = jnp.exp(s - lse)
+        if mask is not None:
+            p = jnp.where(mask, p, 0.0)
+        dp = jax.lax.dot_general(
+            do, vb, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        return p, (p * (dp - delta) * scale).astype(kb.dtype)
 
     # -- backward: dQ (accumulates over k-blocks) ------------------------
 
@@ -161,25 +255,15 @@ def _kernels(Tq: int, Tk: int, D: int, block_q: int, block_k: int,
         def _():
             acc_ref[:] = jnp.zeros_like(acc_ref)
 
-        @pl.when(_live(qi, kj))
-        def _():
-            q = q_ref[0, 0]
-            do = do_ref[0, 0]
-            lse = lse_ref[0, 0, :, 0]
-            delta = delta_ref[0, 0, :, 0]
+        def body(mask):
             kb = k_ref[0, 0]
-            vb = v_ref[0, 0]
-            s = jax.lax.dot_general(
-                q, kb, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) * scale
-            p = jnp.where(_mask(qi, kj), jnp.exp(s - lse[:, None]), 0.0)
-            dp = jax.lax.dot_general(
-                do, vb, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            ds = (p * (dp - delta[:, None]) * scale).astype(kb.dtype)
+            _, ds = _p_and_ds(q_ref[0, 0], kb, v_ref[0, 0], do_ref[0, 0],
+                              lse_ref[0, 0], delta_ref[0, 0], mask)
             acc_ref[:] = acc_ref[:] + jax.lax.dot_general(
                 ds, kb, (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
+
+        _on_live_block(qi, kj, body)
 
         @pl.when(kj == nk - 1)
         def _():
@@ -189,8 +273,9 @@ def _kernels(Tq: int, Tk: int, D: int, block_q: int, block_k: int,
         B, H = q.shape[0], q.shape[1]
         qspec = pl.BlockSpec((1, 1, block_q, D),
                              lambda b, h, i, j: (b, h, i, 0))
-        kspec = pl.BlockSpec((1, 1, block_k, D),
-                             lambda b, h, i, j: (b, h, j, 0))
+        kspec = pl.BlockSpec(
+            (1, 1, block_k, D),
+            lambda b, h, i, j: (b, h // group, _k_seen(i, j), 0))
         vspec = pl.BlockSpec((1, 1, block_q, 1),
                              lambda b, h, i, j: (b, h, i, 0))
         return pl.pallas_call(
@@ -200,86 +285,82 @@ def _kernels(Tq: int, Tk: int, D: int, block_q: int, block_k: int,
             out_specs=qspec,
             out_shape=jax.ShapeDtypeStruct((B, H, Tq, D), q.dtype),
             scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
-            interpret=interpret,
+            interpret=interpret, **_params(1),
         )(q, k, v, do, lse, delta)
 
-    # -- backward: dK, dV (accumulates over q-blocks) --------------------
+    # -- backward: dK, dV (accumulates over a key/value head's query
+    # heads and their q-blocks) ------------------------------------------
 
     def dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                    dk_ref, dv_ref, dk_acc, dv_acc):
-        kj, qi = pl.program_id(2), pl.program_id(3)
+        kj, g, qi = pl.program_id(2), pl.program_id(3), pl.program_id(4)
 
-        @pl.when(qi == 0)
+        @pl.when((g == 0) & (qi == 0))
         def _():
             dk_acc[:] = jnp.zeros_like(dk_acc)
             dv_acc[:] = jnp.zeros_like(dv_acc)
 
-        @pl.when(_live(qi, kj))
-        def _():
-            kb = k_ref[0, 0]
-            vb = v_ref[0, 0]
+        def body(mask):
             qb = q_ref[0, 0]
             dob = do_ref[0, 0]
-            lse = lse_ref[0, 0, :, 0]
-            delta = delta_ref[0, 0, :, 0]
-            s = jax.lax.dot_general(
-                qb, kb, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) * scale
-            p = jnp.where(_mask(qi, kj), jnp.exp(s - lse[:, None]), 0.0)
-            pb = p.astype(dob.dtype)
+            p, ds = _p_and_ds(qb, k_ref[0, 0], v_ref[0, 0], dob,
+                              lse_ref[0, 0], delta_ref[0, 0], mask)
             dv_acc[:] = dv_acc[:] + jax.lax.dot_general(
-                pb, dob, (((0,), (0,)), ((), ())),
+                p.astype(dob.dtype), dob, (((0,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
-            dp = jax.lax.dot_general(
-                dob, vb, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            ds = (p * (dp - delta[:, None]) * scale).astype(qb.dtype)
             dk_acc[:] = dk_acc[:] + jax.lax.dot_general(
                 ds, qb, (((0,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
 
-        @pl.when(qi == nq - 1)
+        _on_live_block(qi, kj, body)
+
+        @pl.when((g == group - 1) & (qi == nq - 1))
         def _():
             dk_ref[0, 0] = dk_acc[:].astype(dk_ref.dtype)
             dv_ref[0, 0] = dv_acc[:].astype(dv_ref.dtype)
 
     def bwd_dkv(q, k, v, do, lse, delta):
-        B, H = q.shape[0], q.shape[1]
-        qspec = pl.BlockSpec((1, 1, block_q, D),
-                             lambda b, h, j, i: (b, h, i, 0))
+        B, KV = k.shape[0], k.shape[1]
+        qspec = pl.BlockSpec(
+            (1, 1, block_q, D),
+            lambda b, h, j, g, i: (b, h * group + g, _q_seen(i, j), 0))
         kspec = pl.BlockSpec((1, 1, block_k, D),
-                             lambda b, h, j, i: (b, h, j, 0))
-        vspec = pl.BlockSpec((1, 1, block_q, 1),
-                             lambda b, h, j, i: (b, h, i, 0))
+                             lambda b, h, j, g, i: (b, h, j, 0))
+        vspec = pl.BlockSpec(
+            (1, 1, block_q, 1),
+            lambda b, h, j, g, i: (b, h * group + g, _q_seen(i, j), 0))
         return pl.pallas_call(
             dkv_kernel,
-            grid=(B, H, nk, nq),
+            grid=(B, KV, nk, group, nq),
             in_specs=[qspec, kspec, kspec, qspec, vspec, vspec],
             out_specs=[kspec, kspec],
-            out_shape=[jax.ShapeDtypeStruct((B, H, Tk, D), k.dtype),
-                       jax.ShapeDtypeStruct((B, H, Tk, D), v.dtype)],
+            out_shape=[jax.ShapeDtypeStruct((B, KV, Tk, D), k.dtype),
+                       jax.ShapeDtypeStruct((B, KV, Tk, D), v.dtype)],
             scratch_shapes=[pltpu.VMEM((block_k, D), jnp.float32),
                             pltpu.VMEM((block_k, D), jnp.float32)],
-            interpret=interpret,
+            interpret=interpret, **_params(2),
         )(q, k, v, do, lse, delta)
 
     return fwd, bwd_dq, bwd_dkv
 
 
-def _round_up(x: int, m: int) -> int:
-    return -(-x // m) * m
-
-
-def flash_attention(q, k, v, *, causal: bool = True, block_q: int = 128,
-                    block_k: int = 128):
-    """Memory-efficient exact attention; drop-in for ``dense_attention``.
+def flash_attention(q, k, v, *, causal: bool = True, block_q=None,
+                    block_k=None):
+    """Memory-efficient exact attention; drop-in for ``dense_attention``
+    and, with grouped queries, for ``grouped_attention``.
 
     ``q, k, v``: ``[B, T, H, D]`` (q and k/v sequence lengths may
     differ; with ``causal`` the queries are taken as the LAST ``Tq``
     positions of the key sequence — the kv-cache decode convention).
-    Scores are scaled by ``1/sqrt(D)``. Differentiable via a custom VJP
-    whose backward runs as Pallas kernels (probabilities recomputed
-    from the saved logsumexp — no quadratic residual).
+    Grouped queries: ``k`` and ``v`` ``[B, T, KV, D]`` under ``q``
+    ``[B, T, KV * G, D]`` or ``[B, T, KV, G, D]`` (the return has q's
+    shape): query head ``h`` reads key/value head ``h // G``, which is
+    never repeated; dK and dV are summed over the group inside the
+    kernel. Scores are scaled by ``1/sqrt(D)``. ``block_q`` /
+    ``block_k``: :func:`attention_blocks` unless given. Differentiable
+    via a custom VJP whose backward runs as Pallas kernels
+    (probabilities recomputed from the saved logsumexp — no quadratic
+    residual).
 
     NOTE for multi-device use: a Pallas kernel has no SPMD partitioning
     rule, so under jit with sharded operands it must be wrapped in
@@ -291,68 +372,66 @@ def flash_attention(q, k, v, *, causal: bool = True, block_q: int = 128,
 
     from geomx_tpu.ops import pallas_interpret
 
+    if q.ndim == 5:
+        b, t, kv, g, d = q.shape
+        return flash_attention(
+            q.reshape(b, t, kv * g, d), k, v, causal=causal,
+            block_q=block_q, block_k=block_k).reshape(q.shape)
     if q.ndim != 4:
         raise ValueError(f"expected [B, T, H, D] tensors, got {q.shape}")
     Tq, Tk = q.shape[1], k.shape[1]
+    group = q.shape[2] // k.shape[2]
+    if k.shape != v.shape or q.shape[2] != group * k.shape[2]:
+        raise ValueError(f"query heads {q.shape} are not groups of the "
+                         f"key/value heads {k.shape}, {v.shape}")
     if causal and Tq > Tk:
         # no decode-convention alignment exists for more queries than
         # keys; without this check, q rows with zero visible keys would
         # silently emit the value-block mean (online-softmax artifact)
         raise ValueError(
             f"causal attention needs Tq <= Tk, got Tq={Tq} > Tk={Tk}")
-    bq, bk = min(block_q, _round_up(Tq, 8)), min(block_k, _round_up(Tk, 8))
-    interpret = pallas_interpret()
+    D = q.shape[3]
+    bq = min(block_q or attention_blocks(Tq, D)[0], _round_up(Tq, 8))
+    bk = min(block_k or attention_blocks(Tk, D)[1], _round_up(Tk, 8))
+    Tqp, Tkp = _round_up(Tq, bq), _round_up(Tk, bk)
+    fwd, bwd_dq, bwd_dkv = _kernels(Tqp, Tkp, D, bq, bk, causal, Tq, Tk,
+                                    group, pallas_interpret())
 
     @jax.custom_vjp
     def _attn(q, k, v):
         return _attn_fwd(q, k, v)[0]
 
-    def _to_bhtd(x):
-        return jnp.transpose(x, (0, 2, 1, 3))
+    def _to_bhtd(x, t_to):
+        x = jnp.transpose(x, (0, 2, 1, 3))
+        return jnp.pad(x, ((0, 0), (0, 0), (0, t_to - x.shape[2]), (0, 0)))
 
-    def _pad_t(x, t_to):
-        pad = t_to - x.shape[2]
-        if pad == 0:
-            return x
-        return jnp.pad(x, ((0, 0), (0, 0), (0, pad), (0, 0)))
+    def _to_bthd(x, t):
+        return jnp.transpose(x[:, :, :t], (0, 2, 1, 3))
 
     def _attn_fwd(q, k, v):
-        qt, kt, vt = _to_bhtd(q), _to_bhtd(k), _to_bhtd(v)
-        Tqp, Tkp = _round_up(Tq, bq), _round_up(Tk, bk)
-        qt, kt, vt = _pad_t(qt, Tqp), _pad_t(kt, Tkp), _pad_t(vt, Tkp)
-        fwd, _, _ = _kernels(Tqp, Tkp, q.shape[3], bq, bk, causal, Tq,
-                             Tk, interpret)
-        o, lse = fwd(qt, kt, vt)
-        out = jnp.transpose(o[:, :, :Tq], (0, 2, 1, 3))
+        o, lse = fwd(_to_bhtd(q, Tqp), _to_bhtd(k, Tkp), _to_bhtd(v, Tkp))
+        out = _to_bthd(o, Tq)
         return out, (q, k, v, out, lse[:, :, :Tq, 0])
 
     def _attn_bwd(res, g):
         q, k, v, out, lse = res
-        qt, kt, vt = _to_bhtd(q), _to_bhtd(k), _to_bhtd(v)
-        dot, ot = _to_bhtd(g), _to_bhtd(out)
-        Tqp, Tkp = _round_up(Tq, bq), _round_up(Tk, bk)
-        delta = jnp.sum(dot.astype(jnp.float32) * ot.astype(jnp.float32),
-                        axis=-1)                       # [B, H, Tq]
-        if Tqp != Tq:
-            pad = ((0, 0), (0, 0), (0, Tqp - Tq))
-            delta = jnp.pad(delta, pad)
-            lse = jnp.pad(lse, pad)
-        qt, dot = _pad_t(qt, Tqp), _pad_t(dot, Tqp)
-        kt, vt = _pad_t(kt, Tkp), _pad_t(vt, Tkp)
-        _, bwd_dq, bwd_dkv = _kernels(Tqp, Tkp, q.shape[3], bq, bk,
-                                      causal, Tq, Tk, interpret)
-        lse4, delta4 = lse[..., None], delta[..., None]
-        dq = bwd_dq(qt, kt, vt, dot, lse4, delta4)
-        dk, dv = bwd_dkv(qt, kt, vt, dot, lse4, delta4)
-        tr = lambda x, t: jnp.transpose(x[:, :, :t], (0, 2, 1, 3))
-        return tr(dq, Tq), tr(dk, Tk), tr(dv, Tk)
+        dot = _to_bhtd(g, Tqp)
+        delta = jnp.sum(dot.astype(jnp.float32)
+                        * _to_bhtd(out, Tqp).astype(jnp.float32),
+                        axis=-1, keepdims=True)         # [B, H, Tqp, 1]
+        lse = jnp.pad(lse, ((0, 0), (0, 0), (0, Tqp - Tq)))[..., None]
+        args = (_to_bhtd(q, Tqp), _to_bhtd(k, Tkp), _to_bhtd(v, Tkp), dot,
+                lse, delta)
+        dk, dv = bwd_dkv(*args)
+        return _to_bthd(bwd_dq(*args), Tq), _to_bthd(dk, Tk), _to_bthd(
+            dv, Tk)
 
     _attn.defvjp(_attn_fwd, _attn_bwd)
     return _attn(q, k, v)
 
 
 def make_sharded_flash_attention(mesh, *, causal: bool = True,
-                                 block_q: int = 128, block_k: int = 128):
+                                 block_q=None, block_k=None):
     """shard_map-wrap :func:`flash_attention` over ``mesh`` (dp/tp).
 
     A Pallas kernel has no SPMD partitioning rule, so under jit with

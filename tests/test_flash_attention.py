@@ -167,3 +167,138 @@ def test_causal_rejects_more_queries_than_keys():
     k = _rand((1, 12, 1, 8), 1)
     with pytest.raises(ValueError, match="Tq <= Tk"):
         flash_attention(q, k, k, causal=True, block_q=8, block_k=8)
+
+
+# -- grouped queries, the blocks, the rule (PR 39) ---------------------------
+
+# what the benchmark's cells pass a head: (name, q shape, k/v shape)
+CELL_SHAPES = [
+    ("olmoe", (1, 4096, 16, 128), (1, 4096, 16, 128)),
+    ("laguna", (1, 4096, 1, 6, 128), (1, 4096, 1, 128)),
+    ("qwen3next", (1, 4096, 1, 8, 256), (1, 4096, 1, 256)),
+    ("gpt2", (8, 1023, 12, 64), (8, 1023, 12, 64)),
+]
+
+
+@pytest.mark.parametrize("t,block", [(512, 128), (200, 64)],
+                         ids=["T512", "T200_ragged"])
+@pytest.mark.parametrize("kv,group,d", [(1, 6, 128), (1, 8, 256),
+                                        (1, 8, 128), (1, 6, 256),
+                                        (2, 3, 16)])
+def test_grouped_queries_match_grouped_attention(kv, group, d, t, block):
+    """``grouped_attention``'s contract served by the kernel: query head
+    h reads key/value head h // G, dK and dV summed over the group
+    inside the kernel; outputs and the gradients of q, k, v."""
+    from geomx_tpu.models.transformer import grouped_attention
+
+    q = _rand((1, t, kv, group, d), 0)
+    k, v = _rand((1, t, kv, d), 1), _rand((1, t, kv, d), 2)
+
+    def kernel(q, k, v):
+        return flash_attention(q, k, v, block_q=block, block_k=block)
+
+    want, back_want = jax.vjp(grouped_attention, q, k, v)
+    got, back_got = jax.vjp(kernel, q, k, v)
+    assert got.shape == q.shape
+    np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-5)
+    cot = _rand(want.shape, 3)
+    for name, a, b in zip("qkv", back_got(cot), back_want(cot)):
+        np.testing.assert_allclose(a, b, atol=2e-4, rtol=2e-4,
+                                   err_msg=f"d{name}")
+
+
+def test_grouped_queries_in_the_flat_head_layout_and_unequal_blocks():
+    """[B, T, KV * G, D] on [B, T, KV, D], block_q != block_k, bfloat16
+    operands against the dense path with float32 scores."""
+    from geomx_tpu.models.transformer import grouped_attention
+
+    q = _rand((2, 96, 2, 2, 32), 0, jnp.bfloat16)
+    k, v = (_rand((2, 96, 2, 32), i, jnp.bfloat16) for i in (1, 2))
+    want = grouped_attention(q, k, v, scores_dtype=jnp.float32)
+    got = flash_attention(q.reshape(2, 96, 4, 32), k, v, block_q=32,
+                          block_k=64)
+    np.testing.assert_allclose(
+        np.asarray(got, np.float32).reshape(want.shape),
+        np.asarray(want, np.float32), atol=2e-2, rtol=2e-2)
+    with pytest.raises(ValueError, match="not groups"):
+        flash_attention(q.reshape(2, 96, 4, 32), k[:, :, :1].repeat(3, 2),
+                        v[:, :, :1].repeat(3, 2))
+
+
+@pytest.mark.parametrize("name,q_shape,_kv", CELL_SHAPES,
+                         ids=[s[0] for s in CELL_SHAPES])
+def test_the_blocks_of_the_cells_shapes(name, q_shape, _kv):
+    from geomx_tpu.ops.flash_attention import attention_blocks
+
+    want = {"olmoe": (512, 1024), "laguna": (512, 1024),
+            "qwen3next": (512, 512), "gpt2": (512, 1024)}[name]
+    assert attention_blocks(q_shape[1], q_shape[-1]) == want
+    # a sequence shorter than a block is one block, whole sublanes
+    assert attention_blocks(37, q_shape[-1]) == (40, 40)
+
+
+@pytest.mark.parametrize("name,q_shape,_kv", CELL_SHAPES,
+                         ids=[s[0] for s in CELL_SHAPES])
+def test_the_rule_by_backend_length_and_mesh(name, q_shape, _kv,
+                                             monkeypatch):
+    """``runs_kernel``: dense wherever Pallas is interpreted (this
+    backend); where it compiles, the kernel at the 4,096-token shapes
+    and dense at GPT-2's length; dense again under a mesh, as a context
+    or as the operand's own sharding."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    import geomx_tpu.ops
+    from geomx_tpu.models.transformer import KERNEL_MIN_T, runs_kernel
+
+    def answer(x, under=lambda f: f):
+        """The rule's answer while ``x`` is traced (a fresh function a
+        call: a trace that is cached asks nothing)."""
+        out = []
+
+        def ask(q):
+            out.append(runs_kernel(q))
+            return q
+
+        jax.jit(under(ask)).lower(x)
+        return out[0]
+
+    q = jax.ShapeDtypeStruct(q_shape, jnp.bfloat16)
+    assert not answer(q)                # a CPU backend
+    monkeypatch.setattr(geomx_tpu.ops, "pallas_interpret", lambda: False)
+    assert answer(q) == (q_shape[1] >= KERNEL_MIN_T) == (name != "gpt2")
+    assert runs_kernel(q, forced=True) and not runs_kernel(q, forced=False)
+    mesh = jax.make_mesh((2, 2), ("dp", "tp"))
+    with jax.set_mesh(mesh):
+        assert not answer(q)
+    sharded = jax.ShapeDtypeStruct(
+        (2,) + q_shape[1:], jnp.bfloat16,
+        sharding=NamedSharding(mesh, P("dp")))
+    assert not answer(sharded)
+    assert not answer(sharded, lambda f: jax.shard_map(
+        f, mesh=mesh, in_specs=P("dp"), out_specs=P("dp")))
+
+
+@pytest.mark.parametrize("t,block_q,block_k", [
+    (4096, 512, 1024), (4096, 512, 512), (4096, 128, 128), (1023, 512, 1024),
+    (520, 512, 256), (200, 64, 32), (37, 40, 40)])
+def test_computed_entries_are_the_live_blocks(t, block_q, block_k):
+    """The count the models book against a brute count: the blocks of
+    the padded [T, T] square that hold an entry the causal mask keeps."""
+    from geomx_tpu.ops.flash_attention import live_blocks
+
+    nq, nk = -(-t // block_q), -(-t // block_k)
+    mask = np.zeros((nq * block_q, nk * block_k), bool)
+    mask[:t, :t] = np.tril(np.ones((t, t), bool))
+    brute = mask.reshape(nq, block_q, nk, block_k).any(axis=(1, 3)).sum()
+    assert live_blocks(t, block_q, block_k) == brute
+
+
+def test_kernel_score_entries_at_the_cells_shapes():
+    from geomx_tpu.models.transformer import (kernel_score_entries,
+                                              score_entries)
+
+    # 20 live tiles of 32 at 512 x 1,024; 36 of 64 at 512 x 512
+    assert kernel_score_entries(4096, 128) == 20 * 512 * 1024 == 10_485_760
+    assert kernel_score_entries(4096, 256) == 36 * 512 * 512 == 9_437_184
+    live, dense = score_entries(4096)
+    assert live < kernel_score_entries(4096, 128) < dense

@@ -346,6 +346,71 @@ def test_eight_head_shares_sum_to_the_attention_branch(kind):
             _tree(flat), x)
 
 
+def _on_the_kernel(monkeypatch):
+    """Force ``transformer.runs_kernel`` to the kernel (its test-only
+    argument): here the kernels run interpreted."""
+    from functools import partial
+
+    from geomx_tpu.models import transformer
+
+    monkeypatch.setattr(transformer, "runs_kernel", partial(
+        transformer.runs_kernel, forced=True))
+
+
+def test_a_full_block_on_the_kernel_is_the_dense_block(monkeypatch):
+    """The full layer with its core as the Pallas kernel against the
+    same block on the dense [T, T] product: the block's output and every
+    parameter's gradient."""
+    flat = _layer_params(dict(WHOLE, layer_types=["full_attention"],
+                              mlp_layer_types=["dense"]))
+    x = jnp.asarray(np.random.default_rng(4).normal(size=(2, 21, 32)),
+                    jnp.float32)
+    block = _block("full_attention", False, (0, 16), (0, 8), (0, 8))
+
+    def loss(variables):
+        out = block.apply(variables, x)[0]
+        return jnp.sum(jnp.sin(out)), out
+
+    (_l, want), grads_want = jax.value_and_grad(loss, has_aux=True)(
+        _tree(flat))
+    _on_the_kernel(monkeypatch)
+    (_l, got), grads_got = jax.value_and_grad(loss, has_aux=True)(
+        _tree(flat))
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-6)
+    for a, b in zip(jax.tree_util.tree_leaves(grads_got),
+                    jax.tree_util.tree_leaves(grads_want)):
+        np.testing.assert_allclose(a, b, rtol=2e-4, atol=2e-5)
+    # the count follows the program: a full layer's head computes one
+    # block of 40 x 40 where the dense product has 37 x 37; the sliding
+    # layers and the live entries are what they were
+    model = bench_model.model_of(TINY)
+    (_r, live, dense), (_r, live_k, on_kernel) = (
+        model.counts(1, SEQ), model.counts(1, SEQ, True))
+    assert live_k == live
+    assert on_kernel - dense == 2 * 6 * (40 * 40 - SEQ * SEQ)
+
+
+@pytest.mark.parametrize("core", ["window", "dense", "kernel"])
+def test_the_checkpoint_is_where_something_quadratic_is_kept(core,
+                                                             monkeypatch):
+    """``gated_attention`` computes a dense core again on the way back
+    (window and full alike) and keeps the kernel's own residuals: read
+    off the jaxpr."""
+    from geomx_tpu.models.transformer import gated_attention
+
+    if core == "kernel":
+        _on_the_kernel(monkeypatch)
+    q = jnp.zeros((1, 24, 1, 2, 8), jnp.float32)
+    k = v = jnp.zeros((1, 24, 1, 8), jnp.float32)
+    gate = jnp.zeros((1, 24, 16), jnp.float32)
+    inv_freq, factor = rotary_frequencies(ROPE["sliding_attention"], 8)
+    text = str(jax.make_jaxpr(lambda q, k, v: gated_attention(
+        q, k, v, gate, inv_freq, factor,
+        window=8 if core == "window" else None))(q, k, v))
+    assert ("remat" in text) == (core != "kernel")
+    assert ("pallas_call" in text) == (core == "kernel")
+
+
 def test_four_expert_shares_and_one_shared_expert_sum_to_the_layer():
     """Expert parallel 4: rank r holds experts 2r and 2r+1 of 8, every
     rank the shared expert. A rank's block output is h' + shared(m) +
@@ -439,9 +504,12 @@ def test_two_party_round_through_the_device_trainer():
 # gathers the held (token, slot) pairs up to a cap before any row moves
 # (PR 33 meant to alter Laguna's program; PRs 31 and 32 left it byte for
 # byte what it was). A change that means to alter it records the new
-# value.
+# value. PR 39 (the full layers' core behind ``causal_attention``: the
+# dense product here, where Pallas is interpreted) left every operation
+# what it was and moved one private function's number (``_take_507`` ->
+# ``_take_506``): recorded anew.
 FUSED_STEP_STABLEHLO = \
-    "677ab71659c886cde786d57933b4e33c110f8b8d60d2ebb9ada2d4037162fea4"
+    "10fc48bddae25e22a8dbad2d6c84735065c5f83cdabfd18dacbed4e2e7dc4051"
 # the same of the GPT-2 family's grad_step at gpt2-small's rehearsal
 # widths, 37 tokens, taken on the tree of PR 32: a family without
 # experts compiles what it compiled before the dispatch changed

@@ -391,6 +391,41 @@ def test_the_ranks_shares_sum_to_the_whole_layer():
     assert sum(int(p[3]) for p in parts) == int(rows)
 
 
+def test_a_block_on_the_kernel_is_the_dense_block(monkeypatch):
+    """``OlmoeBlock`` with ``transformer.runs_kernel`` forced onto the
+    Pallas kernel (its test-only argument; interpreted here) against the
+    same block on the dense [T, T] product: the output and every
+    parameter's gradient."""
+    from functools import partial
+
+    from geomx_tpu.models import transformer
+
+    block = OlmoeBlock(dim=32, heads=4, num_experts=8, experts_per_token=2,
+                       expert_width=16, local_experts=(0, 8))
+    x = jnp.asarray(np.random.default_rng(3).normal(size=(2, 21, 32)),
+                    jnp.float32)
+    variables = block.init(jax.random.PRNGKey(0), x)
+
+    def loss(variables):
+        out = block.apply(variables, x)[0]
+        return jnp.sum(jnp.sin(out)), out
+
+    # (a fresh function a trace: a cached trace asks the rule nothing)
+    assert "pallas_call" not in str(jax.make_jaxpr(
+        lambda v: loss(v))(variables))
+    (_l, want), grads_want = jax.value_and_grad(loss, has_aux=True)(
+        variables)
+    monkeypatch.setattr(transformer, "runs_kernel", partial(
+        transformer.runs_kernel, forced=True))
+    assert "pallas_call" in str(jax.make_jaxpr(
+        lambda v: loss(v))(variables))
+    (_l, got), grads_got = jax.value_and_grad(loss, has_aux=True)(variables)
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-6)
+    for a, b in zip(jax.tree_util.tree_leaves(grads_got),
+                    jax.tree_util.tree_leaves(grads_want)):
+        np.testing.assert_allclose(a, b, rtol=2e-4, atol=2e-5)
+
+
 # -- one round through the system ---------------------------------------------
 
 @pytest.mark.time_limit(300)

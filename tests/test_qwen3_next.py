@@ -282,6 +282,51 @@ def test_two_head_shares_sum_to_the_mixer(kind):
             _tree(flat), x)
 
 
+def test_a_full_block_on_the_kernel_is_the_dense_block(monkeypatch):
+    """The full layer with ``transformer.runs_kernel`` forced onto the
+    Pallas kernel (its test-only argument; interpreted here) against the
+    same block on the dense [T, T] product, which it computes again on
+    the way back and the kernel does not: the output, every parameter's
+    gradient, and the booked score entries."""
+    from functools import partial
+
+    from geomx_tpu.models import transformer
+
+    flat = _layer_params(FULL)
+    x = jnp.asarray(np.random.default_rng(4).normal(size=(2, 21, 64)),
+                    jnp.float32)
+    block = _block(FULL, WHOLE)
+
+    def loss(variables):
+        out = block.apply(variables, x)[0]
+        return jnp.sum(jnp.sin(out)), out
+
+    # (a fresh function a trace: a cached trace asks the rule nothing)
+    dense = str(jax.make_jaxpr(lambda v: block.apply(v, x)[0])(_tree(flat)))
+    assert "remat" in dense and "pallas_call" not in dense
+    (_l, want), grads_want = jax.value_and_grad(loss, has_aux=True)(
+        _tree(flat))
+    monkeypatch.setattr(transformer, "runs_kernel", partial(
+        transformer.runs_kernel, forced=True))
+    on_kernel = str(jax.make_jaxpr(
+        lambda v: block.apply(v, x)[0])(_tree(flat)))
+    # the routed experts keep their checkpoint; the attention core none
+    assert "pallas_call" in on_kernel
+    assert on_kernel.count("remat") < dense.count("remat")
+    (_l, got), grads_got = jax.value_and_grad(loss, has_aux=True)(
+        _tree(flat))
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-6)
+    for a, b in zip(jax.tree_util.tree_leaves(grads_got),
+                    jax.tree_util.tree_leaves(grads_want)):
+        np.testing.assert_allclose(a, b, rtol=2e-4, atol=2e-5)
+    # the count follows the program: one block of 24 x 24 a head where
+    # the dense product has 21 x 21
+    model = bench_model.model_of(dict(WHOLE, layer_types=[FULL],
+                                      num_hidden_layers=1))
+    assert model.counts(2, 21)[1:3] == (2 * 8 * 231, 2 * 8 * 441)
+    assert model.counts(2, 21, True)[1:3] == (2 * 8 * 231, 2 * 8 * 576)
+
+
 def test_four_expert_shares_and_one_shared_expert_sum_to_the_layer():
     """Expert parallel 4: rank r holds experts 4r..4r+3 of 16, every
     rank the shared expert and its gate. A rank's block output is x +

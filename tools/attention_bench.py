@@ -1,18 +1,25 @@
 #!/usr/bin/env python
-"""Flash vs dense attention sweep on the local accelerator.
+"""Full causal attention on the local accelerator: the dense path of
+``models/transformer.py`` against the Pallas kernels of
+``ops/flash_attention.py``, forward + backward of one pass, at the
+shapes the benchmark's cells run (PERF.md section 6, PR 39).
 
-Prints a JSON line per (T, D, causal) config with forward and
-forward+backward wall times for the XLA dense einsum and the Pallas
-FlashAttention-2 kernels (geomx_tpu.ops.flash_attention). TPU only: off
-the chip the flash path is interpret-mode (correctness only, covered by
-tests/test_flash_attention.py), so the tool exits nonzero there.
+A JSON line a reading: ``shapes`` (the four callers as they call: who
+keeps the scores and who recomputes the core on the way back),
+``blocks`` (the kernel over candidate ``block_q`` x ``block_k``: what
+``ops.flash_attention.attention_blocks`` was read from), ``lengths``
+(both forms over T: what ``models.transformer.KERNEL_MIN_T`` was read
+from), ``splash`` (JAX's own splash attention at the same shapes). TPU
+only: off the chip the kernels are interpreted (correctness only,
+``tests/test_flash_attention.py``), so the tool exits nonzero there.
 
-    python tools/attention_bench.py --seqs 512,1024,2048,4096
+    python tools/attention_bench.py shapes blocks lengths
 """
 
 from __future__ import annotations
 
 import argparse
+import functools
 import json
 import os
 import sys
@@ -21,113 +28,181 @@ import time
 sys.path.insert(0, os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 
+# name: (q shape, k/v shape, float32 scores, core recomputed on the way
+# back); GPT-2's batch is the 8 sequences its cells pass at once
+SHAPES = {
+    "olmoe": ((1, 4096, 16, 128), (1, 4096, 16, 128), True, False),
+    "laguna": ((1, 4096, 1, 6, 128), (1, 4096, 1, 128), True, True),
+    "qwen3next": ((1, 4096, 1, 8, 256), (1, 4096, 1, 256), True, True),
+    "gpt2": ((8, 1023, 12, 64), (8, 1023, 12, 64), False, False),
+}
+BLOCKS = ((128, 128), (256, 256), (256, 512), (512, 256), (512, 512),
+          (512, 1024), (1024, 512), (1024, 1024))
+
 
 def _time(fn, q, k, v, iters=20):
-    """Value-fenced timing, correct whether or not block_until_ready
-    waits (tools/chip_sanity.py blocking probe). Iterations thread the
-    output back into q so the dispatched chain is data-dependent end to
-    end, and the clock stops on a SCALAR fetch of the last output; the
-    fetch round-trip is measured separately and subtracted."""
+    """Milliseconds a call of ``fn(q, k, v) -> (dq, dk, dv)``. Iterations
+    thread dq back into q so the dispatched chain is data-dependent end
+    to end, and the clock stops on a SCALAR fetch of the last output
+    (a fence whether or not ``block_until_ready`` waits); the fetch
+    round-trip is measured separately and subtracted."""
     import jax.numpy as jnp
 
-    def _head(out):
-        return out[0] if isinstance(out, tuple) else out
-
-    def _fence(x):
+    def fence(x):
         return float(jnp.sum(x.astype(jnp.float32)))
 
-    x = _head(fn(q, k, v))
-    _fence(x)                                   # warm compile + fence
+    x = fn(q, k, v)[0]
+    fence(x)                                    # compiled, and drained
     t0 = time.perf_counter()
-    _fence(x)                                   # already computed:
-    rtt = time.perf_counter() - t0              # pure fetch round-trip
+    fence(x)
+    rtt = time.perf_counter() - t0
     t0 = time.perf_counter()
     for _ in range(iters):
-        x = _head(fn(x, k, v))
-    _fence(x)
+        x = fn(x, k, v)[0]
+    fence(x)
     return max(time.perf_counter() - t0 - rtt, 1e-9) / iters * 1e3
+
+
+def _grad(attend):
+    import jax
+    import jax.numpy as jnp
+
+    return jax.jit(jax.grad(
+        lambda q, k, v: attend(q, k, v).astype(jnp.float32).sum(),
+        argnums=(0, 1, 2)))
+
+
+def _dense(q_shape, f32: bool, recomputed: bool):
+    import jax
+    import jax.numpy as jnp
+
+    from geomx_tpu.models.transformer import (dense_attention,
+                                              grouped_attention)
+
+    core = functools.partial(
+        grouped_attention if len(q_shape) == 5 else dense_attention,
+        scores_dtype=jnp.float32 if f32 else None)
+    return jax.checkpoint(core) if recomputed else core
+
+
+def _kernel(block_q=None, block_k=None):
+    from geomx_tpu.ops.flash_attention import flash_attention
+
+    return functools.partial(flash_attention, block_q=block_q,
+                             block_k=block_k)
+
+
+def _splash(q_shape, block: int):
+    """JAX's splash attention behind the same contract: causal mask,
+    its MQA form for one key/value head; q is scaled by the caller as
+    its kernels apply none."""
+    import jax
+    from jax.experimental.pallas.ops.tpu.splash_attention import (
+        splash_attention_kernel as sk, splash_attention_mask as sm)
+
+    t, d = q_shape[1], q_shape[-1]
+    heads = q_shape[2] * (q_shape[3] if len(q_shape) == 5 else 1)
+    mask = sm.MultiHeadMask([sm.CausalMask((t, t)) for _ in range(heads)])
+    sizes = sk.BlockSizes(
+        block_q=block, block_kv=block, block_kv_compute=block,
+        block_q_dkv=block, block_kv_dkv=block, block_kv_dkv_compute=block,
+        block_q_dq=block, block_kv_dq=block)
+    grouped = len(q_shape) == 5
+    make = sk.make_splash_mqa_single_device if grouped else sk.make_splash_mha
+    kernel = make(mask, block_sizes=sizes) if grouped else make(
+        mask, block_sizes=sizes, head_shards=1, q_seq_shards=1)
+
+    def attend(q, k, v):
+        q = q * (d ** -0.5)
+        if grouped:                     # [B, T, 1, G, D] on [B, T, 1, D]
+            o = jax.vmap(kernel)(q[:, :, 0].swapaxes(1, 2), k[:, :, 0],
+                                 v[:, :, 0])
+            return o.swapaxes(1, 2)[:, :, None]
+        o = jax.vmap(kernel)(*(x.swapaxes(1, 2) for x in (q, k, v)))
+        return o.swapaxes(1, 2)
+
+    return attend
 
 
 def main():
     ap = argparse.ArgumentParser()
-    ap.add_argument("--seqs", type=str, default="512,1024,2048,4096")
-    ap.add_argument("--heads", type=int, default=8)
-    ap.add_argument("--head-dim", type=int, default=64)
-    ap.add_argument("--batch", type=int, default=4)
-    ap.add_argument("--sweep-blocks", action="store_true",
-                    help="sweep flash block_q x block_k per seq len and "
-                         "report the fastest fwd+bwd combo vs dense")
-    ap.add_argument("--blocks", type=str, default="128,256,512",
-                    help="candidate block sizes for --sweep-blocks")
+    ap.add_argument("what", nargs="+",
+                    choices=["shapes", "blocks", "lengths", "splash"])
+    ap.add_argument("--only", default=",".join(SHAPES),
+                    help="the shapes to read, by name")
+    ap.add_argument("--out", default="chiprun_out/attention_bench.jsonl")
     args = ap.parse_args()
 
     import jax
     import jax.numpy as jnp
 
-    from geomx_tpu.models.transformer import dense_attention
-    from geomx_tpu.ops.flash_attention import flash_attention
+    from geomx_tpu.ops.flash_attention import attention_blocks
     from geomx_tpu.runtime import require_tpu, setup_compile_cache
 
-    print(json.dumps({"device": require_tpu()}), flush=True)
+    os.makedirs(os.path.dirname(args.out) or ".", exist_ok=True)
+    out = open(args.out, "a")
+
+    def say(row):
+        line = json.dumps(row)
+        print(line, flush=True)
+        out.write(line + "\n")
+        out.flush()
+
+    say({"device": require_tpu()})
     setup_compile_cache()
 
-    B, H, D = args.batch, args.heads, args.head_dim
-    for T in [int(s) for s in args.seqs.split(",")]:
-        q, k, v = (jax.random.normal(jax.random.PRNGKey(i), (B, T, H, D),
-                                     jnp.bfloat16) for i in range(3))
+    def operands(q_shape, kv_shape):
+        q = jax.random.normal(jax.random.PRNGKey(0), q_shape, jnp.bfloat16)
+        k, v = (jax.random.normal(jax.random.PRNGKey(i), kv_shape,
+                                  jnp.bfloat16) for i in (1, 2))
+        return q, k, v
 
-        dense_f = jax.jit(lambda q, k, v: dense_attention(q, k, v))
-        flash_f = jax.jit(lambda q, k, v: flash_attention(q, k, v))
-        dense_g = jax.jit(jax.grad(
-            lambda q, k, v: dense_attention(q, k, v).astype(
-                jnp.float32).sum(), argnums=(0, 1, 2)))
-        flash_g = jax.jit(jax.grad(
-            lambda q, k, v: flash_attention(q, k, v).astype(
-                jnp.float32).sum(), argnums=(0, 1, 2)))
+    def read(row, make, ops):
+        try:
+            row["fwdbwd_ms"] = round(_time(_grad(make()), *ops), 4)
+        except Exception as e:  # noqa: BLE001 — report and move on
+            row["error"] = str(e)[:300]
+        say(row)
 
-        row = {"T": T, "B": B, "H": H, "D": D, "causal": True,
-               "dense_fwd_ms": round(_time(dense_f, q, k, v), 3),
-               "flash_fwd_ms": round(_time(flash_f, q, k, v), 3),
-               "dense_fwdbwd_ms": round(_time(dense_g, q, k, v), 3),
-               "flash_fwdbwd_ms": round(_time(flash_g, q, k, v), 3)}
-        row["fwd_speedup"] = round(
-            row["dense_fwd_ms"] / row["flash_fwd_ms"], 2)
-        row["fwdbwd_speedup"] = round(
-            row["dense_fwdbwd_ms"] / row["flash_fwdbwd_ms"], 2)
-        print(json.dumps(row), flush=True)
-
-        if not args.sweep_blocks:
-            continue
-        # block-size sweep: the fwd+bwd time is what a train step pays
-        cands = [int(b) for b in args.blocks.split(",")]
-        best = None
-        for bq in cands:
-            for bk in cands:
-                if bq > T or bk > T:
+    names = [n for n in args.only.split(",") if n]
+    for name in names:
+        q_shape, kv_shape, f32, recomputed = SHAPES[name]
+        ops = operands(q_shape, kv_shape)
+        t, d = q_shape[1], q_shape[-1]
+        if "shapes" in args.what:
+            read({"read": "shapes", "shape": name, "form": "dense",
+                  "recomputed": recomputed},
+                 lambda: _dense(q_shape, f32, recomputed), ops)
+            read({"read": "shapes", "shape": name, "form": "kernel",
+                  "blocks": attention_blocks(t, d)}, _kernel, ops)
+            if recomputed:
+                read({"read": "shapes", "shape": name, "form": "kernel",
+                      "recomputed": True, "blocks": attention_blocks(t, d)},
+                     lambda: jax.checkpoint(_kernel()), ops)
+        if "blocks" in args.what:
+            for bq, bk in BLOCKS:
+                if bq <= t + 7 and bk <= t + 7:
+                    read({"read": "blocks", "shape": name,
+                          "blocks": [bq, bk]},
+                         lambda: _kernel(bq, bk), ops)
+        if "splash" in args.what:
+            for block in (512, 1024):
+                read({"read": "splash", "shape": name, "block": block},
+                     lambda: _splash(q_shape, block), ops)
+    if "lengths" in args.what:
+        for name in names:
+            q_shape, kv_shape, f32, recomputed = SHAPES[name]
+            for t in (512, 1024, 2048, 4096):
+                if t > q_shape[1] + 1:
                     continue
-                fg = jax.jit(jax.grad(
-                    lambda q, k, v, _bq=bq, _bk=bk: flash_attention(
-                        q, k, v, block_q=_bq, block_k=_bk).astype(
-                        jnp.float32).sum(), argnums=(0, 1, 2)))
-                try:
-                    ms = _time(fg, q, k, v, iters=10)
-                except Exception as e:  # noqa: BLE001 — report and move on
-                    print(json.dumps({"T": T, "block_q": bq,
-                                      "block_k": bk,
-                                      "error": str(e)[:200]}), flush=True)
-                    continue
-                print(json.dumps({"T": T, "block_q": bq, "block_k": bk,
-                                  "flash_fwdbwd_ms": round(ms, 3)}),
-                      flush=True)
-                if best is None or ms < best[0]:
-                    best = (ms, bq, bk)
-        if best:
-            print(json.dumps({
-                "T": T, "best_block_q": best[1], "best_block_k": best[2],
-                "best_flash_fwdbwd_ms": round(best[0], 3),
-                "dense_fwdbwd_ms": row["dense_fwdbwd_ms"],
-                "best_speedup": round(
-                    row["dense_fwdbwd_ms"] / best[0], 2)}), flush=True)
+                qs, ks = ((s[0], t) + s[2:] for s in (q_shape, kv_shape))
+                ops = operands(qs, ks)
+                read({"read": "lengths", "shape": name, "t": t,
+                      "form": "dense"},
+                     lambda: _dense(qs, f32, recomputed), ops)
+                read({"read": "lengths", "shape": name, "t": t,
+                      "form": "kernel",
+                      "blocks": attention_blocks(t, qs[-1])}, _kernel, ops)
 
 
 if __name__ == "__main__":
