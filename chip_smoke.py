@@ -161,9 +161,9 @@ def check_kernels(on_chip: bool) -> None:
 
 
 def check_attention_paths(on_chip: bool) -> None:
-    """The three families' full causal attention as their blocks call
+    """The four families' full causal attention as their blocks call
     it (``transformer.causal_attention``), at their head shapes and the
-    cells' length: on the chip the kernels must be in the block's own
+    cells' lengths (Mellum2's 8,192, the others' 4,096): on the chip the kernels must be in the block's own
     lowering (a Mosaic call under the scope ``attention`` /
     ``attention_full``) and agree with the dense product; off it the
     rule must give the dense product."""
@@ -171,13 +171,16 @@ def check_attention_paths(on_chip: bool) -> None:
     import jax.numpy as jnp
 
     from geomx_tpu.models.laguna import LagunaBlock
+    from geomx_tpu.models.mellum import MellumBlock
     from geomx_tpu.models.olmoe import OlmoeBlock
     from geomx_tpu.models.qwen3_next import Qwen3NextBlock
     from geomx_tpu.models.transformer import (causal_attention,
                                               dense_attention,
                                               grouped_attention)
 
-    T = 4096 if on_chip else 32
+    lengths = dict.fromkeys(("olmoe", "laguna", "qwen3next"),
+                            4096 if on_chip else 32)
+    lengths["mellum"] = 8192 if on_chip else 32
     bf = jnp.bfloat16
     rope = dict(rope_type="default", rope_theta=10000.0,
                 partial_rotary_factor=0.5)
@@ -198,9 +201,19 @@ def check_attention_paths(on_chip: bool) -> None:
             linear_value_heads=(0, 1), conv_kernel=4, num_experts=4,
             experts_per_token=2, expert_width=64, shared_width=64,
             local_experts=(0, 4), compute_dtype=bf),
+        "mellum": MellumBlock(
+            dim=256, head_dim=128, kind="full_attention",
+            query_heads=(0, 8), key_value_heads=(0, 1), window=1024,
+            rope=dict(rope_type="yarn", rope_theta=500000, factor=16,
+                      original_max_position_embeddings=8192, beta_fast=32,
+                      beta_slow=1, attention_factor=1.2772588722239782),
+            num_experts=4, experts_per_token=2, expert_width=64,
+            local_experts=(0, 4), compute_dtype=bf),
     }
-    x = jax.random.normal(jax.random.PRNGKey(0), (1, T, 256), jnp.float32)
     for name, block in blocks.items():
+        T = lengths[name]
+        x = jax.random.normal(jax.random.PRNGKey(0), (1, T, 256),
+                              jnp.float32)
         variables = jax.eval_shape(block.init, jax.random.PRNGKey(1), x)
         lowered = jax.jit(jax.grad(
             lambda v, x: block.apply(v, x)[0].sum())).lower(variables, x)
@@ -224,10 +237,13 @@ def check_attention_paths(on_chip: bool) -> None:
         say(f"{name} block T={T}: {calls} Mosaic calls in the lowering")
     if not on_chip:
         return
-    shapes = {"olmoe": ((1, T, 16, 128), (1, T, 16, 128)),
-              "laguna": ((1, T, 1, 6, 128), (1, T, 1, 128)),
-              "qwen3next": ((1, T, 1, 8, 256), (1, T, 1, 256))}
-    for name, (q_shape, kv_shape) in shapes.items():
+    shapes = {"olmoe": ((16, 128), (16, 128)),
+              "laguna": ((1, 6, 128), (1, 128)),
+              "qwen3next": ((1, 8, 256), (1, 256)),
+              "mellum": ((1, 8, 128), (1, 128))}
+    for name, (q_heads, kv_heads) in shapes.items():
+        T = lengths[name]
+        q_shape, kv_shape = (1, T) + q_heads, (1, T) + kv_heads
         q = jax.random.normal(jax.random.PRNGKey(2), q_shape, bf)
         k, v = (jax.random.normal(jax.random.PRNGKey(i), kv_shape, bf)
                 for i in (3, 4))

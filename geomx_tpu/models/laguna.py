@@ -49,17 +49,16 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from geomx_tpu.models.moe import gated_experts, sparse_dispatch
-from geomx_tpu.models.olmoe import HIGHEST, RMSNorm
-from geomx_tpu.models.transformer import (gated_attention,
+from geomx_tpu.models.moe import (gated_experts, next_token_loss,
+                                  sparse_dispatch)
+from geomx_tpu.models.transformer import (FULL, HIGHEST, RMSNorm,
+                                          gated_attention,
                                           kernel_score_entries,
-                                          rotary_frequencies, runs_kernel,
+                                          rotary_frequencies,
                                           score_entries)
 
 __all__ = ["Laguna", "LagunaBlock", "next_token_loss",
            "rotary_frequencies"]
-
-FULL = "full_attention"      # a layer of any other kind slides
 
 
 class LagunaBlock(nn.Module):
@@ -211,17 +210,3 @@ class Laguna(nn.Module):
                                     preferred_element_type=jnp.float32),
                 name="head")(x)
         return logits, rows_local
-
-
-def next_token_loss(model: Laguna, variables, toks):
-    """``toks`` [B, T+1]: the mean next-token cross-entropy. Returns
-    (loss, [rows routed to the held experts, all routed rows, live
-    attention score entries, computed attention score entries]), the
-    counts as float32."""
-    logits, rows_local = model.apply(variables, toks[:, :-1])
-    logp = jax.nn.log_softmax(logits)
-    loss = -jnp.mean(jnp.take_along_axis(logp, toks[:, 1:, None], axis=-1))
-    by_shape = model.counts(toks.shape[0], toks.shape[1] - 1,
-                            runs_kernel(toks[:, :-1]))
-    return loss, jnp.stack([rows_local.astype(jnp.float32),
-                            *(jnp.float32(c) for c in by_shape)])

@@ -44,8 +44,11 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from geomx_tpu.models.transformer import runs_kernel
+
 __all__ = ["MoEBlock", "moe_param_sharding", "is_expert_param",
-           "sparse_dispatch", "dispatch_cap", "gated_experts"]
+           "sparse_dispatch", "dispatch_cap", "gated_experts",
+           "next_token_loss"]
 
 # leaf names of expert-stacked params (leading axis = expert dim)
 EXPERT_PARAM_NAMES = ("w_up", "b_up", "w_dn", "b_dn")
@@ -201,6 +204,23 @@ def gated_experts(w_gate, w_up, w_down):
             return jax.lax.ragged_dot(a, w_down, group_sizes)
 
     return experts
+
+
+def next_token_loss(model, variables, toks):
+    """``toks`` [B, T+1]: the mean next-token cross-entropy of a rank's
+    share of a sparse decoder (``models/laguna.py``,
+    ``models/qwen3_next.py``, ``models/mellum.py``): ``model.apply``
+    gives (logits, rows routed to the held experts), and
+    ``model.counts(batch, t, kernel)`` what the pass has by its shapes.
+    Returns (loss, [the rows routed here, then ``model.counts``]), the
+    counts as float32."""
+    logits, rows_local = model.apply(variables, toks[:, :-1])
+    logp = jax.nn.log_softmax(logits)
+    loss = -jnp.mean(jnp.take_along_axis(logp, toks[:, 1:, None], axis=-1))
+    by_shape = model.counts(toks.shape[0], toks.shape[1] - 1,
+                            runs_kernel(toks[:, :-1]))
+    return loss, jnp.stack([rows_local.astype(jnp.float32),
+                            *(jnp.float32(c) for c in by_shape)])
 
 
 class MoEBlock(nn.Module):

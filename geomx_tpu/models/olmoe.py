@@ -38,31 +38,10 @@ import jax
 import jax.numpy as jnp
 
 from geomx_tpu.models.moe import gated_experts, sparse_dispatch
-from geomx_tpu.models.transformer import causal_attention
+from geomx_tpu.models.transformer import (HIGHEST, RMSNorm,
+                                          causal_attention)
 
 __all__ = ["Olmoe", "OlmoeBlock", "next_token_loss"]
-
-HIGHEST = jax.lax.Precision.HIGHEST
-
-
-class RMSNorm(nn.Module):
-    """``x / rms(x) * scale`` over the last axis; ``zero_centred``: the
-    parameter is the scale's distance from 1, ``x / rms(x) * (1 + w)``,
-    zero at the start (the form Qwen3-Next publishes)."""
-    eps: float
-    dtype: Any = jnp.float32
-    zero_centred: bool = False
-
-    @nn.compact
-    def __call__(self, x):
-        scale = self.param(
-            "scale", nn.initializers.zeros if self.zero_centred
-            else nn.initializers.ones, (x.shape[-1],), jnp.float32)
-        if self.zero_centred:
-            scale = 1.0 + scale
-        x = x.astype(jnp.float32)
-        x = x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + self.eps)
-        return (x * scale).astype(self.dtype)
 
 
 def rope(x, theta: float):

@@ -61,11 +61,12 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from geomx_tpu.models.moe import gated_experts, sparse_dispatch
-from geomx_tpu.models.olmoe import HIGHEST, RMSNorm
-from geomx_tpu.models.transformer import (gated_attention,
+from geomx_tpu.models.moe import (gated_experts, next_token_loss,
+                                  sparse_dispatch)
+from geomx_tpu.models.transformer import (HIGHEST, RMSNorm,
+                                          gated_attention,
                                           kernel_score_entries,
-                                          rotary_frequencies, runs_kernel,
+                                          rotary_frequencies,
                                           score_entries)
 from geomx_tpu.ops.gated_delta import chunks_of, gated_delta_rule
 
@@ -326,16 +327,3 @@ class Qwen3Next(nn.Module):
                                     preferred_element_type=jnp.float32),
                 name="head")(x)
         return logits, rows_local
-
-
-def next_token_loss(model: Qwen3Next, variables, toks):
-    """``toks`` [B, T+1]: the mean next-token cross-entropy. Returns
-    (loss, [rows routed to the held experts, then ``model.counts``]),
-    the counts as float32."""
-    logits, rows_local = model.apply(variables, toks[:, :-1])
-    logp = jax.nn.log_softmax(logits)
-    loss = -jnp.mean(jnp.take_along_axis(logp, toks[:, 1:, None], axis=-1))
-    by_shape = model.counts(toks.shape[0], toks.shape[1] - 1,
-                            runs_kernel(toks[:, :-1]))
-    return loss, jnp.stack([rows_local.astype(jnp.float32),
-                            *(jnp.float32(c) for c in by_shape)])

@@ -19,6 +19,30 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 
+HIGHEST = jax.lax.Precision.HIGHEST
+FULL = "full_attention"     # HF's name of a layer whose keys are all j <= i
+
+
+class RMSNorm(nn.Module):
+    """``x / rms(x) * scale`` over the last axis; ``zero_centred``: the
+    parameter is the scale's distance from 1, ``x / rms(x) * (1 + w)``,
+    zero at the start (the form Qwen3-Next publishes)."""
+    eps: float
+    dtype: Any = jnp.float32
+    zero_centred: bool = False
+
+    @nn.compact
+    def __call__(self, x):
+        scale = self.param(
+            "scale", nn.initializers.zeros if self.zero_centred
+            else nn.initializers.ones, (x.shape[-1],), jnp.float32)
+        if self.zero_centred:
+            scale = 1.0 + scale
+        x = x.astype(jnp.float32)
+        x = x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + self.eps)
+        return (x * scale).astype(self.dtype)
+
+
 def make_attention(impl: str = "auto", *, causal: bool = True,
                    mesh: Optional[Mesh] = None,
                    block_q: Optional[int] = None,
@@ -205,12 +229,13 @@ def kernel_score_entries(t: int, head_dim: int) -> int:
 
 def rotary_frequencies(rope, head_dim: int):
     """(inverse frequencies of the rotated pairs, float32; the factor on
-    cos and sin) from one block of HF ``rope_parameters``: ``default``,
-    or ``yarn`` as ``_compute_yarn_parameters`` has it: pairs that turn
+    cos and sin) from one block of HF ``rope_parameters`` (a block with
+    no ``partial_rotary_factor`` turns the whole head): ``default``, or
+    ``yarn`` as ``_compute_yarn_parameters`` has it: pairs that turn
     more than ``beta_fast`` times over the original context keep their
     frequency, those that turn less than ``beta_slow`` times have it
     divided by ``factor``, a linear ramp over the pairs between."""
-    dim = int(head_dim * rope["partial_rotary_factor"])
+    dim = int(head_dim * rope.get("partial_rotary_factor", 1.0))
     base = float(rope["rope_theta"])
     freq = base ** (-np.arange(0, dim, 2, dtype=np.float64) / dim)
     if rope["rope_type"] == "default":
@@ -249,21 +274,17 @@ def rotary(x, inv_freq, factor: float):
     return jnp.concatenate([turned.astype(x.dtype), passed], -1)
 
 
-def gated_attention(q, k, v, gate, inv_freq, factor: float,
-                    window: Optional[int] = None):
-    """An attention branch with rotary positions on the way in and an
-    element-wise sigmoid gate on the way out (the form Qwen3-Next
-    publishes; ``models/laguna.py``, ``models/qwen3_next.py``): ``q``
-    [B, T, KV, G, D], ``k`` and ``v`` [B, T, KV, D], ``gate`` [B, T,
-    KV * G * D] the gate BEFORE its sigmoid, from the layer's own normed
-    input. The core is :func:`window_attention` over ``window`` keys or,
-    with none, :func:`causal_attention`. A dense core is computed again
-    on the way back (``jax.checkpoint``), so no [T, T] scores are kept;
-    the kernel keeps q, k, v, o and the log-sum-exp only, so under it a
-    checkpoint would buy nothing and cost a forward kernel a pass.
-    Returns ``core(rotary(q), rotary(k), v) * sigmoid(gate)`` [B, T,
-    KV * G * D], what the output projection takes."""
-    gate = nn.sigmoid(gate)
+def rotary_attention(q, k, v, inv_freq, factor: float,
+                     window: Optional[int] = None):
+    """An attention branch with rotary positions on the way in: ``q``
+    [B, T, KV, G, D], ``k`` and ``v`` [B, T, KV, D]. The core is
+    :func:`window_attention` over ``window`` keys or, with none,
+    :func:`causal_attention`. A dense core is computed again on the way
+    back (``jax.checkpoint``), so no [T, T] scores are kept; the kernel
+    keeps q, k, v, o and the log-sum-exp only, so under it a checkpoint
+    would buy nothing and cost a forward kernel a pass. Returns
+    ``core(rotary(q), rotary(k), v)`` [B, T, KV, G, D]
+    (``models/mellum.py``)."""
     q, k = rotary(q, inv_freq, factor), rotary(k, inv_freq, factor)
     if window is not None:
         core = jax.checkpoint(lambda q, k, v: window_attention(
@@ -271,7 +292,20 @@ def gated_attention(q, k, v, gate, inv_freq, factor: float,
     else:
         core = causal_attention if runs_kernel(q) \
             else jax.checkpoint(causal_attention)
-    return core(q, k, v).reshape(gate.shape) * gate
+    return core(q, k, v)
+
+
+def gated_attention(q, k, v, gate, inv_freq, factor: float,
+                    window: Optional[int] = None):
+    """:func:`rotary_attention` with an element-wise sigmoid gate on the
+    way out (the form Qwen3-Next publishes; ``models/laguna.py``,
+    ``models/qwen3_next.py``): ``gate`` [B, T, KV * G * D] is the gate
+    BEFORE its sigmoid, from the layer's own normed input. Returns
+    ``rotary_attention(...) * sigmoid(gate)`` [B, T, KV * G * D], what
+    the output projection takes."""
+    gate = nn.sigmoid(gate)
+    return rotary_attention(q, k, v, inv_freq, factor,
+                            window).reshape(gate.shape) * gate
 
 
 class Block(nn.Module):
