@@ -32,7 +32,12 @@ no placeholders are needed.
 
 Compression tags travel in ``Meta.compr`` / ``KVPairs.compr``:
 "" (none), "fp16", "bsc", "2bit" — plus "bsc16" (BSC with float16
-values) on the quantized combined wire (``compression.device``).
+values) on the quantized combined wire (``compression.device``). A tag
+names how the VALUES travel. The positions of a ``bsc`` / ``bsc16``
+payload are int32 on the LAN; on the party-global link, where they
+ascend strictly, they are the code of their gaps, a ``uint8`` part
+(``entries.CODED``: a third of their bytes at 1%), which
+``Pairs.from_wire`` / ``Entries.from_wire`` decode.
 """
 
 from __future__ import annotations
@@ -42,12 +47,14 @@ from typing import Dict, Optional, Tuple, Union
 import numpy as np
 
 from geomx_tpu import kernels_native
-from geomx_tpu.compression.entries import Entries, Pairs, SPARSE_TAGS
+from geomx_tpu.compression.entries import (Entries, Pairs, SPARSE_TAGS,
+                                            plain_positions)
 
 __all__ = ["make_compressor", "Compressor", "FP16Compressor", "BSCCompressor",
            "TwoBitCompressor", "MPQCompressor", "bsc_compress", "bsc_decompress",
            "bsc_pull_compress", "two_bit_quantize", "two_bit_dequantize",
-           "takes_pairs", "draw_ahead", "Entries", "Pairs", "SPARSE_TAGS"]
+           "takes_pairs", "draw_ahead", "Entries", "Pairs", "SPARSE_TAGS",
+           "plain_positions"]
 
 BSC_MOMENTUM = 0.9  # reference: gradient_compression.cc:198
 

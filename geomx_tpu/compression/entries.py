@@ -23,14 +23,162 @@ arrays where it can.
 from __future__ import annotations
 
 import logging
-from typing import Sequence
+from typing import Optional, Sequence
 
 import numpy as np
 
-__all__ = ["Pairs", "Entries", "SPARSE_TAGS"]
+from geomx_tpu import kernels_native
+
+__all__ = ["Pairs", "Entries", "SPARSE_TAGS", "CODED", "encode_positions",
+           "decode_positions", "encode_positions_numpy",
+           "decode_positions_numpy", "plain_positions"]
 
 # wire tags whose payload is (values, positions): float32 or float16 values
 SPARSE_TAGS = ("bsc", "bsc16")
+
+# A positions part of this type is no list of positions but their code:
+# strictly ascending positions as the gaps between them, the first one
+# absolute, each an unsigned LEB128 varint (7 bits a byte, low bits
+# first, the high bit says "more"), zero bytes behind the last up to a
+# multiple of 4 so that the parts behind it in a frame stay aligned.
+# Positions themselves only ever travel as int32 or int64, so the part's
+# own dtype (``Meta.dtypes``) tells the two apart. It crosses the
+# party-global link only (``kvstore/server.py``); the tag goes on naming
+# how the VALUES travel. ``decode_positions`` is the one reader of it:
+# whoever else reads a positions part calls ``plain_positions``.
+CODED = np.dtype(np.uint8)
+
+
+def _itype(size: int):
+    """The position type of :class:`Entries` over ``size`` elements."""
+    return np.int32 if size <= np.iinfo(np.int32).max else np.int64
+
+
+_WHAT_WAS_WRONG = {
+    1: "the bytes end before the last position",
+    2: "a gap of more than 64 bits",
+    3: "a gap of 0: the positions do not ascend strictly",
+    4: "a position outside the range",
+    5: "more positions than values",
+}
+
+
+def _refuse(code: int, count: int, size: int):
+    raise ValueError(
+        f"coded positions ({count} expected, under {size}): "
+        f"{_WHAT_WAS_WRONG[code]}")
+
+
+def encode_positions_numpy(idx: np.ndarray) -> Optional[np.ndarray]:
+    """:func:`encode_positions` in numpy passes: the reference, and what
+    runs where the native library cannot be built."""
+    idx = np.asarray(idx).ravel()
+    n = idx.size
+    if not n:
+        return np.zeros(0, dtype=CODED)
+    gaps = np.empty(n, dtype=np.int64)
+    gaps[0] = idx[0]
+    np.subtract(idx[1:], idx[:-1], out=gaps[1:], dtype=np.int64)
+    if gaps[0] < 0 or (n > 1 and gaps[1:].min() < 1):
+        return None
+    gaps = gaps.view(np.uint64)
+    nbytes = np.ones(n, dtype=np.int64)     # a byte every 7 bits
+    for k in range(1, 10):
+        longer = gaps >= np.uint64(1 << 7 * k)
+        if not longer.any():
+            break
+        nbytes += longer
+    ends = np.cumsum(nbytes)
+    starts = ends - nbytes
+    out = np.zeros(-(-int(ends[-1]) // 4) * 4, dtype=CODED)
+    for k in range(int(nbytes.max())):
+        has = np.flatnonzero(nbytes > k)
+        byte = ((gaps[has] >> np.uint64(7 * k))
+                & np.uint64(0x7f)).astype(CODED)
+        byte[nbytes[has] > k + 1] |= 0x80
+        out[starts[has] + k] = byte
+    return out
+
+
+def decode_positions_numpy(buf: np.ndarray, count: int,
+                           size: int) -> np.ndarray:
+    """:func:`decode_positions` in numpy passes: the reference, and what
+    runs where the native library cannot be built."""
+    buf = np.asarray(buf, dtype=CODED).ravel()
+    if not count:
+        if buf.size:
+            _refuse(5, count, size)
+        return np.zeros(0, dtype=_itype(size))
+    last = np.flatnonzero(buf < 0x80)       # the bytes that end a gap
+    if last.size < count:
+        _refuse(1, count, size)
+    ends = last[:count] + 1
+    tail = buf[ends[-1]:]
+    if tail.size > 3 or tail.any():
+        _refuse(5, count, size)
+    starts = np.concatenate(([0], ends[:-1]))
+    nbytes = ends - starts
+    if nbytes.max() > 10:
+        _refuse(2, count, size)
+    gaps = np.zeros(count, dtype=np.uint64)
+    for k in range(int(nbytes.max())):
+        has = np.flatnonzero(nbytes > k)
+        bits = buf[starts[has] + k].astype(np.uint64) & np.uint64(0x7f)
+        if k == 9 and (bits > 1).any():
+            _refuse(2, count, size)
+        gaps[has] |= bits << np.uint64(7 * k)
+    if not gaps[1:].all():
+        _refuse(3, count, size)
+    if size <= 0 or (gaps >= np.uint64(size)).any():
+        _refuse(4, count, size)
+    # every gap is under size < 2**63: a sum cannot wrap before it is
+    # over size, so the largest one under size clears them all
+    idx = np.cumsum(gaps, dtype=np.uint64)
+    if idx.max() >= np.uint64(size):
+        _refuse(4, count, size)
+    return idx.astype(_itype(size))
+
+
+def encode_positions(idx: np.ndarray) -> Optional[np.ndarray]:
+    """The positions ``idx`` (int32 or int64) as the code :data:`CODED`
+    describes, padding included; None, for the caller to send them as
+    they are, where they do not ascend strictly from 0 or more. One
+    scalar pass (``native/kernels.cc``, the GIL released), which checks
+    the order as it goes."""
+    idx = np.ascontiguousarray(idx).ravel()
+    if (kernels_native.lib() is None or idx.dtype.kind != "i"
+            or idx.dtype.itemsize < 4):
+        return encode_positions_numpy(idx)
+    return kernels_native.idx_encode(idx)
+
+
+def decode_positions(buf: np.ndarray, count: int, size: int) -> np.ndarray:
+    """``count`` positions of a range of ``size`` elements out of the
+    code ``buf`` (see :data:`CODED`): strictly ascending, each in
+    ``[0, size)``, ``int32`` where ``size`` allows, which is what the
+    decoder holds the bytes to as it reads them. Padding is not read.
+    ``ValueError`` for bytes that end early, a gap over 64 bits or of
+    0, a position outside the range, or more behind the last position
+    than padding: never a shorter list."""
+    buf = np.ascontiguousarray(buf, dtype=CODED).ravel()
+    if kernels_native.lib() is None:
+        return decode_positions_numpy(buf, count, size)
+    out = np.empty(count, dtype=_itype(size))
+    code = kernels_native.idx_decode(buf, out, size)
+    if code:
+        _refuse(code, count, size)
+    return out
+
+
+def plain_positions(aux) -> np.ndarray:
+    """A positions part for a reader that takes positions as they are:
+    flat, and refused where it is the code (its bytes would read as
+    positions 0..255)."""
+    idx = np.asarray(aux).ravel()
+    if idx.dtype == CODED:
+        raise ValueError(
+            "a coded positions part reached a reader of plain positions")
+    return idx
 
 
 def _sum_runs(idx: np.ndarray, vals: np.ndarray):
@@ -46,11 +194,6 @@ def _sum_runs(idx: np.ndarray, vals: np.ndarray):
     if starts.size == idx.size:
         return idx, vals
     return idx[starts], np.add.reduceat(vals, starts)
-
-
-def _itype(size: int):
-    """The position type of :class:`Entries` over ``size`` elements."""
-    return np.int32 if size <= np.iinfo(np.int32).max else np.int64
 
 
 class Pairs:
@@ -69,13 +212,18 @@ class Pairs:
         """A ``bsc`` / ``bsc16`` payload addressing ``size`` elements,
         as the wire's own arrays (float16 values widen). Positions
         outside the range are dropped with a warning. O(entries), no
-        sort."""
+        sort. A coded positions part (:data:`CODED`) is decoded into
+        :class:`Entries`: the decoder has held it to the order and the
+        range, so neither is looked at again."""
         if aux is None:
             raise ValueError("bsc payload missing index aux array")
         idx = np.asarray(aux).ravel()
+        vals = np.asarray(val, dtype=np.float32).ravel()
+        if idx.dtype == CODED:
+            return Entries(decode_positions(idx, vals.size, size), vals,
+                           size)
         if idx.dtype.kind not in "iu":
             idx = idx.astype(np.int64)
-        vals = np.asarray(val, dtype=np.float32).ravel()
         if idx.size and not (idx.min() >= 0 and idx.max() < size):
             ok = (idx >= 0) & (idx < size)
             logging.getLogger("geomx.compression").warning(
@@ -138,6 +286,8 @@ class Entries(Pairs):
         every server's selection and response is — is taken as it
         is, its float32 values without a copy."""
         pairs = Pairs.from_wire(val, aux, size)
+        if isinstance(pairs, Entries):
+            return pairs
         idx = pairs.idx
         if idx.size < 2 or bool((idx[1:] > idx[:-1]).all()):
             return cls(idx.astype(_itype(size), copy=False), pairs.vals,

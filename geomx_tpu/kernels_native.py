@@ -33,6 +33,7 @@ _failed = False
 _f32p = ctypes.POINTER(ctypes.c_float)
 _i64p = ctypes.POINTER(ctypes.c_int64)
 _i32p = ctypes.POINTER(ctypes.c_int32)
+_u8p = ctypes.POINTER(ctypes.c_uint8)
 
 
 def enabled() -> bool:
@@ -74,6 +75,12 @@ def lib() -> Optional[ctypes.CDLL]:
         L.gxk_bsc_sweep.restype = i64
         L.gxk_bsc_sweep.argtypes = [_f32p, _f32p, i64, f32, _i64p, _f32p,
                                     i64, f32, _i32p, _f32p, i64]
+        L.gxk_idx_encode.restype = i64
+        L.gxk_idx_encode.argtypes = [ctypes.c_void_p, i64, ctypes.c_int,
+                                     _u8p, i64]
+        L.gxk_idx_decode.restype = i64
+        L.gxk_idx_decode.argtypes = [_u8p, i64, i64, i64, ctypes.c_int,
+                                     ctypes.c_void_p]
         _lib = L
         return _lib
 
@@ -170,3 +177,35 @@ def bsc_sweep(u: np.ndarray, v: np.ndarray, momentum: float,
         _ptr(u), _ptr(v), u.size, momentum, _i64(idx), _ptr(vals), idx.size,
         boundary, out_idx.ctypes.data_as(_i32p), _ptr(out_val), cap)
     return out_val[:got], out_idx[:got]
+
+
+def idx_encode(idx: np.ndarray) -> Optional[np.ndarray]:
+    """The positions ``idx`` (int32 or int64, contiguous) as the gaps
+    between them (``gxk_idx_encode``) -> the coded bytes, padded with
+    zeros to a multiple of 4, or None where the positions do not ascend
+    strictly from 0 or more."""
+    last = int(idx[-1]) if idx.size else 0
+    if last < 0:
+        return None
+    # a gap of g takes at most 1 + g / 128 bytes, and never more than
+    # 7 bits a byte of its width allow
+    most = idx.size * (5 if idx.dtype.itemsize == 4 else 10)
+    out = np.empty(min(idx.size + last // 128, most) + 10, dtype=np.uint8)
+    got = lib().gxk_idx_encode(idx.ctypes.data, idx.size,
+                               idx.dtype.itemsize == 8,
+                               out.ctypes.data_as(_u8p), out.size)
+    if got < 0:
+        return None
+    end = -(-got // 4) * 4
+    out[got:end] = 0
+    return out[:end]
+
+
+def idx_decode(buf: np.ndarray, out: np.ndarray, size: int) -> int:
+    """``out.size`` positions under ``size`` out of the coded bytes
+    ``buf`` (uint8, contiguous, writeable or not) into ``out`` (int32
+    or int64, contiguous) -> 0, or ``gxk_idx_decode``'s number for
+    what was wrong with the bytes."""
+    return lib().gxk_idx_decode(
+        buf.ctypes.data_as(_u8p), buf.size, out.size, size,
+        out.dtype.itemsize == 8, out.ctypes.data)

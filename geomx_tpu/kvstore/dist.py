@@ -41,6 +41,7 @@ from geomx_tpu import config as cfg_mod
 from geomx_tpu import profiler
 from geomx_tpu import telemetry
 from geomx_tpu.compression.device import WireCodec, decode_wire
+from geomx_tpu.compression.entries import plain_positions
 from geomx_tpu.kvstore import sharding
 from geomx_tpu.kvstore.controller import TransportController
 from geomx_tpu.kvstore.base import Command, DATA_INIT, KVStore, _sum_values
@@ -1159,7 +1160,7 @@ class KVStoreDist(KVStore):
         prepared = []
         for k, values, indices in zip(keys, values_list, indices_list):
             vals = np.ascontiguousarray(values, dtype=np.float32).ravel()
-            idx = np.asarray(indices).ravel()
+            idx = plain_positions(indices)
             if idx.dtype.kind not in "iu":
                 idx = idx.astype(np.int64)
             assert vals.size == idx.size, "values/indices mismatch"
@@ -1206,7 +1207,7 @@ class KVStoreDist(KVStore):
         data = np.asarray(kvs.vals[i], dtype=np.float32).ravel()
         aux = kvs.aux[i] if i < len(kvs.aux) else None
         if kvs.compr in ("bsc", "bsc16") and aux is not None:
-            idx = np.asarray(aux).ravel()
+            idx = plain_positions(aux)   # the LAN's are never coded
             if r_off or self._key_info[kvs.keys[i]].total > _INT32_MAX:
                 telemetry.counter_inc("van.payload_bytes_copied",
                                       idx.nbytes)

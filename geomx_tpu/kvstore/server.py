@@ -85,6 +85,7 @@ from geomx_tpu import profiler
 from geomx_tpu import telemetry
 from geomx_tpu.compression import (Entries, Pairs, SPARSE_TAGS, draw_ahead,
                                    make_compressor, takes_pairs)
+from geomx_tpu.compression.entries import CODED, encode_positions
 from geomx_tpu.compression.device import WireCodec
 from geomx_tpu.kvstore import sharding
 from geomx_tpu.kvstore.base import Command, DATA_INIT
@@ -123,6 +124,25 @@ class _SysModulesUnpickler(pickle.Unpickler):
         if mod is not None:
             return getattr(mod, name)
         return super().find_class(module, name)
+
+
+def _link_positions(idx: np.ndarray) -> np.ndarray:
+    """The positions part of a sparse payload as it crosses the
+    party-global link: the gaps' code (``compression.entries.CODED``,
+    a third of the int32s' bytes at 1%) where the positions ascend
+    strictly, which the encoder finds out as it goes; the positions as
+    they are otherwise (a selection by magnitude)."""
+    coded = encode_positions(idx)
+    return idx if coded is None else coded
+
+
+def _book_link_positions(part: np.ndarray) -> np.ndarray:
+    """``part``, a positions part about to be sent over the
+    party-global link, counted as coded or as a miss."""
+    telemetry.counter_inc("wire.index_bytes_coded" if part.dtype == CODED
+                          else "wire.index_bytes_plain", part.nbytes,
+                          tier="global")
+    return part
 
 
 def _safe_unpickle(data: bytes):
@@ -1478,8 +1498,11 @@ class KVStoreDistServer:
                     keys=[key],
                     vals=[vals.astype(np.float32 if req_compr == "bsc"
                                       else np.float16, copy=False)],
-                    aux=[idx.astype(np.int32, copy=False)], offsets=[lo],
-                    totals=[st.total], lens=[hi - lo], compr=req_compr)
+                    aux=[self._rsp_positions(st, lo, hi, idx)
+                         if req.global_tier
+                         else idx.astype(np.int32, copy=False)],
+                    offsets=[lo], totals=[st.total], lens=[hi - lo],
+                    compr=req_compr)
                 return lambda: srv.response(req, out)
             # Bi-Sparse pull-compression assumes the store holds a SPARSE
             # gradient aggregate (no server-side optimizer — reference
@@ -1570,6 +1593,21 @@ class KVStoreDistServer:
                 ("rsp", key, lo))
             cached = st.rsp_wire[ck] = (st.version, wv, aux)
         return cached[1], cached[2]
+
+    @staticmethod
+    def _rsp_positions(st: _KeyState, lo: int, hi: int,
+                       idx: np.ndarray) -> np.ndarray:
+        """The positions part of a sparse response to the global tier
+        (``_link_positions`` of ``idx``, the positions of the store's
+        range ``[lo, hi)``). A store that is entries is coded once,
+        whoever pulls: every party of a round gets the same buffer, as
+        it gets the same values. Runs under ``st.lock``."""
+        ck = (lo, hi, "positions")
+        cached = st.rsp_wire.get(ck)
+        if (cached is None or st.entries is None
+                or cached[0] is not st.entries):
+            cached = st.rsp_wire[ck] = (st.entries, _link_positions(idx))
+        return _book_link_positions(cached[1])
 
     def _ack_tag(self, r: ReqMeta, n: int, wan: bool = False) -> str:
         """Wire tag for a combined push+pull ack: echo the requester's
@@ -1703,6 +1741,18 @@ class KVStoreDistServer:
             tag = "fp16"
         return self._wire.encode(tag, sub, ("fwd", key, lo))
 
+    def _forward_wire(self, st: _KeyState, key: int, lo: int, sub,
+                      positions=None, span=None):
+        """:meth:`_wan_compress` of a slice the batched forward or its
+        retry sends, as it crosses the link: a sparse selection's
+        positions coded (``_link_positions``), here on the thread that
+        just swept the key. What ``st.fwd_wire`` keeps, so a retry
+        resends these bytes."""
+        wv, aux, t = self._wan_compress(st, key, lo, sub, positions, span)
+        if t in SPARSE_TAGS:
+            aux = _book_link_positions(_link_positions(aux))
+        return wv, aux, t
+
     def _wan_trace_kwargs(self) -> Dict[str, int]:
         """Trace context for WAN re-issues of the current round — the
         forwarded frames inherit the worker push's round id and origin
@@ -1736,7 +1786,7 @@ class KVStoreDistServer:
                 return
             cached = st.fwd_wire.get(lo)
             if cached is None:
-                cached = self._wan_compress(
+                cached = self._forward_wire(
                     st, key, lo, self._outbound_slice(st, lo, hi))
                 st.fwd_wire[lo] = cached
         wire_val, aux, compr = cached
@@ -1783,7 +1833,7 @@ class KVStoreDistServer:
             st.fwd_wire = {}
             total = st.total
             for (g_rank, lo, hi), positions in zip(slices, drawn):
-                cached = self._wan_compress(
+                cached = self._forward_wire(
                     st, key, lo, self._outbound_slice(st, lo, hi),
                     positions, span)
                 st.fwd_wire[lo] = cached

@@ -281,7 +281,8 @@ class DeviceBSCCompressor:
     def decompress_push(self, tag, val, aux, orig_len):
         if tag == "bsc" and orig_len >= 1 << 16:
             return np.asarray(bsc_decompress(
-                np.asarray(val, np.float32), np.asarray(aux, np.int32),
+                np.asarray(val, np.float32),
+                np.asarray(_host().plain_positions(aux), np.int32),
                 orig_len))
         return _host()._generic_decompress(tag, val, aux, orig_len)
 

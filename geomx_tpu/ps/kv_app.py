@@ -92,6 +92,10 @@ class ReqMeta:
     trace_round: int = -1
     trace_chunk: int = -1
     trace_origin: int = -1
+    # the overlay the request came in on (Meta.is_global): a sparse
+    # response to the global tier codes its positions
+    # (compression.entries.CODED), one to the LAN never does
+    global_tier: bool = False
 
 
 def _pack_kv(meta: Meta, kvs: KVPairs) -> Message:
@@ -469,6 +473,7 @@ def _req_meta_of(msg: Message) -> ReqMeta:
         trace_round=msg.meta.trace_round,
         trace_chunk=msg.meta.trace_chunk,
         trace_origin=msg.meta.trace_origin,
+        global_tier=msg.meta.is_global,
     )
 
 

@@ -148,3 +148,127 @@ int64_t gxk_bsc_sweep(float* __restrict u, float* __restrict v, int64_t n,
 }
 
 }  // extern "C"
+
+// A sparse payload's positions as the gaps between them (the party-
+// global link's positions part; compression/entries.py has the numpy
+// form, which is the reference): the first position, then each
+// position's difference to the one before it, every one an unsigned
+// LEB128 varint (7 bits a byte, low bits first, the high bit says
+// "more"). The positions ascend strictly, so a gap after the first is
+// at least 1 and a zero byte there can only be padding.
+//
+// idx[n] (int32, or int64 where `wide`) -> out[cap]. A varint of g
+// takes at most 1 + g / 128 bytes, so ascending positions fit
+// n + idx[n-1] / 128 + 10 (and 10 n + 10 in any case). Returns the
+// bytes written, or -1, whatever was written, where a position is
+// negative or not above the one before it (which is also how `out` can
+// come to be too short).
+template <typename T>
+static int64_t idx_encode(const T* idx, int64_t n, uint8_t* out,
+                          int64_t cap) {
+    uint8_t* p = out;
+    const uint8_t* const full = out + cap - 10;
+    int64_t prev = -1;
+    for (int64_t i = 0; i < n; ++i) {
+        const int64_t at = (int64_t)idx[i];
+        if (at <= prev || p > full) return -1;
+        uint64_t g = (uint64_t)(i ? at - prev : at);
+        prev = at;
+        if (g < 0x4000) {
+            // one byte or two, which at 1% is a coin the processor
+            // cannot call: no branch on it (the second byte is written
+            // either way and the next gap overwrites it where it was
+            // not needed)
+            const uint64_t two = g >= 0x80;
+            p[0] = (uint8_t)(g | (two << 7));
+            p[1] = (uint8_t)(g >> 7);
+            p += 1 + two;
+            continue;
+        }
+        while (g >= 0x80) {
+            *p++ = (uint8_t)(g | 0x80);
+            g >>= 7;
+        }
+        *p++ = (uint8_t)g;
+    }
+    return p - out;
+}
+
+// buf[len] -> exactly n positions in [0, size), out[n]. 0, or what was
+// wrong: 1 the buffer ends inside the list, 2 a varint of more than 64
+// bits, 3 a gap of 0 after the first position, 4 a position >= size,
+// 5 more than padding (up to three zero bytes) behind the n-th.
+template <typename T>
+static int64_t idx_decode(const uint8_t* buf, int64_t len, int64_t n,
+                          int64_t size, T* out) {
+    const uint8_t* p = buf;
+    const uint8_t* const end = buf + len;
+    if (n > 0 && size <= 0) return 4;
+    uint64_t room = (uint64_t)size - 1;     // how far the next may reach
+    uint64_t pos = 0;
+    int64_t i = 0;
+    while (i < n) {
+        uint64_t g;
+        if (i && n - i >= 4 && end - p >= 8) {
+            // four gaps out of one 8-byte load where none of them is
+            // longer than two bytes (no two neighbours with the high
+            // bit set): the next gap's bytes come out of the register,
+            // not out of a load that waits for this gap's length
+            uint64_t w;
+            std::memcpy(&w, p, 8);
+            const uint64_t more = w & 0x8080808080808080ull;
+            if (!(more & (more >> 8))) {
+                int64_t used = 0;
+                for (int k = 0; k < 4; ++k) {
+                    const uint64_t two = (w >> 7) & 1;
+                    g = (w & 0x7f) | ((w >> 8) & (0x7f * two)) << 7;
+                    w >>= 8 << two;
+                    used += 1 + two;
+                    if (!g) return 3;
+                    if (g > room) return 4;
+                    room -= g;
+                    pos += g;
+                    out[i + k] = (T)pos;
+                }
+                p += used;
+                i += 4;
+                continue;
+            }
+        }
+        // the first gap, the last few, and any gap among longer ones
+        if (p >= end) return 1;
+        uint64_t b = *p++;
+        g = b & 0x7f;
+        for (int shift = 7; b & 0x80; shift += 7) {
+            if (p >= end) return 1;
+            b = *p++;
+            if (shift > 63 || (shift == 63 && (b & 0x7e))) return 2;
+            g |= (b & 0x7f) << shift;
+        }
+        if (i && !g) return 3;
+        if (g > room) return 4;
+        room -= g;
+        pos += g;
+        out[i++] = (T)pos;
+    }
+    if (end - p > 3 || (!n && end != p)) return 5;
+    for (; p < end; ++p)
+        if (*p) return 5;
+    return 0;
+}
+
+extern "C" {
+
+int64_t gxk_idx_encode(const void* idx, int64_t n, int wide, uint8_t* out,
+                       int64_t cap) {
+    return wide ? idx_encode((const int64_t*)idx, n, out, cap)
+                : idx_encode((const int32_t*)idx, n, out, cap);
+}
+
+int64_t gxk_idx_decode(const uint8_t* buf, int64_t len, int64_t n,
+                       int64_t size, int wide, void* out) {
+    return wide ? idx_decode(buf, len, n, size, (int64_t*)out)
+                : idx_decode(buf, len, n, size, (int32_t*)out);
+}
+
+}  // extern "C"

@@ -21,6 +21,7 @@ import numpy as np
 import pytest
 
 from geomx_tpu.compression import BSCCompressor
+from geomx_tpu.compression.entries import decode_positions
 from geomx_tpu.config import Config
 from geomx_tpu.kvstore.dist import KVStoreDist
 from geomx_tpu.kvstore.server import KVStoreDistServer
@@ -433,11 +434,12 @@ class RecordingApp:
 
 
 def push_req(sender, ts, compr, head=0, pull=True, num_merge=1,
-             trace_round=-1):
+             trace_round=-1, global_tier=False):
     return ReqMeta(sender=sender, timestamp=ts, customer_id=0, push=True,
                    pull=pull, simple_app=False, head=head, body="",
                    priority=0, version=0, iters=0, compr=compr,
-                   num_merge=num_merge, trace_round=trace_round)
+                   num_merge=num_merge, trace_round=trace_round,
+                   global_tier=global_tier)
 
 
 def server_without_sockets(parties, is_global, fsa_slice_elems=0):
@@ -482,6 +484,16 @@ class RecordingGlobalWorker:
 
     def take_response(self, ts):
         return self.responses.pop(ts)
+
+
+def link_positions(kvs, i=0):
+    """The positions of entry ``i`` of a sparse payload that a server
+    handed the party-global link: coded (``compression.entries.CODED``,
+    padded to whole words), decoded here as the receiver would."""
+    aux = kvs.aux[i]
+    assert aux.dtype == np.uint8 and aux.size % 4 == 0, aux.dtype
+    return decode_positions(aux, np.asarray(kvs.vals[i]).size,
+                            kvs.len_of(i))
 
 
 def party_server_without_sockets(workers, global_servers=1, n=768,

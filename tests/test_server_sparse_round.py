@@ -29,6 +29,7 @@ from geomx_tpu.compression import (BSCCompressor, Entries, FP16Compressor,
                                    MPQCompressor, Pairs, _generic_decompress,
                                    make_compressor, two_bit_dequantize,
                                    two_bit_quantize)
+from geomx_tpu.compression.entries import encode_positions
 from geomx_tpu.kvstore.base import DATA_INIT
 from geomx_tpu.kvstore import server as server_mod
 from geomx_tpu.kvstore.replication import ReplicationManager
@@ -37,7 +38,7 @@ from geomx_tpu.optimizer import SGD
 from geomx_tpu.ps.kv_app import KVPairs
 from geomx_tpu.simulate import InProcessHiPS
 from tests.harness import (KEY, RecordingApp, SingleTier, _parallel,
-                           party_batch_push,
+                           link_positions, party_batch_push,
                            party_server_without_sockets as _party_server,
                            push_req as _req,
                            server_without_sockets as _server)
@@ -62,14 +63,15 @@ def _party_push(s, app, sender, ts, wire, vals, idx, n, num_merge=1):
 
 
 def _global_round(pushes, n, wire, sparse_wire, fsa_slice_elems=0,
-                  rounds=1):
+                  rounds=1, link=False):
     """``rounds`` FSA rounds of one key of ``n`` elements on a global
     server: party p pushes ``pushes[p] = (values, positions)`` with a
     combined push+pull. ``sparse_wire`` sends them as the ``wire``
     payload they are; without it the same pushes go dense (scattered by
     ``_generic_decompress``, the parent's ``decompress_push``) and run the
-    dense ``+=`` and the non-zero filter. Returns the server and the
-    last round's response of every party."""
+    dense ``+=`` and the non-zero filter. ``link``: the requests come in
+    on the global tier as a party server sends them, positions coded.
+    Returns the server and the last round's response of every party."""
     s = _server(len(pushes), True, fsa_slice_elems)
     app = RecordingApp()
     init = KVPairs(keys=[KEY], vals=[np.zeros(n, np.float32)], offsets=[0],
@@ -85,14 +87,15 @@ def _global_round(pushes, n, wire, sparse_wire, fsa_slice_elems=0,
             vdt = np.float16 if wire == "bsc16" else np.float32
             if sparse_wire:
                 kvs = KVPairs(keys=[KEY], vals=[vals.astype(vdt)],
-                              aux=[idx], offsets=[0], totals=[n], lens=[n],
-                              compr=wire)
+                              aux=[encode_positions(idx) if link else idx],
+                              offsets=[0], totals=[n], lens=[n], compr=wire)
             else:
                 dense = _generic_decompress(wire, vals.astype(vdt), idx, n)
                 kvs = KVPairs(keys=[KEY], vals=[dense], offsets=[0],
                               totals=[n], lens=[n])
             acts = []
-            s._handle_one_key(_req(9 + 2 * p, 10 * rnd + p + 1, wire), kvs,
+            s._handle_one_key(_req(9 + 2 * p, 10 * rnd + p + 1, wire,
+                                   global_tier=link), kvs,
                               app, True, True, acts, 0, KEY, 0, n, False)
             for fn in acts:
                 fn()
@@ -111,7 +114,10 @@ def _same_response(got, want):
     assert got.offsets == want.offsets and got.lens == want.lens
     assert got.totals == want.totals and len(got.vals) == len(want.vals)
     for i in range(len(want.vals)):
-        assert got.aux[i].dtype == want.aux[i].dtype == np.int32
+        # int32 positions, or their code where the payload was handed
+        # to the party-global link: equal bytes either way
+        assert got.aux[i].dtype == want.aux[i].dtype
+        assert got.aux[i].dtype in (np.int32, np.uint8)
         np.testing.assert_array_equal(got.aux[i], want.aux[i])
         assert got.vals[i].dtype == want.vals[i].dtype
         np.testing.assert_array_equal(_bits(got.vals[i]),
@@ -129,6 +135,48 @@ def _selections(parties, n, seed):
         out.append((rng.normal(size=k).astype(np.float32),
                     idx.astype(np.int32)))
     return out
+
+
+@pytest.mark.parametrize("wire", ["bsc", "bsc16"])
+@pytest.mark.parametrize("fsa_slice_elems", [0, 300_000])
+@pytest.mark.parametrize("n", [1, 768, 1_000_000])
+def test_the_global_tier_is_answered_in_code_once_a_round(n, fsa_slice_elems,
+                                                          wire):
+    """Two parties' coded pushes on the global tier: the aggregate is
+    the LAN form's to the bit, its positions come back coded (also
+    where the store stayed an array and was filtered: a key of one
+    element), a range's code is made once and every party is handed the
+    same buffer, and each send of it is booked."""
+    pushes = _selections(2, n, seed=n + 2)
+    telemetry.reset()
+    telemetry.enable(True)
+    try:
+        srv, coded = _global_round(pushes, n, wire, True, fsa_slice_elems,
+                                   rounds=2, link=True)
+        booked = (_counters("wire.index_bytes_coded"),
+                  _counters("wire.index_bytes_plain"))
+    finally:
+        telemetry.reset()
+    _srv, plain = _global_round(pushes, n, wire, True, fsa_slice_elems,
+                                rounds=2)
+    sent = 0
+    for got, want in zip(coded, plain):
+        assert got.keys == want.keys and got.offsets == want.offsets
+        assert got.compr == want.compr == wire
+        for i in range(len(want.vals)):
+            np.testing.assert_array_equal(link_positions(got, i),
+                                          want.aux[i])
+            np.testing.assert_array_equal(_bits(got.vals[i]),
+                                          _bits(want.vals[i]))
+            sent += got.aux[i].nbytes
+    a, b = coded
+    assert len(a.aux) == len(b.aux) == (4 if fsa_slice_elems and n > 1e5
+                                        else 1)
+    # a store that is entries: the one buffer; an array (n = 1), filtered
+    # again a puller, equal bytes
+    assert all(x is y if n > 1 else x.tobytes() == y.tobytes()
+               for x, y in zip(a.aux, b.aux))
+    assert booked == (2 * sent, 0)
 
 
 @pytest.mark.parametrize("wire", ["bsc", "bsc16"])
@@ -333,11 +381,11 @@ def test_party_forward_equals_the_dense_recomputation(workers,
             assert kvs.compr == wire and g_rank == pg
             assert kvs.offsets == [lo] and kvs.lens == [hi - lo]
             assert kvs.vals[0].dtype == vdt
-            np.testing.assert_array_equal(kvs.aux[0], idx)
+            np.testing.assert_array_equal(link_positions(kvs), idx)
             np.testing.assert_array_equal(_bits(kvs.vals[0]),
                                           _bits(vals.astype(vdt)))
             _same_response(kvs, pkvs)
-            want_idx.append(kvs.aux[0] + lo)
+            want_idx.append(link_positions(kvs) + lo)
             want_vals.append(kvs.vals[0])
         # the pull-back (here: the forward itself) is every worker's ack
         assert len(acks) == workers
@@ -394,7 +442,7 @@ def test_a_dense_push_makes_the_party_round_dense(first):
     np.testing.assert_array_equal(st.outbound, dense)
     vals, idx, _t = BSCCompressor(0.01).compress_push(dense, (KEY, 0))
     (kvs, _g, _cb), = s.worker_global.pushed
-    np.testing.assert_array_equal(kvs.aux[0], idx)
+    np.testing.assert_array_equal(link_positions(kvs), idx)
     np.testing.assert_array_equal(_bits(kvs.vals[0]), _bits(vals))
 
 
@@ -416,7 +464,7 @@ def test_round_released_early_forwards_the_pairs_it_holds():
     (kvs, _g, _cb), = s.worker_global.pushed
     want = BSCCompressor(0.01).compress_push(
         _generic_decompress("bsc", vals, idx, n), (KEY, 0))
-    np.testing.assert_array_equal(kvs.aux[0], want[1])
+    np.testing.assert_array_equal(link_positions(kvs), want[1])
     np.testing.assert_array_equal(_bits(kvs.vals[0]), _bits(want[0]))
 
 

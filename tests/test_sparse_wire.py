@@ -221,8 +221,9 @@ def test_push_pull_bsc_batch_sums_keys_across_shards(sharded):
 def test_wan_retry_resends_the_same_bytes(server_compression):
     """Two workers a party, and party 0's first WAN forward is given up
     on (as the resender reports it): the per-slice retry of the SAME
-    cycle must carry the bytes of the first attempt, and the aggregate
-    must be the numpy sum. The party server keeps the array its
+    cycle must carry the bytes of the first attempt (a sparse forward's
+    positions in their coded form), and the aggregate must be the numpy
+    sum. The party server keeps the array its
     decompressor built as the round's accumulator and stages it for the
     WAN without copying it; the second worker's push adds into it in
     place; a retry that re-encoded, or a forward that saw a later write,
@@ -233,9 +234,12 @@ def test_wan_retry_resends_the_same_bytes(server_compression):
     wg, van = srv.worker_global, srv.po_global.van
     real_push, real_send = wg.push, van.send
     forwards = []          # per WAN push: {(key, lo): (vals, aux) bytes}
+    positions = []         # the type of every positions part forwarded
     drop = []
 
     def push(kvs, rank, **kw):
+        positions.extend(np.asarray(a).dtype for a in kvs.aux
+                         if a is not None)
         forwards.append({
             (k, kvs.offset_of(i)): (
                 np.asarray(kvs.vals[i]).tobytes(),
@@ -293,6 +297,10 @@ def test_wan_retry_resends_the_same_bytes(server_compression):
     for f in forwards[1:]:
         resent.update(f)
     assert len(first) == len(sizes) and resent == first
+    # a sparse forward's positions cross coded, and the retry resends
+    # the coded bytes it kept (st.fwd_wire), not a second encoding
+    assert (positions == [np.uint8] * 4 if server_compression
+            else not positions)
     for k, n in sizes.items():
         expect = np.zeros(n, np.float32)
         for sel in sels:

@@ -14,8 +14,13 @@ device metric. ``_POOL_HELPERS`` in ``kvstore/server.py`` was set from
 its readings on the chip machine (``PERF.md`` section 6, PR 37).
 
 Usage: python tools/select_bench.py [--cells laguna,gpt2s] [--threads 1,2,3,4]
-                                    [--rounds 3] [--topo]
+                                    [--rounds 3] [--topo] [--positions]
 ``--topo`` keeps a live two-party topology idle beside the measurement.
+``--positions`` reads the other per-key pass of the party-global hop
+and nothing else: ns a position to code and to decode a cell's keys'
+positions (``compression.entries.CODED``) at 1% of each key (a party's
+forward) and at 2% (two parties' union, the pull-back), the native form
+and the numpy form, one thread.
 """
 
 import argparse
@@ -31,6 +36,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 from geomx_tpu import kernels_native  # noqa: E402
 from geomx_tpu.compression import (Pairs, bsc_compress,  # noqa: E402
                                    bsc_sample_positions)
+from geomx_tpu.compression import entries as coding  # noqa: E402
 from geomx_tpu.kvstore.server import _SelectPool  # noqa: E402
 
 THRESHOLD = 0.01
@@ -141,17 +147,58 @@ def timed_rounds(servers, threads, rounds):
     return out
 
 
+def positions_bench(cell: str, rounds: int) -> None:
+    """A line a density: the cell's keys' positions through the code
+    and back, the best of ``rounds`` passes over all keys, as ns a
+    position."""
+    forms = {"numpy": (coding.encode_positions_numpy,
+                       coding.decode_positions_numpy)}
+    if kernels_native.lib() is not None:
+        forms["native"] = (coding.encode_positions, coding.decode_positions)
+    rng = np.random.default_rng(3)
+    for density in (0.01, 0.02):
+        lists = [(np.sort(rng.choice(n, max(int(n * density), 1),
+                                     replace=False, shuffle=False)
+                          ).astype(np.int32), n) for n in CELLS[cell]]
+        count = sum(idx.size for idx, _n in lists)
+        out = {}
+        for name, (encode, decode) in forms.items():
+            enc, dec = [], []
+            for _ in range(rounds):
+                t0 = time.perf_counter()
+                coded = [encode(idx) for idx, _n in lists]
+                t1 = time.perf_counter()
+                back = [decode(c, idx.size, n)
+                        for c, (idx, n) in zip(coded, lists)]
+                t2 = time.perf_counter()
+                enc.append(t1 - t0)
+                dec.append(t2 - t1)
+            assert all((b == idx).all() for b, (idx, _n) in zip(back, lists))
+            out[name] = (f"encode {1e9 * min(enc) / count:6.2f} decode "
+                         f"{1e9 * min(dec) / count:6.2f} ns a position")
+        nbytes = sum(c.size for c in coded)
+        print(f"{cell} positions at {density:.0%}: {count} in "
+              f"{len(lists)} keys, {nbytes / count:.3f} bytes a position "
+              f"coded; " + "; ".join(f"{k}: {v}" for k, v in out.items()),
+              flush=True)
+
+
 def main() -> None:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--cells", default="laguna,gpt2s")
     ap.add_argument("--threads", default="1,2,3,4,6")
     ap.add_argument("--rounds", type=int, default=3)
     ap.add_argument("--topo", action="store_true")
+    ap.add_argument("--positions", action="store_true")
     args = ap.parse_args()
     threads = [int(t) for t in args.threads.split(",")]
 
     print("cpu_count", os.cpu_count(), "affinity",
           len(os.sched_getaffinity(0)), flush=True)
+    if args.positions:
+        for cell in args.cells.split(","):
+            positions_bench(cell, args.rounds)
+        return
     topo = None
     if args.topo:
         from geomx_tpu.simulate import InProcessHiPS

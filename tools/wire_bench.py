@@ -57,6 +57,13 @@ LAYOUTS = {
     "cnn": [(800,), (32,), (25600,), (64,), (51200,), (128,), (65536,),
             (10,), (1176,), (84,)],
     "transformer": None,   # 75 keys, mixed sizes (seeded below)
+    # 150 keys, 16.3M elements: a 12-layer language model's keys (two
+    # vocabulary-sized, then the blocks' matrices, vectors and norms) at
+    # a tenth of their elements, large enough for a shaped link to
+    # charge by the byte
+    "lm150": [(3_859_737,)] * 2 + [(235_929,)] * 24
+    + [(176_947,)] * 12 + [(58_982,)] * 12 + [(78_643,)]
+    + [(307,)] * 50 + [(76,)] * 49,
 }
 
 
@@ -104,7 +111,10 @@ def run_sparse(shapes, threshold: float, rounds: int,
     """Protocol-only round time of the HEADLINE sparse path: the
     combined element-sparse BSC wire (push_pull_bsc_batch — what the
     device-resident trainer sends per round), aggregator-mode PS, top-k
-    payloads of ceil(size*threshold) per key."""
+    payloads of ceil(size*threshold) per key, the party servers
+    re-selecting with Bi-Sparse at the same threshold as the
+    benchmark's cells do (so the round stays sparse on both hops and
+    the party-global link carries coded positions)."""
     from geomx_tpu.simulate import InProcessHiPS
 
     keys = list(range(len(shapes)))
@@ -113,6 +123,8 @@ def run_sparse(shapes, threshold: float, rounds: int,
     times = {}
     try:
         def master_init(kv):
+            kv.set_gradient_compression({"type": "bsc",
+                                         "threshold": threshold})
             for k, sh in zip(keys, shapes):
                 kv.init(k, np.zeros(sh, np.float32))
             kv.wait()
