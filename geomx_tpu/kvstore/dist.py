@@ -1220,11 +1220,13 @@ class KVStoreDist(KVStore):
     def _join_bsc_parts(ps):
         """A key's result from its parts: the part itself where there is
         one, the parts joined (a copy, booked) where a key came back in
-        several."""
+        several, in the key's order whichever shard answered first: a
+        key's positions ascend, and the trainer's apply is told so."""
         if not ps:
             return np.zeros(0, np.float32), np.zeros(0, np.int64)
         if len(ps) == 1:
             return ps[0]
+        ps = sorted(ps, key=lambda p: p[1][0] if len(p[1]) else -1)
         telemetry.counter_inc(
             "van.payload_bytes_copied",
             sum(p[0].nbytes + p[1].nbytes for p in ps))
@@ -1261,7 +1263,8 @@ class KVStoreDist(KVStore):
         own part, read-only views of its frame with the wire's int32
         indices, where a key came back in one part from offset 0; int64
         indices where a shard's offset or the key's size asks for them;
-        a join where there were several parts. It completes each key as
+        a join where there were several parts, in the key's order: the
+        indices of a key ascend and are distinct. It completes each key as
         its last response lands — apply key i while key j is still on
         the wire. Give-ups surface through ``fut.wait()``."""
         assert len(set(keys)) == len(keys), "duplicate keys in one round"

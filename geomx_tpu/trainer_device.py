@@ -60,7 +60,7 @@ import numpy as np
 
 from geomx_tpu import profiler, telemetry
 from geomx_tpu.kvstore.frontier import plan_chunks
-from geomx_tpu.ops.select import topk_flat
+from geomx_tpu.ops.select import leaving, topk_flat
 
 __all__ = ["DeviceResidentTrainer"]
 
@@ -193,19 +193,36 @@ class DeviceResidentTrainer:
             u = 0.9 * u + g
             v = v + u
             # model-flat positions, ascending and distinct (keys in
-            # flat order, each key's ascending), and v there
-            idx, vals = topk_flat(v, offsets, sizes, ks)
-            ordered = dict(indices_are_sorted=True, unique_indices=True)
-            u = u.at[idx].set(0.0, **ordered)
-            if wire16:
-                narrowed = vals.astype(jnp.float16).astype(jnp.float32)
-                # selected coordinates keep the narrowing error as their
-                # residual (instead of resetting to zero) — it rides
-                # into the next round's accumulation
-                v = v.at[idx].set(vals - narrowed, **ordered)
-                vals = narrowed
-            else:
-                v = v.at[idx].set(0.0, **ordered)
+            # flat order, each key's ascending), v there, and each
+            # key's rule of membership
+            idx, vals, rules = topk_flat(v, offsets, sizes, ks)
+            # the selected leave u and v in one dense masked pass over
+            # both, in place, where two scatters wrote the k positions
+            # one by one. The mask (a byte an element) is written a key
+            # at a time, the keys of one size under one traced body
+            with jax.named_scope("bsc_reset"):
+                gone = jnp.zeros(v.shape, bool)
+                for members, t, cut in rules:
+                    size = sizes[members[0]]
+                    at = jnp.asarray([offsets[i] for i in members],
+                                     jnp.int32)
+
+                    def mark(j, gone):
+                        seg = jax.lax.dynamic_slice(v, (at[j],), (size,))
+                        return jax.lax.dynamic_update_slice(
+                            gone, leaving(seg, t[j], cut[j]), (at[j],))
+
+                    gone = jax.lax.fori_loop(0, len(members), mark, gone)
+                u = jnp.where(gone, 0.0, u)
+                if wire16:
+                    # a selected coordinate keeps what the narrowing
+                    # drops as its residual (instead of resetting to
+                    # zero): it rides into the next round's accumulation
+                    narrowed = v.astype(jnp.float16).astype(jnp.float32)
+                    v = jnp.where(gone, v - narrowed, v)
+                    vals = vals.astype(jnp.float16).astype(jnp.float32)
+                else:
+                    v = jnp.where(gone, 0.0, v)
             return loss, vals, idx, u, v
 
         def select(flat, u, v, X, y):
@@ -234,8 +251,12 @@ class DeviceResidentTrainer:
             a, b = ch.items[0], ch.items[-1]
             sel_lo, sel_hi = int(self._kofs[a]), int(self._kofs[b + 1])
             flo, fhi = int(self._offsets[a]), int(self._offsets[b + 1])
-            meta.append((sel_lo, sel_hi, flo, fhi - flo,
-                         nw * (sel_hi - sel_lo)))
+            fsize, cap = fhi - flo, nw * (sel_hi - sel_lo)
+            if fsize + cap >= 1 << 31:
+                # _chunk_up's pad positions run from fsize to fsize+cap
+                raise ValueError("a chunk's elements and upload slots "
+                                 f"together must stay under 2^31: {ch}")
+            meta.append((sel_lo, sel_hi, flo, fsize, cap))
         self._chunk_meta = meta
         sel_bounds = [(m[0], m[1]) for m in meta]
 
@@ -259,14 +280,17 @@ class DeviceResidentTrainer:
         @partial(jax.jit, static_argnums=(3, 4), donate_argnums=(0, 1))
         def apply_chunk(flat, mom, up, flo, fsize):
             # up layout (see _chunk_up): [vals(cap) bitcast i32,
-            # idx(cap) CHUNK-relative]; pad slots are (0.0, 0) — a
-            # scatter-add no-op, and position 0 of the chunk is a real
-            # coordinate so adding 0.0 is exact (aggregated nonzeros
-            # are never ±0.0)
+            # idx(cap) CHUNK-relative]. The positions ascend and are
+            # distinct (the aggregate is sorted unique entries, keys in
+            # flat order), the pad slots go on ascending past the
+            # chunk's end and drop: told so, XLA puts no sort before
+            # the scatter
             cap = up.shape[0] // 2
             vals = jax.lax.bitcast_convert_type(up[:cap], jnp.float32)
             cidx = up[cap:]
-            g = jnp.zeros((fsize,), flat.dtype).at[cidx].add(vals)
+            g = jnp.zeros((fsize,), flat.dtype).at[cidx].add(
+                vals, indices_are_sorted=True, unique_indices=True,
+                mode="drop")
             seg = jax.lax.dynamic_slice(flat, (flo,), (fsize,))
             if mom is None:
                 return (jax.lax.dynamic_update_slice(
@@ -367,9 +391,11 @@ class DeviceResidentTrainer:
     def _book(self, head: np.ndarray) -> float:
         """The loss from the head of a download; grad_fn's counts, if
         any, go to their telemetry counters, and so does the number of
-        keys this round selected by threshold."""
+        keys this round selected by threshold and reset in a dense
+        masked pass (every key: neither has a second path)."""
         telemetry.counter_inc("step.select_threshold_keys",
                               len(self._sizes))
+        telemetry.counter_inc("step.dense_reset_keys", len(self._sizes))
         head = np.atleast_1d(head)
         for name, value in zip(self._aux_names, head[1:]):
             telemetry.counter_inc(name, float(value))
@@ -452,8 +478,10 @@ class DeviceResidentTrainer:
     def _chunk_up(self, ci: int, agg: Dict) -> np.ndarray:
         """Assemble chunk ``ci``'s fixed-size upload from its keys'
         aggregated (values, key-relative indices): [vals(cap) bitcast
-        i32, idx(cap) chunk-relative], zero-padded."""
-        _sel_lo, _sel_hi, flo, _fsize, cap = self._chunk_meta[ci]
+        i32, idx(cap) chunk-relative]; the slots left over hold value
+        0.0 at positions that go on ascending from the chunk's end,
+        which ``apply_chunk`` drops."""
+        _sel_lo, _sel_hi, flo, fsize, cap = self._chunk_meta[ci]
         ups, upi = [], []
         for i in self._chunks[ci].items:
             avals, aidx = agg[self.begin_key + i]
@@ -471,6 +499,7 @@ class DeviceResidentTrainer:
         up = np.zeros(2 * cap, np.int32)
         up[:n] = np.asarray(cat_v, np.float32).view(np.int32)
         up[cap:cap + n] = cat_i.astype(np.int32)
+        up[cap + n:] = np.arange(fsize, fsize + cap - n, dtype=np.int32)
         return up
 
     def step(self, X, y) -> float:

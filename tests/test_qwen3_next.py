@@ -417,8 +417,14 @@ def test_sliced_vocabulary_draws_and_scores_only_held_rows():
 # -- (v) one round through the system ---------------------------------------------
 
 def _by_sorting(x, k):
+    """What ``ops.select.topk_by_magnitude`` stands in for: the
+    positions ``lax.top_k`` gives, put in ascending order, x there,
+    and the rule read off them: the smallest magnitude among them and
+    where the last of those at it lies."""
     pos = jnp.sort(jax.lax.top_k(jnp.abs(x), k)[1]).astype(jnp.int32)
-    return pos, x[pos]
+    bits = jax.lax.bitcast_convert_type(jnp.abs(x[pos]), jnp.int32)
+    t = bits.min()
+    return pos, x[pos], t, jnp.max(jnp.where(bits == t, pos, -1)) + 1
 
 
 def _one_round(leaves, grad_step, toks):

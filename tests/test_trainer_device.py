@@ -214,27 +214,23 @@ def test_local_store_sparse_round_refuses_an_updater():
 
 def _by_sorting(x, k):
     """What ``ops.select.topk_by_magnitude`` stands in for: the
-    positions ``lax.top_k`` gives, put in ascending order, and x
-    there."""
+    positions ``lax.top_k`` gives, put in ascending order, x there,
+    and the rule read off them: the smallest magnitude among them and
+    where the last of those at it lies."""
     pos = jnp.sort(jax.lax.top_k(jnp.abs(x), k)[1]).astype(jnp.int32)
-    return pos, x[pos]
+    bits = jax.lax.bitcast_convert_type(jnp.abs(x[pos]), jnp.int32)
+    t = bits.min()
+    return pos, x[pos], t, jnp.max(jnp.where(bits == t, pos, -1)) + 1
 
 
 def _three_rounds(wire_codec):
     """Every leaf of both workers after three rounds of a two-party job
     over keys of several sizes (two of them alike), with ties in the
-    gradient; and what the rounds booked on the selection's counter."""
+    gradient; and what the rounds booked on the selection's and the
+    reset's counters."""
     from geomx_tpu import telemetry
 
-    shapes = [(40, 16), (640,), (7,), (40, 16), (3, 50)]
-    rng = np.random.default_rng(11)
-    target = [np.round(rng.standard_normal(s) * 4).astype(np.float32) / 4
-              for s in shapes]
-
-    def grad_fn(leaves, X, y):
-        diffs = [w - jnp.asarray(t) + X for w, t in zip(leaves, target)]
-        return sum(0.5 * jnp.sum(d * d) for d in diffs), diffs
-
+    shapes, grad_fn = _SHAPES, _bowl()
     extra = {"wire_codec": wire_codec} if wire_codec else {}
     topo = InProcessHiPS(num_parties=2, workers_per_party=1,
                          extra_cfg=extra).start()
@@ -266,8 +262,10 @@ def _three_rounds(wire_codec):
         topo.stop()
         after = telemetry.snapshot()["counters"]
         telemetry.enable(was_on)
-    name = "step.select_threshold_keys"
-    return results, after.get(name, 0) - before.get(name, 0), len(shapes)
+    booked = {name: after.get(name, 0) - before.get(name, 0)
+              for name in ("step.select_threshold_keys",
+                           "step.dense_reset_keys")}
+    return results, booked, len(shapes)
 
 
 @pytest.mark.parametrize("wire_codec", ["", "fp16"])
@@ -278,14 +276,257 @@ def test_selection_without_a_sort_trains_bit_for_bit(wire_codec,
     worker bit-equal (the same set leaves each key, so the servers sum
     the same pairs), ``bsc`` and ``bsc16``; every key of every round is
     booked on the selection's counter (no key keeps a sort, so there is
-    no second counter)."""
+    no second counter) and on the dense reset's (no key keeps its
+    scatters)."""
     from geomx_tpu.ops import select
 
     got, booked, keys = _three_rounds(wire_codec)
-    assert booked == keys * 3 * 2       # rounds x workers
+    assert booked == {"step.select_threshold_keys": keys * 3 * 2,
+                      "step.dense_reset_keys": keys * 3 * 2}  # x workers
     monkeypatch.setattr(select, "topk_by_magnitude", _by_sorting)
     want, _booked, _keys = _three_rounds(wire_codec)
     for widx in (0, 1):
         for a, b in zip(got[widx], want[widx]):
             np.testing.assert_array_equal(a, b)
     assert any(np.any(l != 0) for l in got[0])
+
+
+# -- the step scatters only where it must ------------------------------------
+
+_SHAPES = [(40, 16), (640,), (7,), (40, 16), (3, 50), (129,)]
+
+
+def _bowl():
+    """A gradient with ties in it (quarters) over keys of several
+    sizes, two of them alike and two no multiple of 128."""
+    rng = np.random.default_rng(11)
+    target = [np.round(rng.standard_normal(s) * 4).astype(np.float32) / 4
+              for s in _SHAPES]
+
+    def grad_fn(leaves, X, y):
+        diffs = [w - jnp.asarray(t) + X for w, t in zip(leaves, target)]
+        return sum(0.5 * jnp.sum(d * d) for d in diffs), diffs
+
+    return grad_fn
+
+
+def _local_trainer(wire_codec="", slice_bytes=0, **kw):
+    """A trainer over the local store, which answers a round with the
+    selection itself; the two settings the device step reads off a
+    store's configuration are handed to it as one."""
+    from types import SimpleNamespace
+
+    from geomx_tpu.kvstore import create as kv_create
+
+    kv = kv_create("local")
+    kv.cfg = SimpleNamespace(wire_codec=wire_codec,
+                             p3_slice_bytes=slice_bytes)
+    kw = dict(dict(threshold=0.05, learning_rate=0.1, momentum=0.9), **kw)
+    return DeviceResidentTrainer(
+        [np.zeros(s, np.float32) for s in _SHAPES], kv, _bowl(), **kw)
+
+
+def _scattering_reference(tr, wire16, lr=0.1, momentum=0.9):
+    """The round as it was before the step stopped scattering what it
+    can write densely: ``lax.top_k`` a key, ``u`` and ``v`` reset by
+    ``x.at[idx].set`` at the selected positions, the aggregate applied
+    by a scatter-add nobody told anything."""
+    grad_fn = _bowl()
+    offsets = [int(o) for o in tr._offsets[:-1]]
+
+    @jax.jit
+    def fwd(flat, u, v, X):
+        leaves = [flat[o:o + s].reshape(sh) for o, s, sh in
+                  zip(offsets, tr._sizes, _SHAPES)]
+        _loss, grads = grad_fn(leaves, X, None)
+        u = 0.9 * u + jnp.concatenate([g.reshape(-1) for g in grads])
+        v = v + u
+        idx = jnp.concatenate([
+            jnp.sort(jax.lax.top_k(jnp.abs(v[o:o + s]), k)[1]) + o
+            for o, s, k in zip(offsets, tr._sizes, tr._ks)])
+        vals = v[idx]
+        u = u.at[idx].set(0.0)
+        if wire16:
+            narrowed = vals.astype(jnp.float16).astype(jnp.float32)
+            v = v.at[idx].set(vals - narrowed)
+            vals = narrowed
+        else:
+            v = v.at[idx].set(0.0)
+        return vals, idx, u, v
+
+    @jax.jit
+    def apply(flat, mom, vals, idx):
+        mom = momentum * mom + jnp.zeros_like(flat).at[idx].add(vals)
+        return flat - lr * mom, mom
+
+    return fwd, apply
+
+
+@pytest.mark.parametrize("slice_bytes", [0, 96])
+@pytest.mark.parametrize("wire_codec", ["", "fp16"])
+def test_three_rounds_equal_the_scattering_reference(wire_codec,
+                                                     slice_bytes):
+    """``flat``, momentum, ``u``, ``v`` and what goes to the wire after
+    each of three rounds, bit for bit those of the reference that
+    resets by scatter and applies by an unhinted scatter-add: float32
+    and ``bsc16`` wires, the round in one chunk and in several."""
+    tr = _local_trainer(wire_codec, slice_bytes)
+    assert (len(tr._chunks) == 1) == (slice_bytes == 0)
+    fwd, apply = _scattering_reference(tr, bool(wire_codec))
+    sent = []
+    inner = tr.kv.push_pull_bsc_batch_async
+
+    def recording(keys, vlist, ilist, **kw):
+        sent.append((list(keys), [np.array(a) for a in vlist],
+                     [np.array(a) for a in ilist]))
+        return inner(keys, vlist, ilist, **kw)
+
+    tr.kv.push_pull_bsc_batch_async = recording
+    flat, mom, u, v = (jnp.zeros(tr.total, jnp.float32) for _ in range(4))
+    for rnd in range(3):
+        X = jnp.asarray(0.5 - 0.25 * rnd)
+        del sent[:]
+        tr.step(X, None)
+        vals, idx, u, v = fwd(flat, u, v, X)
+        flat, mom = apply(flat, mom, vals, idx)
+        for name, got, want in (("flat", tr._flat, flat),
+                                ("momentum", tr._mom, mom),
+                                ("u", tr._u, u), ("v", tr._v, v)):
+            np.testing.assert_array_equal(
+                np.asarray(got).view(np.uint32),
+                np.asarray(want).view(np.uint32),
+                err_msg=f"{name} after round {rnd}")
+        # the packs, as the store got them: key by key the reference's
+        # values and key-relative positions
+        keys = [k for ks, _v, _i in sent for k in ks]
+        assert keys == list(range(len(_SHAPES)))
+        np.testing.assert_array_equal(
+            np.concatenate([a for _k, vs, _i in sent for a in vs])
+            .view(np.uint32), np.asarray(vals).view(np.uint32))
+        np.testing.assert_array_equal(
+            np.concatenate([a + int(tr._offsets[k]) for ks, _v, il in sent
+                            for k, a in zip(ks, il)]), np.asarray(idx))
+    assert np.count_nonzero(np.asarray(tr._v)) > 0 < np.count_nonzero(flat)
+
+
+def _primitives(jaxpr):
+    """Names of the primitives of a jaxpr, those of its sub-jaxprs
+    (loops, calls) included."""
+    names = set()
+    for eqn in jaxpr.eqns:
+        names.add(eqn.primitive.name)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            names |= _primitives(sub)
+    return names
+
+
+def test_the_step_scatters_only_where_it_must():
+    """``fwd_chunks`` writes nothing by ``x.at[idx].set`` any more (the
+    one scatter left is the compaction's ``scatter-max`` of n/128 row
+    marks in ``ops/select.py``), and ``apply_chunk``'s scatter-add is
+    told that its positions ascend, are distinct and may lie outside."""
+    tr = _local_trainer()
+    X = jnp.asarray(0.5)
+    names = _primitives(jax.make_jaxpr(tr._fwd_chunks)(
+        tr._flat, tr._u, tr._v, X, None).jaxpr)
+    assert "scatter" not in names and "sort" not in names, names
+    assert "scatter_max" in names or "scatter-max" in names, names
+    _lo, _hi, flo, fsize, cap = tr._chunk_meta[0]
+    text = tr._apply_chunk.lower(
+        tr._flat, tr._mom, jnp.zeros(2 * cap, jnp.int32), flo,
+        fsize).as_text()
+    scatters = [line for line in text.splitlines()
+                if "stablehlo.scatter" in line]
+    assert len(scatters) == 1, text
+    assert "indices_are_sorted = true" in scatters[0]
+    assert "unique_indices = true" in scatters[0]
+    assert "stablehlo.sort" not in text
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    """A described TPU v5e chip, for compiling without one."""
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:    # noqa: BLE001 — whatever keeps libtpu away
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def test_the_chips_compiler_puts_no_sort_before_the_apply(one_chip):
+    """Compiled for a v5e, ``apply_chunk`` holds its scatter and no
+    sort: the hints reach the compiler that used to sort the positions
+    itself."""
+    tr = _local_trainer()
+    _lo, _hi, flo, fsize, cap = tr._chunk_meta[0]
+    flat = jax.ShapeDtypeStruct((tr.total,), jnp.float32,
+                                sharding=one_chip)
+    up = jax.ShapeDtypeStruct((2 * cap,), jnp.int32, sharding=one_chip)
+    text = tr._apply_chunk.lower(flat, flat, up, flo,
+                                 fsize).compile().as_text()
+    assert " scatter(" in text and " sort(" not in text
+
+
+def test_chunk_up_pads_past_the_chunk_in_ascending_order():
+    """The slots an aggregate leaves over hold 0.0 at positions that go
+    on ascending from the chunk's end, every chunk from its own; an
+    upload at full capacity has no pad and applies all the same."""
+    tr = _local_trainer(slice_bytes=96, momentum=0.0, learning_rate=1.0)
+    assert len(tr._chunks) > 1
+    padded = 0
+    for ci, ch in enumerate(tr._chunks):
+        _lo, _hi, flo, fsize, cap = tr._chunk_meta[ci]
+        first = ch.items[0]
+        agg = {i: (np.zeros(0, np.float32), np.zeros(0, np.int64))
+               for i in ch.items}
+        agg[first] = (np.array([2.0], np.float32), np.array([3]))
+        up = tr._chunk_up(ci, agg)
+        assert up.dtype == np.int32 and up.shape == (2 * cap,)
+        np.testing.assert_array_equal(up[:cap].view(np.float32)[1:], 0.0)
+        assert up[cap] == 3
+        np.testing.assert_array_equal(
+            up[cap + 1:], np.arange(fsize, fsize + cap - 1))
+        padded += cap > 1
+    assert padded
+    # full capacity: every slot of the last chunk a real entry
+    want = np.asarray(tr._flat).copy()
+    sizes = [tr._sizes[i] for i in ch.items]
+    assert cap <= sum(sizes)
+    taken, agg = 0, {}
+    for i, size in zip(ch.items, sizes):
+        n = min(size, cap - taken)
+        agg[i] = (np.full(n, 0.5, np.float32), np.arange(n))
+        want[tr._offsets[i]:tr._offsets[i] + n] -= 0.5
+        taken += n
+    up = tr._chunk_up(ci, agg)
+    assert taken == cap and np.all(np.diff(up[cap:]) > 0)
+    assert np.all(up[cap:] < fsize)
+    tr._flat, tr._mom = tr._apply_chunk(tr._flat, tr._mom,
+                                        jnp.asarray(up), flo, fsize)
+    np.testing.assert_array_equal(np.asarray(tr._flat), want)
+    with pytest.raises(RuntimeError, match="exceeds chunk upload"):
+        agg[first] = (np.ones(sizes[0] + 1, np.float32),
+                      np.arange(sizes[0] + 1))
+        tr._chunk_up(ci, agg)
+
+
+def test_a_sharded_keys_parts_join_in_the_keys_order():
+    """Shards answer in any order; what the trainer gets for the key
+    ascends all the same (``apply_chunk`` is told so), an empty part
+    anywhere among them, and one part alone is handed on as it is."""
+    from geomx_tpu.kvstore.dist import KVStoreDist
+
+    late = (np.array([3.0, 4.0], np.float32), np.array([70, 90]))
+    early = (np.array([1.0], np.float32), np.array([5], np.int32))
+    empty = (np.zeros(0, np.float32), np.zeros(0, np.int64))
+    for parts in ([late, empty, early], [early, late], [empty, late, early]):
+        vals, idx = KVStoreDist._join_bsc_parts(parts)
+        np.testing.assert_array_equal(idx, [5, 70, 90])
+        np.testing.assert_array_equal(vals, [1.0, 3.0, 4.0])
+    assert KVStoreDist._join_bsc_parts([late]) is late
+    for parts in ([], [empty], [empty, empty]):
+        vals, idx = KVStoreDist._join_bsc_parts(parts)
+        assert vals.size == 0 and idx.size == 0
