@@ -59,7 +59,7 @@ THRESHOLD, LR, MOMENTUM = 0.01, 0.05, 0.9
 # wall-clock; ten keep a cold run, mesh variant included, inside the
 # deadline.
 STEPS = 10
-FLASH_SEQS = (512, 2048)    # the lengths bench.py's attention rows use
+FLASH_SEQS = (512, 2048)    # under and at the kernels' KERNEL_MIN_T
 
 # One overall deadline inside the 1200 s contract: on expiry every
 # thread's stack is dumped and the process exits 1. The kv timeouts sit

@@ -7,10 +7,10 @@ inter-DC hop by the HiPS server. Device (JAX/XLA + Pallas) versions live
 in ``geomx_tpu.ops``; ``make_compressor({"type": "bsc", "device": true})``
 or GEOMX_DEVICE_COMPRESSION=1 routes the server's WAN hop through them —
 for multi-million-element keys the device top-k dominates the host
-partition (4.9-9.2x at 8M elements on a v5e; tools/compress_bench.py:
-measured against the permutation-based host pass, whose shuffle of
-all n positions was most of its cost; not re-measured since the sample
-is drawn in O(sample)). Placement matches the reference: the
+partition (4.9-9.2x at 8M elements on a v5e, measured against the
+permutation-based host pass, whose shuffle of all n positions was most
+of its cost; not re-measured since the sample is drawn in O(sample)).
+Placement matches the reference: the
 LAN tier is uncompressed; party servers compress the aggregated gradient
 before the WAN push (BSCompress, :191), the global server decompresses,
 aggregates, and compresses pull responses with the non-zero filter scaled

@@ -5,7 +5,7 @@ kvstore_dist_server.h:1296 ``merged += recved`` runs as engine-scheduled
 elemwise kernels; optimizer steps are C++ for built-ins). numpy holds the
 GIL for these op sizes, so the per-key-locked server still serializes on
 math; ctypes releases the GIL for the call's duration, restoring thread
-scaling (tools/server_bench.py shows the difference).
+scaling.
 
 Same build-on-demand as ps/native.py (native_lib.ensure_built).
 Disable with GEOMX_NATIVE_KERNELS=0; everything falls back to numpy.

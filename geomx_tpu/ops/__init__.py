@@ -255,8 +255,8 @@ class DeviceBSCCompressor:
 
     Per-key momentum (u) and accumulation (v) stay resident on the
     accelerator; only the compressed (values, indices) pair crosses to
-    host for the wire. Device-vs-host pack throughput per size:
-    tools/compress_bench.py (on-chip figures: not measured).
+    host for the wire (device-vs-host pack throughput on the chip: not
+    measured).
     """
 
     type_name = "bsc"

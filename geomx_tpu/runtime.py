@@ -2,7 +2,7 @@
 where compiled programs are cached, and how compilations are counted.
 
 Every entry point that measures or proves something on the accelerator
-(``chip_smoke.py``, ``bench.py`` phases, ``tools/attention_bench.py``)
+(``benchmark/run.py``, ``chip_smoke.py``, ``tools/attention_bench.py``)
 goes through :func:`require_tpu`; none of them sets ``jax_platforms`` or
 falls back to the CPU. Tests and the CPU launch scripts pick the CPU
 from OUTSIDE (``JAX_PLATFORMS=cpu``), never in here.

@@ -7,8 +7,8 @@ our Postoffice/Van are instance-scoped (no process-global singletons,
 unlike ps-lite), a whole multi-party HiPS cluster can also run inside ONE
 process on threads — every protocol byte still crosses real loopback
 sockets through the real transport. This is the on-chip topology: a chip
-belongs to one process, so chip_smoke.py and bench.py run every role here
-(infra roles on host threads, worker compute on the accelerator); the
+belongs to one process, so benchmark/run.py and chip_smoke.py run every
+role here (infra roles on host threads, worker compute on the chip); the
 multi-process launch scripts are CPU protocol demos.
 """
 

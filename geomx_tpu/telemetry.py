@@ -30,8 +30,7 @@ Snapshots: :func:`snapshot` returns plain dicts; :func:`snapshot_json`
 the canonical JSON; :func:`export_round` writes one file per round
 into ``GEOMX_TELEMETRY_DIR`` (Config.telemetry_dir) for the chaos
 matrix to collect. :func:`wan_bytes` sums the global-tier send byte
-counters — the number ROADMAP item 2's "WAN bytes/round down >=4x"
-gates on, embedded by bench.py as ``wan_bytes_per_round``.
+counters — what ``benchmark/run.py`` reports as ``wan_mb_per_round``.
 """
 
 from __future__ import annotations

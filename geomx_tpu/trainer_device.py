@@ -54,7 +54,7 @@ grads, the standard treatment).
 from __future__ import annotations
 
 from functools import partial
-from typing import Any, Callable, Dict, List, Sequence, Tuple
+from typing import Any, Callable, Dict, List, Sequence
 
 import numpy as np
 
@@ -87,8 +87,8 @@ class DeviceResidentTrainer:
         heavy-ball momentum compounds with the u-buffer's own 0.9
         momentum correction and diverges, and per-coordinate adaptive
         optimizers (Adam) see each coordinate only ~threshold*rounds
-        times so their moment estimates starve (both measured —
-        bench.py bench_hips_bsc docstring)."""
+        times so their moment estimates starve (both measured on the
+        demo CNN; SGD at lr 0.05 converges on every platform there)."""
         import jax
         import jax.numpy as jnp
 
@@ -561,49 +561,6 @@ class DeviceResidentTrainer:
                     self._flat, self._mom = self._apply_chunk(
                         self._flat, self._mom, up_d, flo, fsize)
         return loss
-
-    def step_timed(self, X, y) -> Tuple[float, Dict[str, float]]:
-        """One round with an honest per-phase wall-ms breakdown
-        (compute / d2h / wire / h2d / apply), every phase fenced on a
-        VALUE fetch or explicit block (PERF.md round-5 honesty rules).
-        Phases run serially — overlap is deliberately OFF here so each
-        bucket is attributable; use it for auditing (bench.py round
-        breakdown), not throughput."""
-        import time
-
-        import jax
-
-        X, y = self._place_batch(X, y)
-        self._count_mesh_round()
-        t0 = time.perf_counter()
-        loss_d, packs = self._run_fwd_chunks(X, y)
-        loss = self._book(np.asarray(loss_d))   # fences the program
-        t1 = time.perf_counter()
-        arrs = [np.asarray(p) for p in packs]
-        t2 = time.perf_counter()
-        futs = [self.kv.push_pull_bsc_batch_async(
-                    *self._chunk_wire_parts(ci, arrs[ci]),
-                    priority=-ci, slice_bytes=0)
-                for ci in range(len(self._chunks))]
-        aggs = [f.results() for f in futs]
-        t3 = time.perf_counter()
-        ups_d = [jax.device_put(self._chunk_up(ci, aggs[ci]))
-                 for ci in range(len(self._chunks))]
-        jax.block_until_ready(ups_d)
-        t4 = time.perf_counter()
-        for ci, up_d in enumerate(ups_d):
-            _sl, _sh, flo, fsize, _cap = self._chunk_meta[ci]
-            self._flat, self._mom = self._apply_chunk(
-                self._flat, self._mom, up_d, flo, fsize)
-        float(np.asarray(self._flat[0:1])[0])   # value fetch = fence
-        t5 = time.perf_counter()
-        return loss, {
-            "compute_ms": (t1 - t0) * 1e3,
-            "d2h_ms": (t2 - t1) * 1e3,
-            "wire_ms": (t3 - t2) * 1e3,
-            "h2d_ms": (t4 - t3) * 1e3,
-            "apply_ms": (t5 - t4) * 1e3,
-        }
 
     # -- escape hatch ----------------------------------------------------
 

@@ -7,7 +7,7 @@
 // Our server's hot loop is numpy, which holds the GIL for these sizes —
 // flattening multi-key throughput no matter how the locking is arranged.
 // ctypes calls release the GIL, so these plain-C loops restore true
-// thread scaling for concurrent per-key handling (tools/server_bench.py).
+// thread scaling for concurrent per-key handling.
 //
 // Build: g++ -O3 -ffp-contract=off -std=c++17 -fPIC -shared
 // (geomx_tpu/kernels_native.py,

@@ -39,6 +39,7 @@ from geomx_tpu.optimizer import SGD
 from geomx_tpu.simulate import InProcessHiPS
 from geomx_tpu.trainer import Trainer
 from geomx_tpu.trainer_device import DeviceResidentTrainer
+from tests.harness import DEADLINES, _Background, _wait_declared
 
 ROUNDS = 5
 SHAPES = [(4,), (2, 2)]
@@ -340,13 +341,22 @@ def test_mesh_party_survives_remote_server_kill():
     a REMOTE party's server is killed mid-training. The surviving mesh
     party's round must not hang — it either completes once the global
     tier releases the stalled aggregation (elastic membership) or
-    raises the RoundAborted family, within a bounded wait."""
+    raises the RoundAborted family, within a bounded wait.
+
+    Ordered by events, not by sleeps: the victim sits in two tiers, and
+    each tier's scheduler sees its heartbeats lapse in its own time.
+    The survivor's round ends on the GLOBAL tier's declaration; the
+    replacement is started only once the victim's own party has
+    declared it too, because a registration that reaches a scheduler
+    before the lapse is a newcomer to it, not a handover of the slot."""
     from geomx_tpu.kvstore.server import KVStoreDistServer
 
+    # 15 heartbeats to a time-out: a live node's heartbeat thread,
+    # starved beside the suite's other workers, does not miss that many
     sim = InProcessHiPS(
         num_parties=2, workers_per_party=2, party_mesh_size=2,
         extra_cfg={"heartbeat_interval_s": 0.2,
-                   "heartbeat_timeout_s": 1.0}).start()
+                   "heartbeat_timeout_s": 3.0}).start()
     try:
         sim.master.set_optimizer(SGD(learning_rate=1.0))
         w0 = np.zeros(6, np.float32)
@@ -362,47 +372,48 @@ def test_mesh_party_survives_remote_server_kill():
 
         sim.master.init(0, w0)
         sim.master.wait()
-        sim.run_workers(init_and_round, timeout=120)
+        sim.run_workers(init_and_round, timeout=DEADLINES["start_s"])
 
         # kill party 1's server (servers[0] is the global server);
         # party 0's mesh store keeps ITS server — the WAN gateway
         victim = sim.servers[2]
         assert not victim.is_global_server
+        local_id = victim.po_local.my_id
+        global_id = victim.po_global.my_id
         victim.crash()
-
-        survivor = sim.workers[0]
-        done = {}
 
         def survivor_round():
             outb = np.zeros_like(w0)
-            t0 = time.monotonic()
             try:
-                survivor.push_pull(0, _g, outb, priority=0)
-                survivor.wait(timeout=60.0)
-                done["outcome"] = "completed"
+                sim.workers[0].push_pull(0, _g, outb, priority=0)
+                sim.workers[0].wait(timeout=60.0)
+                return "completed"
             except RoundAborted:
-                done["outcome"] = "aborted"
+                return "aborted"
             except TimeoutError:
-                done["outcome"] = "timeout"
-            done["elapsed"] = time.monotonic() - t0
+                return "timeout"
 
-        t = threading.Thread(target=survivor_round, daemon=True)
-        t.start()
-        t.join(90.0)
-        assert not t.is_alive(), (
+        survivor = _Background(survivor_round)
+        survivor.join(90.0)
+        assert survivor.done(), (
             "mesh party hung on the round after the remote server died")
-        assert done["outcome"] in ("completed", "aborted", "timeout")
+        survivor.result(0.0)    # any other error of the round is the test's
+
+        # the slot is up for handover once BOTH of the victim's tiers
+        # have declared it: members learn it from their scheduler
+        _wait_declared([sim.servers[0].po_global.van], global_id)
+        _wait_declared([sim.workers[1].po.van], local_id)
 
         # revive the dead server so the shutdown cascade completes
         revived = KVStoreDistServer(victim.cfg)
         rt = threading.Thread(target=revived.run, daemon=True)
         rt.start()
         sim.threads.append(rt)
-        for _ in range(300):
-            if revived._ready.is_set():
-                break
-            time.sleep(0.1)
-        assert revived._ready.is_set(), "revived party server not ready"
         sim.servers[2] = revived
+        assert revived._ready.wait(DEADLINES["start_s"]), (
+            "revived party server not ready")
+        assert revived.po_local.van.is_recovery, (
+            "the party's scheduler did not hand over the slot")
+        assert revived.po_local.my_id == local_id
     finally:
         sim.stop()

@@ -26,6 +26,8 @@ CPU mesh (tests/conftest.py):
   the owner's codes verbatim, so every rank dequantizes the same bytes.
 """
 
+import functools
+
 import numpy as np
 import pytest
 
@@ -221,6 +223,47 @@ def test_none_codec_is_psum_bitwise():
     np.testing.assert_array_equal(got, ref)
     np.testing.assert_array_equal(np.asarray(red._res), res0)
     assert red.wire_bytes_per_round() == 2 * (P_ - 1) * 4 * n
+
+
+# -- loss parity -----------------------------------------------------------
+
+# gap in final loss allowed against the psum, from a first loss of about
+# 1.0: int8 and fp16 only round; the 2-bit ring sends {0, +thr, -thr} a
+# hop and keeps a noise ball (at the reducer's default threshold, 0.5;
+# what other thresholds read on this problem: PERF.md section 7)
+RING_PARITY_TOL = {"int8": 5e-4, "fp16": 5e-4, "2bit": 0.05}
+
+
+@functools.lru_cache(maxsize=None)
+def _ring_regression_loss(codec, rounds=200, d=512, n_samples=256,
+                          lr=0.1, ranks=4):
+    """Final loss of a linear regression whose gradient is the ring's
+    mean of four ranks' shard gradients, SGD on the replicated output."""
+    red = qc.QuantRingReducer(_mesh(ranks), codec, d, mean=True)
+    w_true = (np.random.RandomState(7).randn(d)
+              / np.sqrt(d)).astype(np.float32)
+    X = np.random.RandomState(42).randn(n_samples, d).astype(np.float32)
+    y = X @ w_true
+    per = n_samples // ranks
+    Xs, ys = X.reshape(ranks, per, d), y.reshape(ranks, per)
+    w = np.zeros(d, np.float32)
+    for _ in range(rounds):
+        g = np.stack([(2.0 / per) * Xs[r].T @ (Xs[r] @ w - ys[r])
+                      for r in range(ranks)]).astype(np.float32)
+        w -= lr * np.asarray(red.reduce(g))
+    r = X @ w - y
+    return float(np.mean(r * r))
+
+
+@pytest.mark.mesh
+@pytest.mark.parametrize("codec", sorted(RING_PARITY_TOL))
+def test_quantized_ring_reaches_the_psums_loss(codec):
+    """What bit-exactness against the oracle cannot say: that training
+    through the quantized ring ends where the fp32 psum's does."""
+    ref = _ring_regression_loss("none")
+    assert ref < 1e-6, f"the psum itself did not converge: {ref}"
+    got = _ring_regression_loss(codec)
+    assert got - ref <= RING_PARITY_TOL[codec], (codec, got, ref)
 
 
 # -- byte models -----------------------------------------------------------

@@ -13,6 +13,8 @@ aggregated gradient, so responses quantize too — both directions of
 the WAN narrow, which is where the >= 4x byte drop comes from.
 """
 
+import functools
+
 import numpy as np
 import pytest
 
@@ -419,3 +421,74 @@ def test_wan_bytes_drop_at_least_4x_with_2bit_wire():
         raw = _wan_bytes_for("")
         quant = _wan_bytes_for("2bit")
     assert quant * 4 <= raw, (raw, quant)
+
+
+# ---------------------------------------------------------------------------
+# loss parity: what the bit-exact oracles above cannot say — that a model
+# trained over the narrowed wire ends where the float32 wire's does
+# ---------------------------------------------------------------------------
+
+PARITY_D = 256
+PARITY_SPLIT = 64       # key 0 holds 64 elements, key 1 the other 192
+# gap in final loss allowed against the float32 wire, from a first loss
+# of about 1.0: fp16 only rounds; a 2-bit leg sends {0, +thr, -thr}, its
+# error feedback closes the rest down to a noise ball
+PARITY_TOL = {"fp16": 1e-4, "2bit": 0.05, "mpq": 0.05}
+
+
+@functools.lru_cache(maxsize=None)
+def _regression_loss(policy, rounds=200, n_samples=64, lr=0.05):
+    """Mean final local loss of a 2-worker linear regression (a shard of
+    data each) whose summed gradient crosses the combined wire every
+    round, SGD applied worker-side: the servers only aggregate, so both
+    workers read the same response bytes and stay in step. The weights
+    are two keys either side of ``size_lower_bound``, so "mpq" sends
+    one as fp16 and one as 2-bit."""
+    keys = [0, 1]
+    topo = InProcessHiPS(
+        num_parties=2, workers_per_party=1,
+        extra_cfg={"wire_codec": policy, "size_lower_bound": 128,
+                   "wire_2bit_threshold": THR}).start()
+    losses = {}
+
+    def split(x):
+        return [part.copy() for part in np.split(x, [PARITY_SPLIT])]
+
+    try:
+        def master_init(kv):
+            for k, part in zip(keys, split(np.zeros(PARITY_D, np.float32))):
+                kv.init(k, part)
+            kv.wait()
+
+        def worker(kv):
+            widx = topo.workers.index(kv)
+            w_true = (np.random.RandomState(7).randn(PARITY_D)
+                      / np.sqrt(PARITY_D)).astype(np.float32)
+            X = np.random.RandomState(42 + widx).randn(
+                n_samples, PARITY_D).astype(np.float32)
+            y = X @ w_true
+            w = np.zeros(PARITY_D, np.float32)
+            master_init(kv)
+            outs = split(w)
+            for _ in range(rounds):
+                grad = (2.0 / n_samples) * (X.T @ (X @ w - y))
+                # a budget of the larger key: a chunk a key, so a codec a key
+                kv.push_pull_async(keys, split(grad), outs,
+                                   slice_bytes=4 * (PARITY_D - PARITY_SPLIT)
+                                   ).wait(timeout=60)
+                w -= lr * np.concatenate(outs) / 2.0   # mean of 2 workers
+            r = X @ w - y
+            losses[widx] = float(np.mean(r * r))
+
+        topo.run_workers(worker, include_master=master_init, timeout=120)
+    finally:
+        topo.stop()
+    return (losses[0] + losses[1]) / 2.0
+
+
+@pytest.mark.parametrize("policy", sorted(PARITY_TOL))
+def test_narrowed_wire_reaches_the_float32_wires_loss(policy):
+    raw = _regression_loss("")
+    assert raw < 1e-3, f"the float32 wire itself did not converge: {raw}"
+    got = _regression_loss(policy)
+    assert got - raw <= PARITY_TOL[policy], (policy, got, raw)

@@ -22,7 +22,7 @@ import pytest
 from geomx_tpu import profiler
 from geomx_tpu.simulate import InProcessHiPS
 
-from tests.harness import (RecordingApp, SingleTier, party_batch_push,
+from tests.harness import (RecordingApp, SingleTier, _poll, party_batch_push,
                            party_server_without_sockets)
 
 # span events a round of the benchmark's GPT-2 cells (150 keys, two
@@ -261,6 +261,13 @@ def test_van_recv_has_a_duration_and_the_merge_keys():
     """``van.recv`` runs from the first byte python sees of a frame to
     its hand-over, and still carries what tools/trace_merge.py pairs a
     send with its recv on."""
+    key = lambda e: tuple(e["args"][k]                      # noqa: E731
+                          for k in ("ovl", "from", "to", "mts", "req"))
+
+    def spans(name):
+        return {key(e) for e in json.loads(profiler.dumps())["traceEvents"]
+                if e["name"] == name}
+
     with SingleTier(num_workers=1) as topo:
         (kv,) = topo.workers
         kv.init(0, np.ones(1 << 16, np.float32))
@@ -269,6 +276,11 @@ def test_van_recv_has_a_duration_and_the_merge_keys():
         kv.push(0, np.ones(1 << 16, np.float32))
         kv.pull(0)
         kv.wait()
+        # a send's span is written when the write returns, on the
+        # sender's thread: the receiver can have the frame, and this
+        # thread its answer, before that. Stop once they are all in.
+        _poll(lambda: spans("van.recv") <= spans("van.send"),
+              "every received frame's van.send span")
         profiler.set_state("stop")
     evs = json.loads(profiler.dumps())["traceEvents"]
     recvs = [e for e in evs if e["name"] == "van.recv"]
@@ -278,8 +290,6 @@ def test_van_recv_has_a_duration_and_the_merge_keys():
     for e in recvs + sends:
         assert {"node", "ovl", "from", "to", "mts", "req", "verb",
                 "bytes"} <= set(e["args"])
-    key = lambda e: tuple(e["args"][k]                      # noqa: E731
-                          for k in ("ovl", "from", "to", "mts", "req"))
     assert {key(e) for e in recvs} <= {key(e) for e in sends}
 
 

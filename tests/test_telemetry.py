@@ -3,8 +3,8 @@ kv.metrics() / wan_bytes() paths over a live 2-party topology.
 
 The acceptance bar this file carries: disabled-telemetry overhead stays
 under 5% of a 10-key loopback round, and wan_bytes() equals the manual
-sum of the per-verb global-tier send counters (the figure bench.py
-embeds as wan_bytes_per_round).
+sum of the per-verb global-tier send counters (the figure
+benchmark/run.py reports as wan_mb_per_round).
 """
 
 import json
@@ -269,7 +269,7 @@ def test_kv_metrics_and_wan_bytes_over_hips():
     """2-party HiPS round with telemetry on: kv.metrics() answers with
     the worker's and the servers' snapshots, the global tier counted
     WAN bytes, and wan_bytes() matches the manual per-verb sum — the
-    same cross-check bench.py's wan_bytes_per_round figure rests on."""
+    cross-check the benchmark's wan_mb_per_round rests on."""
     telemetry.enable(True)
     sim = InProcessHiPS(num_parties=2, workers_per_party=1).start(
         sync_global=True)

@@ -7,8 +7,7 @@ through float lanes) and published a transformer MFU of 14.8-18.3x chip
 peak (a clock that did not wait). Both failures are platform behaviors
 the CPU suite cannot see. This module probes each suspect mechanism
 directly, in about a minute, and returns a machine-readable verdict that
-chip_smoke.py requires and bench.py stamps into its JSON
-(``chip_sanity``) before any throughput phase runs.
+chip_smoke.py requires before it drives a round.
 
 Probes:
 
