@@ -161,15 +161,18 @@ def check_kernels(on_chip: bool) -> None:
 
 
 def check_attention_paths(on_chip: bool) -> None:
-    """The four families' full causal attention as their blocks call
+    """The five families' full causal attention as their blocks call
     it (``transformer.causal_attention``), at their head shapes and the
-    cells' lengths (Mellum2's 8,192, the others' 4,096): on the chip the kernels must be in the block's own
+    cells' lengths (Mellum2's and Kanana's 8,192, the others' 4,096;
+    Kanana's latent core has a query/key head of 192 beside a value head
+    of 128): on the chip the kernels must be in the block's own
     lowering (a Mosaic call under the scope ``attention`` /
-    ``attention_full``) and agree with the dense product; off it the
-    rule must give the dense product."""
+    ``attention_full`` / ``attention_latent``) and agree with the dense
+    product; off it the rule must give the dense product."""
     import jax
     import jax.numpy as jnp
 
+    from geomx_tpu.models.kanana import KananaBlock
     from geomx_tpu.models.laguna import LagunaBlock
     from geomx_tpu.models.mellum import MellumBlock
     from geomx_tpu.models.olmoe import OlmoeBlock
@@ -180,7 +183,7 @@ def check_attention_paths(on_chip: bool) -> None:
 
     lengths = dict.fromkeys(("olmoe", "laguna", "qwen3next"),
                             4096 if on_chip else 32)
-    lengths["mellum"] = 8192 if on_chip else 32
+    lengths["mellum"] = lengths["kanana"] = 8192 if on_chip else 32
     bf = jnp.bfloat16
     rope = dict(rope_type="default", rope_theta=10000.0,
                 partial_rotary_factor=0.5)
@@ -209,7 +212,14 @@ def check_attention_paths(on_chip: bool) -> None:
                       beta_slow=1, attention_factor=1.2772588722239782),
             num_experts=4, experts_per_token=2, expert_width=64,
             local_experts=(0, 4), compute_dtype=bf),
+        "kanana": KananaBlock(
+            dim=256, nope_dim=128, rope_dim=64, value_dim=128,
+            latent_rank=512, heads=(0, 4), rope_theta=1e6, sparse=False,
+            dense_width=64, num_experts=4, experts_per_token=2,
+            expert_width=64, shared_width=64, local_experts=(0, 4),
+            routed_scale=1.0, compute_dtype=bf),
     }
+    scopes = {"olmoe": "attention", "kanana": "attention_latent"}
     for name, block in blocks.items():
         T = lengths[name]
         x = jax.random.normal(jax.random.PRNGKey(0), (1, T, 256),
@@ -226,7 +236,7 @@ def check_attention_paths(on_chip: bool) -> None:
         if on_chip:
             # the compiled program names an operation's scopes; XLA's
             # own grouped matmul is a Mosaic call too, under no scope
-            scope = "attention" if name == "olmoe" else "attention_full"
+            scope = scopes.get(name, "attention_full")
             named = [re.search(r'op_name="([^"]*)"', line).group(1)
                      for line in lowered.compile().as_text().splitlines()
                      if "tpu_custom_call" in line and "pallas_call" in line]
@@ -240,13 +250,15 @@ def check_attention_paths(on_chip: bool) -> None:
     shapes = {"olmoe": ((16, 128), (16, 128)),
               "laguna": ((1, 6, 128), (1, 128)),
               "qwen3next": ((1, 8, 256), (1, 256)),
-              "mellum": ((1, 8, 128), (1, 128))}
-    for name, (q_heads, kv_heads) in shapes.items():
+              "mellum": ((1, 8, 128), (1, 128)),
+              "kanana": ((4, 192), (4, 192), (4, 128))}
+    for name, (q_heads, *kv_heads) in shapes.items():
         T = lengths[name]
-        q_shape, kv_shape = (1, T) + q_heads, (1, T) + kv_heads
+        q_shape = (1, T) + q_heads
         q = jax.random.normal(jax.random.PRNGKey(2), q_shape, bf)
-        k, v = (jax.random.normal(jax.random.PRNGKey(i), kv_shape, bf)
-                for i in (3, 4))
+        # a value head of its own size where the family names one
+        k, v = (jax.random.normal(jax.random.PRNGKey(i), (1, T) + heads, bf)
+                for i, heads in ((3, kv_heads[0]), (4, kv_heads[-1])))
         dense = grouped_attention if len(q_shape) == 5 else dense_attention
         ((_s, out), got), ((_r, ref), want) = _probe(causal_attention)(
             q, k, v), _probe(lambda q, k, v: dense(

@@ -8,9 +8,11 @@ Decoders by module, not re-exported here: ``transformer`` (GPT-2 style;
 runs full causal attention as the Pallas kernels of
 ``ops.flash_attention`` where ``runs_kernel`` says so, ``rotary``
 positions over a part of a head or all of it, ``rotary_attention``,
-the branch three families share, and ``gated_attention``, that branch
-times the sigmoid gate two of them have), and the five users of
-``moe.sparse_dispatch``:
+the branch three families share, ``gated_attention``, that branch
+times the sigmoid gate two of them have, and ``latent_attention``, the
+core of latent attention: one shared rotary key in the interleaved
+pairing beside a head's non-rotary part, a value head of its own size),
+and the six users of ``moe.sparse_dispatch``:
 ``moe.MoEBlock`` (top-1), ``olmoe`` (softmax top-8 of 64), ``laguna``
 (window and full attention layers with their own head counts, a gated
 attention output, a dense first layer, a shared expert beside sigmoid
@@ -18,8 +20,11 @@ top-8 of 256), ``qwen3_next`` (three Gated DeltaNet linear-attention
 layers on ``ops.gated_delta`` to one gated full-attention layer, a
 gated shared expert beside normalised softmax top-10 of 512) and
 ``mellum`` (three window layers to one full YaRN layer, ungated, every
-layer normalised softmax top-8 of 64 with no shared expert), the last
-four as one rank's share of an expert-parallel layout.
+layer normalised softmax top-8 of 64 with no shared expert) and
+``kanana`` (latent attention in every layer, a dense first layer, a
+shared expert beside sigmoid top-6 of 128 chosen by score plus a
+correction bias that is a buffer, every block rematerialised), the last
+five as one rank's share of an expert-parallel layout.
 """
 
 from geomx_tpu.models.cnn import LeNetCNN, create_cnn  # noqa: F401

@@ -27,7 +27,8 @@ probabilities as they are (``models/olmoe.py``, top-8 of 64), sigmoid
 scores normalised over the chosen and scaled (``models/laguna.py``,
 top-8 of 256 beside a shared expert that never comes here), softmax
 probabilities normalised over the chosen (``models/qwen3_next.py``,
-top-10 of 512), one probability (:class:`MoEBlock`, the ``k = 1``
+top-10 of 512), sigmoid scores chosen by score plus a bias and weighed
+by the score alone (``models/kanana.py``, top-6 of 128), one probability (:class:`MoEBlock`, the ``k = 1``
 case); the dispatch multiplies and sums, it normalises nothing.
 
 :class:`MoEBlock` router: top-1 (Switch-style) with optional jitter
@@ -209,7 +210,8 @@ def gated_experts(w_gate, w_up, w_down):
 def next_token_loss(model, variables, toks):
     """``toks`` [B, T+1]: the mean next-token cross-entropy of a rank's
     share of a sparse decoder (``models/laguna.py``,
-    ``models/qwen3_next.py``, ``models/mellum.py``): ``model.apply``
+    ``models/qwen3_next.py``, ``models/mellum.py``,
+    ``models/kanana.py``): ``model.apply``
     gives (logits, rows routed to the held experts), and
     ``model.counts(batch, t, kernel)`` what the pass has by its shapes.
     Returns (loss, [the rows routed here, then ``model.counts``]), the

@@ -152,8 +152,9 @@ def causal_attention(q, k, v):
     kernel's arithmetic is the dense product's: operands in their own
     type, the products accumulated and the softmax run in float32,
     the probabilities rounded to ``v``'s type before the second
-    product. The default of OLMoE's, Laguna's and Qwen3-Next's full
-    layers."""
+    product. ``v``'s heads may have another size than ``q``'s and
+    ``k``'s (:func:`latent_attention`). The default of OLMoE's,
+    Laguna's and Qwen3-Next's full layers."""
     if runs_kernel(q):
         from geomx_tpu.ops.flash_attention import flash_attention
 
@@ -258,16 +259,21 @@ def rotary_frequencies(rope, head_dim: int):
     return scaled.astype(np.float32), float(rope["attention_factor"])
 
 
-def rotary(x, inv_freq, factor: float):
+def rotary(x, inv_freq, factor: float, interleaved: bool = False):
     """Rotary positions on the leading ``2 * len(inv_freq)`` dims of
     every head of ``x`` [B, T, ..., head_dim] (HF's half-split layout,
     ``x * cos + rotate_half(x) * sin``); the other dims pass. Angles in
-    float32."""
+    float32. ``interleaved``: the pairs' members come in as neighbours
+    ``(2i, 2i + 1)`` and are parted into the two halves first (HF's
+    ``apply_rotary_pos_emb_interleave``); they stay parted on the way
+    out, which a product of two such tensors does not see."""
     t, rot = x.shape[1], 2 * len(inv_freq)
     ang = jnp.arange(t, dtype=jnp.float32)[:, None] * jnp.asarray(inv_freq)
     ang = jnp.concatenate([ang, ang], -1).reshape(
         (1, t) + (1,) * (x.ndim - 3) + (rot,))
     turned, passed = x[..., :rot].astype(jnp.float32), x[..., rot:]
+    if interleaved:
+        turned = jnp.concatenate([turned[..., 0::2], turned[..., 1::2]], -1)
     x1, x2 = jnp.split(turned, 2, axis=-1)
     turned = (turned * jnp.cos(ang)
               + jnp.concatenate([-x2, x1], -1) * jnp.sin(ang)) * factor
@@ -290,9 +296,36 @@ def rotary_attention(q, k, v, inv_freq, factor: float,
         core = jax.checkpoint(lambda q, k, v: window_attention(
             q, k, v, window, scores_dtype=jnp.float32))
     else:
-        core = causal_attention if runs_kernel(q) \
-            else jax.checkpoint(causal_attention)
+        core = causal_core(q)
     return core(q, k, v)
+
+
+def causal_core(q):
+    """:func:`causal_attention` as a branch keeps it: the dense product
+    is computed again on the way back (``jax.checkpoint``), the kernel
+    is not (:func:`rotary_attention` says why)."""
+    return causal_attention if runs_kernel(q) \
+        else jax.checkpoint(causal_attention)
+
+
+def latent_attention(q_nope, q_rope, k_nope, k_rope, v, inv_freq):
+    """The core of latent attention (DeepSeek-V2's MLA as trained, the
+    latent already expanded): a query/key head is its non-rotary part
+    beside a rotary part, ``q_nope`` and ``k_nope`` [B, T, H, Dn],
+    ``q_rope`` [B, T, H, Dr], and ONE rotary key ``k_rope`` [B, T, Dr]
+    that every head shares; ``v`` [B, T, H, Dv] has its own size.
+    Rotary positions in the interleaved pairing on all Dr dims of
+    ``q_rope`` and ``k_rope``, then full causal attention over heads of
+    ``Dn + Dr`` (the scale is over that size) in the form
+    :func:`causal_core` gives, under the scope ``latent_core``. Returns
+    [B, T, H, Dv]."""
+    q_rope = rotary(q_rope, inv_freq, 1.0, interleaved=True)
+    k_rope = rotary(k_rope[:, :, None], inv_freq, 1.0, interleaved=True)
+    q = jnp.concatenate([q_nope, q_rope], -1)
+    k = jnp.concatenate(
+        [k_nope, jnp.broadcast_to(k_rope, q_rope.shape)], -1)
+    with jax.named_scope("latent_core"):
+        return causal_core(q)(q, k, v)
 
 
 def gated_attention(q, k, v, gate, inv_freq, factor: float,

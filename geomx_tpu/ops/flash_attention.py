@@ -14,7 +14,9 @@ logsumexp (no quadratic residual): one kernel produces dQ (accumulating
 over k-blocks) and one produces dK/dV (accumulating over q-blocks).
 
 Layout contract matches ``geomx_tpu.models.transformer.dense_attention``:
-``q, k, v`` are ``[B, T, H, D]`` and the return is ``[B, T, H, D]``.
+``q, k, v`` are ``[B, T, H, D]`` and the return is ``[B, T, H, D]``
+(``v`` and the return ``[B, T, H, Dv]`` where a value head has its own
+size).
 Sequence lengths that are not multiples of the block size are
 zero-padded; padded keys are masked out of the softmax and padded query
 rows are sliced off (their cotangents are zero in the backward pass, so
@@ -67,12 +69,14 @@ def live_blocks(t: int, block_q: int, block_k: int) -> int:
 
 
 @functools.lru_cache(maxsize=None)
-def _kernels(Tq: int, Tk: int, D: int, block_q: int, block_k: int,
+def _kernels(Tq: int, Tk: int, D: int, Dv: int, block_q: int, block_k: int,
              causal: bool, q_len: int, kv_len: int, group: int,
              interpret: bool):
     """Build (fwd, bwd_dq, bwd_dkv) pallas_calls for one static shape.
 
-    All three work on ``[B, H, T, D]``-transposed arrays; ``k`` and
+    All three work on ``[B, H, T, D]``-transposed arrays (``v``, ``o``
+    and their cotangents ``[B, H, T, Dv]``: a value head has its own
+    size, the scores and their scale are over ``D``); ``k`` and
     ``v`` have ``H / group`` heads, query head ``h`` reads key/value
     head ``h // group``. Grids are (batch, head, outer-block,
     inner-block) with the inner dimension iterated sequentially
@@ -205,25 +209,26 @@ def _kernels(Tq: int, Tk: int, D: int, block_q: int, block_k: int,
         B, H = q.shape[0], q.shape[1]
         qspec = pl.BlockSpec((1, 1, block_q, D),
                              lambda b, h, i, j: (b, h, i, 0))
-        kspec = pl.BlockSpec(
-            (1, 1, block_k, D),
+        kspec, vspec = (pl.BlockSpec(
+            (1, 1, block_k, d),
             lambda b, h, i, j: (b, h // group, _k_seen(i, j), 0))
+            for d in (D, Dv))
         return pl.pallas_call(
             fwd_kernel,
             grid=(B, H, nq, nk),
-            in_specs=[qspec, kspec, kspec],
+            in_specs=[qspec, kspec, vspec],
             out_specs=[
-                pl.BlockSpec((1, 1, block_q, D),
+                pl.BlockSpec((1, 1, block_q, Dv),
                              lambda b, h, i, j: (b, h, i, 0)),
                 pl.BlockSpec((1, 1, block_q, 1),
                              lambda b, h, i, j: (b, h, i, 0)),
             ],
             out_shape=[
-                jax.ShapeDtypeStruct((B, H, Tq, D), q.dtype),
+                jax.ShapeDtypeStruct((B, H, Tq, Dv), q.dtype),
                 jax.ShapeDtypeStruct((B, H, Tq, 1), jnp.float32),
             ],
             scratch_shapes=[
-                pltpu.VMEM((block_q, D), jnp.float32),
+                pltpu.VMEM((block_q, Dv), jnp.float32),
                 pltpu.VMEM((block_q, 1), jnp.float32),
                 pltpu.VMEM((block_q, 1), jnp.float32),
             ],
@@ -271,17 +276,17 @@ def _kernels(Tq: int, Tk: int, D: int, block_q: int, block_k: int,
 
     def bwd_dq(q, k, v, do, lse, delta):
         B, H = q.shape[0], q.shape[1]
-        qspec = pl.BlockSpec((1, 1, block_q, D),
-                             lambda b, h, i, j: (b, h, i, 0))
-        kspec = pl.BlockSpec(
-            (1, 1, block_k, D),
+        qspec, dospec, rowspec = (pl.BlockSpec(
+            (1, 1, block_q, d), lambda b, h, i, j: (b, h, i, 0))
+            for d in (D, Dv, 1))
+        kspec, vspec = (pl.BlockSpec(
+            (1, 1, block_k, d),
             lambda b, h, i, j: (b, h // group, _k_seen(i, j), 0))
-        vspec = pl.BlockSpec((1, 1, block_q, 1),
-                             lambda b, h, i, j: (b, h, i, 0))
+            for d in (D, Dv))
         return pl.pallas_call(
             dq_kernel,
             grid=(B, H, nq, nk),
-            in_specs=[qspec, kspec, kspec, qspec, vspec, vspec],
+            in_specs=[qspec, kspec, vspec, dospec, rowspec, rowspec],
             out_specs=qspec,
             out_shape=jax.ShapeDtypeStruct((B, H, Tq, D), q.dtype),
             scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
@@ -321,23 +326,22 @@ def _kernels(Tq: int, Tk: int, D: int, block_q: int, block_k: int,
 
     def bwd_dkv(q, k, v, do, lse, delta):
         B, KV = k.shape[0], k.shape[1]
-        qspec = pl.BlockSpec(
-            (1, 1, block_q, D),
+        qspec, dospec, rowspec = (pl.BlockSpec(
+            (1, 1, block_q, d),
             lambda b, h, j, g, i: (b, h * group + g, _q_seen(i, j), 0))
-        kspec = pl.BlockSpec((1, 1, block_k, D),
-                             lambda b, h, j, g, i: (b, h, j, 0))
-        vspec = pl.BlockSpec(
-            (1, 1, block_q, 1),
-            lambda b, h, j, g, i: (b, h * group + g, _q_seen(i, j), 0))
+            for d in (D, Dv, 1))
+        kspec, vspec = (pl.BlockSpec((1, 1, block_k, d),
+                                     lambda b, h, j, g, i: (b, h, j, 0))
+                        for d in (D, Dv))
         return pl.pallas_call(
             dkv_kernel,
             grid=(B, KV, nk, group, nq),
-            in_specs=[qspec, kspec, kspec, qspec, vspec, vspec],
-            out_specs=[kspec, kspec],
+            in_specs=[qspec, kspec, vspec, dospec, rowspec, rowspec],
+            out_specs=[kspec, vspec],
             out_shape=[jax.ShapeDtypeStruct((B, KV, Tk, D), k.dtype),
-                       jax.ShapeDtypeStruct((B, KV, Tk, D), v.dtype)],
+                       jax.ShapeDtypeStruct((B, KV, Tk, Dv), v.dtype)],
             scratch_shapes=[pltpu.VMEM((block_k, D), jnp.float32),
-                            pltpu.VMEM((block_k, D), jnp.float32)],
+                            pltpu.VMEM((block_k, Dv), jnp.float32)],
             interpret=interpret, **_params(2),
         )(q, k, v, do, lse, delta)
 
@@ -356,7 +360,10 @@ def flash_attention(q, k, v, *, causal: bool = True, block_q=None,
     ``[B, T, KV * G, D]`` or ``[B, T, KV, G, D]`` (the return has q's
     shape): query head ``h`` reads key/value head ``h // G``, which is
     never repeated; dK and dV are summed over the group inside the
-    kernel. Scores are scaled by ``1/sqrt(D)``. ``block_q`` /
+    kernel. A value head may have another size than a query/key head
+    (``v`` ``[B, T, KV, Dv]``; the return then ``[B, T, H, Dv]``: the
+    form latent attention has, 192 beside 128). Scores are scaled by
+    ``1/sqrt(D)``. ``block_q`` /
     ``block_k``: :func:`attention_blocks` unless given. Differentiable
     via a custom VJP whose backward runs as Pallas kernels
     (probabilities recomputed from the saved logsumexp — no quadratic
@@ -376,12 +383,14 @@ def flash_attention(q, k, v, *, causal: bool = True, block_q=None,
         b, t, kv, g, d = q.shape
         return flash_attention(
             q.reshape(b, t, kv * g, d), k, v, causal=causal,
-            block_q=block_q, block_k=block_k).reshape(q.shape)
+            block_q=block_q, block_k=block_k).reshape(
+                b, t, kv, g, v.shape[-1])
     if q.ndim != 4:
         raise ValueError(f"expected [B, T, H, D] tensors, got {q.shape}")
     Tq, Tk = q.shape[1], k.shape[1]
     group = q.shape[2] // k.shape[2]
-    if k.shape != v.shape or q.shape[2] != group * k.shape[2]:
+    if (k.shape[:3] != v.shape[:3] or k.shape[3] != q.shape[3]
+            or q.shape[2] != group * k.shape[2]):
         raise ValueError(f"query heads {q.shape} are not groups of the "
                          f"key/value heads {k.shape}, {v.shape}")
     if causal and Tq > Tk:
@@ -394,8 +403,8 @@ def flash_attention(q, k, v, *, causal: bool = True, block_q=None,
     bq = min(block_q or attention_blocks(Tq, D)[0], _round_up(Tq, 8))
     bk = min(block_k or attention_blocks(Tk, D)[1], _round_up(Tk, 8))
     Tqp, Tkp = _round_up(Tq, bq), _round_up(Tk, bk)
-    fwd, bwd_dq, bwd_dkv = _kernels(Tqp, Tkp, D, bq, bk, causal, Tq, Tk,
-                                    group, pallas_interpret())
+    fwd, bwd_dq, bwd_dkv = _kernels(Tqp, Tkp, D, v.shape[3], bq, bk, causal,
+                                    Tq, Tk, group, pallas_interpret())
 
     @jax.custom_vjp
     def _attn(q, k, v):

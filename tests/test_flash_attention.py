@@ -225,6 +225,57 @@ def test_grouped_queries_in_the_flat_head_layout_and_unequal_blocks():
                         v[:, :, :1].repeat(3, 2))
 
 
+@pytest.mark.parametrize("t,block", [(256, 128), (200, 64)],
+                         ids=["T256", "T200_ragged"])
+@pytest.mark.parametrize("kv,group,d,dv", [
+    (2, 1, 192, 128), (1, 2, 192, 128), (4, 1, 48, 32), (2, 3, 48, 32),
+    (2, 1, 32, 48)], ids=["192_128", "192_128_grouped", "48_32",
+                          "48_32_grouped", "32_48"])
+def test_a_value_head_of_another_size(kv, group, d, dv, t, block):
+    """A query/key head of ``d`` beside a value head of ``dv`` (latent
+    attention: 192 / 128): the scale is over ``d``, the output and dV
+    have ``dv`` dims; forward and the gradients of q, k, v against the
+    dense product, grouped and not."""
+    from geomx_tpu.models.transformer import grouped_attention
+
+    q = _rand((1, t, kv, group, d), 0)
+    k, v = _rand((1, t, kv, d), 1), _rand((1, t, kv, dv), 2)
+
+    def kernel(q, k, v):
+        return flash_attention(q, k, v, block_q=block, block_k=block)
+
+    want, back_want = jax.vjp(grouped_attention, q, k, v)
+    got, back_got = jax.vjp(kernel, q, k, v)
+    assert got.shape == want.shape == (1, t, kv, group, dv)
+    np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-5)
+    cot = _rand(want.shape, 3)
+    grads = back_got(cot)
+    assert [g.shape for g in grads] == [q.shape, k.shape, v.shape]
+    for name, a, b in zip("qkv", grads, back_want(cot)):
+        np.testing.assert_allclose(a, b, atol=2e-4, rtol=2e-4,
+                                   err_msg=f"d{name}")
+
+
+def test_a_value_head_of_another_size_in_the_flat_layout_and_bfloat16():
+    """[B, T, H, 192] on k [B, T, H, 192] and v [B, T, H, 128], the
+    blocks ``attention_blocks`` gives a head of 192, bfloat16 operands
+    against the dense product with float32 scores; a key head that
+    differs from the query head is refused."""
+    from geomx_tpu.ops.flash_attention import attention_blocks
+
+    q, k = (_rand((1, 72, 2, 192), i, jnp.bfloat16) for i in (0, 1))
+    v = _rand((1, 72, 2, 128), 2, jnp.bfloat16)
+    want = dense_attention(q, k, v, scores_dtype=jnp.float32)
+    got = flash_attention(q, k, v)
+    assert got.shape == (1, 72, 2, 128) and got.dtype == jnp.bfloat16
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32),
+                               atol=2e-2, rtol=2e-2)
+    assert attention_blocks(8192, 192) == (512, 512)
+    with pytest.raises(ValueError, match="not groups"):
+        flash_attention(q, k[..., :128], v)
+
+
 @pytest.mark.parametrize("name,q_shape,_kv", CELL_SHAPES,
                          ids=[s[0] for s in CELL_SHAPES])
 def test_the_blocks_of_the_cells_shapes(name, q_shape, _kv):
@@ -302,3 +353,6 @@ def test_kernel_score_entries_at_the_cells_shapes():
     assert kernel_score_entries(4096, 256) == 36 * 512 * 512 == 9_437_184
     live, dense = score_entries(4096)
     assert live < kernel_score_entries(4096, 128) < dense
+    # latent attention at 8,192: a query/key head of 192 runs 512 x 512,
+    # 136 live tiles of 256
+    assert kernel_score_entries(8192, 192) == 136 * 512 * 512 == 35_651_584
