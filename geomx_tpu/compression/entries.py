@@ -23,11 +23,13 @@ arrays where it can.
 from __future__ import annotations
 
 import logging
-from typing import Optional, Sequence
+from typing import Optional, Sequence, Tuple
 
 import numpy as np
 
 from geomx_tpu import kernels_native
+
+log = logging.getLogger("geomx.compression")
 
 __all__ = ["Pairs", "Entries", "SPARSE_TAGS", "CODED", "encode_positions",
            "decode_positions", "encode_positions_numpy",
@@ -226,7 +228,7 @@ class Pairs:
             idx = idx.astype(np.int64)
         if idx.size and not (idx.min() >= 0 and idx.max() < size):
             ok = (idx >= 0) & (idx < size)
-            logging.getLogger("geomx.compression").warning(
+            log.warning(
                 "bsc push: dropping %d out-of-range indices "
                 "(payload addresses %d elements)",
                 int((~ok).sum()), size)
@@ -312,17 +314,40 @@ class Entries(Pairs):
     def add(self, other: "Entries") -> "Entries":
         """Element-wise sum with ``other`` (same ``size``): a merge of
         the two index lists, equal positions summed in float32."""
+        return self.merge(other)[0]
+
+    def merge(self, other: "Entries") -> Tuple["Entries", Optional[bool]]:
+        """:meth:`add`, and which pass made the sum: True the native one
+        (``native/kernels.cc`` ``gxk_entries_merge``: one linear pass over
+        two lists that both ascend, the GIL released), False the numpy
+        chain beside it (the reference, and what runs where the library
+        is not loaded, the operands are not contiguous arrays of one
+        position type, or one of them turns out not to ascend), None
+        where there was nothing to pass over. The entries are the same
+        bit for bit: ``self`` is the earlier arriver, its term the first
+        of a sum."""
         if not other.idx.size:
-            return self
+            return self, None
         if not self.idx.size:
-            return other
-        idx = np.concatenate((self.idx, other.idx))
-        vals = np.concatenate((self.vals, other.vals))
+            return other, None
         if self.idx[-1] < other.idx[0]:
             # slices of one key arriving in order: already merged
-            return Entries(idx, vals, self.size)
+            return Entries(np.concatenate((self.idx, other.idx)),
+                           np.concatenate((self.vals, other.vals)),
+                           self.size), None
+        if kernels_native.entries_merge_usable(self.idx, self.vals,
+                                               other.idx, other.vals):
+            merged = kernels_native.entries_merge(self.idx, self.vals,
+                                                  other.idx, other.vals)
+            if merged is not None:
+                return Entries(*merged, self.size), True
+            log.warning(
+                "entries of %d and %d positions do not both ascend: "
+                "merged by sorting them", self.idx.size, other.idx.size)
+        idx = np.concatenate((self.idx, other.idx))
+        vals = np.concatenate((self.vals, other.vals))
         order = np.argsort(idx, kind="stable")
-        return Entries(*_sum_runs(idx[order], vals[order]), self.size)
+        return Entries(*_sum_runs(idx[order], vals[order]), self.size), False
 
     def nonzero(self) -> "Entries":
         """Without the entries whose value is exactly 0 (an explicit

@@ -81,6 +81,10 @@ def lib() -> Optional[ctypes.CDLL]:
         L.gxk_idx_decode.restype = i64
         L.gxk_idx_decode.argtypes = [_u8p, i64, i64, i64, ctypes.c_int,
                                      ctypes.c_void_p]
+        L.gxk_entries_merge.restype = i64
+        L.gxk_entries_merge.argtypes = [
+            ctypes.c_void_p, _f32p, i64, ctypes.c_void_p, _f32p, i64,
+            ctypes.c_int, ctypes.c_void_p, _f32p]
         _lib = L
         return _lib
 
@@ -209,3 +213,34 @@ def idx_decode(buf: np.ndarray, out: np.ndarray, size: int) -> int:
     return lib().gxk_idx_decode(
         buf.ctypes.data_as(_u8p), buf.size, out.size, size,
         out.dtype.itemsize == 8, out.ctypes.data)
+
+
+def entries_merge_usable(idx_a: np.ndarray, vals_a: np.ndarray,
+                         idx_b: np.ndarray, vals_b: np.ndarray) -> bool:
+    """Whether :func:`entries_merge` takes these two lists: the library,
+    positions of one type (int32 or int64) and float32 values, each
+    contiguous and a value a position."""
+    return (idx_a.dtype == idx_b.dtype and idx_a.dtype.kind == "i"
+            and idx_a.dtype.itemsize in (4, 8)
+            and idx_a.flags.c_contiguous and idx_b.flags.c_contiguous
+            and idx_a.size == vals_a.size and idx_b.size == vals_b.size
+            and _eligible(vals_a, vals_b) and lib() is not None)
+
+
+def entries_merge(idx_a: np.ndarray, vals_a: np.ndarray,
+                  idx_b: np.ndarray, vals_b: np.ndarray):
+    """The two sparse lists summed in one pass (``gxk_entries_merge``):
+    positions that ascend strictly with their float32 values, twice ->
+    (positions, values) of the merged list, views of the first entries
+    of two arrays sized for no position in common; a position both hold
+    has ``a + b``. None where a list does not ascend strictly.
+    Arguments as :func:`entries_merge_usable` wants them."""
+    idx = np.empty(idx_a.size + idx_b.size, dtype=idx_a.dtype)
+    vals = np.empty(idx.size, dtype=np.float32)
+    got = lib().gxk_entries_merge(
+        idx_a.ctypes.data, _ptr(vals_a), idx_a.size,
+        idx_b.ctypes.data, _ptr(vals_b), idx_b.size,
+        idx_a.dtype.itemsize == 8, idx.ctypes.data, _ptr(vals))
+    if got < 0:
+        return None
+    return idx[:got], vals[:got]

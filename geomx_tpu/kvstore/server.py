@@ -1002,7 +1002,7 @@ class KVStoreDistServer:
         elif isinstance(val, Pairs) and isinstance(st.merged, Pairs):
             # a second selection: the index lists merge, equal positions
             # summed in arrival order, as the dense += sums them
-            st.merged = st.merged.entries().add(val.entries())
+            st.merged = self._merge(st.merged.entries(), val.entries())
         else:
             # the first dense push makes the round dense (_accumulate)
             st.merged = _as_array(st.merged)
@@ -1275,7 +1275,8 @@ class KVStoreDistServer:
         if (isinstance(sub, Entries) and self._keeps_sparse(st)
                 and not isinstance(st.merged, np.ndarray)):
             part = sub.placed(rel, st.length)
-            st.merged = part if st.merged is None else st.merged.add(part)
+            st.merged = (part if st.merged is None
+                         else self._merge(st.merged, part))
             return
         if st.merged is None:
             st.merged = np.zeros(st.length, dtype=np.float32)
@@ -1285,6 +1286,18 @@ class KVStoreDistServer:
         sub32 = np.ascontiguousarray(_as_array(sub), dtype=np.float32)
         if not kernels_native.acc(seg, sub32):
             seg += sub32
+
+    def _merge(self, merged: Entries, part: Entries) -> Entries:
+        """``merged + part``, the later arriver second, and one more
+        (key, shard) merge on the counter of the pass that made it
+        (``Entries.merge``; neither where there was nothing to pass
+        over: an empty operand, slices of one key in order)."""
+        merged, native = merged.merge(part)
+        if native is not None:
+            telemetry.counter_inc(
+                "server.native_merge_key_rounds" if native
+                else "server.numpy_merge_key_rounds", tier=self._tier)
+        return merged
 
     def _count_key_round(self, sparse: bool) -> None:
         """One (key, shard) round completed with its aggregate stored as

@@ -257,6 +257,56 @@ static int64_t idx_decode(const uint8_t* buf, int64_t len, int64_t n,
     return 0;
 }
 
+// Whether p[n] ascends strictly: one pass the compiler vectorises.
+template <typename T>
+static bool ascends(const T* p, int64_t n) {
+    int bad = 0;
+    for (int64_t i = 1; i < n; ++i) bad |= p[i] <= p[i - 1];
+    return !bad;
+}
+
+// The sum of two sparse aggregates of one range (compression/entries.py
+// ``Entries.merge``, whose numpy chain is the reference: concatenate, a
+// stable argsort, two gathers, a run-sum): a[na] and b[nb] are positions
+// that ascend strictly, va and vb the float32 values beside them; the
+// merged list goes to oi / ov (room for na + nb). A position both hold
+// gets va + vb in float32, a's term first (a is the earlier arriver, as
+// the stable sort kept it); every other value is copied bit for bit, so
+// it moves as the integer it is and the choice of side is a conditional
+// move, not a branch the processor cannot call: only "both hold it" is a
+// branch, and that is rare. Returns the entries written, or -1, nothing
+// of use written, where a list does not ascend strictly (one vector pass
+// over each before the merge).
+template <typename T>
+static int64_t entries_merge(const T* a, const float* va, int64_t na,
+                             const T* b, const float* vb, int64_t nb,
+                             T* oi, float* ov) {
+    if (!ascends(a, na) || !ascends(b, nb)) return -1;
+    int64_t i = 0, j = 0, k = 0;
+    while (i < na && j < nb) {
+        const T x = a[i], y = b[j];
+        if (x == y) {
+            oi[k] = x;
+            ov[k++] = va[i++] + vb[j++];
+            continue;
+        }
+        const bool first = x < y;
+        uint32_t ua, ub;
+        std::memcpy(&ua, va + i, 4);
+        std::memcpy(&ub, vb + j, 4);
+        const uint32_t bits = first ? ua : ub;
+        oi[k] = first ? x : y;
+        std::memcpy(ov + k, &bits, 4);
+        i += first;
+        j += !first;
+        ++k;
+    }
+    const int64_t left = i < na ? na - i : nb - j;
+    std::memcpy(oi + k, i < na ? a + i : b + j, left * sizeof(T));
+    std::memcpy(ov + k, i < na ? va + i : vb + j, left * sizeof(float));
+    return k + left;
+}
+
 extern "C" {
 
 int64_t gxk_idx_encode(const void* idx, int64_t n, int wide, uint8_t* out,
@@ -269,6 +319,15 @@ int64_t gxk_idx_decode(const uint8_t* buf, int64_t len, int64_t n,
                        int64_t size, int wide, void* out) {
     return wide ? idx_decode(buf, len, n, size, (int64_t*)out)
                 : idx_decode(buf, len, n, size, (int32_t*)out);
+}
+
+int64_t gxk_entries_merge(const void* a, const float* va, int64_t na,
+                          const void* b, const float* vb, int64_t nb,
+                          int wide, void* oi, float* ov) {
+    return wide ? entries_merge((const int64_t*)a, va, na,
+                                (const int64_t*)b, vb, nb, (int64_t*)oi, ov)
+                : entries_merge((const int32_t*)a, va, na,
+                                (const int32_t*)b, vb, nb, (int32_t*)oi, ov);
 }
 
 }  // extern "C"

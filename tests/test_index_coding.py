@@ -262,3 +262,220 @@ def test_the_native_form_is_what_a_server_runs():
         assert decode_positions(coded, 3, 40_001).tolist() == idx.tolist()
     finally:
         coding.encode_positions_numpy, coding.decode_positions_numpy = orig
+
+
+# ---------------------------------------------------------------------
+# the sum of two ``Entries``: ``native/kernels.cc`` ``gxk_entries_merge``
+# against the numpy chain (concatenate, stable argsort, gathers, run-sum)
+# ---------------------------------------------------------------------
+
+ITYPES = {"int32": np.int32, "int64": np.int64}
+
+
+def _entries(positions, itype, size=1 << 20, seed=0):
+    """``positions`` with seeded float32 values, negative zero, a
+    denormal and a huge one among them."""
+    idx = np.asarray(positions, dtype=itype)
+    vals = np.random.default_rng([seed, idx.size]).standard_normal(
+        idx.size).astype(np.float32)
+    vals[::7] = -0.0
+    vals[3::11] = 1e-41
+    vals[5::13] = 1e38
+    return Entries(idx, vals, size)
+
+
+def _random_positions(seed, count, size=1 << 20):
+    return np.sort(np.random.default_rng(seed).choice(
+        size, count, replace=False))
+
+
+def _numpy_merge(monkeypatch, a, b):
+    """``a.merge(b)`` as a machine without the library runs it."""
+    with monkeypatch.context() as m:
+        m.setattr(kernels_native, "lib", lambda: None)
+        return a.merge(b)
+
+
+def _same_entries(got, want):
+    assert got.size == want.size
+    assert got.idx.dtype == want.idx.dtype
+    assert got.vals.dtype == want.vals.dtype == np.float32
+    np.testing.assert_array_equal(got.idx, want.idx)
+    assert got.vals.tobytes() == want.vals.tobytes()
+
+
+# name -> (the earlier arriver's positions, the later one's)
+OPERANDS = {
+    "interleaved": (range(0, 4000, 2), range(1, 4001, 2)),
+    "identical": (range(5, 3005, 3), range(5, 3005, 3)),
+    "disjoint_later_first": (range(5000, 6000), range(100, 1100)),
+    "one_inside_the_other": ([1, 1 << 19], range(10, 2010, 4)),
+    "some_in_common": (_random_positions(1, 5000, 40_000),
+                       _random_positions(2, 7000, 40_000)),
+    "one_entry_each_equal": ([77], [77]),
+    "one_entry_each_apart": ([78], [77]),
+    "long_tail_of_the_first": (range(0, 9000), [0, 3, 4]),
+    "long_tail_of_the_second": ([2, 8999], range(0, 9000)),
+    "large": (_random_positions(3, 200_000), _random_positions(4, 210_000)),
+}
+
+
+@pytest.mark.parametrize("itype", list(ITYPES))
+@pytest.mark.parametrize("name", list(OPERANDS))
+def test_the_native_merge_is_the_numpy_chain_bit_for_bit(monkeypatch, name,
+                                                         itype):
+    if kernels_native.lib() is None:
+        pytest.skip("no native kernels on this machine")
+    first, second = OPERANDS[name]
+    a = _entries(list(first), ITYPES[itype], seed=1)
+    b = _entries(list(second), ITYPES[itype], seed=2)
+    got, native = a.merge(b)
+    want, numpy_ran = _numpy_merge(monkeypatch, a, b)
+    assert native is True and numpy_ran is False
+    _same_entries(got, want)
+    _same_entries(a.add(b), want)
+    assert np.all(got.idx[1:] > got.idx[:-1])
+    # a position both hold: the earlier arriver's term first
+    both = np.intersect1d(a.idx, b.idx)
+    if both.size:
+        at = both[0]
+        assert got.vals[got.idx == at] == (a.vals[a.idx == at]
+                                           + b.vals[b.idx == at])
+
+
+# name -> (first, second, whether the sum is one of the two operands)
+NO_PASS = {
+    "empty_second": ([3, 9], [], True),
+    "empty_first": ([], [3, 9], True),
+    "both_empty": ([], [], True),
+    "slices_in_order": (range(0, 500), range(500, 900), False),
+}
+
+
+@pytest.mark.parametrize("form", ["native", "fallback"])
+@pytest.mark.parametrize("itype", list(ITYPES))
+@pytest.mark.parametrize("name", list(NO_PASS))
+def test_a_sum_that_needs_no_pass_takes_none(monkeypatch, name, itype,
+                                             form):
+    if form == "fallback":
+        monkeypatch.setattr(kernels_native, "lib", lambda: None)
+    first, second, same = NO_PASS[name]
+    a = _entries(list(first), ITYPES[itype], seed=1)
+    b = _entries(list(second), ITYPES[itype], seed=2)
+    got, native = a.merge(b)
+    assert native is None
+    assert (got is a or got is b) == same
+    np.testing.assert_array_equal(got.idx, np.concatenate((a.idx, b.idx)))
+    assert got.vals.tobytes() == np.concatenate((a.vals, b.vals)).tobytes()
+
+
+@pytest.mark.parametrize("itype", list(ITYPES))
+def test_three_parties_fold_in_arrival_order(monkeypatch, itype):
+    if kernels_native.lib() is None:
+        pytest.skip("no native kernels on this machine")
+    parts = [_entries(_random_positions(10 + p, 3000, 20_000),
+                      ITYPES[itype], size=20_000, seed=p) for p in range(3)]
+    got = parts[0].add(parts[1]).add(parts[2])
+    want = _numpy_merge(monkeypatch, _numpy_merge(
+        monkeypatch, parts[0], parts[1])[0], parts[2])[0]
+    _same_entries(got, want)
+    # what the dense += of the three gives, term by term (a -0.0 that
+    # one party alone holds stays -0.0 here and is +0.0 there)
+    dense = np.zeros(20_000, dtype=np.float32)
+    for p in parts:
+        dense[p.idx] += p.vals
+    np.testing.assert_array_equal(got.dense(), dense)
+    held = np.zeros(20_000, dtype=bool)
+    for p in parts:
+        held[p.idx] = True
+    np.testing.assert_array_equal(got.idx, np.flatnonzero(held))
+
+
+@pytest.mark.parametrize("itype", list(ITYPES))
+def test_a_sum_of_exactly_0_stays_until_nonzero(monkeypatch, itype):
+    if kernels_native.lib() is None:
+        pytest.skip("no native kernels on this machine")
+    a = Entries(np.asarray([2, 5, 9], dtype=ITYPES[itype]),
+                np.asarray([1.5, -0.25, 0.0], dtype=np.float32), 12)
+    b = Entries(np.asarray([1, 2, 9], dtype=ITYPES[itype]),
+                np.asarray([4.0, -1.5, -0.0], dtype=np.float32), 12)
+    got, native = a.merge(b)
+    assert native is True
+    assert got.idx.tolist() == [1, 2, 5, 9]
+    assert got.vals.tolist() == [4.0, 0.0, -0.25, 0.0]
+    _same_entries(got, _numpy_merge(monkeypatch, a, b)[0])
+    assert got.nonzero().idx.tolist() == [1, 5]
+
+
+# name -> (first, second): one of the two does not ascend strictly
+NOT_MERGED_NATIVELY = {
+    "first_descends": ([9, 4, 6], [1, 5]),
+    "second_descends": ([1, 12], [9, 4, 6]),
+    "first_repeats": ([1, 5, 5, 8], [2, 6]),
+    "second_repeats_at_its_end": ([1, 9], [2, 6, 6]),
+    "only_the_tail_is_out_of_order": ([1, 2], [0, 7, 8, 3]),
+}
+
+
+@pytest.mark.parametrize("itype", list(ITYPES))
+@pytest.mark.parametrize("name", list(NOT_MERGED_NATIVELY))
+def test_a_list_that_does_not_ascend_falls_back_and_says_so(
+        monkeypatch, caplog, name, itype):
+    if kernels_native.lib() is None:
+        pytest.skip("no native kernels on this machine")
+    first, second = NOT_MERGED_NATIVELY[name]
+    a = _entries(first, ITYPES[itype], size=16, seed=1)
+    b = _entries(second, ITYPES[itype], size=16, seed=2)
+    assert kernels_native.entries_merge(a.idx, a.vals, b.idx, b.vals) is None
+    with caplog.at_level("WARNING", logger="geomx.compression"):
+        got, native = a.merge(b)
+    assert native is False
+    assert "do not both ascend" in caplog.text
+    _same_entries(got, _numpy_merge(monkeypatch, a, b)[0])
+    # what sorting gives: every position once, equal ones summed in order
+    dense = np.zeros(16, dtype=np.float32)
+    for e in (a, b):
+        np.add.at(dense, e.idx, e.vals)
+    np.testing.assert_array_equal(got.idx, np.union1d(a.idx, b.idx))
+    np.testing.assert_allclose(got.vals, dense[got.idx], rtol=1e-6)
+
+
+# name -> what makes the operands unfit for the native pass
+UNFIT = {
+    "two_position_types": lambda a, b: (a, Entries(
+        b.idx.astype(np.int64), b.vals, b.size)),
+    "strided_positions": lambda a, b: (Entries(
+        np.repeat(a.idx, 2)[::2], a.vals, a.size), b),
+    "strided_values": lambda a, b: (a, Entries(
+        b.idx, np.repeat(b.vals, 2)[::2], b.size)),
+    "unsigned_positions": lambda a, b: (Entries(
+        a.idx.astype(np.uint32), a.vals, a.size), Entries(
+        b.idx.astype(np.uint32), b.vals, b.size)),
+}
+
+
+@pytest.mark.parametrize("name", list(UNFIT))
+def test_operands_the_native_pass_does_not_take_merge_in_numpy(caplog, name):
+    a = _entries(range(0, 300, 2), np.int32, seed=1)
+    b = _entries(range(1, 300, 3), np.int32, seed=2)
+    want = a.merge(b)[0]
+    a2, b2 = UNFIT[name](a, b)
+    with caplog.at_level("WARNING", logger="geomx.compression"):
+        got, native = a2.merge(b2)
+    assert native is False and not caplog.text
+    np.testing.assert_array_equal(got.idx, want.idx)
+    assert got.vals.tobytes() == want.vals.tobytes()
+
+
+def test_the_native_merge_takes_the_wire_s_read_only_arrays():
+    if kernels_native.lib() is None:
+        pytest.skip("no native kernels on this machine")
+    a = _entries(range(0, 300, 2), np.int32, seed=1)
+    b = _entries(range(1, 300, 3), np.int32, seed=2)
+    want = a.merge(b)[0]
+    for arr in (a.idx, a.vals, b.idx, b.vals):
+        arr.flags.writeable = False
+    got, native = a.merge(b)
+    assert native is True
+    _same_entries(got, want)
+    assert got.idx.flags.c_contiguous and got.vals.flags.c_contiguous

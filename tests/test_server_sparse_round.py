@@ -24,7 +24,7 @@ import types
 import numpy as np
 import pytest
 
-from geomx_tpu import telemetry
+from geomx_tpu import kernels_native, telemetry
 from geomx_tpu.compression import (BSCCompressor, Entries, FP16Compressor,
                                    MPQCompressor, Pairs, _generic_decompress,
                                    make_compressor, two_bit_dequantize,
@@ -1155,3 +1155,129 @@ def test_two_topologies_forward_the_same_bytes(monkeypatch, second):
     for p in (0, 1):
         assert len(first[p]) == 3
         assert all(msg[2] == list(MIXED) for msg in first[p])
+
+
+# ---------------------------------------------------------------------------
+# the global server's merge of the parties' index lists: the native pass
+# (native/kernels.cc gxk_entries_merge) against the numpy chain, through
+# a live round
+# ---------------------------------------------------------------------------
+
+MERGED = {3: 5_000, 4: 300, 7: 120_000, 9: 70_000, 14: 33_000, 15: 2_048}
+
+
+def _merged_by_a_topology(monkeypatch, native, rounds=2):
+    """Two parties x one worker, ``rounds`` Bi-Sparse rounds on keys of
+    mixed sizes, with the kernels' library or without -> (what crossed
+    the party-global link in each direction as bytes, the global
+    server's stored entries, every worker's last aggregate, counters)."""
+    if not native:
+        monkeypatch.setattr(kernels_native, "lib", lambda: None)
+    topo = InProcessHiPS(num_parties=2, workers_per_party=1).start()
+    keys = list(MERGED)
+    forwards, answers, got = {}, [], {}
+    try:
+        (gsrv,) = [s for s in topo.servers if s.is_global_server]
+        party_servers = [s for s in topo.servers if s.has_global_tier]
+        for p, srv in enumerate(party_servers):
+            log = forwards[p] = []
+
+            def push(kvs, g_rank, _real=srv.worker_global.push, _log=log,
+                     **kw):
+                _log.append((list(kvs.keys),
+                             [np.asarray(v).tobytes() for v in kvs.vals],
+                             [np.asarray(a).tobytes() for a in kvs.aux],
+                             [(link_positions(kvs, i)[[0, -1]].tolist()
+                               if np.asarray(kvs.vals[i]).size else None)
+                              for i in range(len(kvs.keys))]))
+                return _real(kvs, g_rank, **kw)
+
+            srv.worker_global.push = push
+
+        def response(req, kvs=None, body="",
+                     _real=gsrv.server_global.response):
+            # a round's answers: the bootstrap's pulls ask a key at a time
+            if kvs is not None and list(kvs.keys) == keys:
+                answers.append((req.sender, list(kvs.keys),
+                                [np.asarray(v).tobytes() for v in kvs.vals],
+                                [np.asarray(a).tobytes() for a in kvs.aux]))
+            return _real(req, kvs, body)
+
+        gsrv.server_global.response = response
+
+        def master_init(kv):
+            kv.set_gradient_compression({"type": "bsc", "threshold": 0.05})
+            for k, n in MERGED.items():
+                kv.init(k, np.zeros(n, np.float32))
+            kv.wait()
+
+        def init(kv):
+            for k, n in MERGED.items():
+                kv.init(k, np.zeros(n, np.float32))
+                kv.pull(k, out=np.zeros(n, np.float32))
+            kv.wait()
+
+        topo.run_workers(init, include_master=master_init, timeout=60)
+        telemetry.reset()
+        telemetry.enable(True)
+
+        def train(kv):
+            p = topo.workers.index(kv)
+            for rnd in range(rounds):
+                sel = _seeded_pushes(MERGED, 91 * p + rnd)
+                agg = kv.push_pull_bsc_batch(
+                    keys, [sel[k][0] for k in keys],
+                    [sel[k][1].astype(np.int64) for k in keys],
+                    timeout=60)()
+            got[p] = {k: (np.asarray(agg[k][0]).tobytes(),
+                          np.asarray(agg[k][1]).tolist()) for k in keys}
+
+        topo.run_workers(train, timeout=120)
+        counters = {name: _counters("server." + name) for name in (
+            "native_merge_key_rounds", "numpy_merge_key_rounds",
+            "sparse_key_rounds", "dense_key_rounds")}
+        stored = {k: (gsrv._states[(k, 0)].entries.idx.tolist(),
+                      gsrv._states[(k, 0)].entries.vals.tobytes())
+                  for k in keys}
+    finally:
+        telemetry.reset()
+        topo.stop()
+    return forwards, sorted(answers), stored, got, counters
+
+
+def test_a_round_merged_natively_is_the_round_merged_in_numpy(monkeypatch):
+    """The same two-party round with the library and without: the bytes
+    on the party-global link in both directions, the global server's
+    store and what every worker applies are equal, and the counters say
+    which pass made each (key, shard) merge."""
+    if kernels_native.lib() is None:
+        pytest.skip("no native kernels on this machine")
+    rounds = 2
+    with monkeypatch.context() as m:
+        first = _merged_by_a_topology(m, native=True, rounds=rounds)
+    second = _merged_by_a_topology(monkeypatch, native=False, rounds=rounds)
+    forwards, answers, stored, got, counters = first
+    assert (forwards, answers, stored, got) == second[:4]
+    # every key's two forwards overlap, so whichever came first the
+    # global server had a merge to make, once a key and round
+    for rnd in range(rounds):
+        for i, k in enumerate(MERGED):
+            (a0, a1), (b0, b1) = (forwards[p][rnd][3][i] for p in (0, 1))
+            assert max(a0, b0) <= min(a1, b1), (rnd, k)
+    keys = len(MERGED)
+    assert counters == {"native_merge_key_rounds": keys * rounds,
+                        "numpy_merge_key_rounds": 0,
+                        "sparse_key_rounds": keys * 3 * rounds,
+                        "dense_key_rounds": 0}
+    assert second[4] == {"native_merge_key_rounds": 0,
+                         "numpy_merge_key_rounds": keys * rounds,
+                         "sparse_key_rounds": keys * 3 * rounds,
+                         "dense_key_rounds": 0}
+    # a round's answer to each party carries every key's merged list
+    assert len(answers) == 2 * rounds and all(any(a[2]) for a in answers)
+    assert got[0] == got[1]
+    # the store is the merged list without its exact zeros (a small
+    # key's boundary of 0 ships zeros, PERF.md section 7)
+    assert all(np.frombuffer(vals, np.float32).all()
+               for _idx, vals in stored.values())
+    assert len(stored[7][0]) > 100
