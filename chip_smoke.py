@@ -168,7 +168,12 @@ def check_attention_paths(on_chip: bool) -> None:
     of 128): on the chip the kernels must be in the block's own
     lowering (a Mosaic call under the scope ``attention`` /
     ``attention_full`` / ``attention_latent``) and agree with the dense
-    product; off it the rule must give the dense product."""
+    product; off it the rule must give the dense product. Beside them
+    Qwen3-Next's LINEAR block at the cell's head sizes (``ops/
+    gated_delta.py``): on the chip its lowering holds the chain's Mosaic
+    calls under ``gated_delta_rule``, and under that scope no
+    ``triangular_solve`` and no ``while`` but the solve's own 16 rows;
+    off it the rule must give the scan."""
     import jax
     import jax.numpy as jnp
 
@@ -181,8 +186,8 @@ def check_attention_paths(on_chip: bool) -> None:
                                               dense_attention,
                                               grouped_attention)
 
-    lengths = dict.fromkeys(("olmoe", "laguna", "qwen3next"),
-                            4096 if on_chip else 32)
+    lengths = dict.fromkeys(("olmoe", "laguna", "qwen3next",
+                             "qwen3next_linear"), 4096 if on_chip else 32)
     lengths["mellum"] = lengths["kanana"] = 8192 if on_chip else 32
     bf = jnp.bfloat16
     rope = dict(rope_type="default", rope_theta=10000.0,
@@ -204,6 +209,14 @@ def check_attention_paths(on_chip: bool) -> None:
             linear_value_heads=(0, 1), conv_kernel=4, num_experts=4,
             experts_per_token=2, expert_width=64, shared_width=64,
             local_experts=(0, 4), compute_dtype=bf),
+        "qwen3next_linear": Qwen3NextBlock(
+            dim=256, kind="linear_attention", head_dim=256,
+            query_heads=(0, 8), key_value_heads=(0, 1), rope=rope,
+            linear_key_dim=128, linear_value_dim=128,
+            linear_key_heads=(0, 2), linear_value_heads=(0, 4),
+            conv_kernel=4, num_experts=4, experts_per_token=2,
+            expert_width=64, shared_width=64, local_experts=(0, 4),
+            compute_dtype=bf),
         "mellum": MellumBlock(
             dim=256, head_dim=128, kind="full_attention",
             query_heads=(0, 8), key_value_heads=(0, 1), window=1024,
@@ -219,7 +232,8 @@ def check_attention_paths(on_chip: bool) -> None:
             expert_width=64, shared_width=64, local_experts=(0, 4),
             routed_scale=1.0, compute_dtype=bf),
     }
-    scopes = {"olmoe": "attention", "kanana": "attention_latent"}
+    scopes = {"olmoe": "attention", "kanana": "attention_latent",
+              "qwen3next_linear": "gated_delta_rule"}
     for name, block in blocks.items():
         T = lengths[name]
         x = jax.random.normal(jax.random.PRNGKey(0), (1, T, 256),
@@ -237,13 +251,25 @@ def check_attention_paths(on_chip: bool) -> None:
             # the compiled program names an operation's scopes; XLA's
             # own grouped matmul is a Mosaic call too, under no scope
             scope = scopes.get(name, "attention_full")
+            compiled = lowered.compile().as_text().splitlines()
             named = [re.search(r'op_name="([^"]*)"', line).group(1)
-                     for line in lowered.compile().as_text().splitlines()
+                     for line in compiled
                      if "tpu_custom_call" in line and "pallas_call" in line]
             if len(named) != calls or not all(
                     f"/{scope}/" in n for n in named):
                 raise RuntimeError(f"{name} block: the kernels are not "
                                    f"under the scope {scope!r}: {named}")
+            # the rule's dependent chains are the kernels' and the
+            # solve's one loop over a block's rows: no other loop and no
+            # solve of XLA's under the scope
+            loops = [line.strip()[:200] for line in compiled
+                     if f"/{scope}/" in line and (
+                         "triangular" in line or " while(" in line
+                         and "_unit_lower_inverse" not in line)]
+            if scope == "gated_delta_rule" and loops:
+                raise RuntimeError(f"{name} block: a while or a "
+                                   f"triangular_solve under {scope!r}: "
+                                   f"{loops[:3]}")
         say(f"{name} block T={T}: {calls} Mosaic calls in the lowering")
     if not on_chip:
         return
@@ -272,6 +298,50 @@ def check_attention_paths(on_chip: bool) -> None:
         if not all(np.isfinite(e) and e < 0.01 for e in errs):
             raise RuntimeError(f"{name} attention on the kernels disagrees "
                                f"with the dense product: {errs}")
+    check_gated_delta_forms()
+
+
+def check_gated_delta_forms() -> None:
+    """On the chip: the gated delta rule's kernel form (what the rule
+    gives here) against its ``lax.scan`` form at the Qwen3-Next cell's
+    shapes (one sequence of 4,096 tokens, 16 value heads of 128 x 128,
+    bfloat16 operands): ``o`` and the five gradients. Both round their
+    matmuls' operands to bfloat16 at the same places, so they differ by
+    the order of float32 sums and where a cotangent is rounded."""
+    import functools
+
+    import jax
+    import jax.numpy as jnp
+
+    from geomx_tpu.ops import gated_delta
+    from tools.gated_delta_bench import layer_inputs
+
+    T = 4096
+    args = layer_inputs(1, T, 16, 128, 128, seed=5)
+
+    def probe():
+        def f(*a):
+            o = gated_delta.gated_delta_rule(*a, dtype=jnp.bfloat16)[0]
+            return jnp.sum(jnp.sin(o)), o
+
+        (_s, o), grads = jax.jit(jax.value_and_grad(
+            f, argnums=range(5), has_aux=True))(*args)
+        return (o, *grads)
+
+    got = probe()
+    asked = gated_delta.runs_kernel
+    gated_delta.runs_kernel = functools.partial(asked, forced=False)
+    try:
+        want = probe()
+    finally:
+        gated_delta.runs_kernel = asked
+    errs = [float(jnp.linalg.norm(a - b) / jnp.linalg.norm(b))
+            for a, b in zip(got, want)]
+    say(f"gated delta rule T={T}: kernel form against the scan form, rel "
+        f"l2 err o/dq/dk/dv/dg/dbeta = {[round(e, 5) for e in errs]}")
+    if not all(np.isfinite(e) and e < 0.02 for e in errs):
+        raise RuntimeError("the gated delta rule's kernel form disagrees "
+                           f"with its scan form: {errs}")
 
 
 def run_round(shape: dict, steps: int, compiles, mesh_party: bool) -> None:

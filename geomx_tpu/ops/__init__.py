@@ -54,7 +54,8 @@ def device_compression_enabled() -> bool:
 
 def pallas_interpret() -> bool:
     """THE rule for every Pallas call site in the package (today the
-    flash-attention kernels, ops/flash_attention.py): kernels are
+    flash-attention kernels, ops/flash_attention.py, and the gated delta
+    rule's chain of chunks, ops/gated_delta.py): kernels are
     compiled by Mosaic when jax's default backend is a TPU and run in
     interpret mode anywhere else (the CPU test suite). Nothing else may
     choose interpret mode, so a chip run can never take it silently;

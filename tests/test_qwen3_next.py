@@ -8,6 +8,8 @@ Tiny widths, seeded weights, CPU. The published widths are compared on
 the chip (``benchmark/tests/chip_limits.py``, PERF.md section 2).
 """
 
+from functools import partial
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -19,6 +21,7 @@ from benchmark.references import qwen3next as reference
 from geomx_tpu import telemetry
 from geomx_tpu.models.qwen3_next import (GatedDeltaNet, Qwen3NextBlock,
                                          causal_conv)
+from geomx_tpu.ops import gated_delta
 from geomx_tpu.ops.gated_delta import (chunks_of, gated_delta_rule,
                                        gated_delta_rule_recurrent)
 from geomx_tpu.trainer_device import DeviceResidentTrainer
@@ -98,6 +101,239 @@ def test_chunked_rule_is_the_token_recurrence(t, chunk, decay):
 
 def test_dependent_steps_are_the_chunks():
     assert [chunks_of(t) for t in (1, 64, 65, 4096)] == [1, 1, 2, 64]
+
+
+def _kernel_case(case):
+    """(inputs, the rule as called, its oracle) of one case of the
+    kernel form, all at the default chunk: the layer's inputs under the
+    three decays in whole chunks, with a padded tail, or with two value
+    heads reading a key head (the layer's ``jnp.repeat``: a key head's
+    cotangent sums its value heads'); one value head (a grid step of one
+    head where the others' hold three); every key of a head the same and
+    beta 0.99, where ``A`` is 0.99 of the all-ones strict triangle and
+    a power series of it passes 1e17 before it cancels; and the cell's
+    head sizes, against the ``lax.scan`` form."""
+    if case == "aligned_keys":
+        q, k, v, g, _ = _rule_inputs(128, "near_one", seed=5)
+        k = jnp.broadcast_to(k[:, :1], k.shape)
+        return ([q, k, v, g, jnp.full(g.shape, 0.99)], gated_delta_rule,
+                gated_delta_rule_recurrent)
+    if case == "cell_head_sizes":
+        return (_rule_inputs(256, "spread", seed=6, b=1, h=2, dk=128,
+                             dv=128), gated_delta_rule, gated_delta_rule)
+    if case == "one_head":
+        return (_rule_inputs(150, "spread", seed=8, h=1), gated_delta_rule,
+                gated_delta_rule_recurrent)
+    decay, layout = case.split("-")
+    t = 150 if layout == "padded_tail" else 128
+    args = _rule_inputs(t, decay, seed=t + len(decay))
+    if layout != "two_value_heads":
+        return args, gated_delta_rule, gated_delta_rule_recurrent
+    q, k = (x[:, :, :1] for x in args[:2])
+
+    def grouped(form):
+        return lambda q, k, *a: form(jnp.repeat(q, 3, axis=2),
+                                     jnp.repeat(k, 3, axis=2), *a)
+
+    return ([q, k] + args[2:], grouped(gated_delta_rule),
+            grouped(gated_delta_rule_recurrent))
+
+
+def _forced(monkeypatch, answer=True):
+    monkeypatch.setattr(gated_delta, "runs_kernel", partial(
+        gated_delta.runs_kernel, forced=answer))
+
+
+@pytest.mark.parametrize("case", [
+    f"{decay}-{layout}" for decay in ("near_one", "near_zero", "spread")
+    for layout in ("whole_chunks", "padded_tail", "two_value_heads")
+] + ["one_head", "aligned_keys", "cell_head_sizes"])
+def test_kernel_form_is_the_token_recurrence(case, monkeypatch):
+    """The form a TPU backend runs (the rolled blocked solve, the chain
+    as Pallas kernels under a custom VJP: interpreted here), forced:
+    ``o`` and all five gradients at the tolerances of the ``lax.scan``
+    form's test, the last state at 1e-5."""
+    args, rule, oracle = _kernel_case(case)
+
+    def both(form):
+        def scalar(*a):
+            o, s = form(*a)
+            return jnp.sum(jnp.sin(o)) + jnp.sum(s * s)
+
+        # fresh functions: a cached trace would not ask the rule again
+        return (jax.jit(lambda *a: form(*a))(*args),
+                jax.jit(jax.grad(lambda *a: scalar(*a), range(5)))(*args))
+
+    (o_r, s_r), want = both(oracle)         # this backend: never a kernel
+    _forced(monkeypatch)
+    (o_c, s_c), got = both(rule)
+    assert float(jnp.abs(o_r).max()) > 1e-3
+    np.testing.assert_allclose(o_c, o_r, rtol=2e-5, atol=2e-6)
+    # 64 tokens a chunk under the spread decays: BOTH forms are 9e-6 off
+    # a float64 recurrence in a few entries of a state of 1.3 (the
+    # chunk's length, not the form; the other test's chunks are 16)
+    np.testing.assert_allclose(s_c, s_r, rtol=2e-5, atol=1e-5)
+    for name, a, b in zip("q k v g beta".split(), got, want):
+        assert np.isfinite(a).all(), name
+        # aligned keys: the gradient to g, at most 0.14, is a difference
+        # of sums the size of the others' (5 to 30), and both forms are
+        # 1e-5 off a float64 recurrence in it
+        floor = 2e-5 if (case, name) == ("aligned_keys", "g") else 5e-7
+        np.testing.assert_allclose(
+            a, b, rtol=2e-4, atol=2e-5 * float(jnp.abs(b).max()) + floor,
+            err_msg=name)
+
+
+@pytest.mark.parametrize("n", [16, 32, 64, 128, 48])
+def test_the_rolled_inverse_is_the_float64_inverse(n):
+    """``(I + a)^-1`` of random strict triangles and of 0.99 of the
+    all-ones one (the power series' failing case: its inverse's entries
+    stay under 1, its powers do not), as one block of rows (16, 48) and
+    as two, four and eight joined: 4e-7 of the largest entry, and
+    nothing over the diagonal."""
+    rng = np.random.default_rng(n)
+    a = np.tril(rng.normal(size=(5, n, n)) * 0.3, -1)
+    a[0] = np.tril(np.full((n, n), 0.99), -1)
+    want = np.linalg.inv(np.eye(n) + a)
+    got = gated_delta._unit_lower_inverse(jnp.asarray(a, jnp.float32))
+    assert np.abs(got - want).max() < 4e-7 * np.abs(want).max()
+    np.testing.assert_array_equal(np.triu(got, 1), 0.0)
+
+
+def _equations(jaxpr, inside=()):
+    """Every equation of ``jaxpr`` and of the jaxprs its equations hold
+    (a ``jit``, a ``custom_vjp``, a loop's body), with the primitives it
+    sits under; a Pallas call is one equation."""
+    for eqn in jaxpr.eqns:
+        yield eqn, inside
+        if eqn.primitive.name == "pallas_call":
+            continue
+        for value in eqn.params.values():
+            for inner in value if isinstance(value, (list, tuple)) else [
+                    value]:
+                inner = getattr(inner, "jaxpr", inner)
+                if hasattr(inner, "eqns"):
+                    yield from _equations(inner,
+                                          inside + (eqn.primitive.name,))
+
+
+def test_kernel_form_has_no_long_loop_and_no_triangular_solve(monkeypatch):
+    """What the chip's trace is held to (``chip_smoke.py``), on the
+    jaxpr: forced, the rule and its gradient hold two Pallas calls, no
+    ``triangular_solve``, no ``while``, and one loop, the solve's rows,
+    of :data:`SOLVE_BLOCK` steps (no scan over the chunks: 2 here, 64 in
+    the cell); as this backend runs it, the scan over the chunks, its
+    transpose, the solve and the two solves of its cotangent."""
+    args = _rule_inputs(128, "spread", seed=7)
+
+    def primitives():
+        jaxpr = jax.make_jaxpr(jax.grad(
+            lambda *a: gated_delta_rule(*a)[0].sum(), range(5)))(*args)
+        eqns = [e for e, _ in _equations(jaxpr.jaxpr)]
+        count = {p: sum(e.primitive.name == p for e in eqns) for p in (
+            "pallas_call", "while", "triangular_solve")}
+        return count, sorted(e.params["length"] for e in eqns
+                             if e.primitive.name == "scan")
+
+    assert primitives() == ({"pallas_call": 0, "while": 0,
+                             "triangular_solve": 3}, [2, 2])
+    _forced(monkeypatch)
+    assert primitives() == ({"pallas_call": 2, "while": 0,
+                             "triangular_solve": 0},
+                            [gated_delta.SOLVE_BLOCK])
+
+
+def test_the_solve_stays_a_small_program():
+    """The guard PR 47's refusal asks for: the solve at the cell's chunk
+    (the inverse, its two products, and the cotangents' four), counted
+    through its inner jaxprs, is under 200 equations; written out as
+    straight-line rows it was 2,000, nine copies of which doubled the
+    cell's ``grad_step`` and took 16 s of every warm set-up. Whoever
+    unrolls it again changes this number first."""
+    a = jnp.zeros((2, 3, 64, 64))
+    sides = jnp.zeros((2, 3, 64, 8)), jnp.zeros((2, 3, 64, 16))
+    jaxpr = jax.make_jaxpr(jax.grad(
+        lambda a, x, y: sum(o.sum() for o in gated_delta._unit_lower_solve(
+            a, x, y)), (0, 1, 2)))(a, *sides)
+    n = sum(1 for _ in _equations(jaxpr.jaxpr))
+    assert 40 < n < 200, n
+    inverse = jax.make_jaxpr(gated_delta._unit_lower_inverse)(a)
+    loops = [e for e, _ in _equations(inverse.jaxpr)
+             if e.primitive.name in ("scan", "while")]
+    assert [e.params["length"] for e in loops] == [gated_delta.SOLVE_BLOCK]
+
+
+def test_the_kernel_form_lowers_to_a_program_the_scan_forms_size(
+        monkeypatch):
+    """At the cell's shapes (one sequence of 4,096 tokens, 16 value
+    heads of 128 x 128, bfloat16 operands; forward, forward again under
+    ``jax.checkpoint`` and backward), lowered for a TPU with the Mosaic
+    calls in (no chip and no compiler: ``lowering_platforms``): the
+    kernel form's text, the kernels' serialized bodies included, is
+    under TWICE the scan form's (1.35 times when this was written; the
+    straight-line solve PR 47 shipped made the compiled rule 4.7
+    times the scan form's)."""
+    import geomx_tpu.ops
+
+    sd = jax.ShapeDtypeStruct
+    args = ([sd((1, 4096, 16, 128), jnp.float32)] * 3
+            + [sd((1, 4096, 16), jnp.float32)] * 2)
+
+    def text(kernel):
+        _forced(monkeypatch, kernel)
+        rule = jax.checkpoint(lambda *a: gated_delta_rule(
+            *a, dtype=jnp.bfloat16)[0].sum())
+        return jax.jit(jax.grad(lambda *a: rule(*a), range(5))).trace(
+            *args).lower(lowering_platforms=("tpu",)).as_text()
+
+    monkeypatch.setattr(geomx_tpu.ops, "pallas_interpret", lambda: False)
+    scan, kernel = text(False), text(True)
+    assert "tpu_custom_call" not in scan and "stablehlo.while" in scan
+    assert kernel.count("tpu_custom_call") == 2
+    assert len(kernel) < 2 * len(scan), (len(kernel), len(scan))
+
+
+@pytest.mark.parametrize("dk,dv,chunk,kernel", [
+    (128, 128, 64, True), (256, 128, 64, True), (64, 128, 64, False),
+    (128, 96, 64, False), (128, 128, 32, False), (128, 128, 128, False)],
+    ids=["cell", "wide_keys", "narrow_keys", "odd_values", "chunk_32",
+         "chunk_128"])
+def test_the_rule_by_backend_mesh_head_size_and_chunk(dk, dv, chunk, kernel,
+                                                      monkeypatch):
+    """``gated_delta.runs_kernel``: the ``lax.scan`` form wherever
+    Pallas is interpreted (this backend); where it compiles, the kernels
+    at head sizes of whole lane tiles and the default chunk, the scan
+    form at any other; the scan form again under a mesh, as a context
+    or as the operand's own sharding."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    import geomx_tpu.ops
+
+    def answer(b=1, sharding=None, under=lambda f: f):
+        out = []
+
+        def ask(q, v):
+            out.append(gated_delta.runs_kernel(q, v, chunk))
+            return q
+
+        jax.jit(under(ask)).lower(*(jax.ShapeDtypeStruct(
+            (b, 256, 4, d), jnp.bfloat16, sharding=sharding)
+            for d in (dk, dv)))
+        return out[0]
+
+    assert not answer()                 # a CPU backend
+    monkeypatch.setattr(geomx_tpu.ops, "pallas_interpret", lambda: False)
+    assert answer() == kernel
+    q = jax.ShapeDtypeStruct((1, 256, 4, dk), jnp.bfloat16)
+    assert gated_delta.runs_kernel(q, q, chunk, forced=True)
+    assert not gated_delta.runs_kernel(q, q, chunk, forced=False)
+    mesh = jax.make_mesh((2, 2), ("dp", "tp"))
+    with jax.set_mesh(mesh):
+        assert not answer()
+    sharded = NamedSharding(mesh, P("dp"))
+    assert not answer(2, sharded)
+    assert not answer(2, sharded, lambda f: jax.shard_map(
+        f, mesh=mesh, in_specs=P("dp"), out_specs=P("dp")))
     assert chunks_of(37, 8) == 5
 
 
