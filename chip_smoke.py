@@ -165,9 +165,13 @@ def check_attention_paths(on_chip: bool) -> None:
     it (``transformer.causal_attention``), at their head shapes and the
     cells' lengths (Mellum2's and Kanana's 8,192, the others' 4,096;
     Kanana's latent core has a query/key head of 192 beside a value head
-    of 128): on the chip the kernels must be in the block's own
+    of 128), and the sixth family's block-diffusion attention
+    (``transformer.block_diffusion_attention``: 8,192 positions, the
+    two copies of 4,096 tokens, under the block mask): on the chip the
+    kernels must be in the block's own
     lowering (a Mosaic call under the scope ``attention`` /
-    ``attention_full`` / ``attention_latent``) and agree with the dense
+    ``attention_full`` / ``attention_latent`` / ``attention_blockdiff``)
+    and agree with the dense
     product; off it the rule must give the dense product. Beside them
     Qwen3-Next's LINEAR block at the cell's head sizes (``ops/
     gated_delta.py``): on the chip its lowering holds the chain's Mosaic
@@ -182,6 +186,7 @@ def check_attention_paths(on_chip: bool) -> None:
     from geomx_tpu.models.mellum import MellumBlock
     from geomx_tpu.models.olmoe import OlmoeBlock
     from geomx_tpu.models.qwen3_next import Qwen3NextBlock
+    from geomx_tpu.models.sdar import SdarBlock
     from geomx_tpu.models.transformer import (causal_attention,
                                               dense_attention,
                                               grouped_attention)
@@ -189,6 +194,7 @@ def check_attention_paths(on_chip: bool) -> None:
     lengths = dict.fromkeys(("olmoe", "laguna", "qwen3next",
                              "qwen3next_linear"), 4096 if on_chip else 32)
     lengths["mellum"] = lengths["kanana"] = 8192 if on_chip else 32
+    lengths["sdar"] = 8192 if on_chip else 32   # both copies
     bf = jnp.bfloat16
     rope = dict(rope_type="default", rope_theta=10000.0,
                 partial_rotary_factor=0.5)
@@ -231,8 +237,14 @@ def check_attention_paths(on_chip: bool) -> None:
             dense_width=64, num_experts=4, experts_per_token=2,
             expert_width=64, shared_width=64, local_experts=(0, 4),
             routed_scale=1.0, compute_dtype=bf),
+        "sdar": SdarBlock(
+            dim=256, head_dim=128, query_heads=(0, 8),
+            key_value_heads=(0, 1), block_length=4, rope_theta=1e6,
+            num_experts=4, experts_per_token=2, expert_width=64,
+            local_experts=(0, 4), compute_dtype=bf),
     }
     scopes = {"olmoe": "attention", "kanana": "attention_latent",
+              "sdar": "attention_blockdiff",
               "qwen3next_linear": "gated_delta_rule"}
     for name, block in blocks.items():
         T = lengths[name]
@@ -271,6 +283,7 @@ def check_attention_paths(on_chip: bool) -> None:
                                    f"triangular_solve under {scope!r}: "
                                    f"{loops[:3]}")
         say(f"{name} block T={T}: {calls} Mosaic calls in the lowering")
+    check_block_mask_kernels(on_chip)
     if not on_chip:
         return
     shapes = {"olmoe": ((16, 128), (16, 128)),
@@ -299,6 +312,46 @@ def check_attention_paths(on_chip: bool) -> None:
             raise RuntimeError(f"{name} attention on the kernels disagrees "
                                f"with the dense product: {errs}")
     check_gated_delta_forms()
+
+
+def check_block_mask_kernels(on_chip: bool) -> None:
+    """The kernels under the block mask of block-diffusion training
+    against the dense product under the mask written out (forward, dQ,
+    dK, dV): at tiles of 128 x 128 over 1,024 positions (112 live tiles
+    of 64, every boundary of the mask inside some tile), and on the chip
+    at the cell's own tiles over 8,192 positions with grouped queries."""
+    import jax
+    import jax.numpy as jnp
+
+    from geomx_tpu.models.transformer import dense_block_diffusion_attention
+    from geomx_tpu.ops.flash_attention import flash_attention
+
+    cases = [(512 if on_chip else 32, 2, 128)]
+    if on_chip:
+        cases.append((4096, 4, None))
+    for t, group, tile in cases:
+        q = jax.random.normal(jax.random.PRNGKey(5), (1, 2 * t, 1, group,
+                                                      128), jnp.bfloat16)
+        k, v = (jax.random.normal(jax.random.PRNGKey(i), (1, 2 * t, 1, 128),
+                                  jnp.bfloat16) for i in (6, 7))
+        kernel = _probe(lambda q, k, v: flash_attention(
+            q, k, v, block_mask=(t, 4), block_q=tile, block_k=tile))
+        if on_chip and "tpu_custom_call" not in kernel.lower(
+                q, k, v).as_text():
+            raise RuntimeError("block-mask kernels: no Mosaic call in the "
+                               "lowering")
+        ((_s, out), got), ((_r, ref), want) = kernel(q, k, v), _probe(
+            lambda q, k, v: dense_block_diffusion_attention(q, k, v, 4))(
+                q, k, v)
+        errs = [float(jnp.linalg.norm((a - b).astype(jnp.float32))
+                      / jnp.linalg.norm(b.astype(jnp.float32)))
+                for a, b in zip((out, *got), (ref, *want))]
+        say(f"block-mask kernels, {2 * t} positions in tiles of "
+            f"{tile or 'the cell'}: rel l2 err fwd/dq/dk/dv = "
+            f"{[round(e, 5) for e in errs]}")
+        if not all(np.isfinite(e) and e < 0.01 for e in errs):
+            raise RuntimeError("the block-mask kernels disagree with the "
+                               f"dense product: {errs}")
 
 
 def check_gated_delta_forms() -> None:

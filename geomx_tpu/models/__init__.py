@@ -9,10 +9,12 @@ runs full causal attention as the Pallas kernels of
 ``ops.flash_attention`` where ``runs_kernel`` says so, ``rotary``
 positions over a part of a head or all of it, ``rotary_attention``,
 the branch three families share, ``gated_attention``, that branch
-times the sigmoid gate two of them have, and ``latent_attention``, the
+times the sigmoid gate two of them have, ``latent_attention``, the
 core of latent attention: one shared rotary key in the interleaved
-pairing beside a head's non-rotary part, a value head of its own size),
-and the six users of ``moe.sparse_dispatch``:
+pairing beside a head's non-rotary part, a value head of its own size,
+and ``block_diffusion_attention``, a clean and a noised copy of every
+sequence under one block mask, with ``rotary`` by position id),
+and the seven users of ``moe.sparse_dispatch``:
 ``moe.MoEBlock`` (top-1), ``olmoe`` (softmax top-8 of 64), ``laguna``
 (window and full attention layers with their own head counts, a gated
 attention output, a dense first layer, a shared expert beside sigmoid
@@ -23,8 +25,11 @@ gated shared expert beside normalised softmax top-10 of 512) and
 layer normalised softmax top-8 of 64 with no shared expert) and
 ``kanana`` (latent attention in every layer, a dense first layer, a
 shared expert beside sigmoid top-6 of 128 chosen by score plus a
-correction bias that is a buffer, every block rematerialised), the last
-five as one rank's share of an expert-parallel layout.
+correction bias that is a buffer, every block rematerialised) and
+``sdar`` (block-diffusion training: both copies of a sequence through
+every layer, per-head q/k norms, normalised softmax top-8 of 128, the
+weighted masked-token loss ``moe.masked_diffusion_loss``), the last
+six as one rank's share of an expert-parallel layout.
 """
 
 from geomx_tpu.models.cnn import LeNetCNN, create_cnn  # noqa: F401

@@ -28,7 +28,9 @@ scores normalised over the chosen and scaled (``models/laguna.py``,
 top-8 of 256 beside a shared expert that never comes here), softmax
 probabilities normalised over the chosen (``models/qwen3_next.py``,
 top-10 of 512), sigmoid scores chosen by score plus a bias and weighed
-by the score alone (``models/kanana.py``, top-6 of 128), one probability (:class:`MoEBlock`, the ``k = 1``
+by the score alone (``models/kanana.py``, top-6 of 128), softmax
+probabilities normalised over the chosen again (``models/sdar.py``,
+top-8 of 128 over two copies of every sequence), one probability (:class:`MoEBlock`, the ``k = 1``
 case); the dispatch multiplies and sums, it normalises nothing.
 
 :class:`MoEBlock` router: top-1 (Switch-style) with optional jitter
@@ -49,7 +51,7 @@ from geomx_tpu.models.transformer import runs_kernel
 
 __all__ = ["MoEBlock", "moe_param_sharding", "is_expert_param",
            "sparse_dispatch", "dispatch_cap", "gated_experts",
-           "next_token_loss"]
+           "next_token_loss", "masked_diffusion_loss"]
 
 # leaf names of expert-stacked params (leading axis = expert dim)
 EXPERT_PARAM_NAMES = ("w_up", "b_up", "w_dn", "b_dn")
@@ -223,6 +225,42 @@ def next_token_loss(model, variables, toks):
                             runs_kernel(toks[:, :-1]))
     return loss, jnp.stack([rows_local.astype(jnp.float32),
                             *(jnp.float32(c) for c in by_shape)])
+
+
+NOISE_STEPS = 1000      # a block's masking probability is n / NOISE_STEPS
+
+
+def masked_diffusion_loss(model, variables, batch):
+    """The masked block-diffusion loss of a rank's share of a sparse
+    decoder (``models/sdar.py``). ``batch`` [B, 3, T+1] int32 (the last
+    column is dropped): row 0 the clean ids ``x0``, each under
+    ``model.vocab - 1``, which is the MASK id; row 1 ``m``, 1 where the
+    position is masked in the noised copy; row 2 ``n`` in
+    1..``NOISE_STEPS``, constant over a block: the block's masking
+    probability is ``n / NOISE_STEPS``. The noise is data: nothing is
+    drawn here. The model sees ``[x0 ; where(m, MASK, x0)]`` and gives
+    the logits of the noised half; the loss is the cross-entropy AT the
+    masked positions (no shift), each weighted by the inverse of its
+    block's probability, over all positions:
+
+        (1 / (B T)) sum m * (NOISE_STEPS / n) * -log softmax(logits)[x0]
+
+    Returns (loss, [the rows routed here, then ``model.counts``, the
+    masked positions, the positions that could bear loss]) in
+    :func:`next_token_loss`'s form."""
+    x0, m, n = (batch[:, i, :-1] for i in range(3))
+    masked = m > 0
+    ids = jnp.concatenate([x0, jnp.where(masked, model.vocab - 1, x0)], 1)
+    logits, rows_local = model.apply(variables, ids)
+    nll = -jnp.take_along_axis(jax.nn.log_softmax(logits), x0[..., None],
+                               axis=-1)[..., 0]
+    weight = masked * (NOISE_STEPS / n.astype(jnp.float32))
+    loss = jnp.sum(weight * nll) / x0.size
+    by_shape = model.counts(*x0.shape, runs_kernel(ids))
+    return loss, jnp.stack([
+        rows_local.astype(jnp.float32),
+        *(jnp.float32(c) for c in by_shape),
+        jnp.sum(masked).astype(jnp.float32), jnp.float32(x0.size)])
 
 
 class MoEBlock(nn.Module):

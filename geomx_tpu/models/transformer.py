@@ -10,6 +10,7 @@ this is the capability the TPU build adds as first-class.
 from __future__ import annotations
 
 import math
+from functools import partial
 from typing import Any, Callable, Optional
 
 import flax.linen as nn
@@ -259,16 +260,22 @@ def rotary_frequencies(rope, head_dim: int):
     return scaled.astype(np.float32), float(rope["attention_factor"])
 
 
-def rotary(x, inv_freq, factor: float, interleaved: bool = False):
+def rotary(x, inv_freq, factor: float, interleaved: bool = False,
+           positions=None):
     """Rotary positions on the leading ``2 * len(inv_freq)`` dims of
     every head of ``x`` [B, T, ..., head_dim] (HF's half-split layout,
     ``x * cos + rotate_half(x) * sin``); the other dims pass. Angles in
-    float32. ``interleaved``: the pairs' members come in as neighbours
+    float32. ``positions`` [T]: the position id of each index along the
+    axis, where that is not the index itself (two copies of a sequence
+    side by side carry 0..T/2-1 twice). ``interleaved``: the pairs' members come in as neighbours
     ``(2i, 2i + 1)`` and are parted into the two halves first (HF's
     ``apply_rotary_pos_emb_interleave``); they stay parted on the way
     out, which a product of two such tensors does not see."""
     t, rot = x.shape[1], 2 * len(inv_freq)
-    ang = jnp.arange(t, dtype=jnp.float32)[:, None] * jnp.asarray(inv_freq)
+    if positions is None:
+        positions = jnp.arange(t, dtype=jnp.float32)
+    ang = jnp.asarray(positions, jnp.float32)[:, None] \
+        * jnp.asarray(inv_freq)
     ang = jnp.concatenate([ang, ang], -1).reshape(
         (1, t) + (1,) * (x.ndim - 3) + (rot,))
     turned, passed = x[..., :rot].astype(jnp.float32), x[..., rot:]
@@ -306,6 +313,77 @@ def causal_core(q):
     is not (:func:`rotary_attention` says why)."""
     return causal_attention if runs_kernel(q) \
         else jax.checkpoint(causal_attention)
+
+
+def block_diffusion_mask(t: int, block: int, xp=np):
+    """The [2t, 2t] mask of block-diffusion training, bool (``xp``:
+    numpy, or ``jax.numpy`` to form it inside a program from two iotas
+    and not as a [2t, 2t] constant): the clean copy of ``t`` positions
+    before their noised copy, position x of either in block ``x //
+    block``. A clean query sees the clean keys of the blocks up to and
+    including its own and no noised key; a noised query the clean keys
+    of the blocks strictly before its own and the noised keys of its
+    own block, both directions. Every row has a live key."""
+    at = xp.arange(2 * t)
+    clean, blk = at < t, at % t // block
+    return xp.where(
+        clean[:, None], clean[None] & (blk[None] <= blk[:, None]),
+        xp.where(clean[None], blk[None] < blk[:, None],
+                 blk[None] == blk[:, None]))
+
+
+def block_score_entries(t: int, block: int):
+    """(live, computed) score entries of one head over one sequence of
+    ``t`` tokens under :func:`block_diffusion_mask`: a row of either
+    copy keeps as many keys as its block's end counts, ``t * (t +
+    block)`` in all where blocks are whole, of the ``4 * t * t`` the
+    dense product has by its shape."""
+    ends = np.minimum((np.arange(t) // block + 1) * block, t)
+    return 2 * int(ends.sum()), 4 * t * t
+
+
+def kernel_block_score_entries(t: int, block: int, head_dim: int) -> int:
+    """Score entries the kernels compute for one head over one sequence
+    under the block mask: the live tiles' area, at the blocks they run
+    with for ``2 * t`` positions."""
+    from geomx_tpu.ops.flash_attention import (attention_blocks,
+                                               block_mask_live_blocks)
+
+    block_q, block_k = attention_blocks(2 * t, head_dim)
+    return block_mask_live_blocks(t, block, block_q, block_k) \
+        * block_q * block_k
+
+
+def dense_block_diffusion_attention(q, k, v, block: int):
+    """:func:`block_diffusion_attention` as the dense [2T, 2T] product
+    under :func:`block_diffusion_mask` written out, float32 scores:
+    the form off the kernels, and what the kernels are held to."""
+    s = jnp.einsum("bqkgd,bjkd->bkgqj", q, k,
+                   preferred_element_type=jnp.float32)
+    s = jnp.where(block_diffusion_mask(q.shape[1] // 2, block, jnp),
+                  s / jnp.sqrt(jnp.float32(q.shape[-1])), -1e30)
+    p = jax.nn.softmax(s, axis=-1)
+    return jnp.einsum("bkgqj,bjkd->bqkgd", p.astype(v.dtype), v)
+
+
+def block_diffusion_attention(q, k, v, block: int):
+    """Attention over a clean and a noised copy of every sequence side
+    by side under :func:`block_diffusion_mask`: ``q`` [B, 2T, KV, G, D]
+    on ``k``, ``v`` [B, 2T, KV, D], positions already on them. ONE
+    softmax a query over the keys the mask allows, float32 scores. In
+    the form :func:`runs_kernel` gives, as :func:`causal_core` keeps
+    it: the kernels with their block mask (dead tiles neither computed
+    nor fetched), or :func:`dense_block_diffusion_attention`, computed
+    again on the way back. Under the scope ``blockdiff_core``. Returns
+    [B, 2T, KV, G, D] (``models/sdar.py``)."""
+    with jax.named_scope("blockdiff_core"):
+        if runs_kernel(q):
+            from geomx_tpu.ops.flash_attention import flash_attention
+
+            return flash_attention(q, k, v,
+                                   block_mask=(q.shape[1] // 2, block))
+        return jax.checkpoint(partial(
+            dense_block_diffusion_attention, block=block))(q, k, v)
 
 
 def latent_attention(q_nope, q_rope, k_nope, k_rope, v, inv_freq):

@@ -26,6 +26,13 @@ The logsumexp rides through the kernels as ``[B, H, T, 1]`` — TPU block
 shapes must keep their last two dims (8, 128)-aligned or equal to the
 full array dims, which a trailing singleton satisfies for vectors.
 
+Masks: the kernels take two, both static and both with dead blocks
+neither computed nor fetched and a mask built only on the blocks a
+boundary crosses: causal with an offset (key j <= query i + offset),
+and the block mask of block-diffusion training over a clean and a
+noised copy of one sequence side by side (:func:`_block_rule`). No
+window, bias or dropout.
+
 Interpret mode is chosen by ``ops.pallas_interpret()`` alone: compiled on
 a TPU backend, interpreted elsewhere — which is what the CPU test suite
 exercises against the dense reference.
@@ -36,7 +43,7 @@ from __future__ import annotations
 import functools
 
 __all__ = ["flash_attention", "make_sharded_flash_attention",
-           "attention_blocks", "live_blocks"]
+           "attention_blocks", "live_blocks", "block_mask_live_blocks"]
 
 
 def _round_up(x: int, m: int) -> int:
@@ -68,11 +75,122 @@ def live_blocks(t: int, block_q: int, block_k: int) -> int:
     return sum(min(nk, -(-(i + 1) * block_q // block_k)) for i in range(nq))
 
 
+def _seen(x, intervals, xp):
+    """The member of the union of ``intervals`` ((lo, hi, non-empty)
+    triples of block indices) that a sweep standing at ``x`` has in
+    hand: ``x`` itself inside one, else the last member before ``x``,
+    else the first after it."""
+    before, first = -1, 2 ** 30
+    for lo, hi, some in intervals:
+        before = xp.maximum(before, xp.where(
+            some & (lo <= x), xp.minimum(hi, x), -1))
+        first = xp.minimum(first, xp.where(some, lo, 2 ** 30))
+    return xp.where(before >= 0, before, first)
+
+
+def _block_rule(xp, t: int, b: int, block_q: int, block_k: int):
+    """The block mask of block-diffusion training over ``2 * t``
+    positions, the clean copy of a sequence before its noised copy, in
+    blocks of ``b`` positions: a clean query sees the clean keys of the
+    blocks up to and including its own, a noised query the clean keys
+    of the blocks strictly before its own and the noised keys of its
+    own block. Every answer is written with the START of a position's
+    block (``x // b * b``), so a row's keys are two ranges: clean row
+    i: [0, start(i) + b); noised row t + l: [0, start(l)) and
+    t + [start(l), start(l) + b). Returns the kernels' five questions,
+    over ``xp`` (``jax.numpy`` on the grid's traced indices,
+    ``numpy`` for counting by hand): ``mask(qpos, kpos)`` elementwise;
+    ``live(qi, kj)``, ``whole(qi, kj)`` a tile; ``k_seen``, ``q_seen``
+    the tile a sweep fetches (a dead tile's index is that of the live
+    one in hand)."""
+    n = 2 * t
+
+    def start(x):
+        return x // b * b
+
+    def tile(i, size):
+        """Tile ``i`` of ``size`` positions, padding left out: (has
+        clean positions, its first, has noised positions, the first and
+        the last of them in the copy's own numbering)."""
+        first = i * size
+        last = xp.minimum(first + size, n) - 1
+        return first < t, first, last >= t, xp.maximum(first, t) - t, last - t
+
+    def k_intervals(qi):
+        """k-blocks that hold a key some row of q-block ``qi`` sees:
+        the clean keys [0, end) and the noised keys [lo, hi)."""
+        has_c, q0, has_n, n_lo, n_hi = tile(qi, block_q)
+        c_hi = xp.minimum(q0 + block_q, t) - 1
+        end = xp.maximum(
+            xp.where(has_c, xp.minimum(start(c_hi) + b, t), 0),
+            xp.where(has_n, start(n_hi), 0))
+        lo = t + start(n_lo)
+        hi = t + xp.minimum(start(n_hi) + b, t)
+        return ((0 * qi, (end - 1) // block_k, end > 0),
+                (lo // block_k, (hi - 1) // block_k, has_n))
+
+    def q_intervals(kj):
+        """q-blocks that hold a row which sees some key of k-block
+        ``kj``: clean rows from the block of its first clean key on,
+        noised rows of the blocks behind that one, and the noised rows
+        of its noised keys' blocks."""
+        has_c, k0, has_n, n_lo, n_hi = tile(kj, block_k)
+        behind = start(k0) + b
+        last = xp.minimum(start(n_hi) + b, t) - 1
+        return ((start(k0) // block_q, (t - 1) // block_q + 0 * kj, has_c),
+                ((t + behind) // block_q, (n - 1) // block_q + 0 * kj,
+                 has_c & (behind < t)),
+                ((t + start(n_lo)) // block_q, (t + last) // block_q,
+                 has_n))
+
+    def mask(qpos, kpos):
+        clean_q, clean_k = qpos < t, kpos < t
+        own = start(xp.where(clean_q, qpos, qpos - t))
+        # no select between masks: Mosaic has none for vectors of bits
+        return (kpos < n) & (
+            (clean_k & (kpos < xp.where(clean_q, own + b, own)))
+            | (~clean_q & (kpos >= t + own) & (kpos < t + own + b)))
+
+    def live(qi, kj):
+        (_, c_hi, some_c), (n_lo, n_hi, some_n) = k_intervals(qi)
+        return (some_c & (kj <= c_hi)) | (some_n & (n_lo <= kj) & (kj <= n_hi))
+
+    def whole(qi, kj):
+        has_c, q0, has_n, n_lo, n_hi = tile(qi, block_q)
+        k0, end = kj * block_k, (kj + 1) * block_k
+        clean_keys = (~has_c | (end <= start(q0) + b)) \
+            & (~has_n | (end <= start(n_lo)))
+        noised_keys = ~has_c & (k0 >= t + start(n_hi)) \
+            & (end <= t + start(n_lo) + b)
+        return (end <= n) & xp.where(end <= t, clean_keys, noised_keys)
+
+    return (mask, live, whole,
+            lambda qi, kj: _seen(kj, k_intervals(qi), xp),
+            lambda qi, kj: _seen(qi, q_intervals(kj), xp))
+
+
+def block_mask_live_blocks(t: int, b: int, block_q: int,
+                           block_k: int) -> int:
+    """(q-block, k-block) pairs of the block mask over ``2 * t``
+    positions in blocks of ``b`` (:func:`_block_rule`) that hold an
+    entry the mask keeps: the tiles the kernels compute."""
+    import numpy as np
+
+    block_q, block_k = (min(x, _round_up(2 * t, 8))
+                        for x in (block_q, block_k))
+    live = _block_rule(np, t, b, block_q, block_k)[1]
+    qi, kj = np.meshgrid(np.arange(-(-2 * t // block_q)),
+                         np.arange(-(-2 * t // block_k)), indexing="ij")
+    return int(live(qi, kj).sum())
+
+
 @functools.lru_cache(maxsize=None)
 def _kernels(Tq: int, Tk: int, D: int, Dv: int, block_q: int, block_k: int,
-             causal: bool, q_len: int, kv_len: int, group: int,
-             interpret: bool):
-    """Build (fwd, bwd_dq, bwd_dkv) pallas_calls for one static shape.
+             rule, q_len: int, kv_len: int, group: int, interpret: bool):
+    """Build (fwd, bwd_dq, bwd_dkv) pallas_calls for one static shape
+    and one static mask, ``rule``: ``True`` causal, ``False`` none, or
+    ``(t, b)``, the block mask of :func:`_block_rule` over ``q_len ==
+    kv_len == 2 * t`` positions.
 
     All three work on ``[B, H, T, D]``-transposed arrays (``v``, ``o``
     and their cotangents ``[B, H, T, Dv]``: a value head has its own
@@ -95,10 +213,15 @@ def _kernels(Tq: int, Tk: int, D: int, Dv: int, block_q: int, block_k: int,
     nk = Tk // block_k
     neg_inf = -1e30
 
+    causal = rule is True
     # decode convention: when Tq != Tk the queries are the LAST q_len
     # positions of the key sequence (kv-cache decode), so q row i sits at
     # absolute position i + (kv_len - q_len)
     causal_offset = kv_len - q_len
+    block = not isinstance(rule, bool)
+    if block:
+        (block_mask, block_live, block_whole, block_k_seen,
+         block_q_seen) = _block_rule(jnp, *rule, block_q, block_k)
 
     def _mask(qi, kj):
         """[block_q, block_k] validity mask for q-block qi, k-block kj."""
@@ -106,6 +229,8 @@ def _kernels(Tq: int, Tk: int, D: int, Dv: int, block_q: int, block_k: int,
             jnp.int32, (block_q, block_k), 0)
         kpos = kj * block_k + jax.lax.broadcasted_iota(
             jnp.int32, (block_q, block_k), 1)
+        if block:
+            return block_mask(qpos, kpos)
         m = kpos < kv_len
         if causal:
             m = m & (qpos + causal_offset >= kpos)
@@ -113,12 +238,16 @@ def _kernels(Tq: int, Tk: int, D: int, Dv: int, block_q: int, block_k: int,
 
     def _live(qi, kj):
         """Does (q-block qi, k-block kj) contribute at all?"""
+        if block:
+            return block_live(qi, kj)
         if not causal:
             return True
         return kj * block_k < (qi + 1) * block_q + causal_offset
 
     def _whole(qi, kj):
         """Is every entry of the block valid (no mask to apply)?"""
+        if block:
+            return block_whole(qi, kj)
         inside = (kj + 1) * block_k <= kv_len
         if not causal:
             return inside
@@ -127,7 +256,8 @@ def _kernels(Tq: int, Tk: int, D: int, Dv: int, block_q: int, block_k: int,
 
     def _on_live_block(qi, kj, body):
         """Run ``body(mask or None)`` for a live block: the mask is
-        built only where the block crosses the diagonal or the padding."""
+        built only where the block crosses a boundary of the mask or
+        the padding."""
         whole = _whole(qi, kj)
 
         @pl.when(_live(qi, kj) & jnp.logical_not(whole))
@@ -141,12 +271,16 @@ def _kernels(Tq: int, Tk: int, D: int, Dv: int, block_q: int, block_k: int,
     # a dead block's tile takes the index of the live one beside it, so
     # the pipeline sees no new block and issues no DMA for it
     def _k_seen(qi, kj):
+        if block:
+            return jnp.clip(block_k_seen(qi, kj), 0, nk - 1)
         if not causal:
             return kj
         last = ((qi + 1) * block_q + causal_offset - 1) // block_k
         return jnp.minimum(kj, jnp.clip(last, 0, nk - 1))
 
     def _q_seen(qi, kj):
+        if block:
+            return jnp.clip(block_q_seen(qi, kj), 0, nq - 1)
         if not causal:
             return qi
         first = (kj * block_k - causal_offset) // block_q
@@ -348,8 +482,8 @@ def _kernels(Tq: int, Tk: int, D: int, Dv: int, block_q: int, block_k: int,
     return fwd, bwd_dq, bwd_dkv
 
 
-def flash_attention(q, k, v, *, causal: bool = True, block_q=None,
-                    block_k=None):
+def flash_attention(q, k, v, *, causal: bool = True, block_mask=None,
+                    block_q=None, block_k=None):
     """Memory-efficient exact attention; drop-in for ``dense_attention``
     and, with grouped queries, for ``grouped_attention``.
 
@@ -362,7 +496,11 @@ def flash_attention(q, k, v, *, causal: bool = True, block_q=None,
     never repeated; dK and dV are summed over the group inside the
     kernel. A value head may have another size than a query/key head
     (``v`` ``[B, T, KV, Dv]``; the return then ``[B, T, H, Dv]``: the
-    form latent attention has, 192 beside 128). Scores are scaled by
+    form latent attention has, 192 beside 128). ``block_mask`` ``(t,
+    b)`` in place of ``causal``: the sequence axis holds the clean and
+    the noised copy of ``t`` positions, ``2 * t`` queries and keys,
+    under the block mask of block-diffusion training in blocks of ``b``
+    (:func:`_block_rule`). Scores are scaled by
     ``1/sqrt(D)``. ``block_q`` /
     ``block_k``: :func:`attention_blocks` unless given. Differentiable
     via a custom VJP whose backward runs as Pallas kernels
@@ -383,7 +521,8 @@ def flash_attention(q, k, v, *, causal: bool = True, block_q=None,
         b, t, kv, g, d = q.shape
         return flash_attention(
             q.reshape(b, t, kv * g, d), k, v, causal=causal,
-            block_q=block_q, block_k=block_k).reshape(
+            block_mask=block_mask, block_q=block_q,
+            block_k=block_k).reshape(
                 b, t, kv, g, v.shape[-1])
     if q.ndim != 4:
         raise ValueError(f"expected [B, T, H, D] tensors, got {q.shape}")
@@ -393,7 +532,12 @@ def flash_attention(q, k, v, *, causal: bool = True, block_q=None,
             or q.shape[2] != group * k.shape[2]):
         raise ValueError(f"query heads {q.shape} are not groups of the "
                          f"key/value heads {k.shape}, {v.shape}")
-    if causal and Tq > Tk:
+    if block_mask is not None:
+        if not Tq == Tk == 2 * block_mask[0]:
+            raise ValueError(
+                f"the block mask {block_mask} is over {2 * block_mask[0]} "
+                f"queries and keys, got {Tq} and {Tk}")
+    elif causal and Tq > Tk:
         # no decode-convention alignment exists for more queries than
         # keys; without this check, q rows with zero visible keys would
         # silently emit the value-block mean (online-softmax artifact)
@@ -403,7 +547,8 @@ def flash_attention(q, k, v, *, causal: bool = True, block_q=None,
     bq = min(block_q or attention_blocks(Tq, D)[0], _round_up(Tq, 8))
     bk = min(block_k or attention_blocks(Tk, D)[1], _round_up(Tk, 8))
     Tqp, Tkp = _round_up(Tq, bq), _round_up(Tk, bk)
-    fwd, bwd_dq, bwd_dkv = _kernels(Tqp, Tkp, D, v.shape[3], bq, bk, causal,
+    rule = causal if block_mask is None else tuple(block_mask)
+    fwd, bwd_dq, bwd_dkv = _kernels(Tqp, Tkp, D, v.shape[3], bq, bk, rule,
                                     Tq, Tk, group, pallas_interpret())
 
     @jax.custom_vjp

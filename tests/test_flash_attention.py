@@ -356,3 +356,127 @@ def test_kernel_score_entries_at_the_cells_shapes():
     # latent attention at 8,192: a query/key head of 192 runs 512 x 512,
     # 136 live tiles of 256
     assert kernel_score_entries(8192, 192) == 136 * 512 * 512 == 35_651_584
+
+
+# -- the block mask of block-diffusion training -------------------------------
+
+# (t, block, block_q, block_k): tiles that straddle the two copies, a
+# short last block, a block no power of two, one tile for everything,
+# and the cell's own shape
+BLOCK_MASK_SHAPES = [
+    (16, 4, 8, 8), (16, 4, 8, 16), (24, 4, 16, 8), (20, 4, 8, 16),
+    (36, 4, 16, 32), (18, 4, 8, 8), (30, 3, 8, 16), (8, 4, 16, 16),
+    (100, 4, 32, 64), (64, 16, 8, 8), (64, 2, 8, 24), (4096, 4, 512, 1024)]
+
+
+@pytest.mark.parametrize("t,block,block_q,block_k", BLOCK_MASK_SHAPES)
+def test_the_block_rule_against_the_mask_written_out(t, block, block_q,
+                                                     block_k):
+    """The kernels' five questions about the block mask (``_block_rule``
+    over numpy) against the [2T, 2T] mask itself: the elementwise mask;
+    a tile is live where it holds a kept entry and whole where every
+    entry of its real rows is kept; a sweep fetches a live tile as
+    itself and, at a dead one, the live tile it has in hand (the last
+    before it, else the first after)."""
+    from geomx_tpu.models.transformer import block_diffusion_mask
+    from geomx_tpu.ops.flash_attention import (_block_rule, _round_up,
+                                               block_mask_live_blocks)
+
+    n = 2 * t
+    block_q, block_k = (min(b, _round_up(n, 8)) for b in (block_q, block_k))
+    nq, nk = -(-n // block_q), -(-n // block_k)
+    mask, live, whole, k_seen, q_seen = _block_rule(
+        np, t, block, block_q, block_k)
+    want = np.zeros((nq * block_q, nk * block_k), bool)
+    want[:n, :n] = block_diffusion_mask(t, block)
+    rows, cols = np.meshgrid(np.arange(nq * block_q),
+                             np.arange(nk * block_k), indexing="ij")
+    np.testing.assert_array_equal(mask(rows, cols)[:n], want[:n])
+    tiles = want.reshape(nq, block_q, nk, block_k)
+    qi, kj = np.meshgrid(np.arange(nq), np.arange(nk), indexing="ij")
+    alive = tiles.any(axis=(1, 3))
+    np.testing.assert_array_equal(live(qi, kj), alive)
+    assert block_mask_live_blocks(t, block, block_q, block_k) == alive.sum()
+    real = np.arange(nq * block_q).reshape(nq, block_q) < n
+    np.testing.assert_array_equal(
+        whole(qi, kj), (tiles | ~real[:, :, None, None]).all(axis=(1, 3)))
+
+    def in_hand(alive_along):
+        """For each index of a sweep: itself where live, else the last
+        live one before it, else the first live one."""
+        at = np.where(alive_along, np.arange(len(alive_along)), -1)
+        last = np.maximum.accumulate(at)
+        return np.where(last >= 0, last, np.argmax(alive_along))
+
+    np.testing.assert_array_equal(
+        k_seen(qi, kj), np.stack([in_hand(row) for row in alive]))
+    np.testing.assert_array_equal(
+        q_seen(qi, kj), np.stack([in_hand(col) for col in alive.T]).T)
+
+
+def test_the_block_mask_at_the_cells_shape():
+    from geomx_tpu.models.transformer import (block_score_entries,
+                                              kernel_block_score_entries)
+
+    # 20 + 20 + 8 live tiles of 512 x 1,024 over [8,192, 8,192]
+    assert kernel_block_score_entries(4096, 4, 128) == 48 * 512 * 1024
+    live, dense = block_score_entries(4096, 4)
+    assert live < kernel_block_score_entries(4096, 4, 128) < dense
+
+
+@pytest.mark.parametrize("t,block,block_q,block_k", [
+    (16, 4, 8, 8), (36, 4, 16, 32), (20, 4, 16, 8), (30, 3, 8, 16)])
+def test_block_mask_kernels_match_the_dense_product(t, block, block_q,
+                                                    block_k):
+    """Forward, dQ, dK and dV of the kernels under the block mask
+    (interpreted) against the dense product under the mask written out:
+    grouped queries, tiles that straddle the boundary between the
+    copies, T no multiple of a tile."""
+    from geomx_tpu.models.transformer import block_diffusion_attention
+
+    q = _rand((2, 2 * t, 2, 2, 16), 0)
+    k, v = _rand((2, 2 * t, 2, 16), 1), _rand((2, 2 * t, 2, 16), 2)
+    cot = _rand(q.shape, 3)
+
+    def kernel(q, k, v):
+        return flash_attention(q, k, v, block_mask=(t, block),
+                               block_q=block_q, block_k=block_k)
+
+    out, back = jax.vjp(kernel, q, k, v)
+    want, want_back = jax.vjp(
+        lambda q, k, v: block_diffusion_attention(q, k, v, block), q, k, v)
+    np.testing.assert_allclose(out, want, atol=2e-5, rtol=2e-5)
+    for name, a, b in zip("qkv", back(cot), want_back(cot)):
+        np.testing.assert_allclose(a, b, atol=2e-4, rtol=2e-4,
+                                   err_msg=f"d{name}")
+    with pytest.raises(ValueError, match="block mask"):
+        flash_attention(q[:, :-2], k, v, block_mask=(t, block))
+
+
+@pytest.mark.parametrize("t", [24, 36])
+def test_the_kernel_form_of_block_diffusion_attention(t, monkeypatch):
+    """``block_diffusion_attention`` in the form the rule gives on a TPU
+    (forced here, interpreted) against its dense form, two lengths, all
+    gradients; the kernel form is not rematerialised."""
+    from functools import partial
+
+    from geomx_tpu.models import transformer
+
+    q = _rand((1, 2 * t, 1, 4, 16), 4)
+    k, v = _rand((1, 2 * t, 1, 16), 5), _rand((1, 2 * t, 1, 16), 6)
+    cot = _rand(q.shape, 7)
+    want, want_back = jax.vjp(
+        lambda q, k, v: transformer.block_diffusion_attention(q, k, v, 4),
+        q, k, v)
+    monkeypatch.setattr(transformer, "runs_kernel",
+                        partial(transformer.runs_kernel, forced=True))
+    out, back = jax.vjp(
+        lambda q, k, v: transformer.block_diffusion_attention(q, k, v, 4),
+        q, k, v)
+    np.testing.assert_allclose(out, want, atol=2e-5, rtol=2e-5)
+    for name, a, b in zip("qkv", back(cot), want_back(cot)):
+        np.testing.assert_allclose(a, b, atol=2e-4, rtol=2e-4,
+                                   err_msg=f"d{name}")
+    text = str(jax.make_jaxpr(
+        lambda q: transformer.block_diffusion_attention(q, k, v, 4))(q))
+    assert "pallas_call" in text and "remat" not in text
