@@ -165,7 +165,9 @@ def check_attention_paths(on_chip: bool) -> None:
     it (``transformer.causal_attention``), at their head shapes and the
     cells' lengths (Mellum2's and Kanana's 8,192, the others' 4,096;
     Kanana's latent core has a query/key head of 192 beside a value head
-    of 128), and the sixth family's block-diffusion attention
+    of 128), Laguna's and Mellum2's sliding layers
+    (``transformer.window_core``: the kernels under their window rule,
+    scope ``attention_window``), and the sixth family's block-diffusion attention
     (``transformer.block_diffusion_attention``: 8,192 positions, the
     two copies of 4,096 tokens, under the block mask): on the chip the
     kernels must be in the block's own
@@ -194,6 +196,8 @@ def check_attention_paths(on_chip: bool) -> None:
     lengths = dict.fromkeys(("olmoe", "laguna", "qwen3next",
                              "qwen3next_linear"), 4096 if on_chip else 32)
     lengths["mellum"] = lengths["kanana"] = 8192 if on_chip else 32
+    lengths["laguna_window"] = lengths["laguna"]
+    lengths["mellum_window"] = lengths["mellum"]
     lengths["sdar"] = 8192 if on_chip else 32   # both copies
     bf = jnp.bfloat16
     rope = dict(rope_type="default", rope_theta=10000.0,
@@ -243,9 +247,15 @@ def check_attention_paths(on_chip: bool) -> None:
             num_experts=4, experts_per_token=2, expert_width=64,
             local_experts=(0, 4), compute_dtype=bf),
     }
+    # the sliding layers of the two families that have them
+    for name in ("laguna", "mellum"):
+        blocks[name + "_window"] = blocks[name].clone(
+            kind="sliding_attention")
     scopes = {"olmoe": "attention", "kanana": "attention_latent",
               "sdar": "attention_blockdiff",
-              "qwen3next_linear": "gated_delta_rule"}
+              "qwen3next_linear": "gated_delta_rule",
+              "laguna_window": "attention_window",
+              "mellum_window": "attention_window"}
     for name, block in blocks.items():
         T = lengths[name]
         x = jax.random.normal(jax.random.PRNGKey(0), (1, T, 256),
@@ -284,6 +294,7 @@ def check_attention_paths(on_chip: bool) -> None:
                                    f"{loops[:3]}")
         say(f"{name} block T={T}: {calls} Mosaic calls in the lowering")
     check_block_mask_kernels(on_chip)
+    check_window_kernels(on_chip)
     if not on_chip:
         return
     shapes = {"olmoe": ((16, 128), (16, 128)),
@@ -314,6 +325,27 @@ def check_attention_paths(on_chip: bool) -> None:
     check_gated_delta_forms()
 
 
+def _hold_kernels_to(what: str, kernels, reference, operands,
+                     on_chip: bool) -> None:
+    """Output, dQ, dK and dV of ``kernels(q, k, v)`` against
+    ``reference``'s, relative L2 errors under 1%; on the chip the
+    kernels' lowering must hold a Mosaic call."""
+    import jax.numpy as jnp
+
+    kernel = _probe(kernels)
+    if on_chip and "tpu_custom_call" not in kernel.lower(
+            *operands).as_text():
+        raise RuntimeError(f"{what}: no Mosaic call in the lowering")
+    ((_s, out), got), ((_r, ref), want) = kernel(*operands), _probe(
+        reference)(*operands)
+    errs = [float(jnp.linalg.norm((a - b).astype(jnp.float32))
+                  / jnp.linalg.norm(b.astype(jnp.float32)))
+            for a, b in zip((out, *got), (ref, *want))]
+    say(f"{what}: rel l2 err fwd/dq/dk/dv = {[round(e, 5) for e in errs]}")
+    if not all(np.isfinite(e) and e < 0.01 for e in errs):
+        raise RuntimeError(f"{what} disagree with their reference: {errs}")
+
+
 def check_block_mask_kernels(on_chip: bool) -> None:
     """The kernels under the block mask of block-diffusion training
     against the dense product under the mask written out (forward, dQ,
@@ -334,24 +366,45 @@ def check_block_mask_kernels(on_chip: bool) -> None:
                                                       128), jnp.bfloat16)
         k, v = (jax.random.normal(jax.random.PRNGKey(i), (1, 2 * t, 1, 128),
                                   jnp.bfloat16) for i in (6, 7))
-        kernel = _probe(lambda q, k, v: flash_attention(
-            q, k, v, block_mask=(t, 4), block_q=tile, block_k=tile))
-        if on_chip and "tpu_custom_call" not in kernel.lower(
-                q, k, v).as_text():
-            raise RuntimeError("block-mask kernels: no Mosaic call in the "
-                               "lowering")
-        ((_s, out), got), ((_r, ref), want) = kernel(q, k, v), _probe(
-            lambda q, k, v: dense_block_diffusion_attention(q, k, v, 4))(
-                q, k, v)
-        errs = [float(jnp.linalg.norm((a - b).astype(jnp.float32))
-                      / jnp.linalg.norm(b.astype(jnp.float32)))
-                for a, b in zip((out, *got), (ref, *want))]
-        say(f"block-mask kernels, {2 * t} positions in tiles of "
-            f"{tile or 'the cell'}: rel l2 err fwd/dq/dk/dv = "
-            f"{[round(e, 5) for e in errs]}")
-        if not all(np.isfinite(e) and e < 0.01 for e in errs):
-            raise RuntimeError("the block-mask kernels disagree with the "
-                               f"dense product: {errs}")
+        _hold_kernels_to(
+            f"block-mask kernels, {2 * t} positions in tiles of "
+            f"{tile or 'the cell'}",
+            lambda q, k, v: flash_attention(
+                q, k, v, block_mask=(t, 4), block_q=tile, block_k=tile),
+            lambda q, k, v: dense_block_diffusion_attention(q, k, v, 4),
+            (q, k, v), on_chip)
+
+
+def check_window_kernels(on_chip: bool) -> None:
+    """The kernels under the sliding window against the blocked product
+    ``transformer.window_attention`` with float32 scores (forward, dQ,
+    dK, dV): at tiles of 128 x 128 with a window no multiple of a tile,
+    and on the chip at the two cells' shapes and own tiles (window
+    1,024 at 8,192 positions, 512 at 4,096; 8 queries a key/value
+    head)."""
+    import jax
+    import jax.numpy as jnp
+
+    from geomx_tpu.models.transformer import window_attention
+    from geomx_tpu.ops.flash_attention import flash_attention
+
+    cases = [(1024 if on_chip else 64, 200 if on_chip else 24, 2,
+              128 if on_chip else 16)]
+    if on_chip:
+        cases += [(8192, 1024, 8, None), (4096, 512, 8, None)]
+    for t, window, group, tile in cases:
+        q = jax.random.normal(jax.random.PRNGKey(8), (1, t, 1, group, 128),
+                              jnp.bfloat16)
+        k, v = (jax.random.normal(jax.random.PRNGKey(i), (1, t, 1, 128),
+                                  jnp.bfloat16) for i in (9, 10))
+        _hold_kernels_to(
+            f"window kernels, window {window} over {t} positions in tiles "
+            f"of {tile or 'the cell'}",
+            lambda q, k, v: flash_attention(
+                q, k, v, window=window, block_q=tile, block_k=tile),
+            lambda q, k, v: window_attention(
+                q, k, v, window, scores_dtype=jnp.float32),
+            (q, k, v), on_chip)
 
 
 def check_gated_delta_forms() -> None:

@@ -4,9 +4,11 @@ CNN, examples/cnn.py:56-63).
 
 Decoders by module, not re-exported here: ``transformer`` (GPT-2 style;
 ``dense_attention``, and beside it ``grouped_attention`` and the blocked
-``window_attention`` for grouped queries, ``causal_attention``, which
-runs full causal attention as the Pallas kernels of
-``ops.flash_attention`` where ``runs_kernel`` says so, ``rotary``
+``window_attention`` for grouped queries, ``causal_attention`` and
+``window_core``, which run full causal and sliding-window attention as
+the Pallas kernels of ``ops.flash_attention`` where ``runs_kernel``
+says so (a TPU backend, no mesh, 2,048 positions and more, a window of
+512 and more) and as those products elsewhere, ``rotary``
 positions over a part of a head or all of it, ``rotary_attention``,
 the branch three families share, ``gated_attention``, that branch
 times the sigmoid gate two of them have, ``latent_attention``, the

@@ -54,6 +54,7 @@ from geomx_tpu.models.moe import (gated_experts, next_token_loss,
 from geomx_tpu.models.transformer import (FULL, HIGHEST, RMSNorm,
                                           gated_attention,
                                           kernel_score_entries,
+                                          kernel_window_score_entries,
                                           rotary_frequencies,
                                           score_entries)
 
@@ -173,13 +174,17 @@ class Laguna(nn.Module):
         """What a pass over ``batch`` sequences of ``t`` positions has
         by shape: (all routed (token, slot) rows, live score entries,
         computed score entries), the entries over all layers and held
-        query heads; ``kernel``: the full layers run as the kernel
-        (``transformer.runs_kernel``), which computes its live blocks."""
+        query heads; ``kernel``: the layers run as the kernels
+        (``transformer.runs_kernel``; the sliding ones from its floor on
+        the window), which compute their live blocks."""
         live = computed = 0
         for kind, (lo, hi) in zip(self.layer_types, self.query_heads):
             a, c = score_entries(t, None if kind == FULL else self.window)
             if kernel and kind == FULL:
                 c = kernel_score_entries(t, self.head_dim)
+            elif kernel:
+                c = kernel_window_score_entries(t, self.window,
+                                                self.head_dim)
             live, computed = live + (hi - lo) * a, computed + (hi - lo) * c
         sparse = sum(m == "sparse" for m in self.mlp_layer_types)
         return (batch * t * sparse * self.experts_per_token,

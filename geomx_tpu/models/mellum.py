@@ -56,6 +56,7 @@ from geomx_tpu.models.moe import (gated_experts, next_token_loss,
                                   sparse_dispatch)
 from geomx_tpu.models.transformer import (FULL, HIGHEST, RMSNorm,
                                           kernel_score_entries,
+                                          kernel_window_score_entries,
                                           rotary_attention,
                                           rotary_frequencies, score_entries)
 
@@ -146,14 +147,18 @@ class Mellum(nn.Module):
         """What a pass over ``batch`` sequences of ``t`` positions has
         by shape: (all routed (token, slot) rows, live score entries,
         computed score entries), the entries over all layers and held
-        query heads; ``kernel``: the full layers run as the kernel
-        (``transformer.runs_kernel``), which computes its live blocks."""
+        query heads; ``kernel``: the layers run as the kernels
+        (``transformer.runs_kernel``; the sliding ones from its floor on
+        the window), which compute their live blocks."""
         heads = self.query_heads[1] - self.query_heads[0]
         live = computed = 0
         for kind in self.layer_types:
             a, c = score_entries(t, None if kind == FULL else self.window)
             if kernel and kind == FULL:
                 c = kernel_score_entries(t, self.head_dim)
+            elif kernel:
+                c = kernel_window_score_entries(t, self.window,
+                                                self.head_dim)
             live, computed = live + heads * a, computed + heads * c
         return (batch * t * len(self.layer_types) * self.experts_per_token,
                 batch * live, batch * computed)

@@ -115,26 +115,34 @@ def grouped_attention(q, k, v, *, scores_dtype=None):
     return jnp.einsum("bkgqj,bjkd->bqkgd", p.astype(v.dtype), v)
 
 
-# The length from which full causal attention runs as the kernel: read
-# on a v5e (tools/attention_bench.py lengths; PERF.md section 6, PR 39)
+# The length from which attention runs as the kernel: read on a v5e
+# (tools/attention_bench.py lengths; PERF.md section 6, PR 39)
 KERNEL_MIN_T = 2048
+# The sliding window from which it does: under it the blocked product's
+# scores are few enough that XLA's form is as fast or faster (tools/
+# attention_bench.py windows; PERF.md section 6, PR 50)
+KERNEL_MIN_WINDOW = 512
 
 
-def runs_kernel(q, forced: Optional[bool] = None) -> bool:
-    """THE rule for the form of full causal attention over ``q``
-    [B, T, ...]: the Pallas kernels of ``ops/flash_attention.py`` where
-    they compile (``ops.pallas_interpret()`` false: a TPU backend), no
-    mesh is in play, and T is at or over :data:`KERNEL_MIN_T` (under it
-    a head's scores are a few MB and the dense product is as fast); the
-    dense product otherwise. It is one algorithm that wants another
-    form at another length, so the rule reads what the trace can see
-    and nothing names a model. A Pallas call has no partitioning rule,
-    so a mesh means dense: seen as the abstract mesh of the context
-    (``jax.set_mesh``, a ``shard_map`` body) or of ``q``'s own sharding
-    (an operand sharded over explicit axes). Operands that GSPMD shards
-    over ``Auto`` axes under a plain ``jit`` show no mesh while tracing:
-    such a caller passes its own ``attn_fn`` (:func:`make_attention`
-    with its mesh). ``forced`` is for tests: the answer itself."""
+def runs_kernel(q, forced: Optional[bool] = None,
+                window: Optional[int] = None) -> bool:
+    """THE rule for the form of attention over ``q`` [B, T, ...], full
+    causal, under a block mask or under a sliding ``window``: the
+    Pallas kernels of ``ops/flash_attention.py`` where they compile
+    (``ops.pallas_interpret()`` false: a TPU backend), no mesh is in
+    play, T is at or over :data:`KERNEL_MIN_T` (under it a head's
+    scores are a few MB and the dense product is as fast) and a window
+    at or over :data:`KERNEL_MIN_WINDOW` (under it the blocked product
+    has as few); the dense product otherwise. It is one algorithm that
+    wants another form at another length, so the rule reads what the
+    trace can see and nothing names a model. A Pallas call has no
+    partitioning rule, so a mesh means dense: seen as the abstract mesh
+    of the context (``jax.set_mesh``, a ``shard_map`` body) or of
+    ``q``'s own sharding (an operand sharded over explicit axes).
+    Operands that GSPMD shards over ``Auto`` axes under a plain ``jit``
+    show no mesh while tracing: such a caller passes its own
+    ``attn_fn`` (:func:`make_attention` with its mesh). ``forced`` is
+    for tests: the answer itself."""
     if forced is not None:
         return forced
     from geomx_tpu.ops import pallas_interpret
@@ -142,7 +150,8 @@ def runs_kernel(q, forced: Optional[bool] = None) -> bool:
     return (not pallas_interpret()
             and jax.sharding.get_abstract_mesh().empty
             and jax.typeof(q).sharding.mesh.empty
-            and q.shape[1] >= KERNEL_MIN_T)
+            and q.shape[1] >= KERNEL_MIN_T
+            and (window is None or window >= KERNEL_MIN_WINDOW))
 
 
 def causal_attention(q, k, v):
@@ -229,6 +238,22 @@ def kernel_score_entries(t: int, head_dim: int) -> int:
     return live_blocks(t, block_q, block_k) * block_q * block_k
 
 
+def kernel_window_score_entries(t: int, window: int, head_dim: int) -> int:
+    """Score entries :func:`window_core` computes for one head over one
+    sequence of ``t`` positions where the kernels run at that length:
+    the live tiles' area at the blocks they run with for that window,
+    and under :data:`KERNEL_MIN_WINDOW`, where the rule keeps the
+    blocked product, that product's entries."""
+    from geomx_tpu.ops.flash_attention import (attention_blocks,
+                                               window_live_blocks)
+
+    if window < KERNEL_MIN_WINDOW:
+        return score_entries(t, window)[1]
+    block_q, block_k = attention_blocks(t, head_dim, window)
+    return window_live_blocks(t, window, block_q, block_k) \
+        * block_q * block_k
+
+
 def rotary_frequencies(rope, head_dim: int):
     """(inverse frequencies of the rotated pairs, float32; the factor on
     cos and sin) from one block of HF ``rope_parameters`` (a block with
@@ -291,19 +316,16 @@ def rotary_attention(q, k, v, inv_freq, factor: float,
                      window: Optional[int] = None):
     """An attention branch with rotary positions on the way in: ``q``
     [B, T, KV, G, D], ``k`` and ``v`` [B, T, KV, D]. The core is
-    :func:`window_attention` over ``window`` keys or, with none,
-    :func:`causal_attention`. A dense core is computed again on the way
-    back (``jax.checkpoint``), so no [T, T] scores are kept; the kernel
-    keeps q, k, v, o and the log-sum-exp only, so under it a checkpoint
-    would buy nothing and cost a forward kernel a pass. Returns
+    :func:`window_core` over ``window`` keys or, with none,
+    :func:`causal_core`, each in the form :func:`runs_kernel` gives. A
+    dense core is computed again on the way back (``jax.checkpoint``),
+    so no [T, T] scores are kept; the kernel keeps q, k, v, o and the
+    log-sum-exp only, so under it a checkpoint would buy nothing and
+    cost a forward kernel a pass. Returns
     ``core(rotary(q), rotary(k), v)`` [B, T, KV, G, D]
     (``models/mellum.py``)."""
     q, k = rotary(q, inv_freq, factor), rotary(k, inv_freq, factor)
-    if window is not None:
-        core = jax.checkpoint(lambda q, k, v: window_attention(
-            q, k, v, window, scores_dtype=jnp.float32))
-    else:
-        core = causal_core(q)
+    core = causal_core(q) if window is None else window_core(q, window)
     return core(q, k, v)
 
 
@@ -313,6 +335,21 @@ def causal_core(q):
     is not (:func:`rotary_attention` says why)."""
     return causal_attention if runs_kernel(q) \
         else jax.checkpoint(causal_attention)
+
+
+def window_core(q, window: int):
+    """Sliding-window attention with float32 scores as a branch keeps
+    it, in the form :func:`runs_kernel` gives: the kernels with their
+    window rule (the k sweep covers the band only and no score reaches
+    HBM), or :func:`window_attention`, the blocked product, computed
+    again on the way back: the CPU form, the form under a mesh, and
+    what the kernels are held to. Same arithmetic in both."""
+    if runs_kernel(q, window=window):
+        from geomx_tpu.ops.flash_attention import flash_attention
+
+        return partial(flash_attention, window=window)
+    return jax.checkpoint(lambda q, k, v: window_attention(
+        q, k, v, window, scores_dtype=jnp.float32))
 
 
 def block_diffusion_mask(t: int, block: int, xp=np):

@@ -26,12 +26,16 @@ The logsumexp rides through the kernels as ``[B, H, T, 1]`` — TPU block
 shapes must keep their last two dims (8, 128)-aligned or equal to the
 full array dims, which a trailing singleton satisfies for vectors.
 
-Masks: the kernels take two, both static and both with dead blocks
+Masks: the kernels take three, all static and all with dead blocks
 neither computed nor fetched and a mask built only on the blocks a
 boundary crosses: causal with an offset (key j <= query i + offset),
-and the block mask of block-diffusion training over a clean and a
-noised copy of one sequence side by side (:func:`_block_rule`). No
-window, bias or dropout.
+the block mask of block-diffusion training over a clean and a noised
+copy of one sequence side by side (:func:`_block_rule`), and the
+sliding window (query i sees the keys j with 0 <= i - j < w;
+:func:`_window_rule`), under which the inner grid dimension is the
+band's own count of blocks and not the sequence's: a grid step costs
+the same whatever its tile, and most of a long sequence's tiles lie
+outside the band. No bias or dropout.
 
 Interpret mode is chosen by ``ops.pallas_interpret()`` alone: compiled on
 a TPU backend, interpreted elsewhere — which is what the CPU test suite
@@ -43,26 +47,33 @@ from __future__ import annotations
 import functools
 
 __all__ = ["flash_attention", "make_sharded_flash_attention",
-           "attention_blocks", "live_blocks", "block_mask_live_blocks"]
+           "attention_blocks", "live_blocks", "block_mask_live_blocks",
+           "window_live_blocks"]
 
 
 def _round_up(x: int, m: int) -> int:
     return -(-x // m) * m
 
 
-def attention_blocks(t: int, d: int):
+def attention_blocks(t: int, d: int, window=None):
     """(block_q, block_k) of the kernels for ``t`` positions and heads
-    of size ``d``, from the two alone. Read on a v5e (PERF.md section 6,
-    PR 39; ``tools/attention_bench.py blocks``): a grid step costs the
-    same whatever its tile, so small tiles are bound by the grid (128 x
-    128 is five times slower than 512 x 512), and past 512 x 1024 the
-    gain is under 5% while the float32 score tile's temporaries grow
-    with the area and the causal diagonal wastes more of it. So 512
-    queries against 1,024 keys at heads up to 128, against 512 over
-    that (a head's K/V tile doubles with ``d``); a shorter sequence is
-    one block, rounded up to the sublane tile."""
+    of size ``d`` under a sliding ``window`` or none, from the three
+    alone. Read on a v5e (PERF.md section 6, PRs 39 and 50;
+    ``tools/attention_bench.py blocks``): a grid step costs the same
+    whatever its tile, so small tiles are bound by the grid (128 x 128
+    is five times slower than 512 x 512), and past 512 x 1024 the gain
+    is under 5% while the float32 score tile's temporaries grow with
+    the area and the causal diagonal wastes more of it. So 512 queries
+    against 1,024 keys at heads up to 128, against 512 over that (a
+    head's K/V tile doubles with ``d``) and under a window: a band of
+    1,024 keys is three tiles of 512 a q-block and two of 1,024, 25%
+    fewer entries for half as many steps again, and 512 x 512 read 8%
+    (window 1,024 at T 8,192) and 15% (512 at 4,096) under 512 x 1,024,
+    256 x 256 and 1,024 x 1,024 over both. A shorter sequence is one
+    block, rounded up to the sublane tile."""
     block_q = min(512, _round_up(t, 8))
-    block_k = min(1024 if d <= 128 else 512, _round_up(t, 8))
+    block_k = min(1024 if d <= 128 and window is None else 512,
+                  _round_up(t, 8))
     return block_q, block_k
 
 
@@ -184,13 +195,73 @@ def block_mask_live_blocks(t: int, b: int, block_q: int,
     return int(live(qi, kj).sum())
 
 
+def _window_rule(xp, t: int, w: int, block_q: int, block_k: int):
+    """The sliding window over ``t`` positions: query i sees the keys j
+    with ``0 <= i - j < w``, ``models.transformer.window_attention``'s
+    mask. A q-block's keys are ONE run of k-blocks and a k-block's
+    queries one run of q-blocks, each from the block's first position
+    to its last real one, so the answers are: ``mask(qpos, kpos)``
+    elementwise; ``live(qi, kj)``, ``whole(qi, kj)`` a tile (whole:
+    every entry of its real rows is kept); ``k_band(qi)``,
+    ``q_band(kj)`` the (first, last) block of the run, which is what a
+    sweep covers. Over ``xp`` as :func:`_block_rule`."""
+    def k_band(qi):
+        q0, q1 = qi * block_q, xp.minimum((qi + 1) * block_q, t) - 1
+        return xp.maximum(q0 - w + 1, 0) // block_k, q1 // block_k
+
+    def q_band(kj):
+        k0, k1 = kj * block_k, xp.minimum((kj + 1) * block_k, t) - 1
+        return k0 // block_q, xp.minimum(k1 + w - 1, t - 1) // block_q
+
+    def mask(qpos, kpos):
+        return (kpos < t) & (qpos >= kpos) & (qpos - kpos < w)
+
+    def live(qi, kj):
+        # a sweep's last steps may stand past the last q-block
+        first, last = k_band(qi)
+        return (qi * block_q < t) & (first <= kj) & (kj <= last)
+
+    def whole(qi, kj):
+        q0, q1 = qi * block_q, xp.minimum((qi + 1) * block_q, t) - 1
+        k0, k1 = kj * block_k, (kj + 1) * block_k - 1
+        return (k1 < t) & (k1 <= q0) & (q1 - k0 < w)
+
+    return mask, live, whole, k_band, q_band
+
+
+def _window_sweeps(t: int, w: int, block_q: int, block_k: int):
+    """(k-blocks a q-block's sweep covers, q-blocks a k-block's) under
+    the window: the longest run of :func:`_window_rule`, the same for
+    every block but those the sequence's ends cut short."""
+    import numpy as np
+
+    k_band, q_band = _window_rule(np, t, w, block_q, block_k)[3:]
+    first, last = k_band(np.arange(-(-t // block_q)))
+    k_steps = int((last - first).max()) + 1
+    first, last = q_band(np.arange(-(-t // block_k)))
+    return k_steps, int((last - first).max()) + 1
+
+
+def window_live_blocks(t: int, w: int, block_q: int, block_k: int) -> int:
+    """(q-block, k-block) pairs of a [t, t] product under the sliding
+    window ``w`` (:func:`_window_rule`) that hold an entry the mask
+    keeps: the tiles the kernels compute."""
+    import numpy as np
+
+    block_q, block_k = (min(x, _round_up(t, 8)) for x in (block_q, block_k))
+    first, last = _window_rule(np, t, w, block_q, block_k)[3](
+        np.arange(-(-t // block_q)))
+    return int((last - first + 1).sum())
+
+
 @functools.lru_cache(maxsize=None)
 def _kernels(Tq: int, Tk: int, D: int, Dv: int, block_q: int, block_k: int,
              rule, q_len: int, kv_len: int, group: int, interpret: bool):
     """Build (fwd, bwd_dq, bwd_dkv) pallas_calls for one static shape
-    and one static mask, ``rule``: ``True`` causal, ``False`` none, or
+    and one static mask, ``rule``: ``True`` causal, ``False`` none,
     ``(t, b)``, the block mask of :func:`_block_rule` over ``q_len ==
-    kv_len == 2 * t`` positions.
+    kv_len == 2 * t`` positions, or ``("window", w)``, the sliding
+    window of :func:`_window_rule` over ``q_len == kv_len`` positions.
 
     All three work on ``[B, H, T, D]``-transposed arrays (``v``, ``o``
     and their cotangents ``[B, H, T, Dv]``: a value head has its own
@@ -200,8 +271,11 @@ def _kernels(Tq: int, Tk: int, D: int, Dv: int, block_q: int, block_k: int,
     inner-block) with the inner dimension iterated sequentially
     on-core, accumulating into VMEM scratch (dK/dV: a key/value head's
     ``group`` query heads are one more inner dimension, summed in the
-    same scratch). ``q_len`` <= Tq and ``kv_len`` <= Tk are the true
-    (unpadded) lengths; keys past ``kv_len`` are masked out.
+    same scratch). Under the window the inner dimension counts the
+    steps of a block's band (:func:`_window_sweeps`) and a step's tile
+    is the band's first plus the step. ``q_len`` <= Tq and ``kv_len``
+    <= Tk are the true (unpadded) lengths; keys past ``kv_len`` are
+    masked out.
     """
     import jax
     import jax.numpy as jnp
@@ -218,10 +292,18 @@ def _kernels(Tq: int, Tk: int, D: int, Dv: int, block_q: int, block_k: int,
     # positions of the key sequence (kv-cache decode), so q row i sits at
     # absolute position i + (kv_len - q_len)
     causal_offset = kv_len - q_len
-    block = not isinstance(rule, bool)
+    window = not isinstance(rule, bool) and rule[0] == "window"
+    block = not isinstance(rule, bool) and not window
+    # steps of the inner grid dimension: a q-block's sweep over k-blocks
+    # (forward, dQ), a k-block's over q-blocks (dK/dV)
+    k_steps, q_steps = nk, nq
     if block:
-        (block_mask, block_live, block_whole, block_k_seen,
+        (rule_mask, rule_live, rule_whole, block_k_seen,
          block_q_seen) = _block_rule(jnp, *rule, block_q, block_k)
+    if window:
+        (rule_mask, rule_live, rule_whole, k_band,
+         q_band) = _window_rule(jnp, kv_len, rule[1], block_q, block_k)
+        k_steps, q_steps = _window_sweeps(kv_len, rule[1], block_q, block_k)
 
     def _mask(qi, kj):
         """[block_q, block_k] validity mask for q-block qi, k-block kj."""
@@ -229,8 +311,8 @@ def _kernels(Tq: int, Tk: int, D: int, Dv: int, block_q: int, block_k: int,
             jnp.int32, (block_q, block_k), 0)
         kpos = kj * block_k + jax.lax.broadcasted_iota(
             jnp.int32, (block_q, block_k), 1)
-        if block:
-            return block_mask(qpos, kpos)
+        if block or window:
+            return rule_mask(qpos, kpos)
         m = kpos < kv_len
         if causal:
             m = m & (qpos + causal_offset >= kpos)
@@ -238,16 +320,16 @@ def _kernels(Tq: int, Tk: int, D: int, Dv: int, block_q: int, block_k: int,
 
     def _live(qi, kj):
         """Does (q-block qi, k-block kj) contribute at all?"""
-        if block:
-            return block_live(qi, kj)
+        if block or window:
+            return rule_live(qi, kj)
         if not causal:
             return True
         return kj * block_k < (qi + 1) * block_q + causal_offset
 
     def _whole(qi, kj):
         """Is every entry of the block valid (no mask to apply)?"""
-        if block:
-            return block_whole(qi, kj)
+        if block or window:
+            return rule_whole(qi, kj)
         inside = (kj + 1) * block_k <= kv_len
         if not causal:
             return inside
@@ -268,9 +350,21 @@ def _kernels(Tq: int, Tk: int, D: int, Dv: int, block_q: int, block_k: int,
         def _():
             body(None)
 
+    def _k_at(qi, j):
+        """The k-block at step ``j`` of q-block ``qi``'s sweep."""
+        return k_band(qi)[0] + j if window else j
+
+    def _q_at(i, kj):
+        """The q-block at step ``i`` of k-block ``kj``'s sweep."""
+        return q_band(kj)[0] + i if window else i
+
     # a dead block's tile takes the index of the live one beside it, so
-    # the pipeline sees no new block and issues no DMA for it
+    # the pipeline sees no new block and issues no DMA for it (the
+    # window's sweeps take a step, the others the block itself)
     def _k_seen(qi, kj):
+        if window:
+            first, last = k_band(qi)
+            return jnp.minimum(first + kj, last)
         if block:
             return jnp.clip(block_k_seen(qi, kj), 0, nk - 1)
         if not causal:
@@ -279,6 +373,9 @@ def _kernels(Tq: int, Tk: int, D: int, Dv: int, block_q: int, block_k: int,
         return jnp.minimum(kj, jnp.clip(last, 0, nk - 1))
 
     def _q_seen(qi, kj):
+        if window:
+            first, last = q_band(kj)
+            return jnp.minimum(first + qi, last)
         if block:
             return jnp.clip(block_q_seen(qi, kj), 0, nq - 1)
         if not causal:
@@ -294,14 +391,16 @@ def _kernels(Tq: int, Tk: int, D: int, Dv: int, block_q: int, block_k: int,
             vmem_limit_bytes=64 * 1024 * 1024))
 
     # -- forward ---------------------------------------------------------
-    # grid (B, H, nq, nk): k-blocks innermost; acc/m/l scratch persists
-    # across the k sweep for one q-block, finalized at the last k step.
+    # grid (B, H, nq, k_steps): k-blocks innermost; acc/m/l scratch
+    # persists across the k sweep for one q-block, finalized at the last
+    # k step.
 
     def fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
                    acc_ref, m_ref, l_ref):
-        qi, kj = pl.program_id(2), pl.program_id(3)
+        qi, step = pl.program_id(2), pl.program_id(3)
+        kj = _k_at(qi, step)
 
-        @pl.when(kj == 0)
+        @pl.when(step == 0)
         def _():
             acc_ref[:] = jnp.zeros_like(acc_ref)
             m_ref[:] = jnp.full_like(m_ref, neg_inf)
@@ -331,7 +430,7 @@ def _kernels(Tq: int, Tk: int, D: int, Dv: int, block_q: int, block_k: int,
 
         _on_live_block(qi, kj, body)
 
-        @pl.when(kj == nk - 1)
+        @pl.when(step == k_steps - 1)
         def _():
             l = l_ref[:]
             # rows with no valid key (padding) have l == 0; emit zeros
@@ -349,7 +448,7 @@ def _kernels(Tq: int, Tk: int, D: int, Dv: int, block_q: int, block_k: int,
             for d in (D, Dv))
         return pl.pallas_call(
             fwd_kernel,
-            grid=(B, H, nq, nk),
+            grid=(B, H, nq, k_steps),
             in_specs=[qspec, kspec, vspec],
             out_specs=[
                 pl.BlockSpec((1, 1, block_q, Dv),
@@ -388,9 +487,10 @@ def _kernels(Tq: int, Tk: int, D: int, Dv: int, block_q: int, block_k: int,
 
     def dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                   dq_ref, acc_ref):
-        qi, kj = pl.program_id(2), pl.program_id(3)
+        qi, step = pl.program_id(2), pl.program_id(3)
+        kj = _k_at(qi, step)
 
-        @pl.when(kj == 0)
+        @pl.when(step == 0)
         def _():
             acc_ref[:] = jnp.zeros_like(acc_ref)
 
@@ -404,7 +504,7 @@ def _kernels(Tq: int, Tk: int, D: int, Dv: int, block_q: int, block_k: int,
 
         _on_live_block(qi, kj, body)
 
-        @pl.when(kj == nk - 1)
+        @pl.when(step == k_steps - 1)
         def _():
             dq_ref[0, 0] = acc_ref[:].astype(dq_ref.dtype)
 
@@ -419,7 +519,7 @@ def _kernels(Tq: int, Tk: int, D: int, Dv: int, block_q: int, block_k: int,
             for d in (D, Dv))
         return pl.pallas_call(
             dq_kernel,
-            grid=(B, H, nq, nk),
+            grid=(B, H, nq, k_steps),
             in_specs=[qspec, kspec, vspec, dospec, rowspec, rowspec],
             out_specs=qspec,
             out_shape=jax.ShapeDtypeStruct((B, H, Tq, D), q.dtype),
@@ -432,9 +532,10 @@ def _kernels(Tq: int, Tk: int, D: int, Dv: int, block_q: int, block_k: int,
 
     def dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                    dk_ref, dv_ref, dk_acc, dv_acc):
-        kj, g, qi = pl.program_id(2), pl.program_id(3), pl.program_id(4)
+        kj, g, step = pl.program_id(2), pl.program_id(3), pl.program_id(4)
+        qi = _q_at(step, kj)
 
-        @pl.when((g == 0) & (qi == 0))
+        @pl.when((g == 0) & (step == 0))
         def _():
             dk_acc[:] = jnp.zeros_like(dk_acc)
             dv_acc[:] = jnp.zeros_like(dv_acc)
@@ -453,7 +554,7 @@ def _kernels(Tq: int, Tk: int, D: int, Dv: int, block_q: int, block_k: int,
 
         _on_live_block(qi, kj, body)
 
-        @pl.when((g == group - 1) & (qi == nq - 1))
+        @pl.when((g == group - 1) & (step == q_steps - 1))
         def _():
             dk_ref[0, 0] = dk_acc[:].astype(dk_ref.dtype)
             dv_ref[0, 0] = dv_acc[:].astype(dv_ref.dtype)
@@ -469,7 +570,7 @@ def _kernels(Tq: int, Tk: int, D: int, Dv: int, block_q: int, block_k: int,
                         for d in (D, Dv))
         return pl.pallas_call(
             dkv_kernel,
-            grid=(B, KV, nk, group, nq),
+            grid=(B, KV, nk, group, q_steps),
             in_specs=[qspec, kspec, vspec, dospec, rowspec, rowspec],
             out_specs=[kspec, vspec],
             out_shape=[jax.ShapeDtypeStruct((B, KV, Tk, D), k.dtype),
@@ -479,11 +580,17 @@ def _kernels(Tq: int, Tk: int, D: int, Dv: int, block_q: int, block_k: int,
             interpret=interpret, **_params(2),
         )(q, k, v, do, lse, delta)
 
+    if window:
+        # every layer of a model calls these at one shape: under a jit
+        # of their own a program traces and lowers each kernel once and
+        # calls it, where a bare pallas_call is traced and lowered anew
+        # at every call (set-up time: PERF.md section 6, PR 50)
+        return jax.jit(fwd), jax.jit(bwd_dq), jax.jit(bwd_dkv)
     return fwd, bwd_dq, bwd_dkv
 
 
 def flash_attention(q, k, v, *, causal: bool = True, block_mask=None,
-                    block_q=None, block_k=None):
+                    window=None, block_q=None, block_k=None):
     """Memory-efficient exact attention; drop-in for ``dense_attention``
     and, with grouped queries, for ``grouped_attention``.
 
@@ -500,9 +607,11 @@ def flash_attention(q, k, v, *, causal: bool = True, block_mask=None,
     b)`` in place of ``causal``: the sequence axis holds the clean and
     the noised copy of ``t`` positions, ``2 * t`` queries and keys,
     under the block mask of block-diffusion training in blocks of ``b``
-    (:func:`_block_rule`). Scores are scaled by
-    ``1/sqrt(D)``. ``block_q`` /
-    ``block_k``: :func:`attention_blocks` unless given. Differentiable
+    (:func:`_block_rule`). ``window`` ``w`` in place of both: query i
+    sees the keys j with ``0 <= i - j < w`` (:func:`_window_rule`), as
+    many queries as keys. Scores are scaled by ``1/sqrt(D)``.
+    ``block_q`` / ``block_k``: :func:`attention_blocks` unless given.
+    Differentiable
     via a custom VJP whose backward runs as Pallas kernels
     (probabilities recomputed from the saved logsumexp — no quadratic
     residual).
@@ -521,7 +630,7 @@ def flash_attention(q, k, v, *, causal: bool = True, block_mask=None,
         b, t, kv, g, d = q.shape
         return flash_attention(
             q.reshape(b, t, kv * g, d), k, v, causal=causal,
-            block_mask=block_mask, block_q=block_q,
+            block_mask=block_mask, window=window, block_q=block_q,
             block_k=block_k).reshape(
                 b, t, kv, g, v.shape[-1])
     if q.ndim != 4:
@@ -537,6 +646,11 @@ def flash_attention(q, k, v, *, causal: bool = True, block_mask=None,
             raise ValueError(
                 f"the block mask {block_mask} is over {2 * block_mask[0]} "
                 f"queries and keys, got {Tq} and {Tk}")
+    elif window is not None:
+        if Tq != Tk or window < 1:
+            raise ValueError(
+                f"a window is one key or more over as many queries as "
+                f"keys, got {window} over {Tq} and {Tk}")
     elif causal and Tq > Tk:
         # no decode-convention alignment exists for more queries than
         # keys; without this check, q rows with zero visible keys would
@@ -544,10 +658,13 @@ def flash_attention(q, k, v, *, causal: bool = True, block_mask=None,
         raise ValueError(
             f"causal attention needs Tq <= Tk, got Tq={Tq} > Tk={Tk}")
     D = q.shape[3]
-    bq = min(block_q or attention_blocks(Tq, D)[0], _round_up(Tq, 8))
-    bk = min(block_k or attention_blocks(Tk, D)[1], _round_up(Tk, 8))
+    bq = min(block_q or attention_blocks(Tq, D, window)[0], _round_up(Tq, 8))
+    bk = min(block_k or attention_blocks(Tk, D, window)[1], _round_up(Tk, 8))
     Tqp, Tkp = _round_up(Tq, bq), _round_up(Tk, bk)
-    rule = causal if block_mask is None else tuple(block_mask)
+    if block_mask is not None:
+        rule = tuple(block_mask)
+    else:
+        rule = causal if window is None else ("window", int(window))
     fwd, bwd_dq, bwd_dkv = _kernels(Tqp, Tkp, D, v.shape[3], bq, bk, rule,
                                     Tq, Tk, group, pallas_interpret())
 

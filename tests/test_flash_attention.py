@@ -346,7 +346,21 @@ def test_computed_entries_are_the_live_blocks(t, block_q, block_k):
 
 def test_kernel_score_entries_at_the_cells_shapes():
     from geomx_tpu.models.transformer import (kernel_score_entries,
+                                              kernel_window_score_entries,
                                               score_entries)
+    from geomx_tpu.ops.flash_attention import attention_blocks
+
+    # the sliding layers run 512 x 512 whatever the head: Mellum2's 45
+    # live tiles of 256 (window 1,024 at 8,192), Laguna's 15 of 64
+    # (512 at 4,096)
+    assert attention_blocks(8192, 128, 1024) == (512, 512)
+    assert attention_blocks(4096, 128, 512) == (512, 512)
+    assert attention_blocks(37, 128, 8) == (40, 40)
+    assert kernel_window_score_entries(8192, 1024, 128) == 45 * 512 * 512
+    assert kernel_window_score_entries(4096, 512, 128) == 15 * 512 * 512
+    for t, window in ((8192, 1024), (4096, 512)):
+        live, blocked = score_entries(t, window)
+        assert live < kernel_window_score_entries(t, window, 128) < blocked
 
     # 20 live tiles of 32 at 512 x 1,024; 36 of 64 at 512 x 512
     assert kernel_score_entries(4096, 128) == 20 * 512 * 1024 == 10_485_760
@@ -389,9 +403,9 @@ def test_the_block_rule_against_the_mask_written_out(t, block, block_q,
         np, t, block, block_q, block_k)
     want = np.zeros((nq * block_q, nk * block_k), bool)
     want[:n, :n] = block_diffusion_mask(t, block)
-    rows, cols = np.meshgrid(np.arange(nq * block_q),
-                             np.arange(nk * block_k), indexing="ij")
-    np.testing.assert_array_equal(mask(rows, cols)[:n], want[:n])
+    rows, cols = (np.arange(m * b, dtype=np.int32)
+                  for m, b in ((nq, block_q), (nk, block_k)))
+    np.testing.assert_array_equal(mask(rows[:n, None], cols[None]), want[:n])
     tiles = want.reshape(nq, block_q, nk, block_k)
     qi, kj = np.meshgrid(np.arange(nq), np.arange(nk), indexing="ij")
     alive = tiles.any(axis=(1, 3))
@@ -480,3 +494,176 @@ def test_the_kernel_form_of_block_diffusion_attention(t, monkeypatch):
     text = str(jax.make_jaxpr(
         lambda q: transformer.block_diffusion_attention(q, k, v, 4))(q))
     assert "pallas_call" in text and "remat" not in text
+
+
+# -- the sliding window -------------------------------------------------------
+
+# (t, window, block_q, block_k, group): T no multiple of the tiles, a
+# window over the whole sequence, one smaller than a tile, one no
+# multiple of a tile, unequal tiles both ways, eight queries on one
+# key/value head, one tile for everything
+WINDOW_SHAPES = [
+    (64, 16, 16, 16, 2), (70, 16, 16, 32, 2), (70, 24, 32, 16, 1),
+    (50, 100, 16, 16, 2), (50, 50, 16, 8, 1), (50, 5, 16, 16, 8),
+    (37, 11, 8, 16, 2), (100, 33, 32, 16, 2), (100, 33, 16, 32, 8),
+    (64, 1, 16, 16, 1), (40, 16, 64, 64, 2)]
+
+
+def _window_mask(t, window):
+    at = np.arange(t, dtype=np.int32)
+    behind = at[:, None] - at[None]
+    return (behind >= 0) & (behind < window)
+
+
+@pytest.mark.parametrize("t,window,block_q,block_k,group", WINDOW_SHAPES)
+def test_window_kernels_match_the_masked_product(t, window, block_q,
+                                                 block_k, group):
+    """Forward, dQ, dK and dV of the kernels under the window rule
+    (interpreted) against the dense product under the mask written out,
+    and the blocked product ``window_attention`` against the same."""
+    from geomx_tpu.models.transformer import window_attention
+
+    q = _rand((1, t, 1, group, 16), 0)
+    k, v = _rand((1, t, 1, 16), 1), _rand((1, t, 1, 16), 2)
+    cot = _rand(q.shape, 3)
+
+    def masked(q, k, v):
+        s = jnp.einsum("bqkgd,bjkd->bkgqj", q, k) / 4.0
+        p = jax.nn.softmax(jnp.where(_window_mask(t, window), s, -1e30), -1)
+        return jnp.einsum("bkgqj,bjkd->bqkgd", p, v)
+
+    def kernel(q, k, v):
+        return flash_attention(q, k, v, window=window, block_q=block_q,
+                               block_k=block_k)
+
+    out, back = jax.vjp(kernel, q, k, v)
+    want, want_back = jax.vjp(masked, q, k, v)
+    np.testing.assert_allclose(out, want, atol=2e-5, rtol=2e-5)
+    for name, a, b in zip("qkv", back(cot), want_back(cot)):
+        np.testing.assert_allclose(a, b, atol=2e-4, rtol=2e-4,
+                                   err_msg=f"d{name}")
+    np.testing.assert_allclose(
+        window_attention(q, k, v, window, scores_dtype=jnp.float32), want,
+        atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.parametrize("t,window,block_q,block_k", [
+    s[:4] for s in WINDOW_SHAPES] + [
+    (8192, 1024, 512, 1024), (8192, 1024, 512, 512), (4096, 512, 256, 256),
+    (4096, 512, 1024, 512), (1000, 300, 128, 256)])
+def test_the_window_rule_against_the_mask_written_out(t, window, block_q,
+                                                      block_k):
+    """The kernels' questions about the window (``_window_rule`` over
+    numpy) against the [T, T] mask itself: the elementwise mask; a tile
+    is live where it holds a kept entry and whole where every entry of
+    its real rows is kept; a block's band is the run of its live tiles,
+    its sweep as long as the longest; ``window_live_blocks`` counts the
+    live tiles."""
+    from geomx_tpu.ops.flash_attention import (_round_up, _window_rule,
+                                               _window_sweeps,
+                                               window_live_blocks)
+
+    block_q, block_k = (min(b, _round_up(t, 8)) for b in (block_q, block_k))
+    nq, nk = -(-t // block_q), -(-t // block_k)
+    mask, live, whole, k_band, q_band = _window_rule(
+        np, t, window, block_q, block_k)
+    want = np.zeros((nq * block_q, nk * block_k), bool)
+    want[:t, :t] = _window_mask(t, window)
+    rows, cols = (np.arange(n * b, dtype=np.int32)
+                  for n, b in ((nq, block_q), (nk, block_k)))
+    np.testing.assert_array_equal(mask(rows[:t, None], cols[None]), want[:t])
+    tiles = want.reshape(nq, block_q, nk, block_k)
+    # a sweep's last steps may stand one past the sequence: dead
+    qi, kj = np.meshgrid(np.arange(nq + 1), np.arange(nk + 1), indexing="ij")
+    alive = np.zeros((nq + 1, nk + 1), bool)
+    alive[:nq, :nk] = tiles.any(axis=(1, 3))
+    np.testing.assert_array_equal(live(qi, kj), alive)
+    assert window_live_blocks(t, window, block_q, block_k) == alive.sum()
+    real = np.arange(nq * block_q).reshape(nq, block_q) < t
+    np.testing.assert_array_equal(
+        whole(qi[:nq, :nk], kj[:nq, :nk]),
+        (tiles | ~real[:, :, None, None]).all(axis=(1, 3)))
+    alive = alive[:nq, :nk]
+    for band, along in ((k_band(np.arange(nq)), alive),
+                        (q_band(np.arange(nk)), alive.T)):
+        first, last = band
+        np.testing.assert_array_equal(first, along.argmax(axis=1))
+        np.testing.assert_array_equal(last - first + 1, along.sum(axis=1))
+    assert _window_sweeps(t, window, block_q, block_k) == (
+        alive.sum(axis=1).max(), alive.sum(axis=0).max())
+
+
+def test_a_window_is_over_as_many_queries_as_keys():
+    q = _rand((1, 16, 2, 8), 0)
+    k = v = _rand((1, 24, 2, 8), 1)
+    with pytest.raises(ValueError, match="as many queries as keys"):
+        flash_attention(q, k, v, window=4)
+    with pytest.raises(ValueError, match="as many queries as keys"):
+        flash_attention(k, k, v, window=0)
+
+
+@pytest.mark.parametrize("rule,text_hash", [
+    (dict(causal=True), "2aaf6464b2fc8223"),
+    (dict(block_mask=(20, 4)), "b2de923e49b6612e"),
+    (dict(causal=False), "928a731488cd99ee")],
+    ids=["causal", "block_mask", "none"])
+def test_the_other_rules_lower_to_the_text_they_had(rule, text_hash):
+    """The window is a third branch beside rules that do not change:
+    a causal, a block-mask and an unmasked call (forward and backward,
+    interpreted) lower to the text they had before the window rule
+    came (PR 50; the hashes are of the parent's text under this JAX)."""
+    import hashlib
+    from functools import partial
+
+    q = jax.ShapeDtypeStruct((1, 40, 1, 2, 16), jnp.float32)
+    k = v = jax.ShapeDtypeStruct((1, 40, 1, 16), jnp.float32)
+
+    def both(q, k, v):
+        out, back = jax.vjp(partial(flash_attention, block_q=16, block_k=8,
+                                    **rule), q, k, v)
+        return out, back(out)
+
+    text = jax.jit(both).lower(q, k, v).as_text()
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] == text_hash
+
+
+@pytest.mark.parametrize("case", ["cpu", "tpu", "forced", "short", "mesh",
+                                  "narrow"])
+def test_the_form_of_a_window_core(case, monkeypatch):
+    """``window_core`` asks THE rule: the blocked product, computed
+    again on the way back, wherever Pallas is interpreted, under a mesh,
+    under ``KERNEL_MIN_T`` and under ``KERNEL_MIN_WINDOW``; the kernels
+    with their window rule, not rematerialised, where the rule says they
+    run."""
+    from functools import partial
+
+    import geomx_tpu.ops
+    from geomx_tpu.models import transformer
+
+    t = 64 if case == "short" else transformer.KERNEL_MIN_T
+    window = transformer.KERNEL_MIN_WINDOW - (
+        8 if case in ("narrow", "forced") else 0)
+    q = jax.ShapeDtypeStruct((1, t, 1, 2, 8), jnp.float32)
+    k = v = jax.ShapeDtypeStruct((1, t, 1, 8), jnp.float32)
+    inv_freq, factor = transformer.rotary_frequencies(
+        {"rope_type": "default", "rope_theta": 10000.0}, 8)
+    if case == "forced":
+        monkeypatch.setattr(transformer, "runs_kernel",
+                            partial(transformer.runs_kernel, forced=True))
+    elif case != "cpu":
+        # the rule's answer on a TPU backend; a jaxpr lowers nothing
+        monkeypatch.setattr(geomx_tpu.ops, "pallas_interpret", lambda: False)
+
+    def text():
+        return str(jax.make_jaxpr(
+            lambda q, k, v: transformer.rotary_attention(
+                q, k, v, inv_freq, factor, window=window))(q, k, v))
+
+    if case == "mesh":
+        with jax.set_mesh(jax.make_mesh((2, 2), ("dp", "tp"))):
+            got = text()
+    else:
+        got = text()
+    kernel = case in ("tpu", "forced")
+    assert ("pallas_call" in got) == kernel
+    assert ("remat" in got or "checkpoint" in got) == (not kernel)

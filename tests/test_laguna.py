@@ -388,17 +388,28 @@ def test_a_full_block_on_the_kernel_is_the_dense_block(monkeypatch):
         model.counts(1, SEQ), model.counts(1, SEQ, True))
     assert live_k == live
     assert on_kernel - dense == 2 * 6 * (40 * 40 - SEQ * SEQ)
+    # from the rule's floor on the window the sliding layers count their
+    # band's live tiles: at 1,100 positions and a window of 512 a head's
+    # 1 + 2 + 2 tiles of 512 x 512 for 3 blocks of 512 x 1,024, beside
+    # a full head's 1 + 1 + 2 tiles of 512 x 1,024 for 1100 x 1100
+    wide = bench_model.model_of(dict(TINY, sliding_window=512))
+    (_r, live, dense), (_r, live_k, on_kernel) = (
+        wide.counts(1, 1100), wide.counts(1, 1100, True))
+    assert live_k == live == 2 * 6 * 605_550 + 3 * 8 * 432_384
+    assert dense == 2 * 6 * 1100 * 1100 + 3 * 8 * 3 * 512 * 1024
+    assert on_kernel == 2 * 6 * 4 * 512 * 1024 + 3 * 8 * 5 * 512 * 512
 
 
-@pytest.mark.parametrize("core", ["window", "dense", "kernel"])
+@pytest.mark.parametrize("core", ["window", "dense", "kernel",
+                                  "window_kernel"])
 def test_the_checkpoint_is_where_something_quadratic_is_kept(core,
                                                              monkeypatch):
     """``gated_attention`` computes a dense core again on the way back
-    (window and full alike) and keeps the kernel's own residuals: read
-    off the jaxpr."""
+    (window and full alike) and keeps the kernels' own residuals (window
+    and full alike): read off the jaxpr."""
     from geomx_tpu.models.transformer import gated_attention
 
-    if core == "kernel":
+    if core.endswith("kernel"):
         _on_the_kernel(monkeypatch)
     q = jnp.zeros((1, 24, 1, 2, 8), jnp.float32)
     k = v = jnp.zeros((1, 24, 1, 8), jnp.float32)
@@ -406,9 +417,9 @@ def test_the_checkpoint_is_where_something_quadratic_is_kept(core,
     inv_freq, factor = rotary_frequencies(ROPE["sliding_attention"], 8)
     text = str(jax.make_jaxpr(lambda q, k, v: gated_attention(
         q, k, v, gate, inv_freq, factor,
-        window=8 if core == "window" else None))(q, k, v))
-    assert ("remat" in text) == (core != "kernel")
-    assert ("pallas_call" in text) == (core == "kernel")
+        window=8 if core.startswith("window") else None))(q, k, v))
+    assert ("remat" in text) == (not core.endswith("kernel"))
+    assert ("pallas_call" in text) == core.endswith("kernel")
 
 
 def test_four_expert_shares_and_one_shared_expert_sum_to_the_layer():

@@ -332,6 +332,70 @@ def test_the_ungated_branch_is_gated_attention_at_an_open_gate(window):
         q, k, v, inv_freq, factor, window))(q))
 
 
+@pytest.mark.parametrize("kind", [SLIDING, FULL])
+def test_a_block_on_the_kernels_is_the_block_off_them(kind, monkeypatch):
+    """A sliding and the full layer with the core as the Pallas kernels
+    (the rule forced, the kernels interpreted: the window rule at a
+    window of 8 over 21 positions) against the same block on the
+    blocked / the dense product: the block's output and every
+    parameter's gradient; the kernel form is not rematerialised."""
+    from functools import partial
+
+    from geomx_tpu.models import transformer
+
+    flat = _layer_params(dict(TINY, hidden_size=32, head_dim=8,
+                              num_experts=8, layer_types=[kind],
+                              num_hidden_layers=1, query_heads=[0, 8],
+                              key_value_heads=[0, 2], local_experts=[0, 8]))
+    x = jnp.asarray(np.random.default_rng(4).normal(size=(2, 21, 32)),
+                    jnp.float32)
+    block = _block(kind, (0, 8), (0, 2), (0, 8))
+
+    def loss(variables):
+        out = block.apply(variables, x)[0]
+        return jnp.sum(jnp.sin(out)), out
+
+    (_l, want), grads_want = jax.value_and_grad(loss, has_aux=True)(
+        _tree(flat))
+    monkeypatch.setattr(transformer, "runs_kernel", partial(
+        transformer.runs_kernel, forced=True))
+    (_l, got), grads_got = jax.value_and_grad(loss, has_aux=True)(
+        _tree(flat))
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-6)
+    for a, b in zip(jax.tree_util.tree_leaves(grads_got),
+                    jax.tree_util.tree_leaves(grads_want)):
+        np.testing.assert_allclose(a, b, rtol=2e-4, atol=2e-5)
+    text = str(jax.make_jaxpr(lambda v: block.apply(v, x))(_tree(flat)))
+    assert "pallas_call" in text and "remat" not in text
+
+
+def test_counts_on_the_kernels_against_a_count_by_hand():
+    """``counts(1, t, True)``: the full layer's live causal tiles and,
+    from the rule's floor on the window, the sliding layers' live tiles
+    of the band; ``counts(1, t)`` is what it was, and under the floor
+    the sliding layers stay on the blocked product."""
+    from geomx_tpu.models.transformer import KERNEL_MIN_WINDOW
+
+    t = 1100
+    model = bench_model.model_of(dict(TINY, sliding_window=512))
+    assert KERNEL_MIN_WINDOW <= 512
+    # a sliding head keeps 512 * 513 / 2 + 588 * 512 entries; the
+    # blocked product has 3 blocks of 512 x 1,024, the kernels 1 + 2 + 2
+    # tiles of 512 x 512 (q-blocks of 512, 512 and 76 rows); a full head
+    # keeps 1100 * 1101 / 2 of 1100 * 1100, the kernels 1 + 1 + 2 tiles
+    # of 512 x 1,024
+    rows, live, computed = model.counts(1, t)
+    assert live == 8 * (3 * 432_384 + 605_550)
+    assert computed == 8 * (3 * 3 * 512 * 1024 + 1100 * 1100)
+    assert model.counts(1, t, True) == (
+        rows, live, 8 * (3 * 5 * 512 * 512 + 4 * 512 * 1024))
+    # the tiny model's window of 8 is under the floor
+    tiny = bench_model.model_of(TINY)
+    assert tiny.counts(1, SEQ, True)[2] - tiny.counts(1, SEQ)[2] \
+        == 8 * (40 * 40 - SEQ * SEQ)
+    assert model.counts(2, t, True)[2] == 2 * model.counts(1, t, True)[2]
+
+
 # -- one round through the system ---------------------------------------------
 
 @pytest.mark.time_limit(300)

@@ -1,15 +1,18 @@
 #!/usr/bin/env python
-"""Full causal attention on the local accelerator: the dense path of
-``models/transformer.py`` against the Pallas kernels of
+"""Full causal and sliding-window attention on the local accelerator:
+the dense path of ``models/transformer.py`` (the blocked product of
+``window_attention`` for a window) against the Pallas kernels of
 ``ops/flash_attention.py``, forward + backward of one pass, at the
-shapes the benchmark's cells run (PERF.md section 6, PR 39).
+shapes the benchmark's cells run (PERF.md section 6, PRs 39 and 50).
 
-A JSON line a reading: ``shapes`` (the four callers as they call: who
+A JSON line a reading: ``shapes`` (the callers as they call: who
 keeps the scores and who recomputes the core on the way back),
 ``blocks`` (the kernel over candidate ``block_q`` x ``block_k``: what
 ``ops.flash_attention.attention_blocks`` was read from), ``lengths``
 (both forms over T: what ``models.transformer.KERNEL_MIN_T`` was read
-from), ``splash`` (JAX's own splash attention at the same shapes). TPU
+from), ``windows`` (both forms of the window shapes over the window:
+whether the rule needs a floor on it), ``splash`` (JAX's own splash
+attention at the same shapes). TPU
 only: off the chip the kernels are interpreted (correctness only,
 ``tests/test_flash_attention.py``), so the tool exits nonzero there.
 
@@ -29,15 +32,20 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 
 # name: (q shape, k/v shape, float32 scores, core recomputed on the way
-# back); GPT-2's batch is the 8 sequences its cells pass at once
+# back, window); GPT-2's batch is the 8 sequences its cells pass at once
 SHAPES = {
-    "olmoe": ((1, 4096, 16, 128), (1, 4096, 16, 128), True, False),
-    "laguna": ((1, 4096, 1, 6, 128), (1, 4096, 1, 128), True, True),
-    "qwen3next": ((1, 4096, 1, 8, 256), (1, 4096, 1, 256), True, True),
-    "gpt2": ((8, 1023, 12, 64), (8, 1023, 12, 64), False, False),
+    "olmoe": ((1, 4096, 16, 128), (1, 4096, 16, 128), True, False, None),
+    "laguna": ((1, 4096, 1, 6, 128), (1, 4096, 1, 128), True, True, None),
+    "qwen3next": ((1, 4096, 1, 8, 256), (1, 4096, 1, 256), True, True,
+                  None),
+    "gpt2": ((8, 1023, 12, 64), (8, 1023, 12, 64), False, False, None),
+    "mellum_window": ((1, 8192, 1, 8, 128), (1, 8192, 1, 128), True, True,
+                      1024),
+    "laguna_window": ((1, 4096, 1, 8, 128), (1, 4096, 1, 128), True, True,
+                      512),
 }
 BLOCKS = ((128, 128), (256, 256), (256, 512), (512, 256), (512, 512),
-          (512, 1024), (1024, 512), (1024, 1024))
+          (512, 1024), (1024, 512), (1024, 1024), (256, 1024), (1024, 256))
 
 
 def _time(fn, q, k, v, iters=20):
@@ -72,24 +80,30 @@ def _grad(attend):
         argnums=(0, 1, 2)))
 
 
-def _dense(q_shape, f32: bool, recomputed: bool):
+def _dense(q_shape, f32: bool, recomputed: bool, window=None):
     import jax
     import jax.numpy as jnp
 
     from geomx_tpu.models.transformer import (dense_attention,
-                                              grouped_attention)
+                                              grouped_attention,
+                                              window_attention)
 
-    core = functools.partial(
-        grouped_attention if len(q_shape) == 5 else dense_attention,
-        scores_dtype=jnp.float32 if f32 else None)
+    scores = jnp.float32 if f32 else None
+    if window is not None:
+        core = functools.partial(window_attention, window=window,
+                                 scores_dtype=scores)
+    else:
+        core = functools.partial(
+            grouped_attention if len(q_shape) == 5 else dense_attention,
+            scores_dtype=scores)
     return jax.checkpoint(core) if recomputed else core
 
 
-def _kernel(block_q=None, block_k=None):
+def _kernel(block_q=None, block_k=None, window=None):
     from geomx_tpu.ops.flash_attention import flash_attention
 
     return functools.partial(flash_attention, block_q=block_q,
-                             block_k=block_k)
+                             block_k=block_k, window=window)
 
 
 def _splash(q_shape, block: int):
@@ -127,7 +141,8 @@ def _splash(q_shape, block: int):
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("what", nargs="+",
-                    choices=["shapes", "blocks", "lengths", "splash"])
+                    choices=["shapes", "blocks", "lengths", "windows",
+                             "splash"])
     ap.add_argument("--only", default=",".join(SHAPES),
                     help="the shapes to read, by name")
     ap.add_argument("--out", default="chiprun_out/attention_bench.jsonl")
@@ -166,32 +181,47 @@ def main():
 
     names = [n for n in args.only.split(",") if n]
     for name in names:
-        q_shape, kv_shape, f32, recomputed = SHAPES[name]
+        q_shape, kv_shape, f32, recomputed, window = SHAPES[name]
         ops = operands(q_shape, kv_shape)
         t, d = q_shape[1], q_shape[-1]
+        blocks = attention_blocks(t, d, window)
         if "shapes" in args.what:
             read({"read": "shapes", "shape": name, "form": "dense",
                   "recomputed": recomputed},
-                 lambda: _dense(q_shape, f32, recomputed), ops)
+                 lambda: _dense(q_shape, f32, recomputed, window), ops)
             read({"read": "shapes", "shape": name, "form": "kernel",
-                  "blocks": attention_blocks(t, d)}, _kernel, ops)
+                  "blocks": blocks}, lambda: _kernel(window=window), ops)
             if recomputed:
                 read({"read": "shapes", "shape": name, "form": "kernel",
-                      "recomputed": True, "blocks": attention_blocks(t, d)},
-                     lambda: jax.checkpoint(_kernel()), ops)
+                      "recomputed": True, "blocks": blocks},
+                     lambda: jax.checkpoint(_kernel(window=window)), ops)
         if "blocks" in args.what:
             for bq, bk in BLOCKS:
                 if bq <= t + 7 and bk <= t + 7:
                     read({"read": "blocks", "shape": name,
                           "blocks": [bq, bk]},
-                         lambda: _kernel(bq, bk), ops)
-        if "splash" in args.what:
+                         lambda: _kernel(bq, bk, window), ops)
+        if "splash" in args.what and window is None:
             for block in (512, 1024):
                 read({"read": "splash", "shape": name, "block": block},
                      lambda: _splash(q_shape, block), ops)
+    if "windows" in args.what:
+        for name in names:
+            q_shape, kv_shape, f32, recomputed, window = SHAPES[name]
+            if window is None:
+                continue
+            ops = operands(q_shape, kv_shape)
+            for w in (128, 256, 512, 1024, 2048):
+                read({"read": "windows", "shape": name, "window": w,
+                      "form": "dense"},
+                     lambda: _dense(q_shape, f32, recomputed, w), ops)
+                read({"read": "windows", "shape": name, "window": w,
+                      "form": "kernel", "blocks": attention_blocks(
+                          q_shape[1], q_shape[-1], w)},
+                     lambda: _kernel(window=w), ops)
     if "lengths" in args.what:
         for name in names:
-            q_shape, kv_shape, f32, recomputed = SHAPES[name]
+            q_shape, kv_shape, f32, recomputed, window = SHAPES[name]
             for t in (512, 1024, 2048, 4096):
                 if t > q_shape[1] + 1:
                     continue
@@ -199,10 +229,11 @@ def main():
                 ops = operands(qs, ks)
                 read({"read": "lengths", "shape": name, "t": t,
                       "form": "dense"},
-                     lambda: _dense(qs, f32, recomputed), ops)
+                     lambda: _dense(qs, f32, recomputed, window), ops)
                 read({"read": "lengths", "shape": name, "t": t,
                       "form": "kernel",
-                      "blocks": attention_blocks(t, qs[-1])}, _kernel, ops)
+                      "blocks": attention_blocks(t, qs[-1], window)},
+                     lambda: _kernel(window=window), ops)
 
 
 if __name__ == "__main__":
