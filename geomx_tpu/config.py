@@ -224,7 +224,9 @@ class Config:
     # metrics registry (geomx_tpu/telemetry.py): labeled counters/gauges/
     # histograms fed by the van, resender, servers and round futures;
     # near-free when off. Snapshots export per round when telemetry_dir
-    # is set, and are pullable over the command channel via kv.metrics()
+    # is set, and are pullable over the command channel via kv.metrics().
+    # Also the one switch of the round account (profiler.py: the round
+    # spans' self time on two clocks, and a slow round's record)
     telemetry: bool = False             # GEOMX_TELEMETRY
     telemetry_dir: str = ""             # GEOMX_TELEMETRY_DIR ("" = no export)
     # crash flight recorder (ps/flightrec.py): always-on bounded ring of

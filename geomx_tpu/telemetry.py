@@ -50,7 +50,7 @@ _LabelKey = Tuple[str, Tuple[Tuple[str, Any], ...]]
 # matrix collectors) pin on it to detect drift; bump it whenever a
 # top-level key is added/removed/renamed or a value shape changes, and
 # update the gate test in tests/test_telemetry.py in the same change.
-SCHEMA_VERSION = 1
+SCHEMA_VERSION = 2
 
 _enabled = False
 _lock = threading.Lock()
@@ -71,9 +71,9 @@ def configure(enabled: Optional[bool] = None,
     """Apply config: ``None`` leaves a setting untouched, so several
     in-process nodes (simulate.InProcessHiPS) can each apply their own
     Config without the last constructor turning the registry back off."""
-    global _enabled, _export_dir
+    global _export_dir
     if enabled is not None:
-        _enabled = enabled
+        enable(enabled)
     if export_dir is not None:
         _export_dir = export_dir
 
@@ -81,6 +81,7 @@ def configure(enabled: Optional[bool] = None,
 def enable(on: bool = True) -> None:
     global _enabled
     _enabled = on
+    profiler.keep_account(on)
 
 
 def enabled() -> bool:
@@ -101,6 +102,16 @@ def counter_inc(name: str, value: float = 1, **labels: Any) -> None:
     k = _key(name, labels)
     with _lock:
         _counters[k] = _counters.get(k, 0) + value
+
+
+def counters_add(rows) -> None:
+    """Add every ``(name, labels, value)`` of ``rows`` under one hold of
+    the lock; ``labels`` as :func:`_key` makes them, sorted pairs. The
+    round account's way in (``profiler.merge_rounds``), once a round."""
+    with _lock:
+        for name, labels, value in rows:
+            k = (name, labels)
+            _counters[k] = _counters.get(k, 0) + value
 
 
 def gauge_set(name: str, value: float, **labels: Any) -> None:
@@ -166,7 +177,10 @@ def _render_key(k: _LabelKey) -> str:
 
 def snapshot() -> Dict[str, Any]:
     """Plain-dict snapshot: counters/gauges as ``name{k=v,...} -> value``,
-    histograms as ``-> {count, sum, min, max, buckets}``."""
+    histograms as ``-> {count, sum, min, max, buckets}``, and the records
+    of this process's slow rounds (``profiler.merge_rounds``, which also
+    brings the round account's counters up to the moment)."""
+    slow = profiler.merge_rounds()
     with _lock:
         counters = {_render_key(k): v for k, v in _counters.items()}
         gauges = {_render_key(k): v for k, v in _gauges.items()}
@@ -180,7 +194,7 @@ def snapshot() -> Dict[str, Any]:
             }
     return {"schema_version": SCHEMA_VERSION, "counters": counters,
             "gauges": gauges, "histograms": hists,
-            "bucket_bounds": list(BUCKETS)}
+            "bucket_bounds": list(BUCKETS), "slow_rounds": slow}
 
 
 def snapshot_json(indent: Optional[int] = None) -> str:
@@ -307,10 +321,11 @@ def mesh_bytes_by_codec(snap: Optional[Dict[str, Any]] = None
 
 
 def reset() -> None:
-    global _enabled, _export_dir
+    global _export_dir
     with _lock:
         _counters.clear()
         _gauges.clear()
         _hists.clear()
-    _enabled = False
+    enable(False)
     _export_dir = ""
+    profiler.reset_rounds()

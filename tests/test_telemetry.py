@@ -54,6 +54,20 @@ def test_counter_labels_render_sorted():
     assert snap["counters"]["plain"] == 1
 
 
+def test_counters_add_books_rows_under_the_labels_counter_inc_builds():
+    """The round account's way in: many rows, one hold of the lock, the
+    keys ``counter_inc`` would have made."""
+    telemetry.enable(True)
+    telemetry.counter_inc("round.spans", 2, span="van.send")
+    telemetry.counters_add([
+        ("round.spans", (("span", "van.send"),), 3),
+        ("round.work_ms", (("span", "van.send"),), 1.5),
+        ("host.gc_ms", (("gen", 2),), 0.25)])
+    assert telemetry.snapshot()["counters"] == {
+        "round.spans{span=van.send}": 5,
+        "round.work_ms{span=van.send}": 1.5, "host.gc_ms{gen=2}": 0.25}
+
+
 def test_gauge_last_value_wins():
     telemetry.enable(True)
     telemetry.gauge_set("epoch", 1)
@@ -142,14 +156,15 @@ def test_snapshot_schema_pinned():
     telemetry.gauge_set("g", 2.0)
     telemetry.histogram_obs("h", 3.0)
     snap = telemetry.snapshot()
-    assert snap["schema_version"] == telemetry.SCHEMA_VERSION == 1
+    assert snap["schema_version"] == telemetry.SCHEMA_VERSION == 2
     assert set(snap) == {"schema_version", "counters", "gauges",
-                         "histograms", "bucket_bounds"}
+                         "histograms", "bucket_bounds", "slow_rounds"}
+    assert snap["slow_rounds"] == []
     assert set(snap["histograms"]["h"]) == {"count", "sum", "min", "max",
                                             "buckets"}
     assert snap["bucket_bounds"] == list(telemetry.BUCKETS)
     # the JSON form carries the same version (what export_round writes)
-    assert json.loads(telemetry.snapshot_json())["schema_version"] == 1
+    assert json.loads(telemetry.snapshot_json())["schema_version"] == 2
 
 
 def test_wan_bytes_sums_global_send_counters_only():
@@ -312,8 +327,15 @@ def test_kv_metrics_and_wan_bytes_over_hips():
         # message counters ride along with matching labels
         assert any(k.startswith("van.messages_sent{")
                    for k in wsnap["counters"])
-        # the server's answer is a valid snapshot of the same registry
+        # the server's answer is a valid snapshot of the same registry,
+        # its share of the slow rounds' records included
         assert all("counters" in s for s in got["servers"])
+        assert all(s["schema_version"] == 2 and s["slow_rounds"] == []
+                   for s in got["servers"] + [wsnap])
+        # no trainer in this process: the servers' spans were merged
+        # when the round's id first showed, and at the snapshot
+        assert wsnap["counters"]["round.spans{span=server.push}"] >= 2
+        assert wsnap["counters"]["round.work_ms{span=van.send}"] > 0
     finally:
         sim.stop()
 
