@@ -253,11 +253,22 @@ class DeviceResidentTrainer:
             flo, fhi = int(self._offsets[a]), int(self._offsets[b + 1])
             fsize, cap = fhi - flo, nw * (sel_hi - sel_lo)
             if fsize + cap >= 1 << 31:
-                # _chunk_up's pad positions run from fsize to fsize+cap
+                # an upload's pad positions run from fsize to fsize+cap
                 raise ValueError("a chunk's elements and upload slots "
                                  f"together must stay under 2^31: {ch}")
             meta.append((sel_lo, sel_hi, flo, fsize, cap))
         self._chunk_meta = meta
+        # one upload a chunk, held for the trainer's life and written in
+        # place by _chunk_up (which says when it may be): all pad until a
+        # round fills it, value 0.0 in every slot and slot s at position
+        # fsize + s, past the chunk's end whatever a round leaves over
+        self._uploads = []
+        for _lo, _hi, _flo, fsize, cap in meta:
+            up = np.empty(2 * cap, np.int32)
+            up[:cap] = 0
+            up[cap:] = np.arange(fsize, fsize + cap, dtype=np.int32)
+            self._uploads.append(up)
+        self._filled = [0] * len(meta)  # slots each one's last round wrote
         sel_bounds = [(m[0], m[1]) for m in meta]
 
         # u and v are donated: the round rebinds both from the outputs,
@@ -282,9 +293,9 @@ class DeviceResidentTrainer:
             # up layout (see _chunk_up): [vals(cap) bitcast i32,
             # idx(cap) CHUNK-relative]. The positions ascend and are
             # distinct (the aggregate is sorted unique entries, keys in
-            # flat order), the pad slots go on ascending past the
-            # chunk's end and drop: told so, XLA puts no sort before
-            # the scatter
+            # flat order), the pad slots ascend on from the chunk's end
+            # or past it and drop: told so, XLA puts no sort before the
+            # scatter
             cap = up.shape[0] // 2
             vals = jax.lax.bitcast_convert_type(up[:cap], jnp.float32)
             cidx = up[cap:]
@@ -452,9 +463,12 @@ class DeviceResidentTrainer:
             args += (self._mesh_res,)
         fwd = self._fwd_chunks_q if self._mesh_quant else self._fwd_chunks
         fwd.lower(*args).compile()
-        for _lo, _hi, flo, fsize, cap in self._chunk_meta:
-            up = jax.device_put(np.zeros(2 * cap, np.int32))
-            self._apply_chunk.lower(self._flat, self._mom, up, flo,
+        for (_lo, _hi, flo, fsize, _cap), up in zip(self._chunk_meta,
+                                                   self._uploads):
+            # the held upload, put as a round puts it; no program reads
+            # this copy, so the first round writes the buffer freely
+            up_d = jax.device_put(up)
+            self._apply_chunk.lower(self._flat, self._mom, up_d, flo,
                                     fsize).compile()
 
     # -- one round -------------------------------------------------------
@@ -476,30 +490,58 @@ class DeviceResidentTrainer:
         return keys, vlist, ilist
 
     def _chunk_up(self, ci: int, agg: Dict) -> np.ndarray:
-        """Assemble chunk ``ci``'s fixed-size upload from its keys'
-        aggregated (values, key-relative indices): [vals(cap) bitcast
-        i32, idx(cap) chunk-relative]; the slots left over hold value
-        0.0 at positions that go on ascending from the chunk's end,
-        which ``apply_chunk`` drops."""
+        """Chunk ``ci``'s fixed-size upload from its keys' aggregated
+        (values, key-relative indices): [vals(cap) bitcast i32,
+        idx(cap) chunk-relative]. The ``n`` real entries come first,
+        keys in the chunk's order, so their positions ascend strictly
+        under ``fsize``; every slot ``s`` left over holds value 0.0 at
+        position ``fsize + s``, ascending on and past the chunk's end,
+        which ``apply_chunk`` drops.
+
+        What comes back is the ONE buffer the trainer holds for the
+        chunk, written in place: each key's values and rebased positions
+        in one pass each, straight into their slots, then only what the
+        last round left behind (its slots past this round's ``n`` go
+        back to pad). A round allocates nothing the size of the upload.
+
+        ``jax.device_put`` returns before the chip has the copy, and the
+        CPU backend may alias the numpy memory for the device array's
+        life: a buffer must not be written while a program may still
+        read its last upload. Hence one buffer a chunk, never one shared
+        by two chunks, and in ``step`` a chunk's buffer is next written
+        a round later, after that round's ``np.asarray(packs[ci])``: the
+        pack comes from the ``fwd_chunks`` that reads the ``flat`` which
+        the round's every ``apply_chunk`` wrote, so the apply that read
+        this buffer is done. A caller with no such fence between two
+        uploads of a chunk copies what it got."""
         _sel_lo, _sel_hi, flo, fsize, cap = self._chunk_meta[ci]
-        ups, upi = [], []
-        for i in self._chunks[ci].items:
-            avals, aidx = agg[self.begin_key + i]
-            ups.append(avals)
-            upi.append(aidx + (int(self._offsets[i]) - flo))
-        cat_v = np.concatenate(ups)
-        cat_i = np.concatenate(upi)
-        n = len(cat_v)
+        items = self._chunks[ci].items
+        parts = [agg[self.begin_key + i] for i in items]
+        n = sum(len(avals) for avals, _aidx in parts)
         if n > cap:
             raise RuntimeError(
                 f"aggregated selection ({n}) exceeds chunk upload "
                 f"capacity ({cap}) — is the PS tier running an "
                 "optimizer? DeviceResidentTrainer requires aggregator "
                 "mode")
-        up = np.zeros(2 * cap, np.int32)
-        up[:n] = np.asarray(cat_v, np.float32).view(np.int32)
-        up[cap:cap + n] = cat_i.astype(np.int32)
-        up[cap + n:] = np.arange(fsize, fsize + cap - n, dtype=np.int32)
+        up = self._uploads[ci]
+        vals, idx = up[:cap].view(np.float32), up[cap:]
+        stale = self._filled[ci]
+        if stale > n:
+            vals[n:stale] = 0.0
+            idx[n:stale] = np.arange(fsize + n, fsize + stale,
+                                     dtype=np.int32)
+        self._filled[ci] = n
+        at = 0
+        for i, (avals, aidx) in zip(items, parts):
+            end = at + len(avals)
+            vals[at:end] = avals
+            # one pass whatever the positions' type (the frame's int32
+            # view, a sharded key's int64): rebased they fit int32
+            np.add(aidx, int(self._offsets[i]) - flo, out=idx[at:end],
+                   casting="unsafe")
+            at = end
+        telemetry.counter_inc("trainer.upload_inplace_keys", len(items))
         return up
 
     def step(self, X, y) -> float:

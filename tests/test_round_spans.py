@@ -253,6 +253,68 @@ def test_the_benchmarks_metric_files_read_the_table_as_it_is():
     assert all(table[n].layer == bucket for n, bucket in listed.items())
 
 
+def test_the_trainers_key_counters_are_what_their_metric_files_read():
+    """The three counters a trainer's round books a key (the selection
+    by threshold, the dense reset, the upload written in place) are the
+    ``prefix`` of the benchmark's metric files of those names, read by
+    its counter reader, and the new one is in the manifest: a round of a
+    trainer moves each by its number of keys, in one chunk or several,
+    so the held upload's reads the dense reset's numbers in every cell."""
+    from types import SimpleNamespace
+
+    import jax.numpy as jnp
+
+    from geomx_tpu.kvstore import create as kv_create
+    from geomx_tpu.trainer_device import DeviceResidentTrainer
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "BENCHMARK.json")) as f:
+        per_layer = {m["name"]: m for m in json.load(f)["per_layer"]}
+    prefixes = {}
+    for name, layer in (("step.select_threshold_keys", "device step"),
+                        ("step.dense_reset_keys", "device step"),
+                        ("round.trainer_upload_inplace_keys", "trainer")):
+        with open(os.path.join(root, "benchmark", "layer_metrics",
+                               name + ".json")) as f:
+            spec = json.load(f)
+        assert (spec["name"], spec["reader"], spec["layer"]) == (
+            name, "counter_per_round", layer)
+        assert "must_contain" not in spec and "zero_with" not in spec
+        entry = per_layer[name]
+        assert all(entry[k] == spec[k]
+                   for k in ("unit", "source", "layer", "moves"))
+        assert entry["source"] == "program_counter"
+        assert "workloads" not in entry     # every cell runs a trainer
+        prefixes[name] = spec["prefix"]
+    assert prefixes["round.trainer_upload_inplace_keys"] == \
+        "trainer.upload_inplace_keys"
+    kv = kv_create("local")
+    kv.cfg = SimpleNamespace(wire_codec="", p3_slice_bytes=96)
+    shapes = [(40, 16), (129,), (7,)]
+    tr = DeviceResidentTrainer(
+        [np.ones(s, np.float32) for s in shapes], kv,
+        lambda leaves, X, y: (sum(jnp.sum(l * l) for l in leaves) * X,
+                              [2 * l * X for l in leaves]),
+        threshold=0.1, learning_rate=0.1)
+    assert len(tr._chunks) > 1
+    was_on = telemetry.enabled()
+    telemetry.enable(True)
+    try:
+        def read():
+            counters = telemetry.snapshot()["counters"]
+            return {name: sum(v for k, v in counters.items()
+                              if k.startswith(prefix))
+                    for name, prefix in prefixes.items()}
+
+        before = read()
+        for rounds in (1, 2):
+            tr.step(jnp.asarray(0.5), None)
+            assert {name: v - before[name] for name, v in read().items()
+                    } == dict.fromkeys(prefixes, len(shapes) * rounds)
+    finally:
+        telemetry.enable(was_on)
+
+
 def test_retired_surface_is_gone():
     from geomx_tpu import telemetry
 

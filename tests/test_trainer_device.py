@@ -264,7 +264,8 @@ def _three_rounds(wire_codec):
         telemetry.enable(was_on)
     booked = {name: after.get(name, 0) - before.get(name, 0)
               for name in ("step.select_threshold_keys",
-                           "step.dense_reset_keys")}
+                           "step.dense_reset_keys",
+                           "trainer.upload_inplace_keys")}
     return results, booked, len(shapes)
 
 
@@ -276,13 +277,14 @@ def test_selection_without_a_sort_trains_bit_for_bit(wire_codec,
     worker bit-equal (the same set leaves each key, so the servers sum
     the same pairs), ``bsc`` and ``bsc16``; every key of every round is
     booked on the selection's counter (no key keeps a sort, so there is
-    no second counter) and on the dense reset's (no key keeps its
-    scatters)."""
+    no second counter), on the dense reset's (no key keeps its
+    scatters) and on the held upload's (no key's parts are joined)."""
     from geomx_tpu.ops import select
 
     got, booked, keys = _three_rounds(wire_codec)
     assert booked == {"step.select_threshold_keys": keys * 3 * 2,
-                      "step.dense_reset_keys": keys * 3 * 2}  # x workers
+                      "step.dense_reset_keys": keys * 3 * 2,  # x workers
+                      "trainer.upload_inplace_keys": keys * 3 * 2}
     monkeypatch.setattr(select, "topk_by_magnitude", _by_sorting)
     want, _booked, _keys = _three_rounds(wire_codec)
     for widx in (0, 1):
@@ -310,10 +312,12 @@ def _bowl():
     return grad_fn
 
 
-def _local_trainer(wire_codec="", slice_bytes=0, **kw):
+def _local_trainer(wire_codec="", slice_bytes=0, shapes=None,
+                   grad_fn=None, **kw):
     """A trainer over the local store, which answers a round with the
     selection itself; the two settings the device step reads off a
-    store's configuration are handed to it as one."""
+    store's configuration are handed to it as one. The bowl over
+    ``_SHAPES`` unless ``shapes`` (and their ``grad_fn``) are given."""
     from types import SimpleNamespace
 
     from geomx_tpu.kvstore import create as kv_create
@@ -322,8 +326,10 @@ def _local_trainer(wire_codec="", slice_bytes=0, **kw):
     kv.cfg = SimpleNamespace(wire_codec=wire_codec,
                              p3_slice_bytes=slice_bytes)
     kw = dict(dict(threshold=0.05, learning_rate=0.1, momentum=0.9), **kw)
+    if shapes is None:
+        shapes, grad_fn = _SHAPES, _bowl()
     return DeviceResidentTrainer(
-        [np.zeros(s, np.float32) for s in _SHAPES], kv, _bowl(), **kw)
+        [np.zeros(s, np.float32) for s in shapes], kv, grad_fn, **kw)
 
 
 def _scattering_reference(tr, wire16, lr=0.1, momentum=0.9):
@@ -471,9 +477,12 @@ def test_the_chips_compiler_puts_no_sort_before_the_apply(one_chip):
 
 
 def test_chunk_up_pads_past_the_chunk_in_ascending_order():
-    """The slots an aggregate leaves over hold 0.0 at positions that go
-    on ascending from the chunk's end, every chunk from its own; an
-    upload at full capacity has no pad and applies all the same."""
+    """The slots an aggregate leaves over hold 0.0 at positions that
+    ascend, distinct, at or past the chunk's end, every chunk from its
+    own (slot ``s`` holds ``fsize + s``, whatever the round filled: the
+    scatter is told that the whole list ascends without a repeat and
+    drops what lies outside); an upload at full capacity has no pad and
+    applies all the same."""
     tr = _local_trainer(slice_bytes=96, momentum=0.0, learning_rate=1.0)
     assert len(tr._chunks) > 1
     padded = 0
@@ -487,8 +496,10 @@ def test_chunk_up_pads_past_the_chunk_in_ascending_order():
         assert up.dtype == np.int32 and up.shape == (2 * cap,)
         np.testing.assert_array_equal(up[:cap].view(np.float32)[1:], 0.0)
         assert up[cap] == 3
+        pad = up[cap + 1:]
+        assert np.all(pad >= fsize) and np.all(np.diff(up[cap:]) > 0)
         np.testing.assert_array_equal(
-            up[cap + 1:], np.arange(fsize, fsize + cap - 1))
+            pad, np.arange(fsize + 1, fsize + cap))
         padded += cap > 1
     assert padded
     # full capacity: every slot of the last chunk a real entry
@@ -530,3 +541,251 @@ def test_a_sharded_keys_parts_join_in_the_keys_order():
     for parts in ([], [empty], [empty, empty]):
         vals, idx = KVStoreDist._join_bsc_parts(parts)
         assert vals.size == 0 and idx.size == 0
+
+
+# -- the upload is a buffer the trainer holds, written in place ---------------
+
+def _chunk_up_as_it_was(tr, ci, agg):
+    """``_chunk_up`` before the trainer held its uploads: the keys'
+    parts joined by two concatenates into a fresh zeroed array, the
+    slots left over at ``fsize, fsize + 1, ..`` from the first of them.
+    The oracle: the held buffer equals it bit for bit on the real slots
+    and keeps the same promise on the pad."""
+    _sel_lo, _sel_hi, flo, fsize, cap = tr._chunk_meta[ci]
+    ups, upi = [], []
+    for i in tr._chunks[ci].items:
+        avals, aidx = agg[tr.begin_key + i]
+        ups.append(avals)
+        upi.append(aidx + (int(tr._offsets[i]) - flo))
+    cat_v = np.concatenate(ups)
+    cat_i = np.concatenate(upi)
+    n = len(cat_v)
+    if n > cap:
+        raise RuntimeError("aggregated selection exceeds chunk upload")
+    up = np.zeros(2 * cap, np.int32)
+    up[:n] = np.asarray(cat_v, np.float32).view(np.int32)
+    up[cap:cap + n] = cat_i.astype(np.int32)
+    up[cap + n:] = np.arange(fsize, fsize + cap - n, dtype=np.int32)
+    return up
+
+
+def _assert_upload(got, want, n, fsize, cap, where):
+    """``got`` is ``want`` bit for bit on the ``n`` real slots; its pad
+    holds the bits of 0.0 at ``fsize + s``; and the whole list of
+    positions keeps what the scatter is promised."""
+    assert got.dtype == want.dtype == np.int32, where
+    assert got.shape == want.shape == (2 * cap,), where
+    assert got[:n].tobytes() == want[:n].tobytes(), where
+    assert got[cap:cap + n].tobytes() == want[cap:cap + n].tobytes(), where
+    assert not got[n:cap].any(), where        # +0.0, not a stale value
+    np.testing.assert_array_equal(
+        got[cap + n:], np.arange(fsize + n, fsize + cap), err_msg=str(where))
+    pos = got[cap:].astype(np.int64)
+    assert np.all(np.diff(pos) > 0), where    # ascending, distinct
+    assert np.all(pos[:n] < fsize) and np.all(pos[n:] >= fsize), where
+    assert np.all(pos >= 0) and pos[-1] < 1 << 31, where
+
+
+def _an_aggregate(tr, ci, counts, seed, idx_dtype=np.int64, frame=False):
+    """An aggregate for chunk ``ci`` with ``counts[j]`` entries for its
+    j-th key: distinct ascending key-relative positions, values with
+    both zeros among them; ``frame`` hands every part out as the native
+    van does, a read-only view into one buffer of bytes."""
+    rng = np.random.default_rng(seed)
+    agg = {}
+    for i, n in zip(tr._chunks[ci].items, counts):
+        idx = np.sort(rng.choice(tr._sizes[i], n, replace=False)).astype(
+            idx_dtype)
+        vals = rng.standard_normal(n).astype(np.float32)
+        vals[::5] = [0.0, -0.0][i % 2]
+        if frame:
+            wire = b"\0" * 4 + vals.tobytes() + idx.tobytes()
+            vals = np.frombuffer(wire, np.float32, n, offset=4)
+            idx = np.frombuffer(wire, idx_dtype, n, offset=4 + 4 * n)
+            assert not (vals.flags.writeable or idx.flags.writeable)
+        agg[tr.begin_key + i] = (vals, idx)
+    return agg
+
+
+def _spread(tr, ci, total, empty=()):
+    """``total`` entries (as many of them as there is room for) over
+    chunk ``ci``'s keys in proportion to their sizes, none to the keys
+    at the places ``empty`` (negative: from the end)."""
+    items = tr._chunks[ci].items
+    empty = {e % len(items) for e in empty}
+    room = [0 if j in empty else tr._sizes[i] for j, i in enumerate(items)]
+    total = min(total, sum(room))
+    counts = [total * r // max(sum(room), 1) for r in room]
+    for j, r in enumerate(room):
+        counts[j] += min(r - counts[j], total - sum(counts))
+    assert sum(counts) == total
+    return counts
+
+
+# a case: the chunk plan, then the rounds ONE trainer makes in a row,
+# each (share of the chunk's capacity taken, keys left empty, how the
+# positions come); a share over 1 is the aggregate that must raise
+_INT32_FRAME = dict(idx_dtype=np.int32, frame=True)
+_UPLOAD_CASES = {
+    "frame_int32_readonly_views": (0, [(0.6, (), _INT32_FRAME)]),
+    "int64_positions": (0, [(0.6, (), {})]),
+    "int64_frame_views": (0, [(0.5, (), dict(frame=True))]),
+    "empty_key_first": (0, [(0.5, (0,), _INT32_FRAME)]),
+    "empty_key_in_the_middle": (0, [(0.5, (2,), _INT32_FRAME)]),
+    "empty_key_last": (0, [(0.5, (-1,), {})]),
+    "every_key_empty_from_the_start": (0, [(0.0, (), {})]),
+    "full_capacity_no_pad": (0, [(1.0, (), _INT32_FRAME)]),
+    "grows_shrinks_empties_fills": (
+        0, [(0.3, (), _INT32_FRAME), (0.9, (), {}), (0.4, (1,), _INT32_FRAME),
+            (0.0, (), {}), (1.0, (), _INT32_FRAME), (0.2, (), {}),
+            (0.2, (0, 3), _INT32_FRAME)]),
+    "a_round_after_one_that_raised": (
+        0, [(0.7, (), {}), (1.5, (), _INT32_FRAME), (0.4, (), {})]),
+    "several_chunks": (
+        96, [(0.8, (), _INT32_FRAME), (0.4, (0,), {}), (1.0, (), {}),
+             (0.0, (), _INT32_FRAME), (0.5, (), {})]),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_UPLOAD_CASES))
+def test_the_held_upload_against_the_joined_one(case):
+    """What ``_chunk_up`` writes into the buffer the trainer holds is,
+    on the real slots, bit for bit the upload its concatenating form
+    made of the same aggregate, and pad by the rule on the rest: round
+    after round on one trainer (an entry of a longer round never
+    survives into a shorter one's pad) and chunk by chunk; and the
+    counter moves by the keys written."""
+    from geomx_tpu import telemetry
+
+    slice_bytes, rounds = _UPLOAD_CASES[case]
+    tr = _local_trainer(slice_bytes=slice_bytes, threshold=0.2)
+    assert (len(tr._chunks) > 1) == bool(slice_bytes)
+    was_on, written = telemetry.enabled(), 0
+    telemetry.enable(True)
+    try:
+        before = telemetry.snapshot()["counters"].get(
+            "trainer.upload_inplace_keys", 0)
+        for rnd, (share, empty, how) in enumerate(rounds):
+            for ci in range(len(tr._chunks)):
+                _lo, _hi, _flo, fsize, cap = tr._chunk_meta[ci]
+                counts = _spread(tr, ci, int(share * cap), empty)
+                agg = _an_aggregate(tr, ci, counts, seed=100 * rnd + ci,
+                                    **how)
+                if share > 1:
+                    held = tr._uploads[ci].copy()
+                    for form in (_chunk_up_as_it_was, type(tr)._chunk_up):
+                        with pytest.raises(RuntimeError,
+                                           match="exceeds chunk upload"):
+                            form(tr, ci, agg)
+                    np.testing.assert_array_equal(tr._uploads[ci], held)
+                    continue
+                want = _chunk_up_as_it_was(tr, ci, agg)
+                got = tr._chunk_up(ci, agg)
+                written += len(tr._chunks[ci].items)
+                assert got is tr._uploads[ci]
+                _assert_upload(got, want, sum(counts), fsize, cap,
+                               (case, rnd, ci))
+                if share == 1.0:
+                    assert sum(counts) == cap
+        after = telemetry.snapshot()["counters"].get(
+            "trainer.upload_inplace_keys", 0)
+    finally:
+        telemetry.enable(was_on)
+    assert after - before == written > 0
+
+
+def test_the_words_of_an_aggregate_over_the_capacity():
+    """An aggregate with more entries than the upload has slots is a
+    server running an optimizer: the error says so, word for word as it
+    did (that it leaves the held buffer alone is the case
+    ``a_round_after_one_that_raised`` above)."""
+    tr = _local_trainer(threshold=0.2)
+    (_lo, _hi, _flo, _fsize, cap), = tr._chunk_meta
+    over = _an_aggregate(tr, 0, _spread(tr, 0, cap + 1), 2)
+    with pytest.raises(RuntimeError) as err:
+        tr._chunk_up(0, over)
+    assert str(err.value) == (
+        f"aggregated selection ({cap + 1}) exceeds chunk upload capacity "
+        f"({cap}) — is the PS tier running an optimizer? "
+        "DeviceResidentTrainer requires aggregator mode")
+
+
+def test_a_round_allocates_nothing_the_size_of_the_upload():
+    """Two calls hand out the one buffer the trainer holds for the
+    chunk, and a call's peak of traced memory stays under a constant
+    far below one part of the upload (the joined form took five of
+    them), int32 positions or int64, and when a round leaves over what
+    the last one filled only that stretch is made anew."""
+    import tracemalloc
+
+    # never stepped: no grad_fn is traced
+    tr = _local_trainer(shapes=[(1 << 21,), (1 << 10, 1 << 10)],
+                        threshold=0.125)
+    (_lo, _hi, _flo, fsize, cap), = tr._chunk_meta
+    assert cap >= 300_000
+    rounds = [([cap // 2, cap // 3], 1, np.int32),
+              ([cap // 2, cap // 3 - 1000], 2, np.int64),
+              ([cap // 2 - 1000, cap // 3], 3, np.int32)]
+    aggs = [_an_aggregate(tr, 0, counts, seed, dtype, frame=True)
+            for counts, seed, dtype in rounds]
+    first = tr._chunk_up(0, aggs[0])
+    peaks = []
+    for agg, (counts, _seed, _dtype) in zip(aggs, rounds):
+        tracemalloc.start()
+        try:
+            again = tr._chunk_up(0, agg)
+            peaks.append(tracemalloc.get_traced_memory()[1])
+        finally:
+            tracemalloc.stop()
+        assert again is first is tr._uploads[0]
+        _assert_upload(again, _chunk_up_as_it_was(tr, 0, agg), sum(counts),
+                       fsize, cap, counts)
+    assert max(peaks) < 256 << 10 < 4 * cap, peaks
+
+
+def _thinning(tr):
+    """Make ``tr``'s store answer round ``r`` with every ``keep[r]``-th
+    entry of a key's selection (none where 0), so that a chunk's real
+    entries grow, shrink and vanish from round to round as an aggregate
+    over workers does."""
+    keep = [2, 1, 0, 3, 1]
+    inner, calls = tr.kv.push_pull_bsc_batch_async, [0]
+
+    def thinned(keys, vlist, ilist, **kw):
+        step = keep[calls[0] // len(tr._chunks) % len(keep)]
+        calls[0] += 1
+        cut = slice(None, None, step) if step else slice(0, 0)
+        return inner(keys, [np.asarray(v)[cut] for v in vlist],
+                     [np.asarray(i)[cut] for i in ilist], **kw)
+
+    tr.kv.push_pull_bsc_batch_async = thinned
+
+
+@pytest.mark.parametrize("slice_bytes", [0, 96])
+def test_rounds_on_held_uploads_end_where_fresh_uploads_do(slice_bytes):
+    """Five rounds of ``step`` whose aggregates grow, shrink and are
+    empty, in one chunk and in several, end bit for bit in the
+    parameters and momentum of the same rounds with a fresh upload made
+    each time: on the CPU backend ``device_put`` may alias the numpy
+    buffer, so this is also what says that no buffer is rewritten while
+    an apply can still read it, and that no chunk's is another's."""
+    held = _local_trainer(slice_bytes=slice_bytes, threshold=0.2)
+    fresh = _local_trainer(slice_bytes=slice_bytes, threshold=0.2)
+    fresh._chunk_up = lambda ci, agg: _chunk_up_as_it_was(fresh, ci, agg)
+    assert (len(held._chunks) > 1) == bool(slice_bytes)
+    assert len({u.ctypes.data for u in held._uploads}) == len(held._chunks)
+    filled = []
+    for tr in (held, fresh):
+        _thinning(tr)
+    for rnd in range(5):
+        X = jnp.asarray(0.5 - 0.25 * rnd)
+        assert held.step(X, None) == fresh.step(X, None)
+        filled.append(sum(held._filled))
+        for name in ("_flat", "_mom", "_u", "_v"):
+            np.testing.assert_array_equal(
+                np.asarray(getattr(held, name)).view(np.uint32),
+                np.asarray(getattr(fresh, name)).view(np.uint32),
+                err_msg=f"{name} after round {rnd}")
+    assert filled[2] == 0 and filled[1] > filled[0] > 0  # it did vary
+    assert filled[1] > filled[3] > 0
+    assert np.any(np.asarray(held._flat) != 0)
