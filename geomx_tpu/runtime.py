@@ -1,5 +1,6 @@
-"""Where the program meets the installed jax: which device it runs on,
-where compiled programs are cached, and how compilations are counted.
+"""Where the program meets the installed interpreter and jax: which
+device it runs on, where compiled programs are cached, how compilations
+are counted, and what the collector need not walk again.
 
 Every entry point that measures or proves something on the accelerator
 (``benchmark/run.py``, ``chip_smoke.py``, ``tools/attention_bench.py``)
@@ -13,13 +14,15 @@ roles must not pay for it.
 
 from __future__ import annotations
 
+import gc
 import os
 from typing import Dict, List
 
+from geomx_tpu import telemetry
 from geomx_tpu.config import env_str
 
 __all__ = ["REPO_ROOT", "setup_compile_cache", "device_stamp",
-           "require_tpu", "CompileCounter"]
+           "require_tpu", "CompileCounter", "settle_heap"]
 
 REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -60,6 +63,29 @@ def require_tpu() -> Dict[str, object]:
             f"({stamp['kind']}, {stamp['count']} device(s)); this entry "
             "point runs on the accelerator only and has no CPU fallback")
     return stamp
+
+
+def settle_heap() -> int:
+    """One full collection, then everything that survived it moves out of
+    the collector's sight (``gc.freeze()``); returns how many objects
+    the process holds frozen.
+
+    For the moment a process has built what it keeps for the life of the
+    job (compiled programs and their jaxprs, the model's pytrees, jax's
+    and flax's modules, the topology): a generation-2 collection walks
+    every container the process tracks, none of that is ever garbage,
+    and the pass holds the interpreter lock, so every thread waits it
+    out. Afterwards a full pass walks what was allocated since. The
+    collection comes first so that no garbage is frozen; the collector
+    stays on, with its thresholds. A later call freezes what has been
+    built since. A frozen object that later joins a dead cycle is never
+    freed: call this where what is alive stays alive."""
+    gc.collect()
+    gc.freeze()
+    frozen = gc.get_freeze_count()
+    telemetry.counter_inc("host.gc_freezes")
+    telemetry.gauge_set("host.gc_frozen_objects", frozen)
+    return frozen
 
 
 class CompileCounter:

@@ -58,7 +58,7 @@ from typing import Any, Callable, Dict, List, Sequence
 
 import numpy as np
 
-from geomx_tpu import profiler, telemetry
+from geomx_tpu import profiler, runtime, telemetry
 from geomx_tpu.kvstore.frontier import plan_chunks
 from geomx_tpu.ops.select import leaving, topk_flat
 
@@ -454,7 +454,11 @@ class DeviceResidentTrainer:
         the one the first call finds) and the state stays untouched —
         lets callers serialize expensive first compiles without holding
         up the FSA barrier (at 59M parameters the forward program is
-        about a minute of cold compile on a v5e)."""
+        about a minute of cold compile on a v5e). What the process has
+        built by then it keeps for the life of the job, so the heap is
+        settled on the way out (``runtime.settle_heap``): the round
+        loop's full collections walk the rounds' garbage, not the
+        programs."""
         import jax
 
         X, y = self._place_batch(X, y)
@@ -470,6 +474,7 @@ class DeviceResidentTrainer:
             up_d = jax.device_put(up)
             self._apply_chunk.lower(self._flat, self._mom, up_d, flo,
                                     fsize).compile()
+        runtime.settle_heap()
 
     # -- one round -------------------------------------------------------
 

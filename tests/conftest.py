@@ -7,6 +7,7 @@ use a fake in-process transport, integration tests spawn real subprocesses.
 """
 
 import faulthandler
+import gc
 import os
 import signal
 import sys
@@ -31,6 +32,17 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 # The slowest tier-1 test takes about 30 s on a loaded box; the driver
 # gives the whole run 1470 s.
 TIME_LIMIT_S = 120.0
+
+
+@pytest.fixture(autouse=True)
+def _thawed_heap():
+    """``DeviceResidentTrainer.warmup`` settles the heap
+    (``runtime.settle_heap``: a collection, then ``gc.freeze()``), which
+    is right for a job and wrong for a worker of the suite that goes on
+    to a thousand other tests: what a test froze goes back to the
+    collector when the test is over."""
+    yield
+    gc.unfreeze()
 
 
 class TimeLimitExceeded(Exception):

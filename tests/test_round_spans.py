@@ -777,6 +777,29 @@ def test_a_collection_is_booked_beside_the_span_it_ran_in():
     assert unpack["longest"]["thread"] == me
 
 
+@pytest.mark.parametrize("on", [True, False])
+def test_a_settled_heap_says_so_and_its_collections_are_still_booked(on):
+    """``runtime.settle_heap``: a gauge and a counter while telemetry is
+    on, nothing otherwise; its own full collection and one made after
+    the freeze are both the account's, under generation 2."""
+    from geomx_tpu import runtime
+
+    telemetry.enable(on)
+    with profiler.scope("trainer.step", node="w9", round=1):
+        frozen = runtime.settle_heap()
+        assert frozen >= gc.get_freeze_count() > 0.99 * frozen
+        gc.collect()                    # walks what was made since
+    snap = telemetry.snapshot()         # (conftest thaws the heap after)
+    if not on:
+        assert "host.gc_frozen_objects" not in snap["gauges"]
+        assert not [k for k in snap["counters"] if k.startswith("host.gc")]
+        return
+    assert snap["gauges"]["host.gc_frozen_objects"] == frozen
+    assert snap["counters"]["host.gc_freezes"] == 1
+    assert snap["counters"]["host.gc_collections{gen=2}"] == 2
+    assert snap["counters"]["host.gc_ms{gen=2}"] > 0
+
+
 def test_the_on_path_fits_a_round_of_cell_one():
     """Telemetry on, no trace of either kind: the account of one round
     of the GPT-2 cells costs under 1.5 ms of host time summed over the

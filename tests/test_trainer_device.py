@@ -7,7 +7,9 @@ SGD), replica consistency, convergence at sparse thresholds, and the
 compact-payload claim.
 """
 
+import gc
 import threading
+import weakref
 
 import jax
 import jax.numpy as jnp
@@ -127,6 +129,116 @@ def test_warmup_compiles_without_state_change():
     np.testing.assert_array_equal(tr.leaves[0], before)
     tr.step(jnp.asarray(0.0), None)  # and a real round still works
     assert not np.array_equal(tr.leaves[0], before)
+
+
+class _Knot:
+    """Garbage only the collector can free."""
+
+    def __init__(self):
+        self.me = self
+
+
+@pytest.fixture
+def heap_account():
+    """Telemetry on; gives what a counter has gained since. (Nothing is
+    frozen going in and the heap is thawed going out: conftest's
+    ``_thawed_heap`` is every test's teardown.)"""
+    from geomx_tpu import telemetry
+
+    was_on = telemetry.enabled()
+    telemetry.enable(True)
+    before = telemetry.snapshot()["counters"]
+    try:
+        yield lambda name: (telemetry.snapshot()["counters"].get(name, 0)
+                            - before.get(name, 0))
+    finally:
+        telemetry.enable(was_on)
+
+
+def _warm_trainer():
+    from geomx_tpu.kvstore import create as kv_create
+
+    return DeviceResidentTrainer(
+        [np.zeros((16,), np.float32)], kv_create("local"), _grad_fn_16,
+        threshold=0.5, learning_rate=0.1)
+
+
+def test_warmup_moves_the_heap_out_of_the_collectors_sight(heap_account):
+    from geomx_tpu import telemetry
+
+    tr = _warm_trainer()
+    gc.collect()                        # the suite's garbage so far
+    tracked = len(gc.get_objects())
+    knot = weakref.ref(_Knot())         # dead already, and a cycle
+    # (a collection parks the interpreter's few hundred immortals there)
+    assert gc.get_freeze_count() < 0.01 * tracked and knot() is not None
+    tr.warmup(jnp.asarray(0.0), None)
+    frozen = gc.get_freeze_count()
+    # jax, numpy, the suite and the programs: all of it, near enough
+    assert frozen > 0.9 * tracked
+    assert len(gc.get_objects()) < 0.1 * tracked
+    # the collection came first: no garbage went into the freeze
+    assert knot() is None
+    # (a frozen object whose last reference goes is freed like any
+    # other: the count read later is the gauge less a frame or two)
+    gauge = telemetry.snapshot()["gauges"]["host.gc_frozen_objects"]
+    assert frozen <= gauge < frozen + 100
+    assert heap_account("host.gc_freezes") == 1
+
+
+def test_a_second_warmup_freezes_what_was_built_since(heap_account):
+    from geomx_tpu import telemetry
+
+    first, second = _warm_trainer(), _warm_trainer()
+    first.warmup(jnp.asarray(0.0), None)
+    once = gc.get_freeze_count()
+    before = second.leaves[0].copy()
+    second.warmup(jnp.asarray(0.0), None)
+    first.warmup(jnp.asarray(0.0), None)    # and the same trainer again
+    twice = gc.get_freeze_count()
+    # additive and small: nothing is thawed, nothing is frozen twice
+    assert 0.99 * once < twice < 1.1 * once
+    gauge = telemetry.snapshot()["gauges"]["host.gc_frozen_objects"]
+    assert twice <= gauge < twice + 100
+    assert heap_account("host.gc_freezes") == 3
+    np.testing.assert_array_equal(second.leaves[0], before)
+    for tr in (first, second):              # and real rounds still work
+        tr.step(jnp.asarray(0.0), None)
+        assert not np.array_equal(tr.leaves[0], before)
+
+
+def test_a_frozen_trainer_that_is_dropped_is_freed(heap_account):
+    """What the freeze must not cost a process that makes trainers again
+    and again: a frozen object in a dead cycle is never freed, so the
+    trainer and its device state may sit in no cycle; the last
+    reference's going frees them, frozen or not."""
+    tr = _warm_trainer()
+    tr.warmup(jnp.asarray(0.0), None)
+    tr.step(jnp.asarray(0.0), None)
+    trainer, flat = weakref.ref(tr), weakref.ref(tr._flat)
+    frozen = gc.get_freeze_count()
+    del tr
+    assert trainer() is None and flat() is None
+    assert gc.get_freeze_count() < frozen
+
+
+def test_the_collector_stays_on_after_warmup(heap_account):
+    """Frozen is not disabled: the thresholds are the interpreter's, and
+    a cycle that dies after the freeze is collected like any other."""
+    thresholds, was_enabled = gc.get_threshold(), gc.isenabled()
+    tr = _warm_trainer()
+    tr.warmup(jnp.asarray(0.0), None)
+    assert gc.get_threshold() == thresholds
+    assert gc.isenabled() == was_enabled
+    tr.step(jnp.asarray(0.0), None)
+    knot = weakref.ref(_Knot())
+    assert knot() is not None
+    gc.collect()
+    assert knot() is None
+    # what a thaw gives back is what was frozen
+    frozen = gc.get_freeze_count()
+    gc.unfreeze()
+    assert gc.get_freeze_count() == 0 and len(gc.get_objects()) >= frozen
 
 
 def _grad_fn_16(leaves, X, y):
