@@ -38,6 +38,12 @@ Precision: parameters float32, matmul operands in ``compute_dtype``;
 float32 for the residual stream, every norm's statistics, the attention
 scores and their softmax, the logits, and everything that decides
 routing (``n2``, the router product at ``highest``, sigmoid, top-k).
+
+Memory: no block and no part of one carries a checkpoint of its own.
+A dense attention core is computed again on the way back and the
+kernels keep q, k, v, o and the log-sum-exp (``models/transformer.py``);
+``moe.sparse_dispatch`` moves only the held rows and keeps nothing a
+tile by itself.
 """
 
 from __future__ import annotations
@@ -136,15 +142,10 @@ class LagunaBlock(nn.Module):
         w_down = self.param("w_down", init, (held, self.expert_width, d),
                             jnp.float32).astype(dt)
 
-        @jax.checkpoint
-        def routed_experts(rows, chosen, weights, w_gate, w_up, w_down):
-            return sparse_dispatch(rows, chosen, weights,
-                                   gated_experts(w_gate, w_up, w_down),
-                                   self.local_experts, self.num_experts)
-
-        routed, group_sizes = routed_experts(
+        routed, group_sizes = sparse_dispatch(
             m.reshape(b * t, d).astype(dt), chosen.reshape(b * t, -1),
-            weights.reshape(b * t, -1), w_gate, w_up, w_down)
+            weights.reshape(b * t, -1), gated_experts(w_gate, w_up, w_down),
+            self.local_experts, self.num_experts)
         y = y.astype(jnp.float32) + routed.reshape(b, t, d).astype(
             jnp.float32)
         return x + y, jnp.sum(group_sizes)

@@ -49,6 +49,17 @@ convolution, the decays and write strengths, the recurrence's state and
 solve, the attention scores and their softmax, the logits, and
 everything that decides routing (``n2``, the router product at
 ``highest``, softmax, top-k).
+
+Memory: one recomputation rule, the linear layers'. The gated delta
+rule's core sits under a ``jax.checkpoint`` with the policy
+``ops.gated_delta.keeps``: kept a layer and pass are its inputs (q, k a
+KEY head, v, g, beta) and what the solve and the chain's kernel hand
+the way back (0.17 GB at 4,096 tokens and 16 value heads); the
+chunk-local products, decays and layout changes are computed again.
+Keeping those too, 0.3 GB a layer more, does not fit the one-chip cell
+(``PERF.md`` section 4 has the readings). The routed experts carry no
+checkpoint: ``moe.sparse_dispatch`` moves only the held rows and keeps
+nothing a tile by itself.
 """
 
 from __future__ import annotations
@@ -68,6 +79,7 @@ from geomx_tpu.models.transformer import (HIGHEST, RMSNorm,
                                           kernel_score_entries,
                                           rotary_frequencies,
                                           score_entries)
+from geomx_tpu.ops import gated_delta
 from geomx_tpu.ops.gated_delta import chunks_of, gated_delta_rule
 
 __all__ = ["Qwen3Next", "Qwen3NextBlock", "GatedDeltaNet", "causal_conv",
@@ -151,10 +163,11 @@ class GatedDeltaNet(nn.Module):
             dt_bias = self.param("dt_bias", nn.initializers.ones, (hv,))
             g = -jnp.exp(a_log) * jax.nn.softplus(a + dt_bias)
 
-            # computed again on the way back: the chunk-local terms and
-            # the loop's states of three layers do not fit beside two
-            # trainers' state; kept are q, k a KEY head, v, g and beta
-            @jax.checkpoint
+            # the chunk-parallel part is computed again on the way
+            # back (its products, decays and layout changes are 0.3 GB a
+            # layer); what the solve and the chain hand the way back
+            # stays, so neither runs twice (the module's ``Memory``)
+            @partial(jax.checkpoint, policy=gated_delta.keeps)
             def rule(q, k, v, g, beta):
                 with jax.named_scope("gated_delta_rule"):
                     return gated_delta_rule(
@@ -247,15 +260,10 @@ class Qwen3NextBlock(nn.Module):
         w_down = self.param("w_down", init, (held, self.expert_width, d),
                             jnp.float32).astype(dt)
 
-        @jax.checkpoint
-        def routed_experts(rows, chosen, weights, w_gate, w_up, w_down):
-            return sparse_dispatch(rows, chosen, weights,
-                                   gated_experts(w_gate, w_up, w_down),
-                                   self.local_experts, self.num_experts)
-
-        routed, group_sizes = routed_experts(
+        routed, group_sizes = sparse_dispatch(
             m.reshape(b * t, d).astype(dt), chosen.reshape(b * t, -1),
-            weights.reshape(b * t, -1), w_gate, w_up, w_down)
+            weights.reshape(b * t, -1), gated_experts(w_gate, w_up, w_down),
+            self.local_experts, self.num_experts)
         y = y + routed.reshape(b, t, d).astype(jnp.float32)
         return x + y, jnp.sum(group_sizes)
 

@@ -43,11 +43,28 @@ states, run in one of two forms, and :func:`runs_kernel` says which:
   the first chunk to the last, so a step's four products (backward:
   eight) run on tiles that are already on the chip, and neither the
   states nor their cotangents pass through a loop of XLA's. The forward
-  kernel writes ``o`` and keeps, for the way back, the state every
-  chunk found and ``v'``.
+  kernel writes ``o`` and, for the way back, the state every chunk
+  found and ``v'``.
 
 Either way the states are kept a CHUNK apart (T / chunk of them), never
 a token apart.
+
+What the way back reads of the two chains carries a name
+(``jax.ad_checkpoint.checkpoint_name``): :data:`SOLVED` the solve's
+``u`` and ``w`` (kernel form: its inverse too, all its cotangents
+take), :data:`CHAINED` the states the chain's kernel found and
+``v'``. A caller that recomputes the rule on the way back
+(``jax.checkpoint``) and gives it the policy :data:`keeps` runs neither
+kernel-form chain a second time: the chunk-parallel part is computed
+again, the chain's operands with it, and the named values are the link
+between the two. At 4,096 tokens and 16 value heads of 128 x 128 they
+are 0.17 GB a layer and pass (the inverse 17 MB, ``u`` and ``w`` 67,
+the states 67, ``v'`` 17 in bfloat16). The scan form names its solve's
+result alike, so the CPU suite differentiates under the same policy;
+its chains are JAX's own programs and run again all the same
+(``triangular_solve``'s rule reads the primitive's result, not the
+named copy; the scan keeps its residuals to itself). Without a policy
+the names are inert.
 
 Precision: the decays, their sums and exponentials, the solve and the
 state (and its cotangent) are float32; the matmuls take their operands
@@ -62,13 +79,23 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 __all__ = ["gated_delta_rule", "gated_delta_rule_recurrent", "chunks_of",
-           "runs_kernel"]
+           "runs_kernel", "keeps"]
 
 CHUNK = 64
 SOLVE_BLOCK = 16        # rows of a diagonal block: the solve's one loop
 HEADS_A_STEP = 8        # value heads a grid step of the chain, at most
+# ``checkpoint_name``s of what the way back reads of the two chains and
+# the policy that keeps them (the module's docstring). ONE policy
+# object: JAX caches how a ``jit`` inside a checkpoint splits into kept
+# and recomputed by the policy's identity, and a policy made a layer
+# lowers the kernel form once a layer (nine Mosaic calls in the cell's
+# ``grad_step`` where this gives five)
+SOLVED = "gated_delta_solved"   # u, w; kernel form: the inverse too
+CHAINED = "gated_delta_chained"  # kernel form: the states found, v'
+keeps = jax.checkpoint_policies.save_only_these_names(SOLVED, CHAINED)
 
 
 def chunks_of(t: int, chunk: int = CHUNK) -> int:
@@ -210,10 +237,10 @@ def _unit_lower_solve(a, *sides):
 
 @jax.named_scope("unit_lower_solve")
 def _unit_lower_solve_fwd(a, *sides):
-    inverse = _unit_lower_inverse(a)
-    out = tuple(jnp.einsum("...ij,...jd->...id", inverse, x,
-                           precision=jax.lax.Precision.HIGHEST)
-                for x in sides)
+    inverse = checkpoint_name(_unit_lower_inverse(a), SOLVED)
+    out = tuple(checkpoint_name(jnp.einsum(
+        "...ij,...jd->...id", inverse, x,
+        precision=jax.lax.Precision.HIGHEST), SOLVED) for x in sides)
     return out, (inverse, out)
 
 
@@ -411,8 +438,9 @@ def _calls_for(interpret, u, w):
 def _chain_fwd(interpret, u, w, k_to_end, through, q_decayed, scores):
     o, found, v_new, last = _calls_for(interpret, u, w)[0](
         u, w, k_to_end, through, q_decayed, scores)
-    return (o, last), (w, k_to_end, through, q_decayed, scores, found,
-                       v_new)
+    return (o, last), (w, k_to_end, through, q_decayed, scores,
+                       checkpoint_name(found, CHAINED),
+                       checkpoint_name(v_new, CHAINED))
 
 
 def _chain_bwd(interpret, res, cotangents):
@@ -474,9 +502,9 @@ def _chunked(q, k, v, g, beta, chunk: int, dt, kernel: bool,
     if kernel:
         u, w = _unit_lower_solve(a, *sides)
     else:
-        uw = jax.lax.linalg.triangular_solve(
+        uw = checkpoint_name(jax.lax.linalg.triangular_solve(
             a, jnp.concatenate(sides, -1),
-            left_side=True, lower=True, unit_diagonal=True)
+            left_side=True, lower=True, unit_diagonal=True), SOLVED)
         u, w = uw[..., :dv], uw[..., dv:]
     scores = jnp.where(lower, mm("bhnik,bhnjk->bhnij", q, k) * decay, 0.0)
     q_decayed = q * jnp.exp(c)[..., None]

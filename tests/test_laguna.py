@@ -518,9 +518,14 @@ def test_two_party_round_through_the_device_trainer():
 # value. PR 39 (the full layers' core behind ``causal_attention``: the
 # dense product here, where Pallas is interpreted) left every operation
 # what it was and moved one private function's number (``_take_507`` ->
-# ``_take_506``): recorded anew.
+# ``_take_506``): recorded anew. PR 57 meant to alter it: the routed
+# experts lost the ``jax.checkpoint`` around ``sparse_dispatch`` (it
+# ran the index work, the gather, the grouped matmuls and the
+# scatter-add once more before the way back, which ``_sum_of_tiles``
+# recomputes a tile by itself), so a sparse layer's second forward is
+# out of the text: recorded anew, and the test below counts the groups.
 FUSED_STEP_STABLEHLO = \
-    "10fc48bddae25e22a8dbad2d6c84735065c5f83cdabfd18dacbed4e2e7dc4051"
+    "8faaf26aa5bf030f1a5d35e813d9332eae6eba70b46c3906fb81d22eab6c31de"
 # the same of the GPT-2 family's grad_step at gpt2-small's rehearsal
 # widths, 37 tokens, taken on the tree of PR 32: a family without
 # experts compiles what it compiled before the dispatch changed
@@ -543,6 +548,47 @@ def test_grad_step_lowers_to_the_recorded_program():
     assert _stablehlo_sha256(
         bench_model, reference,
         dict(TINY, compute_dtype="bfloat16")) == FUSED_STEP_STABLEHLO
+
+
+@pytest.mark.parametrize("seq,in_loops", [(SEQ, False), (300, True)],
+                         ids=["one_tile_by_shape", "loop_over_tiles"])
+def test_grad_step_holds_one_forward_group_of_grouped_matmuls_a_layer(
+        seq, in_loops):
+    """A sparse layer's grouped matmuls (gate, up, down: one group) in
+    the whole of ``grad_step``: one group forward and its six
+    transposed products where the cap is all the pairs; where it is
+    not, the forward loop over the tiles holds the group and the loop
+    of the way back holds it again beside the six
+    (``moe._sum_of_tiles``; 600 pairs a pass, 512 a tile). No third
+    group: until PR 57 a checkpoint around the dispatch ran the forward
+    once more."""
+    names, grad_step = bench_model.build(TINY, seq)
+    shapes = reference.param_shapes(TINY)
+    jaxpr = jax.make_jaxpr(lambda p, x: grad_step(p, x, None))(
+        [jax.ShapeDtypeStruct(shapes[n], jnp.float32) for n in names],
+        jax.ShapeDtypeStruct((2, seq + 1), jnp.int32))
+
+    def equations(jaxpr):
+        """Every equation, those of inner jaxprs included."""
+        for eqn in jaxpr.eqns:
+            yield eqn
+            for value in eqn.params.values():
+                for inner in value if isinstance(value, (list, tuple)) \
+                        else [value]:
+                    inner = getattr(inner, "jaxpr", inner)
+                    if hasattr(inner, "eqns"):
+                        yield from equations(inner)
+
+    def grouped(eqns):
+        return sum(e.primitive.name.startswith("ragged_dot") for e in eqns)
+
+    sparse = sum(kind == "sparse" for kind in TINY["mlp_layer_types"])
+    eqns = list(equations(jaxpr.jaxpr))
+    loops = sorted(filter(None, (
+        grouped(equations(e.params["body_jaxpr"].jaxpr)) for e in eqns
+        if e.primitive.name == "while")))
+    assert loops == ([3] * sparse + [9] * sparse if in_loops else [])
+    assert grouped(eqns) == (12 if in_loops else 9) * sparse
 
 
 def test_gpt2_grad_step_lowers_to_the_parents_program():

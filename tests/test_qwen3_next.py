@@ -293,6 +293,151 @@ def test_the_kernel_form_lowers_to_a_program_the_scan_forms_size(
     assert len(kernel) < 2 * len(scan), (len(kernel), len(scan))
 
 
+# -- (i-b) what a pass keeps for the way back -----------------------------------
+
+NOTHING_KEPT = jax.checkpoint_policies.nothing_saveable
+
+
+def _gradients(f, *args):
+    """Of a fresh function: a cached trace would read neither the rule
+    nor ``gated_delta.keeps`` again."""
+    return jax.jit(jax.grad(lambda *a: f(*a), range(len(args))))(*args)
+
+
+def _mixer_case():
+    mixer = GatedDeltaNet(64, 8, 12, (0, 4), (0, 8), 4)
+    h = jnp.asarray(np.random.default_rng(12).normal(size=(2, 150, 64)),
+                    jnp.float32)
+    variables = mixer.init(jax.random.PRNGKey(1), h)
+    return (lambda v, h: jnp.sum(jnp.sin(mixer.apply(v, h)))), variables, h
+
+
+@pytest.mark.parametrize("form", ["scan_form", "kernel_form", "mixer",
+                                  "mixer_on_the_kernels"])
+def test_what_a_pass_keeps_changes_no_gradient(form, monkeypatch):
+    """The rule under the checkpoint the mixer gives it (policy
+    ``gated_delta.keeps``: the solve's results, the states the chain's
+    kernel found and ``v'`` stay) against the same with nothing kept,
+    as until PR 57, every gradient BIT FOR BIT: a value kept is the
+    value computed again. In both forms (the kernels interpreted),
+    alone and inside the mixer, whose rule reads ``keeps`` when it is
+    traced; the token recurrence is the oracle of both."""
+    from geomx_tpu.models import qwen3_next
+
+    if form.endswith("_form"):
+        args = _rule_inputs(150, "spread", seed=11)
+
+        def oracle(*a):
+            return jnp.sum(jnp.sin(gated_delta_rule_recurrent(*a)[0]))
+
+        def run(policy):
+            return _gradients(jax.checkpoint(
+                lambda *a: jnp.sum(jnp.sin(gated_delta_rule(*a)[0])),
+                policy=policy), *args)
+    else:
+        loss, *args = _mixer_case()
+
+        def oracle(*a):
+            with monkeypatch.context() as m:
+                m.setattr(qwen3_next, "gated_delta_rule",
+                          lambda *x, dtype: gated_delta_rule_recurrent(*x))
+                return loss(*a)
+
+        def run(policy):
+            with monkeypatch.context() as m:
+                m.setattr(gated_delta, "keeps", policy)
+                return _gradients(loss, *args)
+
+    want = _gradients(oracle, *args)
+    if form in ("kernel_form", "mixer_on_the_kernels"):
+        _forced(monkeypatch)
+    kept, again = run(gated_delta.keeps), run(NOTHING_KEPT)
+    for a, b, c in zip(*map(jax.tree_util.tree_leaves, (kept, again, want))):
+        np.testing.assert_array_equal(a, b)
+        assert float(jnp.abs(c).max()) > 0
+        np.testing.assert_allclose(
+            a, c, rtol=2e-4, atol=2e-5 * float(jnp.abs(c).max()) + 5e-7)
+
+
+def _grad_step_on_the_kernels(cfg, monkeypatch, seq=SEQ):
+    """(the benchmark's ``grad_step`` at the tests' size with the rule
+    forced onto the kernels, the shapes it takes)."""
+    _forced(monkeypatch)
+    names, grad_step = bench_model.build(cfg, seq)
+    shapes = reference.param_shapes(cfg)
+    return (lambda p, x: grad_step(p, x, None)), (
+        [jax.ShapeDtypeStruct(shapes[n], jnp.float32) for n in names],
+        jax.ShapeDtypeStruct((2, seq + 1), jnp.int32))
+
+
+def _grad_step_equations(cfg, monkeypatch, keeps=None, seq=SEQ):
+    """Its equations, inner jaxprs included."""
+    if keeps is not None:
+        monkeypatch.setattr(gated_delta, "keeps", keeps)
+    f, shapes = _grad_step_on_the_kernels(cfg, monkeypatch, seq)
+    return [e for e, _ in _equations(jax.make_jaxpr(f)(*shapes).jaxpr)]
+
+
+def test_grad_step_runs_the_chain_forward_once_a_linear_layer(monkeypatch):
+    """Three linear layers: three forward calls of the chain's kernel
+    (four results) beside three backward ones (six) and three solves'
+    loops, in the whole of ``grad_step``; with nothing kept, as until
+    PR 57, the forward kernel and the solve run once more a layer."""
+    def count(keeps=None):
+        eqns = _grad_step_equations(CUT, monkeypatch, keeps)
+        calls = [len(e.outvars) for e in eqns
+                 if e.primitive.name == "pallas_call"]
+        return (calls.count(4), calls.count(6), len(calls),
+                sum(e.primitive.name == "scan" and e.params["length"]
+                    == gated_delta.SOLVE_BLOCK for e in eqns))
+
+    assert count() == (3, 3, 6, 3)
+    assert count(NOTHING_KEPT) == (6, 3, 9, 6)
+
+
+def test_grad_step_lowers_the_chain_once_for_all_its_layers(monkeypatch):
+    """Lowered for a TPU with the Mosaic calls in, the three linear
+    layers share ONE forward and ONE backward kernel in the text: JAX
+    splits the kernel form's ``jit`` under the checkpoint once, by the
+    policy's identity, so ``gated_delta.keeps`` is one object; a policy
+    made a layer puts the pair into the text a layer (the cell's
+    ``grad_step`` held nine Mosaic calls so, five as it is)."""
+    import geomx_tpu.ops
+
+    monkeypatch.setattr(geomx_tpu.ops, "pallas_interpret", lambda: False)
+    f, shapes = _grad_step_on_the_kernels(CUT, monkeypatch)
+    text = jax.jit(f).trace(*shapes).lower(
+        lowering_platforms=("tpu",)).as_text()
+    assert text.count("tpu_custom_call") == 2
+
+
+@pytest.mark.parametrize("seq,loops", [(SEQ, False), (256, True)],
+                         ids=["one_tile_by_shape", "loop_over_tiles"])
+def test_grad_step_runs_the_experts_forward_once_outside_the_way_back(
+        seq, loops, monkeypatch):
+    """A MoE layer's grouped matmuls (gate, up, down: one group) in the
+    whole of ``grad_step``. Where the cap is all the pairs (450 here)
+    the tile is plain code: one group forward, its six transposed
+    products back, nine a layer. Where it is not (768 pairs a pass, 512
+    a tile) the forward loop over the tiles holds one group and the
+    loop of the way back that group again beside the six
+    (``moe._sum_of_tiles``), and there is no third: the checkpoint that
+    wrapped the routed experts until PR 57 ran the forward group once
+    more before the way back began (twelve a layer; three loops)."""
+    def grouped(jaxpr):
+        return sum(e.primitive.name.startswith("ragged_dot")
+                   for e, _ in _equations(jaxpr))
+
+    layers = CUT["num_hidden_layers"]
+    eqns = _grad_step_equations(CUT, monkeypatch, seq=seq)
+    in_loops = sorted(filter(None, (
+        grouped(e.params["body_jaxpr"].jaxpr) for e in eqns
+        if e.primitive.name == "while")))
+    assert in_loops == ([3] * layers + [9] * layers if loops else [])
+    assert sum(e.primitive.name.startswith("ragged_dot")
+               for e in eqns) == (12 if loops else 9) * layers
+
+
 @pytest.mark.parametrize("dk,dv,chunk,kernel", [
     (128, 128, 64, True), (256, 128, 64, True), (64, 128, 64, False),
     (128, 96, 64, False), (128, 128, 32, False), (128, 128, 128, False)],
