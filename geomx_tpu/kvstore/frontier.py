@@ -123,7 +123,9 @@ def slice_bytes_from_shape(cfg) -> int:
     back to the single-chunk wire when nothing is shaped. Shared by
     the worker store and the server (the server FSA sub-splits its
     canonical ranges at the same budget), so both sides of the wire
-    resolve one auto value from one plan."""
+    resolve one auto value from one plan; and by
+    ``DeviceResidentTrainer``, which cuts its round at this budget
+    where ``P3_SLICE_BYTES`` is 0 and leaves every key whole."""
     from geomx_tpu.ps import shaping as shaping_mod
 
     plan = shaping_mod.plan_from_config(cfg)
@@ -154,7 +156,12 @@ def slice_bytes_from_links(links: Iterable[Sequence[float]],
        auto value once real measurements exist;
     3. ``P3_SLICE_BYTES=-1`` resolves against the declared plan
        (:func:`slice_bytes_from_shape`) until then;
-    4. otherwise 0 — the single-chunk round-5 wire.
+    4. otherwise 0 — the single-chunk round-5 wire, and no key sliced.
+       ``DeviceResidentTrainer`` alone reads 0 as "nothing asked
+       for" and cuts ITS round's whole keys at the declared plan's
+       budget (3. without the re-sharding; one chunk where no global
+       link is declared). Its chunk shapes are compiled when it is
+       built, so 2. never reaches it.
 
     Links with ``rtt_ms`` under ``rtt_floor_ms`` (or without a
     bandwidth estimate yet) contribute nothing: a loopback BDP would

@@ -59,7 +59,8 @@ from typing import Any, Callable, Dict, List, Sequence
 import numpy as np
 
 from geomx_tpu import profiler, runtime, telemetry
-from geomx_tpu.kvstore.frontier import plan_chunks
+from geomx_tpu.kvstore.frontier import (plan_chunks,
+                                        slice_bytes_from_shape)
 from geomx_tpu.ops.select import leaving, topk_flat
 
 __all__ = ["DeviceResidentTrainer"]
@@ -234,11 +235,18 @@ class DeviceResidentTrainer:
         # chunks (~8 bytes per selected element); each chunk's D2H
         # fetch, async combined round and jitted dynamic_update_slice
         # apply flow independently — chunk i applies while chunk i+1's
-        # bytes are still on the wire. 0 = one chunk: one message per
-        # server per round.
+        # bytes are still on the wire. Where nothing was asked for (0)
+        # the budget is the declared party-global link's bandwidth-delay
+        # product: a link's two directions then carry pushes and answers
+        # at once, where one chunk holds each idle while the other
+        # works. No declared link = one chunk: one message per server
+        # per round. The budget is this plan's alone: keys stay whole,
+        # the store and the servers shard as their configuration says.
+        budget = int(getattr(kcfg, "p3_slice_bytes", 0))
+        if budget == 0 and getattr(kcfg, "shape_plan", ""):
+            budget = slice_bytes_from_shape(kcfg)
         chunks = plan_chunks(list(range(len(sizes))),
-                             [8 * kk for kk in ks],
-                             int(getattr(kcfg, "p3_slice_bytes", 0)))
+                             [8 * kk for kk in ks], budget)
         self._chunks = chunks
         # per chunk: selection range, flat param range, upload cap —
         # chunk key runs are contiguous, so each covers one flat
@@ -401,12 +409,14 @@ class DeviceResidentTrainer:
 
     def _book(self, head: np.ndarray) -> float:
         """The loss from the head of a download; grad_fn's counts, if
-        any, go to their telemetry counters, and so does the number of
+        any, go to their telemetry counters, and so do the number of
         keys this round selected by threshold and reset in a dense
-        masked pass (every key: neither has a second path)."""
+        masked pass (every key: neither has a second path) and the
+        number of chunks the round went out in."""
         telemetry.counter_inc("step.select_threshold_keys",
                               len(self._sizes))
         telemetry.counter_inc("step.dense_reset_keys", len(self._sizes))
+        telemetry.counter_inc("trainer.round_chunks", len(self._chunks))
         head = np.atleast_1d(head)
         for name, value in zip(self._aux_names, head[1:]):
             telemetry.counter_inc(name, float(value))

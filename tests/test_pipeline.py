@@ -422,7 +422,7 @@ def test_async_frontier_exact_under_faultplan():
 # device trainer: the chunking of the round does not change its result
 # ---------------------------------------------------------------------------
 
-def _run_trainer(extra_cfg, rounds=8):
+def _run_trainer(extra_cfg, rounds=8, look=None):
     import jax.numpy as jnp
 
     from geomx_tpu.trainer_device import DeviceResidentTrainer
@@ -455,6 +455,8 @@ def _run_trainer(extra_cfg, rounds=8):
                 tr.step(shift, None)
             results[widx] = ([np.asarray(l).copy() for l in tr.leaves],
                              len(tr._chunks))
+            if look is not None:
+                look(kv, tr)
 
         topo.run_workers(worker, include_master=master_init,
                          timeout=300)
@@ -480,6 +482,227 @@ def test_trainer_round_is_chunking_invariant():
     for a, b in zip(one, two):
         np.testing.assert_array_equal(a, b)
     assert any(np.abs(a).sum() > 0 for a in two)
+
+
+# ---------------------------------------------------------------------------
+# device trainer: where nothing was asked for, the round is cut at the
+# declared party-global link's bandwidth-delay product
+# ---------------------------------------------------------------------------
+
+WAN_50MS_100MBIT = json.dumps(
+    {"default": {"tier": "global", "rtt_ms": 50.0, "bw_mbps": 100.0}})
+# 75,000 elements at threshold 0.5: 37,500 entries, 300,000 wire bytes
+TOY_SHAPES = [(75000,), (75000,), (75000,), (75000,), (300,), (75000,)]
+
+
+def _toy_trainer(cfg):
+    """A trainer over the local store, which answers a round with the
+    selection itself, under ``cfg`` as its store's configuration
+    (``None``: the local store as it comes, with none)."""
+    import jax.numpy as jnp
+
+    from geomx_tpu.kvstore import create as kv_create
+    from geomx_tpu.trainer_device import DeviceResidentTrainer
+
+    kv = kv_create("local")
+    if cfg is not None:
+        kv.cfg = cfg
+    return DeviceResidentTrainer(
+        [np.ones(s, np.float32) for s in TOY_SHAPES], kv,
+        lambda leaves, X, y: (sum(jnp.sum(l * l) for l in leaves) * X,
+                              [2 * l * X for l in leaves]),
+        threshold=0.5, learning_rate=0.1)
+
+
+def _plan(*budgets):
+    """The toy keys' cut at each budget, as ``plan_chunks`` says."""
+    wire = [8 * max(int(np.prod(s) * 0.5), 1) for s in TOY_SHAPES]
+    return [[c.items for c in plan_chunks(list(range(len(wire))), wire, b)]
+            for b in budgets]
+
+
+def test_trainer_cuts_its_round_at_the_declared_links_bdp():
+    """50 ms x 100 Mbit/s is 625,000 bytes: two toy keys a chunk, where
+    the floor of 65,536 would give each its own and 0 one for all."""
+    from geomx_tpu.config import Config
+    from geomx_tpu.kvstore.frontier import slice_bytes_from_shape
+
+    cfg = Config(shape_plan=WAN_50MS_100MBIT)
+    assert slice_bytes_from_shape(cfg) == 625000
+    at_bdp, at_floor, whole = _plan(625000, 65536, 0)
+    assert at_bdp == [[0, 1], [2, 3, 4], [5]]
+    assert len(at_floor) == 6 and len(whole) == 1
+    tr = _toy_trainer(cfg)
+    assert [c.items for c in tr._chunks] == at_bdp
+    assert cfg.p3_slice_bytes == 0     # the plan's alone: not written back
+    # every chunk is one flat slice, its upload sized to its own keys
+    assert [m[3] for m in tr._chunk_meta] == [150000, 150300, 75000]
+    assert float(tr.step(np.float32(1.0), None)) > 0
+
+
+@pytest.mark.parametrize("cfg_kw", [
+    None,                               # a local store: no configuration
+    {},                                 # no plan
+    {"shape_plan": json.dumps(          # a plan with no global link
+        {"default": {"tier": "local", "rtt_ms": 50.0, "bw_mbps": 100.0}})},
+    {"shape_plan": json.dumps({"links": []})},
+], ids=["local-store", "no-plan", "lan-only-plan", "empty-plan"])
+def test_trainer_round_is_one_chunk_without_a_declared_global_link(cfg_kw):
+    from geomx_tpu.config import Config
+
+    tr = _toy_trainer(None if cfg_kw is None else Config(**cfg_kw))
+    assert [c.items for c in tr._chunks] == _plan(0)[0]
+
+
+@pytest.mark.parametrize("asked", [300000, 4 << 20])
+def test_an_explicit_budget_wins_over_the_declared_link(asked):
+    from geomx_tpu.config import Config
+
+    tr = _toy_trainer(Config(shape_plan=WAN_50MS_100MBIT,
+                             p3_slice_bytes=asked))
+    assert [c.items for c in tr._chunks] == _plan(asked)[0]
+    assert _plan(asked) != _plan(625000)
+
+
+def test_the_declared_link_cuts_the_round_and_shards_no_key():
+    """Under a plan the trainer's round is in chunks, and that is all:
+    the store's configuration holds the budget it held, and every key
+    has the shards ``KVStoreDist._shards`` gives without one, where
+    ``P3_SLICE_BYTES`` slices them at the budget."""
+    from geomx_tpu.kvstore import sharding
+
+    seen = {}
+
+    def look(kv, tr):
+        seen[id(kv)] = (
+            kv.cfg.p3_slice_bytes, len(tr._chunks),
+            {k: [(s.server_rank, s.offset, s.length) for s in i.shards]
+             for k, i in kv._key_info.items()},
+            {k: [(s.server_rank, s.offset, s.length) for s in
+                 sharding.assign(k, i.total, kv.po.num_servers,
+                                 kv.cfg.bigarray_bound)]
+             for k, i in kv._key_info.items()})
+
+    plan = json.dumps({"default": {"tier": "global", "rtt_ms": 2.0,
+                                   "bw_mbps": 1000.0}})
+    shaped, nchunks = _run_trainer({"shape_plan": plan}, rounds=3,
+                                   look=look)
+    assert nchunks == 1     # 250,000 bytes: the model's 72 fit one chunk
+    assert len(seen) == 2
+    for budget, _n, shards, unsliced in seen.values():
+        assert budget == 0
+        assert shards == unsliced and set(shards) == {0, 1}
+    seen.clear()
+    sliced, nchunks = _run_trainer({"p3_slice_bytes": 8}, rounds=3,
+                                   look=look)
+    assert nchunks == 2
+    for budget, _n, shards, unsliced in seen.values():
+        assert budget == 8 and shards != unsliced
+    for a, b in zip(shaped, sliced):
+        np.testing.assert_array_equal(a, b)
+
+
+BSC_SHAPES = [(8192,), (64, 128), (8000,), (90, 90), (8192,), (33,),
+              (8192,)]
+
+
+def _bsc_leaves():
+    return [np.random.RandomState(3 + i).uniform(-1, 1, s)
+            .astype(np.float32) for i, s in enumerate(BSC_SHAPES)]
+
+
+def _run_bsc_trainer(extra_cfg, workers_per_party, rounds=6):
+    """``rounds`` rounds of the device trainer on 2 parties whose
+    servers re-select with Bi-Sparse for the party-global hop ->
+    (worker 0's leaves, chunks a round, van messages a round over the
+    rounds after the first)."""
+    import jax.numpy as jnp
+
+    from geomx_tpu import telemetry
+    from geomx_tpu.trainer_device import DeviceResidentTrainer
+
+    leaves0 = _bsc_leaves()
+
+    def loss_fn(leaves, X, y):
+        # every worker's gradient its own, every coordinate's its own
+        return (sum(jnp.sum(jnp.sin(l * X) ** 2) for l in leaves),
+                [2 * jnp.sin(l * X) * jnp.cos(l * X) * X for l in leaves])
+
+    topo = InProcessHiPS(num_parties=2,
+                         workers_per_party=workers_per_party,
+                         extra_cfg=extra_cfg).start()
+    n = 2 * workers_per_party
+    meet = threading.Barrier(n)
+    results, sent = {}, []
+
+    def messages():
+        return sum(v for k, v in telemetry.snapshot()["counters"].items()
+                   if k.startswith("van.messages_sent"))
+
+    was_on = telemetry.enabled()
+    telemetry.enable(True)
+    try:
+        def master_init(kv):
+            kv.set_gradient_compression({"type": "bsc", "threshold": 0.5})
+            for i, leaf in enumerate(leaves0):
+                kv.init(i, leaf)
+            kv.wait()
+
+        def worker(kv):
+            widx = topo.workers.index(kv)
+            tr = DeviceResidentTrainer(list(leaves0), kv, loss_fn,
+                                       threshold=0.5, learning_rate=0.05,
+                                       momentum=0.9)
+            X = jnp.asarray(1.0 + 0.25 * widx)
+            for r in range(rounds):
+                tr.step(X, None)
+                meet.wait(60)
+                if widx == 0 and r in (0, rounds - 1):
+                    sent.append(messages())
+                meet.wait(60)
+            results[widx] = ([np.asarray(l).copy() for l in tr.leaves],
+                             len(tr._chunks))
+
+        topo.run_workers(worker, include_master=master_init, timeout=300)
+    finally:
+        topo.stop()
+        telemetry.enable(was_on)
+    assert len(results) == n
+    for w in range(1, n):
+        assert results[w][1] == results[0][1]
+        for a, b in zip(results[0][0], results[w][0]):
+            np.testing.assert_array_equal(a, b)
+    return (results[0][0], results[0][1],
+            (sent[1] - sent[0]) / (rounds - 1))
+
+
+@pytest.mark.time_limit(300)
+@pytest.mark.parametrize("workers_per_party", [1, 2])
+def test_trainer_round_under_a_declared_link_is_the_one_chunk_round(
+        workers_per_party):
+    """The arithmetic with the party servers' Bi-Sparse ON: a declared
+    link whose bandwidth-delay product clamps to the floor of 65,536
+    bytes cuts seven keys of some 8k elements (32 KB of selection each
+    at threshold 0.5) into four chunks, every chunk its own message on
+    both hops and its own pass of the party servers' selection, and the
+    leaves are the one-chunk round's bit for bit: a key's boundary
+    sample comes out of the server's one generator in the order keys
+    complete, which chunks in layer order on FIFO links keep."""
+    plan = json.dumps({"default": {"tier": "global", "rtt_ms": 1.0,
+                                   "bw_mbps": 100.0}})
+    one, nchunks, msgs_one = _run_bsc_trainer({}, workers_per_party)
+    assert nchunks == 1
+    cut, nchunks, msgs_cut = _run_bsc_trainer({"shape_plan": plan},
+                                              workers_per_party)
+    assert nchunks == 4
+    # a chunk: a push and its answer a worker, a forward and its answer
+    # a party
+    a_chunk = 2 * 2 * workers_per_party + 2 * 2
+    assert msgs_one == a_chunk and msgs_cut == a_chunk * nchunks
+    for a, b in zip(one, cut):
+        np.testing.assert_array_equal(a, b)
+    assert all(np.abs(a - l0).sum() > 0
+               for a, l0 in zip(cut, _bsc_leaves()))
 
 
 # ---------------------------------------------------------------------------

@@ -315,6 +315,61 @@ def test_the_trainers_key_counters_are_what_their_metric_files_read():
         telemetry.enable(was_on)
 
 
+def test_the_rounds_chunk_counter_is_what_its_metric_file_reads():
+    """``trainer.round_chunks`` moves by ``len(tr._chunks)`` a round,
+    once, whatever the number of keys; ``round.trainer_chunks`` is its
+    data file, in the manifest with no list of cells (every cell runs a
+    trainer)."""
+    import jax.numpy as jnp
+
+    from geomx_tpu.config import Config
+    from geomx_tpu.kvstore import create as kv_create
+    from geomx_tpu.trainer_device import DeviceResidentTrainer
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "BENCHMARK.json")) as f:
+        entry = {m["name"]: m for m in json.load(f)["per_layer"]}[
+            "round.trainer_chunks"]
+    with open(os.path.join(root, "benchmark", "layer_metrics",
+                           "round.trainer_chunks.json")) as f:
+        spec = json.load(f)
+    assert entry == {"name": "round.trainer_chunks", "unit": "count",
+                     "better": "higher", "source": "program_counter",
+                     "layer": "trainer", "moves": "tokens_per_s_per_chip"}
+    assert all(entry[k] == spec[k] for k in entry if k != "better")
+    assert (spec["reader"], spec["prefix"]) == ("counter_per_round",
+                                                "trainer.round_chunks")
+    assert "must_contain" not in spec and "zero_with" not in spec
+
+    def rounds_of(cfg):
+        kv = kv_create("local")
+        kv.cfg = cfg
+        tr = DeviceResidentTrainer(
+            [np.ones(s, np.float32) for s in [(40, 16), (129,), (7,)]],
+            kv, lambda leaves, X, y: (
+                sum(jnp.sum(l * l) for l in leaves) * X,
+                [2 * l * X for l in leaves]),
+            threshold=0.1, learning_rate=0.1)
+
+        def read():
+            return sum(v for k, v in telemetry.snapshot()["counters"]
+                       .items() if k.startswith(spec["prefix"]))
+
+        before = read()
+        for rounds in (1, 2, 3):
+            tr.step(jnp.asarray(0.5), None)
+            assert read() - before == len(tr._chunks) * rounds
+        return len(tr._chunks)
+
+    was_on = telemetry.enabled()
+    telemetry.enable(True)
+    try:
+        assert rounds_of(Config()) == 1
+        assert rounds_of(Config(p3_slice_bytes=96)) == 3
+    finally:
+        telemetry.enable(was_on)
+
+
 def test_retired_surface_is_gone():
     from geomx_tpu import telemetry
 
