@@ -58,14 +58,6 @@ log = logging.getLogger("geomx.dist")
 _INT32_MAX = np.iinfo(np.int32).max
 
 
-def _give_up_exc(errs) -> type:
-    """Exception class for surfacing transport give-ups — one mapping,
-    shared with RoundFuture (kvstore.frontier.give_up_exc): "declared
-    dead" raises WorkerLostError, a blown PS_RESEND_DEADLINE is a
-    TimeoutError, retry-cap give-ups stay RuntimeError."""
-    return give_up_exc(errs)
-
-
 def _wire_decode(kvs, i: int) -> np.ndarray:
     """Decode dense response entry ``i`` of ``kvs`` to flat float32:
     the combined-wire server echoes the requester's codec on its acks
@@ -93,6 +85,237 @@ class _KeyInfo:
         self.shape = shape
         self.dtype = dtype
         self.shards = shards
+
+
+_NO_AUX = object()   # _ServerRound.add: the entry has no aux part
+_NO_VALS = np.zeros(0, np.float32)   # ... and a pull's has no values
+
+
+class _ServerRound:
+    """The round every batched verb of the store runs, written once.
+
+    A verb hands it (key, shard) entries already in wire form
+    (:meth:`add`: one ``KVPairs`` a (chunk, server)) and two callables:
+    ``part_of(kvs, i)`` makes entry ``i`` of a response into a part of
+    its key's result, ``finish(key, parts)`` makes a key's parts into
+    the result (and fills the caller's ``out``). ``part_of=None`` is a
+    plain push: its acks carry no data and complete no key.
+    :meth:`send` then owns what the verbs share:
+
+    - ``_push_acks_left`` / ``_track`` go up one an ENTRY before the
+      first message leaves and come down one an entry as a message's
+      terminal response lands, so a pull of one of the keys
+      (``_issue_after_push_acks``) goes out after the round's last ack;
+      a round of pulls (``push=False``) defers each message the same
+      way and registers no push;
+    - a failed message is re-sent AS IT IS while its chunk's budget
+      lasts (``cfg.chunk_retries``; the same ``KVPairs``, encoded once,
+      so a 2-bit residual drains once), never to a peer "declared
+      dead"; past that the give-up is recorded once a key, in the
+      store's ``_transport_errors`` AND the round's future: the future
+      consumes its own when joined (``_consume_errors``), ``wait()``
+      raises what no future took;
+    - a key is finished when every message that carries it has its
+      terminal response. With an error it completes, so joins raise.
+      With fewer parts than entries sent (a server acked without
+      data) it is NEVER finished from what it has, which would copy
+      zeros over the caller's parameters or pass an empty aggregate
+      for the round's: it is pulled again, once, at the caller's
+      priority and under the round's id, without blocking (this runs
+      on a transport thread); a second short answer is an error.
+
+    Response data is decoded and ``finish`` runs BEFORE the ack
+    bookkeeping: the last ``_untrack`` releases ``wait()``, which must
+    find ``out`` filled. The future's methods are called outside the
+    store's ``_lock``."""
+
+    def __init__(self, kv, verb: str, keys, priority: int,
+                 part_of: Optional[Callable] = None,
+                 finish: Optional[Callable] = None, *, rid: int = -1,
+                 push: bool = True, pull_compr: str = "",
+                 fut: Optional[RoundFuture] = None, repull: bool = True):
+        self.kv = kv
+        self.verb = verb            # names the round in errors and logs
+        self.priority = priority
+        self.part_of = part_of
+        self.finish = finish
+        self.rid = rid
+        self.push = push
+        self.pull_compr = pull_compr    # the tag a re-pull asks under
+        self.repull = repull    # a short answer is pulled again, once
+        # a combined round's messages are the chunks the trace follows
+        self.traced = push and part_of is not None
+        self.fut = fut if fut is not None else RoundFuture(
+            keys, consume=kv._consume_errors,
+            max_retries=kv.cfg.chunk_retries, on_abort=kv._abort_round)
+        self.fut.trace_round = rid
+        self.msgs: List[tuple] = []     # (cid, srank, kvs, priority)
+        self._open: Dict[tuple, KVPairs] = {}
+        self.expected: Dict[int, int] = {}   # entries sent, a key
+        self.left: Dict[int, int] = {}       # ... and not yet answered
+        self.parts: Dict[int, List] = {k: [] for k in keys}
+
+    def add(self, cid: int, priority: int, key: int, sh: sharding.Shard,
+            val=None, aux=_NO_AUX, compr: str = "",
+            length: Optional[int] = None) -> None:
+        """Entry (``key``, ``sh``) onto chunk ``cid``'s message for the
+        shard's server, opened under ``compr`` at ``priority`` by its
+        first entry. ``val`` / ``aux`` are the wire's own arrays."""
+        kvs = self._open.get((cid, sh.server_rank))
+        if kvs is None:
+            kvs = self._open[(cid, sh.server_rank)] = KVPairs(compr=compr)
+            self.msgs.append((cid, sh.server_rank, kvs, priority))
+        self.expected[key] = self.expected.get(key, 0) + 1
+        kvs.keys.append(key)
+        kvs.vals.append(_NO_VALS if val is None else val)
+        if aux is not _NO_AUX:
+            kvs.aux.append(aux)
+        kvs.offsets.append(sh.offset)
+        kvs.totals.append(sh.total)
+        kvs.lens.append(sh.length if length is None else length)
+
+    def send(self, order: Optional[Callable] = None) -> RoundFuture:
+        """Register the round, then send its messages: in the order
+        they were opened, or sorted by ``order(msg)``."""
+        kv = self.kv
+        with kv._lock:
+            self.left = dict(self.expected)
+            if self.push:
+                for k, n in self.expected.items():
+                    kv._push_acks_left[k] = (
+                        kv._push_acks_left.get(k, 0) + n)
+        for k, n in self.expected.items():
+            kv._track(n, k)
+        mids = range(len(self.msgs))
+        if order is not None:
+            mids = sorted(mids, key=lambda m: order(self.msgs[m]))
+        for mid in mids:
+            cid, srank, kvs, _p = self.msgs[mid]
+            if not self.push:
+                # the request must not go out until EVERY key in it has
+                # its push round acked (the freshness ordering, batched)
+                kv._issue_after_push_acks(
+                    set(kvs.keys), lambda m=mid: self._issue(m))
+            elif self.traced:
+                with profiler.scope("pipeline:send", cat="pipeline",
+                                    chunk=cid, server=srank,
+                                    keys=len(kvs.keys),
+                                    **kv.po.van.round_args(self.rid)):
+                    self._issue(mid)
+            else:
+                self._issue(mid)
+        return self.fut
+
+    def _issue(self, mid: int) -> None:
+        cid, srank, kvs, prio = self.msgs[mid]
+        cb = (lambda ts: self._on_resp(ts, mid))
+        if self.push:
+            self.kv.kvw.push(kvs, srank, priority=prio,
+                             pull=self.part_of is not None,
+                             trace_round=self.rid,
+                             trace_chunk=cid if self.traced else -1,
+                             cb=cb)
+        else:
+            self.kv.kvw.pull(kvs.keys, srank, offsets=kvs.offsets,
+                             totals=kvs.totals, lens=kvs.lens,
+                             priority=prio, compr=kvs.compr,
+                             aux=kvs.aux, trace_round=self.rid, cb=cb)
+
+    def _on_resp(self, ts: int, mid: int) -> None:
+        if not self.traced:
+            return self._take(ts, mid)
+        # a response into its keys' results: decode, join the parts,
+        # complete the keys the caller waits for
+        cid, srank, _kvs, _p = self.msgs[mid]
+        with profiler.scope("pipeline:recv", cat="pipeline", chunk=cid,
+                            server=srank,
+                            **self.kv.po.van.round_args(self.rid)):
+            self._take(ts, mid)
+
+    def _fail(self, keys, reason: str) -> None:
+        errs = [(k, f"{self.verb} key {k}: {reason}") for k in keys]
+        with self.kv._lock:
+            self.kv._transport_errors.extend(e for _k, e in errs)
+        for k, err in errs:
+            self.fut.add_error(k, err)
+
+    def _take(self, ts: int, mid: int) -> None:
+        kv, fut = self.kv, self.fut
+        cid, srank, kvs, _p = self.msgs[mid]
+        fail = kv.kvw.take_failure(ts)
+        # bounded per-chunk retry (PS_CHUNK_RETRIES): the bookkeeping
+        # (left, push acks, tracking) stays registered until a terminal
+        # response lands. "declared dead" never retries: that peer is
+        # gone for the epoch; surface WorkerLostError.
+        if (fail is not None and "declared dead" not in fail
+                and fut.retry_budget(cid)):
+            log.warning("%s chunk %d to server %d failed (%s); retry "
+                        "%d/%d", self.verb, cid, srank, fail,
+                        fut.retries_used(cid), fut.max_retries)
+            telemetry.event("chunk.retry", cat="kvstore", chunk=cid,
+                            server=srank)
+            telemetry.counter_inc("chunk.retries")
+            self._issue(mid)
+            return
+        mks = kvs.keys
+        if fail is not None:
+            self._fail(sorted(set(mks)), fail)
+        finished = []
+        if self.part_of is not None:
+            got = [(k, self.part_of(r, i))
+                   for r in kv.kvw.take_response(ts)
+                   for i, k in enumerate(r.keys)]
+            with kv._lock:
+                for k, part in got:
+                    self.parts[k].append(part)
+                for k in mks:
+                    self.left[k] -= 1
+                    if self.left[k] == 0:
+                        finished.append((k, self.parts[k]))
+        done, short = [], []
+        for k, ps in finished:
+            if fut.errors(k):
+                # data is never coming: complete so joins raise
+                done.append((k, None))
+            elif len(ps) < self.expected[k]:
+                short.append(k)
+            else:
+                done.append((k, self.finish(k, ps)))
+        if short and self.repull:
+            self._pull_again(short)
+        elif short:
+            self._fail(short, "answered without data")
+            done.extend((k, None) for k in short)
+        # the ack also advances the push-ordering bookkeeping, so a
+        # later plain pull stays ordered after this round
+        ready = []
+        if self.push:
+            with kv._lock:
+                for k in mks:
+                    kv._push_acks_left[k] -= 1
+                    if (kv._push_acks_left[k] == 0
+                            and k in kv._deferred):
+                        ready.extend(kv._deferred.pop(k))
+        for k in mks:
+            kv._untrack(k)
+        for fn in ready:
+            fn()
+        for k, result in done:
+            fut.complete_key(k, result)
+
+    def _pull_again(self, keys) -> None:
+        """Pull every shard of ``keys`` again as a round of its own on
+        this round's future: tracked before this message's entries are
+        released, sent once the keys' push acks are in."""
+        again = _ServerRound(
+            self.kv, self.verb + " re-pull", keys, self.priority,
+            self.part_of, self.finish, rid=self.rid, push=False,
+            pull_compr=self.pull_compr, fut=self.fut, repull=False)
+        for k in keys:
+            for sh in self.kv._key_info[k].shards:
+                again.add(-1, self.priority, k, sh,
+                          compr=self.pull_compr)
+        again.send()
 
 
 class KVStoreDist(KVStore):
@@ -337,15 +560,6 @@ class KVStoreDist(KVStore):
             # loudly here rather than hanging in wait()
             if len(set(keys)) != len(keys):
                 raise ValueError("push: duplicate keys in one round")
-            if self._ts is None and not self.cfg.enable_p3:
-                # list form = batched wire: ONE message per server
-                # carrying every (key, shard) entry for it, acked once
-                # (the server merges per-key acks —
-                # kvstore.server._BatchResponder). Cuts the per-round
-                # message count from 2*n_keys to 2*n_servers.
-                self._push_batch(keys, values, priority,
-                                 trace_round=trace_round)
-                return
             if self.cfg.enable_p3:
                 # P3 wants per-key messages so the priority send thread
                 # can interleave layers: list order IS layer order, so
@@ -355,6 +569,12 @@ class KVStoreDist(KVStore):
                     self.push(k, v, priority=priority - i,
                               trace_round=trace_round)
                 return
+        # list form = batched wire: ONE message per server carrying
+        # every (key, shard) entry for it, acked once (the server merges
+        # per-key acks — kvstore.server._BatchResponder): 2*n_servers
+        # messages a round, not 2*n_keys. One key goes a message a shard.
+        batched = len(keys) > 1
+        rnd = _ServerRound(self, "push", keys, priority, rid=trace_round)
         for k, v in zip(keys, values):
             merged = _sum_values(v)
             info = self._info(k, merged)
@@ -366,67 +586,10 @@ class KVStoreDist(KVStore):
                 self._track(1, k)
                 self._ts.contribute(k, 0, info.total, flat, ver)
                 continue
-            with self._lock:
-                self._push_acks_left[k] = (
-                    self._push_acks_left.get(k, 0) + len(info.shards))
-            self._track(len(info.shards), k)
-            for sh in info.shards:
-                kvs = KVPairs(keys=[k],
-                              vals=[flat[sh.offset:sh.offset + sh.length]],
-                              offsets=[sh.offset], totals=[sh.total],
-                              lens=[sh.length])
-                self.kvw.push(kvs, sh.server_rank, priority=priority,
-                              trace_round=trace_round,
-                              cb=lambda ts, kk=k: self._on_push_ack(kk, ts))
-
-    def _push_batch(self, keys: List[int], values, priority: int,
-                    trace_round: int = -1) -> None:
-        per_server: Dict[int, KVPairs] = {}
-        server_keys: Dict[int, List[int]] = {}
-        for k, v in zip(keys, values):
-            merged = _sum_values(v)
-            info = self._info(k, merged)
-            flat = np.ascontiguousarray(merged).ravel()
-            for sh in info.shards:
-                kvs = per_server.setdefault(sh.server_rank, KVPairs())
-                kvs.keys.append(k)
-                kvs.vals.append(flat[sh.offset:sh.offset + sh.length])
-                kvs.offsets.append(sh.offset)
-                kvs.totals.append(sh.total)
-                kvs.lens.append(sh.length)
-                server_keys.setdefault(sh.server_rank, []).append(k)
-        # per-(server, shard) ack bookkeeping, then one message per server
-        with self._lock:
-            for ks in server_keys.values():
-                for k in ks:
-                    self._push_acks_left[k] = (
-                        self._push_acks_left.get(k, 0) + 1)
-        for ks in server_keys.values():
-            for k in ks:
-                self._track(1, k)
-        for srank, kvs in per_server.items():
-            ks = tuple(server_keys[srank])
-            self.kvw.push(kvs, srank, priority=priority,
-                          trace_round=trace_round,
-                          cb=lambda ts, kk=ks:
-                          self._on_batch_push_ack(kk, ts))
-
-    def _on_batch_push_ack(self, keys, ts: int) -> None:
-        fail = self.kvw.take_failure(ts)
-        if fail is not None:
-            with self._lock:
-                self._transport_errors.append(
-                    f"push keys {list(keys)}: {fail}")
-        ready = []
-        with self._lock:
-            for k in keys:
-                self._push_acks_left[k] -= 1
-                if self._push_acks_left[k] == 0 and k in self._deferred:
-                    ready.extend(self._deferred.pop(k))
-        for k in keys:
-            self._untrack(k)
-        for fn in ready:
-            fn()
+            for i, sh in enumerate(info.shards):
+                rnd.add(0 if batched else i, priority, k, sh,
+                        flat[sh.offset:sh.offset + sh.length])
+        rnd.send()      # of no message under TSEngine
 
     def _ts_final_push(self, key: int, off: int, total: int,
                        arr: np.ndarray, num_merge: int, ver: int) -> None:
@@ -452,38 +615,20 @@ class KVStoreDist(KVStore):
             self.kvw.push(kvs, sh.server_rank, num_merge=num_merge,
                           cb=on_ack)
 
-    def _on_push_ack(self, key: int, ts: int) -> None:
-        fail = self.kvw.take_failure(ts)
-        if fail is not None:
-            # record and fall through: the ack bookkeeping must still
-            # advance (a wedged counter would hang wait() silently) and
-            # wait() raises the recorded error
-            with self._lock:
-                self._transport_errors.append(f"push key {key}: {fail}")
-        ready = []
-        with self._lock:
-            self._push_acks_left[key] -= 1
-            if self._push_acks_left[key] == 0 and key in self._deferred:
-                ready = self._deferred.pop(key)
-        self._untrack(key)
-        for fn in ready:
-            fn()
-
     def push_pull(self, key, value, out, priority: int = 0) -> None:
         """Combined push+pull (reference: ZPushPull, kv_app.h:140): ONE
         request per server per round — the ack carries the post-round
         parameters, eliminating the separate pull round-trip. Semantics
         match push(list) followed by pull(list, out=...): ``out`` fills
-        with the post-round state; join with wait().
+        with the post-round state; join with wait(). The round is
+        :meth:`push_pull_async`'s (one body, ``_dense_round``), sent as
+        one chunk on the raw wire with its future dropped, so a give-up
+        surfaces from wait().
 
         Falls back to the two-op sequence for single keys, TSEngine
         overlays (models disseminate out-of-band) and P3 (per-key
         priority interleaving wants separate messages)."""
         keys = self._as_key_list(key)
-        values = value if isinstance(value, (list, tuple)) \
-            and len(keys) > 1 else [value]
-        outs = out if isinstance(out, (list, tuple)) and len(keys) > 1 \
-            else [out]
         if (len(keys) == 1 or self._ts is not None
                 or self.cfg.enable_p3):
             # still one logical round: both legs carry the same trace id
@@ -491,109 +636,8 @@ class KVStoreDist(KVStore):
             self.push(key, value, priority=priority, trace_round=rid)
             self.pull(key, out=out, priority=priority, trace_round=rid)
             return
-        if len(set(keys)) != len(keys):
-            raise ValueError("push_pull: duplicate keys in one round")
-        for o in outs:
-            if not (isinstance(o, np.ndarray) and o.flags.writeable):
-                raise TypeError(
-                    "push_pull requires writable numpy ndarrays")
-        rid = self._begin_round()
-        per_server: Dict[int, KVPairs] = {}
-        server_keys: Dict[int, List[int]] = {}
-        for k, v in zip(keys, values):
-            merged = _sum_values(v)
-            info = self._info(k, merged)
-            flat = np.ascontiguousarray(merged).ravel()
-            for sh in info.shards:
-                kvs = per_server.setdefault(sh.server_rank, KVPairs())
-                kvs.keys.append(k)
-                kvs.vals.append(flat[sh.offset:sh.offset + sh.length])
-                kvs.offsets.append(sh.offset)
-                kvs.totals.append(sh.total)
-                kvs.lens.append(sh.length)
-                server_keys.setdefault(sh.server_rank, []).append(k)
-        bufs = {k: np.zeros(self._key_info[k].total, np.float32)
-                for k in keys}
-        out_of = dict(zip(keys, outs))
-        msgs_left: Dict[int, int] = {}
-        with self._lock:
-            for srank, ks in server_keys.items():
-                for k in set(ks):
-                    msgs_left[k] = msgs_left.get(k, 0) + 1
-            for ks in server_keys.values():
-                for k in ks:
-                    self._push_acks_left[k] = (
-                        self._push_acks_left.get(k, 0) + 1)
-        for ks in server_keys.values():
-            for k in ks:
-                self._track(1, k)
-
-        got_data: set = set()
-
-        def on_resp(ts: int, srank: int):
-            # scatter the response data BEFORE the ack bookkeeping: the
-            # final untrack releases wait(), which must observe outs
-            fail = self.kvw.take_failure(ts)
-            if fail is not None:
-                with self._lock:
-                    self._transport_errors.append(
-                        f"push_pull keys "
-                        f"{sorted(set(server_keys[srank]))}: {fail}")
-            finished = []
-            for kvs in self.kvw.take_response(ts):
-                for i, k in enumerate(kvs.keys):
-                    data = _wire_decode(kvs, i)
-                    r_off = kvs.offset_of(i)
-                    buf = bufs[k]
-                    n = min(data.size, buf.size - r_off)
-                    buf[r_off:r_off + n] = data[:n]
-                    with self._lock:
-                        got_data.add((k, srank))
-            with self._lock:
-                for k in set(server_keys[srank]):
-                    msgs_left[k] -= 1
-                    if msgs_left[k] == 0:
-                        finished.append(k)
-            fallback = []
-            for k in finished:
-                with self._lock:
-                    complete = all((k, sr) in got_data
-                                   for sr, ks in server_keys.items()
-                                   if k in ks)
-                if complete:
-                    info = self._key_info[k]
-                    np.copyto(out_of[k], bufs[k].reshape(info.shape)
-                              .astype(info.dtype, copy=False))
-                else:
-                    # a server acked without data (e.g. a range the
-                    # store doesn't hold): NEVER copy the zero-filled
-                    # buffer over the caller's params — fall back to an
-                    # explicit pull for this key, at the caller's own
-                    # priority so the retry doesn't queue behind traffic
-                    # the original request was meant to beat
-                    fallback.append(k)
-            if fallback:
-                self._pull_batch(fallback,
-                                 [out_of[k] for k in fallback], priority,
-                                 trace_round=rid)
-            # the ack also advances the push-ordering bookkeeping so a
-            # subsequent plain pull stays ordered after this round
-            ready = []
-            with self._lock:
-                for k in server_keys[srank]:
-                    self._push_acks_left[k] -= 1
-                    if (self._push_acks_left[k] == 0
-                            and k in self._deferred):
-                        ready.extend(self._deferred.pop(k))
-            for k in server_keys[srank]:
-                self._untrack(k)
-            for fn in ready:
-                fn()
-
-        for srank, kvs in per_server.items():
-            self.kvw.push(kvs, srank, priority=priority, pull=True,
-                          trace_round=rid,
-                          cb=lambda ts, s=srank: on_resp(ts, s))
+        self._dense_round("push_pull", keys, value, out, priority,
+                          slice_bytes=0, coded=False)
 
     def _consume_errors(self, errs: List[str]) -> None:
         """RoundFuture consume hook: the future raised these give-ups,
@@ -606,7 +650,8 @@ class KVStoreDist(KVStore):
     def push_pull_async(self, key, value, out, priority: int = 0,
                         slice_bytes: Optional[int] = None) -> RoundFuture:
         """Non-blocking chunked combined round (the P3-pipelined form of
-        :meth:`push_pull`): the (key, shard) entry list — layer order
+        :meth:`push_pull`, and the same body): the (key, shard) entry
+        list — layer order
         preserved — splits into ~``slice_bytes``-byte chunks (default
         ``cfg.p3_slice_bytes``; <= 0 means one chunk), each chunk ONE
         message per server at descending priority, every chunk's send
@@ -624,17 +669,27 @@ class KVStoreDist(KVStore):
         if self._ts is not None:
             raise NotImplementedError(
                 "push_pull_async is not supported on TSEngine overlays")
-        keys = self._as_key_list(key)
+        return self._dense_round("push_pull_async",
+                                 self._as_key_list(key), value, out,
+                                 priority, slice_bytes, coded=True)
+
+    def _dense_round(self, verb: str, keys, value, out, priority: int,
+                     slice_bytes: Optional[int],
+                     coded: bool) -> RoundFuture:
+        """The dense combined round, the one body of :meth:`push_pull`
+        and :meth:`push_pull_async`. ``coded`` lets GEOMX_WIRE_CODEC and
+        the transport controller's plan choose each chunk's codec and
+        the chunk budget; without it every chunk goes raw."""
         values = value if isinstance(value, (list, tuple)) \
             and len(keys) > 1 else [value]
         outs = out if isinstance(out, (list, tuple)) and len(keys) > 1 \
             else [out]
         if len(set(keys)) != len(keys):
-            raise ValueError("push_pull_async: duplicate keys in one round")
+            raise ValueError(f"{verb}: duplicate keys in one round")
         for o in outs:
             if not (isinstance(o, np.ndarray) and o.flags.writeable):
                 raise TypeError(
-                    "push_pull_async requires writable numpy ndarrays")
+                    f"{verb} requires writable numpy ndarrays")
         rid = self._begin_round()
         # self-tuning transport: one plan per round, computed from the
         # freshest link estimates. It can re-size the chunk budget to
@@ -642,13 +697,13 @@ class KVStoreDist(KVStore):
         # intent) and override the per-server codec below. None when
         # the controller is off: everything stays bit-for-bit static.
         tplan = (self._controller.plan(rid)
-                 if self._controller is not None else None)
+                 if coded and self._controller is not None else None)
         sb = self.cfg.p3_slice_bytes if slice_bytes is None else slice_bytes
         if tplan is not None and slice_bytes is None \
                 and tplan.slice_bytes > 0:
             sb = tplan.slice_bytes
-        wire_on = self._wire.enabled() \
-            or (tplan is not None and tplan.has_codecs())
+        policy = coded and self._wire.enabled()
+        wire_on = policy or (tplan is not None and tplan.has_codecs())
         # layer-ordered (key, shard, flat-segment) entry list
         entries = []
         for k, v in zip(keys, values):
@@ -668,21 +723,13 @@ class KVStoreDist(KVStore):
             list(range(len(entries))),
             [int(e[2].size) * 4 for e in entries],
             sb, base_priority=priority,
-            codec_for=self._wire.chunk_codec
-            if self._wire.enabled() else None)
-        fut = RoundFuture(keys, consume=self._consume_errors,
-                          max_retries=self.cfg.chunk_retries,
-                          on_abort=self._abort_round)
-        bufs = {k: np.zeros(self._key_info[k].total, np.float32)
-                for k in keys}
-        out_of = dict(zip(keys, outs))
+            codec_for=self._wire.chunk_codec if policy else None)
         # one message per (chunk, server); a key completes when every
         # message carrying one of its entries has responded with data
-        msgs = []  # (mid, cid, srank, kvs, msg_keys, chunk_priority)
-        key_msgs: Dict[int, List[int]] = {k: [] for k in keys}
+        part_of, finish, _bufs = self._dense_sink(keys, outs)
+        rnd = _ServerRound(self, verb, keys, priority, part_of, finish,
+                           rid=rid)
         for ch in chunks:
-            per_server: Dict[int, KVPairs] = {}
-            server_keys: Dict[int, List[int]] = {}
             ch_elems = sum(int(entries[ei][2].size) for ei in ch.items)
             for ei in ch.items:
                 k, sh, seg = entries[ei]
@@ -693,146 +740,53 @@ class KVStoreDist(KVStore):
                 codec = ch.codec if tplan is None else tplan.wire_tag(
                     psbase.server_rank_to_id(sh.server_rank),
                     ch.codec, ch_elems)
-                kvs = per_server.setdefault(
-                    sh.server_rank, KVPairs(compr=codec))
-                kvs.keys.append(k)
-                if kvs.compr:
-                    # encode ONCE at message build: chunk retries below
-                    # resend these bytes, so the 2-bit residual for
-                    # (key, offset) drains exactly once per round
+                if codec:
+                    # encode ONCE at message build: a chunk's retry
+                    # resends these bytes, so the 2-bit residual for
+                    # (key, offset) drains exactly once per round. The
+                    # aux part always goes (None for fp16): the server's
+                    # push decompress indexes aux[i] positionally
                     wv, aux, _tag = self._wire.encode(
-                        kvs.compr, seg, (k, sh.offset))
-                    kvs.vals.append(wv)
-                    # always append (None for fp16): the server's push
-                    # decompress indexes aux[i] positionally
-                    kvs.aux.append(aux)
+                        codec, seg, (k, sh.offset))
+                    rnd.add(ch.cid, ch.priority, k, sh, wv, aux, codec)
                 else:
-                    kvs.vals.append(np.asarray(seg))
-                kvs.offsets.append(sh.offset)
-                kvs.totals.append(sh.total)
-                kvs.lens.append(sh.length)
-                server_keys.setdefault(sh.server_rank, []).append(k)
-            for srank, kvs in per_server.items():
-                mid = len(msgs)
-                for k in set(server_keys[srank]):
-                    key_msgs[k].append(mid)
-                msgs.append((mid, ch.cid, srank, kvs,
-                             server_keys[srank], ch.priority))
-        msgs_left = {k: len(key_msgs[k]) for k in keys}
-        with self._lock:
-            for _mid, _cid, _srank, _kvs, mks, _p in msgs:
-                for k in mks:
-                    self._push_acks_left[k] = (
-                        self._push_acks_left.get(k, 0) + 1)
-        for _mid, _cid, _srank, _kvs, mks, _p in msgs:
-            for k in mks:
-                self._track(1, k)
-
-        got_data: set = set()
-
-        def on_resp(ts: int, mid: int):
-            _m, cid, srank, m_kvs, mks, m_prio = msgs[mid]
-            fail = self.kvw.take_failure(ts)
-            # bounded per-chunk retry (PS_CHUNK_RETRIES): transient
-            # give-ups re-issue the identical message — bookkeeping
-            # (msgs_left, push acks, tracking) stays registered until a
-            # terminal response lands. "declared dead" never retries:
-            # that peer is gone for the epoch; surface WorkerLostError.
-            if (fail is not None and "declared dead" not in fail
-                    and fut.retry_budget(cid)):
-                log.warning("push_pull_async chunk %d to server %d "
-                            "failed (%s); retry %d/%d", cid, srank,
-                            fail, fut.retries_used(cid), fut.max_retries)
-                telemetry.event("chunk.retry", cat="kvstore",
-                                chunk=cid, server=srank)
-                telemetry.counter_inc("chunk.retries")
-                self.kvw.push(m_kvs, srank, priority=m_prio, pull=True,
-                              trace_round=rid, trace_chunk=cid,
-                              cb=lambda ts2, m=mid: on_resp(ts2, m))
-                return
-            failed_keys = []
-            if fail is not None:
-                with self._lock:
-                    for k in sorted(set(mks)):
-                        err = f"push_pull_async key {k}: {fail}"
-                        self._transport_errors.append(err)
-                        failed_keys.append((k, err))
-            for k, err in failed_keys:
-                fut.add_error(k, err)   # future methods outside _lock
-            finished = []
-            with profiler.scope("pipeline:recv", cat="pipeline",
-                                chunk=cid, server=srank,
-                                **self.po.van.round_args(rid)):
-                for kvs in self.kvw.take_response(ts):
-                    for i, k in enumerate(kvs.keys):
-                        data = _wire_decode(kvs, i)
-                        r_off = kvs.offset_of(i)
-                        buf = bufs[k]
-                        n = min(data.size, buf.size - r_off)
-                        buf[r_off:r_off + n] = data[:n]
-                        with self._lock:
-                            got_data.add((k, mid))
-            with self._lock:
-                for k in set(mks):
-                    msgs_left[k] -= 1
-                    if msgs_left[k] == 0:
-                        finished.append(k)
-            fallback = []
-            completed = []
-            for k in finished:
-                with self._lock:
-                    complete = all((k, m) in got_data
-                                   for m in key_msgs[k])
-                if complete:
-                    info = self._key_info[k]
-                    np.copyto(out_of[k], bufs[k].reshape(info.shape)
-                              .astype(info.dtype, copy=False))
-                    completed.append(k)
-                elif fut.errors(k):
-                    # data is never coming (transport gave up): complete
-                    # so joins raise the error instead of timing out
-                    completed.append(k)
-                else:
-                    # a server acked without data — same no-zero-copyback
-                    # rule as push_pull: explicit async re-pull, future
-                    # completes when the out array holds real data
-                    fallback.append(k)
-            if fallback:
-                self._pull_batch(fallback,
-                                 [out_of[k] for k in fallback], priority,
-                                 on_key=fut.complete_key, trace_round=rid)
-            ready = []
-            with self._lock:
-                for k in mks:
-                    self._push_acks_left[k] -= 1
-                    if (self._push_acks_left[k] == 0
-                            and k in self._deferred):
-                        ready.extend(self._deferred.pop(k))
-            for k in mks:
-                self._untrack(k)
-            for fn in ready:
-                fn()
-            for k in completed:
-                fut.complete_key(k)
-
+                    rnd.add(ch.cid, ch.priority, k, sh, np.asarray(seg))
         # dispatch largest message first: the biggest chunks are the
         # lone shards of sliced keys, and a sliced key's global round
         # releases only when EVERY shard from every party lands — on a
         # bandwidth-shaped WAN, sending them first starts the response
         # stream back while the small chunks are still serializing
-        # upstream (loopback is order-indifferent). Bookkeeping is
-        # positional over ``msgs``, so only the send order changes.
-        for mid, cid, srank, kvs, _mks, prio in sorted(
-                msgs, key=lambda m: -sum(
-                    np.asarray(v).nbytes for v in m[3].vals)):
-            with profiler.scope("pipeline:send", cat="pipeline",
-                                chunk=cid, server=srank,
-                                keys=len(kvs.keys),
-                                **self.po.van.round_args(rid)):
-                self.kvw.push(kvs, srank, priority=prio, pull=True,
-                              trace_round=rid, trace_chunk=cid,
-                              cb=lambda ts, m=mid: on_resp(ts, m))
-        return fut
+        # upstream (loopback is order-indifferent)
+        return rnd.send(order=lambda m: -sum(
+            np.asarray(v).nbytes for v in m[2].vals))
+
+    def _dense_sink(self, keys, outs):
+        """Where a dense round's answers go, as the ``part_of`` /
+        ``finish`` pair of a :class:`_ServerRound` and the buffers
+        behind them: a response entry is decoded into its key's flat
+        float32 buffer as it lands, and a finished key's buffer is
+        copied into the caller's ``out`` (a writable numpy ndarray;
+        views are fine), so a frame is released as soon as it is read
+        and ``out`` never sees a key that is not whole."""
+        bufs = {k: np.zeros(self._key_info[k].total, np.float32)
+                for k in keys}
+        out_of = dict(zip(keys, outs))
+
+        def part_of(kvs, i: int) -> int:
+            data = _wire_decode(kvs, i)
+            r_off = kvs.offset_of(i)
+            buf = bufs[kvs.keys[i]]
+            n = min(data.size, buf.size - r_off)
+            buf[r_off:r_off + n] = data[:n]
+            return n
+
+        def finish(k: int, _parts) -> None:
+            if out_of[k] is not None:
+                info = self._key_info[k]
+                np.copyto(out_of[k], bufs[k].reshape(info.shape)
+                          .astype(info.dtype, copy=False))
+
+        return part_of, finish, bufs
 
     def pull(self, key, out=None, priority: int = 0,
              trace_round: int = -1):
@@ -841,7 +795,9 @@ class KVStoreDist(KVStore):
 
         The list form with ``out`` batches the wire like list pushes:
         one request per server covering every (key, shard) entry, one
-        merged response back."""
+        merged response back. Either form is a round of
+        :class:`_ServerRound`: a key whose answer lacks a shard is
+        pulled again once and never copied into ``out`` from zeros."""
         keys = self._as_key_list(key)
         outs = out if isinstance(out, (list, tuple)) and len(keys) > 1 \
             else [out] * len(keys)
@@ -855,8 +811,19 @@ class KVStoreDist(KVStore):
         if (len(keys) > 1 and out is not None
                 and not (self._ts is not None
                          and any(self._ts_ver.get(k, 0) for k in keys))):
-            self._pull_batch(keys, list(outs), priority,
-                             trace_round=trace_round)
+            for k, o in zip(keys, outs):
+                assert self._key_info.get(k) is not None, \
+                    f"pull of key {k} before init"
+                if not (isinstance(o, np.ndarray) and o.flags.writeable):
+                    raise TypeError(
+                        "batched pull requires writable numpy ndarrays")
+            part_of, finish, _bufs = self._dense_sink(keys, outs)
+            rnd = _ServerRound(self, "pull", keys, priority, part_of,
+                               finish, rid=trace_round, push=False)
+            for k in keys:
+                for sh in self._key_info[k].shards:
+                    rnd.add(0, priority, k, sh)
+            rnd.send()
             return None
         results = []
         for k, o in zip(keys, outs):
@@ -865,82 +832,6 @@ class KVStoreDist(KVStore):
         if out is None:
             return results[0] if len(results) == 1 else results
         return None
-
-    def _pull_batch(self, keys: List[int], outs: List, priority: int,
-                    on_key: Optional[Callable[[int], None]] = None,
-                    trace_round: int = -1) -> None:
-        for k, o in zip(keys, outs):
-            assert self._key_info.get(k) is not None, \
-                f"pull of key {k} before init"
-            if not (isinstance(o, np.ndarray) and o.flags.writeable):
-                raise TypeError(
-                    "batched pull requires writable numpy ndarrays")
-        bufs = {k: np.zeros(self._key_info[k].total, np.float32)
-                for k in keys}
-        out_of = dict(zip(keys, outs))
-        # per-server request covering every (key, shard) entry on it
-        per_server: Dict[int, KVPairs] = {}
-        server_keys: Dict[int, List[int]] = {}
-        msgs_left: Dict[int, int] = {}   # key -> responses outstanding
-        for k in keys:
-            info = self._key_info[k]
-            for sh in info.shards:
-                kvs = per_server.setdefault(sh.server_rank, KVPairs())
-                kvs.keys.append(k)
-                kvs.vals.append(np.zeros(0, np.float32))
-                kvs.offsets.append(sh.offset)
-                kvs.totals.append(sh.total)
-                kvs.lens.append(sh.length)
-                server_keys.setdefault(sh.server_rank, []).append(k)
-        # one response per server message; a key completes when every
-        # server holding one of its shards has responded
-        with self._lock:
-            for srank, ks in server_keys.items():
-                for k in set(ks):
-                    msgs_left[k] = msgs_left.get(k, 0) + 1
-        for k in keys:
-            self._track(1, k)
-
-        def on_data(ts: int, srank: int):
-            fail = self.kvw.take_failure(ts)
-            if fail is not None:
-                with self._lock:
-                    self._transport_errors.append(
-                        f"pull keys {sorted(set(server_keys[srank]))}: "
-                        f"{fail}")
-            finished = []
-            for kvs in self.kvw.take_response(ts):
-                for i, k in enumerate(kvs.keys):
-                    data = _wire_decode(kvs, i)
-                    r_off = kvs.offset_of(i)
-                    buf = bufs[k]
-                    n = min(data.size, buf.size - r_off)
-                    buf[r_off:r_off + n] = data[:n]
-            with self._lock:
-                for k in set(server_keys[srank]):
-                    msgs_left[k] -= 1
-                    if msgs_left[k] == 0:
-                        finished.append(k)
-            for k in finished:
-                info = self._key_info[k]
-                np.copyto(out_of[k], bufs[k].reshape(info.shape)
-                          .astype(info.dtype, copy=False))
-                self._untrack(k)
-                if on_key is not None:
-                    # async completion hook (push_pull_async fallback
-                    # path): fires AFTER the out array holds the data
-                    on_key(k)
-
-        for srank, kvs in per_server.items():
-            def issue(sr=srank, kv=kvs):
-                self.kvw.pull(kv.keys, sr, offsets=kv.offsets,
-                              totals=kv.totals, lens=kv.lens,
-                              priority=priority, trace_round=trace_round,
-                              cb=lambda ts, s=sr: on_data(ts, s))
-
-            # the message must not go out until EVERY key in it has its
-            # push round acked (the per-key freshness ordering, batched)
-            self._issue_after_push_acks(set(server_keys[srank]), issue)
 
     def _pull_one(self, key: int, out, priority: int,
                   trace_round: int = -1):
@@ -966,49 +857,30 @@ class KVStoreDist(KVStore):
             raise TypeError(
                 "pull(out=...) requires a writable numpy ndarray; for jax "
                 "arrays use the blocking return form: x = kv.pull(key)")
+        # the per-key form of the list pull: a request a shard, so P3's
+        # slices keep their own priorities. The blocking form hands back
+        # the assembly buffer itself; a give-up leaves it zeros and
+        # surfaces from wait(), as it always did.
+        part_of, finish, bufs = self._dense_sink([key], [out])
+        rnd = _ServerRound(self, "pull", [key], priority, part_of, finish,
+                           rid=trace_round, push=False)
+        for i, sh in enumerate(info.shards):
+            rnd.add(i, priority, key, sh)
+        fut = rnd.send()
+        if out is not None:
+            return None
+        self._block_on(fut, key, self.cfg.op_timeout_s, "pull")
+        return bufs[key].reshape(info.shape).astype(info.dtype, copy=False)
+
+    @staticmethod
+    def _block_on(fut: RoundFuture, key: int, timeout: float,
+                  what: str) -> None:
+        """Block a blocking verb until ``key`` completes on ``fut``,
+        leaving the future's errors to wait()."""
         done = threading.Event()
-        buf = np.zeros(info.total, dtype=np.float32)
-        remaining = [len(info.shards)]
-        self._track(1, key)
-
-        def issue():
-            for sh in info.shards:
-                self.kvw.pull(
-                    [key], sh.server_rank, offsets=[sh.offset],
-                    totals=[sh.total], lens=[sh.length], priority=priority,
-                    trace_round=trace_round,
-                    cb=lambda ts, s=sh: on_data(ts, s))
-
-        def on_data(ts: int, sh: sharding.Shard):
-            fail = self.kvw.take_failure(ts)
-            if fail is not None:
-                with self._lock:
-                    self._transport_errors.append(f"pull key {key}: {fail}")
-            resps = self.kvw.take_response(ts)
-            for kvs in resps:
-                for i, _k in enumerate(kvs.keys):
-                    data = _wire_decode(kvs, i)
-                    r_off = kvs.offset_of(i)
-                    n = min(data.size, info.total - r_off)
-                    buf[r_off:r_off + n] = data[:n]
-            with self._lock:
-                remaining[0] -= 1
-                last = remaining[0] == 0
-            if last:
-                if out is not None:
-                    # out must be a writable numpy ndarray (views are fine;
-                    # jax arrays are immutable — use the return form instead)
-                    np.copyto(out, buf.reshape(info.shape)
-                              .astype(info.dtype, copy=False))
-                done.set()
-                self._untrack(key)
-
-        self._issue_after_push_acks(key, issue)
-        if out is None:
-            if not done.wait(self.cfg.op_timeout_s):
-                raise TimeoutError(f"pull of key {key} timed out")
-            return buf.reshape(info.shape).astype(info.dtype, copy=False)
-        return None
+        fut.on_key(key, lambda _k: done.set())
+        if not done.wait(timeout):
+            raise TimeoutError(f"{what} of key {key} timed out")
 
     def _issue_after_push_acks(self, key, issue: Callable) -> None:
         """Run ``issue`` now, or defer it until the in-flight push round
@@ -1065,15 +937,9 @@ class KVStoreDist(KVStore):
             raise IndexError(
                 f"push_row_sparse: row ids out of range for key {key} "
                 f"({n_rows} rows)")
-        sh = info.shards[0]
-        with self._lock:
-            self._push_acks_left[key] = self._push_acks_left.get(key, 0) + 1
-        self._track(1, key)
-        kvs = KVPairs(keys=[key], vals=[rows.ravel()], aux=[ids],
-                      offsets=[sh.offset], totals=[sh.total],
-                      lens=[sh.length], compr="rsp")
-        self.kvw.push(kvs, sh.server_rank, priority=priority,
-                      cb=lambda ts, kk=key: self._on_push_ack(kk, ts))
+        rnd = _ServerRound(self, "push_row_sparse", [key], priority)
+        rnd.add(0, priority, key, info.shards[0], rows.ravel(), ids, "rsp")
+        rnd.send()
 
     def pull_row_sparse(self, key, row_ids, priority: int = 0,
                         timeout: float = None) -> np.ndarray:
@@ -1090,47 +956,39 @@ class KVStoreDist(KVStore):
             raise IndexError(
                 f"pull_row_sparse: row ids out of range for key {key} "
                 f"({info.shape[0]} rows)")
-        sh = info.shards[0]
         out = np.zeros((ids.size, row_len), np.float32)
-        done = threading.Event()
-        self._track(1, key)
 
-        def on_data(ts):
-            fail = self.kvw.take_failure(ts)
-            if fail is not None:
+        def finish(_k, ps):
+            for data, got in ps:
+                got = ids if got is None \
+                    else np.asarray(got, dtype=np.int64).ravel()
+                if not got.size:
+                    continue
+                rows = data.reshape(got.size, -1)
+                if got.size == ids.size and (got == ids).all():
+                    out[:] = rows       # common case: echo order
+                    continue
                 with self._lock:
                     self._transport_errors.append(
-                        f"pull_row_sparse key {key}: {fail}")
-            for kvs in self.kvw.take_response(ts):
-                for i, _k in enumerate(kvs.keys):
-                    data = np.asarray(kvs.vals[i], dtype=np.float32)
-                    got = np.asarray(kvs.aux[i], dtype=np.int64).ravel() \
-                        if kvs.aux[i] is not None else ids
-                    if got.size:
-                        rows = data.reshape(got.size, -1)
-                        if got.size == ids.size and (got == ids).all():
-                            out[:] = rows       # common case: echo order
-                        else:
-                            with self._lock:
-                                self._transport_errors.append(
-                                    f"pull_row_sparse key {key}: server "
-                                    f"served {got.size}/{ids.size} rows")
-                            pos = {int(r): j for j, r in enumerate(got)}
-                            for j, rid in enumerate(ids):
-                                if int(rid) in pos:
-                                    out[j] = rows[pos[int(rid)]]
-            done.set()
-            self._untrack(key)
+                        f"pull_row_sparse key {key}: server served "
+                        f"{got.size}/{ids.size} rows")
+                pos = {int(r): j for j, r in enumerate(got)}
+                for j, rid in enumerate(ids):
+                    if int(rid) in pos:
+                        out[j] = rows[pos[int(rid)]]
 
-        def issue():
-            self.kvw.pull([key], sh.server_rank, offsets=[sh.offset],
-                          totals=[sh.total], lens=[row_len],
-                          priority=priority, compr="rsp", aux=[ids],
-                          cb=on_data)
-
-        self._issue_after_push_acks(key, issue)
-        if not done.wait(timeout):
-            raise TimeoutError(f"pull_row_sparse of key {key} timed out")
+        # one request to the key's one shard, with the row ids in its
+        # aux part and the row length where a dense pull has the range's;
+        # the generic re-pull asks for ranges, so a short answer here is
+        # an error at once
+        rnd = _ServerRound(
+            self, "pull_row_sparse", [key], priority,
+            lambda kvs, i: (np.asarray(kvs.vals[i], dtype=np.float32),
+                            kvs.aux[i]),
+            finish, push=False, repull=False)
+        rnd.add(0, priority, key, info.shards[0], aux=ids, compr="rsp",
+                length=row_len)
+        self._block_on(rnd.send(), key, timeout, "pull_row_sparse")
         return out
 
     # -- the element-sparse round (the TPU-native BSC wire) ---------------
@@ -1147,16 +1005,15 @@ class KVStoreDist(KVStore):
     # push_pull_bsc_batch_async; push_pull_bsc_batch is its blocking
     # one-chunk form.
 
-    def _prepare_bsc_shards(self, keys, values_list, indices_list,
-                            wire_tag: str = "bsc"):
-        """Validate per-key sparse selections and partition them into
-        one KVPairs per server. ``wire_tag="bsc16"`` ships the selected
-        values as float16 (the quantized combined wire; indices stay
-        int32) — the
+    def _prepare_bsc_shards(self, rnd: _ServerRound, chunk,
+                            keys, values_list, indices_list) -> None:
+        """Validate one chunk's per-key sparse selections and put them
+        on ``rnd``, an entry a (key, shard). A chunk with a codec ships
+        the selected values as float16 (``bsc16``, the quantized
+        combined wire; indices stay int32) — the
         trainer's device-side error feedback makes the narrowing
         lossless on the wire (trainer_device.select)."""
-        per_server: Dict[int, KVPairs] = {}
-        server_keys: Dict[int, List[int]] = {}
+        wire_tag = "bsc16" if chunk.codec else "bsc"
         prepared = []
         for k, values, indices in zip(keys, values_list, indices_list):
             vals = np.ascontiguousarray(values, dtype=np.float32).ravel()
@@ -1184,19 +1041,12 @@ class KVStoreDist(KVStore):
                     sel = (idx >= sh.offset) & (idx < sh.offset + sh.length)
                     s_vals, s_idx = vals[sel], idx[sel] - sh.offset
                     copied += s_vals.nbytes + s_idx.nbytes
-                kvs = per_server.setdefault(sh.server_rank,
-                                            KVPairs(compr=wire_tag))
-                kvs.keys.append(k)
-                kvs.vals.append(s_vals.astype(np.float16)
-                                if wire_tag == "bsc16" else s_vals)
-                kvs.aux.append(s_idx.astype(np.int32, copy=False))
-                kvs.offsets.append(sh.offset)
-                kvs.totals.append(sh.total)
-                kvs.lens.append(sh.length)
-                server_keys.setdefault(sh.server_rank, []).append(k)
+                rnd.add(chunk.cid, chunk.priority, k, sh,
+                        s_vals.astype(np.float16)
+                        if wire_tag == "bsc16" else s_vals,
+                        s_idx.astype(np.int32, copy=False), wire_tag)
         if copied:
             telemetry.counter_inc("van.payload_bytes_copied", copied)
-        return per_server, server_keys
 
     def _bsc_entry(self, kvs: KVPairs, i: int, r_off: int):
         """Part ``i`` of a sparse round's response, whose range starts
@@ -1271,196 +1121,26 @@ class KVStoreDist(KVStore):
         keys = list(keys)
         sb = self.cfg.p3_slice_bytes if slice_bytes is None else slice_bytes
         sizes = [np.asarray(v).size * 8 for v in values_list]
+        # sparse chunks have exactly two widths: raw fp32 values ("bsc")
+        # or fp16 values ("bsc16") — any active wire codec maps to the
+        # narrow one (indices dominate past that)
         chunks = plan_chunks(
             list(range(len(keys))), sizes, sb, base_priority=priority,
             codec_for=(self._wire.chunk_codec if self._wire.enabled()
                        else None))
-        rid = self._begin_round()
-        fut = RoundFuture(keys, consume=self._consume_errors,
-                          max_retries=self.cfg.chunk_retries,
-                          on_abort=self._abort_round)
-        fut.trace_round = rid
-        parts: Dict[int, List] = {k: [] for k in keys}
-        expected_parts: Dict[int, int] = {}
-        msgs = []  # (mid, cid, srank, kvs, msg_keys, chunk_priority)
-        key_msgs: Dict[int, List[int]] = {k: [] for k in keys}
+        # a missing entry is NOT an empty aggregate: the round re-pulls
+        # a short key under "bsc" before it completes it
+        rnd = _ServerRound(
+            self, "push_pull_bsc_async", keys, priority,
+            lambda kvs, i: self._bsc_entry(kvs, i, kvs.offset_of(i)),
+            lambda _k, ps: self._join_bsc_parts(ps),
+            rid=self._begin_round(), pull_compr="bsc")
         for ch in chunks:
-            cks = [keys[i] for i in ch.items]
-            # sparse chunks have exactly two widths: raw fp32 values
-            # ("bsc") or fp16 values ("bsc16") — any active wire codec
-            # maps to the narrow one (indices dominate past that)
-            per_server, server_keys = self._prepare_bsc_shards(
-                cks, [values_list[i] for i in ch.items],
-                [indices_list[i] for i in ch.items],
-                wire_tag="bsc16" if ch.codec else "bsc")
-            for srank, kvs in per_server.items():
-                mid = len(msgs)
-                for k in set(server_keys[srank]):
-                    key_msgs[k].append(mid)
-                for k in server_keys[srank]:
-                    expected_parts[k] = expected_parts.get(k, 0) + 1
-                msgs.append((mid, ch.cid, srank, kvs,
-                             server_keys[srank], ch.priority))
-        msgs_left = {k: len(key_msgs[k]) for k in keys}
-        with self._lock:
-            for _mid, _cid, _srank, _kvs, mks, _p in msgs:
-                for k in mks:
-                    self._push_acks_left[k] = (
-                        self._push_acks_left.get(k, 0) + 1)
-        for _mid, _cid, _srank, _kvs, mks, _p in msgs:
-            for k in mks:
-                self._track(1, k)
-
-        def on_resp(ts: int, mid: int):
-            # a response into its keys' results: decode, join the parts,
-            # complete the keys the trainer waits for
-            _m, cid, srank, _kvs, _mks, _prio = msgs[mid]
-            with profiler.scope("pipeline:recv", cat="pipeline",
-                                chunk=cid, server=srank,
-                                **self.po.van.round_args(rid)):
-                take(ts, mid)
-
-        def take(ts: int, mid: int):
-            _m, cid, srank, m_kvs, mks, m_prio = msgs[mid]
-            fail = self.kvw.take_failure(ts)
-            # same bounded retry as push_pull_async's on_resp: re-issue
-            # the identical chunk message while the budget lasts, except
-            # to declared-dead peers (epoch recovery handles those)
-            if (fail is not None and "declared dead" not in fail
-                    and fut.retry_budget(cid)):
-                log.warning("push_pull_bsc_async chunk %d to server %d "
-                            "failed (%s); retry %d/%d", cid, srank,
-                            fail, fut.retries_used(cid), fut.max_retries)
-                telemetry.event("chunk.retry", cat="kvstore",
-                                chunk=cid, server=srank)
-                telemetry.counter_inc("chunk.retries")
-                self.kvw.push(m_kvs, srank, priority=m_prio, pull=True,
-                              trace_round=rid, trace_chunk=cid,
-                              cb=lambda ts2, m=mid: on_resp(ts2, m))
-                return
-            failed_keys = []
-            if fail is not None:
-                with self._lock:
-                    for k in sorted(set(mks)):
-                        err = f"push_pull_bsc_async key {k}: {fail}"
-                        self._transport_errors.append(err)
-                        failed_keys.append((k, err))
-            for k, err in failed_keys:
-                fut.add_error(k, err)   # future methods outside _lock
-            for kvs in self.kvw.take_response(ts):
-                for i, k in enumerate(kvs.keys):
-                    entry = self._bsc_entry(kvs, i, kvs.offset_of(i))
-                    with self._lock:
-                        parts[k].append(entry)
-            finished = []
-            ready = []
-            with self._lock:
-                for k in set(mks):
-                    msgs_left[k] -= 1
-                    if msgs_left[k] == 0:
-                        finished.append(k)
-                for k in mks:
-                    self._push_acks_left[k] -= 1
-                    if (self._push_acks_left[k] == 0
-                            and k in self._deferred):
-                        ready.extend(self._deferred.pop(k))
-            for k in mks:
-                self._untrack(k)
-            for fn in ready:
-                fn()
-            short = []
-            for k in finished:
-                with self._lock:
-                    ps = list(parts[k])
-                if fut.errors(k):
-                    # data is never coming: complete so joins raise
-                    fut.complete_key(k, (np.zeros(0, np.float32),
-                                         np.zeros(0, np.int64)))
-                elif len(ps) < expected_parts[k]:
-                    # a server acked without data — a missing entry is
-                    # NOT an empty aggregate; async re-pull (this runs
-                    # on a transport thread: never block here)
-                    short.append(k)
-                else:
-                    fut.complete_key(k, self._join_bsc_parts(ps))
-            if short:
-                self._repull_bsc_async(short, priority, fut)
-
-        for mid, cid, srank, kvs, _mks, prio in msgs:
-            with profiler.scope("pipeline:send", cat="pipeline",
-                                chunk=cid, server=srank,
-                                keys=len(kvs.keys),
-                                **self.po.van.round_args(rid)):
-                self.kvw.push(kvs, srank, priority=prio, pull=True,
-                              trace_round=rid, trace_chunk=cid,
-                              cb=lambda ts, m=mid: on_resp(ts, m))
-        return fut
-
-    def _repull_bsc_async(self, keys, priority: int,
-                          fut: RoundFuture) -> None:
-        """Async fallback pull for BSC keys whose combined ack came back
-        short: per-server "bsc" pulls, completing each key on ``fut`` as
-        its last response lands."""
-        per_server: Dict[int, KVPairs] = {}
-        server_keys: Dict[int, List[int]] = {}
-        for k in keys:
-            info = self._key_info[k]
-            for sh in info.shards:
-                kvs = per_server.setdefault(sh.server_rank,
-                                            KVPairs(compr="bsc"))
-                kvs.keys.append(k)
-                kvs.vals.append(np.zeros(0, np.float32))
-                kvs.offsets.append(sh.offset)
-                kvs.totals.append(sh.total)
-                kvs.lens.append(sh.length)
-                server_keys.setdefault(sh.server_rank, []).append(k)
-        parts: Dict[int, List] = {k: [] for k in keys}
-        msgs_left: Dict[int, int] = {}
-        with self._lock:
-            for srank, ks in server_keys.items():
-                for k in set(ks):
-                    msgs_left[k] = msgs_left.get(k, 0) + 1
-        for ks in server_keys.values():
-            for k in ks:
-                self._track(1, k)
-
-        def on_data(ts: int, srank: int):
-            fail = self.kvw.take_failure(ts)
-            failed_keys = []
-            if fail is not None:
-                with self._lock:
-                    for k in sorted(set(server_keys[srank])):
-                        err = f"bsc re-pull key {k}: {fail}"
-                        self._transport_errors.append(err)
-                        failed_keys.append((k, err))
-            for k, err in failed_keys:
-                fut.add_error(k, err)
-            for kvs in self.kvw.take_response(ts):
-                for i, k in enumerate(kvs.keys):
-                    entry = self._bsc_entry(kvs, i, kvs.offset_of(i))
-                    with self._lock:
-                        parts[k].append(entry)
-            finished = []
-            with self._lock:
-                for k in set(server_keys[srank]):
-                    msgs_left[k] -= 1
-                    if msgs_left[k] == 0:
-                        finished.append(k)
-            for k in server_keys[srank]:
-                self._untrack(k)
-            for k in finished:
-                with self._lock:
-                    ps = list(parts[k])
-                fut.complete_key(k, self._join_bsc_parts(ps))
-
-        for srank, kvs in per_server.items():
-            def issue(sr=srank, kv=kvs):
-                self.kvw.pull(kv.keys, sr, offsets=kv.offsets,
-                              totals=kv.totals, lens=kv.lens,
-                              priority=priority, compr="bsc",
-                              cb=lambda ts, s=sr: on_data(ts, s))
-
-            self._issue_after_push_acks(set(server_keys[srank]), issue)
+            self._prepare_bsc_shards(
+                rnd, ch, [keys[i] for i in ch.items],
+                [values_list[i] for i in ch.items],
+                [indices_list[i] for i in ch.items])
+        return rnd.send()
 
     def wait(self, keys=None, timeout: float = None) -> None:
         """Block until outstanding pushes/pulls complete. With ``keys``,
@@ -1487,7 +1167,7 @@ class KVStoreDist(KVStore):
         with self._lock:
             errs, self._transport_errors = self._transport_errors, []
         if errs:
-            raise _give_up_exc(errs)("transport gave up on " + "; ".join(errs))
+            raise give_up_exc(errs)("transport gave up on " + "; ".join(errs))
 
     waitall = wait
 

@@ -213,6 +213,9 @@ def test_health_off_overhead_is_a_none_check():
 # acceptance: closed loop against the ShapePlan ground truth
 # ---------------------------------------------------------------------------
 
+BIG = 262_144      # float32 elements of the closed loop's bw probe
+
+
 def test_closed_loop_board_matches_shape_plan(tmp_path):
     """2-party HiPS under scripts/shapes/wan2_50ms_100mbps.json (every
     global-tier link 50 ms / 100 Mbps). The global board — measured
@@ -233,7 +236,12 @@ def test_closed_loop_board_matches_shape_plan(tmp_path):
     try:
         sim.master.set_optimizer(SGD(learning_rate=1.0))
         small = np.zeros(512, np.float32)          # 2 KB: RTT probe
-        big = np.zeros(65_536, np.float32)         # 256 KB: bw probe
+        # 1 MB: bw probe. 84 ms on the shaped link, so the 10-20 ms of
+        # scheduling delay a box shared with five other xdist workers
+        # adds to a span stay under a fifth of it (a 256 KB probe is
+        # 21 ms: the estimator then read 50-68 Mbit/s against the
+        # band's 70 and took the driver's run with it)
+        big = np.zeros(BIG, np.float32)
 
         def init_on(kv):
             kv.init(0, small)
@@ -244,10 +252,16 @@ def test_closed_loop_board_matches_shape_plan(tmp_path):
                    for kv in sim.workers + [sim.master]])
 
         def step(kv):
+            # the probes in turn: a 1 MB frame holds the shaped link for
+            # 84 ms, and an RTT probe sent beside it queues behind it in
+            # one direction or the other in most rounds, which leaves
+            # the window's minimum to the few that did not, each with
+            # the box's scheduling delay on it
             kv.push_pull(0, np.ones(512, np.float32),
                          np.zeros(512, np.float32))
-            kv.push_pull(1, np.ones(65_536, np.float32),
-                         np.zeros(65_536, np.float32))
+            kv.wait()
+            kv.push_pull(1, np.ones(BIG, np.float32),
+                         np.zeros(BIG, np.float32))
             kv.wait()
 
         wan_links = ("9>8", "11>8")
