@@ -450,6 +450,40 @@ def check_gated_delta_forms() -> None:
                            f"with its scan form: {errs}")
 
 
+def check_expand(on_chip: bool) -> None:
+    """A sorted list made dense (``ops/expand.py``, the trainer's
+    apply): on the chip a list of 2^20 slots takes the kernel by the
+    op's own rule, as one Mosaic call, and is the scatter-add's result
+    bit for bit; off the chip the rule gives the scatter and the kernel
+    is driven interpreted at a small size."""
+    import jax
+    import jax.numpy as jnp
+
+    from geomx_tpu.ops import expand
+    from tools.expand_bench import a_list
+
+    slots = 1 << 20 if on_chip else 1 << 11
+    size = 50 * slots
+    v, p = (jnp.asarray(a) for a in a_list(size, slots, 0.9,
+                                           odd_values=True))
+    if expand.runs_kernel(p) != on_chip:
+        raise RuntimeError(f"the expand rule on this backend for {slots} "
+                           f"slots: {expand.runs_kernel(p)}")
+    kernel = expand._expander(size, not on_chip)
+    if on_chip and kernel.lower(v, p).as_text().count(
+            "tpu_custom_call") != 1:
+        raise RuntimeError("the expand kernel is not one Mosaic call")
+    got, want = kernel(v, p), expand.scattered(v, p, size)
+    same = bool(jnp.array_equal(
+        jax.lax.bitcast_convert_type(got, jnp.int32),
+        jax.lax.bitcast_convert_type(want, jnp.int32)))
+    say(f"expand: {slots} slots into {size} elements, kernel "
+        f"{'compiled' if on_chip else 'interpreted'}, bit-equal to the "
+        f"scatter-add: {same}")
+    if not same:
+        raise RuntimeError("the expand kernel disagrees with the scatter")
+
+
 def run_round(shape: dict, steps: int, compiles, mesh_party: bool) -> None:
     """The main path: warm up outside the FSA round, take ``steps``
     steps through a live 2-party topology, stop it, check the outcome."""
@@ -621,6 +655,7 @@ def main(argv=None) -> int:
     check_sanity()
     check_kernels(on_chip=not args.rehearse)
     check_attention_paths(on_chip=not args.rehearse)
+    check_expand(on_chip=not args.rehearse)
     shape = TINY if args.rehearse else FULL
     run_round(shape, args.steps, compiles, mesh_party=False)
     if stamp["count"] >= 4:

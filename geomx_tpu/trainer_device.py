@@ -61,6 +61,7 @@ import numpy as np
 from geomx_tpu import profiler, runtime, telemetry
 from geomx_tpu.kvstore.frontier import (plan_chunks,
                                         slice_bytes_from_shape)
+from geomx_tpu.ops import expand
 from geomx_tpu.ops.select import leaving, topk_flat
 
 __all__ = ["DeviceResidentTrainer"]
@@ -277,6 +278,12 @@ class DeviceResidentTrainer:
             up[cap:] = np.arange(fsize, fsize + cap, dtype=np.int32)
             self._uploads.append(up)
         self._filled = [0] * len(meta)  # slots each one's last round wrote
+        # the chunks whose apply expands its upload by the kernel of
+        # ops/expand.py (its rule: a TPU backend, no mesh, a long list)
+        mesh = self._mesh
+        self._expand_applies = sum(
+            expand.runs_kernel(jax.ShapeDtypeStruct((cap,), jnp.int32), mesh)
+            for *_, cap in meta)
         sel_bounds = [(m[0], m[1]) for m in meta]
 
         # u and v are donated: the round rebinds both from the outputs,
@@ -302,14 +309,14 @@ class DeviceResidentTrainer:
             # idx(cap) CHUNK-relative]. The positions ascend and are
             # distinct (the aggregate is sorted unique entries, keys in
             # flat order), the pad slots ascend on from the chunk's end
-            # or past it and drop: told so, XLA puts no sort before the
-            # scatter
+            # or past it and drop: what ops/expand.py asks of a list, to
+            # make it the chunk's dense update without a sort and, where
+            # its kernel runs, without a scatter
             cap = up.shape[0] // 2
             vals = jax.lax.bitcast_convert_type(up[:cap], jnp.float32)
             cidx = up[cap:]
-            g = jnp.zeros((fsize,), flat.dtype).at[cidx].add(
-                vals, indices_are_sorted=True, unique_indices=True,
-                mode="drop")
+            with jax.named_scope("apply_expand"):
+                g = expand.dense_from_sorted(vals, cidx, fsize, mesh=mesh)
             seg = jax.lax.dynamic_slice(flat, (flo,), (fsize,))
             if mom is None:
                 return (jax.lax.dynamic_update_slice(
@@ -411,12 +418,14 @@ class DeviceResidentTrainer:
         """The loss from the head of a download; grad_fn's counts, if
         any, go to their telemetry counters, and so do the number of
         keys this round selected by threshold and reset in a dense
-        masked pass (every key: neither has a second path) and the
-        number of chunks the round went out in."""
+        masked pass (every key: neither has a second path), the number
+        of chunks the round went out in and how many of them the apply
+        expands by the kernel."""
         telemetry.counter_inc("step.select_threshold_keys",
                               len(self._sizes))
         telemetry.counter_inc("step.dense_reset_keys", len(self._sizes))
         telemetry.counter_inc("trainer.round_chunks", len(self._chunks))
+        telemetry.counter_inc("trainer.expand_applies", self._expand_applies)
         head = np.atleast_1d(head)
         for name, value in zip(self._aux_names, head[1:]):
             telemetry.counter_inc(name, float(value))

@@ -425,7 +425,7 @@ def _bowl():
 
 
 def _local_trainer(wire_codec="", slice_bytes=0, shapes=None,
-                   grad_fn=None, **kw):
+                   grad_fn=None, store=None, **kw):
     """A trainer over the local store, which answers a round with the
     selection itself; the two settings the device step reads off a
     store's configuration are handed to it as one. The bowl over
@@ -434,7 +434,7 @@ def _local_trainer(wire_codec="", slice_bytes=0, shapes=None,
 
     from geomx_tpu.kvstore import create as kv_create
 
-    kv = kv_create("local")
+    kv = store or kv_create("local")
     kv.cfg = SimpleNamespace(wire_codec=wire_codec,
                              p3_slice_bytes=slice_bytes)
     kw = dict(dict(threshold=0.05, learning_rate=0.1, momentum=0.9), **kw)
@@ -586,6 +586,159 @@ def test_the_chips_compiler_puts_no_sort_before_the_apply(one_chip):
     text = tr._apply_chunk.lower(flat, flat, up, flo,
                                  fsize).compile().as_text()
     assert " scatter(" in text and " sort(" not in text
+
+
+# -- the apply's two forms (ops/expand.py) ------------------------------------
+
+def _forced(monkeypatch, answer=True):
+    """The op's rule answers ``answer`` from here on: the kernel runs
+    interpreted on this backend."""
+    from functools import partial
+
+    from geomx_tpu.ops import expand
+
+    monkeypatch.setattr(expand, "runs_kernel",
+                        partial(expand.runs_kernel, forced=answer))
+
+
+def _apply_primitives(tr, ci=0):
+    _lo, _hi, flo, fsize, cap = tr._chunk_meta[ci]
+    return _primitives(tr._apply_chunk.trace(
+        tr._flat, tr._mom, jnp.zeros(2 * cap, jnp.int32), flo,
+        fsize).jaxpr.jaxpr)
+
+
+def _meshed(kv):
+    """The local store dressed as a mesh party's: a mesh and how to
+    replicate over it."""
+    from jax.sharding import NamedSharding, PartitionSpec
+
+    kv.mesh = jax.make_mesh((2,), ("dp",))
+    kv.replicated_sharding = lambda: NamedSharding(kv.mesh, PartitionSpec())
+    return kv
+
+
+@pytest.mark.parametrize("where,kernel", [
+    ("a CPU backend", False),
+    ("a CPU backend, forced", True),
+    ("where Pallas compiles, under the floor", False),
+    ("where Pallas compiles, at the floor", True),
+    ("where Pallas compiles, with a mesh", False),
+    ("forced off where Pallas compiles", False),
+])
+def test_the_form_apply_chunk_takes(where, kernel, monkeypatch):
+    """The rule is the op's, asked with what the trainer knows (its
+    upload's slots, its store's mesh); the count a round books is the
+    number of chunks it answered yes for."""
+    from geomx_tpu import ops
+    from geomx_tpu.kvstore import create as kv_create
+    from geomx_tpu.ops import expand
+
+    cap = int(sum(max(int(np.prod(s) * 0.05), 1) for s in _SHAPES))
+    store = None
+    if "Pallas compiles" in where:
+        monkeypatch.setattr(ops, "pallas_interpret", lambda: False)
+        monkeypatch.setattr(expand, "EXPAND_MIN_SLOTS",
+                            cap + ("under" in where))
+    if "mesh" in where:
+        store = _meshed(kv_create("local"))
+    if "forced" in where:
+        _forced(monkeypatch, kernel)
+    tr = _local_trainer(store=store)
+    assert tr._chunk_meta[0][4] == cap
+    assert tr._expand_applies == int(kernel)
+    names = _apply_primitives(tr)
+    # (the kernel form has a scatter-add of its own: a part a count
+    # into its steps' table)
+    assert ("pallas_call" in names) == kernel, names
+    assert "scatter-add" in names or kernel, names
+
+
+@pytest.mark.parametrize("slice_bytes", [0, 96])
+def test_rounds_with_the_kernel_forced_are_the_scatters_rounds(
+        slice_bytes, monkeypatch):
+    """Three rounds applied by the kernel (interpreted) leave ``flat``
+    and the momentum what the scatter leaves, bit for bit: one chunk
+    and a plan of several; ``trainer.expand_applies`` moves by the
+    chunks a round."""
+    from geomx_tpu import telemetry
+
+    def rounds(tr):
+        for _ in range(3):
+            tr.step(jnp.asarray(0.5), None)
+        return (np.asarray(tr._flat).view(np.int32),
+                np.asarray(tr._mom).view(np.int32))
+
+    want = rounds(_local_trainer(slice_bytes=slice_bytes))
+    _forced(monkeypatch)
+    tr = _local_trainer(slice_bytes=slice_bytes)
+    assert tr._expand_applies == len(tr._chunks) >= 1 + bool(slice_bytes)
+    assert "pallas_call" in _apply_primitives(tr, len(tr._chunks) - 1)
+    was_on = telemetry.enabled()
+    telemetry.enable(True)
+    try:
+        before = telemetry.snapshot()["counters"].get(
+            "trainer.expand_applies", 0)
+        got = rounds(tr)
+        assert telemetry.snapshot()["counters"][
+            "trainer.expand_applies"] - before == 3 * len(tr._chunks)
+    finally:
+        telemetry.enable(was_on)
+    np.testing.assert_array_equal(got[0], want[0])
+    np.testing.assert_array_equal(got[1], want[1])
+    assert np.count_nonzero(got[0]) and np.count_nonzero(got[1])
+
+
+def _tpu_apply(monkeypatch):
+    """A trainer whose ``apply_chunk`` takes the kernel as a TPU backend
+    would compile it (Mosaic, not interpreted), and its arguments."""
+    from geomx_tpu import ops
+
+    monkeypatch.setattr(ops, "pallas_interpret", lambda: False)
+    _forced(monkeypatch)
+    tr = _local_trainer()
+    _lo, _hi, flo, fsize, cap = tr._chunk_meta[0]
+    return tr, (flo, fsize), cap
+
+
+def test_the_lowered_apply_stays_small(monkeypatch):
+    """Set-up follows a program's text (PR 47): lowered for a TPU, the
+    apply with the kernel is ONE Mosaic call and under 40,000
+    characters (23,618 when it was written; the scatter's 3,108), and
+    the scatter form is the text it was."""
+    plain = _local_trainer()
+    _lo, _hi, flo, fsize, cap = plain._chunk_meta[0]
+    up = jnp.zeros(2 * cap, jnp.int32)
+
+    def text(tr):
+        return tr._apply_chunk.trace(tr._flat, tr._mom, up, flo, fsize
+                                     ).lower(lowering_platforms=("tpu",)
+                                             ).as_text()
+
+    scatter = text(plain)
+    assert "tpu_custom_call" not in scatter and len(scatter) < 4_000
+    tr, _static, _cap = _tpu_apply(monkeypatch)
+    kernel = text(tr)
+    assert kernel.count("tpu_custom_call") == 1
+    assert len(kernel) < 40_000, len(kernel)
+    big = [line for line in kernel.splitlines()
+           if '"stablehlo.scatter"' in line and f"<{fsize}x" in line]
+    assert not big, big
+
+
+def test_the_chips_compiler_takes_the_apply_with_the_kernel(
+        one_chip, monkeypatch):
+    """Compiled for a v5e: the kernel is there, and no scatter of the
+    chunk's size."""
+    tr, (flo, fsize), cap = _tpu_apply(monkeypatch)
+    flat = jax.ShapeDtypeStruct((tr.total,), jnp.float32,
+                                sharding=one_chip)
+    up = jax.ShapeDtypeStruct((2 * cap,), jnp.int32, sharding=one_chip)
+    text = tr._apply_chunk.lower(flat, flat, up, flo,
+                                 fsize).compile().as_text()
+    assert "tpu_custom_call" in text
+    assert not [line for line in text.splitlines()
+                if " scatter(" in line and f"f32[{fsize}]" in line]
 
 
 def test_chunk_up_pads_past_the_chunk_in_ascending_order():
