@@ -31,7 +31,13 @@ correction bias that is a buffer, every block rematerialised) and
 ``sdar`` (block-diffusion training: both copies of a sequence through
 every layer, per-head q/k norms, normalised softmax top-8 of 128, the
 weighted masked-token loss ``moe.masked_diffusion_loss``), the last
-six as one rank's share of an expert-parallel layout.
+six as one rank's share of an expert-parallel layout; and ``ouro``, a
+looped dense decoder on the same ``rotary`` and ``causal_core``: one
+stack of sandwich-normed layers applied ``total_ut_steps`` times a pass
+as ONE ``scan`` with the parameters broadcast (a program holds each
+block once), every block and every exit rematerialised, an exit gate
+after every pass and the expected-exit loss ``ouro.looped_exit_loss``,
+as a rank's share of the vocabulary.
 """
 
 from geomx_tpu.models.cnn import LeNetCNN, create_cnn  # noqa: F401
