@@ -484,6 +484,60 @@ def check_expand(on_chip: bool) -> None:
         raise RuntimeError("the expand kernel disagrees with the scatter")
 
 
+# the largest key of each of the benchmark's cells (PERF.md section 4):
+# GPT-2's, OLMoE's, Laguna's, Qwen3-Next's and SDAR's, Mellum2's,
+# Kanana's, Ouro's
+LARGEST_KEYS = (38_597_376, 33_554_432, 25_690_112, 38_895_616, 28_311_552,
+                32_833_536, 12_582_912)
+
+
+def check_select(on_chip: bool) -> None:
+    """A key's exact top-1% with the compaction in its kernel form
+    (``ops/select.py``, the trainer's selection): on the chip a key of
+    each cell's largest size takes the kernel by the op's own rule, as
+    one Mosaic call, and gives XLA's form's positions, values,
+    threshold and cut bit for bit, on a key of ties, zeros of both
+    signs, an inf, a NaN and a denormal; off the chip the rule gives
+    XLA's form and the kernel is driven interpreted at a small size."""
+    import jax
+    import jax.numpy as jnp
+
+    from geomx_tpu.ops import select
+    from tools.select_kernel_bench import a_key
+
+    shipped = select.GEOMETRY
+    if not on_chip:
+        select.GEOMETRY = (128, 2, 8)
+    try:
+        for n in LARGEST_KEYS if on_chip else (40_000,):
+            k = n // 100
+            x = jnp.asarray(a_key(n, seed=n % 97, odd=True))
+            if select.runs_kernel(x, n) != on_chip:
+                raise RuntimeError(f"the selection's rule on this backend "
+                                   f"for {n} elements: "
+                                   f"{select.runs_kernel(x, n)}")
+            kernel = jax.jit(lambda x, k=k: select.topk_by_magnitude(
+                x, k, kernel=True))
+            if on_chip and kernel.lower(x).as_text().count(
+                    "tpu_custom_call") != 1:
+                raise RuntimeError("the selection's kernel form is not "
+                                   "one Mosaic call")
+            got = kernel(x)
+            want = jax.jit(lambda x, k=k: select.topk_by_magnitude(x, k))(x)
+            same = all(bool(jnp.array_equal(
+                jax.lax.bitcast_convert_type(a, jnp.int32),
+                jax.lax.bitcast_convert_type(b, jnp.int32)))
+                       for a, b in zip(got, want))
+            say(f"select: top {k} of {n} elements, compaction "
+                f"{'compiled' if on_chip else 'interpreted'}, bit-equal "
+                f"to XLA's form: {same}")
+            if not same:
+                raise RuntimeError("the selection's kernel form disagrees "
+                                   "with XLA's")
+    finally:
+        select.GEOMETRY = shipped
+
+
 def run_round(shape: dict, steps: int, compiles, mesh_party: bool) -> None:
     """The main path: warm up outside the FSA round, take ``steps``
     steps through a live 2-party topology, stop it, check the outcome."""
@@ -656,6 +710,7 @@ def main(argv=None) -> int:
     check_kernels(on_chip=not args.rehearse)
     check_attention_paths(on_chip=not args.rehearse)
     check_expand(on_chip=not args.rehearse)
+    check_select(on_chip=not args.rehearse)
     shape = TINY if args.rehearse else FULL
     run_round(shape, args.steps, compiles, mesh_party=False)
     if stamp["count"] >= 4:

@@ -17,8 +17,9 @@ on the WAN hop:
   channel assignment (reference: EvalMsgContribution, kv_app.h:978).
 
 The device trainer's own selection is ``ops.select``: the same exact
-top-k found by counting passes and a compaction, no sort (ROADMAP D5
-moves the two ``lax.top_k`` below onto it).
+top-k found by counting passes and a compaction (on a TPU a Pallas pass
+over the key's rows), no sort (ROADMAP D5 moves the two ``lax.top_k``
+below onto it).
 
 All functions are pure (state in, state out) and jit-compiled per
 (shape, static-arg) signature. The host-side numpy kernels in
@@ -42,7 +43,7 @@ __all__ = [
     "bsc_compress", "bsc_decompress", "bsc_pull_compress",
     "two_bit_quantize", "two_bit_dequantize", "dgt_block_contrib",
     "DeviceBSCCompressor", "device_compression_enabled",
-    "pallas_interpret",
+    "pallas_interpret", "kernel_form",
 ]
 
 BSC_MOMENTUM = 0.9  # reference: gradient_compression.cc:198
@@ -54,8 +55,10 @@ def device_compression_enabled() -> bool:
 
 def pallas_interpret() -> bool:
     """THE rule for every Pallas call site in the package (today the
-    flash-attention kernels, ops/flash_attention.py, and the gated delta
-    rule's chain of chunks, ops/gated_delta.py): kernels are
+    flash-attention kernels, ops/flash_attention.py, the gated delta
+    rule's chain of chunks, ops/gated_delta.py, the apply's expansion,
+    ops/expand.py, and the selection's compaction, ops/select.py):
+    kernels are
     compiled by Mosaic when jax's default backend is a TPU and run in
     interpret mode anywhere else (the CPU test suite). Nothing else may
     choose interpret mode, so a chip run can never take it silently;
@@ -63,6 +66,31 @@ def pallas_interpret() -> bool:
     import jax
 
     return jax.default_backend() != "tpu"
+
+
+def kernel_form(operand, size: int, minimum: int, mesh=None,
+                forced=None) -> bool:
+    """THE predicate of the ops that are one algorithm in two forms, a
+    Pallas kernel and plain XLA (``ops/expand.py``, the compaction of
+    ``ops/select.py``; each states its own ``minimum``): the kernel
+    where Pallas compiles (:func:`pallas_interpret` false: a TPU
+    backend), no mesh is in play (a Pallas call has no partitioning
+    rule; seen as ``models.transformer.runs_kernel`` sees it, the
+    abstract mesh of the context and of the operand's own sharding, or
+    handed over as ``mesh`` by a caller whose operands GSPMD shards over
+    ``Auto`` axes, which a trace does not show) and ``size`` is at least
+    ``minimum``; XLA's form otherwise. It reads what a trace can see and
+    nothing names a model. ``operand`` is an array, a tracer or a
+    ``ShapeDtypeStruct``. ``forced`` is for tests: the answer itself."""
+    if forced is not None:
+        return forced
+    import jax
+
+    return (not pallas_interpret()
+            and mesh is None
+            and jax.sharding.get_abstract_mesh().empty
+            and jax.typeof(operand).sharding.mesh.empty
+            and size >= minimum)
 
 
 # ---------------------------------------------------------------------------

@@ -65,28 +65,15 @@ EXPAND_MIN_SLOTS = 1 << 19
 
 
 def runs_kernel(positions, mesh=None, forced: Optional[bool] = None) -> bool:
-    """THE rule for the form of :func:`dense_from_sorted`: the kernel
-    where Pallas compiles (``ops.pallas_interpret()`` false: a TPU
-    backend), no mesh is in play (a Pallas call has no partitioning
-    rule; seen as ``models.transformer.runs_kernel`` sees it, the
-    abstract mesh of the context and of the operand's own sharding, or
-    handed over as ``mesh`` by a caller whose operands GSPMD shards over
-    ``Auto`` axes, which a trace does not show) and the list has at
-    least :data:`EXPAND_MIN_SLOTS` slots; the scatter otherwise. One
-    algorithm that wants another form at another size: the rule reads
-    what the trace can see and nothing names a model. ``forced`` is for
-    tests: the answer itself."""
-    if forced is not None:
-        return forced
-    import jax
+    """THE rule for the form of :func:`dense_from_sorted`:
+    ``ops.kernel_form`` (Pallas compiles, no mesh in play) for a list of
+    at least :data:`EXPAND_MIN_SLOTS` slots; the scatter otherwise. One
+    algorithm that wants another form at another size. ``forced`` is
+    for tests: the answer itself."""
+    from geomx_tpu.ops import kernel_form
 
-    from geomx_tpu.ops import pallas_interpret
-
-    return (not pallas_interpret()
-            and mesh is None
-            and jax.sharding.get_abstract_mesh().empty
-            and jax.typeof(positions).sharding.mesh.empty
-            and positions.shape[0] >= EXPAND_MIN_SLOTS)
+    return kernel_form(positions, positions.shape[0], EXPAND_MIN_SLOTS,
+                       mesh, forced)
 
 
 def dense_from_sorted(values, positions, size: int, mesh=None):

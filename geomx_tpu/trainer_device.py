@@ -62,7 +62,7 @@ from geomx_tpu import profiler, runtime, telemetry
 from geomx_tpu.kvstore.frontier import (plan_chunks,
                                         slice_bytes_from_shape)
 from geomx_tpu.ops import expand
-from geomx_tpu.ops.select import leaving, topk_flat
+from geomx_tpu.ops.select import kernel_keys, leaving, topk_flat
 
 __all__ = ["DeviceResidentTrainer"]
 
@@ -174,6 +174,9 @@ class DeviceResidentTrainer:
         mesh_codec = (getattr(kcfg, "mesh_codec", "none") or "none") \
             if self._mesh is not None else "none"
         self._mesh_quant = mesh_codec != "none"
+        # what the ops that have a kernel form are told (a local: the
+        # jitted programs must not hold the trainer)
+        mesh = self._mesh
 
         def _grad_cat(flat, X, y):
             lv = [p.reshape(s) for p, s in
@@ -197,7 +200,7 @@ class DeviceResidentTrainer:
             # model-flat positions, ascending and distinct (keys in
             # flat order, each key's ascending), v there, and each
             # key's rule of membership
-            idx, vals, rules = topk_flat(v, offsets, sizes, ks)
+            idx, vals, rules = topk_flat(v, offsets, sizes, ks, mesh=mesh)
             # the selected leave u and v in one dense masked pass over
             # both, in place, where two scatters wrote the k positions
             # one by one. The mask (a byte an element) is written a key
@@ -280,10 +283,15 @@ class DeviceResidentTrainer:
         self._filled = [0] * len(meta)  # slots each one's last round wrote
         # the chunks whose apply expands its upload by the kernel of
         # ops/expand.py (its rule: a TPU backend, no mesh, a long list)
-        mesh = self._mesh
         self._expand_applies = sum(
             expand.runs_kernel(jax.ShapeDtypeStruct((cap,), jnp.int32), mesh)
             for *_, cap in meta)
+        # and the keys whose selection compacts by the kernel of
+        # ops/select.py (its rule: the same, for the size groups that
+        # are selected one key after the other)
+        self._select_kernel_keys = len(kernel_keys(
+            jax.ShapeDtypeStruct((self.total,), jnp.float32), sizes, ks,
+            mesh))
         sel_bounds = [(m[0], m[1]) for m in meta]
 
         # u and v are donated: the round rebinds both from the outputs,
@@ -419,10 +427,13 @@ class DeviceResidentTrainer:
         any, go to their telemetry counters, and so do the number of
         keys this round selected by threshold and reset in a dense
         masked pass (every key: neither has a second path), the number
-        of chunks the round went out in and how many of them the apply
-        expands by the kernel."""
+        of chunks the round went out in, how many of them the apply
+        expands by the kernel and how many keys' selections compact by
+        theirs."""
         telemetry.counter_inc("step.select_threshold_keys",
                               len(self._sizes))
+        telemetry.counter_inc("step.select_kernel_keys",
+                              self._select_kernel_keys)
         telemetry.counter_inc("step.dense_reset_keys", len(self._sizes))
         telemetry.counter_inc("trainer.round_chunks", len(self._chunks))
         telemetry.counter_inc("trainer.expand_applies", self._expand_applies)

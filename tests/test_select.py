@@ -182,3 +182,301 @@ def test_flat_selection_keeps_key_order_across_sizes():
     np.testing.assert_array_equal(got, want)
     np.testing.assert_array_equal(vals, np.asarray(v)[want])
     assert np.all(np.diff(got) > 0), "keys in flat order: all ascending"
+
+
+# -- the compaction's kernel form ---------------------------------------------
+
+# a tile of 2 windows (256 rows, 32,768 elements), a part of 8 pieces of
+# 128 slots: many tiles, parts and windows at sizes an interpreted
+# kernel walks in a second
+SMALL = (128, 2, 8)
+WINDOW, TILE, PART = 128 * 128, 2 * 128 * 128, 8 * 128
+
+
+def _odd_values(kind: str, n: int) -> np.ndarray:
+    """The kinds ``_values`` has, and three the kernel has to move bit
+    for bit: ties that straddle a window's and a tile's edge, every
+    special image among the largest, and denormals with zeros of both
+    signs at the k-th magnitude."""
+    rng = np.random.default_rng(n + 1)
+    if kind == "ties_at_the_edges":
+        # one magnitude at every position near a window's or a tile's
+        # first row, of both signs: the cut falls among them
+        x = (rng.random(n) * 0.25).astype(np.float32)
+        for edge in range(WINDOW, n, WINDOW):
+            x[max(edge - 70, 0):edge + 70] = -0.75
+        x[::2] = np.abs(x[::2])
+        return x
+    if kind == "specials":
+        x = rng.standard_normal(n).astype(np.float32)
+        at = rng.choice(n, min(n, 8), replace=False)
+        x[at] = np.resize(np.array(
+            [np.inf, -np.inf, np.nan, -np.nan, 3e38, -3e38], np.float32),
+            len(at))
+        return x
+    if kind == "denormals":
+        x = np.where(rng.random(n) < 0.5, -0.0, 0.0).astype(np.float32)
+        live = rng.random(n) < 0.02
+        x[live] = (rng.integers(1, 1 << 22, live.sum()).astype(np.int32)
+                   | (rng.integers(0, 2, live.sum()).astype(np.int32) << 31)
+                   ).view(np.float32)
+        return x
+    return _values(kind, n)
+
+
+def _both_forms(x, k, monkeypatch, geometry=SMALL):
+    """(kernel form, XLA's form) of one key, every result as integers;
+    the kernel interpreted (this backend) at ``geometry``."""
+    def images(out):
+        return [np.asarray(a).view(np.int32) for a in out]
+
+    monkeypatch.setattr(select, "GEOMETRY", geometry)
+    return (images(jax.jit(
+        lambda x: select.topk_by_magnitude(x, k, kernel=True))(x)),
+            images(jax.jit(lambda x: select.topk_by_magnitude(x, k))(x)))
+
+
+@pytest.mark.parametrize("threshold", [0.01, 0.3])
+@pytest.mark.parametrize("kind", [
+    "normal", "ties", "zeros", "equal", "infinity", "ties_at_the_edges",
+    "specials", "denormals"])
+@pytest.mark.parametrize("n", [
+    100,                # under a row
+    WINDOW - 1,         # a row short of a window
+    WINDOW + 129,       # a window, a row and a lane
+    TILE,               # a tile exactly
+    TILE + WINDOW + 5,  # off a tile, off a window, off a row
+    5 * TILE - 128 * 3 + 77,
+])
+def test_the_kernel_form_is_xlas_form_bit_for_bit(n, kind, threshold,
+                                                   monkeypatch):
+    """Positions, values, ``t`` and ``cut`` of both forms of the
+    compaction (the kernel interpreted, at a small geometry), for sizes
+    that end inside a window, a tile and a piece."""
+    k = max(int(n * threshold), 1)
+    x = jnp.asarray(_odd_values(kind, n))
+    got, want = _both_forms(x, k, monkeypatch)
+    for a, b, what in zip(got, want, ("positions", "values", "t", "cut")):
+        np.testing.assert_array_equal(a, b, err_msg=what)
+    assert got[0].shape == (k,) and np.all(np.diff(got[0]) > 0)
+    np.testing.assert_array_equal(
+        got[1], np.asarray(x).view(np.int32)[got[0]])
+
+
+@pytest.mark.parametrize("n,k", [
+    (select.GEOMETRY[1] * WINDOW + 12_345, 9_000),  # a tile and a bit
+    (70_000, 700), (3 * select.GEOMETRY[1] * WINDOW, 40)])
+def test_the_shipped_geometry(n, k, monkeypatch):
+    x = jnp.asarray(_odd_values("ties", n))
+    got, want = _both_forms(x, k, monkeypatch, select.GEOMETRY)
+    for a, b in zip(got, want):
+        np.testing.assert_array_equal(a, b)
+
+
+@pytest.mark.parametrize("where", ["spread", "one tile", "the last rows",
+                                   "every element"])
+def test_the_steps_table_covers_every_tile_and_part(where):
+    """A tile's steps take consecutive parts, from the part the tile
+    before ended on; every part that holds a slot of a tile's rows is
+    among that tile's; the table's length is tiles + parts - 1."""
+    piece, group, chunk = SMALL
+    tile_rows, rows = group * 128, 11 * group * 128 - 50
+    rng = np.random.default_rng(len(where))
+    count = np.zeros(rows, np.int64)
+    if where == "spread":
+        count = (rng.random(rows) < 0.7) * rng.integers(0, 9, rows)
+    elif where == "one tile":
+        count[3 * tile_rows + 5:4 * tile_rows - 9] = 20
+    elif where == "the last rows":
+        count[-40:] = 128
+    else:
+        count[:] = 128
+    k = int(count.sum())
+    ntiles, nparts = -(-rows // tile_rows), -(-k // (chunk * piece))
+    first = np.concatenate([[0], np.cumsum(count)])
+    tile_slot = np.minimum(first[np.arange(ntiles) * tile_rows], k)
+    tile_of = np.asarray(select._steps(
+        jnp.asarray(tile_slot, jnp.int32), ntiles, nparts, chunk * piece))
+    assert tile_of.shape == (ntiles + nparts - 1,)
+    assert (np.diff(tile_of) >= 0).all()
+    assert set(tile_of.tolist()) == set(range(ntiles))
+    part_of = np.arange(len(tile_of)) - tile_of
+    assert (np.diff(part_of) >= 0).all()
+    assert set(part_of.tolist()) == set(range(nparts))
+    met = set(zip(tile_of.tolist(), part_of.tolist()))
+    slot_row = np.repeat(np.arange(rows), count)
+    for s in range(0, k, 17):
+        assert (slot_row[s] // tile_rows, s // (chunk * piece)) in met, s
+
+
+# -- the rule -----------------------------------------------------------------
+
+def _flat(total):
+    return jax.ShapeDtypeStruct((total,), jnp.float32)
+
+
+def test_the_rule_off_the_chip_is_xlas_form():
+    from geomx_tpu import ops
+
+    size = select.SELECT_MIN_ELEMS
+    assert ops.pallas_interpret()
+    assert not select.runs_kernel(_flat(8 * size), size)
+    assert select.runs_kernel(_flat(8 * size), size, forced=True)
+    assert select.kernel_keys(_flat(8 * size), [size] * 8, [9] * 8) == []
+
+
+def test_the_rule_where_pallas_compiles(monkeypatch):
+    """A TPU backend stood in for: the kernel from ``SELECT_MIN_ELEMS``
+    elements, never under a mesh, whoever shows it; every size group
+    selected one key after the other, never one selected side by
+    side."""
+    from geomx_tpu import ops
+
+    monkeypatch.setattr(ops, "pallas_interpret", lambda: False)
+    size = select.SELECT_MIN_ELEMS
+    v = _flat(64 * size)
+    assert select.runs_kernel(v, size) and not select.runs_kernel(v, size - 1)
+    mesh = jax.make_mesh((2,), ("dp",))
+    assert not select.runs_kernel(v, size, mesh)
+    with jax.set_mesh(mesh):
+        assert not select.runs_kernel(v, size)
+    # eight keys of the least size lie side by side; nine do not
+    assert 8 * size <= select._SIDE_BY_SIDE_ELEMS < 9 * size
+    assert select.kernel_keys(v, [size] * 8, [9] * 8) == []
+    assert select.kernel_keys(v, [size] * 9, [9] * 9) == list(range(9))
+    assert select.kernel_keys(v, [size] * 9, [9] * 9, mesh) == []
+    # three large groups, a group of keys under the least size and one
+    # that lies side by side
+    sizes = [2 * size] * 8 + [size - 128] * 9 + [9 * size] + [300] * 5 + \
+        [2 * size + 128] * 5
+    ks = [max(s // 100, 1) for s in sizes]
+    assert select.kernel_keys(v, sizes, ks) == \
+        list(range(8)) + [17] + list(range(23, 28))
+    # cell 1's keys (PERF.md section 4): every weight matrix but the
+    # position embedding, 99.4% of its elements; the biases and norms
+    # and the position embedding lie side by side
+    gpt2 = [38_597_376] * 2 + [2_359_296] * 24 + [1_769_472] * 12 + \
+        [589_824] * 12 + [786_432] + [768] * 74
+    taken = select.kernel_keys(_flat(sum(gpt2)), gpt2,
+                               [max(s // 100, 1) for s in gpt2])
+    assert taken == list(range(50))
+
+
+def test_topk_flat_takes_the_form_the_rule_gives(monkeypatch):
+    """Forced, the keys selected one after the other compact by the
+    kernel (interpreted here) and the keys selected side by side by
+    XLA's form; the results are the unforced ones bit for bit."""
+    from functools import partial
+
+    monkeypatch.setattr(select, "_SIDE_BY_SIDE_ELEMS", 4000)
+    sizes = [3000, 700, 3000, 129, 700, 5000, 700]
+    ks = [max(int(s * 0.05), 1) for s in sizes]
+    offsets = (np.concatenate([[0], np.cumsum(sizes)])[:-1] + 5).tolist()
+    rng = np.random.default_rng(2)
+    v = jnp.asarray(np.round(rng.standard_normal(sum(sizes) + 9) * 4) / 4,
+                    jnp.float32)
+
+    def flat(v):
+        idx, vals, rules = select.topk_flat(v, offsets, sizes, ks)
+        return idx, vals, [(t, cut) for _m, t, cut in rules]
+
+    def traced():
+        return str(jax.make_jaxpr(lambda v: flat(v))(v))
+
+    want = jax.jit(lambda v: flat(v))(v)
+    assert "pallas_call" not in traced()
+    monkeypatch.setattr(select, "runs_kernel",
+                        partial(select.runs_kernel, forced=True))
+    # 2 x 3000 and 1 x 5000 are over the side-by-side bound; 3 x 700 and
+    # 129 are under it
+    assert select.kernel_keys(v, sizes, ks) == [0, 2, 5]
+    assert traced().count("pallas_call") == 2
+    got = jax.jit(lambda v: flat(v))(v)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_array_equal(np.asarray(a).view(np.int32),
+                                      np.asarray(b).view(np.int32))
+
+
+def test_the_kernel_lowers_to_one_mosaic_call():
+    """For a TPU, with no chip and no libtpu: one ``tpu_custom_call``,
+    no gather or scatter of the key's or the list's size, no sort."""
+    rows, k = 301_542, 385_973
+    text = select._compactor(rows, k, False, select.GEOMETRY).trace(
+        jax.ShapeDtypeStruct((rows * 128,), jnp.float32),
+        jax.ShapeDtypeStruct((rows,), jnp.int32),
+        jax.ShapeDtypeStruct((2,), jnp.int32)).lower(
+            lowering_platforms=("tpu",)).as_text()
+    assert text.count("tpu_custom_call") == 1, text[:2000]
+    assert "stablehlo.sort" not in text
+    assert '"stablehlo.scatter"' not in text
+    moved = [line for line in text.splitlines()
+             if '"stablehlo.gather"' in line
+             and (f"{k}x" in line or f"{rows}x128" in line)]
+    assert not moved, moved
+
+
+def test_a_small_trainers_step_holds_the_budgeted_mosaic_calls(monkeypatch):
+    """Set-up follows a program's text: a trainer whose keys come in
+    four sizes over the side-by-side bound lowers, for a TPU, a fused
+    step with one Mosaic call a size for the selection (the kernel's
+    own ``jit`` is lowered once a shape, whatever the number of keys),
+    and books the kernel's keys a round."""
+    from functools import partial
+    from types import SimpleNamespace
+
+    from geomx_tpu import ops, telemetry
+    from geomx_tpu.kvstore import create as kv_create
+    from geomx_tpu.trainer_device import DeviceResidentTrainer
+
+    monkeypatch.setattr(select, "_SIDE_BY_SIDE_ELEMS", 500)
+    monkeypatch.setattr(select, "SELECT_MIN_ELEMS", 300)
+    shapes = [(40, 16), (650,), (7,), (40, 16), (3, 100), (900,), (3, 100),
+              (129,), (650,)]
+
+    def grad_fn(leaves, x, _y):
+        loss = sum(jnp.sum((leaf - x) ** 2) for leaf in leaves)
+        return loss, [2 * (leaf - x) for leaf in leaves]
+
+    def trainer():
+        kv = kv_create("local")
+        kv.cfg = SimpleNamespace(wire_codec="", p3_slice_bytes=0)
+        return DeviceResidentTrainer(
+            [np.zeros(s, np.float32) for s in shapes], kv, grad_fn,
+            threshold=0.05, learning_rate=0.1, momentum=0.9)
+
+    def step_text(tr):
+        return tr._fwd_chunks.trace(
+            tr._flat, tr._u, tr._v, jnp.asarray(0.5), None).lower(
+                lowering_platforms=("tpu",)).as_text()
+
+    plain = trainer()
+    assert plain._select_kernel_keys == 0
+    assert "tpu_custom_call" not in step_text(plain)
+    monkeypatch.setattr(ops, "pallas_interpret", lambda: False)
+    tr = trainer()
+    # 2 x 650, 2 x 640, 900 and 2 x 300 are selected one after the
+    # other; 7 and 129 side by side
+    assert tr._select_kernel_keys == 7
+    assert step_text(tr).count("tpu_custom_call") == 4
+    # booked a round, interpreted here
+    monkeypatch.undo()
+    monkeypatch.setattr(select, "_SIDE_BY_SIDE_ELEMS", 500)
+    monkeypatch.setattr(select, "runs_kernel",
+                        partial(select.runs_kernel, forced=True))
+    tr = trainer()
+    was_on = telemetry.enabled()
+    telemetry.enable(True)
+    try:
+        before = telemetry.snapshot()["counters"].get(
+            "step.select_kernel_keys", 0)
+        want = plain.step(jnp.asarray(0.5), None)
+        got = tr.step(jnp.asarray(0.5), None)
+        assert telemetry.snapshot()["counters"][
+            "step.select_kernel_keys"] - before == tr._select_kernel_keys > 0
+    finally:
+        telemetry.enable(was_on)
+    assert got == want
+    for a, b in ((tr._flat, plain._flat), (tr._u, plain._u),
+                 (tr._v, plain._v)):
+        np.testing.assert_array_equal(np.asarray(a).view(np.int32),
+                                      np.asarray(b).view(np.int32))

@@ -75,11 +75,13 @@ def a_list(size: int, slots: int, fill: float, seed: int = 0,
 
 def _ms(fn, *ops, iters: int = 10) -> float:
     """Median milliseconds of a call, each fenced by itself."""
-    fn(*ops).block_until_ready()
+    import jax
+
+    jax.block_until_ready(fn(*ops))
     took = []
     for _ in range(iters):
         t0 = time.perf_counter()
-        fn(*ops).block_until_ready()
+        jax.block_until_ready(fn(*ops))
         took.append((time.perf_counter() - t0) * 1e3)
     return round(statistics.median(took), 3)
 
