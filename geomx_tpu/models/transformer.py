@@ -51,11 +51,13 @@ def make_attention(impl: str = "auto", *, causal: bool = True,
     """Attention implementation selector for ``Transformer(attn_fn=...)``.
 
     ``"flash"`` — the Pallas FlashAttention-2 kernels
-    (geomx_tpu.ops.flash_attention): O(block^2) on-chip memory,
-    MXU-tiled, the choice for long sequences on TPU. ``"dense"`` — the
-    XLA einsum reference. ``"auto"`` picks flash exactly where the
-    kernels compile (``ops.pallas_interpret()`` false, i.e. a TPU
-    backend) and dense where they would only be interpreted.
+    (geomx_tpu.ops.flash_attention): no score matrix in memory,
+    MXU-tiled, the choice for long sequences on TPU (its backward keeps
+    a head's cotangents on chip: to 16,384 positions at heads of 128).
+    ``"dense"`` — the XLA einsum reference. ``"auto"`` picks flash
+    exactly where the kernels compile (``ops.pallas_interpret()``
+    false, i.e. a TPU backend) and dense where they would only be
+    interpreted.
 
     A Pallas kernel has no SPMD partitioning rule, so on a multi-device
     ``mesh`` the flash path must run under shard_map; attention is

@@ -8,10 +8,21 @@ memory: the key/value blocks ride the innermost grid dimension, so each
 program instance holds one (block_q, D) query tile, one (block_k, D)
 key/value tile, and fp32 VMEM scratch accumulators carrying the
 online-softmax running (max, sumexp) state of FlashAttention-2 across
-grid steps. Peak VMEM is O(block^2), independent of sequence length.
+grid steps.
 The backward recomputes probabilities blockwise from the saved
-logsumexp (no quadratic residual): one kernel produces dQ (accumulating
-over k-blocks) and one produces dK/dV (accumulating over q-blocks).
+logsumexp (no quadratic residual) in ONE kernel: a live tile's scores,
+probabilities, dP and dS are computed once and feed dQ, dK and dV
+together (five block products a tile; a dQ kernel beside a dK/dV kernel
+took seven and ran the softmax arithmetic twice). dQ is summed over
+k-blocks and dK, dV over q-blocks, so no grid order has both sums
+innermost: each cotangent is summed in float32 VMEM scratch that holds
+a head's whole sequence and is written back once a head. The forward's
+VMEM is O(block^2) whatever the length; the backward's grows with it,
+(4 + 2 * 2) * (Tq * D + Tk * (D + Dv)) bytes for bfloat16 operands
+(scratch, and the outputs' blocks twice under the pipeline's double
+buffer): 34 MB at T 8,192 with heads of 192 / 128, the largest caller,
+and past 16,384 positions at heads of 128 the compiler refuses it (the
+kernels may take 64 MiB of VMEM).
 
 Layout contract matches ``geomx_tpu.models.transformer.dense_attention``:
 ``q, k, v`` are ``[B, T, H, D]`` and the return is ``[B, T, H, D]``
@@ -257,25 +268,27 @@ def window_live_blocks(t: int, w: int, block_q: int, block_k: int) -> int:
 @functools.lru_cache(maxsize=None)
 def _kernels(Tq: int, Tk: int, D: int, Dv: int, block_q: int, block_k: int,
              rule, q_len: int, kv_len: int, group: int, interpret: bool):
-    """Build (fwd, bwd_dq, bwd_dkv) pallas_calls for one static shape
-    and one static mask, ``rule``: ``True`` causal, ``False`` none,
+    """Build (fwd, bwd) pallas_calls for one static shape and one
+    static mask, ``rule``: ``True`` causal, ``False`` none,
     ``(t, b)``, the block mask of :func:`_block_rule` over ``q_len ==
     kv_len == 2 * t`` positions, or ``("window", w)``, the sliding
     window of :func:`_window_rule` over ``q_len == kv_len`` positions.
 
-    All three work on ``[B, H, T, D]``-transposed arrays (``v``, ``o``
+    Both work on ``[B, H, T, D]``-transposed arrays (``v``, ``o``
     and their cotangents ``[B, H, T, Dv]``: a value head has its own
     size, the scores and their scale are over ``D``); ``k`` and
     ``v`` have ``H / group`` heads, query head ``h`` reads key/value
-    head ``h // group``. Grids are (batch, head, outer-block,
-    inner-block) with the inner dimension iterated sequentially
-    on-core, accumulating into VMEM scratch (dK/dV: a key/value head's
-    ``group`` query heads are one more inner dimension, summed in the
-    same scratch). Under the window the inner dimension counts the
-    steps of a block's band (:func:`_window_sweeps`) and a step's tile
-    is the band's first plus the step. ``q_len`` <= Tq and ``kv_len``
-    <= Tk are the true (unpadded) lengths; keys past ``kv_len`` are
-    masked out.
+    head ``h // group``. The forward's grid is (batch, head, q-block,
+    k-block), the backward's (batch, key/value head, the head's
+    ``group`` query heads, k-block, q-block), the inner dimensions
+    iterated sequentially on-core, both accumulating into VMEM scratch
+    (the backward's holds a head's whole sequence: dQ is summed over a
+    query head's k-blocks ascending, dK and dV over the group's heads,
+    then their q-blocks ascending). Under the window the inner
+    dimension counts the steps of a block's band
+    (:func:`_window_sweeps`) and a step's tile is the band's first plus
+    the step. ``q_len`` <= Tq and ``kv_len`` <= Tk are the true
+    (unpadded) lengths; keys past ``kv_len`` are masked out.
     """
     import jax
     import jax.numpy as jnp
@@ -295,7 +308,7 @@ def _kernels(Tq: int, Tk: int, D: int, Dv: int, block_q: int, block_k: int,
     window = not isinstance(rule, bool) and rule[0] == "window"
     block = not isinstance(rule, bool) and not window
     # steps of the inner grid dimension: a q-block's sweep over k-blocks
-    # (forward, dQ), a k-block's over q-blocks (dK/dV)
+    # (forward), a k-block's over q-blocks (backward)
     k_steps, q_steps = nk, nq
     if block:
         (rule_mask, rule_live, rule_whole, block_k_seen,
@@ -383,11 +396,12 @@ def _kernels(Tq: int, Tk: int, D: int, Dv: int, block_q: int, block_k: int,
         first = (kj * block_k - causal_offset) // block_q
         return jnp.maximum(qi, jnp.clip(first, 0, nq - 1))
 
-    def _params(inner: int):
+    def _params(parallel: int, sequential: int):
         if interpret:
             return {}
         return dict(compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel",) * 3 + ("arbitrary",) * inner,
+            dimension_semantics=("parallel",) * parallel
+            + ("arbitrary",) * sequential,
             vmem_limit_bytes=64 * 1024 * 1024))
 
     # -- forward ---------------------------------------------------------
@@ -465,7 +479,7 @@ def _kernels(Tq: int, Tk: int, D: int, Dv: int, block_q: int, block_k: int,
                 pltpu.VMEM((block_q, 1), jnp.float32),
                 pltpu.VMEM((block_q, 1), jnp.float32),
             ],
-            interpret=interpret, **_params(1),
+            interpret=interpret, **_params(3, 1),
         )(q, k, v)
 
     def _p_and_ds(q, kb, vb, do, lse, delta, mask):
@@ -483,101 +497,87 @@ def _kernels(Tq: int, Tk: int, D: int, Dv: int, block_q: int, block_k: int,
             preferred_element_type=jnp.float32)
         return p, (p * (dp - delta) * scale).astype(kb.dtype)
 
-    # -- backward: dQ (accumulates over k-blocks) ------------------------
+    # -- backward --------------------------------------------------------
+    # grid (B, KV, group, nk, q_steps). A live tile's s, p, dP and dS are
+    # computed once and feed all three cotangents, each summed in scratch
+    # over a head's WHOLE sequence. An output's block is the whole
+    # sequence too, its index constant over the dimensions its cotangent
+    # is summed over: it stays in VMEM, takes the cast sum at the head's
+    # last step and leaves once.
 
-    def dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                  dq_ref, acc_ref):
-        qi, step = pl.program_id(2), pl.program_id(3)
-        kj = _k_at(qi, step)
+    def bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                   dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc):
+        g, kj, step = pl.program_id(2), pl.program_id(3), pl.program_id(4)
+        qi = _q_at(step, kj)
+        first = (kj == 0) & (step == 0)
+        last = (kj == nk - 1) & (step == q_steps - 1)
 
-        @pl.when(step == 0)
+        @pl.when(first)
         def _():
-            acc_ref[:] = jnp.zeros_like(acc_ref)
+            dq_acc[...] = jnp.zeros_like(dq_acc)
+
+        @pl.when(first & (g == 0))
+        def _():
+            dk_acc[...] = jnp.zeros_like(dk_acc)
+            dv_acc[...] = jnp.zeros_like(dv_acc)
 
         def body(mask):
-            kb = k_ref[0, 0]
-            _, ds = _p_and_ds(q_ref[0, 0], kb, v_ref[0, 0], do_ref[0, 0],
-                              lse_ref[0, 0], delta_ref[0, 0], mask)
-            acc_ref[:] = acc_ref[:] + jax.lax.dot_general(
+            qb, kb, dob = q_ref[0, 0], k_ref[0, 0], do_ref[0, 0]
+            p, ds = _p_and_ds(qb, kb, v_ref[0, 0], dob, lse_ref[0, 0],
+                              delta_ref[0, 0], mask)
+            q_rows = pl.ds(pl.multiple_of(qi * block_q, block_q), block_q)
+            k_rows = pl.ds(pl.multiple_of(kj * block_k, block_k), block_k)
+            dv_acc[k_rows] += jax.lax.dot_general(
+                p.astype(dob.dtype), dob, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            dk_acc[k_rows] += jax.lax.dot_general(
+                ds, qb, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            dq_acc[q_rows] += jax.lax.dot_general(
                 ds, kb, (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
 
         _on_live_block(qi, kj, body)
 
-        @pl.when(step == k_steps - 1)
+        @pl.when(last)
         def _():
-            dq_ref[0, 0] = acc_ref[:].astype(dq_ref.dtype)
+            dq_ref[0, 0] = dq_acc[...].astype(dq_ref.dtype)
 
-    def bwd_dq(q, k, v, do, lse, delta):
-        B, H = q.shape[0], q.shape[1]
-        qspec, dospec, rowspec = (pl.BlockSpec(
-            (1, 1, block_q, d), lambda b, h, i, j: (b, h, i, 0))
-            for d in (D, Dv, 1))
-        kspec, vspec = (pl.BlockSpec(
-            (1, 1, block_k, d),
-            lambda b, h, i, j: (b, h // group, _k_seen(i, j), 0))
-            for d in (D, Dv))
-        return pl.pallas_call(
-            dq_kernel,
-            grid=(B, H, nq, k_steps),
-            in_specs=[qspec, kspec, vspec, dospec, rowspec, rowspec],
-            out_specs=qspec,
-            out_shape=jax.ShapeDtypeStruct((B, H, Tq, D), q.dtype),
-            scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
-            interpret=interpret, **_params(1),
-        )(q, k, v, do, lse, delta)
-
-    # -- backward: dK, dV (accumulates over a key/value head's query
-    # heads and their q-blocks) ------------------------------------------
-
-    def dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                   dk_ref, dv_ref, dk_acc, dv_acc):
-        kj, g, step = pl.program_id(2), pl.program_id(3), pl.program_id(4)
-        qi = _q_at(step, kj)
-
-        @pl.when((g == 0) & (step == 0))
+        @pl.when(last & (g == group - 1))
         def _():
-            dk_acc[:] = jnp.zeros_like(dk_acc)
-            dv_acc[:] = jnp.zeros_like(dv_acc)
+            dk_ref[0, 0] = dk_acc[...].astype(dk_ref.dtype)
+            dv_ref[0, 0] = dv_acc[...].astype(dv_ref.dtype)
 
-        def body(mask):
-            qb = q_ref[0, 0]
-            dob = do_ref[0, 0]
-            p, ds = _p_and_ds(qb, k_ref[0, 0], v_ref[0, 0], dob,
-                              lse_ref[0, 0], delta_ref[0, 0], mask)
-            dv_acc[:] = dv_acc[:] + jax.lax.dot_general(
-                p.astype(dob.dtype), dob, (((0,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            dk_acc[:] = dk_acc[:] + jax.lax.dot_general(
-                ds, qb, (((0,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-
-        _on_live_block(qi, kj, body)
-
-        @pl.when((g == group - 1) & (step == q_steps - 1))
-        def _():
-            dk_ref[0, 0] = dk_acc[:].astype(dk_ref.dtype)
-            dv_ref[0, 0] = dv_acc[:].astype(dv_ref.dtype)
-
-    def bwd_dkv(q, k, v, do, lse, delta):
+    def bwd(q, k, v, do, lse, delta):
         B, KV = k.shape[0], k.shape[1]
         qspec, dospec, rowspec = (pl.BlockSpec(
             (1, 1, block_q, d),
-            lambda b, h, j, g, i: (b, h * group + g, _q_seen(i, j), 0))
+            lambda b, h, g, j, i: (b, h * group + g, _q_seen(i, j), 0))
             for d in (D, Dv, 1))
         kspec, vspec = (pl.BlockSpec((1, 1, block_k, d),
-                                     lambda b, h, j, g, i: (b, h, j, 0))
+                                     lambda b, h, g, j, i: (b, h, j, 0))
                         for d in (D, Dv))
         return pl.pallas_call(
-            dkv_kernel,
-            grid=(B, KV, nk, group, q_steps),
+            bwd_kernel,
+            grid=(B, KV, group, nk, q_steps),
             in_specs=[qspec, kspec, vspec, dospec, rowspec, rowspec],
-            out_specs=[kspec, vspec],
-            out_shape=[jax.ShapeDtypeStruct((B, KV, Tk, D), k.dtype),
-                       jax.ShapeDtypeStruct((B, KV, Tk, Dv), v.dtype)],
-            scratch_shapes=[pltpu.VMEM((block_k, D), jnp.float32),
-                            pltpu.VMEM((block_k, Dv), jnp.float32)],
-            interpret=interpret, **_params(2),
+            out_specs=[
+                pl.BlockSpec((1, 1, Tq, D),
+                             lambda b, h, g, j, i: (b, h * group + g, 0, 0)),
+                pl.BlockSpec((1, 1, Tk, D),
+                             lambda b, h, g, j, i: (b, h, 0, 0)),
+                pl.BlockSpec((1, 1, Tk, Dv),
+                             lambda b, h, g, j, i: (b, h, 0, 0)),
+            ],
+            out_shape=[
+                jax.ShapeDtypeStruct((B, KV * group, Tq, D), q.dtype),
+                jax.ShapeDtypeStruct((B, KV, Tk, D), k.dtype),
+                jax.ShapeDtypeStruct((B, KV, Tk, Dv), v.dtype),
+            ],
+            scratch_shapes=[pltpu.VMEM((Tq, D), jnp.float32),
+                            pltpu.VMEM((Tk, D), jnp.float32),
+                            pltpu.VMEM((Tk, Dv), jnp.float32)],
+            interpret=interpret, **_params(2, 3),
         )(q, k, v, do, lse, delta)
 
     if window:
@@ -585,8 +585,8 @@ def _kernels(Tq: int, Tk: int, D: int, Dv: int, block_q: int, block_k: int,
         # of their own a program traces and lowers each kernel once and
         # calls it, where a bare pallas_call is traced and lowered anew
         # at every call (set-up time: PERF.md section 6, PR 50)
-        return jax.jit(fwd), jax.jit(bwd_dq), jax.jit(bwd_dkv)
-    return fwd, bwd_dq, bwd_dkv
+        return jax.jit(fwd), jax.jit(bwd)
+    return fwd, bwd
 
 
 def flash_attention(q, k, v, *, causal: bool = True, block_mask=None,
@@ -665,8 +665,8 @@ def flash_attention(q, k, v, *, causal: bool = True, block_mask=None,
         rule = tuple(block_mask)
     else:
         rule = causal if window is None else ("window", int(window))
-    fwd, bwd_dq, bwd_dkv = _kernels(Tqp, Tkp, D, v.shape[3], bq, bk, rule,
-                                    Tq, Tk, group, pallas_interpret())
+    fwd, bwd = _kernels(Tqp, Tkp, D, v.shape[3], bq, bk, rule, Tq, Tk,
+                        group, pallas_interpret())
 
     @jax.custom_vjp
     def _attn(q, k, v):
@@ -691,11 +691,9 @@ def flash_attention(q, k, v, *, causal: bool = True, block_mask=None,
                         * _to_bhtd(out, Tqp).astype(jnp.float32),
                         axis=-1, keepdims=True)         # [B, H, Tqp, 1]
         lse = jnp.pad(lse, ((0, 0), (0, 0), (0, Tqp - Tq)))[..., None]
-        args = (_to_bhtd(q, Tqp), _to_bhtd(k, Tkp), _to_bhtd(v, Tkp), dot,
-                lse, delta)
-        dk, dv = bwd_dkv(*args)
-        return _to_bthd(bwd_dq(*args), Tq), _to_bthd(dk, Tk), _to_bthd(
-            dv, Tk)
+        dq, dk, dv = bwd(_to_bhtd(q, Tqp), _to_bhtd(k, Tkp),
+                         _to_bhtd(v, Tkp), dot, lse, delta)
+        return _to_bthd(dq, Tq), _to_bthd(dk, Tk), _to_bthd(dv, Tk)
 
     _attn.defvjp(_attn_fwd, _attn_bwd)
     return _attn(q, k, v)

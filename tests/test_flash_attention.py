@@ -602,29 +602,34 @@ def test_a_window_is_over_as_many_queries_as_keys():
         flash_attention(k, k, v, window=0)
 
 
-@pytest.mark.parametrize("rule,text_hash", [
-    (dict(causal=True), "2aaf6464b2fc8223"),
-    (dict(block_mask=(20, 4)), "b2de923e49b6612e"),
-    (dict(causal=False), "928a731488cd99ee")],
+@pytest.mark.parametrize("rule,forward_hash,text_hash", [
+    (dict(causal=True), "97384fd3caa4cef7", "14f153684dcc719f"),
+    (dict(block_mask=(20, 4)), "ef7074b924705d60", "d37d27452274a20d"),
+    (dict(causal=False), "59cc76c6c1cb9b2d", "10d56db61ccc035d")],
     ids=["causal", "block_mask", "none"])
-def test_the_other_rules_lower_to_the_text_they_had(rule, text_hash):
+def test_the_other_rules_lower_to_the_text_they_had(rule, forward_hash,
+                                                    text_hash):
     """The window is a third branch beside rules that do not change:
-    a causal, a block-mask and an unmasked call (forward and backward,
-    interpreted) lower to the text they had before the window rule
-    came (PR 50; the hashes are of the parent's text under this JAX)."""
+    a causal, a block-mask and an unmasked call (interpreted) lower to
+    the text they had. The forward's is the text from before the window
+    rule came (PR 50; equal on PR 64's parent and on its tree: that PR
+    left the forward kernel alone); forward and backward together lower
+    to PR 64's text, whose backward is one kernel where there were two
+    (the hashes are of the text under this JAX)."""
     import hashlib
     from functools import partial
 
     q = jax.ShapeDtypeStruct((1, 40, 1, 2, 16), jnp.float32)
     k = v = jax.ShapeDtypeStruct((1, 40, 1, 16), jnp.float32)
+    attend = partial(flash_attention, block_q=16, block_k=8, **rule)
 
     def both(q, k, v):
-        out, back = jax.vjp(partial(flash_attention, block_q=16, block_k=8,
-                                    **rule), q, k, v)
+        out, back = jax.vjp(attend, q, k, v)
         return out, back(out)
 
-    text = jax.jit(both).lower(q, k, v).as_text()
-    assert hashlib.sha256(text.encode()).hexdigest()[:16] == text_hash
+    for f, want in ((attend, forward_hash), (both, text_hash)):
+        text = jax.jit(f).lower(q, k, v).as_text()
+        assert hashlib.sha256(text.encode()).hexdigest()[:16] == want
 
 
 @pytest.mark.parametrize("case", ["cpu", "tpu", "forced", "short", "mesh",
@@ -667,3 +672,137 @@ def test_the_form_of_a_window_core(case, monkeypatch):
     kernel = case in ("tpu", "forced")
     assert ("pallas_call" in got) == kernel
     assert ("remat" in got or "checkpoint" in got) == (not kernel)
+
+
+def _masked_reference(q, k, v, mask):
+    """Attention of flat-head ``q`` [B, Tq, KV * G, D] on ``k``, ``v``
+    [B, Tk, KV, .] under ``mask`` [Tq, Tk] written out: the dense
+    product, float32, every key/value head repeated for its group."""
+    group = q.shape[2] // k.shape[2]
+    k, v = (jnp.repeat(x, group, axis=2) for x in (k, v))
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k) / jnp.sqrt(
+        jnp.float32(q.shape[-1]))
+    s = jnp.where(jnp.asarray(mask)[None, None], s, -1e30)
+    return jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s, -1), v)
+
+
+def _rule_mask(tq, tk, rule):
+    """[tq, tk] of the mask ``rule`` (``flash_attention``'s keywords)
+    describes."""
+    from geomx_tpu.models.transformer import block_diffusion_mask
+
+    qpos, kpos = np.arange(tq)[:, None] + (tk - tq), np.arange(tk)[None]
+    if "block_mask" in rule:
+        return np.asarray(block_diffusion_mask(*rule["block_mask"], np))
+    if "window" in rule:
+        return (qpos >= kpos) & (qpos - kpos < rule["window"])
+    return (qpos >= kpos) if rule["causal"] else np.ones((tq, tk), bool)
+
+
+def _count_eqns(jaxpr, primitive):
+    """Equations of ``primitive`` in ``jaxpr`` and the jaxprs its
+    equations carry (a ``jit``'s, a ``custom_vjp``'s), the kernel
+    bodies under a ``pallas_call`` left out."""
+    n = 0
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == primitive:
+            n += 1
+            continue
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (list, tuple)) else [value]:
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    n += _count_eqns(sub, primitive)
+    return n
+
+
+def _gradients_against_the_mask_written_out(tq, tk, kv, group, d, dv, rule,
+                                            block_q, block_k):
+    """The backward of ``flash_attention`` as (pallas_call equations of
+    forward + backward, the three cotangents, the reference's)."""
+    q = _rand((1, tq, kv * group, d), 0)
+    k, v = _rand((1, tk, kv, d), 1), _rand((1, tk, kv, dv), 2)
+    cot = _rand((1, tq, kv * group, dv), 3)
+
+    def loss(attend):
+        return lambda q, k, v: (attend(q, k, v) * cot).sum()
+
+    def kernel(q, k, v):
+        return flash_attention(q, k, v, block_q=block_q, block_k=block_k,
+                               **rule)
+
+    def reference(q, k, v):
+        return _masked_reference(q, k, v, _rule_mask(tq, tk, rule))
+
+    grad = jax.grad(loss(kernel), argnums=(0, 1, 2))
+    calls = _count_eqns(jax.make_jaxpr(grad)(q, k, v).jaxpr, "pallas_call")
+    return calls, grad(q, k, v), jax.grad(
+        loss(reference), argnums=(0, 1, 2))(q, k, v)
+
+
+# name: (Tq, Tk, KV, G, D, Dv, the rule, block_q, block_k): the rules
+# and the shapes the cells call the kernels with, small
+ONE_BACKWARD_CASES = {
+    "causal": (96, 96, 2, 1, 16, 16, dict(causal=True), 32, 32),
+    "causal_fewer_queries": (32, 96, 2, 1, 16, 16, dict(causal=True), 16, 32),
+    "grouped": (96, 96, 1, 3, 16, 16, dict(causal=True), 32, 64),
+    "value_head_of_its_own": (64, 64, 2, 2, 48, 32, dict(causal=True),
+                              32, 32),
+    "block_mask": (80, 80, 1, 2, 16, 16, dict(block_mask=(40, 4)), 16, 32),
+    "window": (96, 96, 1, 2, 16, 16, dict(window=24), 16, 16),
+    "pads": (100, 100, 2, 1, 16, 16, dict(causal=True), 32, 64),
+    "unmasked": (48, 80, 1, 2, 16, 16, dict(causal=False), 16, 16),
+}
+
+
+@pytest.mark.parametrize("case", list(ONE_BACKWARD_CASES))
+def test_one_backward_kernel_gives_the_three_cotangents(case):
+    """The backward is ONE kernel: the gradient of ``flash_attention``
+    holds exactly two ``pallas_call`` equations (forward, backward)
+    under every rule, and dQ, dK, dV are the dense product's."""
+    calls, got, want = _gradients_against_the_mask_written_out(
+        *ONE_BACKWARD_CASES[case])
+    assert calls == 2
+    for name, a, b in zip("qkv", got, want):
+        assert a.shape == b.shape
+        np.testing.assert_allclose(a, b, atol=2e-4, rtol=2e-4,
+                                   err_msg=f"d{name}")
+
+
+# the tile counts of the largest cells' cores (T 8,192: 16 q-blocks on
+# 8 k-blocks of 1,024 at heads of 128, on 16 of 512 at heads of 192)
+# with small tiles and heads: what the interpreter can walk
+RESIDENT_BLOCK_CASES = {
+    "mellum_full_16x8_grouped": (128, 128, 1, 4, 8, 8, dict(causal=True),
+                                 8, 16),
+    "kanana_16x16_value_head": (128, 128, 2, 1, 24, 16, dict(causal=True),
+                                8, 8),
+    "sdar_16x8_block_mask": (128, 128, 1, 4, 8, 8, dict(block_mask=(64, 4)),
+                             8, 16),
+    "mellum_window_16x16": (128, 128, 1, 4, 8, 8, dict(window=16), 8, 8),
+}
+
+
+@pytest.mark.parametrize("case", list(RESIDENT_BLOCK_CASES))
+def test_every_tile_of_the_resident_cotangents(case):
+    """dQ's block is a head's whole sequence, updated in place a
+    q-block at a time (dK and dV a k-block at a time): at the largest
+    cells' tile counts every q-block of dQ and every k-block of dK and
+    dV is the reference's, the first and the last by name, so an
+    off-by-one in the in-place update has no single-tile test to hide
+    in."""
+    tq, tk, _kv, _g, _d, _dv, _rule, block_q, block_k = shape = \
+        RESIDENT_BLOCK_CASES[case]
+    assert tq // block_q == 16 and tk // block_k in (8, 16)
+    calls, got, want = _gradients_against_the_mask_written_out(*shape)
+    assert calls == 2
+    for name, a, b, block in zip("qkv", got, want,
+                                 (block_q, block_k, block_k)):
+        for tile, rows in (("first", slice(0, block)),
+                           ("last", slice(-block, None)),
+                           ("every", slice(None))):
+            np.testing.assert_allclose(
+                a[:, rows], b[:, rows], atol=2e-4, rtol=2e-4,
+                err_msg=f"d{name}, {tile} tile")
+        assert float(jnp.abs(b[:, :block]).max()) > 0
+        assert float(jnp.abs(b[:, -block:]).max()) > 0
