@@ -16,7 +16,7 @@ core of latent attention: one shared rotary key in the interleaved
 pairing beside a head's non-rotary part, a value head of its own size,
 and ``block_diffusion_attention``, a clean and a noised copy of every
 sequence under one block mask, with ``rotary`` by position id),
-and the seven users of ``moe.sparse_dispatch``:
+and the eight users of ``moe.sparse_dispatch``:
 ``moe.MoEBlock`` (top-1), ``olmoe`` (softmax top-8 of 64), ``laguna``
 (window and full attention layers with their own head counts, a gated
 attention output, a dense first layer, a shared expert beside sigmoid
@@ -30,8 +30,15 @@ shared expert beside sigmoid top-6 of 128 chosen by score plus a
 correction bias that is a buffer, every block rematerialised) and
 ``sdar`` (block-diffusion training: both copies of a sequence through
 every layer, per-head q/k norms, normalised softmax top-8 of 128, the
-weighted masked-token loss ``moe.masked_diffusion_loss``), the last
-six as one rank's share of an expert-parallel layout; and ``ouro``, a
+weighted masked-token loss ``moe.masked_diffusion_loss``) and
+``nemotron_h`` (every layer ONE mixer by its letter in the pattern:
+Mamba-2 state-space layers on the chunked selective scan of
+``ops.ssd``, a shared expert beside sigmoid top-6 of 128 chosen by
+score plus a correction bias, ``moe.biased_sigmoid_router``, none of
+them gated, ``moe.plain_experts``, and grouped-query attention with no
+positional term; every block rematerialised, the head and loss a block
+of rows at a time), the last seven as one rank's share of an
+expert-parallel layout; and ``ouro``, a
 looped dense decoder on the same ``rotary`` and ``causal_core``: one
 stack of sandwich-normed layers applied ``total_ut_steps`` times a pass
 as ONE ``scan`` with the parameters broadcast (a program holds each

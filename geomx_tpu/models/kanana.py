@@ -68,10 +68,9 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from geomx_tpu.models.moe import (gated_experts, next_token_loss,
-                                  sparse_dispatch)
-from geomx_tpu.models.transformer import (HIGHEST, RMSNorm,
-                                          kernel_score_entries,
+from geomx_tpu.models.moe import (biased_sigmoid_router, gated_experts,
+                                  next_token_loss, sparse_dispatch)
+from geomx_tpu.models.transformer import (RMSNorm, kernel_score_entries,
                                           latent_attention,
                                           rotary_frequencies, score_entries)
 
@@ -132,17 +131,9 @@ class KananaBlock(nn.Module):
             with jax.named_scope("dense_ffn"):
                 y = self._gated_ffn(m, self.dense_width, "ffn_")
             return x + y.astype(jnp.float32), jnp.zeros((), jnp.int32)
-        with jax.named_scope("router"):
-            scores = nn.sigmoid(nn.Dense(
-                self.num_experts, use_bias=False, dtype=jnp.float32,
-                precision=HIGHEST, name="router")(m))
-            bias = self.variable(
-                "buffers", "e_score_correction_bias", jnp.zeros,
-                (self.num_experts,), jnp.float32).value
-            _, chosen = jax.lax.top_k(scores + bias, self.experts_per_token)
-            chosen_s = jnp.take_along_axis(scores, chosen, -1)
-            weights = self.routed_scale * chosen_s / (
-                jnp.sum(chosen_s, -1, keepdims=True) + 1e-20)
+        chosen, weights = biased_sigmoid_router(
+            self, m, self.num_experts, self.experts_per_token,
+            self.routed_scale)
         with jax.named_scope("shared_expert"):
             y = self._gated_ffn(m, self.shared_width, "shared_")
         held = self.local_experts[1] - self.local_experts[0]

@@ -28,7 +28,9 @@ scores normalised over the chosen and scaled (``models/laguna.py``,
 top-8 of 256 beside a shared expert that never comes here), softmax
 probabilities normalised over the chosen (``models/qwen3_next.py``,
 top-10 of 512), sigmoid scores chosen by score plus a bias and weighed
-by the score alone (``models/kanana.py``, top-6 of 128), softmax
+by the score alone (:func:`biased_sigmoid_router`: ``models/kanana.py``,
+top-6 of 128, and ``models/nemotron_h.py``, the same rule over experts
+with no gate, :func:`plain_experts`), softmax
 probabilities normalised over the chosen again (``models/sdar.py``,
 top-8 of 128 over two copies of every sequence), one probability (:class:`MoEBlock`, the ``k = 1``
 case); the dispatch multiplies and sums, it normalises nothing.
@@ -47,11 +49,12 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from geomx_tpu.models.transformer import runs_kernel
+from geomx_tpu.models.transformer import HIGHEST, runs_kernel
 
 __all__ = ["MoEBlock", "moe_param_sharding", "is_expert_param",
            "sparse_dispatch", "dispatch_cap", "gated_experts",
-           "next_token_loss", "masked_diffusion_loss"]
+           "plain_experts", "biased_sigmoid_router", "next_token_loss",
+           "masked_diffusion_loss"]
 
 # leaf names of expert-stacked params (leading axis = expert dim)
 EXPERT_PARAM_NAMES = ("w_up", "b_up", "w_dn", "b_dn")
@@ -199,7 +202,8 @@ def gated_experts(w_gate, w_up, w_down):
     """The ``expert_fn`` of :func:`sparse_dispatch` for stacks of
     SiLU-gated experts, ``w_gate`` and ``w_up`` [E, D, W], ``w_down``
     [E, W, D]: ``(silu(x Wg_e) * (x Wu_e)) Wd_e`` as three grouped
-    matmuls over the rows' groups."""
+    matmuls over the rows' groups. :func:`plain_experts` is the sibling
+    for experts with no gate."""
     def experts(rows, group_sizes, _row_expert):
         with jax.named_scope("expert_matmuls"):
             a = nn.silu(jax.lax.ragged_dot(rows, w_gate, group_sizes)) \
@@ -207,6 +211,44 @@ def gated_experts(w_gate, w_up, w_down):
             return jax.lax.ragged_dot(a, w_down, group_sizes)
 
     return experts
+
+
+def plain_experts(w_up, w_down, act):
+    """:func:`gated_experts`' sibling for stacks of experts with no
+    gate, ``w_up`` [E, D, W], ``w_down`` [E, W, D]: ``act(x Wu_e) Wd_e``
+    as two grouped matmuls over the rows' groups
+    (``models/nemotron_h.py``: ``act`` the squared ReLU)."""
+    def experts(rows, group_sizes, _row_expert):
+        with jax.named_scope("expert_matmuls"):
+            return jax.lax.ragged_dot(
+                act(jax.lax.ragged_dot(rows, w_up, group_sizes)), w_down,
+                group_sizes)
+
+    return experts
+
+
+def biased_sigmoid_router(module, m, num_experts: int, k: int, scale: float):
+    """The ``noaux_tc`` router with one group, inside ``module``'s
+    compact call (``models/kanana.py``, ``models/nemotron_h.py``): ``s
+    = sigmoid(m Wr)`` over ALL experts in float32 at ``highest``
+    (``module``'s parameter ``router/kernel``); the ``k`` largest of ``s
+    + b``, where ``b`` is ``module``'s ``e_score_correction_bias`` in
+    the collection ``buffers`` (zeros from ``init``, never a trained
+    leaf); the weights ``scale * s[chosen] / (sum s[chosen] + 1e-20)``:
+    the bias chooses, it does not weigh. Returns (chosen [..., k],
+    weights [..., k])."""
+    with jax.named_scope("router"):
+        scores = nn.sigmoid(nn.Dense(
+            num_experts, use_bias=False, dtype=jnp.float32,
+            precision=HIGHEST, name="router")(m))
+        bias = module.variable(
+            "buffers", "e_score_correction_bias", jnp.zeros,
+            (num_experts,), jnp.float32).value
+        _, chosen = jax.lax.top_k(scores + bias, k)
+        chosen_s = jnp.take_along_axis(scores, chosen, -1)
+        weights = scale * chosen_s / (
+            jnp.sum(chosen_s, -1, keepdims=True) + 1e-20)
+    return chosen, weights
 
 
 def next_token_loss(model, variables, toks):
@@ -217,7 +259,9 @@ def next_token_loss(model, variables, toks):
     gives (logits, rows routed to the held experts), and
     ``model.counts(batch, t, kernel)`` what the pass has by its shapes.
     Returns (loss, [the rows routed here, then ``model.counts``]), the
-    counts as float32."""
+    counts as float32. ``models/nemotron_h.py`` keeps a loss of its own
+    in the same form: its head runs a block of rows at a time and never
+    holds a sequence's logits."""
     logits, rows_local = model.apply(variables, toks[:, :-1])
     logp = jax.nn.log_softmax(logits)
     loss = -jnp.mean(jnp.take_along_axis(logp, toks[:, 1:, None], axis=-1))
